@@ -19,7 +19,7 @@ remove:
 - **cycles/sec** and the **fan-in** (``ctrl_peers``) per config.
 
 Drives the two committed claims of
-``benchmarks/r08_controlplane_scaling.json`` (BENCH_NOTES r9):
+``benchmarks/r08_controlplane_scaling.json`` (CHANGES.md PR 8):
 (a) tree mode cuts rank-0 cold-negotiation bytes/cycle ≥4x at 64
 simulated ranks on 8 simulated hosts vs star, and (b) steady-state
 bypass holds control bytes/cycle flat (within 2x) from 8→64 ranks.
@@ -32,7 +32,7 @@ Worker mode is selected internally via HVT_CPS_WORKER.
 
 Byte metrics are workload-determined, not timing-determined, so the
 numbers are stable on a loaded shared box (unlike latency sweeps — see
-BENCH_NOTES r8 on host co-tenancy).
+CHANGES.md PR 7 on host co-tenancy).
 """
 
 from __future__ import annotations

@@ -45,7 +45,7 @@ Measured claims (committed as ``benchmarks/r14_elastic_recovery.json``):
 
 Timing columns are wall-clock on a shared box, but the two arms run
 back-to-back under the identical workload, so the RATIO is the stable
-claim (BENCH_NOTES r8 methodology); byte/request counts are
+claim (CHANGES.md PR 7 methodology); byte/request counts are
 workload-determined and exact.
 
 Modes:

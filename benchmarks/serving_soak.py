@@ -21,7 +21,7 @@ rank.
 
 **The storyline** (one "pool" arm, phases separated by engine barriers,
 all traffic deterministic step counts — wall-clock-bounded loops
-deadlock gangs, see BENCH_NOTES r13):
+deadlock gangs, see CHANGES.md PR 13):
 
 - ``warm``/``baseline`` — every lane carries light traffic; the
   /statusz health plane must stay ALERT-FREE (the clean-gang pin).
@@ -992,7 +992,7 @@ def _spec(smoke: bool) -> dict:
     runs a MODERATE lane-worker count in capture: 64 ranks on the
     1-core harness box are already fully oversubscribed, so the pool's
     benefit cannot show there (that is the iso pair's job) while its
-    extra threads would only slow the box — see BENCH_NOTES r15."""
+    extra threads would only slow the box — see CHANGES.md PR 15."""
     if smoke:
         return {
             "np": 8, "hosts": 4, "per_host": 2, "window": 8,
@@ -1046,7 +1046,7 @@ def _iso_spec(smoke: bool) -> dict:
         # artifacts (the hot serving thread's GIL share delays the
         # SAME rank's other-tenant submits, identically in both arms)
         # scale with the request COUNT — probed span-level with the
-        # flight recorder, BENCH_NOTES r15. Deep hot_window/hot_burst
+        # flight recorder, CHANGES.md PR 15. Deep hot_window/hot_burst
         # keep several fused ops outstanding so the nopool engine
         # thread is continuously busy
         "hot_elems": 1048576, "hot_factor": 1,
@@ -1095,7 +1095,7 @@ def _col_ratio(arm_rec, spec, metric="exec_us_mean"):
     exactly the head-of-line blocking the lane pool removes and is
     stable on an oversubscribed 1-core harness box, where end-to-end
     p99s at ms scale are scheduler-quantum noise (reported as
-    `p99_ms_max` per lane but not gated — BENCH_NOTES r15). Column
+    `p99_ms_max` per lane but not gated — CHANGES.md PR 15). Column
     lanes containing the flaky rank are excluded: their spikes are the
     injected fault, not the hot neighbor."""
     flaky = (spec.get("faults") or {}).get("flaky_rank")
@@ -1204,7 +1204,7 @@ def capture(out_path, smoke=False):
     # tail), so p50 carries the head-of-line signal with far less
     # scheduler noise than p99 on the shared harness box — probed at
     # 1.63-1.89x across repeated runs vs 1.0-2.6x for exec-based and
-    # 1.0-1.24x for p99-based (BENCH_NOTES r15)
+    # 1.0-1.24x for p99-based (CHANGES.md PR 15)
     p50_pool = _col_ratio(iso_pool, iso_spec, metric="p50_ms_med")
     p50_nopool = _col_ratio(iso_nopool, iso_spec, metric="p50_ms_med")
     # `baseline` is the gated clean-gang observation; `boot` (driver
@@ -1243,7 +1243,7 @@ def capture(out_path, smoke=False):
     # lane's worker starts it mid-span (fraction ~ the hot lane's duty
     # cycle). Wall-clock exec/hol/p50/p99 ratios stay recorded but
     # ungated — on this box they are scheduler noise in BOTH
-    # directions (BENCH_NOTES r15).
+    # directions (CHANGES.md PR 15).
     ov_pool = _col_ov_frac(iso_pool, iso_spec)
     ov_nopool = _col_ov_frac(iso_nopool, iso_spec)
     hol_pool = _col_hol_us(iso_pool, iso_spec)
@@ -1267,7 +1267,7 @@ def capture(out_path, smoke=False):
         "nopool_over_pool": round(
             p50_nopool / max(p50_pool, 1e-9), 2),
         # end-to-end p99 ratios: reported, not gated (ms-scale
-        # scheduler noise on the 1-core harness box — BENCH_NOTES r15)
+        # scheduler noise on the 1-core harness box — CHANGES.md PR 15)
         "idle_col_p99_fire_over_baseline_pool": _col_ratio(
             iso_pool, iso_spec, metric="p99_ms_max"),
         "idle_col_p99_fire_over_baseline_nopool": _col_ratio(

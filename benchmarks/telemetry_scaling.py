@@ -32,7 +32,7 @@ driver-side ``RendezvousServer`` (with ``/statusz``), runs the real
 
 Byte metrics are workload-determined, so the reduction claim is stable
 on a loaded shared box; only the latency column is noisy and ``--check``
-never gates on it (BENCH_NOTES r8 methodology).
+never gates on it (CHANGES.md PR 7 methodology).
 
 Modes:
     --smoke [--out X.json]   8 ranks / 2 hosts pair (ci.sh --obs)

@@ -53,14 +53,14 @@ def allreduce_gradients(grads, *, op=C.Average, axis_name=None,
     """
 
     def _already_reduced(leaf) -> bool:
-        try:
-            from jax._src import config as _jcfg
-
-            if not _jcfg._check_vma.value:
-                return False
-            return axis_name not in jax.typeof(leaf).vma
-        except Exception:
+        # axis_index is varying over its axis by construction, so its
+        # type says whether the enclosing shard_map tracks varying axes
+        # at all (check_vma=False and pmap leave every vma empty). No
+        # fallback: a probe that cannot answer must raise, because a
+        # wrong "not reduced" sums every replicated gradient twice.
+        if axis_name not in jax.typeof(jax.lax.axis_index(axis_name)).vma:
             return False
+        return axis_name not in jax.typeof(leaf).vma
 
     def _one(g):
         c, ctx = compression.compress(g)

@@ -52,11 +52,12 @@ class GPTConfig:
     remat: bool = False  # jax.checkpoint each block (HBM ↔ FLOPs trade)
     # Attention implementation. False (default) = einsum-softmax; True =
     # pallas flash kernel; "auto" = pick per sequence length from the
-    # measured v5-lite crossover — einsum wins up to 2048 (MFU 0.85 vs
-    # 0.78 at 1024), flash wins beyond (1.5x at 4096; at 8192 the einsum
-    # path crashes the TPU worker outright). "auto" only upgrades to
-    # flash on a real TPU backend (elsewhere the kernel runs in pallas
-    # interpret mode, far slower than einsum). Flash requires the LOCAL
+    # crossover in ops/flash_attention.py (einsum up to 2048, flash
+    # beyond; captured by an earlier builder on another rig, not
+    # reproduced). "auto" only upgrades to flash on a TPU backend (on
+    # the CPU the kernel runs in pallas interpret mode, far slower than
+    # einsum). True needs sequence blocks that are multiples of 8
+    # wherever the kernel is compiled. Flash requires the LOCAL
     # sequence to be the full, contiguous sequence (its causal mask is
     # positional-by-block): under plain GSPMD sequence parallelism the
     # trace-time shape cannot reveal the sharding, so neither True nor

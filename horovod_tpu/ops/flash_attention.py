@@ -12,8 +12,10 @@ Backward pass recomputes score tiles (FLOPs-for-HBM trade, the same choice
 one over K/V blocks (dK, dV), using the saved logsumexp.
 
 No reference-framework counterpart (Horovod ships gradients, not kernels);
-this is part of the TPU framework's compute path. Falls back to Pallas
-interpret mode off-TPU so the CPU test mesh exercises the same code.
+this is part of the TPU framework's compute path. On the CPU the same
+kernel code runs through the Pallas interpreter (the CPU has no Mosaic
+compiler), so the CPU test mesh exercises it; on any other platform the
+kernel is compiled and a compiler refusal propagates.
 """
 
 from __future__ import annotations
@@ -30,8 +32,8 @@ _NEG_INF = -1e30
 _LANES = 128  # TPU vector lane width: scratch statistics are stored
               # broadcast across a full lane tile
 
-# Measured crossover on v5-lite (BENCH_NOTES.md round 4): einsum wins at
-# seq<=2048, flash from 4096 up (and is the only path that RUNS at 8192)
+# Crossover captured by an earlier builder on another rig, not
+# reproduced: einsum ahead at seq<=2048, flash from 4096 up
 FLASH_AUTO_THRESHOLD = 2048
 
 
@@ -58,27 +60,19 @@ def resolve_flash(use_flash, local_seq) -> bool:
 
 
 def _interpret() -> bool:
-    import os
-
-    v = os.environ.get("HVT_FLASH_INTERPRET")
-    if v is not None:
-        return v.strip().lower() not in ("0", "false", "no", "off", "")
-    # interpret everywhere but real TPU backends (CPU test meshes run the
-    # same kernel code); TPU *plugin* platforms (e.g. tunneled rigs) vary
-    # in pallas support — force with HVT_FLASH_INTERPRET=0/1
-    return jax.default_backend() != "tpu"
+    # only the CPU interprets (it has no Mosaic compiler; the CPU test
+    # mesh runs the same kernel code)
+    return jax.default_backend() == "cpu"
 
 
 # Two-level decomposition: the sequence operand STREAMS through the
 # grid's sequential LAST axis in large VMEM TILES (so per-kernel VMEM is
-# O(tile), never O(seq) — the previous full-sequence-resident design
-# blew the 16 MB scoped-VMEM limit at seq 8192, where the einsum path
-# crashes the TPU worker outright), while INSIDE the kernel a fori_loop
-# walks 128-wide sub-blocks of the tile with fine-grained causal
-# skipping (a one-block-per-grid-step design measured 26-37% slower at
-# seq 1024-4096: per-step pipeline overhead plus DMA of fully-masked
-# blocks). Online-softmax statistics live in VMEM scratch across the
-# tile axis.
+# O(tile), never O(seq) — a full-sequence-resident design exceeds the
+# 16 MB scoped-VMEM limit at seq 8192), while INSIDE the kernel a
+# fori_loop walks 128-wide sub-blocks of the tile with fine-grained
+# causal skipping (one block per grid step pays per-step pipeline
+# overhead plus DMA of fully-masked blocks). Online-softmax statistics
+# live in VMEM scratch across the tile axis.
 
 
 def _causal_n_eff(qi, block_q, ti, tile, block_k, n_sub):
@@ -296,20 +290,20 @@ def _flash(q, k, v, scale, causal, block_q, block_k, out_dtype):
 
 # The dkv backward kernel carries more per-tile state than the forward
 # (Q + dO tiles streamed together plus two fp32 accumulators), so the
-# largest tile that fits the 16 MB scoped-VMEM limit is SMALLER there:
-# tile 8192 runs in fwd/dq but blows VMEM in dkv (measured, v5-lite,
-# BENCH_NOTES r4). Cap dkv's tile independently so a user-requested
-# HVT_FLASH_SEQ_TILE=8192 degrades only the one kernel that needs it.
+# largest tile that fits the 16 MB scoped-VMEM limit is SMALLER there
+# (tile 8192 in dkv overflowing VMEM was captured by an earlier builder
+# on another rig, not reproduced). Cap dkv's tile independently so a
+# user-requested HVT_FLASH_SEQ_TILE=8192 degrades only the one kernel
+# that needs it.
 _DKV_TILE_CAP = 4096
 
 
 def _seq_tile(s, block_q, block_k, cap=None):
     """Streamed-sequence VMEM tile (elements of the seq axis per grid
-    step). Measured on v5-lite (d=64, 12 heads): 4096 is the sweet spot
-    — within 5% of a fully resident kernel at seq<=4096 while seq 8192
-    runs at MFU 0.35 (tile 2048 costs ~10% more refetch). Override with
-    HVT_FLASH_SEQ_TILE for other head dims; ``cap`` bounds the request
-    per-kernel (the dkv backward caps at ``_DKV_TILE_CAP``).
+    step). The default of 4096 was chosen by an earlier builder on
+    another rig (d=64, 12 heads) and has not been reproduced. Override
+    with HVT_FLASH_SEQ_TILE for other head dims; ``cap`` bounds the
+    request per-kernel (the dkv backward caps at ``_DKV_TILE_CAP``).
 
     The tile must divide ``s`` AND be a multiple of both block sizes —
     the kernels walk ``tile // block`` sub-blocks, so a remainder would
@@ -498,6 +492,15 @@ def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
         scale = d ** -0.5
     block_q = _blocks(s, block_q)
     block_k = _blocks(s, block_k)
+    if not _interpret() and (block_q % 8 or block_k % 8):
+        # Mosaic refuses the kernel ("cannot statically prove that index
+        # in dimension 2 is a multiple of 8"); the interpreter has no
+        # such limit, so name it here rather than inside the compiler
+        raise ValueError(
+            f"flash attention compiles only with sequence blocks that "
+            f"are multiples of 8: sequence length {s} clips the blocks "
+            f"to ({block_q}, {block_k}). Pad the sequence to a multiple "
+            f"of 8 or use the einsum path (use_flash=False)")
     # Kernels are gridded (batch, head, block): BHSD layout.
     to_bhsd = lambda x: jnp.transpose(x, (0, 2, 1, 3))
     o, lse = _flash(to_bhsd(q), to_bhsd(k), to_bhsd(v),

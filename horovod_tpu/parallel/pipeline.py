@@ -27,11 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 PIPELINE_AXIS = "pp"
 
 
@@ -113,7 +108,7 @@ def pipeline(stage_fn, stacked_params, x, n_micro: int, mesh,
         out = lax.psum(out, axis_name)
         return merge_microbatches(out)
 
-    return _shard_map(
+    return jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis_name), stacked_params),
                   P()),

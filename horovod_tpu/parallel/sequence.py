@@ -31,11 +31,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:  # jax>=0.4.35 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 _NEG_INF = -1e30
 
 
@@ -272,7 +267,7 @@ def ulysses_attention_shard(q, k, v, *, axis_name, causal=True, scale=None,
 
 def _wrap(shard_fn, q, k, v, *, mesh, axis_name, seq_specs, **kw):
     fn = functools.partial(shard_fn, axis_name=axis_name, **kw)
-    return _shard_map(fn, mesh=mesh, in_specs=(seq_specs,) * 3,
+    return jax.shard_map(fn, mesh=mesh, in_specs=(seq_specs,) * 3,
                       out_specs=seq_specs, check_vma=False)(q, k, v)
 
 

@@ -20,9 +20,9 @@ def run(fn, args=(), kwargs=None, np: int = 1,
     hvtrun machinery; inside ``fn`` the full horovod_tpu API (rank/size,
     collectives, DistributedOptimizer) is live.
 
-    ``force_cpu`` pins workers to the CPU JAX platform — required for
-    multi-process runs on a single machine where the accelerator is
-    single-process.
+    ``force_cpu`` pins workers to the CPU JAX platform (``JAX_PLATFORMS=
+    cpu``); without it the launcher still does so wherever several
+    workers share a host, because a chip belongs to one process.
 
     Remote ``hosts`` require a filesystem shared between launcher and
     workers: pass ``run_dir`` pointing into it (the pickled function and
@@ -59,7 +59,7 @@ def run(fn, args=(), kwargs=None, np: int = 1,
                  fn_path, tmp]
         extra = dict(env or {})
         if force_cpu:
-            extra["HVT_RUN_FORCE_CPU"] = "1"
+            extra["JAX_PLATFORMS"] = "cpu"
         old = {k: os.environ.get(k) for k in extra}
         os.environ.update(extra)
         try:

@@ -71,6 +71,19 @@ def slot_env_vars(slot: SlotInfo) -> dict:
     }
 
 
+def pin_engine_gang_to_cpu(env, slots):
+    """Engine-mode gangs reduce in host memory, and nothing assigns chips
+    to slots: every worker that initialises JAX's default backend claims
+    all of its host's chips, and a chip belongs to one process (the
+    second worker dies with "Unable to initialize backend 'tpu'"). So
+    where several slots share a host the gang runs on the CPU platform,
+    whatever the environment says — chip hosts export ``JAX_PLATFORMS``
+    themselves. A job that trains on the chips is one process per host
+    (``--backend jax``, or engine mode with one slot per host)."""
+    if any(s.local_size > 1 for s in slots):
+        env["JAX_PLATFORMS"] = "cpu"
+
+
 def get_host_assignments(hosts: List[HostInfo], np: int) -> List[SlotInfo]:
     """Pack ``np`` ranks onto host slots in host order, producing
     rank/local_rank/cross_rank per slot (reference hosts.py:100).

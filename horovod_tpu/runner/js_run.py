@@ -11,6 +11,8 @@ import subprocess
 import sys
 from typing import Dict, List, Optional
 
+from horovod_tpu.runner.hosts import pin_engine_gang_to_cpu
+
 
 def in_lsf_env(env: Optional[dict] = None) -> bool:
     env = os.environ if env is None else env
@@ -57,7 +59,7 @@ def build_jsrun_command(np: int, command: List[str],
 
 
 def js_run(args, slots, master_addr: str) -> int:
-    del slots  # placement is jsrun's job; identity comes from MPI env
+    # placement is jsrun's job; identity comes from MPI env
     if shutil.which("jsrun") is None:
         print("[hvtrun] jsrun not found on PATH", file=sys.stderr)
         return 1
@@ -72,5 +74,6 @@ def js_run(args, slots, master_addr: str) -> int:
     else:
         env["HVT_MASTER_ADDR"] = master_addr
         env["HVT_MASTER_PORT"] = str(args.master_port)
+        pin_engine_gang_to_cpu(env, slots)
     cmd = build_jsrun_command(args.num_proc, list(args.command))
     return subprocess.run(cmd, env=env).returncode

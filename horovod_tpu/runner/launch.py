@@ -23,7 +23,7 @@ import sys
 
 from horovod_tpu.runner import safe_exec
 from horovod_tpu.runner.hosts import (get_host_assignments, parse_hostfile,
-                                      parse_hosts)
+                                      parse_hosts, pin_engine_gang_to_cpu)
 
 _LOCAL_NAMES = ("localhost", "127.0.0.1")
 
@@ -130,7 +130,8 @@ def _ssh_command(env, hostname, ssh_port, command):
     gloo_run.py:114-145). Shared by the static and elastic paths."""
     inline = " ".join(
         f"{k}={shlex.quote(v)}" for k, v in env.items()
-        if k.startswith("HVT_") or k in ("PATH", "PYTHONPATH"))
+        if k.startswith("HVT_")
+        or k in ("PATH", "PYTHONPATH", "JAX_PLATFORMS"))
     remote = f"cd {shlex.quote(os.getcwd())} && env {inline} " + \
         " ".join(shlex.quote(c) for c in command)
     return ["ssh", "-o", "StrictHostKeyChecking=no", "-p",
@@ -152,6 +153,7 @@ def slot_env(base_env, slot, args, master_addr):
     if args.backend == "engine":
         env["HVT_MASTER_ADDR"] = master_addr
         env["HVT_MASTER_PORT"] = str(args.master_port)
+        pin_engine_gang_to_cpu(env, [slot])
     else:
         env["HVT_COORDINATOR_ADDR"] = f"{master_addr}:{args.master_port}"
     if args.timeline:

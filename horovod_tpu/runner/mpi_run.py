@@ -14,6 +14,8 @@ import subprocess
 import sys
 from typing import List, Optional
 
+from horovod_tpu.runner.hosts import pin_engine_gang_to_cpu
+
 OPENMPI = "OpenMPI"
 SPECTRUM = "Spectrum MPI"
 MPICH = "MPICH"
@@ -89,7 +91,8 @@ def build_mpirun_command(np: int, hosts: str, command: List[str],
     else:
         cmd += ["-hosts", ",".join(h.split(":")[0] for h in host_list)]
     forward = sorted(k for k in env
-                     if k.startswith("HVT_") or k in ("PATH", "PYTHONPATH"))
+                     if k.startswith("HVT_")
+                     or k in ("PATH", "PYTHONPATH", "JAX_PLATFORMS"))
     cmd += env_forward_args(impl, forward)
     cmd += extra_args or []
     cmd += command
@@ -141,6 +144,7 @@ def mpi_run(args, slots, master_addr: str) -> int:
     else:
         env["HVT_MASTER_ADDR"] = master_addr
         env["HVT_MASTER_PORT"] = str(args.master_port)
+        pin_engine_gang_to_cpu(env, slots)
     hosts = ",".join(sorted({f"{s.hostname}:{s.local_size}"
                              for s in slots}))
     cmd = build_mpirun_command(args.num_proc, hosts, list(args.command),

@@ -54,10 +54,6 @@ def maybe_arm_fault_timer(rank: int, spec: str = None):
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
     fn_path, out_dir = argv[0], argv[1]
-    if os.environ.get("HVT_RUN_FORCE_CPU") == "1":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
     from horovod_tpu.runner.codec import loads_base64
 
     with open(fn_path) as f:

@@ -1,6 +1,6 @@
 """bench.py harness validation on the virtual CPU mesh.
 
-The real numbers come from the driver's TPU run; these tests pin the
+The real numbers come from a run on the chip; these tests pin the
 harness semantics — measure() produces sane throughput/FLOP estimates on
 a multi-device mesh, and main()'s scaling sweep computes per-chip
 efficiency relative to the 1-chip run (the BASELINE.md metric of record).
@@ -18,12 +18,10 @@ import bench
 def test_measure_multidevice_smoke():
     import jax
 
-    (per_chip, total, std, flops_per_img, xla_flops, loss,
-     suspect) = bench.measure(
+    per_chip, total, std, flops_per_img, xla_flops, loss = bench.measure(
         "resnet50", jax.devices()[:2], per_chip_batch=1, num_iters=1,
         num_batches_per_iter=1, dtype_name="fp32", image_size=32)
     assert per_chip > 0
-    assert suspect is False
     assert total == pytest.approx(per_chip * 2)
     assert np.isfinite(loss)
     # 32px analytic value: 12.3 GFLOP * (32/224)^2 ≈ 0.25 GFLOP
@@ -41,11 +39,17 @@ def test_main_scaling_sweep_and_json_schema(monkeypatch, capsys):
                      num_batches_per_iter, dtype_name, image_size=224,
                      norm_impl="tpu", conv0_s2d=False, unroll=1):
         pc = per_chip_by_n[len(devices)]
-        return pc, pc * len(devices), 0.0, 12.3e9, 23.5e9, 1.23, False
+        return pc, pc * len(devices), 0.0, 12.3e9, 23.5e9, 1.23
 
     monkeypatch.setattr(bench, "measure", fake_measure)
     monkeypatch.setattr(bench, "calibrate_matmul_tflops", lambda p: 100.0)
+    monkeypatch.setattr(bench, "enable_compile_cache", lambda: "unused")
+    # without --force-cpu a run that finds only the CPU is refused
     monkeypatch.setattr(sys, "argv", ["bench.py"])
+    with pytest.raises(SystemExit, match="'cpu'"):
+        bench.main()
+    assert capsys.readouterr().out == ""
+    monkeypatch.setattr(sys, "argv", ["bench.py", "--force-cpu", "8"])
     bench.main()
     out = capsys.readouterr().out.strip().splitlines()[-1]
     rec = json.loads(out)
@@ -66,12 +70,46 @@ def test_main_scaling_sweep_and_json_schema(monkeypatch, capsys):
     # 8 virtual devices → sweep over powers of two, efficiency vs n=1
     assert rec["scaling"]["n"] == [1, 2, 4, 8]
     assert rec["scaling"]["efficiency"] == [1.0, 0.95, 0.9, 0.85]
-    # r5.0 record fields: suspect flag always present; mfu_vs_peak is
-    # null on cpu (paper peak is a TPU spec)
-    assert rec["suspect"] is False
-    assert "mfu_vs_peak" in rec and rec["mfu_vs_peak"] is None
+    # the published peak is a property of an accelerator: null on the cpu
+    assert rec["mfu_vs_peak"] is None and rec["peak_tflops"] is None
+    assert rec["config"]["device_kind"] == "cpu"
+    assert "suspect" not in rec
 
 
 def test_calibration_runs_on_cpu():
     tflops = bench.calibrate_matmul_tflops("cpu")
     assert tflops > 0
+
+
+def test_peak_comes_from_the_table_by_device_kind():
+    from types import SimpleNamespace as Device
+
+    assert bench.peak_bf16_tflops(
+        Device(platform="tpu", device_kind="TPU v5 lite")) == 197.0
+    assert bench.peak_bf16_tflops(
+        Device(platform="cpu", device_kind="cpu")) is None
+    # an accelerator that is not in the table is an error, not a default
+    with pytest.raises(ValueError, match="TPU v99"):
+        bench.peak_bf16_tflops(Device(platform="tpu",
+                                      device_kind="TPU v99"))
+
+
+def test_compile_cache_is_placed_from_outside_or_fixed(monkeypatch):
+    import os
+
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    # set: JAX's own handling of the variable is left alone
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert bench.enable_compile_cache() == "/some/dir"
+    assert jax.config.jax_compilation_cache_dir == before
+    # unset: one fixed path in the checkout, never a temporary name
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    fixed = os.path.join(os.path.dirname(os.path.abspath(bench.__file__)),
+                         ".jax_cache")
+    try:
+        assert bench.enable_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -1,0 +1,102 @@
+"""Main-path kernels at real width, compiled for a v5e that is described
+and not attached (the chip's compiler is installed with jaxlib's TPU
+runtime). Interpret-mode tests cannot see what Mosaic refuses — a slice
+not aligned to the tiling, a kernel over its VMEM budget — and these can,
+at no chip time. A compile that passes here is not a chip run.
+
+The compiles run in the pytest process, one at a time: two processes that
+describe the topology at once collide on libtpu's lock file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.parallel.sequence import ring_attention
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    return list(topo.devices)
+
+
+@pytest.fixture()
+def compiled_kernel(monkeypatch):
+    """Steer the kernel to its compiled form from the test (the code asks
+    ``jax.default_backend()``, which is the CPU here), with the persistent
+    cache off: an entry compiled for a described device is written but
+    cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _loss(attend):
+    def loss(q, k, v):
+        return (attend(q, k, v).astype(jnp.float32) ** 2).mean()
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+
+
+def _qkv(shape, sharding):
+    b, s, h, h_kv, d = shape
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((b, s, h_kv, d), jnp.bfloat16,
+                              sharding=sharding)
+    return q, kv, kv
+
+
+# (batch, seq, heads, kv_heads, head_dim): the shapes bench.py's GPT and
+# chip_smoke.py run, one grouped-query shape, the 4-chip ring, and one
+# sequence whose clipped block Mosaic refuses
+@pytest.mark.parametrize("kind,shape", [
+    pytest.param("flash", (8, 1024, 12, 12, 64), id="flash-8x1024"),
+    pytest.param("flash", (2, 4096, 12, 12, 64), id="flash-2x4096"),
+    pytest.param("flash", (1, 8192, 12, 12, 64), id="flash-1x8192"),
+    pytest.param("flash", (1, 4096, 32, 8, 128), id="flash-gqa-32-8-128"),
+    pytest.param("ring", (1, 16384, 12, 12, 64), id="ring-sp4-16384"),
+    pytest.param("refused", (1, 100, 2, 2, 64), id="block-not-multiple-of-8"),
+])
+def test_main_path_kernel_compiles_for_v5e(kind, shape, compiled_kernel,
+                                           request):
+    if kind == "refused":
+        # named at trace time, before Mosaic: no device needed
+        with pytest.raises(ValueError, match="multiples of 8"):
+            jax.eval_shape(
+                lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+                *_qkv(shape, None))
+        return
+    devices = request.getfixturevalue("v5e_devices")
+    if kind == "flash":
+        step = _loss(lambda q, k, v: fa.flash_attention(q, k, v,
+                                                        causal=True))
+        args = _qkv(shape, SingleDeviceSharding(devices[0]))
+    else:
+        mesh = Mesh(np.array(devices), ("sp",))
+        step = _loss(lambda q, k, v: ring_attention(
+            q, k, v, mesh=mesh, causal=True, use_flash=True))
+        args = _qkv(shape, NamedSharding(mesh, P(None, "sp", None, None)))
+    text = step.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    if kind == "ring":
+        assert "collective-permute" in text

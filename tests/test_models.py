@@ -411,11 +411,11 @@ def test_gpt_use_flash_auto_resolves_by_sequence_length(monkeypatch):
         return real(*a, **k)
 
     monkeypatch.setattr(fa, "flash_attention", spy)
-    # "auto" upgrades only on a real TPU backend (off-TPU the kernel
-    # would run in interpret mode); fake the backend for the resolver
-    # and keep the kernel itself in interpret mode via the env knob
+    # "auto" upgrades only on a TPU backend (on the CPU the kernel runs
+    # in interpret mode); fake the backend for the resolver and keep the
+    # kernel itself interpreted, both steered from here
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setenv("HVT_FLASH_INTERPRET", "1")
+    monkeypatch.setattr(fa, "_interpret", lambda: True)
     # resolver sanity incl. the boundary
     assert tr._resolve_flash("auto", 2048) is False
     assert tr._resolve_flash("auto", 2049) is True

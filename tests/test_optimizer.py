@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvt
@@ -220,3 +221,23 @@ def test_allreduce_gradients_no_axis_is_local():
     g = {"w": jnp.ones((2, 2))}
     out = jax.jit(lambda g: allreduce_gradients(g, axis_name=None))(g)
     np.testing.assert_allclose(np.asarray(out["w"]), 1.0)
+
+
+def test_allreduce_gradients_raises_when_vma_probe_cannot_answer(
+        world_mesh, monkeypatch):
+    # ROADMAP D7: if a JAX release moves the varying-axes typing, the probe
+    # must fail the step. Answering "not reduced" instead would psum every
+    # replicated parameter's gradient a second time, silently.
+    from horovod_tpu.jax import allreduce_gradients
+
+    monkeypatch.setattr(jax, "typeof", lambda x: object())
+
+    def per_shard(p, x):
+        g = jax.grad(lambda p: jnp.mean((p * x[0]) ** 2))(p)
+        return allreduce_gradients(g, axis_name=WORLD_AXIS)
+
+    f = jax.jit(jax.shard_map(per_shard, mesh=world_mesh,
+                              in_specs=(P(), P(WORLD_AXIS)),
+                              out_specs=P()))
+    with pytest.raises(AttributeError, match="vma"):
+        f(jnp.asarray(2.0), jnp.ones((N, 4), jnp.float32))

@@ -86,6 +86,29 @@ def test_jax_backend_env():
     assert "HVT_MASTER_ADDR" not in env
 
 
+def test_engine_slots_sharing_a_host_run_on_the_cpu():
+    # a chip belongs to one process and nothing assigns chips to slots:
+    # chip hosts export JAX_PLATFORMS themselves, so the pin overrides it
+    chip_host = {"JAX_PLATFORMS": "tpu,cpu"}
+    engine = parse_args(["-np", "2", "python", "x.py"])
+    shared = get_host_assignments([HostInfo("localhost", 2)], 2)
+    assert slot_env(chip_host, shared[1], engine,
+                    "127.0.0.1")["JAX_PLATFORMS"] == "cpu"
+    alone = get_host_assignments(
+        [HostInfo("localhost", 1), HostInfo("farhost", 1)], 2)
+    assert slot_env(chip_host, alone[0], engine,
+                    "127.0.0.1")["JAX_PLATFORMS"] == "tpu,cpu"
+    # --backend jax is one process per chip host: its platform stands
+    jax_mode = parse_args(["-np", "2", "--backend", "jax", "python",
+                           "x.py"])
+    assert slot_env(chip_host, shared[0], jax_mode,
+                    "127.0.0.1")["JAX_PLATFORMS"] == "tpu,cpu"
+    # and the choice reaches remote slots through the ssh command
+    cmds = build_commands(engine, get_host_assignments(
+        [HostInfo("farhost", 2)], 2), "farhost")
+    assert "JAX_PLATFORMS=cpu" in " ".join(cmds[0][0])
+
+
 def test_rendezvous_server_roundtrip():
     from horovod_tpu.runner.http_server import RendezvousServer
 

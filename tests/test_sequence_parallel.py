@@ -120,12 +120,10 @@ def test_ring_gqa_ppermute_payload_is_small_kv():
                                     causal=True)
 
     mesh = make_parallel_mesh(sp=8)
-    from horovod_tpu.parallel.sequence import _shard_map
-
     spec = P(None, "sp", None, None)
-    wrapped = _shard_map(shard_fn, mesh=mesh,
-                         in_specs=(spec,) * 3, out_specs=spec,
-                         check_vma=False)
+    wrapped = jax.shard_map(shard_fn, mesh=mesh,
+                            in_specs=(spec,) * 3, out_specs=spec,
+                            check_vma=False)
     q = jnp.zeros((b, s_shard * 8, h, d), jnp.float32)
     k = jnp.zeros((b, s_shard * 8, h_kv, d), jnp.float32)
     jaxpr = jax.make_jaxpr(wrapped)(q, k, k)
