@@ -24,6 +24,8 @@ from __future__ import annotations
 import os
 from typing import Any, Optional
 
+import jax
+
 
 def _orbax():
     try:
@@ -63,12 +65,14 @@ class CheckpointManager:
     def save(self, step: int, state: Any, force: bool = False) -> bool:
         """Queue an async save of the state pytree at ``step``."""
         ocp = _orbax()
-        return self._mgr.save(step, args=ocp.args.StandardSave(state),
-                              force=force)
+        with jax.profiler.TraceAnnotation("hvt_checkpoint/save"):
+            return self._mgr.save(step, args=ocp.args.StandardSave(state),
+                                  force=force)
 
     def wait(self):
         """Block until queued async saves are durable."""
-        self._mgr.wait_until_finished()
+        with jax.profiler.TraceAnnotation("hvt_checkpoint/wait"):
+            self._mgr.wait_until_finished()
 
     def latest_step(self) -> Optional[int]:
         return self._mgr.latest_step()
@@ -81,9 +85,10 @@ class CheckpointManager:
         ocp = _orbax()
         args = ocp.args.StandardRestore(template) if template is not None \
             else ocp.args.StandardRestore()
-        state = self._mgr.restore(step, args=args)
-        if broadcast:
-            state = _broadcast_if_distributed(state, root_rank)
+        with jax.profiler.TraceAnnotation("hvt_checkpoint/restore"):
+            state = self._mgr.restore(step, args=args)
+            if broadcast:
+                state = _broadcast_if_distributed(state, root_rank)
         return state
 
     def restore_latest(self, template: Any = None, broadcast: bool = True,

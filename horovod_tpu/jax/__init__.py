@@ -31,6 +31,14 @@ import optax
 from horovod_tpu.ops import collective_ops as C
 from horovod_tpu.ops.compression import Compression
 
+# The package's contract with any reader of a device trace: inside a compiled
+# step, every operation of the gradient reduction (divides, casts, scaling,
+# the collectives) carries REDUCE_SCOPE in its op_name, and every operation
+# of the wrapped optimizer's update carries UPDATE_SCOPE. Trace-time metadata
+# only: no operation is added. chipbench/regions.py reads both.
+REDUCE_SCOPE = "hvt_reduce_gradients"
+UPDATE_SCOPE = "hvt_optimizer_update"
+
 
 def allreduce_gradients(grads, *, op=C.Average, axis_name=None,
                         compression=Compression.none,
@@ -66,6 +74,9 @@ def allreduce_gradients(grads, *, op=C.Average, axis_name=None,
         c, ctx = compression.compress(g)
         if axis_name is not None:
             if isinstance(c, jax.core.Tracer) and _already_reduced(c):
+                # autodiff's own psum is in the program all the same: an
+                # operator counting traced collectives must see it
+                C._count_traced("allreduce_already_reduced")
                 if op is C.Average:
                     c = c / jax.lax.axis_size(axis_name)
                 if prescale_factor != 1.0:
@@ -149,6 +160,7 @@ def DistributedGradientTransformation(
     else:
         _predivide_by_size = False
 
+    @jax.named_scope(REDUCE_SCOPE)
     def _reduce(grads):
         pre, post = prescale_factor, postscale_factor
         if _predivide_by_size:
@@ -175,13 +187,14 @@ def DistributedGradientTransformation(
                 out.append(leaf)
         return jax.tree.unflatten(treedef, out)
 
+    _inner_update = jax.named_scope(UPDATE_SCOPE)(optimizer.update)
+
     if backward_passes_per_step == 1:
         def init(params):
             return optimizer.init(params)
 
         def update(grads, state, params=None, **extra):
-            reduced = _reduce(grads)
-            return optimizer.update(reduced, state, params, **extra)
+            return _inner_update(_reduce(grads), state, params, **extra)
 
         return optax.GradientTransformation(init, update)
 
@@ -204,7 +217,7 @@ def DistributedGradientTransformation(
             if average_aggregated_gradients:
                 g = jax.tree.map(lambda x: x / n_steps, g)
             g = _reduce(g)
-            updates, new_inner = optimizer.update(g, inner_, params, **extra)
+            updates, new_inner = _inner_update(g, inner_, params, **extra)
             return updates, new_inner, jax.tree.map(jnp.zeros_like, acc_)
 
         def hold(operand):

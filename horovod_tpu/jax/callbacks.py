@@ -31,6 +31,7 @@ from __future__ import annotations
 import math
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 
@@ -48,28 +49,35 @@ class Callback:
         return None
 
 
+def _spanned(cb, hook, *args):
+    """``cb.hook(*args)`` inside a host span named after the callback's
+    class, on the profiler's clock (docs/timeline.md)."""
+    with jax.profiler.TraceAnnotation(f"hvt_callback/{type(cb).__name__}"):
+        return getattr(cb, hook)(*args)
+
+
 class CallbackList(Callback):
     def __init__(self, callbacks: List[Callback]):
         self.callbacks = list(callbacks)
 
     def on_train_begin(self, state):
         for cb in self.callbacks:
-            state = cb.on_train_begin(state)
+            state = _spanned(cb, "on_train_begin", state)
         return state
 
     def on_epoch_begin(self, epoch):
         for cb in self.callbacks:
-            cb.on_epoch_begin(epoch)
+            _spanned(cb, "on_epoch_begin", epoch)
 
     def on_epoch_end(self, epoch, metrics=None):
         for cb in self.callbacks:
-            metrics = cb.on_epoch_end(epoch, metrics)
+            metrics = _spanned(cb, "on_epoch_end", epoch, metrics)
         return metrics
 
     def learning_rate(self, step):
         lr = None
         for cb in self.callbacks:
-            v = cb.learning_rate(step)
+            v = _spanned(cb, "learning_rate", step)
             lr = v if v is not None else lr
         return lr
 

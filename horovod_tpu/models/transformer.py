@@ -227,7 +227,8 @@ class GPT(nn.Module):
             jnp.arange(tokens.shape[-1]), tokens.shape)
         emb = self.param("embedding", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), jnp.float32)
-        x = emb[tokens].astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = emb[tokens].astype(cfg.dtype)
         block = Block
         if cfg.remat:
             block = nn.remat(Block, static_argnums=())
@@ -236,7 +237,9 @@ class GPT(nn.Module):
         x = RMSNorm(name="ln_f")(x)
         if return_hidden:
             return x
-        logits = jnp.einsum("...ld,vd->...lv", x.astype(jnp.float32), emb)
+        with jax.named_scope("lm_head"):
+            logits = jnp.einsum("...ld,vd->...lv", x.astype(jnp.float32),
+                                emb)
         return logits
 
 

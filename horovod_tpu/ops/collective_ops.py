@@ -127,7 +127,10 @@ def _timed_eager(op_name: str, process_set, tensor, fn):
         _payload_bytes(tensor))
     t0 = time.monotonic()
     try:
-        return fn()
+        # a host span on the profiler's clock, the one a device trace
+        # shares (docs/timeline.md); costs nothing while no trace runs
+        with jax.profiler.TraceAnnotation(f"hvt_eager/{op_name}"):
+            return fn()
     finally:
         hist.labels(op=op_name, process_set=ps).observe(
             time.monotonic() - t0)

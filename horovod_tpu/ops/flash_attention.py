@@ -365,6 +365,7 @@ def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype):
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
         interpret=_interpret(),
+        name="hvt_flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -404,6 +405,7 @@ def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name="hvt_flash_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid (b, h, ki, ti) — Q/dO/lse/delta tiles stream past
@@ -436,6 +438,7 @@ def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=_interpret(),
+        name="hvt_flash_dkv",
     )(k, v, q, do, lse, delta)
     if group > 1:
         h_kv = h // group

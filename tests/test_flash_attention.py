@@ -292,3 +292,17 @@ def test_flash_gqa_rejects_indivisible_heads():
     kv = jnp.zeros((1, 128, 3, 8), jnp.float32)
     with pytest.raises(ValueError, match="divisible"):
         flash_attention(q, kv, kv)
+
+
+@pytest.mark.parametrize("kernel, in_backward", [
+    ("hvt_flash_fwd", False), ("hvt_flash_dq", True),
+    ("hvt_flash_dkv", True)])
+def test_kernels_carry_their_names(kernel, in_backward):
+    # a device trace tells the three Pallas calls apart by these names
+    q, k, v = _qkv(s=64)
+    attend = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=32, block_k=32).sum()
+    forward = str(jax.make_jaxpr(attend)(q, k, v))
+    backward = str(jax.make_jaxpr(jax.grad(attend, (0, 1, 2)))(q, k, v))
+    assert (kernel in forward) == (not in_backward)
+    assert kernel in backward

@@ -602,3 +602,32 @@ def test_conv0_space_to_depth_odd_input_raises_clear_error():
     with pytest.raises(ValueError, match="conv0_space_to_depth"):
         stem.init(jax.random.PRNGKey(0),
                   jnp.zeros((1, 32, 31, 3), jnp.float32))
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_gpt_gradient_program_names_its_regions(remat):
+    # chipbench/regions.py splits a step's time by these names: flax names
+    # the blocks, GPT names what flax does not (the embedding lookup, the
+    # vocabulary projection), and nn.remat marks what runs again.
+    import re
+
+    from horovod_tpu.models import GPT, GPTConfig
+
+    cfg = GPTConfig(vocab_size=64, d_model=32, n_layers=2, n_heads=4,
+                    d_ff=64, dtype=jnp.float32, remat=remat,
+                    use_flash=False)
+    model = GPT(cfg)
+    tokens = jnp.zeros((2, 8), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+    grad = jax.jit(jax.grad(
+        lambda p: model.apply({"params": p}, tokens).sum()))
+    names = set(re.findall(r'op_name="([^"]*)"',
+                           grad.lower(params).compile().as_text()))
+
+    def holding(*parts):
+        return [n for n in names if all(p in n for p in parts)]
+
+    for scope in ("/embed/", "/lm_head/"):
+        assert holding("jvp(", scope) and holding("transpose(jvp(", scope)
+    assert holding("transpose(jvp(", "/block_1/mlp/")
+    assert bool(holding("rematted_computation", "/block_1/")) == remat

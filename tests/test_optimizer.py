@@ -241,3 +241,80 @@ def test_allreduce_gradients_raises_when_vma_probe_cannot_answer(
                               out_specs=P()))
     with pytest.raises(AttributeError, match="vma"):
         f(jnp.asarray(2.0), jnp.ones((N, 4), jnp.float32))
+
+
+def _op_names(lowered):
+    import re
+
+    return set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
+
+
+@pytest.mark.parametrize("passes", [1, 2])
+def test_compiled_step_names_the_reduction_and_the_update(
+        world_mesh, passes, monkeypatch):
+    # The two scopes are the package's contract with a trace reader
+    # (chipbench/regions.py): every operation of the reduction and of the
+    # wrapped optimizer carries its scope in the compiled step, also on
+    # the accumulating path's emit branch.
+    import contextlib
+
+    from horovod_tpu.jax import REDUCE_SCOPE, UPDATE_SCOPE
+
+    def lowered():
+        tx = hvt.DistributedOptimizer(
+            optax.adamw(1e-3), axis_name=WORLD_AXIS,
+            backward_passes_per_step=passes)
+
+        def per_shard(p, s, x):
+            loss, g = jax.value_and_grad(
+                lambda p: jnp.mean((x @ p["w"]) ** 2))(p)
+            updates, s = tx.update(g, s, p)
+            return (optax.apply_updates(p, updates), s,
+                    jax.lax.pmean(loss, WORLD_AXIS))
+
+        params = {"w": jnp.ones((4, 3))}
+        return jax.jit(jax.shard_map(
+            per_shard, mesh=world_mesh, in_specs=(P(), P(), P(WORLD_AXIS)),
+            out_specs=P(), check_vma=False)).lower(
+                params, tx.init(params), jnp.ones((N, 4), jnp.float32))
+
+    step = lowered()
+    names = _op_names(step)
+    reduce = [n for n in names if REDUCE_SCOPE in n]
+    update = [n for n in names if UPDATE_SCOPE in n]
+    assert reduce and update, sorted(names)
+    # neither scope is inside the other, nor inside autodiff's regions
+    assert not [n for n in reduce + update if "jvp(" in n]
+    assert not [n for n in reduce if UPDATE_SCOPE in n]
+    # the scopes add names, not operations
+    monkeypatch.setattr(jax, "named_scope",
+                        contextlib.contextmanager(lambda name: (yield)))
+    assert lowered().as_text() == step.as_text()
+
+
+def test_already_reduced_gradients_are_counted_as_traced_collectives(
+        world_mesh):
+    # Under shard_map(check_vma=True) autodiff has psummed a replicated
+    # parameter's gradient: C.allreduce is never called, the device still
+    # runs an all-reduce, and hvt_traced_collectives_total must not read 0.
+    from horovod_tpu import metrics
+    from horovod_tpu.jax import allreduce_gradients
+    from horovod_tpu.ops import collective_ops
+
+    def per_shard(p, x):
+        g = jax.grad(lambda p: jnp.mean((p["a"] * x[0] + p["b"]) ** 2))(p)
+        return allreduce_gradients(g, axis_name=WORLD_AXIS)
+
+    def count(op):
+        return metrics.registry().get(
+            "hvt_traced_collectives_total").labels(op=op).value
+
+    collective_ops._metric_handles()    # makes the counter if nothing has
+    before = count("allreduce_already_reduced"), count("allreduce")
+    f = jax.jit(jax.shard_map(per_shard, mesh=world_mesh,
+                              in_specs=(P(), P(WORLD_AXIS)), out_specs=P()))
+    text = f.lower({"a": jnp.asarray(2.0), "b": jnp.asarray(0.5)},
+                   jnp.ones((N, 4), jnp.float32)).as_text()
+    assert "all_reduce" in text or "all-reduce" in text
+    assert count("allreduce_already_reduced") == before[0] + 2   # a leaf each
+    assert count("allreduce") == before[1]

@@ -1,9 +1,11 @@
 """Timeline tests (reference ``test/parallel/test_timeline.py`` runs a job
 with HOROVOD_TIMELINE and validates the JSON)."""
 
+import glob
 import json
 
 import numpy as np
+import pytest
 
 import horovod_tpu as hvt
 from horovod_tpu.utils import timeline
@@ -40,8 +42,6 @@ def test_timeline_arms_xla_profiler_session(tmp_path):
     """SURVEY §5.1: start_timeline() must also open an XLA/PJRT profiler
     session so compiled-path device activity is captured alongside the
     engine control-plane trace — one command, both views."""
-    import glob
-
     import jax
     import jax.numpy as jnp
 
@@ -159,3 +159,44 @@ def test_engine_timeline_chrome_trace(tmp_path):
         e_ = sum(1 for e in events if e.get("tid") == tid
                  and e["ph"] == "E")
         assert b == e_, f"unbalanced B/E in lane {tid}"
+
+
+# ---- host spans on the profiler's clock (docs/timeline.md): what the
+# package does between steps, beside the device's lines in one trace
+
+@pytest.fixture(scope="module")
+def host_span_names(tmp_path_factory):
+    """Names of the host plane's events in one ``jax.profiler`` trace
+    around an eager collective, a callback dispatch and a checkpoint."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from horovod_tpu import checkpoint
+
+    folder = tmp_path_factory.mktemp("host_spans")
+    state = {"w": np.ones(3, np.float32)}
+    manager = checkpoint.CheckpointManager(str(folder / "ckpt"),
+                                           async_save=False)
+    jax.profiler.start_trace(str(folder / "trace"))
+    try:
+        hvt.allreduce(np.ones(4, np.float32), name="host_span_probe")
+        hvt.jax.CallbackList([hvt.jax.MetricAverageCallback()]).on_epoch_end(
+            0, {"loss": 1.0})
+        manager.save(1, state)
+        manager.wait()
+        manager.restore(1, template=state)
+    finally:
+        jax.profiler.stop_trace()
+        manager.close()
+    written, = glob.glob(str(folder / "trace" / "**" / "*.xplane.pb"),
+                         recursive=True)
+    data = ProfileData.from_file(written)
+    host = next(p for p in data.planes if p.name == "/host:CPU")
+    return {e.name for line in host.lines for e in line.events}
+
+
+@pytest.mark.parametrize("span", [
+    "hvt_eager/allreduce", "hvt_callback/MetricAverageCallback",
+    "hvt_checkpoint/save", "hvt_checkpoint/wait", "hvt_checkpoint/restore"])
+def test_host_spans_are_in_the_profilers_trace(host_span_names, span):
+    assert span in host_span_names
