@@ -1,0 +1,190 @@
+#!/usr/bin/env python
+"""flash_kernels.py — the three flash-attention kernels alone, on the chip.
+
+    chiprun -- python benchmarks/flash_kernels.py \
+        --shape 2,4096,20,20,64 --blocks derived,128x128,512x1024
+
+For every score tile asked for it jits one forward + ``vjp`` of
+``flash_attention`` at ``--shape`` (batch, seq, heads, kv_heads, head_dim;
+bf16, causal unless ``--no-causal``), runs it ``--iters`` times inside one
+profiler trace and prints one JSON line: the device milliseconds a call of
+``hvt_flash_fwd``, ``hvt_flash_dq`` and ``hvt_flash_dkv`` (median over the
+iterations, read from the trace by the kernels' names), the wall-clock
+milliseconds of the whole call, and how far its results are from the first
+tile's (relative L2). ``--einsum`` adds the einsum attention of
+``models/transformer.py`` at the same shape, wall clock only: the evidence
+for where ``FLASH_AUTO_THRESHOLD`` belongs. ``--source FILE`` times another
+copy of ``ops/flash_attention.py`` (the parent commit's) instead.
+
+A microbenchmark, not the yardstick: the cell that decides is
+``gpt2l-s4096`` of ``BENCHMARK.json``. It refuses to run without a TPU.
+"""
+
+import argparse
+import glob
+import importlib.util
+import json
+import os
+import statistics
+import sys
+import tempfile
+import time
+
+KERNELS = ("hvt_flash_fwd", "hvt_flash_dq", "hvt_flash_dkv")
+
+
+def load_flash(source):
+    if source is None:
+        from horovod_tpu.ops import flash_attention
+        return flash_attention
+    spec = importlib.util.spec_from_file_location("flash_under_test", source)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def make_inputs(shape):
+    import jax.numpy as jnp
+    import numpy as np
+
+    b, s, h, h_kv, d = shape
+    rng = np.random.RandomState(0)
+    q, do = (jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.bfloat16)
+             for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(size=(b, s, h_kv, d)), jnp.bfloat16)
+            for _ in range(2))
+    return q, k, v, do
+
+
+def einsum_attention(q, k, v, causal):
+    """The attention ``models/transformer.py`` runs under the crossover."""
+    import jax
+    import jax.numpy as jnp
+
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores / q.shape[-1] ** 0.5
+    if causal:
+        pos = jnp.arange(q.shape[1])
+        scores = jnp.where(pos[None, :] <= pos[:, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def call_and_vjp(attend):
+    import jax
+
+    def run(q, k, v, do):
+        o, vjp = jax.vjp(attend, q, k, v)
+        return (o, *vjp(do))
+
+    return jax.jit(run)
+
+
+def kernel_durations(trace_dir):
+    """{kernel: [ms, ...]} of chip 0's events, in the order they ran."""
+    from jax.profiler import ProfileData
+
+    found = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    data = ProfileData.from_file(max(found, key=os.path.getmtime))
+    out = {name: [] for name in KERNELS}
+    for plane in data.planes:
+        if plane.name != "/device:TPU:0":
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for e in sorted(line.events, key=lambda e: e.start_ns):
+                for name in KERNELS:
+                    # %hvt_flash_fwd.3, %transpose_jvp_hvt_flash_dkv__.1
+                    if name in e.name.split(" = ")[0]:
+                        out[name].append(e.duration_ns / 1e6)
+    return out
+
+
+def measure(flash, shape, tiles, causal=True, iters=5, einsum=False):
+    """One dict a tile (and one for the einsum path), as the module
+    docstring describes."""
+    import jax
+    from chip_smoke import rel_l2
+
+    args = make_inputs(shape)
+    runs = []
+    for tile in tiles:
+        blocks = ({} if tile == "derived" else
+                  dict(zip(("block_q", "block_k"), tile)))
+        runs.append((tile, call_and_vjp(
+            lambda q, k, v, blocks=blocks: flash.flash_attention(
+                q, k, v, causal=causal, **blocks))))
+    if einsum:
+        runs.append(("einsum", call_and_vjp(
+            lambda q, k, v: einsum_attention(q, k, v, causal))))
+    first, lines = None, []
+    for tile, fn in runs:                     # compile, warm up, compare
+        line = {"tile": tile, "shape": list(shape), "causal": causal}
+        lines.append(line)
+        try:
+            got = jax.device_get(fn(*args))
+        except Exception as e:      # a tile the compiler refuses: say so
+            line["refused"] = str(e).split("\n")[0][-300:]
+            continue
+        first = got if first is None else first
+        line["rel_l2_vs_first"] = rel_l2(got, first)
+    ran = [(line, fn) for line, (_, fn) in zip(lines, runs)
+           if "refused" not in line]
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        for line, fn in ran:
+            t0 = time.perf_counter()
+            for _ in range(iters):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            line["wall_ms"] = (time.perf_counter() - t0) * 1e3 / iters
+        jax.profiler.stop_trace()
+        spans = kernel_durations(trace_dir)
+    flash = [line for line, _ in ran if line["tile"] != "einsum"]
+    for name, ms in spans.items():
+        if len(ms) != len(flash) * iters:
+            raise SystemExit(f"{name}: {len(ms)} events in the trace, "
+                             f"expected {len(flash)} x {iters}")
+        for i, line in enumerate(flash):
+            line[name.removeprefix("hvt_flash_") + "_ms"] = (
+                statistics.median(ms[i * iters:(i + 1) * iters]))
+    return lines
+
+
+def parse_tile(text):
+    return text if text == "derived" else tuple(
+        int(x) for x in text.split("x"))
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--shape", default="2,4096,20,20,64")
+    ap.add_argument("--blocks", default="derived")
+    ap.add_argument("--no-causal", action="store_true")
+    ap.add_argument("--iters", type=int, default=5)
+    ap.add_argument("--einsum", action="store_true")
+    ap.add_argument("--source")
+    a = ap.parse_args(argv)
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import jax
+
+    if jax.default_backend() != "tpu":
+        raise SystemExit("flash_kernels.py times kernels on a TPU; found "
+                         f"{jax.default_backend()}")
+    shape = tuple(int(x) for x in a.shape.split(","))
+    tiles = [parse_tile(t) for t in a.blocks.split(",")]
+    for line in measure(load_flash(a.source), shape, tiles,
+                        causal=not a.no_causal, iters=a.iters,
+                        einsum=a.einsum):
+        line["device"] = jax.devices()[0].device_kind
+        print(json.dumps(line), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -416,13 +416,15 @@ def flash_kernel_vs_f32(shape):
 def phase_gpt(devices):
     """bench.py's GPT job on the einsum path and on the compiled flash
     kernel, the two compared, and the kernel alone at the shape whose dK/dV
-    tile is capped."""
+    tile is capped and at the benchmark's long-sequence cell's."""
     return {
         "model": "gpt 12x768 vocab 32768",
         "einsum_1024": gpt_train(devices, 1024, 8, use_flash=False),
         "flash_4096": gpt_train(devices, 4096, 2, use_flash=True),
         "flash_vs_einsum_4096": gpt_flash_vs_einsum(devices, 4096),
         "kernel_8192": flash_kernel_vs_f32((1, 8192, 12, 12, 64)),
+        # the benchmark cell gpt2l-s4096's own attention call
+        "kernel_gpt2l_s4096": flash_kernel_vs_f32((2, 4096, 20, 20, 64)),
     }
 
 

@@ -5,7 +5,10 @@ its own; materializing it is O(S²) HBM traffic, which caps MXU utilization
 at long context. This kernel keeps the [block_q × block_k] score tile in
 VMEM, maintains online-softmax running (max, sum) statistics, and writes
 only the O(S·D) output — the standard FlashAttention-2 decomposition, laid
-out for the MXU (128×128 tiles, fp32 accumulation, bf16 operands).
+out for the MXU (fp32 accumulation and statistics, operands as they
+arrive). The score tile is derived from the shape (``_derive_tile``:
+hundreds of rows by hundreds of columns, so that one pass of the inner
+loop is long enough to hide its own overhead) unless the caller names one.
 
 Backward pass recomputes score tiles (FLOPs-for-HBM trade, the same choice
 ``jax.checkpoint`` makes) in two kernels: one gridded over Q blocks (dQ),
@@ -21,6 +24,8 @@ kernel is compiled and a compiler refusal propagates.
 from __future__ import annotations
 
 import functools
+import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -69,20 +74,54 @@ def _interpret() -> bool:
 # grid's sequential LAST axis in large VMEM TILES (so per-kernel VMEM is
 # O(tile), never O(seq) — a full-sequence-resident design exceeds the
 # 16 MB scoped-VMEM limit at seq 8192), while INSIDE the kernel a
-# fori_loop walks 128-wide sub-blocks of the tile with fine-grained
-# causal skipping (one block per grid step pays per-step pipeline
-# overhead plus DMA of fully-masked blocks). Online-softmax statistics
-# live in VMEM scratch across the tile axis.
+# fori_loop walks [block_q, block_k] score sub-blocks of the tile with
+# causal skipping at that granularity (one block per grid step pays
+# per-step pipeline overhead plus DMA of fully-masked blocks). A pass of
+# that loop costs about half a microsecond before it does any arithmetic
+# (v5e, PERF.md section 6, PR 25), so the sub-block is as large as VMEM
+# allows (``_derive_tile``), not one MXU pass. Every sub-block of a causal
+# call is masked, the ones wholly below the diagonal too: a second,
+# unmasked loop for those measured 3 to 5% slower than the mask it saves.
+# Online-softmax statistics live in VMEM scratch across the tile axis.
+
+_NT = (((1,), (1,)), ((), ()))   # [m, d] x [n, d] -> [m, n]
+_NN = (((1,), (0,)), ((), ()))   # [m, n] x [n, d] -> [m, d]
+_TN = (((0,), (0,)), ((), ()))   # [n, m] x [n, d] -> [m, d]
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _scaled(x, scale):
+    """``(x', rest)`` with ``x' @ y * rest == x @ y * scale``: a power of
+    two only moves exponents, so it is folded into the [block, D] operand
+    once a grid step, exactly; any other scale stays on the f32 scores."""
+    if scale > 0 and math.frexp(scale)[0] == 0.5:
+        return x * scale, None
+    return x, scale
+
+
+def _visible(q0, k0, shape):
+    """Causal mask of a [queries, keys] score sub-block whose first query
+    position is ``q0`` and first key position ``k0``."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return k_pos <= q_pos
 
 
 def _causal_n_eff(qi, block_q, ti, tile, block_k, n_sub):
     """Number of k sub-blocks of this tile a causal Q block attends to
-    (sub-blocks entirely above the diagonal are skipped, same 128-block
-    granularity as the resident design). Shared by the fwd and dQ
-    kernels; the dkv kernel uses the dual (`start`) form."""
+    (sub-blocks entirely above the diagonal are skipped). Shared by the
+    fwd and dQ kernels; the dkv kernel uses the dual (`start`) form."""
     return jnp.clip(
         ((qi + 1) * block_q - ti * tile + block_k - 1) // block_k,
         0, n_sub)
+
+
+def _sub_block(j, block):
+    return pl.ds(pl.multiple_of(j * block, block), block)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
@@ -92,8 +131,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     qi = pl.program_id(2)
     ti = pl.program_id(3)
     n_t = pl.num_programs(3)
-    q = q_ref[0, 0]                                   # [block_q, D]
-    q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
 
     @pl.when(ti == 0)
     def _init():
@@ -102,35 +139,35 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     def _tile():
+        q, rest = _scaled(q_ref[0, 0], scale)         # [block_q, D]
+
         def body(j, carry):
+            # the statistics stay [block_q, 1] columns throughout
             acc, m, l = carry
-            k = k_ref[0, 0, pl.ds(j * block_k, block_k), :]
-            v = v_ref[0, 0, pl.ds(j * block_k, block_k), :]
-            sc = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [bq, bk]
+            k = k_ref[0, 0, _sub_block(j, block_k), :]
+            v = v_ref[0, 0, _sub_block(j, block_k), :]
+            sc = _dot(q, k, _NT)                      # [bq, bk]
+            if rest is not None:
+                sc = sc * rest
             if causal:
-                k_pos = (ti * tile + j * block_k
-                         + jax.lax.iota(jnp.int32, block_k))
-                sc = jnp.where(k_pos[None, :] <= q_pos[:, None], sc,
-                               _NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
-            p = jnp.exp(sc - m_new[:, None])
+                sc = jnp.where(
+                    _visible(qi * block_q, ti * tile + j * block_k,
+                             sc.shape), sc, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+            p = jnp.exp(sc - m_new)
             corr = jnp.exp(m - m_new)
-            l_new = l * corr + jnp.sum(p, axis=-1)
-            acc_new = acc * corr[:, None] + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_new = acc * corr + _dot(p.astype(v.dtype), v, _NN)
             return acc_new, m_new, l_new
 
         n_sub = tile // block_k
         n_eff = (_causal_n_eff(qi, block_q, ti, tile, block_k, n_sub)
                  if causal else n_sub)
         acc, m, l = jax.lax.fori_loop(
-            0, n_eff, body, (acc_ref[...], m_ref[:, 0], l_ref[:, 0]))
+            0, n_eff, body, (acc_ref[...], m_ref[:, :1], l_ref[:, :1]))
         acc_ref[...] = acc
-        m_ref[...] = jnp.broadcast_to(m[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l[:, None], l_ref.shape)
+        m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l, l_ref.shape)
 
     if causal:
         # tiles entirely above the diagonal still stream past (the
@@ -141,10 +178,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
 
     @pl.when(ti == n_t - 1)
     def _finalize():
-        m = m_ref[:, 0]
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, :, 0] = m + jnp.log(l)
+        l = jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -154,36 +190,31 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     qi = pl.program_id(2)
     ti = pl.program_id(3)     # K/V tiles stream
     n_t = pl.num_programs(3)
-    q = q_ref[0, 0]
-    q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0, :, 0]
-    delta = delta_ref[0, 0, :, 0]
 
     @pl.when(ti == 0)
     def _init():
         dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
 
     def _tile():
+        q, rest = _scaled(q_ref[0, 0], scale)
+        do = do_ref[0, 0].astype(jnp.float32)
+        lse = lse_ref[0, 0]                           # [block_q, 1]
+        delta = delta_ref[0, 0]
+
         def body(j, dq):
-            k = k_ref[0, 0, pl.ds(j * block_k, block_k), :]
-            v = v_ref[0, 0, pl.ds(j * block_k, block_k), :]
-            sc = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+            k = k_ref[0, 0, _sub_block(j, block_k), :]
+            v = v_ref[0, 0, _sub_block(j, block_k), :]
+            sc = _dot(q, k, _NT)
+            if rest is not None:
+                sc = sc * rest
             if causal:
-                k_pos = (ti * tile + j * block_k
-                         + jax.lax.iota(jnp.int32, block_k))
-                sc = jnp.where(k_pos[None, :] <= q_pos[:, None], sc,
-                               _NEG_INF)
-            p = jnp.exp(sc - lse[:, None])
-            dp = jax.lax.dot_general(
-                do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = p * (dp - delta[:, None])
-            return dq + jax.lax.dot_general(
-                ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+                sc = jnp.where(
+                    _visible(qi * block_q, ti * tile + j * block_k,
+                             sc.shape), sc, _NEG_INF)
+            p = jnp.exp(sc - lse)
+            dp = _dot(do, v.astype(jnp.float32), _NT)
+            ds = p * (dp - delta)
+            return dq + _dot(ds, k.astype(jnp.float32), _NN)
 
         n_sub = tile // block_k
         n_eff = (_causal_n_eff(qi, block_q, ti, tile, block_k, n_sub)
@@ -198,7 +229,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(ti == n_t - 1)
     def _finalize():
-        dq_ref[0, 0] = dq_acc_ref[...].astype(dq_ref.dtype)
+        # dS K carries the scale once, here, not once a sub-block
+        dq_ref[0, 0] = (dq_acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
@@ -209,9 +241,6 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     ki = pl.program_id(2)
     ti = pl.program_id(3)     # Q/dO/lse/delta tiles stream
     n_t = pl.num_programs(3)
-    k = k_ref[0, 0]                                   # [block_k, D]
-    v = v_ref[0, 0]
-    k_pos = ki * block_k + jax.lax.iota(jnp.int32, block_k)
 
     @pl.when(ti == 0)
     def _init():
@@ -219,32 +248,28 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
     def _tile():
+        k, rest = _scaled(k_ref[0, 0], scale)         # [block_k, D]
+        v = v_ref[0, 0].astype(jnp.float32)
+
         def body(i, carry):
             dk, dv = carry
-            q = q_ref[0, 0, pl.ds(i * block_q, block_q), :]
-            do = do_ref[0, 0, pl.ds(i * block_q, block_q),
-                        :].astype(jnp.float32)
-            lse = lse_ref[0, 0, pl.ds(i * block_q, block_q), 0]
-            delta = delta_ref[0, 0, pl.ds(i * block_q, block_q), 0]
-            sc = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+            rows = _sub_block(i, block_q)
+            q = q_ref[0, 0, rows, :]
+            do = do_ref[0, 0, rows, :].astype(jnp.float32)
+            lse = lse_ref[0, 0, rows, :]              # [block_q, 1]
+            delta = delta_ref[0, 0, rows, :]
+            sc = _dot(q, k, _NT)                      # [bq, bk]
+            if rest is not None:
+                sc = sc * rest
             if causal:
-                q_pos = (ti * tile + i * block_q
-                         + jax.lax.iota(jnp.int32, block_q))
-                sc = jnp.where(k_pos[None, :] <= q_pos[:, None], sc,
-                               _NEG_INF)
-            p = jnp.exp(sc - lse[:, None])         # [bq, bk]
-            dv_new = dv + jax.lax.dot_general(
-                p, do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(
-                do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = p * (dp - delta[:, None])
-            dk_new = dk + jax.lax.dot_general(
-                ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+                sc = jnp.where(
+                    _visible(ti * tile + i * block_q, ki * block_k,
+                             sc.shape), sc, _NEG_INF)
+            p = jnp.exp(sc - lse)
+            dv_new = dv + _dot(p, do, _TN)
+            dp = _dot(do, v, _NT)
+            ds = p * (dp - delta)
+            dk_new = dk + _dot(ds, q.astype(jnp.float32), _TN)
             return dk_new, dv_new
 
         n_sub = tile // block_q
@@ -267,7 +292,7 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(ti == n_t - 1)
     def _finalize():
-        dk_ref[0, 0] = dk_acc_ref[...].astype(dk_ref.dtype)
+        dk_ref[0, 0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
@@ -276,6 +301,109 @@ def _blocks(s, requested):
     while s % b:
         b //= 2
     return max(b, 1)
+
+
+# The score tile each kernel prefers when the caller names none, as
+# (block_q, block_k). Measured on a v5e at 2 x 20 heads x 4096 x 64 and
+# 1 x 32/8 heads x 4096 x 128, bf16, causal and not (PERF.md section 6,
+# PR 25): the forward wants a wide key block (its per-row bookkeeping is
+# paid once a sub-block), dQ a square one, dK/dV long query sub-blocks
+# against the K block it keeps; larger tiles gained under 2% or were
+# refused by the compiler.
+_PREFERRED_TILE = {"fwd": (512, 1024), "dq": (512, 512),
+                   "dkv": (1024, 512)}
+
+# The VMEM Mosaic scopes to one kernel by default.
+_SCOPED_VMEM = 16 * 2 ** 20
+
+
+def _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile):
+    """Estimate of the VMEM one grid step of ``kernel`` holds: one f32
+    [block_q, block_k] score tile (the compiler strip-mines the rest of
+    the softmax), the streamed sequence tiles and the resident blocks,
+    both double-buffered, and the f32 accumulators. A position of an
+    operand takes whole 128-lane rows whatever ``d`` is, and so does a
+    position of a [.., 1] statistic. Checked against the compiler's own
+    answers at 2 x 20 x 4096 x 64: dK/dV at 512 x 1024 is refused at 17.7
+    MiB (estimate 17.0) and accepted at 1024 x 512 (estimate 15.5)."""
+    lanes = -(-d // _LANES) * _LANES
+    row, acc, stat = lanes * itemsize, lanes * 4, _LANES * 4
+    score = 4 * block_q * block_k
+    if kernel == "fwd":     # K V stream; q o lse blocks; acc m l scratch
+        return (score + 2 * 2 * tile * row
+                + block_q * (2 * 2 * row + 2 * stat + acc + 2 * stat))
+    if kernel == "dq":      # K V stream; q do dq lse delta blocks; acc
+        return (score + 2 * 2 * tile * row
+                + block_q * (3 * 2 * row + 2 * 2 * stat + acc))
+    # dkv: Q dO lse delta stream; k v dk dv blocks; two accumulators
+    return (score + 2 * 2 * tile * (row + stat)
+            + block_k * (4 * 2 * row + 2 * acc))
+
+
+def _compiler_params(kernel, block_q, block_k, d, itemsize, tile):
+    """The default scope unless the estimate comes within a quarter of
+    it; then half as much again as the estimate, as the kernel's own
+    ``vmem_limit_bytes``. Around a call XLA keeps buffers of its own in
+    VMEM, and the compiler then asked for up to 3 MiB more than for the
+    kernel alone (dK/dV at 1024 x 512 inside gpt2-large at 1 x 8192: 18.5
+    MiB against an estimate of 15.5), where the default refuses it."""
+    need = _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile)
+    if need + need // 4 <= _SCOPED_VMEM:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=need + need // 2)
+
+
+def _derive_tile(kernel, s, d, itemsize):
+    """The score tile of ``kernel`` ("fwd", "dq", "dkv") for a sequence of
+    ``s`` positions: the largest (block_q, block_k) — multiples of 128
+    that divide ``s``, one dividing the other so the streamed tile is
+    unaffected, neither above the kernel's preferred size — whose buffers
+    fit ``_SCOPED_VMEM``; of equal areas the wider key block. A sequence
+    that is no multiple of 128 gets the one block the old default gave,
+    and so does one whose streamed tiles alone overflow (wide or f32
+    operands: HVT_FLASH_SEQ_TILE is the knob for those, as before)."""
+    if s % _LANES:
+        block = _blocks(s, _LANES)
+        return block, block
+    cap = _DKV_TILE_CAP if kernel == "dkv" else None
+    most_q, most_k = _PREFERRED_TILE[kernel]
+    sizes = [b for b in range(_LANES, s + 1, _LANES) if s % b == 0]
+    fit = [(bq, bk) for bq in sizes if bq <= most_q
+           for bk in sizes if bk <= most_k
+           if max(bq, bk) % min(bq, bk) == 0
+           and _vmem_bytes(kernel, bq, bk, d, itemsize,
+                           _seq_tile(s, bq, bk, cap)) <= _SCOPED_VMEM]
+    return max(fit, key=lambda t: (t[0] * t[1], t[1]),
+               default=(_LANES, _LANES))
+
+
+def _score_tile(kernel, s, d, itemsize, block_q, block_k):
+    """``(block_q, block_k, derived)`` for one of the three kernels: an
+    explicit integer is honoured as ever (clipped to divide ``s``);
+    ``None`` takes that side of the tile derived from the shape."""
+    derived = block_q is None or block_k is None
+    if derived:
+        auto_q, auto_k = _derive_tile(kernel, s, d, itemsize)
+    bq = auto_q if block_q is None else _blocks(s, block_q)
+    bk = auto_k if block_k is None else _blocks(s, block_k)
+    return bq, bk, derived
+
+
+def _count_trace(kernel, block_q, block_k, derived):
+    """The engagement counter: which score tile each traced kernel got and
+    whether the rule or the caller chose it. Trace-time Python only."""
+    try:
+        from horovod_tpu import metrics
+
+        metrics.counter(
+            "hvt_flash_kernel_traces_total",
+            "flash-attention kernels traced into compiled programs, by "
+            "score tile (counted per trace, not per execution)",
+            ("kernel", "block_q", "block_k", "derived"),
+        ).labels(kernel=kernel, block_q=str(block_q), block_k=str(block_k),
+                 derived=str(int(derived))).inc()
+    except Exception:
+        pass  # telemetry must never break a trace
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -300,19 +428,18 @@ _DKV_TILE_CAP = 4096
 
 def _seq_tile(s, block_q, block_k, cap=None):
     """Streamed-sequence VMEM tile (elements of the seq axis per grid
-    step). The default of 4096 was chosen by an earlier builder on
-    another rig (d=64, 12 heads) and has not been reproduced. Override
-    with HVT_FLASH_SEQ_TILE for other head dims; ``cap`` bounds the
-    request per-kernel (the dkv backward caps at ``_DKV_TILE_CAP``).
+    step). The default of 4096 is an earlier builder's choice on another
+    rig; on the v5e it is what every measurement of PR 25 ran with (the
+    whole sequence of the benchmark's ``gpt2l-s4096``, half of 8192's)
+    and no other value has been timed there. Override with
+    HVT_FLASH_SEQ_TILE; ``cap`` bounds the request per-kernel (the dkv
+    backward caps at ``_DKV_TILE_CAP``).
 
     The tile must divide ``s`` AND be a multiple of both block sizes —
     the kernels walk ``tile // block`` sub-blocks, so a remainder would
     silently drop sequence positions. Both blocks divide s (``_blocks``),
     hence lcm(block_q, block_k) divides s and a valid tile always
     exists."""
-    import math
-    import os
-
     req = min(int(os.environ.get("HVT_FLASH_SEQ_TILE", "4096")), s)
     if cap is not None:
         req = min(req, cap)
@@ -338,6 +465,9 @@ def _seq_tile(s, block_q, block_k, cap=None):
 
 def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype):
     b, h, s, d = q.shape
+    block_q, block_k, derived = _score_tile("fwd", s, d, q.dtype.itemsize,
+                                            block_q, block_k)
+    _count_trace("fwd", block_q, block_k, derived)
     # Grouped-query attention is served ZERO-COPY: query head hi reads
     # K/V head hi // group through the block index map — no repeat
     # materialization, and the shared K/V tile stays VMEM-resident
@@ -364,6 +494,8 @@ def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype):
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
+        compiler_params=_compiler_params("fwd", block_q, block_k, d,
+                                         q.dtype.itemsize, tile),
         interpret=_interpret(),
         name="hvt_flash_fwd",
     )(q, k, v)
@@ -379,14 +511,23 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, out_dtype):
 def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
     do, dlse = cot
     q, k, v, o, lse = res
-    b, h, s, d = q.shape
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)        # [B, H, S, 1]
     # lse cotangent: ds gains + P∘dlse, i.e. delta shifts by −dlse
     delta = delta - dlse.astype(jnp.float32)
+    dq = _dq_call(q, k, v, do, lse, delta, scale, causal, block_q, block_k)
+    dk, dv = _dkv_call(q, k, v, do, lse, delta, scale, causal, block_q,
+                       block_k)
+    return dq, dk, dv
 
-    # dq: grid (b, h, qi, ti) — K/V tiles stream past each Q block.
-    # GQA reads the shared K/V head zero-copy via the index map.
+
+def _dq_call(q, k, v, do, lse, delta, scale, causal, block_q, block_k):
+    """dq: grid (b, h, qi, ti) — K/V tiles stream past each Q block.
+    GQA reads the shared K/V head zero-copy via the index map."""
+    b, h, s, d = q.shape
+    block_q, block_k, derived = _score_tile("dq", s, d, q.dtype.itemsize,
+                                            block_q, block_k)
+    _count_trace("dq", block_q, block_k, derived)
     group = h // k.shape[1]
     tile = _seq_tile(s, block_q, block_k)
     q_by_qi = pl.BlockSpec((1, 1, block_q, d),
@@ -395,7 +536,7 @@ def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
                            lambda bi, hi, qi, ti: (bi, hi // group, ti, 0))
     vec_by_qi = pl.BlockSpec((1, 1, block_q, 1),
                              lambda bi, hi, qi, ti: (bi, hi, qi, 0))
-    dq = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_k=block_k),
         grid=(b, h, s // block_q, s // tile),
@@ -404,15 +545,24 @@ def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
         out_specs=q_by_qi,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=_compiler_params("dq", block_q, block_k, d,
+                                         q.dtype.itemsize, tile),
         interpret=_interpret(),
         name="hvt_flash_dq",
     )(q, k, v, do, lse, delta)
 
-    # dk/dv: grid (b, h, ki, ti) — Q/dO/lse/delta tiles stream past
-    # each K/V block (the reduction axis must be LAST). Under GQA the
-    # kernel still reads the shared K/V head zero-copy but emits
-    # per-QUERY-head gradients (full h), which are then group-summed —
-    # each K/V head's gradient is the sum over its query group.
+
+def _dkv_call(q, k, v, do, lse, delta, scale, causal, block_q, block_k):
+    """dk/dv: grid (b, h, ki, ti) — Q/dO/lse/delta tiles stream past
+    each K/V block (the reduction axis must be LAST). Under GQA the
+    kernel still reads the shared K/V head zero-copy but emits
+    per-QUERY-head gradients (full h), which are then group-summed —
+    each K/V head's gradient is the sum over its query group."""
+    b, h, s, d = q.shape
+    block_q, block_k, derived = _score_tile("dkv", s, d, q.dtype.itemsize,
+                                            block_q, block_k)
+    _count_trace("dkv", block_q, block_k, derived)
+    group = h // k.shape[1]
     # The dkv tile is capped independently of the fwd/dq tile: this
     # kernel streams Q AND dO tiles together and was the one that blew
     # scoped VMEM at tile 8192 (see _DKV_TILE_CAP).
@@ -425,7 +575,6 @@ def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
                           lambda bi, hi, ki, ti: (bi, hi, ti, 0))
     vec_tile = pl.BlockSpec((1, 1, dkv_tile, 1),
                             lambda bi, hi, ki, ti: (bi, hi, ti, 0))
-    full_shape = (b, h, s, d)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q),
@@ -433,10 +582,12 @@ def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
         in_specs=[kv_in_ki, kv_in_ki, q_tile, q_tile, vec_tile,
                   vec_tile],
         out_specs=[dkv_out_ki, dkv_out_ki],
-        out_shape=[jax.ShapeDtypeStruct(full_shape, k.dtype),
-                   jax.ShapeDtypeStruct(full_shape, v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, k.dtype),
+                   jax.ShapeDtypeStruct(q.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
+        compiler_params=_compiler_params("dkv", block_q, block_k, d,
+                                         q.dtype.itemsize, dkv_tile),
         interpret=_interpret(),
         name="hvt_flash_dkv",
     )(k, v, q, do, lse, delta)
@@ -446,14 +597,14 @@ def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
             b, h_kv, group, s, d).sum(axis=2).astype(k.dtype)
         dv = dv.astype(jnp.float32).reshape(
             b, h_kv, group, s, d).sum(axis=2).astype(v.dtype)
-    return dq, dk, dv
+    return dk, dv
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, causal=True, scale=None,
-                    block_q=128, block_k=128):
+                    block_q=None, block_k=None):
     """Fused multi-head attention.
 
     Args:
@@ -461,7 +612,9 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
         :mod:`horovod_tpu.models.transformer`).
       causal: apply causal masking.
       scale: softmax scale, default ``head_dim ** -0.5``.
-      block_q / block_k: MXU tile sizes; clipped to divide seq.
+      block_q / block_k: the score tile; ``None`` (the default) derives
+        it from the shape, one tile a kernel (``_derive_tile``); an
+        integer is honoured, clipped to divide seq.
 
     Returns [batch, seq, heads, head_dim] in q.dtype. Differentiable
     (custom VJP with recompute-based backward kernels).
@@ -472,7 +625,7 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
 
 
 def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
-                             block_q=128, block_k=128, out_dtype=None):
+                             block_q=None, block_k=None, out_dtype=None):
     """Fused attention returning ``(o, lse)``; both are differentiable.
 
     ``lse[b, s, h]`` is the log-sum-exp of the (scaled, masked) scores for
@@ -493,16 +646,15 @@ def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
             f"({h_kv})")
     if scale is None:
         scale = d ** -0.5
-    block_q = _blocks(s, block_q)
-    block_k = _blocks(s, block_k)
-    if not _interpret() and (block_q % 8 or block_k % 8):
+    bq, bk, _ = _score_tile("fwd", s, d, q.dtype.itemsize, block_q, block_k)
+    if not _interpret() and (bq % 8 or bk % 8):
         # Mosaic refuses the kernel ("cannot statically prove that index
         # in dimension 2 is a multiple of 8"); the interpreter has no
         # such limit, so name it here rather than inside the compiler
         raise ValueError(
             f"flash attention compiles only with sequence blocks that "
             f"are multiples of 8: sequence length {s} clips the blocks "
-            f"to ({block_q}, {block_k}). Pad the sequence to a multiple "
+            f"to ({bq}, {bk}). Pad the sequence to a multiple "
             f"of 8 or use the einsum path (use_flash=False)")
     # Kernels are gridded (batch, head, block): BHSD layout.
     to_bhsd = lambda x: jnp.transpose(x, (0, 2, 1, 3))

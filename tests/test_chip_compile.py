@@ -67,12 +67,15 @@ def _qkv(shape, sharding):
 
 
 # (batch, seq, heads, kv_heads, head_dim): the shapes bench.py's GPT and
-# chip_smoke.py run, one grouped-query shape, the 4-chip ring, and one
-# sequence whose clipped block Mosaic refuses
+# chip_smoke.py run, the benchmark cell gpt2l-s4096's own, one
+# grouped-query shape, the 4-chip ring, and one sequence whose clipped
+# block Mosaic refuses. No blocks are passed: each kernel's score tile is
+# the one derived from the shape
 @pytest.mark.parametrize("kind,shape", [
     pytest.param("flash", (8, 1024, 12, 12, 64), id="flash-8x1024"),
     pytest.param("flash", (2, 4096, 12, 12, 64), id="flash-2x4096"),
     pytest.param("flash", (1, 8192, 12, 12, 64), id="flash-1x8192"),
+    pytest.param("flash", (2, 4096, 20, 20, 64), id="flash-gpt2l-s4096"),
     pytest.param("flash", (1, 4096, 32, 8, 128), id="flash-gqa-32-8-128"),
     pytest.param("ring", (1, 16384, 12, 12, 64), id="ring-sp4-16384"),
     pytest.param("refused", (1, 100, 2, 2, 64), id="block-not-multiple-of-8"),

@@ -306,3 +306,173 @@ def test_kernels_carry_their_names(kernel, in_backward):
     backward = str(jax.make_jaxpr(jax.grad(attend, (0, 1, 2)))(q, k, v))
     assert (kernel in forward) == (not in_backward)
     assert kernel in backward
+
+
+def _dense_o_lse(q, k, v, causal):
+    """The formula in float32: (o [B,S,H,D], lse [B,S,H]); K/V heads are
+    repeated for a grouped-query shape."""
+    d = q.shape[-1]
+    g = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        precision="highest") * d ** -0.5
+    if causal:
+        pos = jnp.arange(q.shape[1])
+        scores = jnp.where(pos[None, :] <= pos[:, None], scores, -1e30)
+    o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v,
+                   precision="highest")
+    return o, jax.scipy.special.logsumexp(scores, -1).transpose(0, 2, 1)
+
+
+# (seq, heads, kv_heads, causal, block_q, block_k, HVT_FLASH_SEQ_TILE):
+# None blocks are derived from the shape
+@pytest.mark.parametrize("s,h,h_kv,causal,block_q,block_k,seq_tile", [
+    pytest.param(256, 2, 2, True, None, None, None, id="derived-causal"),
+    pytest.param(256, 2, 2, False, None, None, None, id="derived-full"),
+    pytest.param(256, 2, 2, True, 128, 128, None, id="128x128-causal"),
+    pytest.param(256, 2, 2, False, 128, 128, None, id="128x128-full"),
+    # four k sub-blocks cross the diagonal of every Q block (fwd, dQ)
+    pytest.param(512, 1, 1, True, 256, 64, None, id="256x64-diagonal"),
+    # four Q sub-blocks cross it for every K block (the dK/dV dual)
+    pytest.param(512, 1, 1, True, 64, 256, None, id="64x256-diagonal"),
+    pytest.param(512, 1, 1, True, 128, 64, "256", id="multi-tile"),
+    pytest.param(512, 1, 1, True, None, None, "256",
+                 id="derived-multi-tile"),
+    pytest.param(256, 4, 2, True, None, None, None, id="derived-gqa"),
+    pytest.param(256, 4, 1, True, 128, 128, None, id="128x128-mqa"),
+])
+def test_score_tiles_match_the_f32_formula(s, h, h_kv, causal, block_q,
+                                           block_k, seq_tile, monkeypatch):
+    """o, lse, dq, dk, dv of every way a score tile is chosen against
+    the float32 formula, cotangents on o and lse both."""
+    from horovod_tpu.ops.flash_attention import flash_attention_with_lse
+
+    if seq_tile:
+        monkeypatch.setenv("HVT_FLASH_SEQ_TILE", seq_tile)
+    rs = np.random.RandomState(11)
+    q = jnp.asarray(rs.randn(1, s, h, 32), jnp.float32)
+    k, v = (jnp.asarray(rs.randn(1, s, h_kv, 32), jnp.float32)
+            for _ in range(2))
+    w_o = jnp.asarray(rs.randn(1, s, h, 32), jnp.float32)
+    w_lse = jnp.asarray(rs.randn(1, s, h), jnp.float32)
+
+    def run(attend):
+        def loss(q, k, v):
+            o, lse = attend(q, k, v)
+            return (o * w_o).sum() + (lse * w_lse).sum(), (o, lse)
+
+        (_, (o, lse)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (o, lse, *grads)
+
+    got = run(lambda q, k, v: flash_attention_with_lse(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k))
+    want = run(lambda q, k, v: _dense_o_lse(q, k, v, causal))
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [1024, 1536, 2048, 4096, 8192, 16384])
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_derived_tile_divides_the_sequence_and_fits_the_budget(kernel, s,
+                                                               d):
+    from horovod_tpu.ops import flash_attention as fa
+
+    for itemsize in (2, 4):
+        bq, bk = fa._derive_tile(kernel, s, d, itemsize)
+        assert s % bq == 0 and s % bk == 0, (bq, bk)
+        assert bq % 128 == 0 and bk % 128 == 0, (bq, bk)
+        assert max(bq, bk) % min(bq, bk) == 0, (bq, bk)
+        most_q, most_k = fa._PREFERRED_TILE[kernel]
+        assert bq <= most_q and bk <= most_k, (bq, bk)
+        tile = fa._seq_tile(s, bq, bk,
+                            fa._DKV_TILE_CAP if kernel == "dkv" else None)
+        assert tile % bq == 0 and tile % bk == 0 and tile <= 4096
+        # inside the budget, or the old 128 x 128 when nothing is
+        assert (fa._vmem_bytes(kernel, bq, bk, d, itemsize, tile)
+                <= fa._SCOPED_VMEM or (bq, bk) == (128, 128))
+        # bf16 gets the preferred tile wherever the sequence allows it
+        if itemsize == 2 and s % 1024 == 0:
+            assert (bq, bk) == (most_q, most_k)
+        # an explicit block is honoured as before, derived or not beside it
+        assert fa._score_tile(kernel, s, d, itemsize, 32, 384) == (
+            32, fa._blocks(s, 384), False)
+        assert fa._score_tile(kernel, s, d, itemsize, None, 128) == (
+            bq, 128, True)
+        assert fa._score_tile(kernel, s, d, itemsize, None, None) == (
+            bq, bk, True)
+
+
+@pytest.mark.parametrize("s,want", [(64, 64), (96, 96), (192, 64),
+                                    (1000, 8), (100, 100)])
+def test_derived_tile_of_a_sequence_that_is_no_multiple_of_128(s, want):
+    # the one block the old default of 128 gave
+    from horovod_tpu.ops import flash_attention as fa
+
+    for kernel in ("fwd", "dq", "dkv"):
+        assert fa._derive_tile(kernel, s, 64, 2) == (want, want)
+
+
+def _trace_count(**labels):
+    from horovod_tpu import metrics
+
+    m = metrics.registry().get("hvt_flash_kernel_traces_total")
+    return m.labels(**labels).value if m else 0.0
+
+
+def test_trace_counter_carries_the_tile_and_who_chose_it():
+    from horovod_tpu.ops import flash_attention as fa
+
+    q, k, v = _qkv(b=1, s=256, h=1)
+    kernels = ("fwd", "dq", "dkv")
+
+    def counts(derived):
+        out = []
+        for kern in kernels:
+            bq, bk = (fa._derive_tile(kern, 256, q.shape[-1],
+                                      q.dtype.itemsize)
+                      if derived else (32, 64))
+            out.append(_trace_count(kernel=kern, block_q=str(bq),
+                                    block_k=str(bk),
+                                    derived=str(int(derived))))
+        return out
+
+    trace = lambda **kw: jax.make_jaxpr(jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v, **kw).sum(),
+        (0, 1, 2)))(q, k, v)
+    explicit, derived = counts(False), counts(True)
+    trace(block_q=32, block_k=64)
+    assert counts(False) == [n + 1 for n in explicit]
+    assert counts(True) == derived
+    trace()
+    assert counts(True) == [n + 1 for n in derived]
+    assert counts(False) == [n + 1 for n in explicit]
+
+
+def test_trace_counter_adds_nothing_to_the_program(monkeypatch):
+    from horovod_tpu.ops import flash_attention as fa
+
+    q, k, v = _qkv(b=1, s=128, h=1)
+    lowered = lambda: jax.jit(jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v).sum(),
+        (0, 1, 2))).lower(q, k, v).as_text()
+    counted = lowered()
+    monkeypatch.setattr(fa, "_count_trace", lambda *a: None)
+    assert lowered() == counted
+
+
+def test_scoped_vmem_is_raised_only_where_the_estimate_nears_it():
+    # the default scope for the small tiles tests and old callers pass,
+    # a limit above the estimate for the one derived tile that nears it
+    from horovod_tpu.ops import flash_attention as fa
+
+    assert fa._compiler_params("fwd", 512, 1024, 64, 2, 4096) is None
+    assert fa._compiler_params("dq", 512, 512, 128, 2, 4096) is None
+    assert fa._compiler_params("dkv", 128, 128, 64, 2, 4096) is None
+    need = fa._vmem_bytes("dkv", 1024, 512, 64, 2, 4096)
+    limit = fa._compiler_params("dkv", 1024, 512, 64, 2,
+                                4096).vmem_limit_bytes
+    assert need <= fa._SCOPED_VMEM < limit and limit >= need * 1.5
