@@ -74,6 +74,18 @@ class GPTConfig:
     # hash/eq exclude nothing: Mesh is hashable, so the config stays a
     # valid jit-static argument.
     ring_mesh: Optional[object] = None
+    # Sparse blocks (models/moe.py): n_experts > 0 replaces every block's
+    # MLP by a dropless top-k router over n_experts SwiGLU experts, each
+    # d_ff wide, experts_per_token of them a token. 0 = the dense MLP.
+    n_experts: int = 0
+    experts_per_token: int = 0
+    # RMSNorm over the whole projected width of q and of k, before the
+    # heads are split and rotated (OLMoE, OLMo 2).
+    qk_norm: bool = False
+    # False gives the logits a matrix of their own, `lm_head`
+    # [vocab, d_model], in place of the embedding's transpose.
+    tie_embeddings: bool = True
+    norm_eps: float = 1e-6
 
 
 # The crossover policy lives with the kernel (ops/flash_attention.py);
@@ -138,6 +150,10 @@ class Attention(nn.Module):
         q = dense((cfg.n_heads, head_dim), "q")(x)
         k = dense((n_kv, head_dim), "k")(x)
         v = dense((n_kv, head_dim), "v")(x)
+        if cfg.qk_norm:
+            full_width = lambda t, name: RMSNorm(cfg.norm_eps, name=name)(
+                t.reshape(*t.shape[:-2], -1)).reshape(t.shape)
+            q, k = full_width(q, "q_norm"), full_width(k, "k_norm")
         q = _rotary(q, positions)
         k = _rotary(k, positions)
 
@@ -202,26 +218,42 @@ class MLP(nn.Module):
 
 
 class Block(nn.Module):
+    """One pre-norm block. Returns ``(x, aux)``: ``aux`` is the expert
+    layer's auxiliary losses (``models/moe.py``), None for a dense
+    block."""
+
     cfg: GPTConfig
 
     @nn.compact
     def __call__(self, x, positions):
-        x = x + Attention(self.cfg, name="attn")(
-            RMSNorm(name="ln1")(x), positions)
-        x = x + MLP(self.cfg, name="mlp")(RMSNorm(name="ln2")(x))
-        return x
+        cfg = self.cfg
+        x = x + Attention(cfg, name="attn")(
+            RMSNorm(cfg.norm_eps, name="ln1")(x), positions)
+        h = RMSNorm(cfg.norm_eps, name="ln2")(x)
+        if not cfg.n_experts:
+            return x + MLP(cfg, name="mlp")(h), None
+        from horovod_tpu.models.moe import MoEMlp
+
+        out, aux = MoEMlp(cfg.n_experts, cfg.d_ff, cfg.experts_per_token,
+                          dtype=cfg.dtype, name="moe")(h)
+        return x + out, aux
 
 
 class GPT(nn.Module):
     cfg: GPTConfig
 
     @nn.compact
-    def __call__(self, tokens, return_hidden: bool = False):
+    def __call__(self, tokens, return_hidden: bool = False,
+                 return_aux: bool = False):
         """Logits by default; ``return_hidden=True`` returns the final
         (post-ln) hidden states instead, for memory-bounded losses that
         fuse the vocab projection (``ops.losses
-        .softmax_cross_entropy_fused`` with the tied embedding) — the
-        [batch, seq, vocab] logits tensor is then never materialized."""
+        .softmax_cross_entropy_fused`` with the embedding, or with
+        ``params["lm_head"]`` of an untied model) — the
+        [batch, seq, vocab] logits tensor is then never materialized.
+        ``return_aux=True`` returns ``(that, aux)``: the expert layers'
+        auxiliary losses summed over the layers, each unweighted
+        (``{"load_balance", "router_z"}``; ``{}`` for a dense model)."""
         cfg = self.cfg
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[-1]), tokens.shape)
@@ -232,25 +264,35 @@ class GPT(nn.Module):
         block = Block
         if cfg.remat:
             block = nn.remat(Block, static_argnums=())
+        aux = {}
         for i in range(cfg.n_layers):
-            x = block(cfg, name=f"block_{i}")(x, positions)
-        x = RMSNorm(name="ln_f")(x)
-        if return_hidden:
-            return x
-        with jax.named_scope("lm_head"):
-            logits = jnp.einsum("...ld,vd->...lv", x.astype(jnp.float32),
-                                emb)
-        return logits
+            x, layer_aux = block(cfg, name=f"block_{i}")(x, positions)
+            if layer_aux is not None:
+                aux = {name: aux.get(name, 0.0) + value
+                       for name, value in layer_aux.items()}
+        x = RMSNorm(cfg.norm_eps, name="ln_f")(x)
+        head = emb if cfg.tie_embeddings else self.param(
+            "lm_head", nn.initializers.normal(0.02),
+            (cfg.vocab_size, cfg.d_model), jnp.float32)
+        if not return_hidden:
+            with jax.named_scope("lm_head"):
+                x = jnp.einsum("...ld,vd->...lv", x.astype(jnp.float32),
+                               head)
+        return (x, aux) if return_aux else x
 
 
-def param_partition_spec(params, *, tp_axis="tp", tp_size=None):
+def param_partition_spec(params, *, tp_axis="tp", tp_size=None,
+                         ep_axis=None):
     """PartitionSpec pytree for Megatron-style tensor parallelism.
 
     Column-parallel: q/k/v and MLP up kernels shard their output dim over
     ``tp_axis``; row-parallel: attention out and MLP down kernels shard
     their input dim, so XLA inserts exactly one psum per row-parallel
     matmul (the NCCL-allreduce-per-layer pattern, compiled).
-    Embedding shards the vocab dim. Norm scales replicate.
+    Embedding and an untied ``lm_head`` shard the vocab dim. Norm scales
+    replicate. The expert stacks of a sparse model shard their expert
+    axis over ``ep_axis`` where one is given (and their ``d_ff`` axis
+    over ``tp_axis``), as ``moe.moe_param_partition_spec`` does.
 
     ``tp_size`` (the mesh's tp axis size, when known): a head axis not
     divisible by it — GQA/MQA K/V kernels with ``n_kv_heads < tp`` —
@@ -262,8 +304,12 @@ def param_partition_spec(params, *, tp_axis="tp", tp_size=None):
 
     def spec_for(path, leaf):
         names = [getattr(p, "key", None) for p in path]
-        if "embedding" in names:
+        if "embedding" in names or "lm_head" in names:
             return P(tp_axis, None)
+        if "moe" in names:
+            from horovod_tpu.models.moe import expert_leaf_spec
+
+            return expert_leaf_spec(names[-1], leaf, ep_axis, tp_axis)
         if any(n in ("q", "k", "v") for n in names):
             heads = leaf.shape[1] if hasattr(leaf, "shape") else None
             if tp_size and heads is not None and heads % tp_size:

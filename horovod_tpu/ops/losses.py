@@ -59,8 +59,10 @@ def softmax_cross_entropy_fused(hidden, emb, targets, *, chunk=128):
 
     @jax.checkpoint
     def chunk_loss(h, t, w):
-        logits = jnp.einsum("bcd,vd->bcv", h.astype(jnp.float32),
-                            emb.astype(jnp.float32))
+        # the name models.GPT gives its own vocabulary projection
+        with jax.named_scope("lm_head"):
+            logits = jnp.einsum("bcd,vd->bcv", h.astype(jnp.float32),
+                                emb.astype(jnp.float32))
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         tgt = jnp.take_along_axis(logits, t[..., None], axis=-1)[..., 0]
         return ((lse - tgt) * w).sum()

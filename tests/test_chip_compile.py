@@ -17,6 +17,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from horovod_tpu.models import moe
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.parallel.sequence import ring_attention
 
@@ -43,6 +44,7 @@ def compiled_kernel(monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -77,6 +79,7 @@ def _qkv(shape, sharding):
     pytest.param("flash", (1, 8192, 12, 12, 64), id="flash-1x8192"),
     pytest.param("flash", (2, 4096, 20, 20, 64), id="flash-gpt2l-s4096"),
     pytest.param("flash", (1, 4096, 32, 8, 128), id="flash-gqa-32-8-128"),
+    pytest.param("flash", (2, 4096, 16, 16, 128), id="flash-olmoe-s4096"),
     pytest.param("ring", (1, 16384, 12, 12, 64), id="ring-sp4-16384"),
     pytest.param("refused", (1, 100, 2, 2, 64), id="block-not-multiple-of-8"),
 ])
@@ -103,3 +106,34 @@ def test_main_path_kernel_compiles_for_v5e(kind, shape, compiled_kernel,
     assert "tpu_custom_call" in text
     if kind == "ring":
         assert "collective-permute" in text
+
+
+def test_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices):
+    """The benchmark cell olmoe-s4096's expert layer at its own sizes
+    (2 x 4096 tokens, 64 experts of 1024, 8 a token, bf16), forward and
+    backward: the nine grouped products are Pallas calls whose tile fits
+    VMEM, and no scatter is over rows or weights: the only ones are the
+    products' bookkeeping (which tile belongs to which group)."""
+    import re
+
+    tokens, d, experts, k = 2 * 4096, 2048, 64, 8
+    layer = moe.MoEMlp(experts, 1024, k, dtype=jnp.bfloat16)
+    one = SingleDeviceSharding(v5e_devices[0])
+    x = jax.ShapeDtypeStruct((tokens, d), jnp.bfloat16, sharding=one)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(layer.init, jax.random.key(0), x)["params"])
+
+    def loss(params, x):
+        out, aux = layer.apply({"params": params}, x)
+        return (jnp.mean(out.astype(jnp.float32) ** 2)
+                + aux["load_balance"] + aux["router_z"])
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) == 9
+    tiles = tokens * k // 512
+    for line in text.splitlines():
+        if " scatter(" in line:
+            shape = re.search(r"= \w+\[([\d,]*)\]", line).group(1)
+            assert "," not in shape and int(shape) <= 2 * experts + tiles, line

@@ -631,3 +631,151 @@ def test_gpt_gradient_program_names_its_regions(remat):
         assert holding("jvp(", scope) and holding("transpose(jvp(", scope)
     assert holding("transpose(jvp(", "/block_1/mlp/")
     assert bool(holding("rematted_computation", "/block_1/")) == remat
+
+
+# ---- the sparse decoder (OLMoE's block) against its plain reference
+
+_SPARSE = {"num_experts_per_tok": 8, "rope_theta": 10000.0,
+           "rms_norm_eps": 1e-5, "router_aux_loss_coef": 0.01,
+           "router_z_loss_coef": 0.001}
+
+
+def _sparse_model(remat):
+    from horovod_tpu.models import GPT, GPTConfig
+
+    cfg = GPTConfig(vocab_size=64, n_layers=2, d_model=32, n_heads=2,
+                    d_ff=8, dtype=jnp.float32, remat=remat, use_flash=False,
+                    n_experts=64, experts_per_token=8, qk_norm=True,
+                    tie_embeddings=False, norm_eps=1e-5)
+    model = GPT(cfg)
+    tokens = jax.random.randint(jax.random.key(1), (2, 16), 0, 64)
+    params = model.init(jax.random.key(0), tokens)["params"]
+    # at their 0.02 the experts and the router barely move the loss
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, w: w * 20.0 if "moe" in str(path) else w, params)
+    return model, params, tokens
+
+
+def _sparse_loss(model, params, tokens):
+    import optax
+
+    logits, aux = model.apply({"params": params}, tokens, return_aux=True)
+    ce = optax.softmax_cross_entropy_with_integer_labels(
+        logits[:, :-1], tokens[:, 1:]).mean()
+    return (ce + _SPARSE["router_aux_loss_coef"] * aux["load_balance"]
+            + _SPARSE["router_z_loss_coef"] * aux["router_z"])
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_sparse_gpt_matches_reference(remat):
+    """Two layers of 64 experts, 8 a token, q and k normalised over their
+    whole width, an untied head: loss and the gradient of every leaf
+    against chipbench/reference/olmoe.py, to float32's summation order;
+    remat changes nothing."""
+    from chipbench.reference import olmoe as reference
+
+    model, params, tokens = _sparse_model(remat)
+    assert {"lm_head", "embedding"} <= set(params)
+    assert set(params["block_0"]) == {"ln1", "attn", "ln2", "moe"}
+    assert set(params["block_0"]["attn"]) == {"q", "k", "v", "o", "q_norm",
+                                              "k_norm"}
+    assert params["block_0"]["attn"]["q_norm"]["scale"].shape == (32,)
+    got, grads = jax.jit(jax.value_and_grad(
+        lambda p: _sparse_loss(model, p, tokens)))(params)
+    (want, _), want_grads = reference.loss_and_grad(params, tokens, _SPARSE)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    flat, want_flat = (jax.tree_util.tree_leaves_with_path(t)
+                       for t in (grads, want_grads))
+    for (path, g), (_, w) in zip(flat, want_flat, strict=True):
+        err = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+        assert err <= 1e-5, (jax.tree_util.keystr(path), err)
+    plain, _, _ = _sparse_model(not remat)
+    assert float(_sparse_loss(plain, params, tokens)) == pytest.approx(
+        float(got), rel=1e-6)
+    # the hidden states and the head a memory-bounded loss multiplies
+    hidden = model.apply({"params": params}, tokens, return_hidden=True)
+    np.testing.assert_allclose(
+        np.asarray(hidden @ params["lm_head"].T),
+        np.asarray(model.apply({"params": params}, tokens)), rtol=1e-5,
+        atol=1e-5)
+
+
+def test_dense_gpt_is_the_parents():
+    """What the gpt2-large cells build: the parameter tree of the commit
+    before the sparse fields, and a step that carries none of the new
+    scopes or leaves."""
+    import re
+
+    from horovod_tpu.models import GPT, GPTConfig
+
+    cfg = GPTConfig(vocab_size=64, n_layers=2, d_model=32, n_heads=4,
+                    d_ff=128, max_seq_len=8, dtype=jnp.bfloat16, remat=True,
+                    use_flash="auto")
+    model = GPT(cfg)
+    tokens = jnp.zeros((2, 8), jnp.int32)
+    params = model.init(jax.random.key(0), tokens)["params"]
+    shapes = {jax.tree_util.keystr(path): leaf.shape for path, leaf
+              in jax.tree_util.tree_leaves_with_path(params)}
+    block = lambda i: {
+        f"['block_{i}']['ln1']['scale']": (32,),
+        f"['block_{i}']['ln2']['scale']": (32,),
+        f"['block_{i}']['attn']['q']['kernel']": (32, 4, 8),
+        f"['block_{i}']['attn']['k']['kernel']": (32, 4, 8),
+        f"['block_{i}']['attn']['v']['kernel']": (32, 4, 8),
+        f"['block_{i}']['attn']['o']['kernel']": (4, 8, 32),
+        f"['block_{i}']['mlp']['up']['kernel']": (32, 128),
+        f"['block_{i}']['mlp']['down']['kernel']": (128, 32)}
+    assert shapes == {"['embedding']": (64, 32), "['ln_f']['scale']": (32,),
+                      **block(0), **block(1)}
+    out, aux = model.apply({"params": params}, tokens, return_aux=True)
+    assert aux == {} and out.shape == (2, 8, 64)
+    names = set(re.findall(r'loc\("([^"]*)"', jax.jit(jax.grad(
+        lambda p: model.apply({"params": p}, tokens).sum())).lower(
+            params).as_text(debug_info=True)))
+    assert any("/block_1/mlp/" in n for n in names)
+    for new in ("moe", "q_norm", "k_norm"):
+        assert not [n for n in names if new in n], new
+
+
+def test_param_partition_spec_of_a_sparse_model():
+    from horovod_tpu.models.transformer import param_partition_spec
+
+    _, params, _ = _sparse_model(False)
+    specs = param_partition_spec(params, ep_axis="ep")
+    moe = specs["block_1"]["moe"]
+    assert moe["gate"] == moe["up"] == P("ep", None, "tp")
+    assert moe["down"] == P("ep", "tp", None) and moe["router"] == P()
+    assert specs["lm_head"] == specs["embedding"] == P("tp", None)
+    assert specs["block_0"]["attn"]["q_norm"]["scale"] == P()
+    assert specs["block_0"]["attn"]["q"]["kernel"] == P(None, "tp", None)
+    # without an ep axis the expert axis is not sharded
+    assert param_partition_spec(params)["block_0"]["moe"]["gate"] == P(
+        None, None, "tp")
+
+
+@pytest.mark.parametrize("field, value, new_leaves", [
+    ("qk_norm", True, {"q_norm", "k_norm"}),
+    ("tie_embeddings", False, {"lm_head"}),
+    ("norm_eps", 1e-2, set()),
+])
+def test_gpt_config_field_changes_its_part_only(field, value, new_leaves):
+    """Each field OLMoE's block needed: the leaves it adds, and logits
+    that differ from the default model's on the same parameters."""
+    import dataclasses
+
+    from horovod_tpu.models import GPT, GPTConfig
+
+    base = GPTConfig(vocab_size=64, n_layers=1, d_model=32, n_heads=2,
+                     d_ff=64, dtype=jnp.float32, use_flash=False)
+    cfg = dataclasses.replace(base, **{field: value})
+    tokens = jax.random.randint(jax.random.key(2), (1, 12), 0, 64)
+    params = GPT(cfg).init(jax.random.key(0), tokens)["params"]
+    base_params = GPT(base).init(jax.random.key(0), tokens)["params"]
+    names = lambda tree: {str(getattr(k, "key", k)) for path, _ in
+                          jax.tree_util.tree_leaves_with_path(tree)
+                          for k in path}
+    assert names(params) - names(base_params) == new_leaves
+    got = GPT(cfg).apply({"params": params}, tokens)
+    want = GPT(base).apply({"params": base_params}, tokens)
+    assert got.shape == want.shape
+    assert float(jnp.max(jnp.abs(got - want))) > 1e-4

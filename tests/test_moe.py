@@ -1,5 +1,11 @@
-"""MoE / expert-parallelism tests: routing math, capacity, and compiled
-execution on a dp×ep mesh (XLA inserts the all-to-alls)."""
+"""MoE / expert-parallelism tests: the dropless top-k router, the sorted
+dispatch and its inverse, the grouped products against the plain float32
+reference (``chipbench/reference/olmoe.py``), and compiled execution on a
+dp×ep mesh. Float32 and tiny sizes: the Pallas grouped product runs in
+interpret mode on the CPU."""
+
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -7,94 +13,247 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from horovod_tpu.models.moe import MoEMlp, Router, moe_param_partition_spec
+from chipbench.reference import olmoe as reference
+from horovod_tpu.models import moe
+from horovod_tpu.models.moe import MoEMlp, moe_param_partition_spec
 from horovod_tpu.parallel.mesh import make_parallel_mesh
 
+REL = 1e-5      # float32 on both sides: summation order is all that differs
 
-def test_router_dispatch_is_permutation():
-    """With ample capacity every token lands in exactly one (expert, slot)
-    and the combine weights equal the chosen gate values."""
-    x = jnp.asarray(np.random.RandomState(0).randn(2, 8, 16)
-                    .astype(np.float32))
-    router = Router(n_experts=4, capacity_factor=4.0)
-    vars_ = router.init(jax.random.PRNGKey(0), x)
-    dispatch, combine, aux = router.apply(vars_, x)
-    assert dispatch.shape == (2, 8, 4, 8)
-    # each token dispatched exactly once
+
+def _close(got, want, what):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    err = np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30)
+    assert err <= REL, f"{what}: relative error {err:.2e}"
+
+
+def _layer(n_experts, k, skewed, tokens=48, d=16, d_ff=8, seed=0):
+    """A float32 layer, its parameters and its input ``[tokens, d]``. A
+    skewed router sends every token to expert 0 (the most one expert can
+    take: a k-th of the assignments, all of them at k = 1) and none to
+    the last."""
+    layer = MoEMlp(n_experts, d_ff, k, dtype=jnp.float32)
+    h = jax.random.normal(jax.random.key(seed), (tokens, d))
+    params = layer.init(jax.random.key(seed + 1), h)["params"]
+    # initialised at 0.02 the experts' output is 1e-5 of the input: scale
+    # up so that a wrong row or weight cannot hide under the tolerance
+    params = jax.tree.map(lambda w: w * 20.0, params)
+    if skewed:
+        h = h.at[:, 0].set(1.0)
+        router = params["router"].at[0, 0].set(30.0).at[0, -1].set(-30.0)
+        params = {**params, "router": router}
+    return layer, params, h
+
+
+def _scatters(compiled) -> list:
+    """``(elements written to, op_name)`` of every scatter instruction
+    of a compiled program (the opcode, not the word: a test's own name
+    is in the program's metadata)."""
+    found = []
+    for line in compiled.as_text().splitlines():
+        if " scatter(" in line:
+            shape = re.search(r"= \w+\[([\d,]*)\]", line).group(1)
+            found.append((math.prod(int(n) for n in shape.split(",") if n),
+                          re.search(r'op_name="([^"]*)"', line).group(1)))
+    return found
+
+
+def _scalar(out, aux, cot):
+    return (jnp.sum(out * cot) + 0.3 * aux["load_balance"]
+            + 0.7 * aux["router_z"])
+
+
+@pytest.mark.parametrize("skewed", [False, True], ids=["even", "skewed"])
+@pytest.mark.parametrize("n_experts, k", [(8, 2), (64, 8), (4, 1)])
+def test_layer_matches_reference(n_experts, k, skewed):
+    """Output, both auxiliary losses and the gradient of every leaf and
+    of the input against the reference's loop over experts; and nothing
+    is dropped."""
+    layer, params, h = _layer(n_experts, k, skewed)
+    cot = jax.random.normal(jax.random.key(9), h.shape)
+
+    def program(params, h):
+        out, aux = layer.apply({"params": params}, h)
+        return _scalar(out, aux, cot), (out, aux)
+
+    def plain(params, h):
+        out, load_balance, router_z, routing = reference.experts_layer(
+            h, params, k)
+        aux = {"load_balance": load_balance, "router_z": router_z}
+        return _scalar(out, aux, cot), (out, aux, routing)
+
+    (_, (out, aux)), grads = jax.jit(jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True))(params, h)
+    (_, (want, want_aux, routing)), want_grads = jax.value_and_grad(
+        plain, argnums=(0, 1), has_aux=True)(params, h)
+    _close(out, want, "output")
+    for name in ("load_balance", "router_z"):
+        _close(aux[name], want_aux[name], name)
+    for name in ("router", "gate", "up", "down"):
+        _close(grads[0][name], want_grads[0][name], f"d {name}")
+    _close(grads[1], want_grads[1], "d input")
+    # dropless: every assignment has its row in some group
+    experts, _, order, inverse, sizes, *_ = moe.moe_route(
+        h, params["router"], k)
+    assert int(sizes.sum()) == h.shape[0] * k
+    np.testing.assert_array_equal(
+        np.sort(np.asarray(experts), -1),
+        np.sort(np.asarray(routing["own"]), -1))
+    if skewed:
+        assert int(sizes[0]) == h.shape[0] and int(sizes[-1]) == 0
+
+
+def test_router_sort_is_a_stable_permutation_by_expert():
+    """``order`` lists the T x k assignments expert by expert, a token's
+    rows in their own order; ``inverse`` undoes it; the group sizes are
+    the lengths of the runs; the weights are the chosen probabilities,
+    not renormalised."""
+    _, params, h = _layer(8, 2, skewed=False)
+    experts, weights, order, inverse, sizes, aux, probs = moe.moe_route(
+        h, params["router"], 2)
+    flat = np.asarray(experts).reshape(-1)
+    order, inverse = np.asarray(order), np.asarray(inverse)
+    assert sorted(order) == list(range(flat.size))
+    np.testing.assert_array_equal(order[inverse], np.arange(flat.size))
+    np.testing.assert_array_equal(order, np.argsort(flat, kind="stable"))
+    np.testing.assert_array_equal(np.asarray(sizes),
+                                  np.bincount(flat, minlength=8))
     np.testing.assert_allclose(
-        np.asarray(dispatch.sum(axis=(2, 3))), 1.0, atol=1e-6)
-    # each (expert, slot) holds at most one token
-    assert float(dispatch.sum(axis=1).max()) <= 1.0 + 1e-6
-    # combine weight ≤ gate ≤ 1, positive where dispatched
-    c = np.asarray(combine.sum(axis=(2, 3)))
-    assert (c > 0).all() and (c <= 1.0 + 1e-6).all()
-    assert float(aux) > 0
+        np.asarray(probs),
+        np.asarray(jax.nn.softmax(h @ params["router"], -1)), rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(weights),
+        np.take_along_axis(np.asarray(probs), np.asarray(experts), -1),
+        rtol=1e-6)
+    assert float(weights.sum(-1).max()) < 1.0       # top-2 of 8, as they are
+    assert float(aux["load_balance"]) > 0 and float(aux["router_z"]) > 0
 
 
-def test_router_capacity_drops_overflow():
-    """With capacity 1 and tokens forced to one expert, only the first
-    token per batch row survives."""
-    x = jnp.ones((1, 6, 8), jnp.float32)     # identical tokens → same expert
-    router = Router(n_experts=4, capacity_factor=4 / 6)
-    vars_ = router.init(jax.random.PRNGKey(1), x)
-    dispatch, _, _ = router.apply(vars_, x)
-    # capacity = int(4/6 * 6 / 4) = 1 slot per expert
-    assert float(dispatch.sum()) == pytest.approx(1.0)
+def test_layer_sows_what_its_router_saw_and_said():
+    """For a caller that asks for ``intermediates`` (the benchmark's
+    comparison with a float32 router): the router's input as the layer
+    computed in, the probabilities it made of it and the experts it
+    chose; a caller that does not ask gets the same output."""
+    layer, params, h = _layer(8, 2, skewed=False)
+    (out, _), sown = layer.apply({"params": params}, h.reshape(4, 12, 16),
+                                 mutable=["intermediates"])
+    sown = {name: value[0] for name, value in sown["intermediates"].items()}
+    assert set(sown) == {"router_input", "router_probs", "experts"}
+    np.testing.assert_array_equal(np.asarray(sown["router_input"]),
+                                  np.asarray(h))
+    probs, _, experts = reference.route(h, params["router"], 2)
+    _close(sown["router_probs"], probs, "probabilities")
+    np.testing.assert_array_equal(np.sort(np.asarray(sown["experts"]), -1),
+                                  np.sort(np.asarray(experts), -1))
+    plain, _ = layer.apply({"params": params}, h.reshape(4, 12, 16))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(plain))
 
 
-def test_moe_mlp_forward_matches_manual_expert():
-    """Full-capacity MoE output equals routing each token through its
-    argmax expert's FFN scaled by the gate value."""
-    rs = np.random.RandomState(2)
-    x = jnp.asarray(rs.randn(2, 4, 8).astype(np.float32))
-    moe = MoEMlp(n_experts=2, d_ff=16, capacity_factor=2.0,
-                 dtype=jnp.float32)
-    vars_ = moe.init(jax.random.PRNGKey(3), x)
-    out, aux = moe.apply(vars_, x)
-    assert out.shape == x.shape and np.isfinite(np.asarray(out)).all()
+def test_router_drops_nothing_when_every_token_wants_one_expert():
+    """The case a capacity would cut: identical tokens all choose the
+    same expert, whose group is then every row; the output is that
+    expert's for every token."""
+    layer, params, _ = _layer(4, 1, skewed=False)
+    h = jnp.ones((24, 16), jnp.float32)
+    out, _ = layer.apply({"params": params}, h)
+    experts, weights, _, _, sizes, *_ = moe.moe_route(h, params["router"], 1)
+    e = int(experts[0, 0])
+    assert sorted(np.asarray(sizes)) == [0, 0, 0, 24]
+    want = float(weights[0, 0]) * (
+        jax.nn.silu(h @ params["gate"][e]) * (h @ params["up"][e])
+        @ params["down"][e])
+    _close(out, want, "output")
 
-    params = vars_["params"]
-    logits = np.asarray(x, np.float32) @ np.asarray(
-        params["router_block"]["router"]["kernel"], np.float32)
-    gates = jax.nn.softmax(jnp.asarray(logits), axis=-1)
-    idx = np.argmax(np.asarray(gates), axis=-1)
-    wi = np.asarray(params["wi"], np.float32)
-    wo = np.asarray(params["wo"], np.float32)
-    expect = np.zeros_like(np.asarray(x))
-    for b in range(x.shape[0]):
-        for s in range(x.shape[1]):
-            e = idx[b, s]
-            h = np.asarray(jax.nn.gelu(
-                jnp.asarray(np.asarray(x)[b, s] @ wi[e])))
-            expect[b, s] = (h @ wo[e]) * float(gates[b, s, e])
-    np.testing.assert_allclose(np.asarray(out), expect, atol=1e-4)
+
+def test_dispatch_and_combine_are_inverse_gathers():
+    """``combine(dispatch(x))`` with unit weights is ``k x``, in value
+    and in gradient, and the compiled gradient of the pair holds no
+    scatter: the transpose of each gather is written as the gather by
+    the inverse permutation."""
+    k = 4
+    _, params, h = _layer(8, k, skewed=False)
+    _, _, order, inverse, *_ = moe.moe_route(h, params["router"], k)
+    ones = jnp.ones((h.shape[0], k), jnp.float32)
+
+    def pair(x):
+        return moe.moe_combine(moe.moe_dispatch(x, order, inverse, k),
+                               ones, order, inverse)
+
+    np.testing.assert_allclose(np.asarray(pair(h)), k * np.asarray(h),
+                               rtol=1e-6)
+    cot = jax.random.normal(jax.random.key(3), h.shape)
+    grad = jax.jit(jax.grad(lambda x: jnp.sum(pair(x) * cot)))
+    np.testing.assert_allclose(np.asarray(grad(h)), k * np.asarray(cot),
+                               rtol=1e-6)
+    assert not _scatters(grad.lower(h).compile())
+
+
+def test_layer_gradient_program_scatters_no_row():
+    """The whole layer: routing weights, counts, dispatch, products and
+    combine differentiate without a scatter-add over rows or weights.
+    What is left is the grouped product's own bookkeeping (megablox's
+    ``make_group_metadata``: which tile belongs to which group), over a
+    vector of groups + tiles integers."""
+    n_experts, k = 8, 2
+    layer, params, h = _layer(n_experts, k, skewed=False)
+
+    def loss(params, h):
+        out, aux = layer.apply({"params": params}, h)
+        return jnp.sum(out ** 2) + aux["load_balance"] + aux["router_z"]
+
+    found = _scatters(jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, h).compile())
+    for elements, name in found:
+        assert "/jit(gmm)/" in name or "/jit(tgmm)/" in name, name
+        assert elements <= n_experts + h.shape[0] * k, (elements, name)
+
+
+def test_traced_layers_are_counted():
+    from horovod_tpu import metrics
+
+    def count():
+        m = metrics.registry().get("hvt_moe_layers_traced_total")
+        return m.labels(experts="8", top_k="2",
+                        product=moe.PRODUCT).value if m else 0.0
+
+    layer, params, h = _layer(8, 2, skewed=False)
+    before = count()
+    jax.jit(lambda p, h: layer.apply({"params": p}, h)[0]).lower(params, h)
+    assert count() == before + 1
 
 
 def test_moe_compiles_on_dp_ep_mesh():
     """dp=2 × ep=4: tokens batch-sharded, experts ep-sharded; the jitted
-    step must compile and run (XLA emits the dispatch all-to-alls)."""
+    step must compile and run, and agree with the reference."""
     mesh = make_parallel_mesh(dp=2, ep=4)
-    moe = MoEMlp(n_experts=4, d_ff=32, dtype=jnp.float32)
+    moe_layer = MoEMlp(n_experts=4, d_ff=32, experts_per_token=2,
+                       dtype=jnp.float32)
     x = jnp.asarray(np.random.RandomState(4).randn(4, 16, 8)
                     .astype(np.float32))
-    vars_ = moe.init(jax.random.PRNGKey(5), x)
-    pspecs = moe_param_partition_spec(vars_["params"])
+    vars_ = moe_layer.init(jax.random.PRNGKey(5), x)
+    plain = jax.tree.map(lambda w: w * 20.0, vars_["params"])
+    pspecs = moe_param_partition_spec(plain)
     params = jax.tree.map(
         lambda v, s: jax.device_put(v, NamedSharding(mesh, s)),
-        vars_["params"], pspecs, is_leaf=lambda v: isinstance(v, P))
-    x = jax.device_put(x, NamedSharding(mesh, P("dp")))
+        plain, pspecs, is_leaf=lambda v: isinstance(v, P))
+    assert "ep" in str(params["gate"].sharding.spec)
+    x_sharded = jax.device_put(x, NamedSharding(mesh, P("dp")))
 
     @jax.jit
     def step(params, x):
-        out, aux = moe.apply({"params": params}, x)
-        return out.sum() + 0.01 * aux
+        out, aux = moe_layer.apply({"params": params}, x)
+        return out.sum() + 0.01 * aux["load_balance"]
 
-    # grads too: EP backward = reverse all-to-alls
-    val, grads = jax.value_and_grad(
-        lambda p: step(p, x))(params)
+    # grads too: EP backward = the reverse exchanges
+    val, grads = jax.value_and_grad(step)(params, x_sharded)
     jax.block_until_ready(val)
-    assert np.isfinite(float(val))
-    assert all(np.isfinite(np.asarray(g)).all()
-               for g in jax.tree.leaves(grads))
-    # expert weights keep their ep sharding through the step
-    assert "ep" in str(grads["wi"].sharding.spec)
+
+    def plain_step(params, x):
+        out, load_balance, _, _ = reference.experts_layer(
+            x.reshape(-1, x.shape[-1]), params, 2)
+        return out.sum() + 0.01 * load_balance
+
+    want, want_grads = jax.value_and_grad(plain_step)(plain, x)
+    _close(val, want, "loss")
+    for name in ("router", "gate", "up", "down"):
+        _close(grads[name], want_grads[name], f"d {name}")
