@@ -12,9 +12,11 @@ profiler trace and prints one JSON line: the device milliseconds a call of
 iterations, read from the trace by the kernels' names), the wall-clock
 milliseconds of the whole call, and how far its results are from the first
 tile's (relative L2). ``--einsum`` adds the einsum attention of
-``models/transformer.py`` at the same shape, wall clock only: the evidence
-for where ``FLASH_AUTO_THRESHOLD`` belongs. ``--source FILE`` times another
-copy of ``ops/flash_attention.py`` (the parent commit's) instead.
+``models/transformer.py`` at the same shape, wall clock only, and alone: inside
+a training step XLA schedules it otherwise (PERF.md section 6, PR 27: 7.82
+ms here, 4.70 in the step), so it does not say where ``"auto"``'s crossover
+belongs; the cells do. ``--source FILE`` times another copy of
+``ops/flash_attention.py`` (the parent commit's) instead.
 
 A microbenchmark, not the yardstick: the cell that decides is
 ``gpt2l-s4096`` of ``BENCHMARK.json``. It refuses to run without a TPU.

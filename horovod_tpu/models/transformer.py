@@ -51,16 +51,19 @@ class GPTConfig:
     dtype: jnp.dtype = jnp.bfloat16
     remat: bool = False  # jax.checkpoint each block (HBM ↔ FLOPs trade)
     # Attention implementation. False (default) = einsum-softmax; True =
-    # pallas flash kernel; "auto" = pick per sequence length from the
-    # crossover in ops/flash_attention.py (einsum up to 2048, flash
-    # beyond; captured by an earlier builder on another rig, not
-    # reproduced). "auto" only upgrades to flash on a TPU backend (on
-    # the CPU the kernel runs in pallas interpret mode, far slower than
-    # einsum). True needs sequence blocks that are multiples of 8
-    # wherever the kernel is compiled. Flash requires the LOCAL
-    # sequence to be the full, contiguous sequence (its causal mask is
-    # positional-by-block): under plain GSPMD sequence parallelism the
-    # trace-time shape cannot reveal the sharding, so neither True nor
+    # pallas flash kernel; "auto" = pick per local sequence length by
+    # the rule in ops/flash_attention.py (resolve_flash): the kernels
+    # from 1024 positions up where the length is a multiple of 128, the
+    # crossover measured in the benchmark's cells on a v5e (PERF.md
+    # section 6, PR 27), einsum for every other length. "auto" only
+    # upgrades to flash on a TPU backend (on the CPU the kernel runs in
+    # pallas interpret mode, far slower than einsum). True needs
+    # sequence blocks that are multiples of 8 wherever the kernel is
+    # compiled; "auto" never asks for one that is not. Flash requires
+    # the LOCAL sequence to be the full, contiguous sequence (its causal
+    # mask is positional-by-block): under plain GSPMD sequence
+    # parallelism the trace-time shape cannot reveal the sharding, so
+    # neither True nor
     # "auto" is safe there — keep False, or use ring_mesh, where flash
     # composes with SP correctly (the ring schedule owns the blocks and
     # "auto" decides by the per-shard block length).

@@ -26,6 +26,7 @@ from __future__ import annotations
 import functools
 import math
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -37,17 +38,25 @@ _NEG_INF = -1e30
 _LANES = 128  # TPU vector lane width: scratch statistics are stored
               # broadcast across a full lane tile
 
-# Crossover captured by an earlier builder on another rig, not
-# reproduced: einsum ahead at seq<=2048, flash from 4096 up
-FLASH_AUTO_THRESHOLD = 2048
+# The shortest sequence at which the kernels were measured to beat the
+# einsum path inside a training step: gpt2-large's 8192 tokens a step on
+# a v5e, run both ways as the benchmark's cell runs them, not in a
+# microbenchmark (whose einsum figure is 1.7x the step's). At 16 x 512
+# einsum is ahead by 8.7%; at 8 x 1024 the kernels by 17.7%, at 4 x 2048
+# by 50% (PERF.md section 6, PR 27).
+_AUTO_FROM = 1024
 
 
 def resolve_flash(use_flash, local_seq) -> bool:
     """Resolve a ``use_flash`` policy ("auto" | bool) for a given LOCAL
     sequence length (a static trace-time shape, so the choice compiles
-    away). "auto" upgrades to flash only on a real TPU backend — the
-    crossover was measured there, and off-TPU the kernel runs in pallas
-    interpret mode, far slower than einsum.
+    away). "auto" takes the kernels where all of what it can observe
+    says they win: a TPU backend (elsewhere the kernel is interpreted,
+    far slower than einsum), a sequence of at least ``_AUTO_FROM``
+    positions, and one that takes a proper score tile, a multiple of
+    128 (any other length clips the blocks to a few rows, or to fewer
+    than Mosaic compiles). Every other length stays on einsum, so
+    "auto" never raises for a length einsum serves.
 
     ``local_seq`` must be the length the attention actually runs over:
     the global length on a single device, the per-shard block length
@@ -59,7 +68,7 @@ def resolve_flash(use_flash, local_seq) -> bool:
             raise ValueError(
                 f"use_flash must be True, False, or 'auto'; got "
                 f"{use_flash!r}")
-        return (local_seq > FLASH_AUTO_THRESHOLD
+        return (local_seq >= _AUTO_FROM and local_seq % _LANES == 0
                 and jax.default_backend() == "tpu")
     return bool(use_flash)
 
@@ -353,7 +362,7 @@ def _compiler_params(kernel, block_q, block_k, d, itemsize, tile):
     return pltpu.CompilerParams(vmem_limit_bytes=need + need // 2)
 
 
-def _derive_tile(kernel, s, d, itemsize):
+def _derive_tile(kernel, s, d, itemsize, causal):
     """The score tile of ``kernel`` ("fwd", "dq", "dkv") for a sequence of
     ``s`` positions: the largest (block_q, block_k) — multiples of 128
     that divide ``s``, one dividing the other so the streamed tile is
@@ -361,12 +370,30 @@ def _derive_tile(kernel, s, d, itemsize):
     fit ``_SCOPED_VMEM``; of equal areas the wider key block. A sequence
     that is no multiple of 128 gets the one block the old default gave,
     and so does one whose streamed tiles alone overflow (wide or f32
-    operands: HVT_FLASH_SEQ_TILE is the knob for those, as before)."""
+    operands: HVT_FLASH_SEQ_TILE is the knob for those, as before).
+
+    A causal sequence no longer than the preferred tile's long side
+    (1024) leaves that tile one sub-block a grid step, so the skipping of
+    sub-blocks above the diagonal never happens, and what the chip
+    prefers there differs by kernel (v5e, 8 x 20 heads x 1024 x 64 and 2
+    x 16 x 1024 x 128, PERF.md section 6, PR 27). The forward's passes
+    cost by their rows whatever they skip, so narrower key blocks lose;
+    with the one key block every query block goes through whole, a split
+    of the queries only adds grid steps: it takes the sequence whole
+    (1024 x 1024, 15% under 512 x 1024). dK/dV's cost by their area: it
+    halves its query sub-block (512 x 512, 10% under 1024 x 512), so that
+    the second K block skips the half no query of which sees it. dQ's
+    512 x 512 already skips a quarter."""
     if s % _LANES:
         block = _blocks(s, _LANES)
         return block, block
     cap = _DKV_TILE_CAP if kernel == "dkv" else None
     most_q, most_k = _PREFERRED_TILE[kernel]
+    if causal and kernel == "fwd" and s <= most_k:
+        most_q = s
+    if (causal and kernel == "dkv" and most_k < s <= most_q
+            and s % (2 * _LANES) == 0):
+        most_q = s // 2
     sizes = [b for b in range(_LANES, s + 1, _LANES) if s % b == 0]
     fit = [(bq, bk) for bq in sizes if bq <= most_q
            for bk in sizes if bk <= most_k
@@ -377,13 +404,13 @@ def _derive_tile(kernel, s, d, itemsize):
                default=(_LANES, _LANES))
 
 
-def _score_tile(kernel, s, d, itemsize, block_q, block_k):
+def _score_tile(kernel, s, d, itemsize, causal, block_q, block_k):
     """``(block_q, block_k, derived)`` for one of the three kernels: an
     explicit integer is honoured as ever (clipped to divide ``s``);
     ``None`` takes that side of the tile derived from the shape."""
     derived = block_q is None or block_k is None
     if derived:
-        auto_q, auto_k = _derive_tile(kernel, s, d, itemsize)
+        auto_q, auto_k = _derive_tile(kernel, s, d, itemsize, causal)
     bq = auto_q if block_q is None else _blocks(s, block_q)
     bk = auto_k if block_k is None else _blocks(s, block_k)
     return bq, bk, derived
@@ -463,11 +490,56 @@ def _seq_tile(s, block_q, block_k, cap=None):
     return best
 
 
+def _out(shape, dtype, *operands):
+    """A kernel's output: under ``jax.shard_map`` it varies over every
+    mesh axis an operand varies over (``check_vma``, the default there,
+    refuses an output that does not say)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+class _Plan(NamedTuple):
+    """All a kernel call is built from besides its operands' shapes."""
+    scale: float
+    causal: bool
+    block_q: int
+    block_k: int
+    derived: bool       # the rule chose the tile, not the caller
+    tile: int           # positions of the streamed operand a grid step
+    interpret: bool
+
+
+def _plan(kernel, q, scale, causal, block_q, block_k):
+    """Made outside the jitted calls below, so that what the process
+    holds besides the operands (HVT_FLASH_SEQ_TILE, the backend) is part
+    of their cache's key and never read under a cached trace."""
+    _, _, s, d = q.shape
+    block_q, block_k, derived = _score_tile(
+        kernel, s, d, q.dtype.itemsize, causal, block_q, block_k)
+    # The dkv tile is capped independently of the fwd/dq tile: that
+    # kernel streams Q AND dO tiles together and was the one that blew
+    # scoped VMEM at tile 8192 (see _DKV_TILE_CAP).
+    tile = _seq_tile(s, block_q, block_k,
+                     _DKV_TILE_CAP if kernel == "dkv" else None)
+    return _Plan(scale, causal, block_q, block_k, derived, tile,
+                 _interpret())
+
+
 def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype):
+    return _fwd_call(q, k, v, out_dtype=out_dtype,
+                     plan=_plan("fwd", q, scale, causal, block_q, block_k))
+
+
+# Each of the three calls is a ``jax.jit`` of its own: a model's layers
+# then share one trace and one lowered function a kernel (XLA inlines the
+# calls, so the compiled program is the same), where 36 layers' kernels
+# traced and lowered one by one were 20 s of every start (PERF.md section
+# 6, PR 27).
+@functools.partial(jax.jit, static_argnames=("plan", "out_dtype"))
+def _fwd_call(q, k, v, *, plan, out_dtype):
     b, h, s, d = q.shape
-    block_q, block_k, derived = _score_tile("fwd", s, d, q.dtype.itemsize,
-                                            block_q, block_k)
-    _count_trace("fwd", block_q, block_k, derived)
+    block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
+    _count_trace("fwd", block_q, block_k, plan.derived)
     # Grouped-query attention is served ZERO-COPY: query head hi reads
     # K/V head hi // group through the block index map — no repeat
     # materialization, and the shared K/V tile stays VMEM-resident
@@ -475,28 +547,27 @@ def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype):
     group = h // k.shape[1]
     # K/V stream through the grid's sequential LAST axis in VMEM tiles;
     # scratch accumulators carry the online softmax across tiles
-    tile = _seq_tile(s, block_q, block_k)
     grid = (b, h, s // block_q, s // tile)
     qspec = pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, hi, qi, ti: (bi, hi, qi, 0))
     kvspec = pl.BlockSpec((1, 1, tile, d),
                           lambda bi, hi, qi, ti: (bi, hi // group, ti, 0))
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          block_k=block_k),
+        functools.partial(_fwd_kernel, scale=plan.scale,
+                          causal=plan.causal, block_k=block_k),
         grid=grid,
         in_specs=[qspec, kvspec, kvspec],
         out_specs=[qspec,
                    pl.BlockSpec((1, 1, block_q, 1),
                                 lambda bi, hi, qi, ti: (bi, hi, qi, 0))],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, out_dtype),
-                   jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32)],
+        out_shape=[_out(q.shape, out_dtype, q, k, v),
+                   _out((b, h, s, 1), jnp.float32, q, k, v)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
         compiler_params=_compiler_params("fwd", block_q, block_k, d,
                                          q.dtype.itemsize, tile),
-        interpret=_interpret(),
+        interpret=plan.interpret,
         name="hvt_flash_fwd",
     )(q, k, v)
     return o, lse
@@ -515,21 +586,22 @@ def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
                     axis=-1, keepdims=True)        # [B, H, S, 1]
     # lse cotangent: ds gains + P∘dlse, i.e. delta shifts by −dlse
     delta = delta - dlse.astype(jnp.float32)
-    dq = _dq_call(q, k, v, do, lse, delta, scale, causal, block_q, block_k)
-    dk, dv = _dkv_call(q, k, v, do, lse, delta, scale, causal, block_q,
-                       block_k)
+    dq = _dq_call(q, k, v, do, lse, delta,
+                  plan=_plan("dq", q, scale, causal, block_q, block_k))
+    dk, dv = _dkv_call(q, k, v, do, lse, delta,
+                       plan=_plan("dkv", q, scale, causal, block_q,
+                                  block_k))
     return dq, dk, dv
 
 
-def _dq_call(q, k, v, do, lse, delta, scale, causal, block_q, block_k):
+@functools.partial(jax.jit, static_argnames="plan")
+def _dq_call(q, k, v, do, lse, delta, *, plan):
     """dq: grid (b, h, qi, ti) — K/V tiles stream past each Q block.
     GQA reads the shared K/V head zero-copy via the index map."""
     b, h, s, d = q.shape
-    block_q, block_k, derived = _score_tile("dq", s, d, q.dtype.itemsize,
-                                            block_q, block_k)
-    _count_trace("dq", block_q, block_k, derived)
+    block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
+    _count_trace("dq", block_q, block_k, plan.derived)
     group = h // k.shape[1]
-    tile = _seq_tile(s, block_q, block_k)
     q_by_qi = pl.BlockSpec((1, 1, block_q, d),
                            lambda bi, hi, qi, ti: (bi, hi, qi, 0))
     kv_tile = pl.BlockSpec((1, 1, tile, d),
@@ -537,36 +609,32 @@ def _dq_call(q, k, v, do, lse, delta, scale, causal, block_q, block_k):
     vec_by_qi = pl.BlockSpec((1, 1, block_q, 1),
                              lambda bi, hi, qi, ti: (bi, hi, qi, 0))
     return pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_k=block_k),
+        functools.partial(_dq_kernel, scale=plan.scale,
+                          causal=plan.causal, block_k=block_k),
         grid=(b, h, s // block_q, s // tile),
         in_specs=[q_by_qi, kv_tile, kv_tile, q_by_qi, vec_by_qi,
                   vec_by_qi],
         out_specs=q_by_qi,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=_out(q.shape, q.dtype, q, k, v, do, lse, delta),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_compiler_params("dq", block_q, block_k, d,
                                          q.dtype.itemsize, tile),
-        interpret=_interpret(),
+        interpret=plan.interpret,
         name="hvt_flash_dq",
     )(q, k, v, do, lse, delta)
 
 
-def _dkv_call(q, k, v, do, lse, delta, scale, causal, block_q, block_k):
+@functools.partial(jax.jit, static_argnames="plan")
+def _dkv_call(q, k, v, do, lse, delta, *, plan):
     """dk/dv: grid (b, h, ki, ti) — Q/dO/lse/delta tiles stream past
     each K/V block (the reduction axis must be LAST). Under GQA the
     kernel still reads the shared K/V head zero-copy but emits
     per-QUERY-head gradients (full h), which are then group-summed —
     each K/V head's gradient is the sum over its query group."""
     b, h, s, d = q.shape
-    block_q, block_k, derived = _score_tile("dkv", s, d, q.dtype.itemsize,
-                                            block_q, block_k)
-    _count_trace("dkv", block_q, block_k, derived)
+    block_q, block_k, dkv_tile = plan.block_q, plan.block_k, plan.tile
+    _count_trace("dkv", block_q, block_k, plan.derived)
     group = h // k.shape[1]
-    # The dkv tile is capped independently of the fwd/dq tile: this
-    # kernel streams Q AND dO tiles together and was the one that blew
-    # scoped VMEM at tile 8192 (see _DKV_TILE_CAP).
-    dkv_tile = _seq_tile(s, block_q, block_k, cap=_DKV_TILE_CAP)
     kv_in_ki = pl.BlockSpec((1, 1, block_k, d),
                             lambda bi, hi, ki, ti: (bi, hi // group, ki, 0))
     dkv_out_ki = pl.BlockSpec((1, 1, block_k, d),
@@ -576,19 +644,19 @@ def _dkv_call(q, k, v, do, lse, delta, scale, causal, block_q, block_k):
     vec_tile = pl.BlockSpec((1, 1, dkv_tile, 1),
                             lambda bi, hi, ki, ti: (bi, hi, ti, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q),
+        functools.partial(_dkv_kernel, scale=plan.scale,
+                          causal=plan.causal, block_q=block_q),
         grid=(b, h, s // block_k, s // dkv_tile),
         in_specs=[kv_in_ki, kv_in_ki, q_tile, q_tile, vec_tile,
                   vec_tile],
         out_specs=[dkv_out_ki, dkv_out_ki],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, k.dtype),
-                   jax.ShapeDtypeStruct(q.shape, v.dtype)],
+        out_shape=[_out(q.shape, k.dtype, q, k, v, do, lse, delta),
+                   _out(q.shape, v.dtype, q, k, v, do, lse, delta)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=_compiler_params("dkv", block_q, block_k, d,
                                          q.dtype.itemsize, dkv_tile),
-        interpret=_interpret(),
+        interpret=plan.interpret,
         name="hvt_flash_dkv",
     )(k, v, q, do, lse, delta)
     if group > 1:
@@ -646,7 +714,8 @@ def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
             f"({h_kv})")
     if scale is None:
         scale = d ** -0.5
-    bq, bk, _ = _score_tile("fwd", s, d, q.dtype.itemsize, block_q, block_k)
+    bq, bk, _ = _score_tile("fwd", s, d, q.dtype.itemsize, causal,
+                             block_q, block_k)
     if not _interpret() and (bq % 8 or bk % 8):
         # Mosaic refuses the kernel ("cannot statically prove that index
         # in dimension 2 is a multiple of 8"); the interpreter has no

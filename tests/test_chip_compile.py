@@ -69,15 +69,20 @@ def _qkv(shape, sharding):
 
 
 # (batch, seq, heads, kv_heads, head_dim): the shapes bench.py's GPT and
-# chip_smoke.py run, the benchmark cell gpt2l-s4096's own, one
-# grouped-query shape, the 4-chip ring, and one sequence whose clipped
-# block Mosaic refuses. No blocks are passed: each kernel's score tile is
+# chip_smoke.py run, the benchmark cells gpt2l-s1024's, gpt2l-s4096's and
+# olmoe-s4096's own, gpt2l-dp4's (four chips' batch under a shard_map
+# that checks vma), one grouped-query shape, the 4-chip ring, and one
+# sequence whose clipped block Mosaic refuses. No blocks are passed: each
+# kernel's score tile is
 # the one derived from the shape
 @pytest.mark.parametrize("kind,shape", [
     pytest.param("flash", (8, 1024, 12, 12, 64), id="flash-8x1024"),
     pytest.param("flash", (2, 4096, 12, 12, 64), id="flash-2x4096"),
     pytest.param("flash", (1, 8192, 12, 12, 64), id="flash-1x8192"),
+    pytest.param("flash", (8, 1024, 20, 20, 64), id="flash-gpt2l-s1024"),
     pytest.param("flash", (2, 4096, 20, 20, 64), id="flash-gpt2l-s4096"),
+    pytest.param("shard_map", (32, 1024, 20, 20, 64),
+                 id="flash-gpt2l-dp4-shard-map"),
     pytest.param("flash", (1, 4096, 32, 8, 128), id="flash-gqa-32-8-128"),
     pytest.param("flash", (2, 4096, 16, 16, 128), id="flash-olmoe-s4096"),
     pytest.param("ring", (1, 16384, 12, 12, 64), id="ring-sp4-16384"),
@@ -97,6 +102,16 @@ def test_main_path_kernel_compiles_for_v5e(kind, shape, compiled_kernel,
         step = _loss(lambda q, k, v: fa.flash_attention(q, k, v,
                                                         causal=True))
         args = _qkv(shape, SingleDeviceSharding(devices[0]))
+    elif kind == "shard_map":
+        mesh = Mesh(np.array(devices), ("world",))
+        rows = P("world")
+        per_chip = _loss(lambda q, k, v: fa.flash_attention(q, k, v,
+                                                            causal=True))
+        step = jax.jit(jax.shard_map(
+            lambda q, k, v: jax.tree.map(
+                lambda x: jax.lax.pmean(x, "world"), per_chip(q, k, v)),
+            mesh=mesh, in_specs=(rows,) * 3, out_specs=P()))
+        args = _qkv(shape, NamedSharding(mesh, rows))
     else:
         mesh = Mesh(np.array(devices), ("sp",))
         step = _loss(lambda q, k, v: ring_attention(
@@ -106,6 +121,34 @@ def test_main_path_kernel_compiles_for_v5e(kind, shape, compiled_kernel,
     assert "tpu_custom_call" in text
     if kind == "ring":
         assert "collective-permute" in text
+
+
+def test_layers_share_one_lowered_kernel(compiled_kernel, v5e_devices):
+    """Each kernel is traced and lowered once a program: a two-layer
+    ``models.GPT`` training step holds as many ``tpu_custom_call`` sites
+    as a one-layer step (the forward's, the recomputed forward's, dQ's
+    and dK/dV's), each called once a layer. Lowered, not compiled."""
+    from horovod_tpu.models import GPT, GPTConfig
+
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def sites(n_layers):
+        model = GPT(GPTConfig(
+            vocab_size=512, n_layers=n_layers, d_model=128, n_heads=2,
+            d_ff=256, max_seq_len=1024, remat=True, use_flash=True))
+        tokens = jax.ShapeDtypeStruct((2, 1024), jnp.int32,
+                                      sharding=one_chip)
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(model.init, jax.random.key(0), tokens))
+        loss = lambda p, t: model.apply(p, t).astype(jnp.float32).mean()
+        text = jax.jit(jax.grad(loss)).lower(params, tokens).as_text()
+        return text.count("tpu_custom_call"), text.count("call @")
+
+    (one, calls_one), (two, calls_two) = sites(1), sites(2)
+    assert one == two == 4, (one, two)
+    assert calls_two > calls_one
 
 
 def test_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices):

@@ -374,36 +374,100 @@ def test_score_tiles_match_the_f32_formula(s, h, h_kv, causal, block_q,
                                    rtol=2e-4, atol=2e-4, err_msg=name)
 
 
+@pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("d", [64, 128])
 @pytest.mark.parametrize("s", [1024, 1536, 2048, 4096, 8192, 16384])
 @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
 def test_derived_tile_divides_the_sequence_and_fits_the_budget(kernel, s,
-                                                               d):
+                                                               d, causal):
     from horovod_tpu.ops import flash_attention as fa
 
     for itemsize in (2, 4):
-        bq, bk = fa._derive_tile(kernel, s, d, itemsize)
+        bq, bk = fa._derive_tile(kernel, s, d, itemsize, causal)
         assert s % bq == 0 and s % bk == 0, (bq, bk)
         assert bq % 128 == 0 and bk % 128 == 0, (bq, bk)
         assert max(bq, bk) % min(bq, bk) == 0, (bq, bk)
         most_q, most_k = fa._PREFERRED_TILE[kernel]
-        assert bq <= most_q and bk <= most_k, (bq, bk)
+        assert bq <= max(most_q, s if causal else 0) and bk <= most_k
         tile = fa._seq_tile(s, bq, bk,
                             fa._DKV_TILE_CAP if kernel == "dkv" else None)
         assert tile % bq == 0 and tile % bk == 0 and tile <= 4096
         # inside the budget, or the old 128 x 128 when nothing is
         assert (fa._vmem_bytes(kernel, bq, bk, d, itemsize, tile)
                 <= fa._SCOPED_VMEM or (bq, bk) == (128, 128))
-        # bf16 gets the preferred tile wherever the sequence allows it
-        if itemsize == 2 and s % 1024 == 0:
+        # bf16 gets the preferred tile wherever the sequence allows it,
+        # but a causal sequence of one preferred tile (the test below)
+        if itemsize == 2 and s % 1024 == 0 and not (causal and s == 1024):
             assert (bq, bk) == (most_q, most_k)
         # an explicit block is honoured as before, derived or not beside it
-        assert fa._score_tile(kernel, s, d, itemsize, 32, 384) == (
+        assert fa._score_tile(kernel, s, d, itemsize, causal, 32, 384) == (
             32, fa._blocks(s, 384), False)
-        assert fa._score_tile(kernel, s, d, itemsize, None, 128) == (
-            bq, 128, True)
-        assert fa._score_tile(kernel, s, d, itemsize, None, None) == (
-            bq, bk, True)
+        assert fa._score_tile(kernel, s, d, itemsize, causal, None,
+                              128) == (bq, 128, True)
+        assert fa._score_tile(kernel, s, d, itemsize, causal, None,
+                              None) == (bq, bk, True)
+
+
+def _visited(kernel, s, block_q, block_k):
+    """Score sub-blocks of the [s, s] square a causal call of ``kernel``
+    passes through, as the kernels skip (``_causal_n_eff``; ``start``)."""
+    n_q, n_k = s // block_q, s // block_k
+    if kernel == "dkv":
+        return sum(n_q - ki * block_k // block_q for ki in range(n_k))
+    return sum(min(-(-(qi + 1) * block_q // block_k), n_k)
+               for qi in range(n_q))
+
+
+# what the v5e chose (PERF.md section 6, PR 27) as (block_q, block_k,
+# sub-blocks visited); 4096 and 8192 are PR 25's
+_PR25 = {"fwd": (512, 1024), "dq": (512, 512), "dkv": (1024, 512)}
+_CAUSAL_TILES = {
+    ("fwd", 1024): (1024, 1024, 1),     # whole: a forward pass costs by
+    ("dq", 1024): (512, 512, 3),        # its rows whatever it skips
+    ("dkv", 1024): (512, 512, 3),       # (PR 25's 1024 x 512: 2 of 2)
+    ("fwd", 2048): (512, 1024, 6),
+    ("dq", 2048): (512, 512, 10),
+    ("dkv", 2048): (1024, 512, 6),
+}
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [1024, 2048, 4096, 8192])
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_derived_tile_is_the_one_the_chip_chose(kernel, s, d, causal):
+    from horovod_tpu.ops import flash_attention as fa
+
+    got = fa._derive_tile(kernel, s, d, 2, causal)
+    if not causal or s >= 4096:
+        assert got == _PR25[kernel]
+        return
+    bq, bk, visited = _CAUSAL_TILES[kernel, s]
+    assert got == (bq, bk)
+    assert _visited(kernel, s, bq, bk) == visited
+    # less than the square of sub-blocks, but for the forward's one
+    if (kernel, s) != ("fwd", 1024):
+        assert visited < (s // bq) * (s // bk)
+
+
+@pytest.mark.parametrize("s", [1024, 2048])
+def test_short_causal_tiles_match_the_einsum_path(s):
+    """Value and gradients through every tile the rule returns for a
+    short causal sequence (each kernel its own) against the einsum
+    formula, interpreted."""
+    q, k, v = _qkv(b=1, s=s, h=1, d=64, seed=3)
+    w = jnp.asarray(np.random.RandomState(4).randn(*q.shape), jnp.float32)
+
+    def run(attend):
+        return jax.value_and_grad(
+            lambda q, k, v: (attend(q, k, v) * w).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    got = run(lambda q, k, v: flash_attention(q, k, v, causal=True))
+    want = run(lambda q, k, v: _dense(q, k, v, True))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("s,want", [(64, 64), (96, 96), (192, 64),
@@ -413,7 +477,9 @@ def test_derived_tile_of_a_sequence_that_is_no_multiple_of_128(s, want):
     from horovod_tpu.ops import flash_attention as fa
 
     for kernel in ("fwd", "dq", "dkv"):
-        assert fa._derive_tile(kernel, s, 64, 2) == (want, want)
+        for causal in (True, False):
+            assert fa._derive_tile(kernel, s, 64, 2, causal) == (
+                want, want)
 
 
 def _trace_count(**labels):
@@ -433,20 +499,26 @@ def test_trace_counter_carries_the_tile_and_who_chose_it():
         out = []
         for kern in kernels:
             bq, bk = (fa._derive_tile(kern, 256, q.shape[-1],
-                                      q.dtype.itemsize)
+                                      q.dtype.itemsize, True)
                       if derived else (32, 64))
             out.append(_trace_count(kernel=kern, block_q=str(bq),
                                     block_k=str(bk),
                                     derived=str(int(derived))))
         return out
 
+    # two layers of one shape: each kernel is a jit of its own, traced
+    # once for both (and once a process, hence the cleared caches)
     trace = lambda **kw: jax.make_jaxpr(jax.grad(
-        lambda q, k, v: fa.flash_attention(q, k, v, **kw).sum(),
+        lambda q, k, v: fa.flash_attention(
+            fa.flash_attention(q, k, v, **kw), k, v, **kw).sum(),
         (0, 1, 2)))(q, k, v)
+    jax.clear_caches()
     explicit, derived = counts(False), counts(True)
     trace(block_q=32, block_k=64)
     assert counts(False) == [n + 1 for n in explicit]
     assert counts(True) == derived
+    trace()
+    assert counts(True) == [n + 1 for n in derived]
     trace()
     assert counts(True) == [n + 1 for n in derived]
     assert counts(False) == [n + 1 for n in explicit]
@@ -459,8 +531,14 @@ def test_trace_counter_adds_nothing_to_the_program(monkeypatch):
     lowered = lambda: jax.jit(jax.grad(
         lambda q, k, v: fa.flash_attention(q, k, v).sum(),
         (0, 1, 2))).lower(q, k, v).as_text()
+    jax.clear_caches()
+    before = _trace_count(kernel="fwd", block_q="128", block_k="128",
+                          derived="1")
     counted = lowered()
+    assert _trace_count(kernel="fwd", block_q="128", block_k="128",
+                        derived="1") == before + 1
     monkeypatch.setattr(fa, "_count_trace", lambda *a: None)
+    jax.clear_caches()      # or the kernels' cached traces are served
     assert lowered() == counted
 
 
@@ -476,3 +554,31 @@ def test_scoped_vmem_is_raised_only_where_the_estimate_nears_it():
     limit = fa._compiler_params("dkv", 1024, 512, 64, 2,
                                 4096).vmem_limit_bytes
     assert need <= fa._SCOPED_VMEM < limit and limit >= need * 1.5
+
+
+def test_kernels_trace_under_shard_map_with_check_vma():
+    """Value and gradients inside ``jax.shard_map`` as it is called by
+    default (``check_vma=True`` wants every ``pallas_call`` output to say
+    which mesh axes it varies over) equal the unsharded call's."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("world",))
+    q, k, v = _qkv(b=4, s=128, h=2)
+
+    def value_and_grads(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: (flash_attention(q, k, v, causal=True)
+                             ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    def per_chip(q, k, v):
+        value, grads = value_and_grads(q, k, v)
+        return jax.lax.psum(value, "world"), grads
+
+    rows = P("world")
+    got = jax.jit(jax.shard_map(
+        per_chip, mesh=mesh, in_specs=(rows,) * 3,
+        out_specs=(P(), (rows,) * 3)))(q, k, v)
+    want = value_and_grads(q, k, v)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)

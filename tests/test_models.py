@@ -393,10 +393,11 @@ def test_gpt_ring_mesh_matches_plain(use_flash):
 
 def test_gpt_use_flash_auto_resolves_by_sequence_length(monkeypatch):
     """use_flash="auto" (opt-in; the default stays False) picks the
-    measured winner per sequence length: einsum at/below the 2048 crossover, the flash
-    kernel above (at 8192 the einsum path crashes the TPU worker, so
-    auto is also a safety rail). Verified by instrumenting the kernel
-    entry point."""
+    measured winner per sequence length: einsum below the crossover
+    measured in the benchmark's cells and on any length no proper score
+    tile divides, the flash kernels elsewhere (at 8192 the einsum path
+    crashes the TPU worker, so auto is also a safety rail). Verified by
+    instrumenting the kernel entry point."""
     import dataclasses
 
     from horovod_tpu.models import GPT, GPTConfig
@@ -416,9 +417,12 @@ def test_gpt_use_flash_auto_resolves_by_sequence_length(monkeypatch):
     # kernel itself interpreted, both steered from here
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(fa, "_interpret", lambda: True)
-    # resolver sanity incl. the boundary
-    assert tr._resolve_flash("auto", 2048) is False
-    assert tr._resolve_flash("auto", 2049) is True
+    # the resolver: the boundary, a length that takes no proper tile
+    assert tr._resolve_flash("auto", fa._AUTO_FROM - 128) is False
+    assert tr._resolve_flash("auto", fa._AUTO_FROM) is True
+    assert tr._resolve_flash("auto", 4096) is True
+    for ragged in (fa._AUTO_FROM + 8, 3000, 4100):
+        assert tr._resolve_flash("auto", ragged) is False
     assert tr._resolve_flash(True, 16) is True
     assert tr._resolve_flash(False, 100000) is False
     with pytest.raises(ValueError, match="auto"):
@@ -434,13 +438,14 @@ def test_gpt_use_flash_auto_resolves_by_sequence_length(monkeypatch):
     model.apply(params, tokens_short)
     assert not calls, "auto must use einsum at short sequences"
 
-    # long sequence: auto must route through the flash kernel. Shrink
-    # the threshold so the CPU-interpret run stays fast.
-    monkeypatch.setattr(fa, "FLASH_AUTO_THRESHOLD", 64)
-    tokens_long = jnp.asarray(
-        np.random.RandomState(0).randint(0, 64, (1, 128)))
-    model.apply(params, tokens_long)
-    assert calls, "auto must use the flash kernel at long sequences"
+    # a sequence the einsum path serves and the kernels would refuse or
+    # crawl through: traced only (shapes decide, nothing runs)
+    at = lambda n: jax.eval_shape(
+        model.apply, params, jax.ShapeDtypeStruct((1, n), jnp.int32))
+    at(fa._AUTO_FROM + 4)
+    assert not calls, "auto must use einsum where no proper tile divides"
+    at(fa._AUTO_FROM)
+    assert calls == [(1, fa._AUTO_FROM, 2, 16)], calls
 
 
 def test_vgg16_and_inception_forward_backward():

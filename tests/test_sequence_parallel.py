@@ -81,6 +81,15 @@ def test_ring_attention_auto_resolves_per_shard(monkeypatch):
                                rtol=1e-5, atol=1e-5)
     assert seen and all(s == 64 // 8 for s in seen), seen
     del seq_mod  # imported to make the monkeypatch target explicit
+    # what the shard function gets for the lengths it may see: the
+    # kernels from the measured crossover up, on a TPU, and only where a
+    # proper score tile divides the shard (einsum serves the rest)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert [real("auto", s) for s in
+            (8, fa._AUTO_FROM - 128, fa._AUTO_FROM, fa._AUTO_FROM + 64,
+             8192)] == [False, False, True, False, True]
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert real("auto", 8192) is False
 
 
 @pytest.mark.parametrize("use_flash", [False, True])
