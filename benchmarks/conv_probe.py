@@ -1,12 +1,12 @@
 #!/usr/bin/env python
 """Per-shape convolution roofline probe (ResNet-50 MFU investigation).
 
-The matmul calibration (bench.py) gives a measured MXU rate; this
-probe measures what fraction of it each ResNet-50 conv SHAPE reaches,
+This probe measures the rate each ResNet-50 conv SHAPE reaches,
 fwd-only. The step-level MFU is a blend —
 attribution needs per-shape rates: if the 3-channel stem runs at a few
 TFLOP/s while the 3x3 body convs run near the matmul ceiling, the stem
-is the lever (→ --conv0-s2d); if the small-spatial deep convs lag, the
+is the lever (→ ``ResNet(conv0_space_to_depth=True)``); if the
+small-spatial deep convs lag, the
 ceiling story is HBM/arithmetic-intensity instead.
 
 Protocol: K independent convs per timed block (stacked inputs walked by

@@ -22,7 +22,7 @@ ring (HVT_EVENT_DRIVEN=0 + HVT_RING_PIPELINE=0) and the wire codecs:
 Wire-codec sweep (PR 9 artifact, ``ci.sh --codec``): every registry
 codec on a faked 2-host pair (inter-host link class), recording exact
 per-codec wire byte counters, relative error vs the exact sum, and the
-``bench.py --codec-ab`` convergence probe; ``--check`` validates an
+``benchmarks/codec_ab.py`` convergence probe; ``--check`` validates an
 artifact (fresh or committed) against the schema + the committed
 claims (int8 ≥3.5x inter-host wire-byte reduction, per-codec relerr
 bounds, EF recovering the int8 convergence bias):
@@ -353,7 +353,7 @@ def sweep_main():
 def codec_main():
     """--codec: the PR 9 wire-codec sweep. Every registry codec over a
     faked 2-host pair (inter-host link class), exact per-codec byte
-    counters + relerr + p50s, plus the bench.py --codec-ab convergence
+    counters + relerr + p50s, plus the benchmarks/codec_ab.py convergence
     probe; writes the r09 artifact schema."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     quick = "--quick" in sys.argv
@@ -418,18 +418,18 @@ def codec_main():
         }
         for codec, p in record["planes"].items() if codec != "none"
     }
-    # convergence A/B (bench.py --codec-ab): int8+EF vs fp32 vs int8−EF
+    # convergence A/B (codec_ab.py): int8+EF vs fp32 vs int8−EF
     import subprocess
     # the A/B is deterministic (fixed seeds/problem) and ~seconds per
     # config, so --quick never shortens it: at 80 steps the EF arm has
     # not yet closed to within the 10%-of-bias gate and --check would
     # fail deterministically
-    # budget: bench.py allows each of its 3 launch configs 600 s, so
+    # budget: codec_ab.py allows each of its 3 launch configs 600 s, so
     # the wrapper must not undercut the aggregate on a co-tenant-loaded
     # box — a mid-config TimeoutExpired here would eat the per-config
-    # diagnostics bench.py prints on its own failures
+    # diagnostics codec_ab.py prints on its own failures
     ab = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--codec-ab"],
+        [sys.executable, os.path.join(repo, "benchmarks", "codec_ab.py")],
         cwd=repo, capture_output=True, text=True, timeout=3 * 600 + 120)
     if ab.returncode != 0:
         raise RuntimeError(f"codec-ab failed:\n{ab.stdout}\n{ab.stderr}")

@@ -3,10 +3,9 @@
 # tree (the reference pins its matrix in .buildkite/gen-pipeline.sh; this
 # is the same intent for one TPU/CPU host).
 #
-#   ./ci.sh            # full: build + lint + tests + dryrun + bench smoke
+#   ./ci.sh            # full: build + lint + tests + dryrun
 #   ./ci.sh --fast     # inner loop: quick-marked tests only (~minutes
-#                      # vs ~37 min full on the 1-core host), skip the
-#                      # bench smoke
+#                      # vs ~37 min full on the 1-core host)
 #   ./ci.sh --chaos    # build + the fault-injection / failure-
 #                      # containment suite only (SIGKILL/SIGSTOP gangs,
 #                      # deadline bounds, abort metrics)
@@ -77,8 +76,6 @@
 #      multi-process engine/launcher/elastic integration suites)
 #   4. driver multi-chip dryrun: dp/sp/tp + MoE ep + GPipe pp on an
 #      8-device mesh with exact single-device parity checks
-#   5. bench smoke: tiny ResNet block through bench.py end to end
-#      (CPU shapes; validates the harness, not the numbers)
 set -euo pipefail
 cd "$(dirname "$0")"
 
@@ -127,7 +124,7 @@ run_pytest() {
   timeout -k 30 "$PYTEST_GUARD_SEC" python -m pytest "$@"
 }
 
-echo "=== [1/5] build C++ engine ==="
+echo "=== [1/4] build C++ engine ==="
 make -C horovod_tpu/csrc -j
 make -C horovod_tpu/csrc tf_ops   # no-op when TF is not importable
 make -C horovod_tpu/csrc tidy    # clang -Wthread-safety (skips w/o clang)
@@ -408,10 +405,10 @@ if [[ "$SANITIZE" == "1" ]]; then
   exit 0
 fi
 
-echo "=== [2/5] contract lint ==="
+echo "=== [2/4] contract lint ==="
 python -m horovod_tpu.tools.hvt_lint
 
-echo "=== [3/5] test suite ==="
+echo "=== [3/4] test suite ==="
 if [[ "$FAST" == "1" ]]; then
   # quick subset: modules outside tests/conftest.py's known-slow list
   # (subprocess gangs, TF imports, pallas interpret). Full suite stays
@@ -421,17 +418,7 @@ else
   run_pytest tests/ -x -q
 fi
 
-echo "=== [4/5] multi-chip dryrun (8 virtual devices) ==="
+echo "=== [4/4] multi-chip dryrun (8 virtual devices) ==="
 JAX_PLATFORMS=cpu python -c "import __graft_entry__ as g; g.dryrun_multichip(8); print('dryrun OK')"
-
-if [[ "$FAST" == "0" ]]; then
-  echo "=== [5/5] bench smoke (CPU harness validation) ==="
-  # --force-cpu: harness validation on a virtual CPU mesh; without it
-  # bench.py refuses a run that finds no accelerator
-  python bench.py --force-cpu --model resnet50 --batch-size 2 \
-    --num-iters 1 --num-batches-per-iter 2 --image-size 32 --no-scaling
-else
-  echo "=== [5/5] bench smoke skipped (--fast) ==="
-fi
 
 echo "CI OK"

@@ -18,8 +18,8 @@ Consumption paths:
 - ``GET /metrics`` on the elastic rendezvous server
   (``runner/http_server.py``) or the standalone :func:`serve` endpoint
   (``hvtrun --metrics-port`` starts it per worker);
-- :func:`json_snapshot` embedded in every BENCH record (``bench.py``)
-  so perf data survives even when the driver probe fails;
+- :func:`json_snapshot`, for a record that must carry its counters
+  where the live endpoint is unreachable;
 - ``MetricsCallback`` (``hvt.jax.callbacks`` / ``hvt.keras``) folding
   training-loop metrics into the registry.
 

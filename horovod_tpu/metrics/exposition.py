@@ -7,8 +7,8 @@ Two wire formats from one ``MetricRegistry``:
   histogram series with ``_sum``/``_count``), scrapeable by any
   Prometheus-compatible agent.
 - :func:`json_snapshot` — structured dict of every family and sample,
-  embedded verbatim in BENCH records (``bench.py``) so perf data carries
-  its engine counters even when the live endpoint is unreachable.
+  for a record that must carry its engine counters where the live
+  endpoint is unreachable.
 
 :func:`serve` starts a daemon HTTP server answering ``GET /metrics``
 (text) and ``GET /metrics.json`` for jobs without the elastic rendezvous

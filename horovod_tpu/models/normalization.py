@@ -2,9 +2,9 @@
 
 ``flax.linen.BatchNorm`` promotes the activation tensor to float32 both
 for the statistics pass and for the normalization pass. On TPU that means
-two extra full fp32 elementwise sweeps over HBM per layer — measured at
-~18% of the ResNet-50 step on a real v5-lite chip (bench.py profile
-notes). ``TpuBatchNorm`` keeps the fp32 *accuracy* contract of the
+two extra full fp32 elementwise sweeps over HBM per layer (an earlier
+builder's reason for this module; the two have not been timed against
+each other on the benchmark's chip: ROADMAP S3). ``TpuBatchNorm`` keeps the fp32 *accuracy* contract of the
 reference's recipes (fp16 training with fp32 BN statistics — e.g.
 ``horovod/torch/sync_batch_norm.py`` keeps stats in fp32) while keeping
 the HBM traffic in bf16:

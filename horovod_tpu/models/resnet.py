@@ -3,7 +3,7 @@
 The reference benchmarks data-parallel ResNet-50/101 throughput
 (``examples/pytorch/pytorch_synthetic_benchmark.py``,
 ``docs/benchmarks.rst:28-43``); this is the TPU-native model used by
-``bench.py`` and the examples.
+the benchmark's cell ``resnet50-b256`` (BENCHMARK.json) and the examples.
 
 TPU-first choices:
 - NHWC layout (XLA:TPU's native conv layout — channels last feeds the MXU

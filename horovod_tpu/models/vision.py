@@ -4,9 +4,9 @@ The reference's published scaling headline is Inception V3 and VGG-16
 (``/root/reference/README.rst:96``, ``docs/benchmarks.rst:13-14``: 90%
 scaling efficiency for Inception V3 / ResNet-101, 68% for VGG-16 at 512
 GPUs) plus ResNet throughput. ``horovod_tpu/models/resnet.py`` covers
-the ResNet family; this module completes the benchmark trio so
-``bench.py --model vgg16|inception_v3`` can reproduce the same model mix
-TPU-natively.
+the ResNet family; this module completes the benchmark trio, so that
+the same model mix can be built TPU-natively (no cell of the benchmark
+runs these two: BENCHMARK.json).
 
 TPU-first choices (same policy as resnet.py):
 - NHWC layout throughout — XLA:TPU's native conv layout.

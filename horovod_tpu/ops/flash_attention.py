@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import NamedTuple
 
 import jax
@@ -370,7 +369,7 @@ def _derive_tile(kernel, s, d, itemsize, causal):
     fit ``_SCOPED_VMEM``; of equal areas the wider key block. A sequence
     that is no multiple of 128 gets the one block the old default gave,
     and so does one whose streamed tiles alone overflow (wide or f32
-    operands: HVT_FLASH_SEQ_TILE is the knob for those, as before).
+    operands).
 
     A causal sequence no longer than the preferred tile's long side
     (1024) leaves that tile one sub-block a grid step, so the skipping of
@@ -387,7 +386,6 @@ def _derive_tile(kernel, s, d, itemsize, causal):
     if s % _LANES:
         block = _blocks(s, _LANES)
         return block, block
-    cap = _DKV_TILE_CAP if kernel == "dkv" else None
     most_q, most_k = _PREFERRED_TILE[kernel]
     if causal and kernel == "fwd" and s <= most_k:
         most_q = s
@@ -399,7 +397,7 @@ def _derive_tile(kernel, s, d, itemsize, causal):
            for bk in sizes if bk <= most_k
            if max(bq, bk) % min(bq, bk) == 0
            and _vmem_bytes(kernel, bq, bk, d, itemsize,
-                           _seq_tile(s, bq, bk, cap)) <= _SCOPED_VMEM]
+                           _seq_tile(s, bq, bk)) <= _SCOPED_VMEM]
     return max(fit, key=lambda t: (t[0] * t[1], t[1]),
                default=(_LANES, _LANES))
 
@@ -443,50 +441,28 @@ def _flash(q, k, v, scale, causal, block_q, block_k, out_dtype):
     return o, lse
 
 
-# The dkv backward kernel carries more per-tile state than the forward
-# (Q + dO tiles streamed together plus two fp32 accumulators), so the
-# largest tile that fits the 16 MB scoped-VMEM limit is SMALLER there
-# (tile 8192 in dkv overflowing VMEM was captured by an earlier builder
-# on another rig, not reproduced). Cap dkv's tile independently so a
-# user-requested HVT_FLASH_SEQ_TILE=8192 degrades only the one kernel
-# that needs it.
-_DKV_TILE_CAP = 4096
+# Most positions of the streamed operand a grid step holds in VMEM: the
+# whole sequence of every benchmark cell (1024, 4096) and half of 8192's,
+# the only two things measured on the v5e.
+_SEQ_TILE = 4096
 
 
-def _seq_tile(s, block_q, block_k, cap=None):
+def _seq_tile(s, block_q, block_k):
     """Streamed-sequence VMEM tile (elements of the seq axis per grid
-    step). The default of 4096 is an earlier builder's choice on another
-    rig; on the v5e it is what every measurement of PR 25 ran with (the
-    whole sequence of the benchmark's ``gpt2l-s4096``, half of 8192's)
-    and no other value has been timed there. Override with
-    HVT_FLASH_SEQ_TILE; ``cap`` bounds the request per-kernel (the dkv
-    backward caps at ``_DKV_TILE_CAP``).
+    step): the largest multiple of lcm(block_q, block_k) that divides
+    ``s`` and is at most ``_SEQ_TILE``.
 
     The tile must divide ``s`` AND be a multiple of both block sizes —
     the kernels walk ``tile // block`` sub-blocks, so a remainder would
     silently drop sequence positions. Both blocks divide s (``_blocks``),
     hence lcm(block_q, block_k) divides s and a valid tile always
     exists."""
-    req = min(int(os.environ.get("HVT_FLASH_SEQ_TILE", "4096")), s)
-    if cap is not None:
-        req = min(req, cap)
     base = math.lcm(block_q, block_k)
     best, m = base, 2
-    while m * base <= req:
+    while m * base <= _SEQ_TILE:
         if s % (m * base) == 0:
             best = m * base
         m += 1
-    if cap is not None and best > cap:
-        # correctness pins the tile to >= lcm(block_q, block_k); block
-        # sizes whose lcm exceeds the cap force a tile the capped
-        # kernel may not fit in VMEM — say so instead of failing later
-        # with an opaque scoped-VMEM allocation error
-        import sys
-
-        print(f"# horovod_tpu flash: block sizes ({block_q}, {block_k}) "
-              f"force tile {best} > VMEM cap {cap} in the capped "
-              f"backward kernel; expect scoped-VMEM pressure — use "
-              f"blocks with lcm <= {cap}", file=sys.stderr)
     return best
 
 
@@ -511,18 +487,13 @@ class _Plan(NamedTuple):
 
 def _plan(kernel, q, scale, causal, block_q, block_k):
     """Made outside the jitted calls below, so that what the process
-    holds besides the operands (HVT_FLASH_SEQ_TILE, the backend) is part
-    of their cache's key and never read under a cached trace."""
+    holds besides the operands (the backend) is part of their cache's
+    key and never read under a cached trace."""
     _, _, s, d = q.shape
     block_q, block_k, derived = _score_tile(
         kernel, s, d, q.dtype.itemsize, causal, block_q, block_k)
-    # The dkv tile is capped independently of the fwd/dq tile: that
-    # kernel streams Q AND dO tiles together and was the one that blew
-    # scoped VMEM at tile 8192 (see _DKV_TILE_CAP).
-    tile = _seq_tile(s, block_q, block_k,
-                     _DKV_TILE_CAP if kernel == "dkv" else None)
-    return _Plan(scale, causal, block_q, block_k, derived, tile,
-                 _interpret())
+    return _Plan(scale, causal, block_q, block_k, derived,
+                 _seq_tile(s, block_q, block_k), _interpret())
 
 
 def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype):

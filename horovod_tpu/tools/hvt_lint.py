@@ -108,7 +108,6 @@ BINDING_FILES = (
 # Where HVT_* env reads count as product surface needing documentation.
 # tests/ and examples/ set knobs but their reads are not user surface.
 ENV_SCAN_DIRS = ("horovod_tpu", "benchmarks")
-ENV_SCAN_FILES = ("bench.py",)
 
 # The four per-op slot groups and the two engine histograms, in the
 # exact order hvt_engine_stats emits them (after the scalar block,
@@ -696,8 +695,6 @@ def _env_read_sites(root: Path):
         for p in sorted(base.rglob("*")):
             if p.is_file():
                 scan(p, str(p.relative_to(root)))
-    for f in ENV_SCAN_FILES:
-        scan(root / f, f)
     return reads
 
 

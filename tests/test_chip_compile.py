@@ -68,7 +68,7 @@ def _qkv(shape, sharding):
     return q, kv, kv
 
 
-# (batch, seq, heads, kv_heads, head_dim): the shapes bench.py's GPT and
+# (batch, seq, heads, kv_heads, head_dim): the shapes a 12 x 768 GPT and
 # chip_smoke.py run, the benchmark cells gpt2l-s1024's, gpt2l-s4096's and
 # olmoe-s4096's own, gpt2l-dp4's (four chips' batch under a shard_map
 # that checks vma), one grouped-query shape, the 4-chip ring, and one

@@ -1,7 +1,7 @@
 """CPU rehearsal of chip_smoke.py (on-chip-measurement guide, section 2.1):
-the phases' control flow at tiny sizes, and the one thing a run without
-an accelerator must do — fail, naming the platform it found, and print no
-result line.
+the phases' control flow at tiny sizes, which phases a run is made of, and
+the one thing a run without an accelerator must do — fail, naming the
+platform it found, and print no result line.
 """
 
 import json
@@ -10,11 +10,8 @@ import subprocess
 import sys
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
-import bench
 import chip_smoke
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -53,51 +50,66 @@ def test_flash_must_be_the_compiled_kernel():
         chip_smoke.require_compiled_flash("... tpu_custom_call ...")
 
 
-def test_resnet_phase_control_flow(monkeypatch):
-    """phase_resnet50 over a stand-in job with the same state layout
-    (params, batch_stats, opt_state), built on bench's own block."""
-
-    def tiny_job(model_name, devices, batch, steps, dtype, image_size):
-        def train_step(params, stats, opt_state, x):
-            loss, g = jax.value_and_grad(
-                lambda p: jnp.mean((x @ p["w"] - 1.0) ** 2))(params)
-            params = jax.tree.map(lambda p, g: p - 0.1 * g, params, g)
-            stats = {"mean": 0.9 * stats["mean"] + 0.1 * x.mean(0)}
-            return (params, stats, opt_state + 1), loss
-
-        state = ({"w": jnp.zeros((4, 2))}, {"mean": jnp.zeros(4)},
-                 jnp.int32(0))
-        block = bench._multi_step_block(train_step, 3, steps, 1)
-        return bench.TrainJob(block, state, (jnp.ones((batch, 4)),), None)
-
-    monkeypatch.setattr(bench, "resnet_job", tiny_job)
-    out = chip_smoke.phase_resnet50(jax.devices()[:1], batch=2,
-                                    image_size=8)
-    assert out["steps"] == 6 and len(out["losses"]) == 3
-    assert out["losses"][-1] < out["losses"][0] < 1.0
-    assert out["kernels_changed"] == "1/1"
-    assert out["batch_stat_leaves_changed"] == "1/1"
-
-    # a job whose parameters stand still does not pass
-    def frozen_job(*args, **kwargs):
-        job = tiny_job(*args, **kwargs)
-        frozen = jax.jit(lambda p, s, o, x: (p, s, o, jnp.float32(1.0)))
-        return job._replace(block=frozen)
-
-    monkeypatch.setattr(bench, "resnet_job", frozen_job)
-    with pytest.raises(chip_smoke.PhaseFailed, match="0 of 1 kernels"):
-        chip_smoke.phase_resnet50(jax.devices()[:1], batch=2,
-                                  image_size=8)
+# what no cell of the benchmark decides, in the order it runs
+PHASES = {1: ["launcher", "device", "flash8192", "eager"],
+          4: ["device", "ring4", "dryrun4"]}
 
 
-def test_gpt_job_trains_at_a_tiny_sequence():
-    # bench.gpt_job as the gpt phase calls it (12 x 768, vocabulary 32768)
-    out = chip_smoke.gpt_train(jax.devices()[:1], 16, 1, use_flash=False,
-                               dtype="fp32", steps_per_block=1, calls=2)
-    assert out["steps"] == 2
-    # ln(32768) = 10.4 at initialisation, falling on a repeated batch
-    assert 9.0 < out["losses"][0] < 12.0 and out["losses"][1] < \
-        out["losses"][0]
+@pytest.mark.parametrize("chips", [1, 4])
+def test_a_run_is_made_of_exactly_these_phases(chips, monkeypatch, capsys):
+    """A phase can neither be dropped nor come back (a model phase, say)
+    in silence."""
+    ran = []
+    monkeypatch.setattr(chip_smoke, "run_phase",
+                        lambda name, fn, meter=None: ran.append(name))
+    monkeypatch.setattr("chipbench.setup_sources.enable_compile_cache",
+                        lambda: "/nowhere")
+    chip_smoke.main(["--chips", str(chips)])
+    assert ran == PHASES[chips]
+    assert {name[len("phase_"):] for name in vars(chip_smoke)
+            if name.startswith("phase_")} == set(PHASES[1] + PHASES[4])
+    lines = [json.loads(line) for line in
+             capsys.readouterr().out.strip().splitlines()]
+    assert lines[0]["phase"] == "start" and lines[-1]["ok"] is True
+
+
+def test_the_chips_peak_is_the_benchmarks():
+    """One table of the chip's peak: the device phase's bound on its
+    matmul chain is ``chipbench/peaks.json``'s entry, and no other
+    figure for it is written in the script."""
+    import inspect
+
+    from chipbench.flops import peaks
+
+    with open(os.path.join(REPO, "chipbench", "peaks.json")) as f:
+        table = json.load(f)
+    assert peaks("TPU v5 lite") == table["TPU v5 lite"]
+    assert ('peaks(devices[0].device_kind)["bf16_flops_per_s"]'
+            in inspect.getsource(chip_smoke.phase_device))
+    assert "197" not in inspect.getsource(chip_smoke)
+
+
+def test_compile_cache_is_placed_from_outside_or_fixed(monkeypatch):
+    """The rule the smoke test and the dry run take from the benchmark."""
+    from chipbench.setup_sources import enable_compile_cache
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    before = {name: getattr(jax.config, name) for name in names}
+    try:
+        # set: JAX's own handling of the variable is left alone
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        assert enable_compile_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == before[names[0]]
+        # unset: one fixed path in the checkout, never a temporary name
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = os.path.join(REPO, ".jax_cache")
+        assert enable_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        for name, value in before.items():
+            jax.config.update(name, value)
 
 
 def test_flash_kernel_against_the_float32_formula():
@@ -108,10 +120,14 @@ def test_flash_kernel_against_the_float32_formula():
     assert max(out["rel_l2"].values()) < chip_smoke.BF16_REL_L2
 
 
-def test_eager_phase_on_the_immediate_path():
-    params = {"w": jnp.ones((4, 4)), "b": jnp.zeros(4)}
-    out = chip_smoke.phase_eager(params)
+def test_eager_phase_on_the_immediate_path(capsys):
+    # through run_phase with the benchmark's meter, as main() runs it
+    from chipbench.setup_sources import CompileMeter
+
+    chip_smoke.run_phase("eager", chip_smoke.phase_eager, CompileMeter())
+    out = json.loads(capsys.readouterr().out)
     assert out["allreduce"] == 3.5
     assert out["broadcast_parameters_leaves"] == 2
-    np.testing.assert_array_equal(
-        np.asarray(chip_smoke.rel_l2(params, params)), 0.0)
+    assert out["compile_seconds"] >= 0 and out["cache_hits"] >= 0
+    params = {"w": jax.numpy.ones((4, 4))}
+    assert chip_smoke.rel_l2(params, params) == 0.0

@@ -177,43 +177,44 @@ def test_seq_tile_divisibility_invariants():
         assert s % t == 0 and t % bq == 0 and t % bk == 0, (s, bq, bk, t)
 
 
-def test_seq_tile_cap_bounds_the_dkv_tile():
-    """The dkv backward streams Q AND dO tiles together and blows the
-    16 MB scoped-VMEM limit one tile size earlier than fwd/dq (measured
-    r4, v5-lite): a user-requested HVT_FLASH_SEQ_TILE=8192 must degrade
-    only dkv, to _DKV_TILE_CAP, while still satisfying the
-    divisibility invariants."""
-    import os
+def test_seq_tile_cap_bounds_the_dkv_tile(monkeypatch):
+    """The streamed tile never exceeds the module's constant where a
+    smaller valid one exists, for dK/dV (which streams Q AND dO together)
+    as for the other two, while still satisfying the divisibility
+    invariants; blocks whose lcm is above the constant get their lcm,
+    the smallest tile that is correct."""
+    from horovod_tpu.ops import flash_attention as fa
 
-    from horovod_tpu.ops.flash_attention import _DKV_TILE_CAP, _seq_tile
-
-    os.environ["HVT_FLASH_SEQ_TILE"] = "8192"
-    try:
-        full = _seq_tile(8192, 128, 128)
-        capped = _seq_tile(8192, 128, 128, cap=_DKV_TILE_CAP)
-        assert full == 8192
-        assert capped == _DKV_TILE_CAP == 4096
-        assert 8192 % capped == 0 and capped % 128 == 0
-        # cap interacts with odd block sizes without breaking invariants
-        t = _seq_tile(6144, 128, 512, cap=4096)
-        assert t <= 4096 and 6144 % t == 0 and t % 512 == 0
-    finally:
-        del os.environ["HVT_FLASH_SEQ_TILE"]
+    assert fa._SEQ_TILE == 4096
+    assert fa._seq_tile(8192, 128, 128) == 4096
+    assert fa._plan("dkv", jax.ShapeDtypeStruct((1, 1, 8192, 64),
+                                                jnp.bfloat16),
+                    0.125, True, 128, 128).tile == 4096
+    # the bound interacts with odd block sizes without breaking invariants
+    t = fa._seq_tile(6144, 128, 512)
+    assert t <= 4096 and 6144 % t == 0 and t % 512 == 0
+    monkeypatch.setattr(fa, "_SEQ_TILE", 256)
+    assert fa._seq_tile(8192, 128, 128) == 256
+    assert fa._seq_tile(768, 384, 256) == 768
 
 
 def test_flash_grads_match_dense_when_fwd_and_dkv_tiles_differ(
         monkeypatch):
-    """Gradient correctness when the fwd/dq streaming tile differs from
-    the capped dkv tile (the seq-8192 + HVT_FLASH_SEQ_TILE=8192 shape,
-    shrunk: fwd tile 512, dkv capped at 256)."""
+    """Gradient correctness when the kernels' streamed tiles differ from
+    the sequence and from one another (the seq-8192 shape, shrunk: seq
+    1536 under a constant of 512, where the forward's derived 384 x 768
+    and dK/dV's 768 x 384 stream two tiles of 768 and dQ's 512 x 512
+    three of 512)."""
     from horovod_tpu.ops import flash_attention as fa
 
-    monkeypatch.setenv("HVT_FLASH_SEQ_TILE", "512")
-    monkeypatch.setattr(fa, "_DKV_TILE_CAP", 256)
+    monkeypatch.setattr(fa, "_SEQ_TILE", 512)
     rs = np.random.RandomState(7)
-    q = jnp.asarray(rs.randn(1, 512, 2, 32), jnp.float32)
-    k = jnp.asarray(rs.randn(1, 512, 2, 32), jnp.float32)
-    v = jnp.asarray(rs.randn(1, 512, 2, 32), jnp.float32)
+    q = jnp.asarray(rs.randn(1, 1536, 1, 32), jnp.float32)
+    k = jnp.asarray(rs.randn(1, 1536, 1, 32), jnp.float32)
+    v = jnp.asarray(rs.randn(1, 1536, 1, 32), jnp.float32)
+    spec = jax.ShapeDtypeStruct((1, 1, 1536, 32), jnp.float32)
+    assert [fa._plan(kernel, spec, 1.0, True, None, None).tile
+            for kernel in ("fwd", "dq", "dkv")] == [768, 512, 768]
 
     def loss_flash(q, k, v):
         return flash_attention(q, k, v, causal=True).sum()
@@ -228,33 +229,30 @@ def test_flash_grads_match_dense_when_fwd_and_dkv_tiles_differ(
                                    rtol=2e-2, atol=2e-2)
 
 
-def test_flash_multi_tile_matches_dense_768_mixed_blocks():
+def test_flash_multi_tile_matches_dense_768_mixed_blocks(monkeypatch):
     """The review's concrete miss case: s=768, block_q=384, block_k=256
     forces a tile that is a multiple of both; fwd AND grads must match
     the dense reference (pre-fix, dq dropped K positions 256..383)."""
-    import os
+    from horovod_tpu.ops import flash_attention as fa
 
-    os.environ["HVT_FLASH_SEQ_TILE"] = "256"  # force multi-tile paths
-    try:
-        rs = np.random.RandomState(3)
-        q = jnp.asarray(rs.randn(1, 768, 2, 32), jnp.float32)
-        k = jnp.asarray(rs.randn(1, 768, 2, 32), jnp.float32)
-        v = jnp.asarray(rs.randn(1, 768, 2, 32), jnp.float32)
+    monkeypatch.setattr(fa, "_SEQ_TILE", 256)  # force multi-tile paths
+    rs = np.random.RandomState(3)
+    q = jnp.asarray(rs.randn(1, 768, 2, 32), jnp.float32)
+    k = jnp.asarray(rs.randn(1, 768, 2, 32), jnp.float32)
+    v = jnp.asarray(rs.randn(1, 768, 2, 32), jnp.float32)
 
-        def loss_flash(q, k, v):
-            return flash_attention(q, k, v, causal=True,
-                                   block_q=384, block_k=256).sum()
+    def loss_flash(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               block_q=384, block_k=256).sum()
 
-        def loss_dense(q, k, v):
-            return _dense(q, k, v, causal=True).sum()
+    def loss_dense(q, k, v):
+        return _dense(q, k, v, causal=True).sum()
 
-        gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gf, gd):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-2, atol=2e-2)
-    finally:
-        del os.environ["HVT_FLASH_SEQ_TILE"]
+    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-2, atol=2e-2)
 
 
 @pytest.mark.parametrize("h,h_kv", [(4, 2), (4, 1), (6, 3)])
@@ -324,8 +322,8 @@ def _dense_o_lse(q, k, v, causal):
     return o, jax.scipy.special.logsumexp(scores, -1).transpose(0, 2, 1)
 
 
-# (seq, heads, kv_heads, causal, block_q, block_k, HVT_FLASH_SEQ_TILE):
-# None blocks are derived from the shape
+# (seq, heads, kv_heads, causal, block_q, block_k, the module's _SEQ_TILE
+# where the case patches it): None blocks are derived from the shape
 @pytest.mark.parametrize("s,h,h_kv,causal,block_q,block_k,seq_tile", [
     pytest.param(256, 2, 2, True, None, None, None, id="derived-causal"),
     pytest.param(256, 2, 2, False, None, None, None, id="derived-full"),
@@ -335,8 +333,8 @@ def _dense_o_lse(q, k, v, causal):
     pytest.param(512, 1, 1, True, 256, 64, None, id="256x64-diagonal"),
     # four Q sub-blocks cross it for every K block (the dK/dV dual)
     pytest.param(512, 1, 1, True, 64, 256, None, id="64x256-diagonal"),
-    pytest.param(512, 1, 1, True, 128, 64, "256", id="multi-tile"),
-    pytest.param(512, 1, 1, True, None, None, "256",
+    pytest.param(512, 1, 1, True, 128, 64, 256, id="multi-tile"),
+    pytest.param(512, 1, 1, True, None, None, 256,
                  id="derived-multi-tile"),
     pytest.param(256, 4, 2, True, None, None, None, id="derived-gqa"),
     pytest.param(256, 4, 1, True, 128, 128, None, id="128x128-mqa"),
@@ -345,10 +343,11 @@ def test_score_tiles_match_the_f32_formula(s, h, h_kv, causal, block_q,
                                            block_k, seq_tile, monkeypatch):
     """o, lse, dq, dk, dv of every way a score tile is chosen against
     the float32 formula, cotangents on o and lse both."""
+    from horovod_tpu.ops import flash_attention as fa
     from horovod_tpu.ops.flash_attention import flash_attention_with_lse
 
     if seq_tile:
-        monkeypatch.setenv("HVT_FLASH_SEQ_TILE", seq_tile)
+        monkeypatch.setattr(fa, "_SEQ_TILE", seq_tile)
     rs = np.random.RandomState(11)
     q = jnp.asarray(rs.randn(1, s, h, 32), jnp.float32)
     k, v = (jnp.asarray(rs.randn(1, s, h_kv, 32), jnp.float32)
@@ -389,8 +388,7 @@ def test_derived_tile_divides_the_sequence_and_fits_the_budget(kernel, s,
         assert max(bq, bk) % min(bq, bk) == 0, (bq, bk)
         most_q, most_k = fa._PREFERRED_TILE[kernel]
         assert bq <= max(most_q, s if causal else 0) and bk <= most_k
-        tile = fa._seq_tile(s, bq, bk,
-                            fa._DKV_TILE_CAP if kernel == "dkv" else None)
+        tile = fa._seq_tile(s, bq, bk)
         assert tile % bq == 0 and tile % bk == 0 and tile <= 4096
         # inside the budget, or the old 128 x 128 when nothing is
         assert (fa._vmem_bytes(kernel, bq, bk, d, itemsize, tile)
@@ -448,6 +446,21 @@ def test_derived_tile_is_the_one_the_chip_chose(kernel, s, d, causal):
     # less than the square of sub-blocks, but for the forward's one
     if (kernel, s) != ("fwd", 1024):
         assert visited < (s // bq) * (s // bk)
+
+
+# (seq, head_dim) of the benchmark's cells (gpt2-large at 1024 and 4096,
+# OLMoE's 4096 x 128) and two beyond them -> the positions each kernel
+# streams a grid step, as PR 27 computed them with no variable set: the
+# whole sequence up to 4096, half of 8192
+@pytest.mark.parametrize("s,d,want", [
+    (1024, 64, 1024), (4096, 64, 4096), (4096, 128, 4096),
+    (8192, 64, 4096), (1536, 64, 1536)])
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_streamed_tile_is_the_one_the_cells_run_with(kernel, s, d, want):
+    from horovod_tpu.ops import flash_attention as fa
+
+    q = jax.ShapeDtypeStruct((1, 2, s, d), jnp.bfloat16)
+    assert fa._plan(kernel, q, d ** -0.5, True, None, None).tile == want
 
 
 @pytest.mark.parametrize("s", [1024, 2048])

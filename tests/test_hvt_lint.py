@@ -807,3 +807,18 @@ def test_stats_slot_count_matches_python_bridge():
     text = (REPO_ROOT / hvt_lint.STATS_SLOTS_H).read_text()
     m = hvt_lint._SLOT_COUNT_RE.search(text)
     assert m and int(m.group(1)) == native.STATS_SLOT_COUNT == 161
+
+
+def test_the_compiled_step_reads_no_environment_variable():
+    """Models, meshes, the optimizer wrapper, the kernels and the fused
+    loss are what a training step is compiled from: what they do is
+    decided by their arguments and the shapes, never by the process's
+    environment (a variable read under a cached trace would be stale)."""
+    sources = [p for d in ("models", "parallel", "jax")
+               for p in sorted((REPO_ROOT / "horovod_tpu" / d).rglob("*.py"))]
+    sources += [REPO_ROOT / "horovod_tpu" / "ops" / name
+                for name in ("flash_attention.py", "losses.py")]
+    assert len(sources) > 10
+    readers = [str(p.relative_to(REPO_ROOT)) for p in sources
+               if "environ" in p.read_text() or "getenv" in p.read_text()]
+    assert readers == []

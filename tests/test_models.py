@@ -459,8 +459,8 @@ def test_vgg16_and_inception_forward_backward():
     from horovod_tpu.models import InceptionV3, VGG16
 
     # canonical param counts at native resolution: a silently altered
-    # tower width would otherwise keep loss/grads finite while bench.py
-    # benchmarks a different model than the reference trio
+    # tower width would otherwise keep loss/grads finite while the
+    # model is no longer the reference trio's
     def n_params(model, size):
         var = jax.eval_shape(
             lambda: model.init(jax.random.PRNGKey(0),
