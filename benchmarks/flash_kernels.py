@@ -1,21 +1,25 @@
 #!/usr/bin/env python
-"""flash_kernels.py — the three flash-attention kernels alone, on the chip.
+"""flash_kernels.py — the flash-attention kernels alone, on the chip.
 
     chiprun -- python benchmarks/flash_kernels.py \
-        --shape 2,4096,20,20,64 --blocks derived,128x128,512x1024
+        --shape 2,4096,20,20,64 --blocks derived,128x128,bwd=512x512
 
 For every score tile asked for it jits one forward + ``vjp`` of
 ``flash_attention`` at ``--shape`` (batch, seq, heads, kv_heads, head_dim;
 bf16, causal unless ``--no-causal``), runs it ``--iters`` times inside one
 profiler trace and prints one JSON line: the device milliseconds a call of
-``hvt_flash_fwd``, ``hvt_flash_dq`` and ``hvt_flash_dkv`` (median over the
-iterations, read from the trace by the kernels' names), the wall-clock
-milliseconds of the whole call, and how far its results are from the first
-tile's (relative L2). ``--einsum`` adds the einsum attention of
-``models/transformer.py`` at the same shape, wall clock only, and alone: inside
-a training step XLA schedules it otherwise (PERF.md section 6, PR 27: 7.82
-ms here, 4.70 in the step), so it does not say where ``"auto"``'s crossover
-belongs; the cells do. ``--source FILE`` times another copy of
+``hvt_flash_fwd`` and ``hvt_flash_bwd`` (median over the iterations, read
+from the trace by the kernels' names; ``hvt_flash_dq`` and
+``hvt_flash_dkv`` where ``--source`` is a copy from before PR 29, which
+had those two in the backward pass), the wall-clock milliseconds of the
+whole call, and how far its results are from the first tile's (relative
+L2). A tile is ``derived`` (each kernel's own rule), ``QxK`` (passed as
+``block_q``, ``block_k``, so to every kernel) or ``fwd=QxK`` / ``bwd=QxK``
+(that kernel's derived tile replaced, the other's kept). ``--einsum`` adds
+the einsum attention of ``models/transformer.py`` at the same shape, wall
+clock only, and alone: inside a training step XLA schedules it otherwise
+(PERF.md section 6, PR 27: 7.82 ms here, 4.70 in the step), so it does not
+say where ``"auto"``'s crossover belongs; the cells do. ``--source FILE`` times another copy of
 ``ops/flash_attention.py`` (the parent commit's) instead.
 
 A microbenchmark, not the yardstick: the cell that decides is
@@ -32,7 +36,7 @@ import sys
 import tempfile
 import time
 
-KERNELS = ("hvt_flash_fwd", "hvt_flash_dq", "hvt_flash_dkv")
+KERNELS = ("hvt_flash_fwd", "hvt_flash_bwd", "hvt_flash_dq", "hvt_flash_dkv")
 
 
 def load_flash(source):
@@ -101,7 +105,7 @@ def kernel_durations(trace_dir):
                 continue
             for e in sorted(line.events, key=lambda e: e.start_ns):
                 for name in KERNELS:
-                    # %hvt_flash_fwd.3, %transpose_jvp_hvt_flash_dkv__.1
+                    # %hvt_flash_fwd.3, %transpose_jvp_hvt_flash_bwd__.1
                     if name in e.name.split(" = ")[0]:
                         out[name].append(e.duration_ns / 1e6)
     return out
@@ -114,10 +118,11 @@ def measure(flash, shape, tiles, causal=True, iters=5, einsum=False):
     from chip_smoke import rel_l2
 
     args = make_inputs(shape)
+    derive = flash._derive_tile
     runs = []
     for tile in tiles:
-        blocks = ({} if tile == "derived" else
-                  dict(zip(("block_q", "block_k"), tile)))
+        blocks = (dict(zip(("block_q", "block_k"), tile))
+                  if isinstance(tile, tuple) else {})
         runs.append((tile, call_and_vjp(
             lambda q, k, v, blocks=blocks: flash.flash_attention(
                 q, k, v, causal=causal, **blocks))))
@@ -128,11 +133,18 @@ def measure(flash, shape, tiles, causal=True, iters=5, einsum=False):
     for tile, fn in runs:                     # compile, warm up, compare
         line = {"tile": tile, "shape": list(shape), "causal": causal}
         lines.append(line)
+        if isinstance(tile, str) and "=" in tile:
+            # traced here, once: the rule is read outside the jitted call
+            kernel, forced = tile.split("=")
+            flash._derive_tile = lambda k, *a, kernel=kernel, forced=forced: (
+                parse_tile(forced) if k == kernel else derive(k, *a))
         try:
             got = jax.device_get(fn(*args))
         except Exception as e:      # a tile the compiler refuses: say so
             line["refused"] = str(e).split("\n")[0][-300:]
             continue
+        finally:
+            flash._derive_tile = derive
         first = got if first is None else first
         line["rel_l2_vs_first"] = rel_l2(got, first)
     ran = [(line, fn) for line, (_, fn) in zip(lines, runs)
@@ -147,19 +159,21 @@ def measure(flash, shape, tiles, causal=True, iters=5, einsum=False):
             line["wall_ms"] = (time.perf_counter() - t0) * 1e3 / iters
         jax.profiler.stop_trace()
         spans = kernel_durations(trace_dir)
-    flash = [line for line, _ in ran if line["tile"] != "einsum"]
+    timed = [line for line, _ in ran if line["tile"] != "einsum"]
     for name, ms in spans.items():
-        if len(ms) != len(flash) * iters:
+        if not ms:                  # a kernel this source has not got
+            continue
+        if len(ms) != len(timed) * iters:
             raise SystemExit(f"{name}: {len(ms)} events in the trace, "
-                             f"expected {len(flash)} x {iters}")
-        for i, line in enumerate(flash):
+                             f"expected {len(timed)} x {iters}")
+        for i, line in enumerate(timed):
             line[name.removeprefix("hvt_flash_") + "_ms"] = (
                 statistics.median(ms[i * iters:(i + 1) * iters]))
     return lines
 
 
 def parse_tile(text):
-    return text if text == "derived" else tuple(
+    return text if text == "derived" or "=" in text else tuple(
         int(x) for x in text.split("x"))
 
 

@@ -37,7 +37,7 @@ class GPTConfig:
     # Grouped-query attention (LLaMA-2/Mistral lineage): number of K/V
     # heads; None → n_heads (standard MHA), 1 → MQA. Must divide
     # n_heads. Shrinks the K/V projection params and K/V HBM traffic by
-    # n_heads/n_kv_heads. The flash FORWARD and dQ kernels serve GQA
+    # n_heads/n_kv_heads. The flash kernels read the shared K/V heads
     # zero-copy (K/V block index-map aliasing: head hi reads kv head
     # hi // group); the flash backward emits per-query-head dK/dV then
     # group-sums (one transient full-h gradient array), and the

@@ -11,8 +11,10 @@ hundreds of rows by hundreds of columns, so that one pass of the inner
 loop is long enough to hide its own overhead) unless the caller names one.
 
 Backward pass recomputes score tiles (FLOPs-for-HBM trade, the same choice
-``jax.checkpoint`` makes) in two kernels: one gridded over Q blocks (dQ),
-one over K/V blocks (dK, dV), using the saved logsumexp.
+``jax.checkpoint`` makes) from the saved logsumexp in one kernel gridded
+over K/V blocks: each tile is made once and gives its share of dQ, dK and
+dV (five products; dQ and dK/dV kernels of their own made seven, and the
+element-wise pass twice: PERF.md section 6, PR 29).
 
 No reference-framework counterpart (Horovod ships gradients, not kernels);
 this is part of the TPU framework's compute path. On the CPU the same
@@ -121,8 +123,8 @@ def _visible(q0, k0, shape):
 
 def _causal_n_eff(qi, block_q, ti, tile, block_k, n_sub):
     """Number of k sub-blocks of this tile a causal Q block attends to
-    (sub-blocks entirely above the diagonal are skipped). Shared by the
-    fwd and dQ kernels; the dkv kernel uses the dual (`start`) form."""
+    (sub-blocks entirely above the diagonal are skipped); the backward
+    kernel, which keeps the K block, uses the dual (`start`) form."""
     return jnp.clip(
         ((qi + 1) * block_q - ti * tile + block_k - 1) // block_k,
         0, n_sub)
@@ -191,63 +193,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_acc_ref, *, scale, causal, block_k):
-    block_q = q_ref.shape[2]
-    tile = k_ref.shape[2]
-    qi = pl.program_id(2)
-    ti = pl.program_id(3)     # K/V tiles stream
-    n_t = pl.num_programs(3)
-
-    @pl.when(ti == 0)
-    def _init():
-        dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
-
-    def _tile():
-        q, rest = _scaled(q_ref[0, 0], scale)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]                           # [block_q, 1]
-        delta = delta_ref[0, 0]
-
-        def body(j, dq):
-            k = k_ref[0, 0, _sub_block(j, block_k), :]
-            v = v_ref[0, 0, _sub_block(j, block_k), :]
-            sc = _dot(q, k, _NT)
-            if rest is not None:
-                sc = sc * rest
-            if causal:
-                sc = jnp.where(
-                    _visible(qi * block_q, ti * tile + j * block_k,
-                             sc.shape), sc, _NEG_INF)
-            p = jnp.exp(sc - lse)
-            dp = _dot(do, v.astype(jnp.float32), _NT)
-            ds = p * (dp - delta)
-            return dq + _dot(ds, k.astype(jnp.float32), _NN)
-
-        n_sub = tile // block_k
-        n_eff = (_causal_n_eff(qi, block_q, ti, tile, block_k, n_sub)
-                 if causal else n_sub)
-        dq_acc_ref[...] = jax.lax.fori_loop(0, n_eff, body,
-                                            dq_acc_ref[...])
-
-    if causal:
-        pl.when(ti * tile < (qi + 1) * block_q)(_tile)
-    else:
-        _tile()
-
-    @pl.when(ti == n_t - 1)
-    def _finalize():
-        # dS K carries the scale once, here, not once a sub-block
-        dq_ref[0, 0] = (dq_acc_ref[...] * scale).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale, causal,
-                block_q):
+def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref,
+                *, scale, causal, block_q):
     block_k = k_ref.shape[2]
     tile = q_ref.shape[2]
     ki = pl.program_id(2)
     ti = pl.program_id(3)     # Q/dO/lse/delta tiles stream
+    n_k = pl.num_programs(2)
     n_t = pl.num_programs(3)
 
     @pl.when(ti == 0)
@@ -255,9 +208,20 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
+    @pl.when(ki == 0)
+    def _init_dq():
+        # this tile's rows of the whole-sequence accumulator: every K
+        # block adds to them, in the order the grid visits the blocks
+        rows = _sub_block(ti, tile)
+        dq_acc_ref[rows, :] = jnp.zeros((tile, dq_acc_ref.shape[1]),
+                                        jnp.float32)
+
     def _tile():
-        k, rest = _scaled(k_ref[0, 0], scale)         # [block_k, D]
+        k = k_ref[0, 0]                               # [block_k, D]
+        k_sc, rest = _scaled(k, scale)
+        k = k.astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
+        n_sub = tile // block_q
 
         def body(i, carry):
             dk, dv = carry
@@ -266,7 +230,7 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
             do = do_ref[0, 0, rows, :].astype(jnp.float32)
             lse = lse_ref[0, 0, rows, :]              # [block_q, 1]
             delta = delta_ref[0, 0, rows, :]
-            sc = _dot(q, k, _NT)                      # [bq, bk]
+            sc = _dot(q, k_sc, _NT)                   # [bq, bk]
             if rest is not None:
                 sc = sc * rest
             if causal:
@@ -278,9 +242,10 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
             dp = _dot(do, v, _NT)
             ds = p * (dp - delta)
             dk_new = dk + _dot(ds, q.astype(jnp.float32), _TN)
+            dq_acc_ref[_sub_block(ti * n_sub + i, block_q), :] += _dot(
+                ds, k, _NN)
             return dk_new, dv_new
 
-        n_sub = tile // block_q
         if causal:
             # Q sub-blocks strictly before this K block see nothing
             start = jnp.clip((ki * block_k - ti * tile) // block_q,
@@ -300,8 +265,15 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(ti == n_t - 1)
     def _finalize():
+        # dS^T Q and dS K carry the scale once, here, not once a sub-block
         dk_ref[0, 0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc_ref[...].astype(dv_ref.dtype)
+
+    @pl.when(ki == n_k - 1)
+    def _finalize_dq():
+        # the last K block has passed this tile's rows: dQ leaves once
+        dq_ref[0, 0] = (dq_acc_ref[_sub_block(ti, tile), :]
+                        * scale).astype(dq_ref.dtype)
 
 
 def _blocks(s, requested):
@@ -314,59 +286,81 @@ def _blocks(s, requested):
 # The score tile each kernel prefers when the caller names none, as
 # (block_q, block_k). Measured on a v5e at 2 x 20 heads x 4096 x 64 and
 # 1 x 32/8 heads x 4096 x 128, bf16, causal and not (PERF.md section 6,
-# PR 25): the forward wants a wide key block (its per-row bookkeeping is
-# paid once a sub-block), dQ a square one, dK/dV long query sub-blocks
-# against the K block it keeps; larger tiles gained under 2% or were
-# refused by the compiler.
-_PREFERRED_TILE = {"fwd": (512, 1024), "dq": (512, 512),
-                   "dkv": (1024, 512)}
+# PRs 25, 29): the forward wants a wide key block (its per-row bookkeeping
+# is paid once a sub-block), the backward long query sub-blocks against
+# the K block it keeps; larger tiles gained under 2% or were refused by
+# the compiler.
+_PREFERRED_TILE = {"fwd": (512, 1024), "bwd": (1024, 512)}
 
-# The VMEM Mosaic scopes to one kernel by default.
+# The VMEM Mosaic scopes to one kernel by default, what a core has (v5e;
+# the later generations have as much or more), and what XLA keeps of its
+# own inside a kernel's scope: around a call in a model the compiler
+# asked for up to 3 MiB more than for the kernel alone (PR 25's dK/dV at
+# 1024 x 512 inside gpt2-large at 1 x 8192: 18.5 MiB against 15.5).
 _SCOPED_VMEM = 16 * 2 ** 20
+_VMEM = 128 * 2 ** 20
+_XLA_VMEM = 3 * 2 ** 20
+
+# What a kernel's buffers may take. The forward's are a tile's, and the
+# default scope has always held them; the backward's grow with the
+# sequence, so the core's VMEM is the bound.
+_VMEM_BUDGET = {"fwd": _SCOPED_VMEM, "bwd": _VMEM - _XLA_VMEM}
 
 
-def _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile):
-    """Estimate of the VMEM one grid step of ``kernel`` holds: one f32
-    [block_q, block_k] score tile (the compiler strip-mines the rest of
-    the softmax), the streamed sequence tiles and the resident blocks,
-    both double-buffered, and the f32 accumulators. A position of an
-    operand takes whole 128-lane rows whatever ``d`` is, and so does a
-    position of a [.., 1] statistic. Checked against the compiler's own
-    answers at 2 x 20 x 4096 x 64: dK/dV at 512 x 1024 is refused at 17.7
-    MiB (estimate 17.0) and accepted at 1024 x 512 (estimate 15.5)."""
+def _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile, s):
+    """Estimate of the VMEM one grid step of ``kernel`` holds: the f32
+    [block_q, block_k] score tiles alive at once (one in the forward,
+    the compiler strip-mines the rest of the softmax; two in the
+    backward, p beside ds), the streamed sequence tiles and the resident
+    blocks, both double-buffered, and the f32 accumulators, of which the
+    backward's dQ is as long as the sequence. A position of an operand
+    takes whole 128-lane rows whatever ``d`` is, and so does a position
+    of a [.., 1] statistic. Checked against the least limit the compiler
+    takes for the backward alone (v5e, bf16, 20 heads of 64): 19.0 MiB
+    at 2 x 4096 and 1024 x 512 (estimate 21.5), 26.25 at 1024 x 1024
+    (27.0), 21.0 at 1 x 8192 (23.5), 8.0 at 8 x 1024 and 512 x 512
+    (7.5), 13.0 at 4 x 2048 (13.5)."""
     lanes = -(-d // _LANES) * _LANES
     row, acc, stat = lanes * itemsize, lanes * 4, _LANES * 4
     score = 4 * block_q * block_k
     if kernel == "fwd":     # K V stream; q o lse blocks; acc m l scratch
         return (score + 2 * 2 * tile * row
                 + block_q * (2 * 2 * row + 2 * stat + acc + 2 * stat))
-    if kernel == "dq":      # K V stream; q do dq lse delta blocks; acc
-        return (score + 2 * 2 * tile * row
-                + block_q * (3 * 2 * row + 2 * 2 * stat + acc))
-    # dkv: Q dO lse delta stream; k v dk dv blocks; two accumulators
-    return (score + 2 * 2 * tile * (row + stat)
-            + block_k * (4 * 2 * row + 2 * acc))
+    # bwd: Q dO lse delta stream in, a dq tile out; k v dk dv blocks and
+    # the two accumulators of a block; dq's accumulator
+    return (2 * score + 2 * tile * (2 * (row + stat) + row)
+            + block_k * (4 * 2 * row + 2 * acc) + s * acc)
 
 
-def _compiler_params(kernel, block_q, block_k, d, itemsize, tile):
-    """The default scope unless the estimate comes within a quarter of
-    it; then half as much again as the estimate, as the kernel's own
-    ``vmem_limit_bytes``. Around a call XLA keeps buffers of its own in
-    VMEM, and the compiler then asked for up to 3 MiB more than for the
-    kernel alone (dK/dV at 1024 x 512 inside gpt2-large at 1 x 8192: 18.5
-    MiB against an estimate of 15.5), where the default refuses it."""
-    need = _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile)
-    if need + need // 4 <= _SCOPED_VMEM:
+def _compiler_params(kernel, block_q, block_k, d, itemsize, tile, s):
+    """The default scope where the estimate and XLA's share fit it; else
+    just that much as the kernel's own ``vmem_limit_bytes``, and no more:
+    the VMEM a kernel's scope takes is taken from the program around it.
+    gpt2-large at 2 x 4096 (v5e, PERF.md section 6, PR 29) ran the same
+    step in 713.3 ms with the backward's scope at 32.25 MiB (half as much
+    again as the estimate, PR 25's rule) and in 700.4 at 24.5: XLA kept
+    fewer operands of the fusions around each call in VMEM, and they and
+    the kernel itself were slower; from 24.5 down to 20 nothing moved."""
+    limit = _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile,
+                        s) + _XLA_VMEM
+    if limit <= _SCOPED_VMEM:
         return None
-    return pltpu.CompilerParams(vmem_limit_bytes=need + need // 2)
+    if limit > _VMEM:
+        raise ValueError(
+            f"flash attention's backward pass keeps a float32 dQ "
+            f"accumulator of the whole sequence in VMEM: {s} positions "
+            f"need {limit / 2 ** 20:.0f} MiB of a core's "
+            f"{_VMEM // 2 ** 20}. Shard the sequence (ring_attention) or "
+            f"use the einsum path (use_flash=False)")
+    return pltpu.CompilerParams(vmem_limit_bytes=limit)
 
 
 def _derive_tile(kernel, s, d, itemsize, causal):
-    """The score tile of ``kernel`` ("fwd", "dq", "dkv") for a sequence of
-    ``s`` positions: the largest (block_q, block_k) — multiples of 128
-    that divide ``s``, one dividing the other so the streamed tile is
+    """The score tile of ``kernel`` ("fwd", "bwd") for a sequence of ``s``
+    positions: the largest (block_q, block_k) — multiples of 128 that
+    divide ``s``, one dividing the other so the streamed tile is
     unaffected, neither above the kernel's preferred size — whose buffers
-    fit ``_SCOPED_VMEM``; of equal areas the wider key block. A sequence
+    fit ``_VMEM_BUDGET``; of equal areas the wider key block. A sequence
     that is no multiple of 128 gets the one block the old default gave,
     and so does one whose streamed tiles alone overflow (wide or f32
     operands).
@@ -375,21 +369,20 @@ def _derive_tile(kernel, s, d, itemsize, causal):
     (1024) leaves that tile one sub-block a grid step, so the skipping of
     sub-blocks above the diagonal never happens, and what the chip
     prefers there differs by kernel (v5e, 8 x 20 heads x 1024 x 64 and 2
-    x 16 x 1024 x 128, PERF.md section 6, PR 27). The forward's passes
-    cost by their rows whatever they skip, so narrower key blocks lose;
-    with the one key block every query block goes through whole, a split
-    of the queries only adds grid steps: it takes the sequence whole
-    (1024 x 1024, 15% under 512 x 1024). dK/dV's cost by their area: it
-    halves its query sub-block (512 x 512, 10% under 1024 x 512), so that
-    the second K block skips the half no query of which sees it. dQ's
-    512 x 512 already skips a quarter."""
+    x 16 x 1024 x 128, PERF.md section 6, PRs 27, 29). The forward's
+    passes cost by their rows whatever they skip, so narrower key blocks
+    lose; with the one key block every query block goes through whole, a
+    split of the queries only adds grid steps: it takes the sequence
+    whole (1024 x 1024, 15% under 512 x 1024). The backward's cost by
+    their area: it halves its query sub-block (512 x 512), so that the
+    second K block skips the half no query of which sees it."""
     if s % _LANES:
         block = _blocks(s, _LANES)
         return block, block
     most_q, most_k = _PREFERRED_TILE[kernel]
     if causal and kernel == "fwd" and s <= most_k:
         most_q = s
-    if (causal and kernel == "dkv" and most_k < s <= most_q
+    if (causal and kernel == "bwd" and most_k < s <= most_q
             and s % (2 * _LANES) == 0):
         most_q = s // 2
     sizes = [b for b in range(_LANES, s + 1, _LANES) if s % b == 0]
@@ -397,13 +390,13 @@ def _derive_tile(kernel, s, d, itemsize, causal):
            for bk in sizes if bk <= most_k
            if max(bq, bk) % min(bq, bk) == 0
            and _vmem_bytes(kernel, bq, bk, d, itemsize,
-                           _seq_tile(s, bq, bk)) <= _SCOPED_VMEM]
+                           _seq_tile(s, bq, bk), s) <= _VMEM_BUDGET[kernel]]
     return max(fit, key=lambda t: (t[0] * t[1], t[1]),
                default=(_LANES, _LANES))
 
 
 def _score_tile(kernel, s, d, itemsize, causal, block_q, block_k):
-    """``(block_q, block_k, derived)`` for one of the three kernels: an
+    """``(block_q, block_k, derived)`` for one of the two kernels: an
     explicit integer is honoured as ever (clipped to divide ``s``);
     ``None`` takes that side of the tile derived from the shape."""
     derived = block_q is None or block_k is None
@@ -434,8 +427,8 @@ def _count_trace(kernel, block_q, block_k, derived):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, scale, causal, block_q, block_k, out_dtype):
     """Differentiable (o, lse). The lse output carries its own gradient:
-    d lse/dS = P, so a dlse cotangent folds into the backward kernels as
-    delta := rowsum(do∘o) − dlse — the kernels are unchanged."""
+    d lse/dS = P, so a dlse cotangent folds into the backward kernel as
+    delta := rowsum(do∘o) − dlse — the kernel is unchanged."""
     o, lse = _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k,
                              out_dtype)
     return o, lse
@@ -501,7 +494,7 @@ def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype):
                      plan=_plan("fwd", q, scale, causal, block_q, block_k))
 
 
-# Each of the three calls is a ``jax.jit`` of its own: a model's layers
+# Each of the two calls is a ``jax.jit`` of its own: a model's layers
 # then share one trace and one lowered function a kernel (XLA inlines the
 # calls, so the compiled program is the same), where 36 layers' kernels
 # traced and lowered one by one were 20 s of every start (PERF.md section
@@ -537,7 +530,7 @@ def _fwd_call(q, k, v, *, plan, out_dtype):
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
         compiler_params=_compiler_params("fwd", block_q, block_k, d,
-                                         q.dtype.itemsize, tile),
+                                         q.dtype.itemsize, tile, s),
         interpret=plan.interpret,
         name="hvt_flash_fwd",
     )(q, k, v)
@@ -557,78 +550,55 @@ def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
                     axis=-1, keepdims=True)        # [B, H, S, 1]
     # lse cotangent: ds gains + P∘dlse, i.e. delta shifts by −dlse
     delta = delta - dlse.astype(jnp.float32)
-    dq = _dq_call(q, k, v, do, lse, delta,
-                  plan=_plan("dq", q, scale, causal, block_q, block_k))
-    dk, dv = _dkv_call(q, k, v, do, lse, delta,
-                       plan=_plan("dkv", q, scale, causal, block_q,
-                                  block_k))
-    return dq, dk, dv
+    return _bwd_call(q, k, v, do, lse, delta,
+                     plan=_plan("bwd", q, scale, causal, block_q, block_k))
 
 
 @functools.partial(jax.jit, static_argnames="plan")
-def _dq_call(q, k, v, do, lse, delta, *, plan):
-    """dq: grid (b, h, qi, ti) — K/V tiles stream past each Q block.
-    GQA reads the shared K/V head zero-copy via the index map."""
+def _bwd_call(q, k, v, do, lse, delta, *, plan):
+    """dq, dk, dv: grid (b, h, ki, ti) — Q/dO/lse/delta tiles stream past
+    each K/V block (the reduction axis must be LAST), and every score
+    sub-block is made once for all three. dk/dv accumulate a K block;
+    dq accumulates over the K blocks in a float32 VMEM scratch of the
+    whole sequence and leaves a tile at a time while the last K block
+    passes: until then its block index stays where it is, so nothing is
+    written back. Under GQA the kernel reads the shared K/V head
+    zero-copy via the index map but emits per-QUERY-head dk/dv (full h),
+    which are then group-summed — each K/V head's gradient is the sum
+    over its query group."""
     b, h, s, d = q.shape
     block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
-    _count_trace("dq", block_q, block_k, plan.derived)
+    _count_trace("bwd", block_q, block_k, plan.derived)
     group = h // k.shape[1]
-    q_by_qi = pl.BlockSpec((1, 1, block_q, d),
-                           lambda bi, hi, qi, ti: (bi, hi, qi, 0))
-    kv_tile = pl.BlockSpec((1, 1, tile, d),
-                           lambda bi, hi, qi, ti: (bi, hi // group, ti, 0))
-    vec_by_qi = pl.BlockSpec((1, 1, block_q, 1),
-                             lambda bi, hi, qi, ti: (bi, hi, qi, 0))
-    return pl.pallas_call(
-        functools.partial(_dq_kernel, scale=plan.scale,
-                          causal=plan.causal, block_k=block_k),
-        grid=(b, h, s // block_q, s // tile),
-        in_specs=[q_by_qi, kv_tile, kv_tile, q_by_qi, vec_by_qi,
-                  vec_by_qi],
-        out_specs=q_by_qi,
-        out_shape=_out(q.shape, q.dtype, q, k, v, do, lse, delta),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params("dq", block_q, block_k, d,
-                                         q.dtype.itemsize, tile),
-        interpret=plan.interpret,
-        name="hvt_flash_dq",
-    )(q, k, v, do, lse, delta)
-
-
-@functools.partial(jax.jit, static_argnames="plan")
-def _dkv_call(q, k, v, do, lse, delta, *, plan):
-    """dk/dv: grid (b, h, ki, ti) — Q/dO/lse/delta tiles stream past
-    each K/V block (the reduction axis must be LAST). Under GQA the
-    kernel still reads the shared K/V head zero-copy but emits
-    per-QUERY-head gradients (full h), which are then group-summed —
-    each K/V head's gradient is the sum over its query group."""
-    b, h, s, d = q.shape
-    block_q, block_k, dkv_tile = plan.block_q, plan.block_k, plan.tile
-    _count_trace("dkv", block_q, block_k, plan.derived)
-    group = h // k.shape[1]
+    n_k = s // block_k
     kv_in_ki = pl.BlockSpec((1, 1, block_k, d),
                             lambda bi, hi, ki, ti: (bi, hi // group, ki, 0))
     dkv_out_ki = pl.BlockSpec((1, 1, block_k, d),
                               lambda bi, hi, ki, ti: (bi, hi, ki, 0))
-    q_tile = pl.BlockSpec((1, 1, dkv_tile, d),
+    q_tile = pl.BlockSpec((1, 1, tile, d),
                           lambda bi, hi, ki, ti: (bi, hi, ti, 0))
-    vec_tile = pl.BlockSpec((1, 1, dkv_tile, 1),
+    vec_tile = pl.BlockSpec((1, 1, tile, 1),
                             lambda bi, hi, ki, ti: (bi, hi, ti, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=plan.scale,
+    dq_tile = pl.BlockSpec(
+        (1, 1, tile, d),
+        lambda bi, hi, ki, ti: (bi, hi, jnp.where(ki == n_k - 1, ti, 0), 0))
+    operands = (q, k, v, do, lse, delta)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=plan.scale,
                           causal=plan.causal, block_q=block_q),
-        grid=(b, h, s // block_k, s // dkv_tile),
-        in_specs=[kv_in_ki, kv_in_ki, q_tile, q_tile, vec_tile,
-                  vec_tile],
-        out_specs=[dkv_out_ki, dkv_out_ki],
-        out_shape=[_out(q.shape, k.dtype, q, k, v, do, lse, delta),
-                   _out(q.shape, v.dtype, q, k, v, do, lse, delta)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+        grid=(b, h, n_k, s // tile),
+        in_specs=[kv_in_ki, kv_in_ki, q_tile, q_tile, vec_tile, vec_tile],
+        out_specs=[dq_tile, dkv_out_ki, dkv_out_ki],
+        out_shape=[_out(q.shape, q.dtype, *operands),
+                   _out(q.shape, k.dtype, *operands),
+                   _out(q.shape, v.dtype, *operands)],
+        scratch_shapes=[pltpu.VMEM((s, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_compiler_params("dkv", block_q, block_k, d,
-                                         q.dtype.itemsize, dkv_tile),
+        compiler_params=_compiler_params("bwd", block_q, block_k, d,
+                                         q.dtype.itemsize, tile, s),
         interpret=plan.interpret,
-        name="hvt_flash_dkv",
+        name="hvt_flash_bwd",
     )(k, v, q, do, lse, delta)
     if group > 1:
         h_kv = h // group
@@ -636,7 +606,7 @@ def _dkv_call(q, k, v, do, lse, delta, *, plan):
             b, h_kv, group, s, d).sum(axis=2).astype(k.dtype)
         dv = dv.astype(jnp.float32).reshape(
             b, h_kv, group, s, d).sum(axis=2).astype(v.dtype)
-    return dk, dv
+    return dq, dk, dv
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -656,7 +626,7 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
         integer is honoured, clipped to divide seq.
 
     Returns [batch, seq, heads, head_dim] in q.dtype. Differentiable
-    (custom VJP with recompute-based backward kernels).
+    (custom VJP with a recompute-based backward kernel).
     """
     o, _ = flash_attention_with_lse(q, k, v, causal=causal, scale=scale,
                                     block_q=block_q, block_k=block_k)

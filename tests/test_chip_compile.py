@@ -9,6 +9,7 @@ describe the topology at once collide on libtpu's lock file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,16 +72,21 @@ def _qkv(shape, sharding):
 # (batch, seq, heads, kv_heads, head_dim): the shapes a 12 x 768 GPT and
 # chip_smoke.py run, the benchmark cells gpt2l-s1024's, gpt2l-s4096's and
 # olmoe-s4096's own, gpt2l-dp4's (four chips' batch under a shard_map
-# that checks vma), one grouped-query shape, the 4-chip ring, and one
-# sequence whose clipped block Mosaic refuses. No blocks are passed: each
-# kernel's score tile is
-# the one derived from the shape
+# that checks vma), gpt2-large's heads at 4 x 2048 and at 1 x 8192 (two
+# streamed tiles: the backward's dQ accumulator is addressed by a dynamic
+# slice and leaves a tile at a time), one grouped-query shape, the 4-chip
+# ring, and one sequence whose clipped block Mosaic refuses. No blocks
+# are passed: each kernel's score tile is the one derived from the shape,
+# and every step is value_and_grad, so the forward and the one backward
+# kernel are both compiled
 @pytest.mark.parametrize("kind,shape", [
     pytest.param("flash", (8, 1024, 12, 12, 64), id="flash-8x1024"),
     pytest.param("flash", (2, 4096, 12, 12, 64), id="flash-2x4096"),
     pytest.param("flash", (1, 8192, 12, 12, 64), id="flash-1x8192"),
     pytest.param("flash", (8, 1024, 20, 20, 64), id="flash-gpt2l-s1024"),
     pytest.param("flash", (2, 4096, 20, 20, 64), id="flash-gpt2l-s4096"),
+    pytest.param("flash", (4, 2048, 20, 20, 64), id="flash-gpt2l-4x2048"),
+    pytest.param("flash", (1, 8192, 20, 20, 64), id="flash-gpt2l-1x8192"),
     pytest.param("shard_map", (32, 1024, 20, 20, 64),
                  id="flash-gpt2l-dp4-shard-map"),
     pytest.param("flash", (1, 4096, 32, 8, 128), id="flash-gqa-32-8-128"),
@@ -119,6 +125,7 @@ def test_main_path_kernel_compiles_for_v5e(kind, shape, compiled_kernel,
         args = _qkv(shape, NamedSharding(mesh, P(None, "sp", None, None)))
     text = step.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    assert "hvt_flash_fwd" in text and "hvt_flash_bwd" in text
     if kind == "ring":
         assert "collective-permute" in text
 
@@ -126,8 +133,9 @@ def test_main_path_kernel_compiles_for_v5e(kind, shape, compiled_kernel,
 def test_layers_share_one_lowered_kernel(compiled_kernel, v5e_devices):
     """Each kernel is traced and lowered once a program: a two-layer
     ``models.GPT`` training step holds as many ``tpu_custom_call`` sites
-    as a one-layer step (the forward's, the recomputed forward's, dQ's
-    and dK/dV's), each called once a layer. Lowered, not compiled."""
+    as a one-layer step (the forward's, the recomputed forward's and the
+    one backward's), each called once a layer, and two Pallas kernels by
+    name. Lowered, not compiled."""
     from horovod_tpu.models import GPT, GPTConfig
 
     one_chip = SingleDeviceSharding(v5e_devices[0])
@@ -144,11 +152,13 @@ def test_layers_share_one_lowered_kernel(compiled_kernel, v5e_devices):
             jax.eval_shape(model.init, jax.random.key(0), tokens))
         loss = lambda p, t: model.apply(p, t).astype(jnp.float32).mean()
         text = jax.jit(jax.grad(loss)).lower(params, tokens).as_text()
-        return text.count("tpu_custom_call"), text.count("call @")
+        kernels = set(re.findall(r"hvt_flash_\w+", text))
+        return text.count("tpu_custom_call"), text.count("call @"), kernels
 
-    (one, calls_one), (two, calls_two) = sites(1), sites(2)
-    assert one == two == 4, (one, two)
+    (one, calls_one, names), (two, calls_two, _) = sites(1), sites(2)
+    assert one == two == 3, (one, two)
     assert calls_two > calls_one
+    assert names == {"hvt_flash_fwd", "hvt_flash_bwd"}
 
 
 def test_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices):
@@ -157,8 +167,6 @@ def test_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices):
     backward: the nine grouped products are Pallas calls whose tile fits
     VMEM, and no scatter is over rows or weights: the only ones are the
     products' bookkeeping (which tile belongs to which group)."""
-    import re
-
     tokens, d, experts, k = 2 * 4096, 2048, 64, 8
     layer = moe.MoEMlp(experts, 1024, k, dtype=jnp.bfloat16)
     one = SingleDeviceSharding(v5e_devices[0])

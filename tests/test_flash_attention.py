@@ -3,6 +3,9 @@
 Runs in interpret mode on the CPU test platform; the same kernels compile
 for TPU (the driver's bench path)."""
 
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -177,17 +180,17 @@ def test_seq_tile_divisibility_invariants():
         assert s % t == 0 and t % bq == 0 and t % bk == 0, (s, bq, bk, t)
 
 
-def test_seq_tile_cap_bounds_the_dkv_tile(monkeypatch):
+def test_seq_tile_cap_bounds_the_bwd_tile(monkeypatch):
     """The streamed tile never exceeds the module's constant where a
-    smaller valid one exists, for dK/dV (which streams Q AND dO together)
-    as for the other two, while still satisfying the divisibility
+    smaller valid one exists, for the backward (which streams Q AND dO
+    together) as for the forward, while still satisfying the divisibility
     invariants; blocks whose lcm is above the constant get their lcm,
     the smallest tile that is correct."""
     from horovod_tpu.ops import flash_attention as fa
 
     assert fa._SEQ_TILE == 4096
     assert fa._seq_tile(8192, 128, 128) == 4096
-    assert fa._plan("dkv", jax.ShapeDtypeStruct((1, 1, 8192, 64),
+    assert fa._plan("bwd", jax.ShapeDtypeStruct((1, 1, 8192, 64),
                                                 jnp.bfloat16),
                     0.125, True, 128, 128).tile == 4096
     # the bound interacts with odd block sizes without breaking invariants
@@ -198,13 +201,13 @@ def test_seq_tile_cap_bounds_the_dkv_tile(monkeypatch):
     assert fa._seq_tile(768, 384, 256) == 768
 
 
-def test_flash_grads_match_dense_when_fwd_and_dkv_tiles_differ(
+def test_flash_grads_match_dense_when_fwd_and_bwd_tiles_differ(
         monkeypatch):
-    """Gradient correctness when the kernels' streamed tiles differ from
-    the sequence and from one another (the seq-8192 shape, shrunk: seq
-    1536 under a constant of 512, where the forward's derived 384 x 768
-    and dK/dV's 768 x 384 stream two tiles of 768 and dQ's 512 x 512
-    three of 512)."""
+    """Gradient correctness when the kernels' score tiles differ from one
+    another and their streamed tiles from the sequence (the seq-8192
+    shape, shrunk: seq 1536 under a constant of 512, where the forward's
+    derived 384 x 768 and the backward's 768 x 384 stream two tiles of
+    768, so dQ leaves its accumulator a tile at a time)."""
     from horovod_tpu.ops import flash_attention as fa
 
     monkeypatch.setattr(fa, "_SEQ_TILE", 512)
@@ -213,8 +216,10 @@ def test_flash_grads_match_dense_when_fwd_and_dkv_tiles_differ(
     k = jnp.asarray(rs.randn(1, 1536, 1, 32), jnp.float32)
     v = jnp.asarray(rs.randn(1, 1536, 1, 32), jnp.float32)
     spec = jax.ShapeDtypeStruct((1, 1, 1536, 32), jnp.float32)
-    assert [fa._plan(kernel, spec, 1.0, True, None, None).tile
-            for kernel in ("fwd", "dq", "dkv")] == [768, 512, 768]
+    plans = [fa._plan(kernel, spec, 1.0, True, None, None)
+             for kernel in ("fwd", "bwd")]
+    assert [(p.block_q, p.block_k, p.tile) for p in plans] == [
+        (384, 768, 768), (768, 384, 768)]
 
     def loss_flash(q, k, v):
         return flash_attention(q, k, v, causal=True).sum()
@@ -293,10 +298,9 @@ def test_flash_gqa_rejects_indivisible_heads():
 
 
 @pytest.mark.parametrize("kernel, in_backward", [
-    ("hvt_flash_fwd", False), ("hvt_flash_dq", True),
-    ("hvt_flash_dkv", True)])
+    ("hvt_flash_fwd", False), ("hvt_flash_bwd", True)])
 def test_kernels_carry_their_names(kernel, in_backward):
-    # a device trace tells the three Pallas calls apart by these names
+    # a device trace tells the two Pallas calls apart by these names
     q, k, v = _qkv(s=64)
     attend = lambda q, k, v: flash_attention(
         q, k, v, causal=True, block_q=32, block_k=32).sum()
@@ -304,6 +308,8 @@ def test_kernels_carry_their_names(kernel, in_backward):
     backward = str(jax.make_jaxpr(jax.grad(attend, (0, 1, 2)))(q, k, v))
     assert (kernel in forward) == (not in_backward)
     assert kernel in backward
+    # one pass over the score tiles: the backward is a single call
+    assert backward.count("hvt_flash_bwd") == 1
 
 
 def _dense_o_lse(q, k, v, causal):
@@ -329,9 +335,9 @@ def _dense_o_lse(q, k, v, causal):
     pytest.param(256, 2, 2, False, None, None, None, id="derived-full"),
     pytest.param(256, 2, 2, True, 128, 128, None, id="128x128-causal"),
     pytest.param(256, 2, 2, False, 128, 128, None, id="128x128-full"),
-    # four k sub-blocks cross the diagonal of every Q block (fwd, dQ)
+    # four k sub-blocks cross the diagonal of every Q block (fwd)
     pytest.param(512, 1, 1, True, 256, 64, None, id="256x64-diagonal"),
-    # four Q sub-blocks cross it for every K block (the dK/dV dual)
+    # four Q sub-blocks cross it for every K block the backward keeps
     pytest.param(512, 1, 1, True, 64, 256, None, id="64x256-diagonal"),
     pytest.param(512, 1, 1, True, 128, 64, 256, id="multi-tile"),
     pytest.param(512, 1, 1, True, None, None, 256,
@@ -373,72 +379,75 @@ def test_score_tiles_match_the_f32_formula(s, h, h_kv, causal, block_q,
                                    rtol=2e-4, atol=2e-4, err_msg=name)
 
 
+@pytest.mark.parametrize("itemsize", [2, 4])
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("d", [64, 128])
 @pytest.mark.parametrize("s", [1024, 1536, 2048, 4096, 8192, 16384])
-@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
-def test_derived_tile_divides_the_sequence_and_fits_the_budget(kernel, s,
-                                                               d, causal):
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_derived_tile_divides_the_sequence_and_fits_the_budget(
+        kernel, s, d, causal, itemsize):
     from horovod_tpu.ops import flash_attention as fa
 
-    for itemsize in (2, 4):
-        bq, bk = fa._derive_tile(kernel, s, d, itemsize, causal)
-        assert s % bq == 0 and s % bk == 0, (bq, bk)
-        assert bq % 128 == 0 and bk % 128 == 0, (bq, bk)
-        assert max(bq, bk) % min(bq, bk) == 0, (bq, bk)
-        most_q, most_k = fa._PREFERRED_TILE[kernel]
-        assert bq <= max(most_q, s if causal else 0) and bk <= most_k
-        tile = fa._seq_tile(s, bq, bk)
-        assert tile % bq == 0 and tile % bk == 0 and tile <= 4096
-        # inside the budget, or the old 128 x 128 when nothing is
-        assert (fa._vmem_bytes(kernel, bq, bk, d, itemsize, tile)
-                <= fa._SCOPED_VMEM or (bq, bk) == (128, 128))
-        # bf16 gets the preferred tile wherever the sequence allows it,
-        # but a causal sequence of one preferred tile (the test below)
-        if itemsize == 2 and s % 1024 == 0 and not (causal and s == 1024):
-            assert (bq, bk) == (most_q, most_k)
-        # an explicit block is honoured as before, derived or not beside it
-        assert fa._score_tile(kernel, s, d, itemsize, causal, 32, 384) == (
-            32, fa._blocks(s, 384), False)
-        assert fa._score_tile(kernel, s, d, itemsize, causal, None,
-                              128) == (bq, 128, True)
-        assert fa._score_tile(kernel, s, d, itemsize, causal, None,
-                              None) == (bq, bk, True)
+    bq, bk = fa._derive_tile(kernel, s, d, itemsize, causal)
+    assert s % bq == 0 and s % bk == 0, (bq, bk)
+    assert bq % 128 == 0 and bk % 128 == 0, (bq, bk)
+    assert max(bq, bk) % min(bq, bk) == 0, (bq, bk)
+    most_q, most_k = fa._PREFERRED_TILE[kernel]
+    assert bq <= max(most_q, s if causal else 0) and bk <= most_k
+    tile = fa._seq_tile(s, bq, bk)
+    assert tile % bq == 0 and tile % bk == 0 and tile <= 4096
+    # inside the kernel's budget (the forward's the default scope, the
+    # backward's the core's VMEM, its dQ accumulator counted), or the
+    # old 128 x 128 when nothing is
+    need = fa._vmem_bytes(kernel, bq, bk, d, itemsize, tile, s)
+    assert need <= fa._VMEM_BUDGET[kernel] or (bq, bk) == (128, 128)
+    if kernel == "bwd":
+        assert need >= s * 128 * 4
+    # bf16 gets the preferred tile wherever the sequence allows it,
+    # but a causal sequence of one preferred tile (the test below)
+    if itemsize == 2 and s % 1024 == 0 and not (causal and s == 1024):
+        assert (bq, bk) == (most_q, most_k)
+    # an explicit block is honoured as before, derived or not beside it
+    assert fa._score_tile(kernel, s, d, itemsize, causal, 32, 384) == (
+        32, fa._blocks(s, 384), False)
+    assert fa._score_tile(kernel, s, d, itemsize, causal, None,
+                          128) == (bq, 128, True)
+    assert fa._score_tile(kernel, s, d, itemsize, causal, None,
+                          None) == (bq, bk, True)
 
 
 def _visited(kernel, s, block_q, block_k):
     """Score sub-blocks of the [s, s] square a causal call of ``kernel``
     passes through, as the kernels skip (``_causal_n_eff``; ``start``)."""
     n_q, n_k = s // block_q, s // block_k
-    if kernel == "dkv":
+    if kernel == "bwd":
         return sum(n_q - ki * block_k // block_q for ki in range(n_k))
     return sum(min(-(-(qi + 1) * block_q // block_k), n_k)
                for qi in range(n_q))
 
 
-# what the v5e chose (PERF.md section 6, PR 27) as (block_q, block_k,
-# sub-blocks visited); 4096 and 8192 are PR 25's
-_PR25 = {"fwd": (512, 1024), "dq": (512, 512), "dkv": (1024, 512)}
+# what the v5e chose as (block_q, block_k, sub-blocks visited): the
+# forward's in PRs 25 and 27, the one backward kernel's in PR 29, where
+# dK/dV's tiles stayed the best or within 2% of it (PERF.md section 6)
+_PREFERRED = {"fwd": (512, 1024), "bwd": (1024, 512)}
 _CAUSAL_TILES = {
     ("fwd", 1024): (1024, 1024, 1),     # whole: a forward pass costs by
-    ("dq", 1024): (512, 512, 3),        # its rows whatever it skips
-    ("dkv", 1024): (512, 512, 3),       # (PR 25's 1024 x 512: 2 of 2)
-    ("fwd", 2048): (512, 1024, 6),
-    ("dq", 2048): (512, 512, 10),
-    ("dkv", 2048): (1024, 512, 6),
+    ("bwd", 1024): (512, 512, 3),       # its rows whatever it skips
+    ("fwd", 2048): (512, 1024, 6),      # (1024 x 512 at 1024: 2 of 2,
+    ("bwd", 2048): (1024, 512, 6),      # 18% slower)
 }
 
 
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("d", [64, 128])
 @pytest.mark.parametrize("s", [1024, 2048, 4096, 8192])
-@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
 def test_derived_tile_is_the_one_the_chip_chose(kernel, s, d, causal):
     from horovod_tpu.ops import flash_attention as fa
 
     got = fa._derive_tile(kernel, s, d, 2, causal)
     if not causal or s >= 4096:
-        assert got == _PR25[kernel]
+        assert got == _PREFERRED[kernel]
         return
     bq, bk, visited = _CAUSAL_TILES[kernel, s]
     assert got == (bq, bk)
@@ -449,13 +458,15 @@ def test_derived_tile_is_the_one_the_chip_chose(kernel, s, d, causal):
 
 
 # (seq, head_dim) of the benchmark's cells (gpt2-large at 1024 and 4096,
-# OLMoE's 4096 x 128) and two beyond them -> the positions each kernel
-# streams a grid step, as PR 27 computed them with no variable set: the
-# whole sequence up to 4096, half of 8192
+# OLMoE's 4096 x 128) and some beyond them (the ring's local block of
+# 16384 over four chips is 4096) -> the positions each kernel streams a
+# grid step, as PR 27 computed them with no variable set: the whole
+# sequence up to 4096, half of 8192, a quarter of 16384
 @pytest.mark.parametrize("s,d,want", [
     (1024, 64, 1024), (4096, 64, 4096), (4096, 128, 4096),
-    (8192, 64, 4096), (1536, 64, 1536)])
-@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+    (8192, 64, 4096), (1536, 64, 1536), (2048, 64, 2048),
+    (8192, 128, 4096), (16384, 64, 4096)])
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
 def test_streamed_tile_is_the_one_the_cells_run_with(kernel, s, d, want):
     from horovod_tpu.ops import flash_attention as fa
 
@@ -489,7 +500,7 @@ def test_derived_tile_of_a_sequence_that_is_no_multiple_of_128(s, want):
     # the one block the old default of 128 gave
     from horovod_tpu.ops import flash_attention as fa
 
-    for kernel in ("fwd", "dq", "dkv"):
+    for kernel in ("fwd", "bwd"):
         for causal in (True, False):
             assert fa._derive_tile(kernel, s, 64, 2, causal) == (
                 want, want)
@@ -506,7 +517,7 @@ def test_trace_counter_carries_the_tile_and_who_chose_it():
     from horovod_tpu.ops import flash_attention as fa
 
     q, k, v = _qkv(b=1, s=256, h=1)
-    kernels = ("fwd", "dq", "dkv")
+    kernels = ("fwd", "bwd")
 
     def counts(derived):
         out = []
@@ -520,7 +531,8 @@ def test_trace_counter_carries_the_tile_and_who_chose_it():
         return out
 
     # two layers of one shape: each kernel is a jit of its own, traced
-    # once for both (and once a process, hence the cleared caches)
+    # once for both (and once a process, hence the cleared caches); the
+    # backward pass of both is the one kernel="bwd"
     trace = lambda **kw: jax.make_jaxpr(jax.grad(
         lambda q, k, v: fa.flash_attention(
             fa.flash_attention(q, k, v, **kw), k, v, **kw).sum(),
@@ -556,17 +568,116 @@ def test_trace_counter_adds_nothing_to_the_program(monkeypatch):
 
 
 def test_scoped_vmem_is_raised_only_where_the_estimate_nears_it():
-    # the default scope for the small tiles tests and old callers pass,
-    # a limit above the estimate for the one derived tile that nears it
+    # the default scope for the small tiles tests and old callers pass; a
+    # limit of the estimate and XLA's 3 MiB where that is over it, and no
+    # more (a wider scope slowed the step around the call: PERF.md section
+    # 6, PR 29): for the backward at every long shape a cell runs, its dQ
+    # accumulator included
     from horovod_tpu.ops import flash_attention as fa
 
-    assert fa._compiler_params("fwd", 512, 1024, 64, 2, 4096) is None
-    assert fa._compiler_params("dq", 512, 512, 128, 2, 4096) is None
-    assert fa._compiler_params("dkv", 128, 128, 64, 2, 4096) is None
-    need = fa._vmem_bytes("dkv", 1024, 512, 64, 2, 4096)
-    limit = fa._compiler_params("dkv", 1024, 512, 64, 2,
-                                4096).vmem_limit_bytes
-    assert need <= fa._SCOPED_VMEM < limit and limit >= need * 1.5
+    assert fa._compiler_params("fwd", 512, 1024, 64, 2, 4096, 4096) is None
+    assert fa._compiler_params("fwd", 1024, 1024, 64, 2, 1024, 1024) is None
+    assert fa._compiler_params("bwd", 128, 128, 64, 2, 1024, 1024) is None
+    assert fa._compiler_params("bwd", 512, 512, 64, 2, 1024, 1024) is None
+    for bq, bk, d, s in [(1024, 512, 64, 4096), (1024, 512, 128, 4096),
+                         (1024, 512, 64, 8192)]:
+        need = fa._vmem_bytes("bwd", bq, bk, d, 2, 4096, s)
+        limit = fa._compiler_params("bwd", bq, bk, d, 2, 4096,
+                                    s).vmem_limit_bytes
+        assert fa._SCOPED_VMEM < limit == need + 3 * 2 ** 20 < 32 * 2 ** 20
+    # the accumulator is the sequence's: 512 bytes a position of 128 lanes
+    assert (fa._vmem_bytes("bwd", 1024, 512, 64, 2, 4096, 8192)
+            - fa._vmem_bytes("bwd", 1024, 512, 64, 2, 4096, 4096)
+            == 4096 * 128 * 4)
+
+
+@pytest.mark.parametrize("s, fits", [(65536, True), (131072, True),
+                                     (262144, False)])
+def test_backward_names_a_sequence_its_accumulator_cannot_hold(s, fits):
+    """The float32 dQ accumulator is as long as the sequence: past what a
+    core's VMEM holds the backward pass says so at trace time, before
+    Mosaic, and names the ways out; the forward, whose buffers are a
+    tile's, traces at any length."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    q = jax.ShapeDtypeStruct((1, s, 1, 64), jnp.bfloat16)
+    loss = lambda q, k, v: flash_attention(q, k, v).astype(
+        jnp.float32).sum()
+    assert jax.eval_shape(loss, q, q, q).shape == ()
+    if fits:
+        grads = jax.eval_shape(jax.grad(loss, (0, 1, 2)), q, q, q)
+        assert [g.shape for g in grads] == [q.shape] * 3
+        bq, bk = fa._derive_tile("bwd", s, 64, 2, True)
+        assert (bq, bk) == fa._PREFERRED_TILE["bwd"]
+    else:
+        with pytest.raises(ValueError, match="ring_attention"):
+            jax.eval_shape(jax.grad(loss, (0, 1, 2)), q, q, q)
+
+
+def _parent_gradients():
+    with open(os.path.join(os.path.dirname(__file__), "data",
+                           "flash_bwd_pr28.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [1024, 2048])
+def test_fused_backward_equals_the_two_kernels_it_replaced(s, d, causal):
+    """dq, dk, dv of the one kernel against what PR 28's dQ and dK/dV
+    kernels gave for the same bf16 operands at their derived tiles
+    (interpreter; 48 fixed positions of each gradient and its norm,
+    written by that commit's module from ``RandomState(s + d + causal)``).
+    Same products, same operands, same order over the key blocks: they
+    were equal to the bit when this was written; the tolerance is one
+    bf16 rounding."""
+    want = _parent_gradients()[f"{s}-{d}-{int(causal)}"]
+    rs = np.random.RandomState(s + d + causal)
+    q, k, v, w = (jnp.asarray(rs.randn(1, s, 1, d), jnp.bfloat16)
+                  for _ in range(4))
+    grads = jax.grad(
+        lambda q, k, v: (flash_attention(q, k, v, causal=causal).astype(
+            jnp.float32) * w.astype(jnp.float32)).sum(), (0, 1, 2))(q, k, v)
+    at = np.random.RandomState(1).choice(s * d, 48, replace=False)
+    for name, g in zip(("dq", "dk", "dv"), grads):
+        g = np.asarray(g, np.float32)
+        np.testing.assert_allclose(g.ravel()[at], want[name]["values"],
+                                   rtol=2 ** -7, atol=1e-6, err_msg=name)
+        np.testing.assert_allclose(np.linalg.norm(g.astype(np.float64)),
+                                   want[name]["norm"], rtol=1e-4)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [1024, 2048])
+def test_fused_backward_matches_the_f32_formula(s, d, causal):
+    """The backward kernel as ring attention calls it (``out_dtype``
+    float32, cotangents on o and lse both) over a grouped-query pair of
+    heads, at its derived tile, against the float32 formula."""
+    from horovod_tpu.ops.flash_attention import flash_attention_with_lse
+
+    rs = np.random.RandomState(5)
+    q = jnp.asarray(rs.randn(1, s, 2, d), jnp.float32)
+    k, v = (jnp.asarray(rs.randn(1, s, 1, d), jnp.float32)
+            for _ in range(2))
+    w_o = jnp.asarray(rs.randn(1, s, 2, d), jnp.float32)
+    w_lse = jnp.asarray(rs.randn(1, s, 2), jnp.float32)
+
+    def grads(attend):
+        def loss(q, k, v):
+            o, lse = attend(q, k, v)
+            assert o.dtype == jnp.float32
+            return (o * w_o).sum() + (lse * w_lse).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    got = grads(lambda q, k, v: flash_attention_with_lse(
+        q, k, v, causal=causal, out_dtype=jnp.float32))
+    want = grads(lambda q, k, v: _dense_o_lse(q, k, v, causal))
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)
 
 
 def test_kernels_trace_under_shard_map_with_check_vma():
