@@ -20,9 +20,11 @@ compile seconds and cache hits, so a second run shows the hit.
 
 Phases, each something no cell of the benchmark decides. One chip:
 ``launcher`` (``hvtrun --backend jax`` reaches the chip), ``device``
-(topology; ``block_until_ready`` waits), ``flash8192`` (the kernels at 1 x
-8192 against the float32 formula: only there do they stream more than one
-sequence tile a grid step on a chip), ``eager`` (the immediate path). Four
+(topology; ``block_until_ready`` waits), ``flash8192`` (the kernels at 2 x
+8192, four query heads on one key-value head of 128, the attention of the
+cell ``nemotron3s-s8192``, against the float32 formula: only at that length
+do they stream more than one sequence tile a grid step on a chip, and the
+cell compares its gradients at 2048 positions), ``eager`` (the immediate path). Four
 chips: ``device``, ``ring4`` (``parallel/sequence.py``, ``sp`` = 4),
 ``dryrun4`` (the GSPMD dp x sp x tp step against one device).
 
@@ -293,10 +295,12 @@ def flash_kernel_vs_f32(shape):
     return {"shape": list(shape), "rel_l2": errs}
 
 
-def phase_flash8192(shape=(1, 8192, 12, 12, 64)):
+def phase_flash8192(shape=(2, 8192, 4, 1, 128)):
     """The compiled kernels where each streams two tiles of 4096 positions
-    a grid step, forward and backward; the benchmark's cells stream their
-    whole sequence in one."""
+    a grid step, forward and backward, at the attention shape of the cell
+    ``nemotron3s-s8192`` (2 x 8192, four query heads reading one key-value
+    head, d = 128): that cell's own comparison of gradients runs at 2048
+    positions, where a sequence is one tile."""
     import jax
     import jax.numpy as jnp
 

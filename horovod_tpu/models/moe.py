@@ -29,6 +29,30 @@ Dispatch and combine are a permutation and its inverse, so both
 directions of both are gathers (``_take_rows``): the gradient program
 holds no scatter-add, which a TPU serialises.
 
+The same layer builds what other sparse models ask for, each an option
+that defaults to the above: ``score="sigmoid"`` (scores ``sigmoid(h
+W_r)``, the choice made from ``scores + choice_bias``, a buffer that
+takes no gradient, the weights the scores at the chosen, divided by
+their sum and times ``route_scale``; no auxiliary loss, ``aux`` is
+``{}``), ``expert_act="relu2"`` (``down(relu(up(x))^2)``, two stacks),
+``latent`` (the routed experts work in a narrower width between a down-
+and an up-projection, scope ``moe_latent``) and ``shared_ff`` (an expert
+every token passes, beside the routed ones, scope ``moe_shared``).
+
+**A chip's share**: ``held = (first, count)`` builds the stacks for
+experts ``[first, first + count)`` alone. The router still scores and
+chooses over all ``n_experts``; only an assignment to a held expert gets
+a row, and the layer returns the held experts' part of the sum (plus what
+every chip computes alike: the latent projections and the shared expert).
+Nothing is dropped: a token has one slot a held expert, the slots are
+sorted by expert with the unassigned last, and the first ``T x min(k,
+count)`` of them (the most that can be assigned, a token choosing an
+expert once) are the rows. The group sizes sum to the rows really
+assigned, and the grouped product does not visit the tiles past them
+(``megablox`` takes group sizes that sum to fewer rows than it is given);
+what it leaves there is masked out on both sides of the experts. No code
+stands in for the other chips or for the exchange with them.
+
 Expert parallelism: the stacks carry ``P("ep", ...)`` in
 ``moe_param_partition_spec`` (and in ``transformer.param_partition_spec``
 when given an ``ep_axis``); how GSPMD divides the ragged products over
@@ -54,7 +78,7 @@ PRODUCT = "megablox_gmm"
 _GMM_TILE = (512, 1024, 1024)
 
 
-def _count_trace(n_experts, top_k):
+def _count_trace(n_experts, top_k, held):
     """The engagement counter: one count a traced layer. Trace-time
     Python only."""
     try:
@@ -64,9 +88,9 @@ def _count_trace(n_experts, top_k):
             "hvt_moe_layers_traced_total",
             "mixture-of-experts layers traced into compiled programs "
             "(counted per trace, not per execution)",
-            ("experts", "top_k", "product"),
-        ).labels(experts=str(n_experts), top_k=str(top_k),
-                 product=PRODUCT).inc()
+            ("experts", "top_k", "product", "held"),
+        ).labels(experts=str(n_experts), top_k=str(top_k), product=PRODUCT,
+                 held=str(held[1] if held else n_experts)).inc()
     except Exception:
         pass  # telemetry must never break a trace
 
@@ -75,22 +99,32 @@ def _rows(x, index):
     return x.at[index].get(mode="promise_in_bounds")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_rows(x, index, inverse, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _take_rows(x, index, inverse, k, n_rows=None):
     """Rows ``index // k`` of ``x [n, d]``, where ``index`` is a
-    permutation of ``range(n x k)`` and ``inverse`` its inverse. The
-    transpose of a gather is a scatter-add; of a permutation it is the
-    gather by the inverse (then the sum over the ``k`` copies of a row),
-    which is what the backward pass runs."""
+    permutation of ``range(n x k)`` and ``inverse`` its inverse; the
+    first ``n_rows`` of them where that is given. The transpose of a
+    gather is a scatter-add; of a permutation it is the gather by the
+    inverse (then the sum over the ``k`` copies of a row), which is what
+    the backward pass runs; rows that were cut off come back as zeros."""
+    if n_rows is not None:
+        index = index[:n_rows]
     return _rows(x, index // k if k > 1 else index)
 
 
-def _take_rows_fwd(x, index, inverse, k):
-    return _take_rows(x, index, inverse, k), inverse
+def _take_rows_fwd(x, index, inverse, k, n_rows):
+    return _take_rows(x, index, inverse, k, n_rows), inverse
 
 
-def _take_rows_bwd(k, inverse, g):
-    g = _rows(g, inverse)
+def _pad_rows(rows, n):
+    """``rows [m, d]`` with zeros after them up to ``n`` rows."""
+    if rows.shape[0] == n:
+        return rows
+    return jnp.pad(rows, ((0, n - rows.shape[0]), (0, 0)))
+
+
+def _take_rows_bwd(k, n_rows, inverse, g):
+    g = _rows(_pad_rows(g, inverse.shape[0]), inverse)
     if k > 1:
         g = g.reshape(-1, k, g.shape[-1]).sum(1)
     return g, None, None
@@ -99,7 +133,8 @@ def _take_rows_bwd(k, inverse, g):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-def moe_route(h, router, k):
+def moe_route(h, router, k, *, score="softmax", bias=None, scale=1.0,
+              held=None):
     """``h [T, d]``, ``router [d, E]`` -> the ``k`` choices of every
     token. Returns ``(experts [T, k], weights [T, k] float32, order
     [T x k], inverse [T x k], group_sizes [E], aux, probs [T, E])``:
@@ -107,20 +142,57 @@ def moe_route(h, router, k):
     ``i`` is choice ``i % k`` of token ``i // k``) by expert, stably;
     ``inverse`` is its inverse permutation; ``aux`` holds the two losses,
     unweighted; ``probs`` is the softmax the weights were taken from. All
-    of it in float32 whatever the experts compute in."""
+    of it in float32 whatever the experts compute in.
+
+    ``score="sigmoid"``: ``probs`` is ``sigmoid(h W_r)``, the choice is
+    the ``k`` largest of ``probs + bias`` (``bias [E]`` takes no
+    gradient: it only enters the choice), the weights are ``probs`` at
+    the chosen over their sum + 1e-20, times ``scale``; ``aux`` is
+    ``{}``.
+
+    ``held = (first, count)``: the choice is still over all ``E``, but a
+    token has a slot a *held* expert in place of one a choice:
+    ``weights [T, count]`` (0 where the token did not choose the expert),
+    ``order`` and ``inverse`` over the ``T x count`` slots (token-major),
+    sorted by held expert with the slots nobody chose last, and
+    ``group_sizes [count]``, which sum to the slots really assigned."""
     n_tokens, n_experts = h.shape[0], router.shape[-1]
     logits = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    _, experts = jax.lax.top_k(jax.lax.stop_gradient(probs), k)
+    if score == "softmax":
+        probs = pick = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        pick = probs if bias is None else probs + bias.astype(jnp.float32)
+    else:
+        raise ValueError(f"score {score!r}: softmax or sigmoid")
+    _, experts = jax.lax.top_k(jax.lax.stop_gradient(pick), k)
     # [T, k, E]; the weights and the counts as sums over it, so that
     # neither a gather's transpose nor a histogram puts a scatter in
     chosen = experts[..., None] == jnp.arange(n_experts)
-    weights = jnp.sum(jnp.where(chosen, probs[:, None, :], 0.0), axis=-1)
-    group_sizes = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)
-    order = jnp.argsort(experts.reshape(-1), stable=True)
+    if held is None:
+        weights = jnp.sum(jnp.where(chosen, probs[:, None, :], 0.0), axis=-1)
+        counts = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)
+    else:       # [T, E]: a token chooses an expert at most once
+        assigned = jnp.any(chosen, axis=1)
+        weights = jnp.where(assigned, probs, 0.0)
+        counts = jnp.sum(assigned, axis=0, dtype=jnp.int32)
+    group_sizes = counts
+    if score == "sigmoid":
+        weights = weights * (scale / (
+            jnp.sum(weights, axis=-1, keepdims=True) + 1e-20))
+    if held is None:
+        order = jnp.argsort(experts.reshape(-1), stable=True)
+    else:
+        first, count = held
+        mine = slice(first, first + count)
+        weights, group_sizes = weights[:, mine], counts[mine]
+        order = jnp.argsort(jnp.where(assigned[:, mine], jnp.arange(count),
+                                      count).reshape(-1), stable=True)
     inverse = jnp.argsort(order)
-    share = group_sizes.astype(jnp.float32) / (n_tokens * k)
+    if score == "sigmoid":
+        return experts, weights, order, inverse, group_sizes, {}, probs
+    share = counts.astype(jnp.float32) / (n_tokens * k)
     aux = {
         "load_balance": n_experts * jnp.sum(share * probs.mean(axis=0)),
         "router_z": jnp.mean(
@@ -129,9 +201,18 @@ def moe_route(h, router, k):
     return experts, weights, order, inverse, group_sizes, aux, probs
 
 
-def moe_dispatch(h, order, inverse, k):
-    """``h [T, d]`` -> its rows in expert order, ``[T x k, d]``."""
-    return _take_rows(h, order, inverse, k)
+def held_rows(n_tokens, k, held):
+    """The static row bound of a share ``held = (first, count)``: the
+    most of its ``n_tokens x count`` slots that can be assigned, a token
+    choosing ``k`` experts and each at most once. None (every row) for a
+    layer that holds every expert."""
+    return None if held is None else n_tokens * min(k, held[1])
+
+
+def moe_dispatch(h, order, inverse, k, n_rows=None):
+    """``h [T, d]`` -> its rows in expert order, ``[T x k, d]`` (``k``
+    the slots a token has; the first ``n_rows`` where given)."""
+    return _take_rows(h, order, inverse, k, n_rows)
 
 
 def _interpret() -> bool:
@@ -152,61 +233,138 @@ def _grouped_product(lhs, rhs, group_sizes):
                tiling=tile, interpret=_interpret())
 
 
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
 def moe_experts(rows, gate, up, down, group_sizes):
     """``down(silu(gate(x)) * up(x))`` of every row by its group's
-    expert, multiplied in ``rows.dtype``."""
+    expert, multiplied in ``rows.dtype``; ``gate=None``:
+    ``down(relu(up(x))^2)``."""
+    if gate is None:
+        hidden = _relu2(_grouped_product(rows, up.astype(rows.dtype),
+                                         group_sizes))
+        return _grouped_product(hidden, down.astype(rows.dtype), group_sizes)
     gate, up, down = (w.astype(rows.dtype) for w in (gate, up, down))
     hidden = (jax.nn.silu(_grouped_product(rows, gate, group_sizes))
               * _grouped_product(rows, up, group_sizes))
     return _grouped_product(hidden, down, group_sizes)
 
 
+def _assigned_rows(rows, group_sizes):
+    """``rows`` with zeros from row ``sum(group_sizes)`` on: the grouped
+    product does not visit those tiles, so what it returns there, and to
+    there in its backward pass, is whatever the memory held."""
+    real = jnp.arange(rows.shape[0]) < jnp.sum(group_sizes)
+    return jnp.where(real[:, None], rows, jnp.zeros((), rows.dtype))
+
+
 def moe_combine(rows, weights, order, inverse):
-    """Rows ``[T x k, d]`` in expert order -> ``[T, d]`` float32: each
-    token's ``k`` rows times their weights, summed."""
+    """Rows ``[T x k, d]`` in expert order (or the first of them, the
+    rest taken as zeros) -> ``[T, d]`` float32: each token's ``k`` rows
+    times their weights, summed."""
     k = weights.shape[-1]
-    rows = _take_rows(rows, inverse, order, 1)
+    rows = _take_rows(_pad_rows(rows, inverse.shape[0]), inverse, order, 1)
     rows = rows.reshape(-1, k, rows.shape[-1]).astype(jnp.float32)
     return jnp.sum(rows * weights[..., None], axis=1)
 
 
 class MoEMlp(nn.Module):
-    """The expert layer of a block: ``n_experts`` SwiGLU experts of width
+    """The expert layer of a block: ``n_experts`` experts of width
     ``d_ff``, ``experts_per_token`` of them a token, nothing dropped.
-    Returns ``(out, aux)``; ``aux`` is ``{"load_balance", "router_z"}``,
-    each loss unweighted (OLMoE trains with 0.01 and 0.001). For a
-    caller that asks for the collection ``intermediates``, what the
-    router saw and said is sown there: ``router_input [T, d]``,
-    ``router_probs [T, E]`` and the chosen ``experts [T, k]``."""
+    By default OLMoE's: softmax scores, SwiGLU experts (``gate``, ``up``,
+    ``down``) on the model's width, every expert here. Returns ``(out,
+    aux)``; ``aux`` is ``{"load_balance", "router_z"}``, each loss
+    unweighted (OLMoE trains with 0.01 and 0.001), and ``{}`` for
+    ``score="sigmoid"``. For a caller that asks for the collection
+    ``intermediates``, what the router saw and said is sown there:
+    ``router_input [T, d]``, ``router_probs [T, E]`` and the chosen
+    ``experts [T, k]``.
+
+    ``score``, ``route_scale``: see ``moe_route``; the sigmoid router's
+    ``choice_bias [E]`` lives in the collection ``buffers`` (zeros; no
+    gradient reaches it and no optimizer sees it). ``expert_act``:
+    ``"swiglu"`` or ``"relu2"`` (stacks ``up`` and ``down`` alone).
+    ``latent``: the routed experts' width, between ``latent_in [d,
+    latent]`` and ``latent_out [latent, d]`` (0: the model's own).
+    ``shared_ff``: the width of one shared expert of the experts' kind on
+    the layer's own input (0: none). ``held = (first, count)``: the
+    stacks hold experts ``[first, first + count)`` and the output is
+    their part of the sum, with the latent projections and the shared
+    expert, which every chip computes alike, in full."""
 
     n_experts: int
     d_ff: int
     experts_per_token: int
     dtype: Any = jnp.bfloat16
+    score: str = "softmax"
+    route_scale: float = 1.0
+    expert_act: str = "swiglu"
+    latent: int = 0
+    shared_ff: int = 0
+    held: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, x):
         d = x.shape[-1]
+        gated = {"swiglu": True, "relu2": False}[self.expert_act]
+        if self.held is not None and not (
+                0 <= self.held[0] and 0 < self.held[1]
+                and sum(self.held) <= self.n_experts):
+            raise ValueError(f"experts held {self.held} of {self.n_experts}")
+        stack = self.held[1] if self.held else self.n_experts
+        width = self.latent or d
         init = nn.initializers.normal(0.02)
         router = self.param("router", init, (d, self.n_experts))
-        gate = self.param("gate", init, (self.n_experts, d, self.d_ff))
-        up = self.param("up", init, (self.n_experts, d, self.d_ff))
-        down = self.param("down", init, (self.n_experts, self.d_ff, d))
-        _count_trace(self.n_experts, self.experts_per_token)
+        gate = self.param("gate", init, (stack, width, self.d_ff)) \
+            if gated else None
+        up = self.param("up", init, (stack, width, self.d_ff))
+        down = self.param("down", init, (stack, self.d_ff, width))
+        bias = self.variable(
+            "buffers", "choice_bias", jnp.zeros, (self.n_experts,),
+            jnp.float32).value if self.score == "sigmoid" else None
+        _count_trace(self.n_experts, self.experts_per_token, self.held)
+        slots = stack if self.held else self.experts_per_token
         h = x.reshape(-1, d)
         with jax.named_scope("moe_route"):
             (experts, weights, order, inverse, group_sizes, aux,
-             probs) = moe_route(h, router, self.experts_per_token)
+             probs) = moe_route(h, router, self.experts_per_token,
+                                score=self.score, bias=bias,
+                                scale=self.route_scale, held=self.held)
         for name, value in (("router_input", h), ("router_probs", probs),
                             ("experts", experts)):
             self.sow("intermediates", name, value)
+        # a [d_in, d_out] matrix of the layer, in what it multiplies in
+        dense = lambda name, *shape: self.param(name, init, shape).astype(
+            self.dtype)
+        low = lambda: h.astype(self.dtype)     # cast under the user's scope
+        if self.latent:
+            with jax.named_scope("moe_latent"):
+                tokens = jnp.dot(low(), dense("latent_in", d, width))
         with jax.named_scope("moe_dispatch"):
-            rows = moe_dispatch(h.astype(self.dtype), order, inverse,
-                                self.experts_per_token)
+            rows = moe_dispatch(
+                tokens if self.latent else low(), order, inverse, slots,
+                held_rows(h.shape[0], self.experts_per_token, self.held))
+            if self.held:
+                rows = _assigned_rows(rows, group_sizes)
         with jax.named_scope("moe_experts"):
             rows = moe_experts(rows, gate, up, down, group_sizes)
         with jax.named_scope("moe_combine"):
+            if self.held:
+                rows = _assigned_rows(rows, group_sizes)
             out = moe_combine(rows, weights, order, inverse)
+        if self.latent:
+            with jax.named_scope("moe_latent"):
+                out = jnp.dot(out.astype(self.dtype),
+                              dense("latent_out", width, d))
+        if self.shared_ff:
+            with jax.named_scope("moe_shared"):
+                hidden = jnp.dot(low(), dense("shared_up", d, self.shared_ff))
+                hidden = (jax.nn.silu(jnp.dot(low(), dense(
+                    "shared_gate", d, self.shared_ff))) * hidden
+                    if gated else _relu2(hidden))
+                out = out + jnp.dot(hidden, dense("shared_down",
+                                                  self.shared_ff, d))
         return out.reshape(x.shape).astype(x.dtype), aux
 
 

@@ -89,6 +89,45 @@ class GPTConfig:
     # [vocab, d_model], in place of the embedding's transpose.
     tie_embeddings: bool = True
     norm_eps: float = 1e-6
+    # One mixer a layer, in the order a string gives (hybrid models:
+    # Nemotron-H's ``hybrid_override_pattern``): layer i is ``x +
+    # mixer(RMSNorm(x))`` with the mixer ``layer_pattern[i]`` names, "*"
+    # attention, "M" a Mamba-2 mixer (models/ssm.py), "E" the expert
+    # layer (models/moe.py), "-" the dense MLP. None (default) = every
+    # layer the attention + MLP (or expert) pair of ``Block``.
+    layer_pattern: Optional[str] = None
+    # False leaves q and k unrotated: attention without a positional
+    # term (the state-space layers of a hybrid carry the order).
+    rotary: bool = True
+    # The dense MLP's activation: "gelu", or "relu2" (relu(x)^2).
+    mlp_act: str = "gelu"
+    # The Mamba-2 mixers' sizes: heads of ssm_head_dim channels in
+    # ssm_groups groups, a state of ssm_state a channel, ssm_conv taps.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    # The expert layer beyond OLMoE's (models/moe.py, ``MoEMlp``): the
+    # scoring ("softmax" | "sigmoid": with a choice bias, renormalised,
+    # times moe_route_scale), the experts' kind ("swiglu" | "relu2"), a
+    # latent width the routed experts work in (0: d_model) and one
+    # shared expert's width (0: none).
+    moe_score: str = "softmax"
+    moe_route_scale: float = 1.0
+    moe_expert_act: str = "swiglu"
+    moe_latent: int = 0
+    moe_shared_ff: int = 0
+    # A chip's share of every layer, each ``(first, count)`` and None =
+    # all: the routed experts whose stacks are here (the router stays
+    # n_experts wide), the attention's query heads (with the key-value
+    # heads they read) and the Mamba-2 heads (with their groups). The
+    # sizes above stay the model's; a mixer's output is then its share of
+    # the sum over all shares, and goes on to the next layer as it is:
+    # nothing stands in for the other chips or the exchange with them.
+    experts_held: Optional[tuple] = None
+    heads_held: Optional[tuple] = None
+    ssm_heads_held: Optional[tuple] = None
 
 
 # The crossover policy lives with the kernel (ops/flash_attention.py);
@@ -135,6 +174,24 @@ class RMSNorm(nn.Module):
         return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
 
 
+def held_heads(n_heads, n_kv, held):
+    """``(query heads, key-value heads)`` of a share ``held = (first,
+    count)`` of ``n_heads`` query heads over ``n_kv`` key-value heads:
+    either whole groups, or a part of one group that divides it, so that
+    the heads held group evenly over the key-value heads they read."""
+    if held is None:
+        return n_heads, n_kv
+    first, count = held
+    group = n_heads // n_kv
+    whole = count % group == 0 and first % group == 0
+    part = group % count == 0 and first % count == 0
+    if not (0 < count <= n_heads - first and (whole or part)):
+        raise ValueError(
+            f"heads held {held} of {n_heads} over {n_kv} key-value heads: "
+            f"a share is whole groups of {group}, or a divisor of one")
+    return count, max(1, count // group)
+
+
 class Attention(nn.Module):
     cfg: GPTConfig
 
@@ -150,15 +207,17 @@ class Attention(nn.Module):
             raise ValueError(
                 f"n_kv_heads ({n_kv}) must divide n_heads "
                 f"({cfg.n_heads})")
-        q = dense((cfg.n_heads, head_dim), "q")(x)
+        n_heads, n_kv = held_heads(cfg.n_heads, n_kv, cfg.heads_held)
+        q = dense((n_heads, head_dim), "q")(x)
         k = dense((n_kv, head_dim), "k")(x)
         v = dense((n_kv, head_dim), "v")(x)
         if cfg.qk_norm:
             full_width = lambda t, name: RMSNorm(cfg.norm_eps, name=name)(
                 t.reshape(*t.shape[:-2], -1)).reshape(t.shape)
             q, k = full_width(q, "q_norm"), full_width(k, "k_norm")
-        q = _rotary(q, positions)
-        k = _rotary(k, positions)
+        if cfg.rotary:
+            q = _rotary(q, positions)
+            k = _rotary(k, positions)
 
         if cfg.ring_mesh is not None:
             from horovod_tpu.parallel.sequence import ring_attention
@@ -173,7 +232,7 @@ class Attention(nn.Module):
             # there (the pre-r5 behavior) so the sharding stays valid.
             tp = dict(cfg.ring_mesh.shape).get("tp", 1)
             if n_kv % tp:
-                k, v = _repeat_kv(k, v, cfg.n_heads // n_kv)
+                k, v = _repeat_kv(k, v, n_heads // n_kv)
             # "auto" passes through UNRESOLVED: the ring shard function
             # resolves it against its local (post-shard_map) block
             # length, where the shape is unambiguous — dividing the
@@ -192,7 +251,7 @@ class Attention(nn.Module):
                                   scale=1.0 / np.sqrt(head_dim))
         else:
             # XLA turns the repeat into a broadcast inside the dot
-            k, v = _repeat_kv(k, v, cfg.n_heads // n_kv)
+            k, v = _repeat_kv(k, v, n_heads // n_kv)
             scores = jnp.einsum("...qhd,...khd->...hqk", q, k,
                                 preferred_element_type=jnp.float32)
             scores = scores / np.sqrt(head_dim)
@@ -215,9 +274,21 @@ class MLP(nn.Module):
         cfg = self.cfg
         h = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
                      param_dtype=jnp.float32, name="up")(x)
-        h = nn.gelu(h)
+        h = {"gelu": nn.gelu,
+             "relu2": lambda t: jnp.square(nn.relu(t))}[cfg.mlp_act](h)
         return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                         param_dtype=jnp.float32, name="down")(h)
+
+
+def _expert_layer(cfg: GPTConfig):
+    from horovod_tpu.models.moe import MoEMlp
+
+    return MoEMlp(cfg.n_experts, cfg.d_ff, cfg.experts_per_token,
+                  dtype=cfg.dtype, score=cfg.moe_score,
+                  route_scale=cfg.moe_route_scale,
+                  expert_act=cfg.moe_expert_act, latent=cfg.moe_latent,
+                  shared_ff=cfg.moe_shared_ff, held=cfg.experts_held,
+                  name="moe")
 
 
 class Block(nn.Module):
@@ -235,10 +306,39 @@ class Block(nn.Module):
         h = RMSNorm(cfg.norm_eps, name="ln2")(x)
         if not cfg.n_experts:
             return x + MLP(cfg, name="mlp")(h), None
-        from horovod_tpu.models.moe import MoEMlp
+        out, aux = _expert_layer(cfg)(h)
+        return x + out, aux
 
-        out, aux = MoEMlp(cfg.n_experts, cfg.d_ff, cfg.experts_per_token,
-                          dtype=cfg.dtype, name="moe")(h)
+
+class MixerBlock(nn.Module):
+    """One layer of a patterned model: ``x + mixer(RMSNorm(x))`` with the
+    one mixer ``kind`` names (``GPTConfig.layer_pattern``). Returns
+    ``(x, aux)`` as ``Block`` does."""
+
+    cfg: GPTConfig
+    kind: str
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg, aux = self.cfg, None
+        h = RMSNorm(cfg.norm_eps, name="norm")(x)
+        if self.kind == "*":
+            out = Attention(cfg, name="attn")(h, positions)
+        elif self.kind == "M":
+            from horovod_tpu.models.ssm import Mamba2Mixer
+
+            out = Mamba2Mixer(
+                cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                cfg.ssm_state, cfg.ssm_conv, held=cfg.ssm_heads_held,
+                norm_eps=cfg.norm_eps, dtype=cfg.dtype, name="ssm")(h)
+        elif self.kind == "E":
+            out, aux = _expert_layer(cfg)(h)
+        elif self.kind == "-":
+            out = MLP(cfg, name="mlp")(h)
+        else:
+            raise ValueError(
+                f"layer_pattern holds {self.kind!r}: a layer is one of "
+                f"'*' (attention), 'M' (Mamba-2), 'E' (experts), '-' (MLP)")
         return x + out, aux
 
 
@@ -264,12 +364,20 @@ class GPT(nn.Module):
                          (cfg.vocab_size, cfg.d_model), jnp.float32)
         with jax.named_scope("embed"):
             x = emb[tokens].astype(cfg.dtype)
-        block = Block
+        if cfg.layer_pattern is not None and (
+                len(cfg.layer_pattern) != cfg.n_layers):
+            raise ValueError(
+                f"layer_pattern {cfg.layer_pattern!r} names "
+                f"{len(cfg.layer_pattern)} layers, n_layers is "
+                f"{cfg.n_layers}")
+        block = Block if cfg.layer_pattern is None else MixerBlock
         if cfg.remat:
-            block = nn.remat(Block, static_argnums=())
+            block = nn.remat(block, static_argnums=())
         aux = {}
         for i in range(cfg.n_layers):
-            x, layer_aux = block(cfg, name=f"block_{i}")(x, positions)
+            kind = () if cfg.layer_pattern is None else (
+                cfg.layer_pattern[i],)
+            x, layer_aux = block(cfg, *kind, name=f"block_{i}")(x, positions)
             if layer_aux is not None:
                 aux = {name: aux.get(name, 0.0) + value
                        for name, value in layer_aux.items()}
@@ -313,6 +421,10 @@ def param_partition_spec(params, *, tp_axis="tp", tp_size=None,
             from horovod_tpu.models.moe import expert_leaf_spec
 
             return expert_leaf_spec(names[-1], leaf, ep_axis, tp_axis)
+        if "ssm" in names:
+            from horovod_tpu.models.ssm import ssm_leaf_spec
+
+            return ssm_leaf_spec(names[-1], tp_axis)
         if any(n in ("q", "k", "v") for n in names):
             heads = leaf.shape[1] if hasattr(leaf, "shape") else None
             if tp_size and heads is not None and heads % tp_size:

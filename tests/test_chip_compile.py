@@ -71,7 +71,8 @@ def _qkv(shape, sharding):
 
 # (batch, seq, heads, kv_heads, head_dim): the shapes a 12 x 768 GPT and
 # chip_smoke.py run, the benchmark cells gpt2l-s1024's, gpt2l-s4096's and
-# olmoe-s4096's own, gpt2l-dp4's (four chips' batch under a shard_map
+# olmoe-s4096's and nemotron3s-s8192's own (four query heads on one
+# key-value head at 8192 positions), gpt2l-dp4's (four chips' batch under a shard_map
 # that checks vma), gpt2-large's heads at 4 x 2048 and at 1 x 8192 (two
 # streamed tiles: the backward's dQ accumulator is addressed by a dynamic
 # slice and leaves a tile at a time), one grouped-query shape, the 4-chip
@@ -91,6 +92,7 @@ def _qkv(shape, sharding):
                  id="flash-gpt2l-dp4-shard-map"),
     pytest.param("flash", (1, 4096, 32, 8, 128), id="flash-gqa-32-8-128"),
     pytest.param("flash", (2, 4096, 16, 16, 128), id="flash-olmoe-s4096"),
+    pytest.param("flash", (2, 8192, 4, 1, 128), id="flash-nemotron3s-s8192"),
     pytest.param("ring", (1, 16384, 12, 12, 64), id="ring-sp4-16384"),
     pytest.param("refused", (1, 100, 2, 2, 64), id="block-not-multiple-of-8"),
 ])
@@ -188,3 +190,70 @@ def test_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices):
         if " scatter(" in line:
             shape = re.search(r"= \w+\[([\d,]*)\]", line).group(1)
             assert "," not in shape and int(shape) <= 2 * experts + tiles, line
+
+
+def _shapes_on(device, init, *args):
+    one = SingleDeviceSharding(device)
+    place = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    args = place(args)
+    return place(dict(jax.eval_shape(init, jax.random.key(0), *args))), args
+
+
+def test_mamba2_layer_compiles_for_v5e(v5e_devices):
+    """The benchmark cell nemotron3s-s8192's Mamba-2 mixer at its own
+    sizes (2 x 8192 positions of 4096, 16 of 128 heads of 64 in 1 of 8
+    groups, a state of 128, bf16), forward and backward: the chunked scan
+    is plain XLA (no Pallas call), its carry one loop each way, and its
+    temporaries fit beside a training step (under 2 GiB)."""
+    from horovod_tpu.models import ssm
+
+    layer = ssm.Mamba2Mixer(128, 64, 8, 128, held=(0, 16),
+                            dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((2, 8192, 4096), jnp.bfloat16)
+    variables, (x,) = _shapes_on(v5e_devices[0], layer.init, x)
+    assert variables["params"]["in_proj"].shape == (4096, 2 * 1024 + 256 + 16)
+
+    def loss(params, x):
+        return jnp.mean(layer.apply({"params": params}, x).astype(
+            jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        variables["params"], x).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert len(re.findall(r" while\(", text)) >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
+
+
+def test_held_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices):
+    """The benchmark cell nemotron3s-s8192's expert layer at its own
+    sizes (2 x 8192 tokens of 4096, a sigmoid router over 512 experts, 22
+    a token, experts 0 to 7 held, relu2 experts of 2688 in a latent of
+    1024, a shared expert of 5376, bf16), forward and backward: two
+    stacks, so six grouped products as Pallas calls over the static bound
+    of 16384 x 8 rows; the only scatters are the products' bookkeeping."""
+    tokens, d, k, held = 2 * 8192, 4096, 22, (0, 8)
+    layer = moe.MoEMlp(512, 2688, k, dtype=jnp.bfloat16, score="sigmoid",
+                       route_scale=5.0, expert_act="relu2", latent=1024,
+                       shared_ff=5376, held=held)
+    x = jax.ShapeDtypeStruct((tokens, d), jnp.bfloat16)
+    variables, (x,) = _shapes_on(v5e_devices[0], layer.init, x)
+    assert variables["params"]["up"].shape == (8, 1024, 2688)
+    assert variables["buffers"]["choice_bias"].shape == (512,)
+
+    def loss(params, x, buffers):
+        out, aux = layer.apply({"params": params, "buffers": buffers}, x)
+        assert aux == {}
+        return jnp.mean(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        variables["params"], x, variables["buffers"]).compile().as_text()
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) == 6
+    rows = moe.held_rows(tokens, k, held)
+    assert rows == tokens * 8 and f"bf16[{rows},1024]" in text
+    tiles = rows // 512
+    for line in text.splitlines():
+        if " scatter(" in line:
+            shape = re.search(r"= \w+\[([\d,]*)\]", line).group(1)
+            assert "," not in shape and int(shape) <= 2 * 8 + tiles, line

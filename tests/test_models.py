@@ -1,6 +1,9 @@
 """Model + sharding tests (the reference has no models of its own; these
 cover the benchmark/flagship models and the driver entry contract)."""
 
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -738,8 +741,9 @@ def test_dense_gpt_is_the_parents():
         lambda p: model.apply({"params": p}, tokens).sum())).lower(
             params).as_text(debug_info=True)))
     assert any("/block_1/mlp/" in n for n in names)
-    for new in ("moe", "q_norm", "k_norm"):
+    for new in ("moe", "q_norm", "k_norm", "ssm", "/norm/"):
         assert not [n for n in names if new in n], new
+    assert set(model.init(jax.random.key(0), tokens)) == {"params"}
 
 
 def test_param_partition_spec_of_a_sparse_model():
@@ -762,6 +766,8 @@ def test_param_partition_spec_of_a_sparse_model():
     ("qk_norm", True, {"q_norm", "k_norm"}),
     ("tie_embeddings", False, {"lm_head"}),
     ("norm_eps", 1e-2, set()),
+    ("rotary", False, set()),
+    ("mlp_act", "relu2", set()),
 ])
 def test_gpt_config_field_changes_its_part_only(field, value, new_leaves):
     """Each field OLMoE's block needed: the leaves it adds, and logits
@@ -784,3 +790,231 @@ def test_gpt_config_field_changes_its_part_only(field, value, new_leaves):
     want = GPT(base).apply({"params": base_params}, tokens)
     assert got.shape == want.shape
     assert float(jnp.max(jnp.abs(got - want))) > 1e-4
+
+
+def test_sparse_gpt_is_the_parents():
+    """What the olmoe-1b-7b cell builds: the parameter tree of the commit
+    before the hybrid fields (three stacks over every expert, no buffer,
+    no latent or shared leaf), both auxiliary losses, and a step that
+    carries the four scopes it had and none of the new ones."""
+    import re
+
+    model, params, tokens = _sparse_model(remat=True)
+    assert set(model.init(jax.random.key(0), tokens)) == {"params"}
+    assert {k: v.shape for k, v in params["block_1"]["moe"].items()} == {
+        "router": (32, 64), "gate": (64, 32, 8), "up": (64, 32, 8),
+        "down": (64, 8, 32)}
+    assert set(params["block_1"]) == {"ln1", "attn", "ln2", "moe"}
+    _, aux = model.apply({"params": params}, tokens, return_aux=True)
+    assert set(aux) == {"load_balance", "router_z"}
+    names = set(re.findall(r'loc\("([^"]*)"', jax.jit(jax.grad(
+        lambda p: _sparse_loss(model, p, tokens))).lower(params).as_text(
+            debug_info=True)))
+    for scope in ("moe_route", "moe_dispatch", "moe_experts", "moe_combine"):
+        assert [n for n in names if f"/{scope}/" in n], scope
+    for new in ("ssm_", "moe_latent", "moe_shared", "/norm/"):
+        assert not [n for n in names if new in n], new
+
+
+# ---- the hybrid decoder (Nemotron-H's layers) against its plain reference
+
+_HYBRID = {"norm_eps": 1e-5, "ssm_state_size": 8, "mamba_head_dim": 4,
+           "num_experts_per_tok": 3, "norm_topk_prob": True,
+           "routed_scaling_factor": 2.5, "experts_held_first": 4}
+_HYBRID_SCOPES = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
+                  "ssm_out_proj", "moe_latent", "moe_shared")
+
+
+def _hybrid_model(remat=False, pattern="*EMEM", **changes):
+    """A share of a small hybrid: 2 of 8 query heads on 1 of 2 key-value
+    heads, 4 of 8 Mamba-2 heads in 1 of 2 groups, experts 4 to 7 of 16."""
+    from horovod_tpu.models import GPT, GPTConfig
+
+    cfg = GPTConfig(
+        vocab_size=64, n_layers=len(pattern), layer_pattern=pattern,
+        d_model=32, n_heads=8, n_kv_heads=2, heads_held=(4, 2), rotary=False,
+        d_ff=24, dtype=jnp.float32, remat=remat, use_flash=False,
+        tie_embeddings=False, norm_eps=1e-5, mlp_act="relu2", ssm_heads=8,
+        ssm_head_dim=4, ssm_groups=2, ssm_state=8, ssm_heads_held=(4, 4),
+        n_experts=16, experts_per_token=3, moe_score="sigmoid",
+        moe_route_scale=2.5, moe_expert_act="relu2", moe_latent=16,
+        moe_shared_ff=40, experts_held=(4, 4))
+    cfg = dataclasses.replace(cfg, **changes)
+    model = GPT(cfg)
+    tokens = jax.random.randint(jax.random.key(1), (2, 20), 0, 64)
+    variables = model.init(jax.random.key(0), tokens)
+    # at their 0.02 the experts and the router barely move the loss
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, w: w * 10.0 if "moe" in str(path) else w,
+        variables["params"])
+    return model, params, variables.get("buffers", {}), tokens
+
+
+def _hybrid_loss(model, params, buffers, tokens):
+    import optax
+
+    logits = model.apply({"params": params, "buffers": buffers}, tokens)
+    return optax.softmax_cross_entropy_with_integer_labels(
+        logits[:, :-1], tokens[:, 1:]).mean()
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_hybrid_gpt_matches_reference(remat):
+    """One mixer a layer in the pattern's order, all three kinds, each a
+    chip's share: the tree, the loss and the gradient of every leaf
+    against chipbench/reference/nemotron_h.py, to float32's summation
+    order; remat changes nothing; the logits are the reference's."""
+    from chipbench.reference import nemotron_h as reference
+
+    model, params, buffers, tokens = _hybrid_model(remat)
+    kinds = [set(params[f"block_{i}"]) - {"norm"} for i in range(5)]
+    assert kinds == [{"attn"}, {"moe"}, {"ssm"}, {"moe"}, {"ssm"}]
+    assert params["block_0"]["attn"]["q"]["kernel"].shape == (32, 2, 4)
+    assert params["block_0"]["attn"]["k"]["kernel"].shape == (32, 1, 4)
+    assert params["block_1"]["moe"]["up"].shape == (4, 16, 24)
+    assert params["block_2"]["ssm"]["in_proj"].shape == (32, 2 * 16 + 16 + 4)
+    assert set(buffers) == {"block_1", "block_3"}
+    got, grads = jax.jit(jax.value_and_grad(
+        lambda p: _hybrid_loss(model, p, buffers, tokens)))(params)
+    (want, routing), want_grads = reference.loss_and_grad(
+        params, buffers, tokens, _HYBRID)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    assert len(routing) == 2 and routing[0]["own"].shape == (40, 3)
+    flat, want_flat = (jax.tree_util.tree_leaves_with_path(t)
+                       for t in (grads, want_grads))
+    for (path, g), (_, w) in zip(flat, want_flat, strict=True):
+        err = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+        assert err <= 2e-5, (jax.tree_util.keystr(path), err)
+    plain, _, _, _ = _hybrid_model(not remat)
+    assert float(_hybrid_loss(plain, params, buffers, tokens)) == \
+        pytest.approx(float(got), rel=1e-6)
+    # no positional term in the attention: with the Mamba-2 layers' and
+    # the causal mask's order taken away a permutation of the positions
+    # permutes the logits
+    attention_only, only_params, _, _ = _hybrid_model(pattern="*")
+    apply = lambda t: attention_only.apply({"params": only_params}, t)
+    np.testing.assert_allclose(np.asarray(apply(tokens)[:, -1]),
+                               np.asarray(apply(tokens.at[:, :-1].set(
+                                   tokens[:, -2::-1]))[:, -1]),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_hybrid_gradient_program_names_its_scopes_and_scatters_no_row():
+    """The seven scopes the benchmark's readers look for are in the
+    lowered step of a hybrid, forward and backward, beside the expert
+    layer's four; and the only scatters the layers put in the compiled
+    gradient program are the grouped products' bookkeeping (the
+    embedding's and this test's own loss's are outside the blocks)."""
+    import re
+
+    model, params, buffers, tokens = _hybrid_model(remat=True)
+    grad = jax.jit(jax.grad(
+        lambda p: _hybrid_loss(model, p, buffers, tokens)))
+    lowered = grad.lower(params)
+    names = set(re.findall(r'loc\("([^"]*)"',
+                           lowered.as_text(debug_info=True)))
+    for scope in _HYBRID_SCOPES + ("moe_route", "moe_dispatch",
+                                   "moe_experts", "moe_combine"):
+        assert [n for n in names if f"/{scope}/" in n and "jvp(" in n
+                and "transpose(" not in n], scope
+        assert [n for n in names if f"/{scope}/" in n
+                and "transpose(jvp(" in n], scope
+    assert [n for n in names if "rematted_computation" in n
+            and "/ssm_scan/" in n]
+    for line in lowered.compile().as_text().splitlines():
+        if " scatter(" in line:
+            name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert ("/jit(gmm)/" in name or "/jit(tgmm)/" in name
+                    or "/block_" not in name), name
+
+
+def _take_attention_heads(p, first, count, group):
+    kv = slice(first // group, max(first // group + 1,
+                                   (first + count) // group))
+    return {"q": {"kernel": p["q"]["kernel"][:, first:first + count]},
+            "k": {"kernel": p["k"]["kernel"][:, kv]},
+            "v": {"kernel": p["v"]["kernel"][:, kv]},
+            "o": {"kernel": p["o"]["kernel"][first:first + count]}}
+
+
+@pytest.mark.parametrize("count", [2, 4, 8], ids=[
+    "part-of-a-group", "a-whole-group", "every-head"])
+def test_the_shares_of_the_attention_heads_add_up(count):
+    """8 query heads over 2 key-value heads, divided ``8 / count`` ways:
+    each chip builds its heads' slices of q and o and the key-value head
+    they read, and the shares' outputs summed are the uncut reference's
+    attention (the out-projection is linear)."""
+    from chipbench.reference import nemotron_h as reference
+    from horovod_tpu.models import GPTConfig
+    from horovod_tpu.models.transformer import Attention
+
+    base = GPTConfig(d_model=32, n_heads=8, n_kv_heads=2, rotary=False,
+                     dtype=jnp.float32, use_flash=False)
+    x = jax.random.normal(jax.random.key(0), (2, 12, 32))
+    positions = jnp.broadcast_to(jnp.arange(12), (2, 12))
+    whole = Attention(base).init(jax.random.key(1), x, positions)["params"]
+    want = jax.lax.map(lambda one: reference.attention(one, whole), x)
+    total = 0.0
+    for first in range(0, 8, count):
+        cfg = dataclasses.replace(base, heads_held=(first, count))
+        mine = _take_attention_heads(whole, first, count, 4)
+        shapes = jax.eval_shape(Attention(cfg).init, jax.random.key(1), x,
+                                positions)["params"]
+        assert jax.tree.map(lambda a: a.shape, mine) == jax.tree.map(
+            lambda a: a.shape, jax.tree.map(lambda a: a, dict(shapes)))
+        total = total + Attention(cfg).apply({"params": mine}, x, positions)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("changes, match", [
+    ({"layer_pattern": "*EM"}, "names 3 layers"),
+    ({"layer_pattern": "*EM?M"}, "layer_pattern holds"),
+    ({"heads_held": (1, 2)}, "heads held"),
+    ({"heads_held": (0, 3)}, "heads held"),
+    ({"heads_held": (6, 4)}, "heads held"),
+    ({"ssm_heads_held": (2, 4)}, "whole groups"),
+    ({"experts_held": (14, 4)}, "experts held"),
+])
+def test_hybrid_config_is_refused_by_name(changes, match):
+    with pytest.raises(ValueError, match=match):
+        _hybrid_model(**changes)
+
+
+def test_dense_mlp_layer_of_a_pattern_and_param_partition_spec():
+    """"-" is the dense MLP (here relu2) behind its one norm; and every
+    new leaf has its PartitionSpec: Mamba-2's per-head vectors and
+    out-projection rows over tp, the expert stacks over ep, the rest
+    replicated."""
+    from horovod_tpu.models.transformer import param_partition_spec
+
+    _, params, _, _ = _hybrid_model(pattern="-EM*")
+    assert set(params["block_0"]) == {"norm", "mlp"}
+    specs = param_partition_spec(params, ep_axis="ep")
+    ssm, moe = specs["block_2"]["ssm"], specs["block_1"]["moe"]
+    assert ssm["A_log"] == ssm["dt_bias"] == ssm["D_skip"] == P("tp")
+    assert ssm["norm_scale"] == P("tp") and ssm["out_proj"] == P("tp", None)
+    assert ssm["in_proj"] == ssm["conv_kernel"] == ssm["conv_bias"] == P()
+    assert moe["up"] == P("ep", None, "tp") and moe["down"] == P(
+        "ep", "tp", None)
+    for name in ("router", "latent_in", "latent_out", "shared_up",
+                 "shared_down"):
+        assert moe[name] == P(), name
+    assert specs["block_0"]["norm"]["scale"] == P()
+    assert specs["block_3"]["attn"]["o"]["kernel"] == P("tp", None, None)
+
+
+def test_ssm_and_held_counters_show_on_metrics():
+    """Both trace-time counters are on ``/metrics`` once a hybrid has
+    been traced: the state-space layers by heads, state and chunk, the
+    expert layers with what they hold."""
+    from horovod_tpu import metrics
+
+    model, params, buffers, tokens = _hybrid_model()
+    jax.jit(lambda p: _hybrid_loss(model, p, buffers, tokens)).lower(params)
+    text = metrics.prometheus_text()
+    assert re.search(r'hvt_ssm_layers_traced_total\{[^}]*chunk="20"[^}]*\}',
+                     text), text[-2000:]
+    assert 'heads="4"' in text and 'state="8"' in text
+    assert re.search(r'hvt_moe_layers_traced_total\{[^}]*held="4"[^}]*\}',
+                     text)

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from chipbench.reference import nemotron_h as latent_reference
 from chipbench.reference import olmoe as reference
 from horovod_tpu.models import moe
 from horovod_tpu.models.moe import MoEMlp, moe_param_partition_spec
@@ -213,8 +214,8 @@ def test_traced_layers_are_counted():
 
     def count():
         m = metrics.registry().get("hvt_moe_layers_traced_total")
-        return m.labels(experts="8", top_k="2",
-                        product=moe.PRODUCT).value if m else 0.0
+        return m.labels(experts="8", top_k="2", product=moe.PRODUCT,
+                        held="8").value if m else 0.0
 
     layer, params, h = _layer(8, 2, skewed=False)
     before = count()
@@ -257,3 +258,252 @@ def test_moe_compiles_on_dp_ep_mesh():
     _close(val, want, "loss")
     for name in ("router", "gate", "up", "down"):
         _close(grads[name], want_grads[name], f"d {name}")
+
+
+# ---- the layer Nemotron-H's expert block needs: sigmoid scores with a
+# choice bias, renormalised and scaled weights, relu2 experts in a latent
+# width, a shared expert, and a chip's share of the experts
+
+_LATENT = {"num_experts_per_tok": 3, "norm_topk_prob": True,
+           "routed_scaling_factor": 2.5}
+
+
+def _latent_layer(held, k=3, n_experts=8, tokens=40, d=16, seed=0,
+                  skewed=False):
+    """A float32 latent layer, its parameters (scaled up from 0.02, as
+    ``_layer`` does), a choice bias that changes some choices, and its
+    input. ``skewed``: every token chooses the first held expert and none
+    the second."""
+    layer = MoEMlp(n_experts, 12, k, dtype=jnp.float32, score="sigmoid",
+                   route_scale=2.5, expert_act="relu2", latent=8,
+                   shared_ff=20, held=held)
+    h = jax.random.normal(jax.random.key(seed), (tokens, d))
+    variables = layer.init(jax.random.key(seed + 1), h)
+    params = jax.tree.map(lambda w: w * 20.0, variables["params"])
+    bias = 0.2 * jax.random.normal(jax.random.key(seed + 2), (n_experts,))
+    if skewed:
+        first = held[0]
+        h = h.at[:, 0].set(1.0)
+        router = params["router"].at[0, first].set(30.0).at[
+            0, first + 1].set(-30.0)
+        params = {**params, "router": router}
+    return layer, params, {"choice_bias": bias}, h
+
+
+def _latent_config(held, k=3):
+    return {**_LATENT, "num_experts_per_tok": k,
+            "experts_held_first": held[0] if held else 0}
+
+
+@pytest.mark.parametrize("held, k", [
+    (None, 3), ((4, 4), 3), ((0, 2), 3), ((2, 6), 3), ((3, 1), 2)],
+    ids=["every-expert", "held-more-than-k", "held-fewer-than-k",
+         "held-twice-k", "one-held"])
+def test_latent_layer_matches_reference(held, k):
+    """Sigmoid scores, the choice from scores + bias, the weights the
+    scores at the chosen over their sum times the scale, relu2 experts in
+    the latent width, the shared expert beside them: output and the
+    gradient of every leaf and of the input against the reference given
+    the program's expert indices; ``aux`` is empty; a share holds stacks
+    of its experts alone and the bias takes no gradient."""
+    layer, params, buffers, h = _latent_layer(held, k)
+    stack = held[1] if held else 8
+    assert params["up"].shape == (stack, 8, 12)
+    assert params["down"].shape == (stack, 12, 8)
+    assert set(params) == {"router", "up", "down", "latent_in",
+                           "latent_out", "shared_up", "shared_down"}
+    cot = jax.random.normal(jax.random.key(9), h.shape)
+    config = _latent_config(held, k)
+
+    def program(params, h, bias):
+        (out, aux), sown = layer.apply(
+            {"params": params, "buffers": {"choice_bias": bias}}, h,
+            mutable=["intermediates"])
+        return jnp.sum(out * cot), (out, aux, sown["intermediates"])
+
+    (_, (out, aux, sown)), grads = jax.jit(jax.value_and_grad(
+        program, argnums=(0, 1, 2), has_aux=True))(
+            params, h, buffers["choice_bias"])
+    experts = sown["experts"][0]
+
+    def plain(params, h):
+        out, routing = latent_reference.experts_layer(
+            h, params, buffers["choice_bias"], config, forced=experts)
+        return jnp.sum(out * cot), (out, routing)
+
+    (_, (want, routing)), want_grads = jax.value_and_grad(
+        plain, argnums=(0, 1), has_aux=True)(params, h)
+    assert aux == {}
+    _close(out, want, "output")
+    for name in params:
+        _close(grads[0][name], want_grads[0][name], f"d {name}")
+    _close(grads[1], want_grads[1], "d input")
+    assert float(jnp.abs(grads[2]).max()) == 0.0
+    # the program's own choice is the reference's, and the bias moved it
+    np.testing.assert_array_equal(np.sort(np.asarray(experts), -1),
+                                  np.sort(np.asarray(routing["own"]), -1))
+    _, unbiased = latent_reference.route(h, params["router"], 0.0, k)
+    assert (np.sort(np.asarray(unbiased), -1)
+            != np.sort(np.asarray(experts), -1)).any()
+    _close(sown["router_probs"][0], jax.nn.sigmoid(h @ params["router"]),
+           "scores")
+
+
+def test_held_route_gives_a_slot_a_held_expert():
+    """The choice is over all the experts; the weights are renormalised
+    over all a token chose, not over the held ones; the slots are sorted
+    by held expert with the unassigned last, and the group sizes count
+    the assigned alone."""
+    _, params, buffers, h = _latent_layer((4, 4))
+    first, count, k = 4, 4, 3
+    experts, weights, order, inverse, sizes, aux, scores = moe.moe_route(
+        h, params["router"], k, score="sigmoid",
+        bias=buffers["choice_bias"], scale=2.5, held=(first, count))
+    want_scores, want = latent_reference.route(
+        h, params["router"], buffers["choice_bias"], k)
+    np.testing.assert_array_equal(np.sort(np.asarray(experts), -1),
+                                  np.sort(np.asarray(want), -1))
+    chosen = (np.asarray(want)[..., None] == np.arange(8)).any(1)
+    picked = np.where(chosen, np.asarray(want_scores), 0.0)
+    full = 2.5 * picked / picked.sum(-1, keepdims=True)
+    np.testing.assert_allclose(np.asarray(weights),
+                               full[:, first:first + count], rtol=1e-5)
+    assert aux == {} and weights.shape == (h.shape[0], count)
+    held = chosen[:, first:first + count]
+    np.testing.assert_array_equal(np.asarray(sizes), held.sum(0))
+    assert 0 < int(sizes.sum()) < h.shape[0] * k
+    order, inverse = np.asarray(order), np.asarray(inverse)
+    assert sorted(order) == list(range(h.shape[0] * count))
+    np.testing.assert_array_equal(order[inverse], np.arange(order.size))
+    key = np.where(held, np.arange(count), count).reshape(-1)
+    np.testing.assert_array_equal(order, np.argsort(key, kind="stable"))
+    assert moe.held_rows(h.shape[0], k, (first, count)) == h.shape[0] * 3
+    assert moe.held_rows(h.shape[0], k, (0, 2)) == h.shape[0] * 2
+
+
+@pytest.mark.parametrize("held", [(4, 4), (2, 2)],
+                         ids=["bound-T-x-k", "bound-T-x-held"])
+def test_held_layer_drops_nothing_under_the_most_uneven_routing(held):
+    """One held expert gets every token and another none: the static row
+    bound covers it, the rows past the real count multiply nothing, and
+    the output and gradients are still the reference's."""
+    layer, params, buffers, h = _latent_layer(held, skewed=True)
+    *_, sizes, _, _ = moe.moe_route(
+        h, params["router"], 3, score="sigmoid",
+        bias=buffers["choice_bias"], scale=2.5, held=held)
+    assert int(sizes[0]) == h.shape[0] and int(sizes[1]) == 0
+    cot = jax.random.normal(jax.random.key(5), h.shape)
+    variables = lambda p: {"params": p, "buffers": buffers}
+    program = lambda p, h: jnp.sum(layer.apply(variables(p), h)[0] * cot)
+    plain = lambda p, h: jnp.sum(latent_reference.experts_layer(
+        h, p, buffers["choice_bias"], _latent_config(held))[0] * cot)
+    _close(layer.apply(variables(params), h)[0],
+           latent_reference.experts_layer(
+               h, params, buffers["choice_bias"], _latent_config(held))[0],
+           "output")
+    got = jax.jit(jax.grad(program, argnums=(0, 1)))(params, h)
+    want = jax.grad(plain, argnums=(0, 1))(params, h)
+    for name in params:
+        _close(got[0][name], want[0][name], f"d {name}")
+    _close(got[1], want[1], "d input")
+
+
+def test_rows_past_the_assigned_are_masked_on_both_sides():
+    """What the grouped product leaves in the tiles it does not visit is
+    not read: rows past ``sum(group_sizes)`` come out as zeros, and so
+    does the gradient that goes back to them, whatever is in them."""
+    rows = jnp.full((6, 3), jnp.nan).at[:2].set(1.0)
+    sizes = jnp.array([1, 1, 0], jnp.int32)
+    np.testing.assert_array_equal(
+        np.asarray(moe._assigned_rows(rows, sizes)),
+        np.concatenate([np.ones((2, 3)), np.zeros((4, 3))]))
+    grad = jax.grad(lambda r: jnp.sum(moe._assigned_rows(r, sizes)
+                                      * jnp.where(jnp.isnan(r), 0.0, r)))(
+        jnp.ones((6, 3)))
+    np.testing.assert_array_equal(np.asarray(grad)[2:], 0.0)
+
+
+def test_the_shares_of_the_experts_add_up():
+    """Four chips, two experts each, of a layer of eight: the held
+    experts' parts, each through the latent up-projection (linear, no
+    bias), summed over the shares, with the shared expert, which every
+    chip computes alike, counted once, are the uncut reference's layer
+    output."""
+    _, params, buffers, h = _latent_layer(None)
+    shared = jnp.square(jax.nn.relu(h @ params["shared_up"])) @ params[
+        "shared_down"]
+    total = 0.0
+    for first in (0, 2, 4, 6):
+        share, _, _, _ = _latent_layer((first, 2))
+        mine = {**params, "up": params["up"][first:first + 2],
+                "down": params["down"][first:first + 2]}
+        out, _ = share.apply({"params": mine, "buffers": buffers}, h)
+        total = total + (out - shared)
+    want, _ = latent_reference.experts_layer(
+        h, params, buffers["choice_bias"], _latent_config(None))
+    _close(total + shared, want, "sum of the shares")
+    assert float(jnp.linalg.norm(total)) > 0.1 * float(jnp.linalg.norm(want))
+
+
+def test_held_layer_gradient_program_scatters_no_row():
+    """As the layer that holds every expert: dispatch over the static
+    bound, the mask, the pad and the combine differentiate without a
+    scatter-add; what is left is the grouped product's bookkeeping."""
+    layer, params, buffers, h = _latent_layer((4, 4))
+
+    def loss(params, h):
+        out, _ = layer.apply({"params": params, "buffers": buffers}, h)
+        return jnp.sum(out ** 2)
+
+    found = _scatters(jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, h).compile())
+    for elements, name in found:
+        assert "/jit(gmm)/" in name or "/jit(tgmm)/" in name, name
+        assert elements <= 4 + h.shape[0] * 3, (elements, name)
+
+
+def test_held_layers_are_counted_by_what_they_hold():
+    from horovod_tpu import metrics
+
+    def count(held):
+        m = metrics.registry().get("hvt_moe_layers_traced_total")
+        return m.labels(experts="8", top_k="3", product=moe.PRODUCT,
+                        held=held).value if m else 0.0
+
+    layer, params, buffers, h = _latent_layer((4, 4))
+    before = count("4"), count("8")
+    jax.jit(lambda p, h: layer.apply(
+        {"params": p, "buffers": buffers}, h)[0]).lower(params, h)
+    assert (count("4"), count("8")) == (before[0] + 1, before[1])
+
+
+@pytest.mark.parametrize("field, value, match", [
+    ("score", "tanh", "softmax or sigmoid"), ("held", (6, 4), "held"),
+    ("held", (0, 0), "held")])
+def test_layer_refuses_what_it_does_not_build(field, value, match):
+    layer = MoEMlp(8, 12, 2, dtype=jnp.float32, **{field: value})
+    with pytest.raises(ValueError, match=match):
+        layer.init(jax.random.key(0), jnp.zeros((4, 16)))
+
+
+def test_swiglu_layer_with_a_shared_expert_and_a_share():
+    """The options compose with OLMoE's kind too: softmax scores, SwiGLU
+    experts (three stacks, a gated shared expert), two of four held: the
+    held experts' part against the loop over them."""
+    layer = MoEMlp(4, 8, 2, dtype=jnp.float32, shared_ff=6, held=(1, 2))
+    h = jax.random.normal(jax.random.key(0), (24, 16))
+    params = jax.tree.map(lambda w: w * 20.0, layer.init(
+        jax.random.key(1), h)["params"])
+    assert set(params) == {"router", "gate", "up", "down", "shared_up",
+                           "shared_gate", "shared_down"}
+    out, aux = layer.apply({"params": params}, h)
+    assert set(aux) == {"load_balance", "router_z"}
+    probs, _, experts = reference.route(h, params["router"], 2)
+    want = (jax.nn.silu(h @ params["shared_gate"]) * (h @ params["shared_up"])
+            ) @ params["shared_down"]
+    for e in (1, 2):
+        weight = jnp.where((experts == e).any(-1), probs[:, e], 0.0)
+        hidden = jax.nn.silu(h @ params["gate"][e - 1]) * (
+            h @ params["up"][e - 1])
+        want = want + weight[:, None] * (hidden @ params["down"][e - 1])
+    _close(out, want, "output")
