@@ -27,7 +27,7 @@ that a device trace can be split by them:
 
 Dispatch and combine are a permutation and its inverse, so both
 directions of both are gathers (``_take_rows``): the gradient program
-holds no scatter-add, which a TPU serialises.
+of a layer that holds every expert has no scatter-add.
 
 The same layer builds what other sparse models ask for, each an option
 that defaults to the above: ``score="sigmoid"`` (scores ``sigmoid(h
@@ -44,14 +44,31 @@ experts ``[first, first + count)`` alone. The router still scores and
 chooses over all ``n_experts``; only an assignment to a held expert gets
 a row, and the layer returns the held experts' part of the sum (plus what
 every chip computes alike: the latent projections and the shared expert).
-Nothing is dropped: a token has one slot a held expert, the slots are
-sorted by expert with the unassigned last, and the first ``T x min(k,
-count)`` of them (the most that can be assigned, a token choosing an
-expert once) are the rows. The group sizes sum to the rows really
-assigned, and the grouped product does not visit the tiles past them
-(``megablox`` takes group sizes that sum to fewer rows than it is given);
-what it leaves there is masked out on both sides of the experts. No code
-stands in for the other chips or for the exchange with them.
+Nothing is dropped: a token has one slot a held expert and the slots are
+sorted by expert with the unassigned last. The assigned slots are worked
+through in **rounds of ``T`` rows, one row a token** (``held_rows``,
+``_held_experts``): round ``r`` takes the sorted slots ``[r T, (r + 1)
+T)``, gathers their tokens' rows, runs the same grouped products on a
+``[T, width]`` operand with the part of each group that falls into the
+round, and adds the weighted rows to their tokens (a scatter-add of a
+round's rows: the one place the layer has one, chosen on the chip's
+timing, ``_sum_by_token``). The most that can be assigned is ``min(k,
+count)`` rounds, a token choosing an expert once; how many run is the
+router's to say, ``ceil(sum(group_sizes) / T)``, one at the loads a share
+sees as a rule: a loop whose trips the data decide, with a backward pass
+written to match (``_held_experts_bwd``: the same loop, each round's
+forward made again for its pullback). So a step pays for the rows
+assigned, a program holds the round once (``_held_round`` is a
+``jax.jit`` that every layer of one shape shares) and the grouped product
+at one row count, and nothing is traced, compiled or run for a round that
+is not needed. What the rounds summed to carries the name ``HELD_SUM``
+for a caller's ``jax.checkpoint`` to keep. The group sizes of a round sum
+to the rows really assigned, and the grouped product does not visit the
+tiles past them (``megablox`` takes group sizes that sum to fewer rows
+than it is given); what it leaves there is masked out on both sides of
+the experts. Gradients of the expert stacks are summed over rounds in the
+layer's ``dtype``, where one pass rounds once. No code stands in for the
+other chips or for the exchange with them.
 
 Expert parallelism: the stacks carry ``P("ep", ...)`` in
 ``moe_param_partition_spec`` (and in ``transformer.param_partition_spec``
@@ -68,6 +85,7 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 # The grouped-product implementation, as the engagement counter names it.
@@ -76,9 +94,14 @@ PRODUCT = "megablox_gmm"
 # 65,536 rows x 2048 x 1024 over 64 groups on a v5e (PERF.md, PR 26);
 # a larger one does not fit the kernels' VMEM.
 _GMM_TILE = (512, 1024, 1024)
+# The name (``jax.ad_checkpoint.checkpoint_name``) of what a held layer's
+# rounds summed to, for a caller's ``jax.checkpoint`` policy to keep:
+# ``models.GPT`` does under ``remat``, because the layer's own backward
+# pass already makes each round's forward again.
+HELD_SUM = "moe_held_sum"
 
 
-def _count_trace(n_experts, top_k, held):
+def _count_trace(n_experts, top_k, held, n_tokens):
     """The engagement counter: one count a traced layer. Trace-time
     Python only."""
     try:
@@ -88,9 +111,10 @@ def _count_trace(n_experts, top_k, held):
             "hvt_moe_layers_traced_total",
             "mixture-of-experts layers traced into compiled programs "
             "(counted per trace, not per execution)",
-            ("experts", "top_k", "product", "held"),
+            ("experts", "top_k", "product", "held", "round_rows"),
         ).labels(experts=str(n_experts), top_k=str(top_k), product=PRODUCT,
-                 held=str(held[1] if held else n_experts)).inc()
+                 held=str(held[1] if held else n_experts),
+                 round_rows=str(n_tokens) if held else "all").inc()
     except Exception:
         pass  # telemetry must never break a trace
 
@@ -99,32 +123,22 @@ def _rows(x, index):
     return x.at[index].get(mode="promise_in_bounds")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _take_rows(x, index, inverse, k, n_rows=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x, index, inverse, k):
     """Rows ``index // k`` of ``x [n, d]``, where ``index`` is a
-    permutation of ``range(n x k)`` and ``inverse`` its inverse; the
-    first ``n_rows`` of them where that is given. The transpose of a
-    gather is a scatter-add; of a permutation it is the gather by the
-    inverse (then the sum over the ``k`` copies of a row), which is what
-    the backward pass runs; rows that were cut off come back as zeros."""
-    if n_rows is not None:
-        index = index[:n_rows]
+    permutation of ``range(n x k)`` and ``inverse`` its inverse. The
+    transpose of a gather is a scatter-add; of a permutation it is the
+    gather by the inverse (then the sum over the ``k`` copies of a row),
+    which is what the backward pass runs."""
     return _rows(x, index // k if k > 1 else index)
 
 
-def _take_rows_fwd(x, index, inverse, k, n_rows):
-    return _take_rows(x, index, inverse, k, n_rows), inverse
+def _take_rows_fwd(x, index, inverse, k):
+    return _take_rows(x, index, inverse, k), inverse
 
 
-def _pad_rows(rows, n):
-    """``rows [m, d]`` with zeros after them up to ``n`` rows."""
-    if rows.shape[0] == n:
-        return rows
-    return jnp.pad(rows, ((0, n - rows.shape[0]), (0, 0)))
-
-
-def _take_rows_bwd(k, n_rows, inverse, g):
-    g = _rows(_pad_rows(g, inverse.shape[0]), inverse)
+def _take_rows_bwd(k, inverse, g):
+    g = _rows(g, inverse)
     if k > 1:
         g = g.reshape(-1, k, g.shape[-1]).sum(1)
     return g, None, None
@@ -202,17 +216,22 @@ def moe_route(h, router, k, *, score="softmax", bias=None, scale=1.0,
 
 
 def held_rows(n_tokens, k, held):
-    """The static row bound of a share ``held = (first, count)``: the
-    most of its ``n_tokens x count`` slots that can be assigned, a token
-    choosing ``k`` experts and each at most once. None (every row) for a
-    layer that holds every expert."""
-    return None if held is None else n_tokens * min(k, held[1])
+    """``(rounds, rows a round)`` of a share ``held = (first, count)``:
+    its sorted slots are worked through ``n_tokens`` rows at a time (one
+    row a token), and the most that can be assigned, a token choosing
+    ``k`` experts and each at most once, is ``min(k, count)`` such rounds.
+    That is the static worst case, which nothing in the program is sized
+    by: the rounds that run are the router's to say (``ceil(sum(
+    group_sizes) / n_tokens)``, ``_rounds``), so the layer is dropless
+    without a bound. None for a layer that holds every expert: one pass
+    over its ``n_tokens x k`` rows, every one of them real."""
+    return None if held is None else (min(k, held[1]), n_tokens)
 
 
-def moe_dispatch(h, order, inverse, k, n_rows=None):
+def moe_dispatch(h, order, inverse, k):
     """``h [T, d]`` -> its rows in expert order, ``[T x k, d]`` (``k``
-    the slots a token has; the first ``n_rows`` where given)."""
-    return _take_rows(h, order, inverse, k, n_rows)
+    the choices of a token)."""
+    return _take_rows(h, order, inverse, k)
 
 
 def _interpret() -> bool:
@@ -260,13 +279,117 @@ def _assigned_rows(rows, group_sizes):
 
 
 def moe_combine(rows, weights, order, inverse):
-    """Rows ``[T x k, d]`` in expert order (or the first of them, the
-    rest taken as zeros) -> ``[T, d]`` float32: each token's ``k`` rows
-    times their weights, summed."""
+    """Rows ``[T x k, d]`` in expert order -> ``[T, d]`` float32: each
+    token's ``k`` rows times their weights, summed."""
     k = weights.shape[-1]
-    rows = _take_rows(_pad_rows(rows, inverse.shape[0]), inverse, order, 1)
+    rows = _take_rows(rows, inverse, order, 1)
     rows = rows.reshape(-1, k, rows.shape[-1]).astype(jnp.float32)
     return jnp.sum(rows * weights[..., None], axis=1)
+
+
+# ---- a chip's share: the held experts' rows, a round of one row a token
+# at a time. A round's rows belong to tokens in no order and a token may
+# own several of them, so the movement from tokens to rows is a gather and
+# the one from rows to tokens a sum by token, each the other's transpose.
+# Both cost by the rows of the round, not by the ``T x count`` slots.
+
+def _sum_by_token(rows, token, n_tokens):
+    """``rows [R, d]`` -> ``[n_tokens, d]``: the sum of the rows of each
+    token, in float32 (a token has at most ``min(k, count)`` rows). A
+    scatter-add, which a TPU takes a row at a time: at 16,384 rows of 1024
+    that is 1.0 ms, where sorting the rows by token and summing the runs
+    with shifted adds took 2.2 and the gather over all ``T x count`` slots
+    4.6 (``benchmarks/moe_rows_to_tokens.py``; PERF.md, PR 32)."""
+    return jax.ops.segment_sum(rows.astype(jnp.float32), token,
+                               num_segments=n_tokens).astype(rows.dtype)
+
+
+@jax.custom_vjp
+def _rows_of_tokens(x, token):
+    """``x [T, d]`` -> ``x[token] [T, d]``, the rows of a round (one a
+    token, so as many as there are tokens). Its transpose is
+    ``_sum_by_token``, written out so that it sums in float32 whatever
+    ``x`` is in."""
+    return _rows(x, token)
+
+
+_rows_of_tokens.defvjp(
+    lambda x, token: (_rows(x, token), token),
+    lambda token, g: (_sum_by_token(g, token, g.shape[0]), None))
+
+
+@jax.jit
+def _held_round(tokens, weights, stacks, route, r):
+    """Round ``r`` of a share: the sorted slots ``[r T, (r + 1) T)`` of
+    ``order``, their tokens' rows through the held experts (``stacks =
+    (gate, up, down)``) and weighted: ``(rows [T, width] float32, their
+    tokens [T])``. ``route = (order, group_sizes)`` as ``moe_route`` made
+    them; the round's group sizes are the part of each expert's group that
+    falls into it. A ``jax.jit`` of its own so that a program holds one
+    traced and lowered copy of it, however many layers call it."""
+    order, group_sizes = route
+    n_tokens, count = weights.shape
+    ends = jnp.cumsum(group_sizes) - r * n_tokens
+    sizes = jnp.diff(jnp.clip(ends, 0, n_tokens), prepend=0)
+    slots = jax.lax.dynamic_slice(order, (r * n_tokens,), (n_tokens,))
+    token, expert = slots // count, slots % count
+    with jax.named_scope("moe_dispatch"):
+        rows = _assigned_rows(_rows_of_tokens(tokens, token), sizes)
+    with jax.named_scope("moe_experts"):
+        rows = moe_experts(rows, *stacks, sizes)
+    with jax.named_scope("moe_combine"):
+        rows = _assigned_rows(rows, sizes).astype(jnp.float32)
+        weight = jnp.sum(jnp.where(
+            expert[:, None] == jnp.arange(count),
+            _rows_of_tokens(weights, token), 0.0), axis=-1)
+        return rows * weight[:, None], token
+
+
+def _rounds(route, n_tokens, body, carry):
+    """``carry`` after ``body(r, carry)`` for every round the router's
+    count asks for, ``ceil(assigned / n_tokens)`` of them (one, as a rule;
+    ``min(k, count)`` at most; none where no token chose a held expert): a
+    loop whose trips the data decide, so nothing stands in for a round
+    that does not run, no branch, no zeros, no copy of what is carried,
+    and a program holds one round however many it may run."""
+    return jax.lax.fori_loop(
+        jnp.int32(0), -(-jnp.sum(route[1]) // n_tokens), body, carry)
+
+
+@jax.custom_vjp
+def _held_experts(tokens, weights, stacks, route):
+    """The held experts' part of the layer's sum, ``[T, width]`` float32,
+    in rounds of ``T`` rows (``held_rows``, ``_rounds``), each round's
+    weighted rows added to their tokens (the sum by token, in place). A
+    loop whose trips the data decide has no reverse mode of its own, so
+    the backward pass is written here: the same loop, each round's forward
+    made again for its pullback (a round's residuals then live for one
+    trip, where one pass kept those of all ``T x min(k, count)`` rows)."""
+    def one(r, total):
+        rows, token = _held_round(tokens, weights, stacks, route, r)
+        with jax.named_scope("moe_combine"):
+            return total.at[token].add(rows, mode="promise_in_bounds")
+
+    return _rounds(route, tokens.shape[0], one,
+                   jnp.zeros(tokens.shape, jnp.float32))
+
+
+def _held_experts_bwd(res, g):
+    *of, route = res
+
+    def one(r, grads):
+        _, pull, token = jax.vjp(
+            lambda *of: _held_round(*of, route, r), *of, has_aux=True)
+        with jax.named_scope("moe_combine"):
+            rows = _rows(g, token)
+        return jax.tree.map(jnp.add, grads, pull(rows))
+
+    return *_rounds(route, g.shape[0], one,
+                    jax.tree.map(jnp.zeros_like, tuple(of))), None
+
+
+_held_experts.defvjp(
+    lambda *inputs: (_held_experts(*inputs), inputs), _held_experts_bwd)
 
 
 class MoEMlp(nn.Module):
@@ -323,9 +446,9 @@ class MoEMlp(nn.Module):
         bias = self.variable(
             "buffers", "choice_bias", jnp.zeros, (self.n_experts,),
             jnp.float32).value if self.score == "sigmoid" else None
-        _count_trace(self.n_experts, self.experts_per_token, self.held)
-        slots = stack if self.held else self.experts_per_token
         h = x.reshape(-1, d)
+        _count_trace(self.n_experts, self.experts_per_token, self.held,
+                     h.shape[0])
         with jax.named_scope("moe_route"):
             (experts, weights, order, inverse, group_sizes, aux,
              probs) = moe_route(h, router, self.experts_per_token,
@@ -341,18 +464,21 @@ class MoEMlp(nn.Module):
         if self.latent:
             with jax.named_scope("moe_latent"):
                 tokens = jnp.dot(low(), dense("latent_in", d, width))
-        with jax.named_scope("moe_dispatch"):
-            rows = moe_dispatch(
-                tokens if self.latent else low(), order, inverse, slots,
-                held_rows(h.shape[0], self.experts_per_token, self.held))
-            if self.held:
-                rows = _assigned_rows(rows, group_sizes)
-        with jax.named_scope("moe_experts"):
-            rows = moe_experts(rows, gate, up, down, group_sizes)
-        with jax.named_scope("moe_combine"):
-            if self.held:
-                rows = _assigned_rows(rows, group_sizes)
-            out = moe_combine(rows, weights, order, inverse)
+        if self.held:
+            with jax.named_scope("moe_experts"):   # cast once, not a round
+                stacks = tuple(None if w is None else w.astype(self.dtype)
+                               for w in (gate, up, down))
+            out = checkpoint_name(_held_experts(
+                tokens if self.latent else low(), weights, stacks,
+                (order, group_sizes)), HELD_SUM)
+        else:
+            with jax.named_scope("moe_dispatch"):
+                rows = moe_dispatch(tokens if self.latent else low(), order,
+                                    inverse, self.experts_per_token)
+            with jax.named_scope("moe_experts"):
+                rows = moe_experts(rows, gate, up, down, group_sizes)
+            with jax.named_scope("moe_combine"):
+                out = moe_combine(rows, weights, order, inverse)
         if self.latent:
             with jax.named_scope("moe_latent"):
                 out = jnp.dot(out.astype(self.dtype),

@@ -372,7 +372,16 @@ class GPT(nn.Module):
                 f"{cfg.n_layers}")
         block = Block if cfg.layer_pattern is None else MixerBlock
         if cfg.remat:
-            block = nn.remat(block, static_argnums=())
+            # everything recomputed but what a held expert layer's rounds
+            # summed to (``moe.HELD_SUM``: [T, width] float32 a layer): its
+            # backward pass makes each round again for its pullback, so
+            # the recomputed block would only run the rounds a third time
+            from horovod_tpu.models.moe import HELD_SUM
+
+            block = nn.remat(
+                block, static_argnums=(),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    HELD_SUM))
         aux = {}
         for i in range(cfg.n_layers):
             kind = () if cfg.layer_pattern is None else (

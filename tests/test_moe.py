@@ -215,7 +215,7 @@ def test_traced_layers_are_counted():
     def count():
         m = metrics.registry().get("hvt_moe_layers_traced_total")
         return m.labels(experts="8", top_k="2", product=moe.PRODUCT,
-                        held="8").value if m else 0.0
+                        held="8", round_rows="all").value if m else 0.0
 
     layer, params, h = _layer(8, 2, skewed=False)
     before = count()
@@ -269,11 +269,13 @@ _LATENT = {"num_experts_per_tok": 3, "norm_topk_prob": True,
 
 
 def _latent_layer(held, k=3, n_experts=8, tokens=40, d=16, seed=0,
-                  skewed=False):
+                  skewed=False, crowd=None):
     """A float32 latent layer, its parameters (scaled up from 0.02, as
     ``_layer`` does), a choice bias that changes some choices, and its
     input. ``skewed``: every token chooses the first held expert and none
-    the second."""
+    the second. ``crowd``: every token chooses the first ``crowd`` held
+    experts (0: none of the held), so that ``crowd`` whole rounds of one
+    row a token are assigned, and what the other choices add."""
     layer = MoEMlp(n_experts, 12, k, dtype=jnp.float32, score="sigmoid",
                    route_scale=2.5, expert_act="relu2", latent=8,
                    shared_ff=20, held=held)
@@ -281,12 +283,21 @@ def _latent_layer(held, k=3, n_experts=8, tokens=40, d=16, seed=0,
     variables = layer.init(jax.random.key(seed + 1), h)
     params = jax.tree.map(lambda w: w * 20.0, variables["params"])
     bias = 0.2 * jax.random.normal(jax.random.key(seed + 2), (n_experts,))
-    if skewed:
-        first = held[0]
+    if skewed or crowd is not None:
+        first, count = held
         h = h.at[:, 0].set(1.0)
-        router = params["router"].at[0, first].set(30.0).at[
-            0, first + 1].set(-30.0)
+        if crowd is None:
+            pull = jnp.array([30.0, -30.0])
+        else:
+            pull = jnp.where(jnp.arange(count) < crowd, 30.0,
+                             -30.0 if crowd == 0 else 0.0)
+        mine = slice(first, first + pull.size)
+        router = params["router"]
+        router = router.at[0, mine].set(
+            jnp.where(pull == 0.0, router[0, mine], pull))
         params = {**params, "router": router}
+        if crowd:       # the bias must not move a crowd's choice
+            bias = bias.at[first:first + crowd].set(1.0)
     return layer, params, {"choice_bias": bias}, h
 
 
@@ -377,21 +388,38 @@ def test_held_route_gives_a_slot_a_held_expert():
     np.testing.assert_array_equal(order[inverse], np.arange(order.size))
     key = np.where(held, np.arange(count), count).reshape(-1)
     np.testing.assert_array_equal(order, np.argsort(key, kind="stable"))
-    assert moe.held_rows(h.shape[0], k, (first, count)) == h.shape[0] * 3
-    assert moe.held_rows(h.shape[0], k, (0, 2)) == h.shape[0] * 2
+    assert moe.held_rows(h.shape[0], k, (first, count)) == (3, h.shape[0])
+    assert moe.held_rows(h.shape[0], k, (0, 2)) == (2, h.shape[0])
+    assert moe.held_rows(h.shape[0], k, None) is None
 
 
-@pytest.mark.parametrize("held", [(4, 4), (2, 2)],
-                         ids=["bound-T-x-k", "bound-T-x-held"])
-def test_held_layer_drops_nothing_under_the_most_uneven_routing(held):
-    """One held expert gets every token and another none: the static row
-    bound covers it, the rows past the real count multiply nothing, and
-    the output and gradients are still the reference's."""
-    layer, params, buffers, h = _latent_layer(held, skewed=True)
+@pytest.mark.parametrize("held, crowd, rounds", [
+    ((4, 4), None, 2), ((2, 2), None, 1), ((4, 4), 0, 0), ((4, 4), 2, 3),
+    ((2, 3), 3, 3), ((1, 6), 3, 3)],
+    ids=["bound-T-x-k", "bound-T-x-held", "no-row", "two-rounds-and-more",
+         "every-held-expert", "three-of-six-held"])
+def test_held_layer_drops_nothing_under_the_most_uneven_routing(
+        held, crowd, rounds):
+    """The rounds that do work, from none to all ``min(k, count)``: no
+    token on a held expert; one held expert with every token and another
+    with none (of two held: one round, exactly full; of four: a second
+    round for what the others got); every token on two and on every held
+    expert (more rows than a round holds, so the loop past the first round
+    runs, to a last round partly or wholly full). Nothing is dropped, the
+    rows past the real count multiply nothing, and the output and
+    gradients are still the reference's."""
+    layer, params, buffers, h = _latent_layer(held, skewed=crowd is None,
+                                              crowd=crowd)
     *_, sizes, _, _ = moe.moe_route(
         h, params["router"], 3, score="sigmoid",
         bias=buffers["choice_bias"], scale=2.5, held=held)
-    assert int(sizes[0]) == h.shape[0] and int(sizes[1]) == 0
+    n_tokens = h.shape[0]
+    if crowd is None:
+        assert int(sizes[0]) == n_tokens and int(sizes[1]) == 0
+    else:
+        assert all(int(n) == n_tokens for n in sizes[:crowd])
+    assert -(-int(sizes.sum()) // n_tokens) == rounds
+    assert rounds <= moe.held_rows(n_tokens, 3, held)[0]
     cot = jax.random.normal(jax.random.key(5), h.shape)
     variables = lambda p: {"params": p, "buffers": buffers}
     program = lambda p, h: jnp.sum(layer.apply(variables(p), h)[0] * cot)
@@ -423,32 +451,58 @@ def test_rows_past_the_assigned_are_masked_on_both_sides():
     np.testing.assert_array_equal(np.asarray(grad)[2:], 0.0)
 
 
-def test_the_shares_of_the_experts_add_up():
+@pytest.mark.parametrize("crowd", [None, 2], ids=["even", "one-share-full"])
+def test_the_shares_of_the_experts_add_up(crowd):
     """Four chips, two experts each, of a layer of eight: the held
     experts' parts, each through the latent up-projection (linear, no
     bias), summed over the shares, with the shared expert, which every
-    chip computes alike, counted once, are the uncut reference's layer
-    output."""
-    _, params, buffers, h = _latent_layer(None)
-    shared = jnp.square(jax.nn.relu(h @ params["shared_up"])) @ params[
-        "shared_down"]
-    total = 0.0
-    for first in (0, 2, 4, 6):
-        share, _, _, _ = _latent_layer((first, 2))
-        mine = {**params, "up": params["up"][first:first + 2],
-                "down": params["down"][first:first + 2]}
-        out, _ = share.apply({"params": mine, "buffers": buffers}, h)
-        total = total + (out - shared)
+    chip computes alike, counted once, are the layer that holds every
+    expert (and the uncut reference's), in output and in the gradient of
+    the input. ``one-share-full``: every token chooses both experts of the
+    first share, which then works through two full rounds while the
+    others share what is left."""
+    whole, params, buffers, h = _latent_layer(None)
+    if crowd:
+        _, params, buffers, h = _latent_layer((0, 8), crowd=crowd)
+    shared = lambda h: jnp.square(jax.nn.relu(h @ params["shared_up"])
+                                  ) @ params["shared_down"]
+
+    def total(h):
+        parts = 0.0
+        for first in (0, 2, 4, 6):
+            share, _, _, _ = _latent_layer((first, 2))
+            mine = {**params, "up": params["up"][first:first + 2],
+                    "down": params["down"][first:first + 2]}
+            out, _ = share.apply({"params": mine, "buffers": buffers}, h)
+            parts = parts + (out - shared(h))
+        return parts, parts + shared(h)
+
+    parts, got = total(h)
     want, _ = latent_reference.experts_layer(
         h, params, buffers["choice_bias"], _latent_config(None))
-    _close(total + shared, want, "sum of the shares")
-    assert float(jnp.linalg.norm(total)) > 0.1 * float(jnp.linalg.norm(want))
+    _close(got, want, "sum of the shares")
+    every = lambda h: whole.apply({"params": params, "buffers": buffers},
+                                  h)[0]
+    _close(got, every(h), "sum of the shares against every expert held")
+    assert float(jnp.linalg.norm(parts)) > 0.1 * float(jnp.linalg.norm(want))
+    cot = jax.random.normal(jax.random.key(7), h.shape)
+    _close(jax.grad(lambda h: jnp.sum(total(h)[1] * cot))(h),
+           jax.grad(lambda h: jnp.sum(every(h) * cot))(h), "d input")
+    if crowd:
+        *_, sizes, _, _ = moe.moe_route(
+            h, params["router"], 3, score="sigmoid",
+            bias=buffers["choice_bias"], scale=2.5, held=(0, 2))
+        assert int(sizes.sum()) == 2 * h.shape[0]
 
 
-def test_held_layer_gradient_program_scatters_no_row():
-    """As the layer that holds every expert: dispatch over the static
-    bound, the mask, the pad and the combine differentiate without a
-    scatter-add; what is left is the grouped product's bookkeeping."""
+def test_held_layer_gradient_program_scatters_a_rounds_rows_alone():
+    """Routing weights, counts, the sort and the mask differentiate
+    without a scatter-add, as in the layer that holds every expert. What
+    is left beside the grouped product's bookkeeping is a round's sum by
+    token (``_sum_by_token``: combine forward, dispatch backward and the
+    weights' gradient), which adds a round's rows into ``[T, width]`` and
+    was chosen on the chip's timing: never anything over the ``T x
+    count`` slots."""
     layer, params, buffers, h = _latent_layer((4, 4))
 
     def loss(params, h):
@@ -457,24 +511,130 @@ def test_held_layer_gradient_program_scatters_no_row():
 
     found = _scatters(jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         params, h).compile())
-    for elements, name in found:
-        assert "/jit(gmm)/" in name or "/jit(tgmm)/" in name, name
+    sums = [(elements, name) for elements, name in found
+            if "/jit(gmm)/" not in name and "/jit(tgmm)/" not in name]
+    assert sums
+    for elements, name in sums:
+        assert "/moe_dispatch/" in name or "/moe_combine/" in name, name
+        assert elements <= h.shape[0] * 8, (elements, name)   # T x latent
+    for elements, name in set(found) - set(sums):
         assert elements <= 4 + h.shape[0] * 3, (elements, name)
+
+
+def _grouped_products(jaxpr):
+    """``[(name, row counts)]`` of every grouped product (megablox's
+    ``gmm`` and ``tgmm``, each a ``jax.jit``) in ``jaxpr`` and what it
+    calls, a traced function counted once however many equations call it
+    (``jax.jit`` hands every caller of one function at one shape the same
+    jaxpr), and the primitives met on the way."""
+    found, primitives, seen = [], set(), set()
+
+    def walk(jaxpr):
+        jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+        if id(jaxpr) in seen:
+            return
+        seen.add(id(jaxpr))
+        for eqn in jaxpr.eqns:
+            primitives.add(eqn.primitive.name)
+            if eqn.params.get("name") in ("gmm", "tgmm"):
+                found.append((eqn.params["name"], sorted(
+                    {n for v in eqn.invars[:2] for n in v.aval.shape})))
+                continue
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) else (
+                        value,):
+                    if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                        walk(sub)
+
+    walk(jaxpr)
+    return found, primitives
+
+
+@pytest.mark.parametrize("act, stacks", [("relu2", 2), ("swiglu", 3)])
+@pytest.mark.parametrize("held, k", [((2, 2), 3), ((1, 6), 4), ((3, 1), 2)],
+                         ids=["two-rounds", "four-rounds", "one-round"])
+def test_held_layer_holds_its_round_once(held, k, act, stacks):
+    """The set-up's proxy a CPU can read: whatever ``min(k, count)`` is,
+    the program of a held layer holds the round's grouped products at one
+    row count, ``T``, and once: as many products as the layer's one pass
+    had, a stack each in the forward program; in the gradient that, the
+    round made again in the backward loop (a stack each) and its pullback
+    (two a stack). The rounds are a ``while`` that the router's count
+    bounds, and there is no ``cond`` and no ``scan``."""
+    tokens = 40
+    layer = MoEMlp(8, 12, k, dtype=jnp.float32, expert_act=act, held=held,
+                   **({"score": "sigmoid"} if act == "relu2" else {}))
+    h = jax.random.normal(jax.random.key(0), (tokens, 16))
+    variables = layer.init(jax.random.key(1), h)
+    forward = lambda h: layer.apply(variables, h)[0]
+    for program, products in (
+            (forward, stacks),
+            (jax.grad(lambda h: jnp.sum(forward(h) ** 2)), 4 * stacks)):
+        found, primitives = _grouped_products(jax.make_jaxpr(program)(h))
+        assert len(found) == products, found
+        for _, sizes in found:
+            assert tokens in sizes
+            assert not [n for n in sizes if n > tokens and n % tokens == 0]
+        assert "while" in primitives
+        assert "cond" not in primitives and "scan" not in primitives
+
+
+def test_layer_that_holds_every_expert_has_no_loop():
+    """``held=None`` keeps its single pass over ``T x k`` rows: no
+    ``cond`` and no ``while`` in forward or gradient, and its products are
+    over every row."""
+    layer, params, h = _layer(8, 2, skewed=False)
+    forward = lambda h: layer.apply({"params": params}, h)[0]
+    for program in (forward, jax.grad(lambda h: jnp.sum(forward(h) ** 2))):
+        found, primitives = _grouped_products(jax.make_jaxpr(program)(h))
+        assert found and all(2 * h.shape[0] in sizes for _, sizes in found)
+        assert not primitives & {"cond", "while", "scan"}
+
+
+def test_held_layer_names_add_no_operation(monkeypatch):
+    """The scopes of a round are names: the lowered gradient of a held
+    layer is the same text without them; and they are there, inside the
+    loop over the rounds, in the forward and in the backward pass, where a
+    device trace's readers look for them."""
+    import contextlib
+
+    def lowered():
+        # a round is a jax.jit that remembers its trace: build it anew
+        monkeypatch.setattr(moe, "_held_round",
+                            jax.jit(moe._held_round.__wrapped__))
+        layer, params, buffers, h = _latent_layer((4, 4))
+        return jax.jit(jax.grad(lambda p, h: jnp.sum(layer.apply(
+            {"params": p, "buffers": buffers}, h)[0] ** 2))).lower(params, h)
+
+    step = lowered()
+    # a round is lowered once and called: its call site's names are put
+    # before its own when the program becomes HLO
+    names = set(re.findall(r'op_name="([^"]*)"', step.compile().as_text()))
+    for scope in ("moe_dispatch", "moe_experts", "moe_combine"):
+        inside = [n for n in names if "/while/body/" in n
+                  and f"/{scope}/" in n]
+        assert [n for n in inside if "transpose(" in n], scope
+        assert [n for n in inside if "transpose(" not in n], scope
+    monkeypatch.setattr(jax, "named_scope",
+                        contextlib.contextmanager(lambda name: (yield)))
+    assert lowered().as_text() == step.as_text()
 
 
 def test_held_layers_are_counted_by_what_they_hold():
     from horovod_tpu import metrics
 
-    def count(held):
+    def count(held, round_rows):
         m = metrics.registry().get("hvt_moe_layers_traced_total")
         return m.labels(experts="8", top_k="3", product=moe.PRODUCT,
-                        held=held).value if m else 0.0
+                        held=held, round_rows=round_rows).value if m else 0.0
 
     layer, params, buffers, h = _latent_layer((4, 4))
-    before = count("4"), count("8")
+    rows = str(h.shape[0])          # a round is one row a token
+    before = count("4", rows), count("8", "all")
     jax.jit(lambda p, h: layer.apply(
         {"params": p, "buffers": buffers}, h)[0]).lower(params, h)
-    assert (count("4"), count("8")) == (before[0] + 1, before[1])
+    assert (count("4", rows), count("8", "all")) == (before[0] + 1,
+                                                     before[1])
 
 
 @pytest.mark.parametrize("field, value, match", [
