@@ -1,0 +1,96 @@
+#!/usr/bin/env python
+"""qwen3next_learning_rate.py — which constant learning rate the cell
+``qwen3next-s8192`` can repeat one batch at: for each of ``--rates`` it
+trains the cell's model from the same initialisation on the cell's batch
+for ``--steps`` steps and prints, every ``--every`` steps, the loss and
+the rows that land on the 32 held experts of each of the four expert
+layers (a round is 16,384 rows: more than that in a layer is a second
+round). One compiled step serves every rate (the rate is part of the
+optimizer's state, ``optax.inject_hyperparams``).
+
+    chiprun -- python benchmarks/qwen3next_learning_rate.py --rates 1e-6 4e-6
+
+A builder's script: it decides nothing. It refuses to run without a TPU.
+"""
+
+import argparse
+import json
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def main():
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--rates", type=float, nargs="+",
+                   default=[1e-6, 4e-6, 1.6e-5])
+    p.add_argument("--steps", type=int, default=48)
+    p.add_argument("--every", type=int, default=12)
+    p.add_argument("--seed", type=int, default=2147488301)
+    args = p.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    if jax.devices()[0].platform != "tpu":
+        sys.exit("qwen3next_learning_rate: no TPU, nothing to measure")
+
+    from chipbench import run as harness
+    from chipbench.families import qwen3_next
+    from chipbench.setup_sources import enable_compile_cache
+
+    enable_compile_cache()
+    config = harness.read_json("chipbench", "configs", "qwen3-next-80b.json")
+    cell = harness.read_json("chipbench", "workloads", "qwen3next-s8192.json")
+    job = qwen3_next.build(config, cell)
+    cfg = qwen3_next._model_config(config, cell["seq_len"])
+    spec = {k: v for k, v in config["optimizer"].items()
+            if k not in ("name", "learning_rate")}
+    tx = optax.inject_hyperparams(
+        lambda learning_rate: optax.adamw(learning_rate, **spec))(
+            learning_rate=0.0)
+
+    @jax.jit
+    def init(key, rate):
+        params, extra = job.init(key)
+        state = tx.init(params)
+        state.hyperparams["learning_rate"] = rate
+        return params, extra, state
+
+    def step(params, extra, state, batch):
+        (loss, extra), grads = jax.value_and_grad(job.loss, has_aux=True)(
+            params, extra, batch)
+        updates, state = tx.update(grads, state, params)
+        return optax.apply_updates(params, updates), extra, state, loss
+
+    step = jax.jit(step, donate_argnums=(0, 1, 2))
+
+    @jax.jit
+    def rows(params, extra, batch):
+        _, sown = job.loss_and_sown(params, extra, batch)
+        return [jnp.sum(qwen3_next.held_rows(block["experts"], cfg))
+                for block in sown.values() if "experts" in block]
+
+    k_init, k_batch = jax.random.split(jax.random.key(args.seed))
+    batch = jax.jit(lambda k: job.make_batch(k, 1))(k_batch)
+    for rate in args.rates:
+        params, extra, state = init(k_init, rate)
+        for i in range(args.steps + 1):
+            if i % args.every == 0:
+                print(json.dumps({
+                    "learning_rate": rate, "step": i,
+                    "rows_on_held_experts": [
+                        int(n) for n in rows(params, extra, batch)],
+                    "round": int(batch.size)}), flush=True)
+            if i < args.steps:
+                params, extra, state, loss = step(params, extra, state, batch)
+                if i % args.every == 0 or i == args.steps - 1:
+                    print(json.dumps({"learning_rate": rate, "step": i,
+                                      "loss": float(loss)}), flush=True)
+        del params, extra, state
+
+
+if __name__ == "__main__":
+    main()

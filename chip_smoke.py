@@ -24,7 +24,12 @@ Phases, each something no cell of the benchmark decides. One chip:
 8192, four query heads on one key-value head of 128, the attention of the
 cell ``nemotron3s-s8192``, against the float32 formula: only at that length
 do they stream more than one sequence tile a grid step on a chip, and the
-cell compares its gradients at 2048 positions), ``eager`` (the immediate path). Four
+cell compares its gradients at 2048 positions), ``flash256`` (the same
+kernels at a head of 256, 16 query heads on 2 key-value heads, the attention
+of the cell ``qwen3next-s8192``), ``gdn8192`` (the chunked gated delta rule
+of ``models/gdn.py`` at that cell's shape against the float32 recurrence,
+and the time a forward and backward takes by chunk length), ``eager`` (the
+immediate path); ``--phases`` names the ones to run. Four
 chips: ``device``, ``ring4`` (``parallel/sequence.py``, ``sp`` = 4),
 ``dryrun4`` (the GSPMD dp x sp x tp step against one device).
 
@@ -314,6 +319,84 @@ def phase_flash8192(shape=(2, 8192, 4, 1, 128)):
     return flash_kernel_vs_f32(shape)
 
 
+def phase_flash256(shape=(2, 8192, 16, 2, 256)):
+    """The compiled kernels at a head of 256 in groups of 8 query heads a
+    key-value head, 2 x 8192: the attention shape of the cell
+    ``qwen3next-s8192`` (the kernels' tiles and VMEM estimate had been
+    checked at 64 and 128 alone)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    b, s, h, h_kv, d = shape
+    q, k = (jax.ShapeDtypeStruct((b, s, n, d), jnp.bfloat16)
+            for n in (h, h_kv))
+    require_compiled_flash(jax.jit(functools.partial(
+        flash_attention, causal=True)).lower(q, k, k).as_text())
+    return flash_kernel_vs_f32(shape)
+
+
+# -------------------------------------------------------------------- gdn8192
+
+def phase_gdn8192(shape=(2, 8192, 16, 32, 128, 128), chunks=(64, 128, 256)):
+    """The chunked gated delta rule (``models/gdn.py``) at the cell
+    ``qwen3next-s8192``'s shape, (batch, seq, key heads, value heads, d_k,
+    d_v) in bf16, output and gradients against the float32 recurrence
+    taken one position after another (the benchmark's reference's,
+    ``chipbench/reference/qwen3_next.py``), and the seconds one forward and
+    backward takes at each of ``chunks`` (host clock around
+    ``block_until_ready``, the mean of five calls after one)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chipbench.reference import qwen3_next as reference
+    from horovod_tpu.models import gdn
+
+    b, s, h_k, h_v, d_k, d_v = shape
+    rng = np.random.RandomState(0)
+    normal = lambda *dims: jnp.asarray(rng.normal(size=dims), jnp.float32)
+    low = lambda t: t.astype(jnp.bfloat16)
+    q = low(gdn.l2_normalise(normal(b, s, h_k, d_k)) * d_k ** -0.5)
+    k = low(gdn.l2_normalise(normal(b, s, h_k, d_k)))
+    v, do = low(normal(b, s, h_v, d_v)), low(normal(b, s, h_v, d_v))
+    g = -jnp.exp(normal(h_v)) * jax.nn.softplus(normal(b, s, h_v) + 1.0) / 16
+    beta = jax.nn.sigmoid(normal(b, s, h_v))
+
+    def recurrence(q, k, v, g, beta):       # one sequence, float32
+        wide = lambda t: jnp.repeat(t, h_v // h_k, axis=1)
+        return reference.delta_rule(wide(q), wide(k), v, g, beta)
+
+    def with_gradients(rule, do):
+        return jax.jit(lambda *a: (lambda o, vjp: (o, *vjp(do)))(
+            *jax.vjp(rule, *a)))
+
+    f32 = lambda t: t.astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = with_gradients(lambda *a: jax.lax.map(
+            lambda one: recurrence(*one), a), f32(do))(
+                f32(q), f32(k), f32(v), g, beta)
+    out = {"shape": list(shape)}
+    for chunk in chunks:
+        step = with_gradients(lambda *a: gdn.gated_delta_rule(
+            *a, chunk=chunk), do)
+        got = jax.block_until_ready(step(q, k, v, g, beta))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            jax.block_until_ready(step(q, k, v, g, beta))
+        seconds = (time.perf_counter() - t0) / 5
+        errs = {name: rel_l2(one, w) for name, one, w in
+                zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want)}
+        check(all(np.isfinite(list(errs.values())))
+              and max(errs.values()) <= BF16_REL_L2,
+              f"the chunked rule at chunk {chunk} differs from the float32 "
+              f"recurrence: {errs} (relative L2), bound {BF16_REL_L2}")
+        out[f"chunk_{chunk}"] = {"ms_forward_and_backward": 1e3 * seconds,
+                                 "rel_l2": errs}
+    return out
+
+
 # --------------------------------------------------------------------- eager
 
 def phase_eager():
@@ -420,13 +503,17 @@ def main(argv=None):
                         "it is compared with")
     p.add_argument("--worker", action="store_true",
                    help="internal: what the launcher phase starts")
+    p.add_argument("--phases", default="",
+                   help="comma-separated names: of the one-chip phases "
+                        "other than 'device', only these (default: all)")
     args = p.parse_args(argv)
     if args.worker:
         return worker()
 
     versions = {name: importlib.metadata.version(name)
                 for name in ("jax", "jaxlib", "libtpu", "flax", "optax")}
-    if args.chips == 1:
+    only = set(filter(None, args.phases.split(",")))
+    if args.chips == 1 and (not only or "launcher" in only):
         # first: no backend is up in this process yet
         run_phase("launcher", phase_launcher)
 
@@ -443,8 +530,12 @@ def main(argv=None):
     run_phase("device", lambda: phase_device(args.chips), meter)
     devices = jax.devices()
     if args.chips == 1:
-        run_phase("flash8192", phase_flash8192, meter)
-        run_phase("eager", phase_eager, meter)
+        for name, phase in (("flash8192", phase_flash8192),
+                            ("flash256", phase_flash256),
+                            ("gdn8192", phase_gdn8192),
+                            ("eager", phase_eager)):
+            if not only or name in only:
+                run_phase(name, phase, meter)
     else:
         run_phase("ring4", lambda: phase_ring4(devices), meter)
         run_phase("dryrun4", lambda: phase_dryrun4(devices), meter)

@@ -36,8 +36,11 @@ takes no gradient, the weights the scores at the chosen, divided by
 their sum and times ``route_scale``; no auxiliary loss, ``aux`` is
 ``{}``), ``expert_act="relu2"`` (``down(relu(up(x))^2)``, two stacks),
 ``latent`` (the routed experts work in a narrower width between a down-
-and an up-projection, scope ``moe_latent``) and ``shared_ff`` (an expert
-every token passes, beside the routed ones, scope ``moe_shared``).
+and an up-projection, scope ``moe_latent``), ``shared_ff`` (an expert
+every token passes, beside the routed ones, scope ``moe_shared``;
+``shared_gate``: its output times ``sigmoid(h w_g)``) and ``renormalise``
+(the softmax score's chosen weights divided by their sum too: Qwen's
+``norm_topk_prob``).
 
 **A chip's share**: ``held = (first, count)`` builds the stacks for
 experts ``[first, first + count)`` alone. The router still scores and
@@ -148,7 +151,7 @@ _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
 def moe_route(h, router, k, *, score="softmax", bias=None, scale=1.0,
-              held=None):
+              held=None, renormalise=None):
     """``h [T, d]``, ``router [d, E]`` -> the ``k`` choices of every
     token. Returns ``(experts [T, k], weights [T, k] float32, order
     [T x k], inverse [T x k], group_sizes [E], aux, probs [T, E])``:
@@ -163,6 +166,11 @@ def moe_route(h, router, k, *, score="softmax", bias=None, scale=1.0,
     gradient: it only enters the choice), the weights are ``probs`` at
     the chosen over their sum + 1e-20, times ``scale``; ``aux`` is
     ``{}``.
+
+    ``renormalise``: whether a token's ``k`` weights are divided by their
+    sum (+ 1e-20) and multiplied by ``scale``, over all its chosen, held
+    here or not; None is each score's habit (the sigmoid's are, the
+    softmax's are not).
 
     ``held = (first, count)``: the choice is still over all ``E``, but a
     token has a slot a *held* expert in place of one a choice:
@@ -192,7 +200,9 @@ def moe_route(h, router, k, *, score="softmax", bias=None, scale=1.0,
         weights = jnp.where(assigned, probs, 0.0)
         counts = jnp.sum(assigned, axis=0, dtype=jnp.int32)
     group_sizes = counts
-    if score == "sigmoid":
+    if renormalise is None:
+        renormalise = score == "sigmoid"
+    if renormalise:
         weights = weights * (scale / (
             jnp.sum(weights, axis=-1, keepdims=True) + 1e-20))
     if held is None:
@@ -411,7 +421,9 @@ class MoEMlp(nn.Module):
     ``latent``: the routed experts' width, between ``latent_in [d,
     latent]`` and ``latent_out [latent, d]`` (0: the model's own).
     ``shared_ff``: the width of one shared expert of the experts' kind on
-    the layer's own input (0: none). ``held = (first, count)``: the
+    the layer's own input (0: none), ``shared_gate``: its output times
+    ``sigmoid(h w_g)``, ``shared_expert_gate [d, 1]``. ``renormalise``:
+    see ``moe_route``. ``held = (first, count)``: the
     stacks hold experts ``[first, first + count)`` and the output is
     their part of the sum, with the latent projections and the shared
     expert, which every chip computes alike, in full."""
@@ -426,6 +438,8 @@ class MoEMlp(nn.Module):
     latent: int = 0
     shared_ff: int = 0
     held: Optional[tuple] = None
+    renormalise: Optional[bool] = None
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -453,7 +467,8 @@ class MoEMlp(nn.Module):
             (experts, weights, order, inverse, group_sizes, aux,
              probs) = moe_route(h, router, self.experts_per_token,
                                 score=self.score, bias=bias,
-                                scale=self.route_scale, held=self.held)
+                                scale=self.route_scale, held=self.held,
+                                renormalise=self.renormalise)
         for name, value in (("router_input", h), ("router_probs", probs),
                             ("experts", experts)):
             self.sow("intermediates", name, value)
@@ -489,8 +504,13 @@ class MoEMlp(nn.Module):
                 hidden = (jax.nn.silu(jnp.dot(low(), dense(
                     "shared_gate", d, self.shared_ff))) * hidden
                     if gated else _relu2(hidden))
-                out = out + jnp.dot(hidden, dense("shared_down",
-                                                  self.shared_ff, d))
+                shared = jnp.dot(hidden, dense("shared_down",
+                                               self.shared_ff, d))
+                if self.shared_gate:
+                    shared = shared * jax.nn.sigmoid(jnp.dot(
+                        low(), dense("shared_expert_gate", d, 1),
+                        preferred_element_type=jnp.float32))
+                out = out + shared
         return out.reshape(x.shape).astype(x.dtype), aux
 
 

@@ -87,13 +87,13 @@ def chunk_for(seq_len: int, chunk: Optional[int] = None) -> int:
     return max(1, min(chunk or CHUNK, seq_len))
 
 
-def causal_conv(x, weight, bias):
-    """``x [b, s, c]``, ``weight [taps, c]``, ``bias [c]``: position t
-    gets ``sum_j weight[j] x[t - taps + 1 + j] + bias`` (zeros before the
-    sequence), then ``silu``; float32 inside, ``x.dtype`` out."""
+def causal_conv(x, weight, bias=None):
+    """``x [b, s, c]``, ``weight [taps, c]``, ``bias [c]`` or none:
+    position t gets ``sum_j weight[j] x[t - taps + 1 + j] + bias`` (zeros
+    before the sequence), then ``silu``; float32 inside, ``x.dtype`` out."""
     taps, seq = weight.shape[0], x.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    out = bias.astype(jnp.float32)
+    out = 0.0 if bias is None else bias.astype(jnp.float32)
     for j in range(taps):
         out = out + weight[j].astype(jnp.float32) * padded[:, j:j + seq]
     return jax.nn.silu(out).astype(x.dtype)

@@ -92,7 +92,8 @@ class GPTConfig:
     # One mixer a layer, in the order a string gives (hybrid models:
     # Nemotron-H's ``hybrid_override_pattern``): layer i is ``x +
     # mixer(RMSNorm(x))`` with the mixer ``layer_pattern[i]`` names, "*"
-    # attention, "M" a Mamba-2 mixer (models/ssm.py), "E" the expert
+    # attention, "M" a Mamba-2 mixer (models/ssm.py), "G" a Gated
+    # DeltaNet mixer (models/gdn.py), "E" the expert
     # layer (models/moe.py), "-" the dense MLP. None (default) = every
     # layer the attention + MLP (or expert) pair of ``Block``.
     layer_pattern: Optional[str] = None
@@ -128,6 +129,40 @@ class GPTConfig:
     experts_held: Optional[tuple] = None
     heads_held: Optional[tuple] = None
     ssm_heads_held: Optional[tuple] = None
+    # Attention beyond the above, each default today's layer (Qwen3-Next's
+    # gated attention sets all five). ``head_dim``: a head's width where it
+    # is not d_model / n_heads (None). ``head_norm``: RMSNorm a head on q
+    # and on k, one [head_dim] scale for all heads, before the rotary
+    # (``qk_norm`` is over the whole projected width). ``rotary_base`` and
+    # ``rotary_fraction``: the rotary's base, and the leading share of a
+    # head's channels it turns (the rest pass unrotated). ``attn_gate``:
+    # the q projection is twice as wide, a head's columns ``[query |
+    # gate]``, and the attention's output is multiplied by sigmoid(gate)
+    # before ``o``.
+    head_dim: Optional[int] = None
+    head_norm: bool = False
+    rotary_base: float = 10000.0
+    rotary_fraction: float = 1.0
+    attn_gate: bool = False
+    # RMSNorm as ``x rsqrt(mean x^2 + eps) (1 + scale)`` with ``scale``
+    # from zero, for the layers' norms, the final norm and ``head_norm``
+    # (under weight decay the gain is pulled to 1, where the default's
+    # ``scale`` from one is pulled to 0).
+    norm_unit_offset: bool = False
+    # The Gated DeltaNet mixers' sizes (models/gdn.py, pattern letter
+    # "G"): gdn_key_heads of gdn_key_dim channels serving gdn_value_heads
+    # of gdn_value_dim, gdn_conv taps.
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 128
+    gdn_value_dim: int = 128
+    gdn_conv: int = 4
+    # The expert layer's further options: whether a token's chosen weights
+    # are divided by their sum (``norm_topk_prob``; None: the sigmoid
+    # score's are and the softmax's are not), and the shared expert's
+    # output times sigmoid(h w_g).
+    moe_renormalise: Optional[bool] = None
+    moe_shared_gate: bool = False
 
 
 # The crossover policy lives with the kernel (ops/flash_attention.py);
@@ -140,17 +175,21 @@ def _resolve_flash(use_flash, local_seq) -> bool:
     return resolve_flash(use_flash, local_seq)
 
 
-def _rotary(x, positions):
-    """Rotary position embeddings (fp32 phase math)."""
+def _rotary(x, positions, base=10000.0, width=None):
+    """Rotary position embeddings (fp32 phase math) at ``base`` over the
+    first ``width`` channels of a head (None: all of them), the halves of
+    that width rotated against each other; the rest pass as they are."""
     *_, seq, heads, head_dim = x.shape
-    half = head_dim // 2
-    freqs = 1.0 / (10000.0 ** (np.arange(0, half) / half))
+    width = head_dim if width is None else width
+    half = width // 2
+    freqs = 1.0 / (base ** (np.arange(0, half) / half))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [.., seq, half]
     cos = jnp.cos(angles)[..., None, :].astype(x.dtype)
     sin = jnp.sin(angles)[..., None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin,
-                            x1 * sin + x2 * cos], axis=-1)
+    x1, x2 = x[..., :half], x[..., half:width]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+                           + ([x[..., width:]] if width < head_dim else []),
+                           axis=-1)
 
 
 def _repeat_kv(k, v, group):
@@ -163,15 +202,27 @@ def _repeat_kv(k, v, group):
 
 
 class RMSNorm(nn.Module):
+    """``x rsqrt(mean x^2 + eps) scale`` over the last axis with ``scale``
+    from one; ``unit_offset``: ``... (1 + scale)`` with ``scale`` from
+    zero."""
+
     eps: float = 1e-6
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones_init(),
-                           (x.shape[-1],), jnp.float32)
+        scale = self.param(
+            "scale", nn.initializers.zeros_init() if self.unit_offset
+            else nn.initializers.ones_init(), (x.shape[-1],), jnp.float32)
+        if self.unit_offset:
+            scale = 1.0 + scale
         x32 = x.astype(jnp.float32)
         var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
         return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
+
+
+def _norm(cfg: "GPTConfig", name: str) -> RMSNorm:
+    return RMSNorm(cfg.norm_eps, cfg.norm_unit_offset, name=name)
 
 
 def held_heads(n_heads, n_kv, held):
@@ -198,7 +249,7 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        head_dim = cfg.d_model // cfg.n_heads
+        head_dim = cfg.head_dim or cfg.d_model // cfg.n_heads
         dense = lambda feats, name: nn.DenseGeneral(
             feats, axis=-1, use_bias=False, dtype=cfg.dtype,
             param_dtype=jnp.float32, name=name)
@@ -208,16 +259,25 @@ class Attention(nn.Module):
                 f"n_kv_heads ({n_kv}) must divide n_heads "
                 f"({cfg.n_heads})")
         n_heads, n_kv = held_heads(cfg.n_heads, n_kv, cfg.heads_held)
-        q = dense((n_heads, head_dim), "q")(x)
+        if cfg.qk_norm and cfg.head_norm:
+            raise ValueError("qk_norm (over the whole projected width) and "
+                             "head_norm (a head) are one or the other")
+        q = dense((n_heads, head_dim * (2 if cfg.attn_gate else 1)), "q")(x)
+        if cfg.attn_gate:
+            q, gate = jnp.split(q, 2, axis=-1)
         k = dense((n_kv, head_dim), "k")(x)
         v = dense((n_kv, head_dim), "v")(x)
         if cfg.qk_norm:
             full_width = lambda t, name: RMSNorm(cfg.norm_eps, name=name)(
                 t.reshape(*t.shape[:-2], -1)).reshape(t.shape)
             q, k = full_width(q, "q_norm"), full_width(k, "k_norm")
+        if cfg.head_norm:
+            q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
         if cfg.rotary:
-            q = _rotary(q, positions)
-            k = _rotary(k, positions)
+            turned = (None if cfg.rotary_fraction == 1.0
+                      else int(cfg.rotary_fraction * head_dim))
+            q = _rotary(q, positions, cfg.rotary_base, turned)
+            k = _rotary(k, positions, cfg.rotary_base, turned)
 
         if cfg.ring_mesh is not None:
             from horovod_tpu.parallel.sequence import ring_attention
@@ -261,6 +321,9 @@ class Attention(nn.Module):
             scores = jnp.where(causal, scores, -1e30)
             probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
             out = jnp.einsum("...hqk,...khd->...qhd", probs, v)
+        if cfg.attn_gate:
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(cfg.dtype)
         return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False,
                                dtype=cfg.dtype, param_dtype=jnp.float32,
                                name="o")(out)
@@ -288,7 +351,8 @@ def _expert_layer(cfg: GPTConfig):
                   route_scale=cfg.moe_route_scale,
                   expert_act=cfg.moe_expert_act, latent=cfg.moe_latent,
                   shared_ff=cfg.moe_shared_ff, held=cfg.experts_held,
-                  name="moe")
+                  renormalise=cfg.moe_renormalise,
+                  shared_gate=cfg.moe_shared_gate, name="moe")
 
 
 class Block(nn.Module):
@@ -301,9 +365,8 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        x = x + Attention(cfg, name="attn")(
-            RMSNorm(cfg.norm_eps, name="ln1")(x), positions)
-        h = RMSNorm(cfg.norm_eps, name="ln2")(x)
+        x = x + Attention(cfg, name="attn")(_norm(cfg, "ln1")(x), positions)
+        h = _norm(cfg, "ln2")(x)
         if not cfg.n_experts:
             return x + MLP(cfg, name="mlp")(h), None
         out, aux = _expert_layer(cfg)(h)
@@ -321,7 +384,7 @@ class MixerBlock(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         cfg, aux = self.cfg, None
-        h = RMSNorm(cfg.norm_eps, name="norm")(x)
+        h = _norm(cfg, "norm")(x)
         if self.kind == "*":
             out = Attention(cfg, name="attn")(h, positions)
         elif self.kind == "M":
@@ -331,6 +394,13 @@ class MixerBlock(nn.Module):
                 cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
                 cfg.ssm_state, cfg.ssm_conv, held=cfg.ssm_heads_held,
                 norm_eps=cfg.norm_eps, dtype=cfg.dtype, name="ssm")(h)
+        elif self.kind == "G":
+            from horovod_tpu.models.gdn import GatedDeltaNet
+
+            out = GatedDeltaNet(
+                cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim,
+                cfg.gdn_value_dim, cfg.gdn_conv, norm_eps=cfg.norm_eps,
+                dtype=cfg.dtype, name="gdn")(h)
         elif self.kind == "E":
             out, aux = _expert_layer(cfg)(h)
         elif self.kind == "-":
@@ -338,7 +408,8 @@ class MixerBlock(nn.Module):
         else:
             raise ValueError(
                 f"layer_pattern holds {self.kind!r}: a layer is one of "
-                f"'*' (attention), 'M' (Mamba-2), 'E' (experts), '-' (MLP)")
+                f"'*' (attention), 'M' (Mamba-2), 'G' (Gated DeltaNet), "
+                f"'E' (experts), '-' (MLP)")
         return x + out, aux
 
 
@@ -390,7 +461,7 @@ class GPT(nn.Module):
             if layer_aux is not None:
                 aux = {name: aux.get(name, 0.0) + value
                        for name, value in layer_aux.items()}
-        x = RMSNorm(cfg.norm_eps, name="ln_f")(x)
+        x = _norm(cfg, "ln_f")(x)
         head = emb if cfg.tie_embeddings else self.param(
             "lm_head", nn.initializers.normal(0.02),
             (cfg.vocab_size, cfg.d_model), jnp.float32)
@@ -434,6 +505,10 @@ def param_partition_spec(params, *, tp_axis="tp", tp_size=None,
             from horovod_tpu.models.ssm import ssm_leaf_spec
 
             return ssm_leaf_spec(names[-1], tp_axis)
+        if "gdn" in names:
+            from horovod_tpu.models.gdn import gdn_leaf_spec
+
+            return gdn_leaf_spec(names[-1], tp_axis)
         if any(n in ("q", "k", "v") for n in names):
             heads = leaf.shape[1] if hasattr(leaf, "shape") else None
             if tp_size and heads is not None and heads % tp_size:

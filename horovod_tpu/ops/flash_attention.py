@@ -323,13 +323,21 @@ def _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile, s):
     lanes = -(-d // _LANES) * _LANES
     row, acc, stat = lanes * itemsize, lanes * 4, _LANES * 4
     score = 4 * block_q * block_k
+    # past 128 lanes, what the float32 [block, d] values of a sub-block
+    # (the scaled q, the rescaled accumulator, dQ's, dK's and dV's
+    # contributions) outgrow the slack the figures above were checked
+    # with: at d = 256, 2 x 8192, 16 heads on 2 (qwen3-next-80b) the
+    # compiler took 16.31 MiB for the forward alone and 38.27 for the
+    # backward inside the model, where the terms before this one and
+    # XLA's share came to 15.5 and 38.0
+    wide = 3 * (block_q + block_k) * (acc - stat)
     if kernel == "fwd":     # K V stream; q o lse blocks; acc m l scratch
         return (score + 2 * 2 * tile * row
-                + block_q * (2 * 2 * row + 2 * stat + acc + 2 * stat))
+                + block_q * (2 * 2 * row + 2 * stat + acc + 2 * stat) + wide)
     # bwd: Q dO lse delta stream in, a dq tile out; k v dk dv blocks and
     # the two accumulators of a block; dq's accumulator
     return (2 * score + 2 * tile * (2 * (row + stat) + row)
-            + block_k * (4 * 2 * row + 2 * acc) + s * acc)
+            + block_k * (4 * 2 * row + 2 * acc) + s * acc + wide)
 
 
 def _compiler_params(kernel, block_q, block_k, d, itemsize, tile, s):

@@ -72,7 +72,8 @@ def _qkv(shape, sharding):
 # (batch, seq, heads, kv_heads, head_dim): the shapes a 12 x 768 GPT and
 # chip_smoke.py run, the benchmark cells gpt2l-s1024's, gpt2l-s4096's and
 # olmoe-s4096's and nemotron3s-s8192's own (four query heads on one
-# key-value head at 8192 positions), gpt2l-dp4's (four chips' batch under a shard_map
+# key-value head at 8192 positions) and qwen3next-s8192's (a head of 256,
+# sixteen query heads on two key-value heads), gpt2l-dp4's (four chips' batch under a shard_map
 # that checks vma), gpt2-large's heads at 4 x 2048 and at 1 x 8192 (two
 # streamed tiles: the backward's dQ accumulator is addressed by a dynamic
 # slice and leaves a tile at a time), one grouped-query shape, the 4-chip
@@ -93,6 +94,7 @@ def _qkv(shape, sharding):
     pytest.param("flash", (1, 4096, 32, 8, 128), id="flash-gqa-32-8-128"),
     pytest.param("flash", (2, 4096, 16, 16, 128), id="flash-olmoe-s4096"),
     pytest.param("flash", (2, 8192, 4, 1, 128), id="flash-nemotron3s-s8192"),
+    pytest.param("flash", (2, 8192, 16, 2, 256), id="flash-qwen3next-s8192"),
     pytest.param("ring", (1, 16384, 12, 12, 64), id="ring-sp4-16384"),
     pytest.param("refused", (1, 100, 2, 2, 64), id="block-not-multiple-of-8"),
 ])

@@ -51,7 +51,8 @@ def test_flash_must_be_the_compiled_kernel():
 
 
 # what no cell of the benchmark decides, in the order it runs
-PHASES = {1: ["launcher", "device", "flash8192", "eager"],
+PHASES = {1: ["launcher", "device", "flash8192", "flash256", "gdn8192",
+              "eager"],
           4: ["device", "ring4", "dryrun4"]}
 
 
@@ -110,6 +111,27 @@ def test_compile_cache_is_placed_from_outside_or_fixed(monkeypatch):
     finally:
         for name, value in before.items():
             jax.config.update(name, value)
+
+
+def test_named_phases_alone_run(monkeypatch):
+    ran = []
+    monkeypatch.setattr(chip_smoke, "run_phase",
+                        lambda name, fn, meter=None: ran.append(name))
+    monkeypatch.setattr("chipbench.setup_sources.enable_compile_cache",
+                        lambda: "/nowhere")
+    chip_smoke.main(["--phases", "gdn8192,flash256"])
+    assert ran == ["device", "flash256", "gdn8192"]
+
+
+def test_chunked_rule_against_the_float32_recurrence():
+    # the gdn8192 phase's comparison at a size the CPU can afford, a chunk
+    # that divides the sequence and one that does not
+    out = chip_smoke.phase_gdn8192(shape=(1, 256, 2, 4, 16, 16),
+                                   chunks=(64, 100))
+    for chunk in ("chunk_64", "chunk_100"):
+        assert set(out[chunk]["rel_l2"]) == {"o", "dq", "dk", "dv", "dg",
+                                             "dbeta"}
+        assert max(out[chunk]["rel_l2"].values()) < chip_smoke.BF16_REL_L2
 
 
 def test_flash_kernel_against_the_float32_formula():

@@ -591,6 +591,31 @@ def test_scoped_vmem_is_raised_only_where_the_estimate_nears_it():
             == 4096 * 128 * 4)
 
 
+@pytest.mark.parametrize("kernel, took_mib", [("fwd", 16.31), ("bwd", 38.27)])
+def test_scoped_vmem_at_a_head_of_256(kernel, took_mib):
+    """Past 128 lanes the float32 [block, d] values of a sub-block count:
+    at 2 x 8192 and d = 256 (qwen3next-s8192's attention) the limit each
+    kernel is given covers what the v5e's compiler took for it (PERF.md
+    section 6, PR 33), where the estimate without that term, with XLA's
+    share, came to less; and at 64 and 128 lanes the term is nothing, so
+    the figures the estimate was checked with stand."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    bq, bk = fa._derive_tile(kernel, 8192, 256, 2, True)
+    assert (bq, bk) == fa._PREFERRED_TILE[kernel]
+    tile = fa._seq_tile(8192, bq, bk)
+    limit = fa._compiler_params(kernel, bq, bk, 256, 2, tile,
+                                8192).vmem_limit_bytes
+    assert took_mib * 2 ** 20 < limit < (took_mib + 2.5) * 2 ** 20
+    wide = 3 * (bq + bk) * (256 - 128) * 4
+    for d in (64, 128):
+        assert fa._vmem_bytes(kernel, bq, bk, d, 2, tile, 8192) == (
+            fa._vmem_bytes(kernel, bq, bk, 256, 2, tile, 8192) - wide
+            - {"fwd": 4 * tile * 256 + bq * (4 * 256 + 512),
+               "bwd": 2 * tile * 3 * 256 + bk * (8 * 256 + 1024)
+               + 8192 * 512}[kernel])
+
+
 @pytest.mark.parametrize("s, fits", [(65536, True), (131072, True),
                                      (262144, False)])
 def test_backward_names_a_sequence_its_accumulator_cannot_hold(s, fits):

@@ -667,3 +667,116 @@ def test_swiglu_layer_with_a_shared_expert_and_a_share():
             h @ params["up"][e - 1])
         want = want + weight[:, None] * (hidden @ params["down"][e - 1])
     _close(out, want, "output")
+
+
+# ---- Qwen3-Next's expert layer: softmax scores renormalised over a
+# token's chosen, a sigmoid gate on the shared expert
+
+def _qwen_layer(held, renormalise=True, shared_gate=True):
+    layer = MoEMlp(8, 12, 3, dtype=jnp.float32, shared_ff=10, held=held,
+                   renormalise=renormalise, shared_gate=shared_gate)
+    h = jax.random.normal(jax.random.key(0), (40, 16))
+    whole = MoEMlp(8, 12, 3, dtype=jnp.float32, shared_ff=10,
+                   renormalise=renormalise, shared_gate=shared_gate)
+    params = jax.tree.map(lambda w: w * 15.0, whole.init(
+        jax.random.key(1), h)["params"])
+    return layer, params, h
+
+
+_QWEN_LAYER = {"num_experts_per_tok": 3, "norm_topk_prob": True}
+
+
+def test_renormalised_softmax_router():
+    """``renormalise`` divides a token's chosen softmax weights by their
+    sum, over all it chose whether held here or not; None keeps each
+    score's habit (softmax: as they are; sigmoid: renormalised)."""
+    h = jax.random.normal(jax.random.key(0), (40, 16))
+    router = jax.random.normal(jax.random.key(1), (16, 8))
+    experts, plain, *_, probs = moe.moe_route(h, router, 3)
+    _, normed, *_ = moe.moe_route(h, router, 3, renormalise=True)
+    np.testing.assert_allclose(np.asarray(normed.sum(-1)), 1.0, rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(normed),
+        np.asarray(plain / plain.sum(-1, keepdims=True)), rtol=1e-6)
+    assert float(plain.sum(-1).min()) < 0.9
+    np.testing.assert_allclose(
+        np.asarray(plain), np.asarray(jnp.take_along_axis(probs, experts, 1)),
+        rtol=1e-6)
+    # a share's weights are the same numbers, sliced: their sum over the
+    # held is what the token gave the held experts, not 1
+    _, held, *_ = moe.moe_route(h, router, 3, renormalise=True, held=(2, 3))
+    full = jnp.sum(jnp.where(experts[..., None] == jnp.arange(8),
+                             normed[..., None], 0.0), axis=1)
+    np.testing.assert_allclose(np.asarray(held), np.asarray(full[:, 2:5]),
+                               rtol=1e-6)
+    _, sig, *_ = moe.moe_route(h, router, 3, score="sigmoid")
+    _, raw, *_ = moe.moe_route(h, router, 3, score="sigmoid",
+                               renormalise=False)
+    np.testing.assert_allclose(np.asarray(sig.sum(-1)), 1.0, rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(sig), np.asarray(raw / raw.sum(-1, keepdims=True)),
+        rtol=1e-6)
+
+
+@pytest.mark.parametrize("held", [None, (2, 4)], ids=["whole", "a-share"])
+def test_qwen_layer_matches_reference(held):
+    """Renormalised softmax weights, SwiGLU experts and the shared expert
+    behind its sigmoid gate against chipbench/reference/qwen3_next.py:
+    output and every gradient."""
+    from chipbench.reference import qwen3_next as qwen_reference
+
+    layer, params, h = _qwen_layer(held)
+    assert params["shared_expert_gate"].shape == (16, 1)
+    first, count = held or (0, 8)
+    mine = {**params, **{name: params[name][first:first + count]
+                         for name in ("gate", "up", "down")}}
+    config = {**_QWEN_LAYER, "experts_held_first": first}
+    out, aux = layer.apply({"params": mine}, h)
+    want, _ = qwen_reference.experts_layer(h, mine, config)
+    _close(out, want, "output")
+    cot = jax.random.normal(jax.random.key(5), h.shape)
+    got = jax.grad(lambda p, h: jnp.sum(
+        layer.apply({"params": p}, h)[0] * cot), argnums=(0, 1))(mine, h)
+    ref = jax.grad(lambda p, h: jnp.sum(
+        qwen_reference.experts_layer(h, p, config)[0] * cot),
+        argnums=(0, 1))(mine, h)
+    for name in got[0]:
+        _close(got[0][name], ref[0][name], f"d {name}")
+    _close(got[1], ref[1], "d input")
+
+
+@pytest.mark.parametrize("shares", [4, 2])
+def test_the_shares_of_a_qwen_layer_add_up(shares):
+    """The share test: the held experts' parts of the 4 (or 2) shares of a
+    layer of eight, with the shared expert and its gate, which every chip
+    computes alike, counted once, sum to the uncut reference layer; the
+    weights are renormalised over all a token chose, so the parts are the
+    whole layer's terms and not each share's own normalisation."""
+    from chipbench.reference import qwen3_next as qwen_reference
+
+    whole, params, h = _qwen_layer(None)
+    count = 8 // shares
+    shared = lambda h: jax.nn.sigmoid(h @ params["shared_expert_gate"]) * (
+        (jax.nn.silu(h @ params["shared_gate"]) * (h @ params["shared_up"]))
+        @ params["shared_down"])
+
+    def total(h):
+        parts = 0.0
+        for first in range(0, 8, count):
+            share, _, _ = _qwen_layer((first, count))
+            mine = {**params, **{name: params[name][first:first + count]
+                                 for name in ("gate", "up", "down")}}
+            parts = parts + share.apply({"params": mine}, h)[0] - shared(h)
+        return parts, parts + shared(h)
+
+    parts, got = total(h)
+    want, _ = qwen_reference.experts_layer(
+        h, params, {**_QWEN_LAYER, "experts_held_first": 0})
+    _close(got, want, "sum of the shares")
+    _close(got, whole.apply({"params": params}, h)[0],
+           "sum of the shares against every expert held")
+    assert float(jnp.linalg.norm(parts)) > 0.1 * float(jnp.linalg.norm(want))
+    cot = jax.random.normal(jax.random.key(7), h.shape)
+    _close(jax.grad(lambda h: jnp.sum(total(h)[1] * cot))(h),
+           jax.grad(lambda h: jnp.sum(whole.apply(
+               {"params": params}, h)[0] * cot))(h), "d input")
