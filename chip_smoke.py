@@ -27,8 +27,9 @@ do they stream more than one sequence tile a grid step on a chip, and the
 cell compares its gradients at 2048 positions), ``flash256`` (the same
 kernels at a head of 256, 16 query heads on 2 key-value heads, the attention
 of the cell ``qwen3next-s8192``), ``gdn8192`` (the chunked gated delta rule
-of ``models/gdn.py`` at that cell's shape against the float32 recurrence,
-and the time a forward and backward takes by chunk length), ``eager`` (the
+of ``models/gdn.py`` at that cell's shape, the Pallas kernels and the plain
+``jax.numpy`` path side by side, each against the float32 recurrence and
+timed, forward alone and forward and backward), ``eager`` (the
 immediate path); ``--phases`` names the ones to run. Four
 chips: ``device``, ``ring4`` (``parallel/sequence.py``, ``sp`` = 4),
 ``dryrun4`` (the GSPMD dp x sp x tp step against one device).
@@ -339,13 +340,15 @@ def phase_flash256(shape=(2, 8192, 16, 2, 256)):
 
 # -------------------------------------------------------------------- gdn8192
 
-def phase_gdn8192(shape=(2, 8192, 16, 32, 128, 128), chunks=(64, 128, 256)):
-    """The chunked gated delta rule (``models/gdn.py``) at the cell
-    ``qwen3next-s8192``'s shape, (batch, seq, key heads, value heads, d_k,
-    d_v) in bf16, output and gradients against the float32 recurrence
-    taken one position after another (the benchmark's reference's,
-    ``chipbench/reference/qwen3_next.py``), and the seconds one forward and
-    backward takes at each of ``chunks`` (host clock around
+def phase_gdn8192(shape=(2, 8192, 16, 32, 128, 128), chunk=128):
+    """The chunked gated delta rule at the cell ``qwen3next-s8192``'s
+    shape, (batch, seq, key heads, value heads, d_k, d_v) in bf16, by its
+    two paths side by side: the Pallas kernels of
+    ``ops/gated_delta_rule.py`` and the plain ``jax.numpy`` body of
+    ``models/gdn.py``. Each: output and gradients against the float32
+    recurrence taken one position after another (the benchmark's
+    reference's, ``chipbench/reference/qwen3_next.py``), and the seconds a
+    forward alone and a forward and backward take (host clock around
     ``block_until_ready``, the mean of five calls after one)."""
     import jax
     import jax.numpy as jnp
@@ -353,6 +356,7 @@ def phase_gdn8192(shape=(2, 8192, 16, 32, 128, 128), chunks=(64, 128, 256)):
 
     from chipbench.reference import qwen3_next as reference
     from horovod_tpu.models import gdn
+    from horovod_tpu.ops import gated_delta_rule as kernels
 
     b, s, h_k, h_v, d_k, d_v = shape
     rng = np.random.RandomState(0)
@@ -372,28 +376,37 @@ def phase_gdn8192(shape=(2, 8192, 16, 32, 128, 128), chunks=(64, 128, 256)):
         return jax.jit(lambda *a: (lambda o, vjp: (o, *vjp(do)))(
             *jax.vjp(rule, *a)))
 
+    def seconds(call):
+        jax.block_until_ready(call(q, k, v, g, beta))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            jax.block_until_ready(call(q, k, v, g, beta))
+        return (time.perf_counter() - t0) / 5
+
     f32 = lambda t: t.astype(jnp.float32)
     with jax.default_matmul_precision("highest"):
         want = with_gradients(lambda *a: jax.lax.map(
             lambda one: recurrence(*one), a), f32(do))(
                 f32(q), f32(k), f32(v), g, beta)
-    out = {"shape": list(shape)}
-    for chunk in chunks:
-        step = with_gradients(lambda *a: gdn.gated_delta_rule(
-            *a, chunk=chunk), do)
+    out = {"shape": list(shape), "chunk": chunk,
+           "kernels_compiled": jax.default_backend() != "cpu"}
+    paths = {"kernels": functools.partial(kernels.gated_delta_rule,
+                                          chunk=chunk),
+             "plain": functools.partial(gdn.gated_delta_rule_plain,
+                                        chunk=chunk)}
+    for name, rule in paths.items():
+        step = with_gradients(rule, do)
         got = jax.block_until_ready(step(q, k, v, g, beta))
-        t0 = time.perf_counter()
-        for _ in range(5):
-            jax.block_until_ready(step(q, k, v, g, beta))
-        seconds = (time.perf_counter() - t0) / 5
-        errs = {name: rel_l2(one, w) for name, one, w in
+        errs = {what: rel_l2(one, w) for what, one, w in
                 zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want)}
         check(all(np.isfinite(list(errs.values())))
               and max(errs.values()) <= BF16_REL_L2,
-              f"the chunked rule at chunk {chunk} differs from the float32 "
-              f"recurrence: {errs} (relative L2), bound {BF16_REL_L2}")
-        out[f"chunk_{chunk}"] = {"ms_forward_and_backward": 1e3 * seconds,
-                                 "rel_l2": errs}
+              f"the chunked rule by its {name} path differs from the "
+              f"float32 recurrence: {errs} (relative L2), bound "
+              f"{BF16_REL_L2}")
+        out[name] = {"ms_forward": 1e3 * seconds(jax.jit(rule)),
+                     "ms_forward_and_backward": 1e3 * seconds(step),
+                     "rel_l2": errs}
     return out
 
 

@@ -24,10 +24,16 @@ device trace can be split by them:
 
    Computed **chunked** (``gated_delta_rule`` below): a chunk's
    corrections from a unit lower-triangular system, inverted by blocks,
-   batched products, and a carry over the chunks. The decays, their
+   products inside the chunks, and a carry over them. The decays, their
    cumulative sums, the inverse and the carried state are float32
    (``STATE_DTYPE``), the products run in the layer's ``dtype`` and
-   accumulate in float32. The backward pass is ``jax.grad`` of that.
+   accumulate in float32. Two programs of the same equations, chosen from
+   what can be observed (``kernels_serve``; no option): on a TPU, at the
+   chunk of 128 and heads that are multiples of 128, the Pallas kernels of
+   ``ops/gated_delta_rule.py`` (the state in VMEM, the chunks walked
+   inside the kernels, a backward kernel of their own under a custom
+   VJP); everywhere else ``gated_delta_rule_plain``, plain ``jax.numpy``
+   whose backward pass is ``jax.grad`` of it, and the kernels' reference.
 4. ``gdn_gate_norm``: ``RMSNorm(o) w * silu(z)`` a head: the norm over a
    head's ``d_v`` channels **before** the gate, ``w [d_v]`` shared by the
    heads (Mamba-2's ``ssm.gated_group_norm`` gates first: another
@@ -49,6 +55,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models import ssm
+from horovod_tpu.ops import gated_delta_rule as rule_kernels
 
 # What the decays, their cumulative sums, the triangular inverse and the
 # carried state are computed in, whatever the products run in. A module
@@ -56,13 +63,15 @@ from horovod_tpu.models import ssm
 # outside.
 STATE_DTYPE = jnp.float32
 # The chunk the rule takes where the caller names none: the longest the
-# sequence allows up to this. The inverse costs by the chunk's square a
-# position and the carry by the number of chunks. On a v5e at 2 x 8192, 32
-# value heads of 128 x 128 on 16 key heads, bf16, a forward and backward
-# took 50.4 ms at 64 (the source's kernel's), 49.8 at 128 and 87.1 at 256
-# (chip_smoke.py's gdn8192; PERF.md section 6, PR 33); at 128 the states
-# the carry keeps for its backward pass are half as many as at 64 and the
-# qwen3-next-80b step is 13.98 GiB where at 64 it is 14.53.
+# sequence allows up to this, and the one chunk the Pallas kernels serve
+# (a chunk's [c, c] matrices are then whole 128 x 128 tiles). The inverse
+# costs by the chunk's square a position and the carry by the number of
+# chunks. On a v5e at 2 x 8192, 32 value heads of 128 x 128 on 16 key
+# heads, bf16, a forward and backward of the plain path took 50.4 ms at 64
+# (the source's kernel's), 49.8 at 128 and 87.1 at 256 (PERF.md section 6,
+# PR 33); at 128 the states kept for the backward pass are half as many as
+# at 64. The kernels at 128: chip_smoke.py's gdn8192 and PERF.md section
+# 6, PR 34.
 CHUNK = 128
 # The precision of the float32 products that invert a chunk's system.
 INVERSE_PRECISION = jax.lax.Precision.HIGHEST
@@ -145,8 +154,35 @@ def _unit_lower_inverse_bwd(inverse, g):
 unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
+def kernels_serve(chunk: int, key_dim: int, value_dim: int) -> bool:
+    """Whether the rule goes to the Pallas kernels of
+    ``ops/gated_delta_rule.py``, from what can be observed (static
+    trace-time facts, so the choice compiles away): a TPU backend
+    (elsewhere the kernels are interpreted, far slower than
+    ``jax.numpy``), the chunk they were measured at, and heads that fill
+    whole 128-lane tiles. Everything else stays on
+    ``gated_delta_rule_plain``, so the choice never raises for a shape
+    that serves."""
+    return (jax.default_backend() == "tpu" and chunk == CHUNK
+            and key_dim % 128 == 0 and value_dim % 128 == 0)
+
+
 def gated_delta_rule(q, k, v, g, beta, *, chunk: Optional[int] = None):
-    """The gated delta rule, chunked.
+    """The gated delta rule, chunked: ``gated_delta_rule_plain``'s
+    arguments and result, by the Pallas kernels where ``kernels_serve``
+    says so and by ``gated_delta_rule_plain`` itself everywhere else."""
+    c = chunk_for(q.shape[1], chunk)
+    if kernels_serve(c, q.shape[-1], v.shape[-1]):
+        return rule_kernels.gated_delta_rule(
+            q, k, v, g, beta, chunk=c, state_dtype=STATE_DTYPE,
+            precision=INVERSE_PRECISION)
+    return gated_delta_rule_plain(q, k, v, g, beta, chunk=chunk)
+
+
+def gated_delta_rule_plain(q, k, v, g, beta, *,
+                           chunk: Optional[int] = None):
+    """The gated delta rule, chunked, in plain ``jax.numpy``: the path of
+    every backend and shape the kernels do not serve, and their reference.
 
     ``q``, ``k`` ``[batch, s, H_k, d_k]`` (normalised and scaled by the
     caller), ``v [batch, s, H_v, d_v]``, ``g`` and ``beta`` ``[batch, s,

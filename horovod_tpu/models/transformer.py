@@ -446,13 +446,19 @@ class GPT(nn.Module):
             # everything recomputed but what a held expert layer's rounds
             # summed to (``moe.HELD_SUM``: [T, width] float32 a layer): its
             # backward pass makes each round again for its pullback, so
-            # the recomputed block would only run the rounds a third time
+            # the recomputed block would only run the rounds a third time;
+            # and the inverses of a Gated DeltaNet's chunks where the rule
+            # runs as kernels (``KEPT_INVERSE``: [c, c] bf16 a chunk and
+            # value head): two thirds of the rule's forward, which the
+            # recomputed block then leaves out. Neither name is in any
+            # other model's program
             from horovod_tpu.models.moe import HELD_SUM
+            from horovod_tpu.ops.gated_delta_rule import KEPT_INVERSE
 
             block = nn.remat(
                 block, static_argnums=(),
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    HELD_SUM))
+                    HELD_SUM, KEPT_INVERSE))
         aux = {}
         for i in range(cfg.n_layers):
             kind = () if cfg.layer_pattern is None else (

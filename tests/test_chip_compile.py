@@ -18,8 +18,9 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from horovod_tpu.models import moe
+from horovod_tpu.models import gdn, moe
 from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.ops import gated_delta_rule as gdr
 from horovod_tpu.parallel.sequence import ring_attention
 
 
@@ -46,6 +47,7 @@ def compiled_kernel(monkeypatch):
 
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(moe, "_interpret", lambda: False)
+    monkeypatch.setattr(gdr, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -264,3 +266,93 @@ def test_held_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices):
             shape = re.search(r"= \w+\[([\d,]*)\]", line).group(1)
             assert shape in (f"{tokens},1024", f"{tokens},8") or (
                 "," not in shape and int(shape) <= 2 * 8 + tiles), line
+
+
+@pytest.mark.parametrize("shape", [
+    pytest.param((2, 8192, 16, 32, 128, 128), id="gdn-qwen3next-s8192"),
+    pytest.param((1, 1000, 4, 4, 128, 256), id="gdn-a-head-a-key-head-padded"),
+])
+def test_gated_delta_rule_kernels_compile_for_v5e(shape, compiled_kernel,
+                                                  v5e_devices):
+    """The three kernels of ``ops/gated_delta_rule.py`` at the benchmark
+    cell qwen3next-s8192's own sizes (2 x 8192 positions, 32 value heads
+    of 128 x 128 on 16 key heads, bf16, chunks of 128), forward and
+    backward, and at a value head a key head with a wider value head and a
+    length the chunk does not divide: both compile, in the default VMEM
+    scope, and their temporaries are what the forward keeps for the
+    backward (the states and the bf16 inverses, 403 MB at the cell's
+    sizes) and the gradients on their way out, where the plain path keeps
+    a dozen ``[c, c]`` float32 arrays of 268 MB."""
+    b, s, h_k, h_v, d_k, d_v = shape
+    one = SingleDeviceSharding(v5e_devices[0])
+    like = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one)
+    q, v = like(b, s, h_k, d_k), like(b, s, h_v, d_v)
+    g = like(b, s, h_v, dtype=jnp.float32)
+
+    def loss(*a):
+        return jnp.mean(gdr.gated_delta_rule(*a, chunk=128).astype(
+            jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        q, q, v, g, g).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert all(f"hvt_gdn_{kernel}" in text
+               for kernel in ("inverse", "fwd", "bwd"))
+    assert "vmem_limit_bytes" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_gdn_layers_share_one_lowered_kernel_under_their_scope(
+        compiled_kernel, v5e_devices, monkeypatch):
+    """Which program gets the kernels is decided from the backend, which
+    is the CPU here, so the test steers it: a three-layer ``models.GPT``
+    of Gated DeltaNet mixers at heads of 128 then holds four
+    ``tpu_custom_call`` sites however many layers it has (the inverses',
+    the forward's, the recomputed forward's and the backward's; the
+    inverses are kept under ``remat`` and not made again), three kernels by
+    name, and, compiled, each of the twelve calls' ``op_name`` has the scope
+    ``gdn_rule`` in it (what ``chipbench/layer_metrics/gdn_rule_ms.py`` and
+    ``gdn_ms.py`` match) and the pass it belongs to (what
+    ``chipbench/regions.py`` splits the step by)."""
+    from horovod_tpu.models import GPT, GPTConfig
+
+    monkeypatch.setattr(gdn, "kernels_serve", lambda *shape: True)
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def step(pattern):
+        model = GPT(GPTConfig(
+            vocab_size=512, n_layers=len(pattern), layer_pattern=pattern,
+            d_model=128, n_heads=2, d_ff=256, max_seq_len=256, remat=True,
+            use_flash=False, gdn_key_heads=1, gdn_value_heads=2,
+            gdn_key_dim=128, gdn_value_dim=128))
+        tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32,
+                                      sharding=one_chip)
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(model.init, jax.random.key(0), tokens))
+        loss = lambda p, t: model.apply(p, t).astype(jnp.float32).mean()
+        return jax.jit(jax.grad(loss)).lower(params, tokens)
+
+    one, three = step("G"), step("GGG")
+    sites = lambda lowered: lowered.as_text().count(
+        "stablehlo.custom_call @tpu_custom_call")
+    assert sites(one) == sites(three) == 4, (sites(one), sites(three))
+    assert set(re.findall(r"hvt_gdn_\w+", three.as_text())) == {
+        "hvt_gdn_inverse", "hvt_gdn_fwd", "hvt_gdn_bwd"}
+    # inlined, each call keeps its call site's whole op_name
+    names = re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*?op_name="([^"]*)"',
+        three.compile().as_text())
+    assert len(names) == 12 and all("/gdn_rule/" in n for n in names), names
+    kinds = lambda kernel, inside: [
+        n for n in names if f"/{kernel}/" in n and inside(n)]
+    assert len(kinds("hvt_gdn_fwd", lambda n: "transpose(" not in n)) == 3
+    assert len(kinds("hvt_gdn_inverse", lambda n: "transpose(" not in n)) == 3
+    assert len(kinds("hvt_gdn_inverse", lambda n: True)) == 3
+    assert len(kinds("hvt_gdn_fwd",
+                     lambda n: "rematted_computation" in n)) == 3
+    assert len(kinds("hvt_gdn_bwd", lambda n: "transpose(jvp(" in n
+                     and "rematted_computation" not in n)) == 3

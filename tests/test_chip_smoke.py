@@ -124,14 +124,19 @@ def test_named_phases_alone_run(monkeypatch):
 
 
 def test_chunked_rule_against_the_float32_recurrence():
-    # the gdn8192 phase's comparison at a size the CPU can afford, a chunk
-    # that divides the sequence and one that does not
-    out = chip_smoke.phase_gdn8192(shape=(1, 256, 2, 4, 16, 16),
-                                   chunks=(64, 100))
-    for chunk in ("chunk_64", "chunk_100"):
-        assert set(out[chunk]["rel_l2"]) == {"o", "dq", "dk", "dv", "dg",
-                                             "dbeta"}
-        assert max(out[chunk]["rel_l2"].values()) < chip_smoke.BF16_REL_L2
+    # the gdn8192 phase's comparison at a size the CPU can afford: the
+    # kernels (interpreted here) and the plain path side by side, at a
+    # chunk that divides the sequence and one that does not
+    for chunk in (64, 100):
+        out = chip_smoke.phase_gdn8192(shape=(1, 256, 2, 4, 16, 16),
+                                       chunk=chunk)
+        assert out["chunk"] == chunk and not out["kernels_compiled"]
+        for path in ("kernels", "plain"):
+            assert set(out[path]["rel_l2"]) == {"o", "dq", "dk", "dv", "dg",
+                                                "dbeta"}
+            assert max(out[path]["rel_l2"].values()) < chip_smoke.BF16_REL_L2
+            assert out[path]["ms_forward"] > 0
+            assert out[path]["ms_forward_and_backward"] > 0
 
 
 def test_flash_kernel_against_the_float32_formula():
