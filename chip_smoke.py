@@ -201,7 +201,8 @@ def phase_device(n_chips):
            "local_size": hvt.local_size(), "rank": hvt.rank(),
            "hbm_bytes_limit": devices[0].memory_stats()["bytes_limit"]}
     if n_chips > 1:
-        return out
+        # this start's split (docs/troubleshooting.md, "a slow start")
+        return {**out, "startup": hvt.startup_report()}
 
     # Does block_until_ready wait? Time one dependent matmul chain both
     # ways, interleaved: a scalar read back to the host cannot arrive
@@ -233,7 +234,7 @@ def phase_device(n_chips):
     peak = peaks(devices[0].device_kind)["bf16_flops_per_s"] / 1e12
     check(tflops <= peak, f"matmul chain at {tflops:.0f} TFLOP/s is above "
                           f"the chip's peak of {peak:.0f}")
-    return out
+    return {**out, "startup": hvt.startup_report()}
 
 
 # ----------------------------------------------------------------- flash8192

@@ -26,12 +26,19 @@ Public API mirrors ``horovod.tensorflow`` / ``horovod.torch``
     opt = hvt.DistributedOptimizer(optax.adam(1e-3))
 """
 
+# first and last statement of this file: the ``import`` phase of a job's
+# start (metrics/startup.py)
+from horovod_tpu.metrics import startup as _startup
+_import_span = _startup.span("import")
+_import_span.__enter__()
+
 from horovod_tpu.common.basics import (
     init,
     shutdown,
     is_initialized,
     start_timeline,
     stop_timeline,
+    startup_report,
     diagnostics,
     rank,
     size,
@@ -111,9 +118,9 @@ __version__ = "0.1.0"
 
 def __getattr__(name):
     # lazy submodules: checkpoint pulls in orbax, runner pulls launcher
-    # machinery, metrics is only needed by jobs that scrape it — none
-    # belongs in the base import path
-    if name in ("checkpoint", "runner", "metrics"):
+    # machinery — neither belongs in the base import path (metrics is
+    # standard library only and is imported above, for the start's spans)
+    if name in ("checkpoint", "runner"):
         import importlib
 
         return importlib.import_module(f"horovod_tpu.{name}")
@@ -122,7 +129,7 @@ def __getattr__(name):
 __all__ = [
     # lifecycle
     "init", "shutdown", "is_initialized", "start_timeline", "stop_timeline",
-    "diagnostics",
+    "startup_report", "diagnostics",
     # topology
     "rank", "size", "local_rank", "local_size", "cross_rank", "cross_size",
     "process_rank", "process_size", "is_homogeneous",
@@ -148,9 +155,12 @@ __all__ = [
     "PartialDistributedGradientTransformation",
     # elastic
     "elastic",
-    # telemetry (lazy submodule)
+    # telemetry
     "metrics",
     # exceptions
     "HorovodInternalError", "HorovodTimeoutError",
     "HostsUpdatedInterrupt",
 ]
+
+_import_span.__exit__(None, None, None)
+del _import_span

@@ -71,112 +71,132 @@ def init(comm=None, process_sets=None):
     with _lock:
         if _initialized:
             return
-        jax = _jax()
+        from horovod_tpu.metrics import startup as _startup
 
-        if os.environ.get("HVT_FROM_MPI"):
-            # mpirun/jsrun placed us: derive slot identity from the MPI
-            # launcher's env (OMPI_COMM_WORLD_RANK etc.)
-            from horovod_tpu.runner.mpi_run import env_from_mpi
+        with _startup.span("init"):
+            _init_locked(process_sets)
+            # last, so that no stage span lies inside ``init``: the
+            # phases and JAX's stages tile a start without overlap
+            _startup.listen()
+        _initialized = True
 
-            os.environ.update(env_from_mpi())
 
-        coordinator = os.environ.get("HVT_COORDINATOR_ADDR")
-        nprocs = os.environ.get("HVT_NUM_PROCESSES")
-        procid = os.environ.get("HVT_PROCESS_ID")
-        if coordinator and nprocs and int(nprocs) > 1:
-            global _started_jax_distributed
+def _init_locked(process_sets):
+    from horovod_tpu.metrics.startup import span
+
+    jax = _jax()
+
+    if os.environ.get("HVT_FROM_MPI"):
+        # mpirun/jsrun placed us: derive slot identity from the MPI
+        # launcher's env (OMPI_COMM_WORLD_RANK etc.)
+        from horovod_tpu.runner.mpi_run import env_from_mpi
+
+        os.environ.update(env_from_mpi())
+
+    coordinator = os.environ.get("HVT_COORDINATOR_ADDR")
+    nprocs = os.environ.get("HVT_NUM_PROCESSES")
+    procid = os.environ.get("HVT_PROCESS_ID")
+    if coordinator and nprocs and int(nprocs) > 1:
+        global _started_jax_distributed
+        with span("distributed_join"):
             jax.distributed.initialize(
                 coordinator_address=coordinator,
                 num_processes=int(nprocs),
                 process_id=int(procid) if procid is not None else None,
             )
-            _started_jax_distributed = True
+        _started_jax_distributed = True
 
-        # CPU engine mode (hvtrun -np N for the eager/torch path): bring up
-        # the C++ core — control star + TCP data mesh + background thread
-        # (the analog of the reference's InitializeHorovodOnce spawning
-        # BackgroundThreadLoop, operations.cc:649,688).
-        master = os.environ.get("HVT_MASTER_ADDR")
-        if master and nprocs and int(nprocs) > 1:
-            from horovod_tpu.engine import native as _native
+    # CPU engine mode (hvtrun -np N for the eager/torch path): bring up
+    # the C++ core — control star + TCP data mesh + background thread
+    # (the analog of the reference's InitializeHorovodOnce spawning
+    # BackgroundThreadLoop, operations.cc:649,688).
+    master = os.environ.get("HVT_MASTER_ADDR")
+    if master and nprocs and int(nprocs) > 1:
+        from horovod_tpu.engine import native as _native
 
-            if not _native.available():
-                raise RuntimeError(
-                    "hvtrun multi-process launch requires the C++ engine; "
-                    "build it with `make -C horovod_tpu/csrc`")
+        if not _native.available():
+            raise RuntimeError(
+                "hvtrun multi-process launch requires the C++ engine; "
+                "build it with `make -C horovod_tpu/csrc`")
+        with span("engine"):
             _native.init_engine(
                 rank=int(procid or 0), size=int(nprocs),
                 master_addr=master,
                 master_port=int(os.environ.get("HVT_MASTER_PORT", "29510")),
                 cycle_ms=int(os.environ.get("HVT_CYCLE_TIME_MS", "2")))
 
-        # Telemetry endpoint (hvtrun --metrics-port → HVT_METRICS_PORT):
-        # every worker serves GET /metrics at base_port + process_rank so
-        # co-hosted workers never collide; port 0 binds ephemerally.
-        metrics_port = os.environ.get("HVT_METRICS_PORT")
-        if metrics_port is not None:
-            from horovod_tpu import metrics as _metrics
+    metrics_port = os.environ.get("HVT_METRICS_PORT")
+    shard_base = os.environ.get("HVT_TIMELINE_SHARD")
+    # HVT_DIAG_ADDR: the static launcher's KV server (--timeline);
+    # HVT_RENDEZVOUS_ADDR: the elastic rendezvous (same surface).
+    # The split exists because the latter is the "elastic launch"
+    # marker that elastic/run.py and preemption.py key off.
+    rdv_addr = (os.environ.get("HVT_DIAG_ADDR")
+                or os.environ.get("HVT_RENDEZVOUS_ADDR"))
+    if metrics_port is not None or shard_base:
+        with span("endpoints"):
+            _start_endpoints(metrics_port, shard_base, rdv_addr,
+                             int(procid or 0))
 
-            base = int(metrics_port)
-            offset = int(procid or 0) if base else 0
-            bound = _metrics.serve(base + offset)
-            if os.environ.get("HVT_VERBOSE"):
-                print(f"[hvt] metrics endpoint on :{bound}/metrics")
+    # Background /debugz reporter: periodically push this worker's
+    # diagnostics() snapshot to the rendezvous KV so the launcher's
+    # GET /debugz names stalled tensors without touching workers.
+    if rdv_addr:
+        global _debugz_stop
+        _debugz_stop = threading.Event()
+        threading.Thread(
+            target=_debugz_push_loop,
+            args=(rdv_addr, int(procid or 0), _debugz_stop),
+            daemon=True).start()
 
-        # Flight recorder (hvtrun --timeline → HVT_TIMELINE_SHARD): every
-        # worker records a per-rank chrome-trace shard, clock-aligned to
-        # the rendezvous server and uploaded there at teardown so the
-        # launcher can merge all ranks into one loadable trace.
-        shard_base = os.environ.get("HVT_TIMELINE_SHARD")
-        # HVT_DIAG_ADDR: the static launcher's KV server (--timeline);
-        # HVT_RENDEZVOUS_ADDR: the elastic rendezvous (same surface).
-        # The split exists because the latter is the "elastic launch"
-        # marker that elastic/run.py and preemption.py key off.
-        rdv_addr = (os.environ.get("HVT_DIAG_ADDR")
-                    or os.environ.get("HVT_RENDEZVOUS_ADDR"))
-        if shard_base:
-            from horovod_tpu.utils import timeline as _tl
+    # Materialize the device list once; this is the global communicator.
+    from horovod_tpu.parallel import mesh as _mesh
 
-            my_rank = int(procid or 0)
-            if rdv_addr:
-                try:
-                    _tl.set_clock_offset_us(
-                        _tl.measure_clock_offset_us(rdv_addr))
-                except Exception:
-                    pass  # unaligned shards still merge, just skewed
-            # xla_profiler off: every gang member arming a PJRT session
-            # would fight over the one-session limit; opt back in with
-            # HVT_TIMELINE_XLA=1 via start_timeline on the rank you want
-            _tl.start(f"{shard_base}.rank{my_rank}",
-                      mark_cycles=os.environ.get(
-                          "HVT_TIMELINE_MARK_CYCLES", "0") != "0",
-                      xla_profiler=False, pid=my_rank,
-                      upload_addr=rdv_addr)
+    _mesh.build_global_mesh()
 
-        # Background /debugz reporter: periodically push this worker's
-        # diagnostics() snapshot to the rendezvous KV so the launcher's
-        # GET /debugz names stalled tensors without touching workers.
-        if rdv_addr:
-            global _debugz_stop
-            _debugz_stop = threading.Event()
-            threading.Thread(
-                target=_debugz_push_loop,
-                args=(rdv_addr, int(procid or 0), _debugz_stop),
-                daemon=True).start()
+    from horovod_tpu.common import process_sets as _ps
 
-        # Materialize the device list once; this is the global communicator.
-        from horovod_tpu.parallel import mesh as _mesh
-
-        _mesh.build_global_mesh()
-
-        from horovod_tpu.common import process_sets as _ps
-
+    with span("process_sets"):
         _ps._init_global_process_set()
         if process_sets:
             for ps in process_sets:
                 _ps.add_process_set(ps)
 
-        _initialized = True
+
+def _start_endpoints(metrics_port, shard_base, rdv_addr, my_rank):
+    # Telemetry endpoint (hvtrun --metrics-port → HVT_METRICS_PORT):
+    # every worker serves GET /metrics at base_port + process_rank so
+    # co-hosted workers never collide; port 0 binds ephemerally.
+    if metrics_port is not None:
+        from horovod_tpu import metrics as _metrics
+
+        base = int(metrics_port)
+        offset = my_rank if base else 0
+        bound = _metrics.serve(base + offset)
+        if os.environ.get("HVT_VERBOSE"):
+            print(f"[hvt] metrics endpoint on :{bound}/metrics")
+
+    # Flight recorder (hvtrun --timeline → HVT_TIMELINE_SHARD): every
+    # worker records a per-rank chrome-trace shard, clock-aligned to
+    # the rendezvous server and uploaded there at teardown so the
+    # launcher can merge all ranks into one loadable trace.
+    if shard_base:
+        from horovod_tpu.utils import timeline as _tl
+
+        if rdv_addr:
+            try:
+                _tl.set_clock_offset_us(
+                    _tl.measure_clock_offset_us(rdv_addr))
+            except Exception:
+                pass  # unaligned shards still merge, just skewed
+        # xla_profiler off: every gang member arming a PJRT session
+        # would fight over the one-session limit; opt back in with
+        # HVT_TIMELINE_XLA=1 via start_timeline on the rank you want
+        _tl.start(f"{shard_base}.rank{my_rank}",
+                  mark_cycles=os.environ.get(
+                      "HVT_TIMELINE_MARK_CYCLES", "0") != "0",
+                  xla_profiler=False, pid=my_rank,
+                  upload_addr=rdv_addr)
 
 
 def shutdown():
@@ -218,7 +238,11 @@ def shutdown():
 
         _ps._reset()
         from horovod_tpu import metrics as _metrics
+        from horovod_tpu.metrics import startup as _startup
 
+        _startup.stop_listening()
+        if os.environ.get("HVT_VERBOSE"):
+            print(_startup.format_report(_startup.report()), flush=True)
         _metrics.stop_server()
         _initialized = False
 
@@ -722,6 +746,20 @@ def stop_timeline():
     from horovod_tpu.utils import timeline as _tl
 
     _tl.stop()
+
+
+def startup_report(until=None) -> dict:
+    """What this process's start was made of: the phases of the import
+    and of ``hvt.init()``, the seconds JAX spent tracing, lowering,
+    compiling and reading its cache (nested spans counted once), the
+    cache's hits and misses, and the ten functions that took most to
+    trace and lower with how often each was traced, lowered and compiled
+    (``metrics/startup.py:report``; docs/troubleshooting.md, "a slow
+    start"). ``until``: seconds since the epoch, to leave out what ended
+    later. Works before ``init()`` and after ``shutdown()``."""
+    from horovod_tpu.metrics import startup as _startup
+
+    return _startup.report(until)
 
 
 def diagnostics() -> dict:

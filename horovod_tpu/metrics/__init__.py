@@ -62,7 +62,9 @@ def registry() -> MetricRegistry:
     """The process-wide default registry. Created on first use with the
     engine stats collector installed, so every scrape/snapshot carries
     fresh ``hvt_engine_*`` counters (zeros when the engine is absent —
-    the series must exist either way so dashboards don't go blank)."""
+    the series must exist either way so dashboards don't go blank), and
+    with the collector of this process's start (``metrics/startup.py``:
+    ``hvt_startup_seconds``, ``hvt_jax_stage_*``)."""
     global _registry
     with _lock:
         if _registry is None:
@@ -74,7 +76,13 @@ def registry() -> MetricRegistry:
 
                 basics.poll_engine_stats(_registry)
 
+            def _startup_collector():
+                from horovod_tpu.metrics import startup
+
+                startup.collect(_registry)
+
             _registry.register_collector(_engine_collector)
+            _registry.register_collector(_startup_collector)
         return _registry
 
 

@@ -44,8 +44,13 @@ def _jax():
 def build_global_mesh():
     """(Re)build the global 1-D mesh over all chips. Called from hvt.init()."""
     global _global_mesh, _hier_mesh
+    from horovod_tpu.metrics import startup
+
     jax = _jax()
-    devices = np.asarray(jax.devices())
+    # a process's first jax.devices() brings the runtime up: on a TPU most
+    # of what a start costs before the first program
+    with startup.span("devices"):
+        devices = np.asarray(jax.devices())
     _global_mesh = jax.sharding.Mesh(devices, axis_names=(WORLD_AXIS,))
     _hier_mesh = None
     return _global_mesh
