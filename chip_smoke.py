@@ -29,7 +29,10 @@ kernels at a head of 256, 16 query heads on 2 key-value heads, the attention
 of the cell ``qwen3next-s8192``), ``gdn8192`` (the chunked gated delta rule
 of ``models/gdn.py`` at that cell's shape, the Pallas kernels and the plain
 ``jax.numpy`` path side by side, each against the float32 recurrence and
-timed, forward alone and forward and backward), ``eager`` (the
+timed, forward alone and forward and backward), ``conv8192`` (the causal
+depthwise convolution in front of that rule and of Mamba-2's scan, at the
+two cells' widths, the Pallas kernels of ``ops/causal_conv.py`` and the
+plain body side by side, forward and the three gradients), ``eager`` (the
 immediate path); ``--phases`` names the ones to run. Four
 chips: ``device``, ``ring4`` (``parallel/sequence.py``, ``sp`` = 4),
 ``dryrun4`` (the GSPMD dp x sp x tp step against one device).
@@ -411,6 +414,70 @@ def phase_gdn8192(shape=(2, 8192, 16, 32, 128, 128), chunk=128):
     return out
 
 
+# ------------------------------------------------------------------- conv8192
+
+def phase_conv8192(shapes=((2, 8192, 8192, False), (2, 8192, 1280, True)),
+                   taps=4):
+    """The causal depthwise convolution and its ``silu`` at the two cells'
+    shapes, (batch, seq, channels, bias) in bf16: ``qwen3next-s8192``'s
+    without a bias and ``nemotron3s-s8192``'s with one, by the Pallas
+    kernels of ``ops/causal_conv.py`` and the plain ``jax.numpy`` body of
+    ``models/ssm.py`` side by side. Each: output and the gradients of
+    ``x``, ``weight`` and ``bias`` against the plain body on float32
+    operands (the same bf16 numbers, so what differs is where each path
+    rounds), and the milliseconds a forward alone and a forward and
+    backward take (host clock around ``block_until_ready``, the mean of
+    five calls after one; ``benchmarks/causal_conv.py`` reads the device's
+    own)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.models import ssm
+    from horovod_tpu.ops import causal_conv as kernels
+
+    def with_gradients(conv, g):
+        return jax.jit(lambda *a: (lambda o, vjp: (o, *vjp(g)))(
+            *jax.vjp(conv, *a)))
+
+    out = {"taps": taps, "kernels_compiled": jax.default_backend() != "cpu"}
+    for b, s, c, with_bias in shapes:
+        rng = np.random.RandomState(c)
+        x, g = (jnp.asarray(rng.normal(size=(b, s, c)), jnp.bfloat16)
+                for _ in range(2))
+        weight = jnp.asarray(rng.uniform(-0.5, 0.5, (taps, c)), jnp.float32)
+        bias = (jnp.asarray(rng.normal(size=(c,)), jnp.float32)
+                if with_bias else None)
+
+        def seconds(call):
+            jax.block_until_ready(call(x, weight, bias))
+            t0 = time.perf_counter()
+            for _ in range(5):
+                jax.block_until_ready(call(x, weight, bias))
+            return (time.perf_counter() - t0) / 5
+
+        f32 = lambda t: t.astype(jnp.float32)
+        want = with_gradients(ssm.causal_conv_plain, f32(g))(
+            f32(x), weight, bias)
+        here = out[f"{b}x{s}x{c}"] = {"bias": with_bias}
+        for name, conv in (("kernels", kernels.causal_conv),
+                           ("plain", ssm.causal_conv_plain)):
+            step = with_gradients(conv, g)
+            got = jax.block_until_ready(step(x, weight, bias))
+            errs = {what: rel_l2(one, w) for what, one, w in
+                    zip(("o", "dx", "dweight", "dbias"), got, want)
+                    if w is not None}
+            check(all(np.isfinite(list(errs.values())))
+                  and max(errs.values()) <= BF16_REL_L2,
+                  f"the convolution at {b} x {s} x {c} by its {name} path "
+                  f"differs from the plain body in float32: {errs} "
+                  f"(relative L2), bound {BF16_REL_L2}")
+            here[name] = {"ms_forward": 1e3 * seconds(jax.jit(conv)),
+                          "ms_forward_and_backward": 1e3 * seconds(step),
+                          "rel_l2": errs}
+    return out
+
+
 # --------------------------------------------------------------------- eager
 
 def phase_eager():
@@ -547,6 +614,7 @@ def main(argv=None):
         for name, phase in (("flash8192", phase_flash8192),
                             ("flash256", phase_flash256),
                             ("gdn8192", phase_gdn8192),
+                            ("conv8192", phase_conv8192),
                             ("eager", phase_eager)):
             if not only or name in only:
                 run_phase(name, phase, meter)

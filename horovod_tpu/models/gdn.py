@@ -12,7 +12,9 @@ device trace can be split by them:
 1. ``gdn_in_proj``: ``[q | k | v | z] = u W_qkvz`` (widths ``K``, ``K``,
    ``V``, ``V``) and ``[b | a] = u W_ba`` (``H_v`` each); no bias.
 2. ``gdn_conv``: ``[q | k | v] <- silu(conv([q | k | v]))``, the causal
-   depthwise convolution of ``conv`` taps, no bias (``ssm.causal_conv``).
+   depthwise convolution of ``conv`` taps, no bias (``ssm.causal_conv``:
+   on a TPU the Pallas kernels of ``ops/causal_conv.py``, which read and
+   write the layer's ``dtype`` once and keep float32 in VMEM).
 3. ``gdn_rule``: ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a +
    dt_bias)`` a value head (``g <= 0``), both float32; ``q`` and ``k``
    L2-normalised a head (``x / sqrt(sum x^2 + 1e-6)``), ``q`` then times

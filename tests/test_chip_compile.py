@@ -18,7 +18,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from horovod_tpu.models import gdn, moe
+from horovod_tpu.models import gdn, moe, ssm
+from horovod_tpu.ops import causal_conv as conv
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops import gated_delta_rule as gdr
 from horovod_tpu.parallel.sequence import ring_attention
@@ -48,6 +49,7 @@ def compiled_kernel(monkeypatch):
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(moe, "_interpret", lambda: False)
     monkeypatch.setattr(gdr, "_interpret", lambda: False)
+    monkeypatch.setattr(conv, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -355,4 +357,99 @@ def test_gdn_layers_share_one_lowered_kernel_under_their_scope(
     assert len(kinds("hvt_gdn_fwd",
                      lambda n: "rematted_computation" in n)) == 3
     assert len(kinds("hvt_gdn_bwd", lambda n: "transpose(jvp(" in n
+                     and "rematted_computation" not in n)) == 3
+
+
+@pytest.mark.parametrize("shape", [
+    pytest.param((2, 8192, 8192, False), id="conv-qwen3next-s8192"),
+    pytest.param((2, 8192, 1280, True), id="conv-nemotron3s-s8192"),
+    pytest.param((1, 2048, 1280, True), id="conv-nemotron3s-probe"),
+    pytest.param((3, 384, 640, False), id="conv-a-block-of-384-by-640"),
+])
+def test_causal_conv_kernels_compile_for_v5e(shape, compiled_kernel,
+                                             v5e_devices):
+    """The two kernels of ``ops/causal_conv.py`` at the benchmark cells'
+    own sizes (2 x 8192 positions of qwen3next-s8192's 8192 channels
+    without a bias and of nemotron3s-s8192's 1280 with one, bf16, four
+    taps), at the probes' 2048 positions and at a block that is no power
+    of two, forward and backward: both compile with the blocks they derive,
+    in the default VMEM scope, and the program's temporaries are smaller
+    than one float32 array of the operand's size (the plain body pads one
+    and keeps more)."""
+    b, s, c, with_bias = shape
+    one = SingleDeviceSharding(v5e_devices[0])
+    like = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one)
+    x, weight = like(b, s, c, dtype=jnp.bfloat16), like(4, c)
+    bias = like(c) if with_bias else None
+
+    def loss(*a):
+        return jnp.mean(conv.causal_conv(*a).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2)[:2 + with_bias])
+                       ).lower(x, weight, bias).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert all(f"hvt_causal_conv_{kernel}" in text
+               for kernel in ("fwd", "bwd"))
+    assert "vmem_limit_bytes" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * b * s * c
+
+
+@pytest.mark.parametrize("pattern,scope,channels", [
+    pytest.param("GGG", "gdn_conv", 512, id="gated-delta-net"),
+    pytest.param("MMM", "ssm_conv", 256, id="mamba-2"),
+])
+def test_mixers_share_one_lowered_conv_kernel_under_their_scope(
+        pattern, scope, channels, compiled_kernel, v5e_devices, monkeypatch):
+    """Which program gets the convolution's kernels is decided from the
+    backend, which is the CPU here, so the test steers it: a three-layer
+    ``models.GPT`` of either mixer then holds three ``tpu_custom_call``
+    sites however many layers it has (the forward's, the recomputed
+    forward's and the backward's), two kernels by name, and, compiled, each
+    of the nine calls' ``op_name`` has the mixer's convolution scope in it
+    (``gdn_conv``: what ``chipbench/layer_metrics/gdn_ms.py`` matches;
+    ``ssm_conv``: ``ssm_ms.py``) and the pass it belongs to (what
+    ``chipbench/regions.py`` splits the step by)."""
+    from horovod_tpu.models import GPT, GPTConfig
+
+    monkeypatch.setattr(ssm, "conv_kernels_serve", lambda *shape: True)
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def step(pattern):
+        model = GPT(GPTConfig(
+            vocab_size=512, n_layers=len(pattern), layer_pattern=pattern,
+            d_model=128, n_heads=2, d_ff=256, max_seq_len=256, remat=True,
+            use_flash=False, gdn_key_heads=1, gdn_value_heads=2,
+            gdn_key_dim=128, gdn_value_dim=128, ssm_heads=2, ssm_head_dim=64,
+            ssm_groups=1, ssm_state=64))
+        tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32,
+                                      sharding=one_chip)
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(model.init, jax.random.key(0), tokens))
+        assert params["params"]["block_0"][scope[:3]]["conv_kernel"].shape \
+            == (4, channels)
+        loss = lambda p, t: model.apply(p, t).astype(jnp.float32).mean()
+        return jax.jit(jax.grad(loss)).lower(params, tokens)
+
+    one, three = step(pattern[:1]), step(pattern)
+    sites = lambda lowered: lowered.as_text().count(
+        "stablehlo.custom_call @tpu_custom_call")
+    assert sites(one) == sites(three) == 3, (sites(one), sites(three))
+    assert set(re.findall(r"hvt_causal_conv_\w+", three.as_text())) == {
+        "hvt_causal_conv_fwd", "hvt_causal_conv_bwd"}
+    # inlined, each call keeps its call site's whole op_name
+    names = re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*?op_name="([^"]*)"',
+        three.compile().as_text())
+    assert len(names) == 9 and all(f"/{scope}/" in n for n in names), names
+    kinds = lambda kernel, inside: [
+        n for n in names if f"/{kernel}/" in n and inside(n)]
+    assert len(kinds("hvt_causal_conv_fwd",
+                     lambda n: "transpose(" not in n)) == 3
+    assert len(kinds("hvt_causal_conv_fwd",
+                     lambda n: "rematted_computation" in n)) == 3
+    assert len(kinds("hvt_causal_conv_bwd", lambda n: "transpose(jvp(" in n
                      and "rematted_computation" not in n)) == 3

@@ -52,7 +52,7 @@ def test_flash_must_be_the_compiled_kernel():
 
 # what no cell of the benchmark decides, in the order it runs
 PHASES = {1: ["launcher", "device", "flash8192", "flash256", "gdn8192",
-              "eager"],
+              "conv8192", "eager"],
           4: ["device", "ring4", "dryrun4"]}
 
 
@@ -137,6 +137,23 @@ def test_chunked_rule_against_the_float32_recurrence():
             assert max(out[path]["rel_l2"].values()) < chip_smoke.BF16_REL_L2
             assert out[path]["ms_forward"] > 0
             assert out[path]["ms_forward_and_backward"] > 0
+
+
+def test_convolution_kernels_beside_the_plain_body():
+    # the conv8192 phase's comparison at a size the CPU can afford: the
+    # kernels (interpreted here) and the plain body side by side, both
+    # cells' kinds of shape (no bias, a bias), two blocks of positions
+    out = chip_smoke.phase_conv8192(shapes=((2, 2048, 32, False),
+                                            (1, 32, 40, True)))
+    assert out["taps"] == 4 and not out["kernels_compiled"]
+    for shape, names in (("2x2048x32", {"o", "dx", "dweight"}),
+                         ("1x32x40", {"o", "dx", "dweight", "dbias"})):
+        for path in ("kernels", "plain"):
+            assert set(out[shape][path]["rel_l2"]) == names
+            assert max(out[shape][path]["rel_l2"].values()) \
+                < chip_smoke.BF16_REL_L2
+            assert out[shape][path]["ms_forward"] > 0
+            assert out[shape][path]["ms_forward_and_backward"] > 0
 
 
 def test_flash_kernel_against_the_float32_formula():
