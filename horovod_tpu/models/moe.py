@@ -47,8 +47,10 @@ experts ``[first, first + count)`` alone. The router still scores and
 chooses over all ``n_experts``; only an assignment to a held expert gets
 a row, and the layer returns the held experts' part of the sum (plus what
 every chip computes alike: the latent projections and the shared expert).
-Nothing is dropped: a token has one slot a held expert and the slots are
-sorted by expert with the unassigned last. The assigned slots are worked
+Nothing is dropped: a token has one slot a held expert, assigned or not by
+``[T, E]`` comparisons with the token's ``k``-th score (``_chosen``; no
+``[T, k, E]`` mask), and the slots are sorted by expert with the
+unassigned last. The assigned slots are worked
 through in **rounds of ``T`` rows, one row a token** (``held_rows``,
 ``_held_experts``): round ``r`` takes the sorted slots ``[r T, (r + 1)
 T)``, gathers their tokens' rows, runs the same grouped products on a
@@ -91,6 +93,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from horovod_tpu.ops import kth_largest as kth_kernel
+
 # The grouped-product implementation, as the engagement counter names it.
 PRODUCT = "megablox_gmm"
 # Its tile, rows x contraction x columns: the fastest of nine timed at
@@ -102,6 +106,13 @@ _GMM_TILE = (512, 1024, 1024)
 # ``models.GPT`` does under ``remat``, because the layer's own backward
 # pass already makes each round's forward again.
 HELD_SUM = "moe_held_sum"
+# And of what a held layer's router chose, which takes no gradient: which
+# experts a token was assigned (a bit a token and expert, ``_pack``) and
+# its slots in their order. Kept, a recomputed block makes the scores
+# again for their gradient but not the choice (the ``k``-th score, the
+# comparisons, the sort): 1.5 MB a layer at 16,384 tokens, 512 experts
+# and 8 held.
+HELD_CHOICE = "moe_held_choice"
 
 
 def _count_trace(n_experts, top_k, held, n_tokens):
@@ -150,6 +161,57 @@ def _take_rows_bwd(k, inverse, g):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
+def _chosen(pick, k, top):
+    """``[T, E]`` bool: the experts ``jax.lax.top_k(pick, k)`` gives each
+    token, from its ``k``-th largest score and comparisons ``[T, E]``, with
+    nothing ``[T, k, E]``. Every score above the ``k``-th is chosen; of
+    those equal to it, as many as are still needed, the lower index first,
+    as ``top_k`` breaks a tie: a threshold alone would choose more than
+    ``k`` where scores tie at the ``k``-th place, and a sigmoid's do once
+    they saturate. The ``k``-th score is ``ops/kth_largest.py``'s kernel's
+    where that serves (a TPU, whole 128-lane tiles: 0.28 ms at ``[16384,
+    512]`` and ``k`` = 22 on a v5e where ``top_k``, a sort of every row,
+    took 1.44; PERF.md section 6, PR 37) and the last of ``top [T, k]``,
+    ``top_k``'s values, elsewhere."""
+    kth = (kth_kernel.kth_largest(pick, k)
+           if kth_kernel.serves(*pick.shape, k) else top[:, -1:])
+    above, level = pick > kth, pick == kth
+    needed = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    in_front = jnp.cumsum(level, axis=-1, dtype=jnp.int32) - level
+    return above | (level & (in_front < needed))
+
+
+def _pack(mask):
+    """``mask [T, E]`` bool -> ``[ceil(T / 32), E]`` uint32, a token a
+    bit: what is kept of a held layer's choice for the backward pass, an
+    eighth of the mask's bytes. Along the tokens, so that the experts
+    stay on the lanes both ways."""
+    tokens, width = mask.shape
+    rows = jnp.pad(mask, ((0, -tokens % 32), (0, 0))).reshape(-1, 32, width)
+    return jnp.sum(rows.astype(jnp.uint32)
+                   << jnp.arange(32, dtype=jnp.uint32)[:, None],
+                   axis=1, dtype=jnp.uint32)
+
+
+def _unpack(bits, tokens):
+    """``_pack``'s mask of ``tokens`` rows again."""
+    rows = (bits[:, None, :] >> jnp.arange(32, dtype=jnp.uint32)[:, None]) & 1
+    return rows.reshape(-1, bits.shape[-1])[:tokens].astype(bool)
+
+
+def _slots_by_expert(assigned):
+    """``assigned [T, count]`` bool -> the ``T x count`` slots
+    (token-major: slot ``t count + e``), the assigned first, by expert and
+    within an expert by token: one stable sort. Writing each assigned slot
+    to its place (its expert's offset plus the earlier tokens that chose
+    the expert, two ``cumsum``s) took 0.65 and 2.50 ms on a v5e at 16,384
+    x 8 and x 32 slots where this sort takes 0.12 and 0.55
+    (``benchmarks/moe_route_pieces.py``; PERF.md section 6, PR 37)."""
+    count = assigned.shape[-1]
+    return jnp.argsort(jnp.where(assigned, jnp.arange(count), count)
+                       .reshape(-1), stable=True)
+
+
 def moe_route(h, router, k, *, score="softmax", bias=None, scale=1.0,
               held=None, renormalise=None):
     """``h [T, d]``, ``router [d, E]`` -> the ``k`` choices of every
@@ -172,12 +234,17 @@ def moe_route(h, router, k, *, score="softmax", bias=None, scale=1.0,
     here or not; None is each score's habit (the sigmoid's are, the
     softmax's are not).
 
-    ``held = (first, count)``: the choice is still over all ``E``, but a
-    token has a slot a *held* expert in place of one a choice:
-    ``weights [T, count]`` (0 where the token did not choose the expert),
-    ``order`` and ``inverse`` over the ``T x count`` slots (token-major),
-    sorted by held expert with the slots nobody chose last, and
-    ``group_sizes [count]``, which sum to the slots really assigned."""
+    ``held = (first, count)``: the choice is still over all ``E`` and
+    the same (``experts`` is ``top_k``'s, for a caller that sows or checks
+    it), but a token has a slot a *held* expert in place of one a choice,
+    and all of it comes from ``[T, E]`` comparisons with the token's
+    ``k``-th score (``_chosen``): ``weights [T, count]`` (0 where the
+    token did not choose the expert), ``order`` over the ``T x count``
+    slots (token-major), the assigned first, by held expert and within one
+    by token, ``inverse`` None (nothing reads a held layer's; only the
+    first ``sum(group_sizes)`` of ``order`` mean anything, the rest are
+    slots nobody chose) and ``group_sizes [count]``, which sum to the
+    slots really assigned."""
     n_tokens, n_experts = h.shape[0], router.shape[-1]
     logits = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
@@ -188,15 +255,17 @@ def moe_route(h, router, k, *, score="softmax", bias=None, scale=1.0,
         pick = probs if bias is None else probs + bias.astype(jnp.float32)
     else:
         raise ValueError(f"score {score!r}: softmax or sigmoid")
-    _, experts = jax.lax.top_k(jax.lax.stop_gradient(pick), k)
-    # [T, k, E]; the weights and the counts as sums over it, so that
-    # neither a gather's transpose nor a histogram puts a scatter in
-    chosen = experts[..., None] == jnp.arange(n_experts)
+    pick = jax.lax.stop_gradient(pick)
+    top, experts = jax.lax.top_k(pick, k)
     if held is None:
+        # [T, k, E]; the weights and the counts as sums over it, so that
+        # neither a gather's transpose nor a histogram puts a scatter in
+        chosen = experts[..., None] == jnp.arange(n_experts)
         weights = jnp.sum(jnp.where(chosen, probs[:, None, :], 0.0), axis=-1)
         counts = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)
     else:       # [T, E]: a token chooses an expert at most once
-        assigned = jnp.any(chosen, axis=1)
+        assigned = _unpack(checkpoint_name(
+            _pack(_chosen(pick, k, top)), HELD_CHOICE), n_tokens)
         weights = jnp.where(assigned, probs, 0.0)
         counts = jnp.sum(assigned, axis=0, dtype=jnp.int32)
     group_sizes = counts
@@ -207,13 +276,13 @@ def moe_route(h, router, k, *, score="softmax", bias=None, scale=1.0,
             jnp.sum(weights, axis=-1, keepdims=True) + 1e-20))
     if held is None:
         order = jnp.argsort(experts.reshape(-1), stable=True)
+        inverse = jnp.argsort(order)
     else:
         first, count = held
         mine = slice(first, first + count)
         weights, group_sizes = weights[:, mine], counts[mine]
-        order = jnp.argsort(jnp.where(assigned[:, mine], jnp.arange(count),
-                                      count).reshape(-1), stable=True)
-    inverse = jnp.argsort(order)
+        order, inverse = checkpoint_name(
+            _slots_by_expert(assigned[:, mine]), HELD_CHOICE), None
     if score == "sigmoid":
         return experts, weights, order, inverse, group_sizes, {}, probs
     share = counts.astype(jnp.float32) / (n_tokens * k)

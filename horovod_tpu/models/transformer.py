@@ -450,15 +450,18 @@ class GPT(nn.Module):
             # and the inverses of a Gated DeltaNet's chunks where the rule
             # runs as kernels (``KEPT_INVERSE``: [c, c] bf16 a chunk and
             # value head): two thirds of the rule's forward, which the
-            # recomputed block then leaves out. Neither name is in any
-            # other model's program
-            from horovod_tpu.models.moe import HELD_SUM
+            # recomputed block then leaves out; and what a held layer's
+            # router chose (``moe.HELD_CHOICE``: a bit a token and expert
+            # and the slots' order), which has no gradient and so is not
+            # chosen and sorted again. No name is in any other model's
+            # program
+            from horovod_tpu.models.moe import HELD_CHOICE, HELD_SUM
             from horovod_tpu.ops.gated_delta_rule import KEPT_INVERSE
 
             block = nn.remat(
                 block, static_argnums=(),
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    HELD_SUM, KEPT_INVERSE))
+                    HELD_SUM, HELD_CHOICE, KEPT_INVERSE))
         aux = {}
         for i in range(cfg.n_layers):
             kind = () if cfg.layer_pattern is None else (

@@ -22,6 +22,7 @@ from horovod_tpu.models import gdn, moe, ssm
 from horovod_tpu.ops import causal_conv as conv
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops import gated_delta_rule as gdr
+from horovod_tpu.ops import kth_largest as kth
 from horovod_tpu.parallel.sequence import ring_attention
 
 
@@ -50,6 +51,7 @@ def compiled_kernel(monkeypatch):
     monkeypatch.setattr(moe, "_interpret", lambda: False)
     monkeypatch.setattr(gdr, "_interpret", lambda: False)
     monkeypatch.setattr(conv, "_interpret", lambda: False)
+    monkeypatch.setattr(kth, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -232,7 +234,22 @@ def test_mamba2_layer_compiles_for_v5e(v5e_devices):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
 
 
-def test_held_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices):
+@pytest.mark.parametrize("k", [22, 10])
+def test_kth_largest_kernel_compiles_for_v5e(k, compiled_kernel,
+                                             v5e_devices):
+    """The routers of nemotron3s-s8192 and qwen3next-s8192: the ``k``-th
+    largest of 512 scores for 2 x 8192 tokens, one Pallas call whose
+    turned block fits VMEM, no sort beside it."""
+    x = jax.ShapeDtypeStruct((2 * 8192, 512), jnp.float32,
+                             sharding=SingleDeviceSharding(v5e_devices[0]))
+    text = jax.jit(lambda x: kth.kth_largest(x, k)).lower(
+        x).compile().as_text()
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) == 1
+    assert "hvt_moe_kth" in text and " sort(" not in text
+
+
+def test_held_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices,
+                                            monkeypatch):
     """The benchmark cell nemotron3s-s8192's expert layer at its own
     sizes (2 x 8192 tokens of 4096, a sigmoid router over 512 experts, 22
     a token, experts 0 to 7 held, relu2 experts of 2688 in a latent of
@@ -241,8 +258,12 @@ def test_held_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices):
     loop over the rounds; in the backward loop two to make a round's
     forward again and four for its pullback), every one over a round of
     16384 rows and none over the 16384 x 8 slots; the scatters are the
-    products' bookkeeping and a round's sums by token."""
+    products' bookkeeping and a round's sums by token. The ninth Pallas
+    call is the router's ``k``-th score under ``moe_route`` (steered here
+    as a TPU would choose): no ``top_k`` is left in the program, nothing
+    of ``T x k x E`` elements, and one sort, of the ``T x 8`` slots."""
     tokens, d, k, held = 2 * 8192, 4096, 22, (0, 8)
+    monkeypatch.setattr(kth, "serves", lambda *shape: True)
     layer = moe.MoEMlp(512, 2688, k, dtype=jnp.bfloat16, score="sigmoid",
                        route_scale=5.0, expert_act="relu2", latent=1024,
                        shared_ff=5376, held=held)
@@ -258,7 +279,14 @@ def test_held_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         variables["params"], x, variables["buffers"]).compile().as_text()
-    assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) == 8
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) == 9
+    kth_calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and "hvt_moe_kth" in line]
+    assert len(kth_calls) == 1 and "/moe_route/" in kth_calls[0]
+    assert "topk" not in text.lower() and f"[{tokens},{k},512]" not in text
+    sorts = re.findall(r"= \(?\w+\[([\d,]*)\][^=]* sort\(", text)
+    # (the others are over a round's T rows: the sums by token)
+    assert [n for n in sorts if n != str(tokens)] == [str(tokens * 8)], sorts
     assert moe.held_rows(tokens, k, held) == (8, tokens)
     assert f"bf16[{tokens},1024]" in text
     assert f"[{tokens * 8},1024]" not in text

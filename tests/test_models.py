@@ -933,6 +933,27 @@ def test_hybrid_gradient_program_names_its_scopes_and_scatters_no_row():
                     or "/block_" not in name), name
 
 
+@pytest.mark.parametrize("remat", [False, True])
+def test_recomputed_block_does_not_choose_its_experts_again(remat):
+    """What a held expert layer's router chose has no gradient, and
+    ``models.GPT`` keeps it under ``remat`` (``moe.HELD_CHOICE``: a bit a
+    token and expert, and the slots' order): the gradient program of a
+    hybrid with two expert layers holds ``top_k`` twice and sorts the
+    ``T x count`` slots twice, once a layer, with ``remat`` as without,
+    where a recomputed block would choose and sort a second time (the
+    scores it chose from are made again: their gradient needs them)."""
+    import re
+
+    model, params, buffers, tokens = _hybrid_model(remat=remat)
+    text = jax.jit(jax.grad(lambda p: _hybrid_loss(
+        model, p, buffers, tokens))).lower(params).as_text()
+    slots = tokens.size * 4
+    assert len(re.findall(r"chlo\.top_k", text)) == 2
+    # (one lowered ``argsort`` that both layers call)
+    assert len(re.findall(
+        rf"call @argsort\w*\(.*\(tensor<{slots}xi32>\)", text)) == 2
+
+
 def _take_attention_heads(p, first, count, group):
     kv = slice(first // group, max(first // group + 1,
                                    (first + count) // group))

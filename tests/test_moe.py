@@ -362,9 +362,10 @@ def test_latent_layer_matches_reference(held, k):
 
 def test_held_route_gives_a_slot_a_held_expert():
     """The choice is over all the experts; the weights are renormalised
-    over all a token chose, not over the held ones; the slots are sorted
-    by held expert with the unassigned last, and the group sizes count
-    the assigned alone."""
+    over all a token chose, not over the held ones; the assigned slots
+    come first, by held expert and within one by token, and the group
+    sizes count them; a held route has no inverse permutation, and what
+    follows the assigned in ``order`` are slots of the layer's own."""
     _, params, buffers, h = _latent_layer((4, 4))
     first, count, k = 4, 4, 3
     experts, weights, order, inverse, sizes, aux, scores = moe.moe_route(
@@ -382,15 +383,117 @@ def test_held_route_gives_a_slot_a_held_expert():
     assert aux == {} and weights.shape == (h.shape[0], count)
     held = chosen[:, first:first + count]
     np.testing.assert_array_equal(np.asarray(sizes), held.sum(0))
-    assert 0 < int(sizes.sum()) < h.shape[0] * k
-    order, inverse = np.asarray(order), np.asarray(inverse)
-    assert sorted(order) == list(range(h.shape[0] * count))
-    np.testing.assert_array_equal(order[inverse], np.arange(order.size))
+    assigned = int(sizes.sum())
+    assert 0 < assigned < h.shape[0] * k
+    assert inverse is None
+    order = np.asarray(order)
+    assert order.shape == (h.shape[0] * count,)
+    assert order.min() >= 0 and order.max() < h.shape[0] * count
     key = np.where(held, np.arange(count), count).reshape(-1)
-    np.testing.assert_array_equal(order, np.argsort(key, kind="stable"))
+    np.testing.assert_array_equal(
+        order[:assigned], np.argsort(key, kind="stable")[:assigned])
     assert moe.held_rows(h.shape[0], k, (first, count)) == (3, h.shape[0])
     assert moe.held_rows(h.shape[0], k, (0, 2)) == (2, h.shape[0])
     assert moe.held_rows(h.shape[0], k, None) is None
+
+
+def _held_route_as_it_was(h, router, k, *, score, bias, scale, held,
+                          renormalise=None):
+    """A held layer's route as ``moe_route`` made it until PR 37, kept
+    here as the plain formulation the new one is held to: ``top_k``, the
+    ``[T, k, E]`` comparison of the chosen indices with every expert, a
+    stable ``argsort`` of all ``T x count`` slots. ``(experts, weights,
+    order, group_sizes, probs)``."""
+    logits = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if score == "softmax":
+        probs = pick = jax.nn.softmax(logits, axis=-1)
+    else:
+        probs = jax.nn.sigmoid(logits)
+        pick = probs if bias is None else probs + bias.astype(jnp.float32)
+    _, experts = jax.lax.top_k(pick, k)
+    chosen = experts[..., None] == jnp.arange(router.shape[-1])
+    assigned = jnp.any(chosen, axis=1)
+    weights = jnp.where(assigned, probs, 0.0)
+    counts = jnp.sum(assigned, axis=0, dtype=jnp.int32)
+    if score == "sigmoid" if renormalise is None else renormalise:
+        weights = weights * (scale / (
+            jnp.sum(weights, axis=-1, keepdims=True) + 1e-20))
+    first, count = held
+    mine = slice(first, first + count)
+    order = jnp.argsort(jnp.where(assigned[:, mine], jnp.arange(count),
+                                  count).reshape(-1), stable=True)
+    return experts, weights[:, mine], order, counts[mine], probs
+
+
+def _routed(scene, held, tokens=48, d=16, n_experts=8):
+    """``(h, router, bias)`` for a route over eight experts. ``ties``:
+    experts 1, 2 and 6 share a router column and a bias, 0 and 4 another,
+    and the input is large enough that a sigmoid saturates at 1 and a
+    softmax underflows to 0, so that scores tie at every place, the
+    ``k``-th among them; ``crowd``: every token chooses the first held
+    expert; ``none``: no token chooses a held one."""
+    keys = jax.random.split(jax.random.key(len(scene) + 7 * held[0]), 3)
+    h = jax.random.normal(keys[0], (tokens, d))
+    router = jax.random.normal(keys[1], (d, n_experts))
+    bias = 0.05 * jax.random.normal(keys[2], (n_experts,))
+    if scene == "ties":
+        for same in ((1, 2, 6), (0, 4)):
+            router = router.at[:, same].set(router[:, same[:1]])
+            bias = bias.at[jnp.array(same)].set(bias[same[0]])
+        h = h.at[::2].multiply(40.0)
+    elif scene in ("crowd", "none"):
+        h = h.at[:, 0].set(1.0)
+        mine = slice(held[0], held[0] + (1 if scene == "crowd" else held[1]))
+        router = router.at[0, mine].set(60.0 if scene == "crowd" else -60.0)
+        bias = jnp.zeros_like(bias)     # the scores alone decide
+    return h, router, bias
+
+
+@pytest.mark.parametrize("scene", ["even", "ties", "crowd", "none"])
+@pytest.mark.parametrize("k", [2, 5], ids=["k2", "k5"])
+@pytest.mark.parametrize("held", [(0, 3), (3, 3), (5, 1)],
+                         ids=["front", "middle", "one-expert"])
+@pytest.mark.parametrize("score", ["softmax", "sigmoid"])
+def test_held_route_is_the_plain_formulation_bit_for_bit(score, held, k,
+                                                         scene):
+    """The route of a held layer, made from the ``k``-th score and ``[T,
+    E]`` comparisons, against the formulation it replaced (``top_k``, the
+    ``[T, k, E]`` mask, the stable ``argsort``), jitted both: the same
+    experts, and ``weights``, ``group_sizes``, ``probs`` and the assigned
+    part of ``order`` equal to the last bit, for ``k`` below and above the
+    experts held, with scores tied at the ``k``-th place (where a
+    threshold alone would assign more than ``k``), with every token on one
+    held expert and with none on any."""
+    h, router, bias = _routed(scene, held)
+    options = dict(score=score, bias=bias if score == "sigmoid" else None,
+                   scale=2.5, held=held, renormalise=True)
+    experts, weights, order, inverse, sizes, _, probs = jax.jit(
+        lambda h, router: moe.moe_route(h, router, k, **options))(h, router)
+    want_experts, want, want_order, want_sizes, want_probs = jax.jit(
+        lambda h, router: _held_route_as_it_was(h, router, k, **options))(
+            h, router)
+    assert inverse is None
+    np.testing.assert_array_equal(np.asarray(experts),
+                                  np.asarray(want_experts))
+    for got, plain in ((weights, want), (sizes, want_sizes),
+                       (probs, want_probs)):
+        assert got.dtype == plain.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(plain))
+    assigned = int(want_sizes.sum())
+    np.testing.assert_array_equal(np.asarray(order)[:assigned],
+                                  np.asarray(want_order)[:assigned])
+    order = np.asarray(order)
+    assert order.min() >= 0 and order.max() < h.shape[0] * held[1]
+    pick = np.asarray(probs) + (np.asarray(bias) if score == "sigmoid"
+                                else 0.0)
+    kth = np.sort(pick, -1)[:, -k][:, None]
+    if scene == "ties":     # a threshold alone would choose too many
+        assert ((pick >= kth).sum(-1) > k).any()
+    if scene == "crowd":
+        assert int(sizes[0]) == h.shape[0]
+    if scene == "none":
+        assert assigned == 0
 
 
 @pytest.mark.parametrize("held, crowd, rounds", [
@@ -519,6 +622,71 @@ def test_held_layer_gradient_program_scatters_a_rounds_rows_alone():
         assert elements <= h.shape[0] * 8, (elements, name)   # T x latent
     for elements, name in set(found) - set(sums):
         assert elements <= 4 + h.shape[0] * 3, (elements, name)
+
+
+@pytest.mark.parametrize("tokens, width", [(32, 8), (44, 8), (7, 3),
+                                           (96, 16), (1, 1)])
+def test_the_kept_choice_is_a_bit_a_token_and_expert(tokens, width):
+    """What ``models.GPT`` keeps of a held layer's choice under ``remat``
+    (``HELD_CHOICE``): the mask packed 32 tokens a word, whatever the
+    token count, and the same mask unpacked."""
+    mask = jax.random.bernoulli(jax.random.key(tokens), 0.3, (tokens, width))
+    bits = moe._pack(mask)
+    assert bits.shape == (-(-tokens // 32), width)
+    assert bits.dtype == jnp.uint32
+    np.testing.assert_array_equal(np.asarray(moe._unpack(bits, tokens)),
+                                  np.asarray(mask))
+    np.testing.assert_array_equal(
+        np.asarray(moe._unpack(moe._pack(jnp.ones_like(mask)), tokens)), True)
+
+
+def _element_counts(text):
+    """``{elements: dimensions as written}`` of every array type in a
+    program's text, lowered (``tensor<44x5x8xi1>``) or compiled
+    (``pred[44,5,8]``)."""
+    dims = re.findall(r"tensor<((?:\d+x)+)\w+>", text) + re.findall(
+        r"\w+\[((?:\d+,)*\d+)\]", text)
+    return {math.prod(int(n) for n in re.split(r"[x,]", d) if n): d
+            for d in dims}
+
+
+@pytest.mark.parametrize("program", ["forward", "gradient"])
+@pytest.mark.parametrize("kind", ["sigmoid-latent", "softmax-renormalised"])
+def test_held_layer_program_has_nothing_T_k_E_and_one_sort_of_its_slots(
+        kind, program):
+    """A held layer's route is ``[T, E]`` work: neither as traced nor as
+    compiled does the layer, forward or with its gradient, hold a value
+    of ``T x k x E`` elements (the mask of the chosen indices against
+    every expert that the route was made from until PR 37), and it sorts
+    its ``T x count`` slots once: the second sort, the inverse
+    permutation that nothing read, is not traced. The layer that holds
+    every expert still has both (its weights are one a choice)."""
+    tokens, n_experts, k, held = 44, 8, 5, (2, 4)
+    options = (dict(score="sigmoid", route_scale=2.5, expert_act="relu2",
+                    latent=16, shared_ff=20) if kind == "sigmoid-latent"
+               else dict(renormalise=True, shared_ff=10, shared_gate=True))
+    h = jax.random.normal(jax.random.key(0), (tokens, 16))
+
+    def lowered(held):
+        layer = MoEMlp(n_experts, 12, k, dtype=jnp.float32, held=held,
+                       **options)
+        variables = layer.init(jax.random.key(1), h)
+        forward = lambda h: jnp.sum(layer.apply(variables, h)[0] ** 2)
+        return jax.jit(forward if program == "forward"
+                       else jax.grad(forward)).lower(h)
+
+    step = lowered(held)
+    slots = tokens * held[1]
+    for text in (step.as_text(), step.compile().as_text()):
+        counts = _element_counts(text)
+        assert tokens * k * n_experts not in counts, counts[
+            tokens * k * n_experts]
+    sorts = re.findall(r"stablehlo\.sort.*?\}\) : \(tensor<(\d+)xi32>",
+                       step.as_text(), re.DOTALL)
+    assert sorts.count(str(slots)) == 1, sorts
+    every = lowered(None).as_text()
+    assert tokens * k * n_experts in _element_counts(every)
+    assert len(re.findall(r"stablehlo\.sort", every)) == 2
 
 
 def _grouped_products(jaxpr):
