@@ -52,14 +52,15 @@ def _inputs(shape, taps):
     return (x, weight, bias), g
 
 
-def _device_ms(trace_dir, calls):
-    """``(ms a call chip 0 was busy, {kernel: ms a call})`` of a trace."""
+def _device_ms(trace_dir, calls, names=KERNELS):
+    """``(ms a call chip 0 was busy, {kernel: ms a call})`` of a trace,
+    for the kernels of ``names``."""
     from jax.profiler import ProfileData
 
     found = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
                                    "*.xplane.pb"))
     data = ProfileData.from_file(max(found, key=os.path.getmtime))
-    spans, kernels = [], dict.fromkeys(KERNELS, 0.0)
+    spans, kernels = [], dict.fromkeys(names, 0.0)
     for plane in data.planes:
         if plane.name != "/device:TPU:0":
             continue
@@ -68,7 +69,7 @@ def _device_ms(trace_dir, calls):
                 continue
             for e in line.events:
                 spans.append((e.start_ns, e.start_ns + e.duration_ns))
-                for name in KERNELS:
+                for name in names:
                     if name in e.name.split(" = ")[0]:
                         kernels[name] += e.duration_ns / 1e6 / calls
     busy, end = 0.0, 0
@@ -78,7 +79,7 @@ def _device_ms(trace_dir, calls):
     return busy / 1e6 / calls, {k: v for k, v in kernels.items() if v}
 
 
-def _time(fn, args, calls):
+def _time(fn, args, calls, names=KERNELS):
     import jax
 
     jax.block_until_ready(fn(*args))
@@ -90,7 +91,7 @@ def _time(fn, args, calls):
         jax.block_until_ready(out)
         wall = 1e3 * (time.perf_counter() - t0) / calls
         jax.profiler.stop_trace()
-        device, kernels = _device_ms(trace_dir, calls)
+        device, kernels = _device_ms(trace_dir, calls, names)
     return {"device_ms": device, "wall_ms": wall, **kernels}
 
 

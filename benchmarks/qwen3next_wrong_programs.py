@@ -16,7 +16,11 @@ cumulative sums, inverse and carried state in bf16 (``gdn.STATE_DTYPE``),
 a router whose product is left at the TPU's default precision (one bf16
 pass), the chosen weights not renormalised, q and k not L2-normalised,
 ``beta`` left out, the decay left out, the gate applied before the
-mixer's norm, and the rotary over the whole head. Then the loss of the
+mixer's norm, and the rotary over the whole head. (On the chip the per-head
+norms are the Pallas kernels of ``ops/head_norm.py``; ``gdn.l2_normalise``
+and ``gdn.gated_head_norm`` are the names the mixer calls them by, so the
+two programs that swap those names run without the kernel they replace:
+the swap steers the kernel path.) Then the loss of the
 whole model on a fresh initialisation against the reference's, and the
 reference itself at the TPU's default precision: what the step-loss
 comparison can and cannot tell. One JSON line each.
@@ -86,12 +90,24 @@ def _rule_given(**fixed):
 
 
 def _gate_first(o, z, scale, eps):
+    """``gdn.gated_head_norm``'s arguments (``o`` and ``z`` ``[b, s, H
+    d]``, ``scale [d]``), the gate applied before the norm."""
     import jax
     import jax.numpy as jnp
 
-    gated = o.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    heads = lambda t: t.astype(jnp.float32).reshape(
+        *t.shape[:-1], -1, scale.shape[-1])
+    gated = heads(o) * jax.nn.silu(heads(z))
     return (gated * jax.lax.rsqrt(jnp.mean(gated * gated, -1, keepdims=True)
-                                  + eps) * scale).astype(o.dtype)
+                                  + eps) * scale).astype(o.dtype).reshape(
+                                      o.shape)
+
+
+def _not_normalised(x, dim=None, scale=1.0):
+    """``gdn.l2_normalise``'s arguments, the norm left out."""
+    import jax.numpy as jnp
+
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
 
 
 def main():
@@ -150,7 +166,7 @@ def main():
             ("chosen weights not renormalised",
              _route_with(lambda o: ({**o, "renormalise": False}, None))),
             ("q and k not L2-normalised",
-             _swapped(gdn, "l2_normalise", lambda x: x.astype(jnp.float32))),
+             _swapped(gdn, "l2_normalise", _not_normalised)),
             ("beta left out", _rule_given(beta=1.0)),
             ("the decay left out", _rule_given(g=0.0)),
             ("the gate before the mixer's norm",

@@ -32,7 +32,10 @@ of ``models/gdn.py`` at that cell's shape, the Pallas kernels and the plain
 timed, forward alone and forward and backward), ``conv8192`` (the causal
 depthwise convolution in front of that rule and of Mamba-2's scan, at the
 two cells' widths, the Pallas kernels of ``ops/causal_conv.py`` and the
-plain body side by side, forward and the three gradients), ``eager`` (the
+plain body side by side, forward and the three gradients), ``norms8192``
+(that mixer's per-head norms on ``[b, s, H d]``, the L2 norm of q and k and
+``RMSNorm(o) w silu(z)``, the Pallas kernels of ``ops/head_norm.py`` and the
+plain bodies side by side against the float32 formula), ``eager`` (the
 immediate path); ``--phases`` names the ones to run. Four
 chips: ``device``, ``ring4`` (``parallel/sequence.py``, ``sp`` = 4),
 ``dryrun4`` (the GSPMD dp x sp x tp step against one device).
@@ -83,6 +86,27 @@ def rel_l2(a, b):
         num += float(np.sum((x - y) ** 2))
         den += float(np.sum(y ** 2))
     return (num / den) ** 0.5
+
+
+def with_gradients(fn, cot):
+    """A jitted ``fn`` that also returns every operand's gradient for the
+    output's cotangent ``cot``: ``(out, *gradients)``."""
+    import jax
+
+    return jax.jit(lambda *a: (lambda o, vjp: (o, *vjp(cot)))(
+        *jax.vjp(fn, *a)))
+
+
+def mean_seconds(call, *args):
+    """Seconds a call of ``call(*args)`` on the host's clock around
+    ``block_until_ready``: the mean of five after one."""
+    import jax
+
+    jax.block_until_ready(call(*args))
+    t0 = time.perf_counter()
+    for _ in range(5):
+        jax.block_until_ready(call(*args))
+    return (time.perf_counter() - t0) / 5
 
 
 def run_phase(name, fn, meter=None):
@@ -376,17 +400,7 @@ def phase_gdn8192(shape=(2, 8192, 16, 32, 128, 128), chunk=128):
         wide = lambda t: jnp.repeat(t, h_v // h_k, axis=1)
         return reference.delta_rule(wide(q), wide(k), v, g, beta)
 
-    def with_gradients(rule, do):
-        return jax.jit(lambda *a: (lambda o, vjp: (o, *vjp(do)))(
-            *jax.vjp(rule, *a)))
-
-    def seconds(call):
-        jax.block_until_ready(call(q, k, v, g, beta))
-        t0 = time.perf_counter()
-        for _ in range(5):
-            jax.block_until_ready(call(q, k, v, g, beta))
-        return (time.perf_counter() - t0) / 5
-
+    seconds = lambda call: mean_seconds(call, q, k, v, g, beta)
     f32 = lambda t: t.astype(jnp.float32)
     with jax.default_matmul_precision("highest"):
         want = with_gradients(lambda *a: jax.lax.map(
@@ -436,10 +450,6 @@ def phase_conv8192(shapes=((2, 8192, 8192, False), (2, 8192, 1280, True)),
     from horovod_tpu.models import ssm
     from horovod_tpu.ops import causal_conv as kernels
 
-    def with_gradients(conv, g):
-        return jax.jit(lambda *a: (lambda o, vjp: (o, *vjp(g)))(
-            *jax.vjp(conv, *a)))
-
     out = {"taps": taps, "kernels_compiled": jax.default_backend() != "cpu"}
     for b, s, c, with_bias in shapes:
         rng = np.random.RandomState(c)
@@ -449,13 +459,7 @@ def phase_conv8192(shapes=((2, 8192, 8192, False), (2, 8192, 1280, True)),
         bias = (jnp.asarray(rng.normal(size=(c,)), jnp.float32)
                 if with_bias else None)
 
-        def seconds(call):
-            jax.block_until_ready(call(x, weight, bias))
-            t0 = time.perf_counter()
-            for _ in range(5):
-                jax.block_until_ready(call(x, weight, bias))
-            return (time.perf_counter() - t0) / 5
-
+        seconds = lambda call: mean_seconds(call, x, weight, bias)
         f32 = lambda t: t.astype(jnp.float32)
         want = with_gradients(ssm.causal_conv_plain, f32(g))(
             f32(x), weight, bias)
@@ -475,6 +479,65 @@ def phase_conv8192(shapes=((2, 8192, 8192, False), (2, 8192, 1280, True)),
             here[name] = {"ms_forward": 1e3 * seconds(jax.jit(conv)),
                           "ms_forward_and_backward": 1e3 * seconds(step),
                           "rel_l2": errs}
+    return out
+
+
+# ----------------------------------------------------------------- norms8192
+
+def phase_norms8192(shapes=((2, 8192, 32, 128), (1, 1040, 3, 256))):
+    """The Gated DeltaNet mixer's per-head norms at ``qwen3next-s8192``'s
+    shape, (batch, seq, heads, a head's channels) in bf16 on ``[b, s, H
+    d]``: its 32 value heads of 128, and three heads of 256 over a
+    sequence the kernels' block does not divide. ``RMSNorm(o) w
+    silu(z)`` and the L2 norm at q's scale, by the Pallas kernels of
+    ``ops/head_norm.py`` and the plain ``jax.numpy`` bodies of
+    ``models/gdn.py`` side by side. Each: output and every gradient against
+    the plain body on float32 operands (the same bf16 numbers, so what
+    differs is where each path rounds), and the milliseconds a forward
+    alone and a forward and backward take (host clock around
+    ``block_until_ready``, the mean of five calls after one;
+    ``benchmarks/head_norm_kernels.py`` reads the device's own)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.models import gdn
+    from horovod_tpu.ops import head_norm as kernels
+
+    eps = 1e-6
+    out = {"kernels_compiled": jax.default_backend() != "cpu"}
+    for b, s, heads, dim in shapes:
+        rng = np.random.RandomState(heads * dim)
+        o, z, g = (jnp.asarray(rng.normal(size=(b, s, heads * dim)),
+                               jnp.bfloat16) for _ in range(3))
+        w = jnp.asarray(rng.uniform(0.5, 1.5, (dim,)), jnp.float32)
+        f32 = lambda t: t.astype(jnp.float32)
+        here = out[f"{b}x{s}x{heads}x{dim}"] = {}
+        for norm, args, names, paths in (
+                ("gated", (o, z, w), ("y", "do", "dz", "dw"), {
+                    "kernels": lambda *a: kernels.gated_norm(*a, eps=eps),
+                    "plain": lambda *a: gdn.gated_head_norm_plain(*a, eps)}),
+                ("l2", (o,), ("y", "dx"), {
+                    "kernels": lambda x: kernels.l2_norm(
+                        x, dim, eps=eps, scale=dim ** -0.5),
+                    "plain": lambda x: gdn.l2_normalise_plain(
+                        x, dim, dim ** -0.5)})):
+            want = with_gradients(paths["plain"], f32(g))(
+                *(f32(a) if a.ndim == 3 else a for a in args))
+            for name, fn in paths.items():
+                step = with_gradients(fn, g)
+                errs = {what: rel_l2(one, w_) for what, one, w_ in
+                        zip(names, jax.block_until_ready(step(*args)), want)}
+                check(all(np.isfinite(list(errs.values())))
+                      and max(errs.values()) <= BF16_REL_L2,
+                      f"the {norm} norm at {b} x {s} x {heads} x {dim} by "
+                      f"its {name} path differs from the plain body in "
+                      f"float32: {errs} (relative L2), bound {BF16_REL_L2}")
+                here[f"{norm}_{name}"] = {
+                    "ms_forward": 1e3 * mean_seconds(jax.jit(fn), *args),
+                    "ms_forward_and_backward": 1e3 * mean_seconds(step,
+                                                                  *args),
+                    "rel_l2": errs}
     return out
 
 
@@ -615,6 +678,7 @@ def main(argv=None):
                             ("flash256", phase_flash256),
                             ("gdn8192", phase_gdn8192),
                             ("conv8192", phase_conv8192),
+                            ("norms8192", phase_norms8192),
                             ("eager", phase_eager)):
             if not only or name in only:
                 run_phase(name, phase, meter)

@@ -18,7 +18,12 @@ device trace can be split by them:
 3. ``gdn_rule``: ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a +
    dt_bias)`` a value head (``g <= 0``), both float32; ``q`` and ``k``
    L2-normalised a head (``x / sqrt(sum x^2 + 1e-6)``), ``q`` then times
-   ``d_k^-1/2``; a state ``S [d_k, d_v]`` a value head, from zero:
+   ``d_k^-1/2`` (``l2_normalise``, on ``[b, s, H_k d_k]`` as the
+   convolution left them, a head a group of ``d_k`` adjacent channels: on
+   a TPU at heads that are multiples of 128 the Pallas kernels of
+   ``ops/head_norm.py``, float32 in VMEM alone; everywhere else
+   ``l2_normalise_plain``); a state ``S [d_k, d_v]`` a value head, from
+   zero:
 
        S' = exp(g_t) S_{t-1}
        S_t = S' + k_t (beta_t (v_t - S'^T k_t))^T
@@ -39,7 +44,13 @@ device trace can be split by them:
 4. ``gdn_gate_norm``: ``RMSNorm(o) w * silu(z)`` a head: the norm over a
    head's ``d_v`` channels **before** the gate, ``w [d_v]`` shared by the
    heads (Mamba-2's ``ssm.gated_group_norm`` gates first: another
-   function).
+   function). ``gated_head_norm``, on ``[b, s, H_v d_v]``, which is what
+   the rule's kernels write, ``z`` is and the out-projection reads:
+   chosen as step 3's norms are, between ``ops/head_norm.py``'s kernels
+   and ``gated_head_norm_plain``. Where the kernels serve, nothing
+   between the convolution and the out-projection is reshaped to ``[b,
+   s, H, d]`` (whose tiles differ from ``[b, s, H d]``'s at ``d`` = 128:
+   a copy through HBM each) or written to HBM in float32.
 5. ``gdn_out_proj``: ``y W_out``, no bias.
 
 For a caller that asks for the collection ``intermediates`` the mixer's
@@ -58,6 +69,7 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models import ssm
 from horovod_tpu.ops import gated_delta_rule as rule_kernels
+from horovod_tpu.ops import head_norm as norm_kernels
 
 # What the decays, their cumulative sums, the triangular inverse and the
 # carried state are computed in, whatever the products run in. A module
@@ -105,10 +117,29 @@ def chunk_for(seq_len: int, chunk: Optional[int] = None) -> int:
     return ssm.chunk_for(seq_len, chunk or CHUNK)
 
 
-def l2_normalise(x):
-    """``x / sqrt(sum x^2 + 1e-6)`` over the last axis, float32."""
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+def _by_heads(x, dim):
+    return x.reshape(*x.shape[:-1], x.shape[-1] // dim, dim)
+
+
+def l2_normalise(x, dim: Optional[int] = None, scale: float = 1.0):
+    """``x / sqrt(sum x^2 + 1e-6) * scale`` over each group of ``dim``
+    adjacent channels of the last axis (a head of ``[..., H dim]``; the
+    whole axis where none is named), float32 inside, like ``x``. By the
+    Pallas kernels of ``ops/head_norm.py`` where ``norm_kernels.serves``
+    says so and by ``l2_normalise_plain`` everywhere else."""
+    dim = dim or x.shape[-1]
+    if x.ndim == 3 and norm_kernels.serves(x.shape[1], dim):
+        return norm_kernels.l2_norm(x, dim, eps=L2_EPS, scale=scale)
+    return l2_normalise_plain(x, dim, scale)
+
+
+def l2_normalise_plain(x, dim: Optional[int] = None, scale: float = 1.0):
+    """``l2_normalise`` in plain ``jax.numpy``: the path of every backend
+    and shape the kernels do not serve, and their reference."""
+    heads = _by_heads(x.astype(jnp.float32), dim or x.shape[-1])
+    return (heads * jax.lax.rsqrt(
+        jnp.sum(heads * heads, axis=-1, keepdims=True) + L2_EPS) * scale
+            ).astype(x.dtype).reshape(x.shape)
 
 
 def _dot32(a, b):
@@ -280,13 +311,25 @@ def gated_delta_rule_plain(q, k, v, g, beta, *,
 
 
 def gated_head_norm(o, z, scale, eps):
-    """``RMSNorm(o) * scale * silu(z)`` with the mean square over the last
-    axis (a head's channels), the norm before the gate; float32 inside."""
-    o32 = o.astype(jnp.float32)
+    """``RMSNorm(o) * scale * silu(z)`` with the mean square over each
+    group of ``scale.shape[-1]`` adjacent channels of the last axis (a
+    head of ``[..., H d]``, or of ``[..., H, d]``), the norm before the
+    gate; float32 inside, like ``o``. By the Pallas kernels of
+    ``ops/head_norm.py`` where ``norm_kernels.serves`` says so and by
+    ``gated_head_norm_plain`` everywhere else."""
+    if o.ndim == 3 and norm_kernels.serves(o.shape[1], scale.shape[-1]):
+        return norm_kernels.gated_norm(o, z, scale, eps=eps)
+    return gated_head_norm_plain(o, z, scale, eps)
+
+
+def gated_head_norm_plain(o, z, scale, eps):
+    """``gated_head_norm`` in plain ``jax.numpy``: the path of every
+    backend and shape the kernels do not serve, and their reference."""
+    o32 = _by_heads(o.astype(jnp.float32), scale.shape[-1])
     normed = o32 * jax.lax.rsqrt(
         jnp.mean(o32 * o32, axis=-1, keepdims=True) + eps)
-    return (normed * scale * jax.nn.silu(z.astype(jnp.float32))).astype(
-        o.dtype)
+    gate = jax.nn.silu(_by_heads(z.astype(jnp.float32), scale.shape[-1]))
+    return (normed * scale * gate).astype(o.dtype).reshape(o.shape)
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -351,17 +394,19 @@ class GatedDeltaNet(nn.Module):
             beta = jax.nn.sigmoid(b)
             g = -jnp.exp(a_log.astype(STATE_DTYPE)) * jax.nn.softplus(
                 a + dt_bias.astype(STATE_DTYPE))
-            q = (l2_normalise(heads(q, self.key_heads))
-                 * self.key_dim ** -0.5).astype(self.dtype)
-            k = l2_normalise(heads(k, self.key_heads)).astype(self.dtype)
-            o = gated_delta_rule(q, k, heads(v, self.value_heads), g, beta,
-                                 chunk=self.chunk)
+            # flat in, flat out; the rule takes heads and its kernels
+            # flatten them again, a reshape and its inverse, which XLA
+            # drops
+            q = l2_normalise(q, self.key_dim, self.key_dim ** -0.5)
+            k = l2_normalise(k, self.key_dim)
+            o = gated_delta_rule(
+                heads(q, self.key_heads), heads(k, self.key_heads),
+                heads(v, self.value_heads), g, beta, chunk=self.chunk)
         with jax.named_scope("gdn_gate_norm"):
-            y = gated_head_norm(o, heads(z, self.value_heads), norm_scale,
-                                self.norm_eps)
+            y = gated_head_norm(o.reshape(*o.shape[:-2], values), z,
+                                norm_scale, self.norm_eps)
         with jax.named_scope("gdn_out_proj"):
-            out = jnp.dot(y.reshape(*y.shape[:-2], values),
-                          w_out.astype(self.dtype))
+            out = jnp.dot(y, w_out.astype(self.dtype))
         out = out.reshape(*lead, seq, d)
         self.sow("intermediates", "gdn_output", out)
         return out

@@ -22,6 +22,7 @@ from horovod_tpu.models import gdn, moe, ssm
 from horovod_tpu.ops import causal_conv as conv
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops import gated_delta_rule as gdr
+from horovod_tpu.ops import head_norm
 from horovod_tpu.ops import kth_largest as kth
 from horovod_tpu.parallel.sequence import ring_attention
 
@@ -52,6 +53,7 @@ def compiled_kernel(monkeypatch):
     monkeypatch.setattr(gdr, "_interpret", lambda: False)
     monkeypatch.setattr(conv, "_interpret", lambda: False)
     monkeypatch.setattr(kth, "_interpret", lambda: False)
+    monkeypatch.setattr(head_norm, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -481,3 +483,111 @@ def test_mixers_share_one_lowered_conv_kernel_under_their_scope(
                      lambda n: "rematted_computation" in n)) == 3
     assert len(kinds("hvt_causal_conv_bwd", lambda n: "transpose(jvp(" in n
                      and "rematted_computation" not in n)) == 3
+
+
+@pytest.mark.parametrize("shape", [
+    pytest.param((2, 8192, 32, 128), id="norms-qwen3next-s8192-value-heads"),
+    pytest.param((2, 8192, 16, 128), id="norms-qwen3next-s8192-key-heads"),
+    pytest.param((1, 2048, 16, 128), id="norms-qwen3next-probe"),
+    pytest.param((1, 1040, 3, 256), id="norms-a-ragged-last-block-at-256"),
+    pytest.param((1, 2048, 2, 2048), id="norms-a-head-of-2048"),
+])
+def test_head_norm_kernels_compile_for_v5e(shape, compiled_kernel,
+                                           v5e_devices):
+    """The four kernels of ``ops/head_norm.py`` at the benchmark cell
+    qwen3next-s8192's own sizes (2 x 8192 positions, 32 value heads and 16
+    key heads of 128, bf16), at its probe's 2048 positions and at heads of
+    256 over a sequence the block of 512 positions does not divide, and at
+    a head wider than a block's 1024 lanes (fewer rows a block then),
+    forward and backward: all compile with the blocks they derive, in the
+    default VMEM scope, and the program's temporaries are no more than the
+    two norms' bf16 results (nothing float32 of the operands' size)."""
+    b, s, heads, dim = shape
+    one = SingleDeviceSharding(v5e_devices[0])
+    like = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one)
+    o, w = like(b, s, heads * dim), like(dim, dtype=jnp.float32)
+
+    def loss(o, z, w):
+        y = head_norm.gated_norm(o, z, w, eps=1e-6)
+        x = head_norm.l2_norm(o, dim, eps=1e-6, scale=dim ** -0.5)
+        return jnp.mean(y.astype(jnp.float32) ** 2) + jnp.mean(
+            x.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        o, o, w).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert all(f"hvt_{kernel}" in text for kernel in (
+        "gated_norm_fwd", "gated_norm_bwd", "l2_norm_fwd", "l2_norm_bwd"))
+    assert "vmem_limit_bytes" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= (
+        3 * 2 * b * s * heads * dim)
+
+
+def test_gdn_layers_hold_the_norm_kernels_and_stay_flat(
+        compiled_kernel, v5e_devices, monkeypatch):
+    """With the mixer's three choices steered to their kernels (they are
+    decided from the backend, which is the CPU here), a three-layer
+    ``models.GPT`` of Gated DeltaNet mixers at heads of 128 holds four
+    more ``tpu_custom_call`` sites than the rule's and the convolution's,
+    however many layers it has; compiled, the L2 norms' calls carry the
+    scope ``gdn_rule`` and the gated norm's ``gdn_gate_norm`` in their
+    ``op_name`` (what ``chipbench/layer_metrics/gdn_rule_ms.py`` and
+    ``gdn_ms.py`` match) with the pass they belong to, and **nothing of an
+    activation's size is reshaped or copied between the convolution's
+    kernels and the out-projection**: the mixer's ``[b, s, H d]`` arrays go
+    from custom call to custom call as they are."""
+    from horovod_tpu.models import GPT, GPTConfig
+
+    monkeypatch.setattr(gdn, "kernels_serve", lambda *shape: True)
+    monkeypatch.setattr(ssm, "conv_kernels_serve", lambda *shape: True)
+    monkeypatch.setattr(head_norm, "serves", lambda *shape: True)
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+    batch, seq, heads = 2, 256, 4
+
+    def step(pattern):
+        model = GPT(GPTConfig(
+            vocab_size=512, n_layers=len(pattern), layer_pattern=pattern,
+            d_model=128, n_heads=2, d_ff=256, max_seq_len=seq, remat=True,
+            use_flash=False, gdn_key_heads=2, gdn_value_heads=heads,
+            gdn_key_dim=128, gdn_value_dim=128))
+        tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                                      sharding=one_chip)
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(model.init, jax.random.key(0), tokens))
+        loss = lambda p, t: model.apply(p, t).astype(jnp.float32).mean()
+        return jax.jit(jax.grad(loss)).lower(params, tokens)
+
+    one, three = step("G"), step("GGG")
+    sites = lambda lowered: lowered.as_text().count(
+        "stablehlo.custom_call @tpu_custom_call")
+    # the rule's 4 and the convolution's 3, then the L2 norm forward (at
+    # q's scale and at k's), recomputed and backward, and the gated norm's
+    # three
+    assert sites(one) == sites(three) == 4 + 3 + 9, (sites(one), sites(three))
+    text = three.compile().as_text()
+    names = re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*?op_name="([^"]*)"', text)
+    kinds = lambda kernel, inside: [
+        n for n in names if f"/{kernel}/" in n and inside(n)]
+    for kernel, scope, a_pass in (("hvt_l2_norm", "/gdn_rule/", 2),
+                                  ("hvt_gated_norm", "/gdn_gate_norm/", 1)):
+        calls = kinds(f"{kernel}_fwd", lambda n: True) + kinds(
+            f"{kernel}_bwd", lambda n: True)
+        assert len(calls) == 9 * a_pass and all(scope in n for n in calls)
+        assert len(kinds(f"{kernel}_fwd", lambda n: "transpose(" not in n)
+                   ) == 3 * a_pass
+        assert len(kinds(f"{kernel}_fwd",
+                         lambda n: "rematted_computation" in n)) == 3 * a_pass
+        assert len(kinds(f"{kernel}_bwd", lambda n: "transpose(jvp(" in n
+                         and "rematted_computation" not in n)) == 3 * a_pass
+    # what is left to reshape or copy is of the model's width (128) or the
+    # gates' [b, s, H_v]; q and k are twice that wide, v, z and o four times
+    moved = re.findall(
+        r"= \w+\[([\d,]+)\]\S* (?:reshape|copy|transpose)\(", text)
+    size = lambda dims: int(np.prod([int(n) for n in dims.split(",")]))
+    assert moved and max(map(size, moved)) <= batch * seq * 128, sorted(
+        set(moved), key=size)[-3:]

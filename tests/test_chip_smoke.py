@@ -52,7 +52,7 @@ def test_flash_must_be_the_compiled_kernel():
 
 # what no cell of the benchmark decides, in the order it runs
 PHASES = {1: ["launcher", "device", "flash8192", "flash256", "gdn8192",
-              "conv8192", "eager"],
+              "conv8192", "norms8192", "eager"],
           4: ["device", "ring4", "dryrun4"]}
 
 
@@ -154,6 +154,24 @@ def test_convolution_kernels_beside_the_plain_body():
                 < chip_smoke.BF16_REL_L2
             assert out[shape][path]["ms_forward"] > 0
             assert out[shape][path]["ms_forward_and_backward"] > 0
+
+
+def test_norm_kernels_beside_the_plain_bodies():
+    # the norms8192 phase's comparison at a size the CPU can afford: the
+    # kernels (interpreted here) and the plain bodies side by side, heads
+    # of 128 over two blocks of positions and of 256 over a ragged one
+    out = chip_smoke.phase_norms8192(shapes=((1, 1024, 2, 128),
+                                             (2, 40, 3, 256)))
+    assert not out["kernels_compiled"]
+    for shape in ("1x1024x2x128", "2x40x3x256"):
+        for path in ("kernels", "plain"):
+            for norm, names in (("gated", {"y", "do", "dz", "dw"}),
+                                ("l2", {"y", "dx"})):
+                got = out[shape][f"{norm}_{path}"]
+                assert set(got["rel_l2"]) == names
+                assert max(got["rel_l2"].values()) < chip_smoke.BF16_REL_L2
+                assert got["ms_forward"] > 0
+                assert got["ms_forward_and_backward"] > 0
 
 
 def test_flash_kernel_against_the_float32_formula():

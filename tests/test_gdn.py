@@ -255,17 +255,26 @@ def _rule_given(**fixed):
 
 
 def _gate_first(o, z, scale, eps):
-    gated = o.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    """``gated_head_norm``'s arguments (``o`` and ``z`` ``[b, s, H d]``,
+    ``scale [d]``), the gate applied before the norm."""
+    heads = lambda t: t.astype(jnp.float32).reshape(
+        *t.shape[:-1], -1, scale.shape[-1])
+    gated = heads(o) * jax.nn.silu(heads(z))
     return (gated * jax.lax.rsqrt(jnp.mean(gated * gated, -1, keepdims=True)
-                                  + eps) * scale).astype(o.dtype)
+                                  + eps) * scale).astype(o.dtype).reshape(
+                                      o.shape)
+
+
+def _not_normalised(x, dim=None, scale=1.0):
+    """``l2_normalise``'s arguments, the norm left out."""
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
 
 
 WRONG_MIXERS = {
     "state-and-cumulative-sums-in-bf16": (
         jnp.bfloat16, lambda: _swapped(gdn, "STATE_DTYPE", jnp.bfloat16)),
     "l2-norm-left-out": (
-        jnp.float32, lambda: _swapped(gdn, "l2_normalise",
-                                      lambda x: x.astype(jnp.float32))),
+        jnp.float32, lambda: _swapped(gdn, "l2_normalise", _not_normalised)),
     "beta-left-out": (
         jnp.float32, lambda: _swapped(gdn, "gated_delta_rule",
                                       _rule_given(beta=1.0))),
@@ -277,13 +286,24 @@ WRONG_MIXERS = {
 }
 
 
-@pytest.mark.parametrize("wrong", list(WRONG_MIXERS))
-def test_wrong_mixers_are_refused(wrong):
+# the two whose swapped names are the per-head norms', once more with the
+# norms' Pallas kernels serving (interpreted): the names steer that path too
+KERNELS_SERVE = "-kernels-serve"
+
+
+@pytest.mark.parametrize("wrong", list(WRONG_MIXERS) + [
+    "l2-norm-left-out" + KERNELS_SERVE,
+    "gate-before-the-norm" + KERNELS_SERVE])
+def test_wrong_mixers_are_refused(wrong, monkeypatch):
     """At 512 positions and heads of 32: the mixer as it is, in the
     precision the wrong one runs in, is within the family's bound of the
     position-by-position reference on its own input; the wrong one is
-    outside it."""
-    dtype, swap = WRONG_MIXERS[wrong]
+    outside it. Where the per-head norms' kernels serve, the mixer as it
+    is holds all of them and the wrong one has lost the one it swapped."""
+    served = wrong.endswith(KERNELS_SERVE)
+    dtype, swap = WRONG_MIXERS[wrong.removesuffix(KERNELS_SERVE)]
+    if served:
+        monkeypatch.setattr(gdn.norm_kernels, "serves", lambda *shape: True)
     config = _config(2, 4) | {"linear_key_head_dim": 32,
                               "linear_value_head_dim": 32}
     layer, params, u = _mixer(key_heads=2, value_heads=4, dtype=dtype,
@@ -296,8 +316,19 @@ def test_wrong_mixers_are_refused(wrong):
             {k: v[0] for k, v in sown["intermediates"].items()}, params,
             config)
 
+    def norm_kernels():
+        jaxpr = jax.make_jaxpr(lambda u: layer.apply({"params": params}, u))(u)
+        return sorted(eqn.params["name"] for eqn in _equations(jaxpr.jaxpr)
+                      if eqn.primitive.name == "pallas_call")
+
     sound = distance()
     assert sound <= family.MIXER_REL_L2_BOUND, sound
+    if served:
+        assert norm_kernels() == ["hvt_gated_norm_fwd", "hvt_l2_norm_fwd",
+                                  "hvt_l2_norm_fwd"]
     with swap():
         far = distance()
+        lost = ("hvt_l2_norm_fwd" if wrong.startswith("l2-norm")
+                else "hvt_gated_norm_fwd")
+        assert not served or lost not in norm_kernels()
     assert not far <= family.MIXER_REL_L2_BOUND, (wrong, far, sound)
