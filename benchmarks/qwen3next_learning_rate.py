@@ -1,14 +1,17 @@
 #!/usr/bin/env python
-"""qwen3next_learning_rate.py — which constant learning rate the cell
-``qwen3next-s8192`` can repeat one batch at: for each of ``--rates`` it
+"""qwen3next_learning_rate.py — which constant learning rate a cell of a
+held expert layer (``--cell``: ``qwen3next-s8192`` by default, since PR 40
+``lfm2moe-s8192`` too) can repeat one batch at: for each of ``--rates`` it
 trains the cell's model from the same initialisation on the cell's batch
 for ``--steps`` steps and prints, every ``--every`` steps, the loss and
-the rows that land on the 32 held experts of each of the four expert
-layers (a round is 16,384 rows: more than that in a layer is a second
-round). One compiled step serves every rate (the rate is part of the
-optimizer's state, ``optax.inject_hyperparams``).
+the rows that land on the held experts of each expert layer (a round is
+16,384 rows: more than that in a layer is a second round). One compiled
+step serves every rate (the rate is part of the optimizer's state,
+``optax.inject_hyperparams``).
 
     chiprun -- python benchmarks/qwen3next_learning_rate.py --rates 1e-6 4e-6
+    chiprun -- python benchmarks/qwen3next_learning_rate.py \
+        --cell lfm2moe-s8192 --steps 80 --every 20
 
 A builder's script: it decides nothing. It refuses to run without a TPU.
 """
@@ -28,7 +31,10 @@ def main():
     p.add_argument("--steps", type=int, default=48)
     p.add_argument("--every", type=int, default=12)
     p.add_argument("--seed", type=int, default=2147488301)
+    p.add_argument("--cell", default="qwen3next-s8192")
     args = p.parse_args()
+
+    import importlib
 
     import jax
     import jax.numpy as jnp
@@ -38,14 +44,13 @@ def main():
         sys.exit("qwen3next_learning_rate: no TPU, nothing to measure")
 
     from chipbench import run as harness
-    from chipbench.families import qwen3_next
     from chipbench.setup_sources import enable_compile_cache
 
     enable_compile_cache()
-    config = harness.read_json("chipbench", "configs", "qwen3-next-80b.json")
-    cell = harness.read_json("chipbench", "workloads", "qwen3next-s8192.json")
-    job = qwen3_next.build(config, cell)
-    cfg = qwen3_next._model_config(config, cell["seq_len"])
+    config, cell, _ = harness.load_cell(args.cell)
+    family = importlib.import_module(f"chipbench.families.{config['family']}")
+    job = family.build(config, cell)
+    cfg = family._model_config(config, cell["seq_len"])
     spec = {k: v for k, v in config["optimizer"].items()
             if k not in ("name", "learning_rate")}
     tx = optax.inject_hyperparams(
@@ -70,7 +75,7 @@ def main():
     @jax.jit
     def rows(params, extra, batch):
         _, sown = job.loss_and_sown(params, extra, batch)
-        return [jnp.sum(qwen3_next.held_rows(block["experts"], cfg))
+        return [jnp.sum(family.held_rows(block["experts"], cfg))
                 for block in sown.values() if "experts" in block]
 
     k_init, k_batch = jax.random.split(jax.random.key(args.seed))

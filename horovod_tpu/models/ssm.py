@@ -133,16 +133,19 @@ def causal_conv(x, weight, bias=None):
     return causal_conv_plain(x, weight, bias)
 
 
-def causal_conv_plain(x, weight, bias=None):
+def causal_conv_plain(x, weight, bias=None, activation=jax.nn.silu):
     """``causal_conv`` in plain ``jax.numpy``: the path of every backend
     and shape the kernels do not serve, and their reference. The operand
-    is cast to float32 and padded, each tap a slice of that copy."""
+    is cast to float32 and padded, each tap a slice of that copy.
+    ``activation=None`` leaves the sum of the taps as it is (the gated
+    short convolution of ``models/sconv.py``, which the kernels, ``silu``
+    alone, do not compute)."""
     taps, seq = weight.shape[0], x.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
     out = 0.0 if bias is None else bias.astype(jnp.float32)
     for j in range(taps):
         out = out + weight[j].astype(jnp.float32) * padded[:, j:j + seq]
-    return jax.nn.silu(out).astype(x.dtype)
+    return (out if activation is None else activation(out)).astype(x.dtype)
 
 
 def ssm_scan(x, delta, a, b, c, *, chunk: Optional[int] = None):

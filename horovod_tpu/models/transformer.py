@@ -93,14 +93,17 @@ class GPTConfig:
     # Nemotron-H's ``hybrid_override_pattern``): layer i is ``x +
     # mixer(RMSNorm(x))`` with the mixer ``layer_pattern[i]`` names, "*"
     # attention, "M" a Mamba-2 mixer (models/ssm.py), "G" a Gated
-    # DeltaNet mixer (models/gdn.py), "E" the expert
+    # DeltaNet mixer (models/gdn.py), "C" a gated short convolution
+    # (models/sconv.py), "E" the expert
     # layer (models/moe.py), "-" the dense MLP. None (default) = every
     # layer the attention + MLP (or expert) pair of ``Block``.
     layer_pattern: Optional[str] = None
     # False leaves q and k unrotated: attention without a positional
     # term (the state-space layers of a hybrid carry the order).
     rotary: bool = True
-    # The dense MLP's activation: "gelu", or "relu2" (relu(x)^2).
+    # The dense MLP's activation: "gelu", "relu2" (relu(x)^2), or
+    # "swiglu": the gated MLP ``down(silu(gate(x)) * up(x))``, a third
+    # matrix ``gate`` beside ``up``.
     mlp_act: str = "gelu"
     # The Mamba-2 mixers' sizes: heads of ssm_head_dim channels in
     # ssm_groups groups, a state of ssm_state a channel, ssm_conv taps.
@@ -163,6 +166,13 @@ class GPTConfig:
     # output times sigmoid(h w_g).
     moe_renormalise: Optional[bool] = None
     moe_shared_gate: bool = False
+    # The experts' width where it is not the dense MLP's (None: ``d_ff``
+    # is both): a model with leading dense layers of one width and experts
+    # of another has "-" and "E" in one pattern.
+    moe_expert_ff: Optional[int] = None
+    # The gated short-convolution mixers' taps (models/sconv.py, pattern
+    # letter "C"); their channels are d_model.
+    sconv_taps: int = 3
 
 
 # The crossover policy lives with the kernel (ops/flash_attention.py);
@@ -330,23 +340,33 @@ class Attention(nn.Module):
 
 
 class MLP(nn.Module):
+    """The dense MLP, ``down(act(up(x)))`` or, gated (``mlp_act``
+    "swiglu"), ``down(silu(gate(x)) * up(x))``; under the scope
+    ``dense_mlp``."""
+
     cfg: GPTConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        h = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
-                     param_dtype=jnp.float32, name="up")(x)
-        h = {"gelu": nn.gelu,
-             "relu2": lambda t: jnp.square(nn.relu(t))}[cfg.mlp_act](h)
-        return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
-                        param_dtype=jnp.float32, name="down")(h)
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=cfg.dtype, param_dtype=jnp.float32,
+            name=name)
+        with jax.named_scope("dense_mlp"):
+            h = dense(cfg.d_ff, "up")(x)
+            if cfg.mlp_act == "swiglu":
+                h = nn.silu(dense(cfg.d_ff, "gate")(x)) * h
+            else:
+                h = {"gelu": nn.gelu, "relu2": lambda t: jnp.square(
+                    nn.relu(t))}[cfg.mlp_act](h)
+            return dense(cfg.d_model, "down")(h)
 
 
 def _expert_layer(cfg: GPTConfig):
     from horovod_tpu.models.moe import MoEMlp
 
-    return MoEMlp(cfg.n_experts, cfg.d_ff, cfg.experts_per_token,
+    return MoEMlp(cfg.n_experts, cfg.moe_expert_ff or cfg.d_ff,
+                  cfg.experts_per_token,
                   dtype=cfg.dtype, score=cfg.moe_score,
                   route_scale=cfg.moe_route_scale,
                   expert_act=cfg.moe_expert_act, latent=cfg.moe_latent,
@@ -401,6 +421,11 @@ class MixerBlock(nn.Module):
                 cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim,
                 cfg.gdn_value_dim, cfg.gdn_conv, norm_eps=cfg.norm_eps,
                 dtype=cfg.dtype, name="gdn")(h)
+        elif self.kind == "C":
+            from horovod_tpu.models.sconv import ShortConv
+
+            out = ShortConv(cfg.sconv_taps, dtype=cfg.dtype,
+                            name="sconv")(h)
         elif self.kind == "E":
             out, aux = _expert_layer(cfg)(h)
         elif self.kind == "-":
@@ -409,7 +434,7 @@ class MixerBlock(nn.Module):
             raise ValueError(
                 f"layer_pattern holds {self.kind!r}: a layer is one of "
                 f"'*' (attention), 'M' (Mamba-2), 'G' (Gated DeltaNet), "
-                f"'E' (experts), '-' (MLP)")
+                f"'C' (gated short convolution), 'E' (experts), '-' (MLP)")
         return x + out, aux
 
 
@@ -518,6 +543,10 @@ def param_partition_spec(params, *, tp_axis="tp", tp_size=None,
             from horovod_tpu.models.gdn import gdn_leaf_spec
 
             return gdn_leaf_spec(names[-1], tp_axis)
+        if "sconv" in names:
+            from horovod_tpu.models.sconv import sconv_leaf_spec
+
+            return sconv_leaf_spec(names[-1], tp_axis)
         if any(n in ("q", "k", "v") for n in names):
             heads = leaf.shape[1] if hasattr(leaf, "shape") else None
             if tp_size and heads is not None and heads % tp_size:
@@ -525,7 +554,7 @@ def param_partition_spec(params, *, tp_axis="tp", tp_size=None,
             return P(None, tp_axis, None)      # (d_model, heads, head_dim)
         if "o" in names:
             return P(tp_axis, None, None)      # (heads, head_dim, d_model)
-        if "up" in names:
+        if "up" in names or "gate" in names:
             return P(None, tp_axis)
         if "down" in names:
             return P(tp_axis, None)
