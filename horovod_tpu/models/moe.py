@@ -55,23 +55,40 @@ through in **rounds of ``T`` rows, one row a token** (``held_rows``,
 ``_held_experts``): round ``r`` takes the sorted slots ``[r T, (r + 1)
 T)``, gathers their tokens' rows, runs the same grouped products on a
 ``[T, width]`` operand with the part of each group that falls into the
-round, and adds the weighted rows to their tokens (a scatter-add of a
-round's rows: the one place the layer has one, chosen on the chip's
-timing, ``_sum_by_token``). The most that can be assigned is ``min(k,
-count)`` rounds, a token choosing an expert once; how many run is the
-router's to say, ``ceil(sum(group_sizes) / T)``, one at the loads a share
-sees as a rule: a loop whose trips the data decide, with a backward pass
-written to match (``_held_experts_bwd``: the same loop, each round's
-forward made again for its pullback). So a step pays for the rows
-assigned, a program holds the round once (``_held_round`` is a
-``jax.jit`` that every layer of one shape shares) and the grouped product
-at one row count, and nothing is traced, compiled or run for a round that
-is not needed. What the rounds summed to carries the name ``HELD_SUM``
-for a caller's ``jax.checkpoint`` to keep. The group sizes of a round sum
-to the rows really assigned, and the grouped product does not visit the
-tiles past them (``megablox`` takes group sizes that sum to fewer rows
-than it is given); what it leaves there is masked out on both sides of
-the experts. Gradients of the expert stacks are summed over rounds in the
+round, and adds the weighted rows to their tokens. The most that can be
+assigned is ``min(k, count)`` rounds, a token choosing an expert once;
+how many run is the router's to say, ``ceil(sum(group_sizes) / T)``, one
+at the loads a share sees as a rule: a loop whose trips the data decide,
+with a backward pass written to match (``_held_experts_bwd``: the same
+loop, each round's forward made again for its pullback). So a step pays
+for the rows assigned, a program holds the round once (``_held_round`` is
+a ``jax.jit`` that every layer of one shape shares) and the grouped
+product at one row count, and nothing is traced, compiled or run for a
+round that is not needed. What the rounds summed to carries the name
+``HELD_SUM`` for a caller's ``jax.checkpoint`` to keep. The group sizes
+of a round sum to the rows really assigned, and the grouped product does
+not visit the tiles past them (``megablox`` takes group sizes that sum to
+fewer rows than it is given); what it leaves there nothing reads.
+
+**Inside a round the same rule holds for what is not a grouped product
+and does not already run at the memory's rate** (a gather of a round's
+``T`` rows does: ``_rows_of_tokens``). The weighted sum by token and its
+twin in the backward pass, the transpose of the gather, were scatter-adds
+of ``T`` rows, which a TPU takes a row at a time, a row of zeros like any
+other, at a twelfth of the memory's rate (PERF.md, PR 41), and a round is
+half empty at the loads a share sees: they are ``ops/sum_by_token.py``'s
+kernel (``_add_to_tokens``, ``_sum_by_token``), which takes the rows in
+the order of their tokens and makes a tile of tokens' sum on the MXU from
+the chunks of sorted rows that hold its own, one grid step a chunk and
+tile that meet and none past the rows assigned. The cotangent of the sum,
+a gather of float32 rows with the weights' products and the weights' own
+gradient on it, goes by the assigned rows **a piece of ``_PIECE`` rows a
+trip** of a loop that the round's count bounds (``_pieces``;
+``move_rows``; ``_add_to_tokens_transposed``), so that no float32 pass
+over ``[T, width]`` is left beside the grouped products but the total
+itself. A program holds the kernel and the piece's body once, a round
+with no row runs no trip, and a full round costs no more than one pass
+over it did. Gradients of the expert stacks are summed over rounds in the
 layer's ``dtype``, where one pass rounds once. No code stands in for the
 other chips or for the exchange with them.
 
@@ -94,6 +111,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.ops import kth_largest as kth_kernel
+from horovod_tpu.ops import sum_by_token as token_sum
 
 # The grouped-product implementation, as the engagement counter names it.
 PRODUCT = "megablox_gmm"
@@ -125,10 +143,13 @@ def _count_trace(n_experts, top_k, held, n_tokens):
             "hvt_moe_layers_traced_total",
             "mixture-of-experts layers traced into compiled programs "
             "(counted per trace, not per execution)",
-            ("experts", "top_k", "product", "held", "round_rows"),
+            ("experts", "top_k", "product", "held", "round_rows",
+             "move_rows"),
         ).labels(experts=str(n_experts), top_k=str(top_k), product=PRODUCT,
                  held=str(held[1] if held else n_experts),
-                 round_rows=str(n_tokens) if held else "all").inc()
+                 round_rows=str(n_tokens) if held else "all",
+                 move_rows=str(move_rows(n_tokens)) if held else "all"
+                 ).inc()
     except Exception:
         pass  # telemetry must never break a trace
 
@@ -349,14 +370,6 @@ def moe_experts(rows, gate, up, down, group_sizes):
     return _grouped_product(hidden, down, group_sizes)
 
 
-def _assigned_rows(rows, group_sizes):
-    """``rows`` with zeros from row ``sum(group_sizes)`` on: the grouped
-    product does not visit those tiles, so what it returns there, and to
-    there in its backward pass, is whatever the memory held."""
-    real = jnp.arange(rows.shape[0]) < jnp.sum(group_sizes)
-    return jnp.where(real[:, None], rows, jnp.zeros((), rows.dtype))
-
-
 def moe_combine(rows, weights, order, inverse):
     """Rows ``[T x k, d]`` in expert order -> ``[T, d]`` float32: each
     token's ``k`` rows times their weights, summed."""
@@ -370,58 +383,141 @@ def moe_combine(rows, weights, order, inverse):
 # at a time. A round's rows belong to tokens in no order and a token may
 # own several of them, so the movement from tokens to rows is a gather and
 # the one from rows to tokens a sum by token, each the other's transpose.
-# Both cost by the rows of the round, not by the ``T x count`` slots.
+# The gather of a round's ``T`` rows runs at the memory's rate; the sums,
+# and the gather of float32 rows that is the weighted sum's transpose, go
+# by the rows the router assigned to the round.
 
-def _sum_by_token(rows, token, n_tokens):
-    """``rows [R, d]`` -> ``[n_tokens, d]``: the sum of the rows of each
-    token, in float32 (a token has at most ``min(k, count)`` rows). A
-    scatter-add, which a TPU takes a row at a time: at 16,384 rows of 1024
-    that is 1.0 ms, where sorting the rows by token and summing the runs
-    with shifted adds took 2.2 and the gather over all ``T x count`` slots
-    4.6 (``benchmarks/moe_rows_to_tokens.py``; PERF.md, PR 32)."""
-    return jax.ops.segment_sum(rows.astype(jnp.float32), token,
-                               num_segments=n_tokens).astype(rows.dtype)
+# The rows a trip of the cotangent's gather takes (``_pieces``).
+_PIECE = 2048
+
+
+def move_rows(n_rows):
+    """The rows of a piece of a round of ``n_rows``: ``_PIECE``, or the
+    largest part of it that divides the round (the same rule as the
+    grouped product's row tile), so that no piece hangs over its end."""
+    return math.gcd(n_rows, _PIECE)
+
+
+def _pieces(assigned, n_rows, body, carry):
+    """``carry`` after ``body(at, live, carry)`` for every piece of a
+    round's ``n_rows`` rows that holds one of its first ``assigned``:
+    ``at`` the piece's first row, ``live [piece, 1]`` which of its rows
+    are assigned. ``ceil(assigned / piece)`` trips, none for a round with
+    no row: ``_rounds``' rule inside a round. A program holds the body
+    once, and whatever lies past the last piece is neither read nor
+    written."""
+    piece = move_rows(n_rows)
+
+    def one(p, carry):
+        live = p * piece + jnp.arange(piece) < assigned
+        return body(p * piece, live[:, None], carry)
+
+    return jax.lax.fori_loop(jnp.int32(0), -(-assigned // piece), one, carry)
+
+
+def _piece_of(at, live):
+    """``x -> x[at:at + piece]``, the piece's rows of a round's array."""
+    return lambda x: jax.lax.dynamic_slice_in_dim(x, at, live.shape[0])
+
+
+def _set_piece(x, at, piece):
+    return jax.lax.dynamic_update_slice_in_dim(x, piece.astype(x.dtype),
+                                               at, 0)
+
+
+def _add_to_tokens(total, rows, weight, where):
+    """``total [T, d]`` float32 with a round's assigned rows, each times
+    its ``weight`` (None: as it is), added to their tokens, in float32 (a
+    token has at most ``min(k, count)`` rows). ``where = (token, plan,
+    assigned)``: the rows' tokens, ``token_sum.plan`` of them and how many
+    are assigned. As a scatter-add a TPU takes it a row at a time whatever
+    is in the row, at a twelfth of the memory's rate at best, and pieces
+    of it under a loop at a thirtieth (PERF.md, PR 41), so it is
+    ``ops/sum_by_token.py``'s kernel: the rows gathered into the order of
+    their tokens, and a tile of tokens' sum made on the MXU from the
+    chunks of sorted rows that hold its own, grid steps past the assigned
+    rows skipped. What the grouped product left in ``rows`` past the
+    assigned is not read. Sorting the rows by token and summing the runs
+    with shifted adds took twice a whole round's scatter-add and a gather
+    over all ``T x count`` slots four times
+    (``benchmarks/moe_rows_to_tokens.py``; PERF.md, PR 32)."""
+    return token_sum.sum_by_token(rows, where[1], weight=weight, total=total)
+
+
+def _sum_by_token(rows, where):
+    """``rows [T, d]`` -> ``[T, d]``: the sum of a round's assigned rows by
+    their token, in float32 (``_add_to_tokens`` onto zeros)."""
+    return token_sum.sum_by_token(rows, where[1])
 
 
 @jax.custom_vjp
-def _rows_of_tokens(x, token):
+def _rows_of_tokens(x, where):
     """``x [T, d]`` -> ``x[token] [T, d]``, the rows of a round (one a
-    token, so as many as there are tokens). Its transpose is
-    ``_sum_by_token``, written out so that it sums in float32 whatever
-    ``x`` is in."""
-    return _rows(x, token)
+    token, so as many as there are tokens), in one pass: a gather runs at
+    the memory's rate, and the zeros that pieces would fill cost what it
+    does. Past the assigned they are the rows of tokens nobody assigned,
+    which the grouped product does not visit. Its transpose is the sum by
+    token of the assigned (``_sum_by_token``), written out because a loop
+    whose trips the data decide has no reverse mode, and so that it sums
+    in float32 whatever ``x`` is in."""
+    return _rows(x, where[0])
 
 
 _rows_of_tokens.defvjp(
-    lambda x, token: (_rows(x, token), token),
-    lambda token, g: (_sum_by_token(g, token, g.shape[0]), None))
+    lambda x, where: (_rows(x, where[0]), where),
+    lambda where, g: (_sum_by_token(g, where), None))
+
+
+def _add_to_tokens_transposed(g, rows, weight, where):
+    """What ``g [T, d]``, the cotangent of ``_add_to_tokens``' result,
+    gives back to ``rows`` (in their dtype) and to ``weight``: a piece of
+    ``g[token]`` at a time, zeros past the assigned rows."""
+    token, _, assigned = where
+
+    def take(at, live, grads):
+        of = _piece_of(at, live)
+        mine = _rows(g, of(token))
+        to_weight = jnp.sum(mine * of(rows).astype(jnp.float32), axis=-1)
+        return (_set_piece(grads[0], at, jnp.where(
+                    live, mine * of(weight)[:, None], 0.0)),
+                _set_piece(grads[1], at, jnp.where(live[:, 0], to_weight,
+                                                   0.0)))
+
+    return _pieces(assigned, rows.shape[0], take,
+                   (jnp.zeros_like(rows), jnp.zeros_like(weight)))
 
 
 @jax.jit
 def _held_round(tokens, weights, stacks, route, r):
     """Round ``r`` of a share: the sorted slots ``[r T, (r + 1) T)`` of
     ``order``, their tokens' rows through the held experts (``stacks =
-    (gate, up, down)``) and weighted: ``(rows [T, width] float32, their
-    tokens [T])``. ``route = (order, group_sizes)`` as ``moe_route`` made
-    them; the round's group sizes are the part of each expert's group that
-    falls into it. A ``jax.jit`` of its own so that a program holds one
-    traced and lowered copy of it, however many layers call it."""
+    (gate, up, down)``): ``((rows [T, width], their weights [T] float32),
+    where)``, ``where = (their tokens [T], token_sum.plan of them, how
+    many of the rows are assigned)``. ``route = (order, group_sizes)`` as
+    ``moe_route`` made them; the round's group sizes are the part of each
+    expert's group that falls into it. The grouped products take all ``T``
+    rows in one call and visit the assigned; what they leave in the rows
+    past them is whatever the memory held, which nothing reads: the sums
+    on either side select the assigned rows by their count. A ``jax.jit``
+    of its own so that a program holds one traced and lowered copy of it,
+    however many layers call it."""
     order, group_sizes = route
     n_tokens, count = weights.shape
     ends = jnp.cumsum(group_sizes) - r * n_tokens
     sizes = jnp.diff(jnp.clip(ends, 0, n_tokens), prepend=0)
+    assigned = jnp.sum(sizes)
     slots = jax.lax.dynamic_slice(order, (r * n_tokens,), (n_tokens,))
     token, expert = slots // count, slots % count
     with jax.named_scope("moe_dispatch"):
-        rows = _assigned_rows(_rows_of_tokens(tokens, token), sizes)
+        where = token, token_sum.plan(token, assigned), assigned
+        rows = _rows_of_tokens(tokens, where)
     with jax.named_scope("moe_experts"):
         rows = moe_experts(rows, *stacks, sizes)
     with jax.named_scope("moe_combine"):
-        rows = _assigned_rows(rows, sizes).astype(jnp.float32)
         weight = jnp.sum(jnp.where(
             expert[:, None] == jnp.arange(count),
-            _rows_of_tokens(weights, token), 0.0), axis=-1)
-        return rows * weight[:, None], token
+            _rows(weights, token), 0.0), axis=-1)
+    return (rows, weight), where
 
 
 def _rounds(route, n_tokens, body, carry):
@@ -439,15 +535,15 @@ def _rounds(route, n_tokens, body, carry):
 def _held_experts(tokens, weights, stacks, route):
     """The held experts' part of the layer's sum, ``[T, width]`` float32,
     in rounds of ``T`` rows (``held_rows``, ``_rounds``), each round's
-    weighted rows added to their tokens (the sum by token, in place). A
+    weighted rows added to their tokens (``_add_to_tokens``, in place). A
     loop whose trips the data decide has no reverse mode of its own, so
     the backward pass is written here: the same loop, each round's forward
     made again for its pullback (a round's residuals then live for one
     trip, where one pass kept those of all ``T x min(k, count)`` rows)."""
     def one(r, total):
-        rows, token = _held_round(tokens, weights, stacks, route, r)
+        (rows, weight), where = _held_round(tokens, weights, stacks, route, r)
         with jax.named_scope("moe_combine"):
-            return total.at[token].add(rows, mode="promise_in_bounds")
+            return _add_to_tokens(total, rows, weight, where)
 
     return _rounds(route, tokens.shape[0], one,
                    jnp.zeros(tokens.shape, jnp.float32))
@@ -457,11 +553,11 @@ def _held_experts_bwd(res, g):
     *of, route = res
 
     def one(r, grads):
-        _, pull, token = jax.vjp(
+        out, pull, where = jax.vjp(
             lambda *of: _held_round(*of, route, r), *of, has_aux=True)
         with jax.named_scope("moe_combine"):
-            rows = _rows(g, token)
-        return jax.tree.map(jnp.add, grads, pull(rows))
+            back = _add_to_tokens_transposed(g, *out, where)
+        return jax.tree.map(jnp.add, grads, pull(back))
 
     return *_rounds(route, g.shape[0], one,
                     jax.tree.map(jnp.zeros_like, tuple(of))), None

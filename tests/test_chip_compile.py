@@ -24,6 +24,7 @@ from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops import gated_delta_rule as gdr
 from horovod_tpu.ops import head_norm
 from horovod_tpu.ops import kth_largest as kth
+from horovod_tpu.ops import sum_by_token as token_sum
 from horovod_tpu.parallel.sequence import ring_attention
 
 
@@ -54,6 +55,7 @@ def compiled_kernel(monkeypatch):
     monkeypatch.setattr(conv, "_interpret", lambda: False)
     monkeypatch.setattr(kth, "_interpret", lambda: False)
     monkeypatch.setattr(head_norm, "_interpret", lambda: False)
+    monkeypatch.setattr(token_sum, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -259,11 +261,15 @@ def test_held_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices,
     stacks, so eight grouped products as Pallas calls (two in the forward
     loop over the rounds; in the backward loop two to make a round's
     forward again and four for its pullback), every one over a round of
-    16384 rows and none over the 16384 x 8 slots; the scatters are the
-    products' bookkeeping and a round's sums by token. The ninth Pallas
-    call is the router's ``k``-th score under ``moe_route`` (steered here
-    as a TPU would choose): no ``top_k`` is left in the program, nothing
-    of ``T x k x E`` elements, and one sort, of the ``T x 8`` slots."""
+    16384 rows and none over the 16384 x 8 slots; a round's sums by
+    token are a Pallas call each (``ops/sum_by_token.py``: the combine's
+    in the forward loop, the dispatch's transpose in the backward one),
+    under those two scopes, so the scatters left are the products'
+    bookkeeping and the transpose of the gather of a round's weights. The
+    eleventh Pallas call is the router's ``k``-th score under
+    ``moe_route`` (steered here as a TPU would choose): no ``top_k`` is
+    left in the program, nothing of ``T x k x E`` elements, and one sort
+    beside those of a round's ``T`` tokens, of the ``T x 8`` slots."""
     tokens, d, k, held = 2 * 8192, 4096, 22, (0, 8)
     monkeypatch.setattr(kth, "serves", lambda *shape: True)
     layer = moe.MoEMlp(512, 2688, k, dtype=jnp.bfloat16, score="sigmoid",
@@ -281,10 +287,14 @@ def test_held_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices,
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         variables["params"], x, variables["buffers"]).compile().as_text()
-    assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) == 9
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) == 11
     kth_calls = [line for line in text.splitlines()
                  if "tpu_custom_call" in line and "hvt_moe_kth" in line]
     assert len(kth_calls) == 1 and "/moe_route/" in kth_calls[0]
+    sums = [line for line in text.splitlines()
+            if "tpu_custom_call" in line and "hvt_moe_sum_by_token" in line]
+    assert len(sums) == 2 and "/moe_combine/" in sums[0] and (
+        "/moe_dispatch/" in sums[1]), sums
     assert "topk" not in text.lower() and f"[{tokens},{k},512]" not in text
     sorts = re.findall(r"= \(?\w+\[([\d,]*)\][^=]* sort\(", text)
     # (the others are over a round's T rows: the sums by token)
@@ -296,7 +306,7 @@ def test_held_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices,
     for line in text.splitlines():
         if " scatter(" in line:
             shape = re.search(r"= \w+\[([\d,]*)\]", line).group(1)
-            assert shape in (f"{tokens},1024", f"{tokens},8") or (
+            assert shape == f"{tokens},8" or (
                 "," not in shape and int(shape) <= 2 * 8 + tiles), line
 
 

@@ -4,6 +4,7 @@ reference (``chipbench/reference/olmoe.py``), and compiled execution on a
 dp×ep mesh. Float32 and tiny sizes: the Pallas grouped product runs in
 interpret mode on the CPU."""
 
+import collections
 import math
 import re
 
@@ -215,7 +216,8 @@ def test_traced_layers_are_counted():
     def count():
         m = metrics.registry().get("hvt_moe_layers_traced_total")
         return m.labels(experts="8", top_k="2", product=moe.PRODUCT,
-                        held="8", round_rows="all").value if m else 0.0
+                        held="8", round_rows="all",
+                        move_rows="all").value if m else 0.0
 
     layer, params, h = _layer(8, 2, skewed=False)
     before = count()
@@ -541,17 +543,129 @@ def test_held_layer_drops_nothing_under_the_most_uneven_routing(
 
 def test_rows_past_the_assigned_are_masked_on_both_sides():
     """What the grouped product leaves in the tiles it does not visit is
-    not read: rows past ``sum(group_sizes)`` come out as zeros, and so
-    does the gradient that goes back to them, whatever is in them."""
+    not read: of a round's rows only the first ``sum(group_sizes)`` are
+    added to their tokens, whatever is in the others, and so it is with
+    the gradient that comes back to them (the dispatch's transposed sum);
+    and the cotangent that goes to the grouped product is zero past
+    them."""
+    token = jnp.array([3, 0, 3, 5, 1, 2])
+    where = token, moe.token_sum.plan(token, 2), 2
+    np.testing.assert_array_equal(np.asarray(where[1].order)[:2], [1, 0])
     rows = jnp.full((6, 3), jnp.nan).at[:2].set(1.0)
-    sizes = jnp.array([1, 1, 0], jnp.int32)
+    weight = jnp.full((6,), jnp.nan).at[:2].set(2.0)
+    want = np.zeros((6, 3))
+    want[[3, 0]] = 1.0
     np.testing.assert_array_equal(
-        np.asarray(moe._assigned_rows(rows, sizes)),
-        np.concatenate([np.ones((2, 3)), np.zeros((4, 3))]))
-    grad = jax.grad(lambda r: jnp.sum(moe._assigned_rows(r, sizes)
-                                      * jnp.where(jnp.isnan(r), 0.0, r)))(
-        jnp.ones((6, 3)))
-    np.testing.assert_array_equal(np.asarray(grad)[2:], 0.0)
+        np.asarray(moe._sum_by_token(rows, where)), want)
+    np.testing.assert_array_equal(np.asarray(moe._add_to_tokens(
+        jnp.zeros((6, 3)), rows, weight, where)), 2 * want)
+    x = jnp.arange(18.0).reshape(6, 3)
+    got, pull = jax.vjp(lambda x: moe._rows_of_tokens(x, where), x)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(x)[token])
+    np.testing.assert_array_equal(np.asarray(pull(rows)[0]), want)
+    d_rows, d_weight = moe._add_to_tokens_transposed(
+        jnp.ones((6, 3)), rows, weight, where)
+    np.testing.assert_array_equal(
+        np.asarray(d_rows), np.concatenate([np.full((2, 3), 2.0),
+                                            np.zeros((4, 3))]))
+    np.testing.assert_array_equal(np.asarray(d_weight), [3, 3, 0, 0, 0, 0])
+
+
+def _one_pass_held_experts(tokens, weights, stacks, route, rounds):
+    """The held experts' sum as it was made until PR 41, every movement
+    one pass over a round's ``T`` rows and plain ``jax.numpy`` that JAX
+    differentiates by itself: a gather of ``T`` rows, masks on both sides
+    of the experts, the weights' product over ``[T, width]``, one
+    scatter-add of ``T`` rows. ``rounds`` is read off the route by the
+    test, where the layer's loop reads it on the device."""
+    order, group_sizes = route
+    n_tokens, count = weights.shape
+    total = jnp.zeros(tokens.shape, jnp.float32)
+    for r in range(rounds):
+        ends = jnp.cumsum(group_sizes) - r * n_tokens
+        sizes = jnp.diff(jnp.clip(ends, 0, n_tokens), prepend=0)
+        real = (jnp.arange(n_tokens) < jnp.sum(sizes))[:, None]
+        slots = order[r * n_tokens:(r + 1) * n_tokens]
+        token, expert = slots // count, slots % count
+        rows = moe.moe_experts(jnp.where(real, tokens[token], 0.0),
+                               *stacks, sizes)
+        weight = jnp.sum(jnp.where(expert[:, None] == jnp.arange(count),
+                                   weights[token], 0.0), axis=-1)
+        total = total.at[token].add(
+            jnp.where(real, rows, 0.0).astype(jnp.float32)
+            * weight[:, None])
+    return total
+
+
+@pytest.mark.parametrize("assigned", [0, 5, 16, 32, 45], ids=[
+    "no-row", "under-a-piece", "whole-pieces", "every-row", "two-rounds"])
+def test_pieces_are_the_one_pass_movement(assigned, monkeypatch):
+    """A round's movements by pieces of the assigned rows (here 8 rows
+    of a round of 32) against the one pass over all its rows that they
+    replace: the gather of the tokens' rows and its transposed sum, the
+    weighted sum by token and what its cotangent gives back to the rows
+    and to the weights; then the held experts' whole sum and its
+    gradients in tokens, weights and stacks, over no round, one and two.
+    Float32 on the CPU: the gathers equal to the last bit, the sums to a
+    rounding of the last (a token's rows are added in the order of the
+    sort by token, not in the rows')."""
+    monkeypatch.setattr(moe, "_PIECE", 8)
+    monkeypatch.setattr(moe, "_held_round",
+                        jax.jit(moe._held_round.__wrapped__))
+    n_tokens, count, width = 32, 3, 8
+    assert moe.move_rows(n_tokens) == 8
+    keys = jax.random.split(jax.random.key(assigned), 6)
+    chosen = jnp.zeros(n_tokens * count, bool).at[jax.random.permutation(
+        keys[0], n_tokens * count)[:assigned]].set(True).reshape(
+            n_tokens, count)
+    route = (moe._slots_by_expert(chosen),
+             jnp.sum(chosen, axis=0, dtype=jnp.int32))
+    tokens = jax.random.normal(keys[1], (n_tokens, width))
+    weights = jnp.where(chosen, jax.random.uniform(keys[2], chosen.shape),
+                        0.0)
+    stacks = tuple(jax.random.normal(k, shape) for k, shape in zip(
+        keys[3:], [(count, width, 12)] * 2 + [(count, 12, width)]))
+    same = lambda got, want: jax.tree.map(
+        lambda a, b: np.testing.assert_array_equal(np.asarray(a),
+                                                   np.asarray(b)), got, want)
+    near = lambda got, want: jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6), got, want)
+
+    first = min(assigned, n_tokens)           # the first round's movements
+    token = route[0][:n_tokens] // count
+    where = token, moe.token_sum.plan(token, first), first
+    by_token = np.asarray(where[1].order)[:first]   # the assigned, by token
+    assert sorted(by_token) == list(range(first))
+    assert (np.diff(np.asarray(token)[by_token]) >= 0).all()
+    real = (jnp.arange(n_tokens) < first)[:, None]
+    weight = jax.random.uniform(keys[2], (n_tokens,))
+    g = jax.random.normal(keys[1], (n_tokens, width)) + 1.0
+    got, pull = jax.vjp(lambda x: moe._rows_of_tokens(x, where), tokens)
+    want, plain = jax.vjp(lambda x: jnp.where(real, x[token], 0.0), tokens)
+    same(got[:first], want[:first])
+    near(pull(g), plain(g))
+    near(moe._sum_by_token(g, where), plain(g)[0])
+    add = lambda rows, weight: jnp.ones_like(rows).at[token].add(
+        jnp.where(real, rows * weight[:, None], 0.0))
+    want, plain = jax.vjp(add, tokens, weight)
+    near(moe._add_to_tokens(jnp.ones_like(tokens), tokens, weight, where),
+         want)
+    same(moe._add_to_tokens_transposed(g, tokens, weight, where), plain(g))
+
+    rounds = -(-assigned // n_tokens)
+    cot = jax.random.normal(keys[5], (n_tokens, width))
+    got = jax.jit(jax.value_and_grad(lambda *of: jnp.sum(
+        moe._held_experts(*of, route) * cot), argnums=(0, 1, 2)))(
+            tokens, weights, stacks)
+    want = jax.value_and_grad(lambda *of: jnp.sum(_one_pass_held_experts(
+        *of, route, rounds) * cot), argnums=(0, 1, 2))(
+            tokens, weights, stacks)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        _close(a, b, "the held experts' sum and its gradients")
+    if assigned == 0:
+        assert not any(np.asarray(leaf).any()
+                       for leaf in jax.tree.leaves(got))
 
 
 @pytest.mark.parametrize("crowd", [None, 2], ids=["even", "one-share-full"])
@@ -600,28 +714,31 @@ def test_the_shares_of_the_experts_add_up(crowd):
 
 def test_held_layer_gradient_program_scatters_a_rounds_rows_alone():
     """Routing weights, counts, the sort and the mask differentiate
-    without a scatter-add, as in the layer that holds every expert. What
-    is left beside the grouped product's bookkeeping is a round's sum by
-    token (``_sum_by_token``: combine forward, dispatch backward and the
-    weights' gradient), which adds a round's rows into ``[T, width]`` and
-    was chosen on the chip's timing: never anything over the ``T x
-    count`` slots."""
+    without a scatter-add, as in the layer that holds every expert, and
+    since PR 41 a round's sums by token are no scatter-add either
+    (``ops/sum_by_token.py``: a gather into the tokens' order and
+    products). What is left beside the grouped product's bookkeeping is
+    the transpose of the gather of a round's weights, ``T x count``
+    numbers in one pass under ``moe_combine``: nothing adds rows of the
+    layer's width, a piece's, a round's or the ``T x count`` slots'."""
     layer, params, buffers, h = _latent_layer((4, 4))
 
     def loss(params, h):
         out, _ = layer.apply({"params": params, "buffers": buffers}, h)
         return jnp.sum(out ** 2)
 
-    found = _scatters(jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        params, h).compile())
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, h).compile()
+    found = _scatters(compiled)
     sums = [(elements, name) for elements, name in found
             if "/jit(gmm)/" not in name and "/jit(tgmm)/" not in name]
-    assert sums
-    for elements, name in sums:
-        assert "/moe_dispatch/" in name or "/moe_combine/" in name, name
-        assert elements <= h.shape[0] * 8, (elements, name)   # T x latent
-    for elements, name in set(found) - set(sums):
-        assert elements <= 4 + h.shape[0] * 3, (elements, name)
+    assert len(sums) == 1, sums
+    for elements, name in found:
+        if (elements, name) in sums:
+            assert "/moe_combine/" in name, name
+            assert elements == h.shape[0] * 4, (elements, name)  # T x count
+        else:
+            assert elements <= 4 + h.shape[0] * 3, (elements, name)
 
 
 @pytest.mark.parametrize("tokens, width", [(32, 8), (44, 8), (7, 3),
@@ -694,8 +811,10 @@ def _grouped_products(jaxpr):
     ``gmm`` and ``tgmm``, each a ``jax.jit``) in ``jaxpr`` and what it
     calls, a traced function counted once however many equations call it
     (``jax.jit`` hands every caller of one function at one shape the same
-    jaxpr), and the primitives met on the way."""
-    found, primitives, seen = [], set(), set()
+    jaxpr), and how often each primitive was met on the way (a kernel's
+    own body left out: its ``cond`` is ``pl.when``, a grid step's, not a
+    branch of the program)."""
+    found, primitives, seen = [], collections.Counter(), set()
 
     def walk(jaxpr):
         jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
@@ -703,10 +822,12 @@ def _grouped_products(jaxpr):
             return
         seen.add(id(jaxpr))
         for eqn in jaxpr.eqns:
-            primitives.add(eqn.primitive.name)
+            primitives[eqn.primitive.name] += 1
             if eqn.params.get("name") in ("gmm", "tgmm"):
                 found.append((eqn.params["name"], sorted(
                     {n for v in eqn.invars[:2] for n in v.aval.shape})))
+                continue
+            if eqn.primitive.name == "pallas_call":
                 continue
             for value in eqn.params.values():
                 for sub in value if isinstance(value, (tuple, list)) else (
@@ -728,16 +849,24 @@ def test_held_layer_holds_its_round_once(held, k, act, stacks):
     had, a stack each in the forward program; in the gradient that, the
     round made again in the backward loop (a stack each) and its pullback
     (two a stack). The rounds are a ``while`` that the router's count
-    bounds, and there is no ``cond`` and no ``scan``."""
+    bounds, and there is no ``cond`` and no ``scan``. A round's sums by
+    token are one kernel each (``ops/sum_by_token.py``, whose grid steps
+    past the assigned rows are skipped) and the cotangent's rows a loop
+    over its pieces, each held once: in the forward program the rounds'
+    loop, the weighted sum's kernel and no scatter-add; in the gradient
+    the two loops over the rounds and the pieces' in the backward one,
+    the two sums' kernels, and one scatter-add, the transpose of the
+    weights' gather."""
     tokens = 40
     layer = MoEMlp(8, 12, k, dtype=jnp.float32, expert_act=act, held=held,
                    **({"score": "sigmoid"} if act == "relu2" else {}))
     h = jax.random.normal(jax.random.key(0), (tokens, 16))
     variables = layer.init(jax.random.key(1), h)
     forward = lambda h: layer.apply(variables, h)[0]
-    for program, products in (
-            (forward, stacks),
-            (jax.grad(lambda h: jnp.sum(forward(h) ** 2)), 4 * stacks)):
+    for program, products, loops in (
+            (forward, stacks, (1, 1, 0)),
+            (jax.grad(lambda h: jnp.sum(forward(h) ** 2)), 4 * stacks,
+             (3, 2, 1))):
         found, primitives = _grouped_products(jax.make_jaxpr(program)(h))
         assert len(found) == products, found
         for _, sizes in found:
@@ -745,6 +874,8 @@ def test_held_layer_holds_its_round_once(held, k, act, stacks):
             assert not [n for n in sizes if n > tokens and n % tokens == 0]
         assert "while" in primitives
         assert "cond" not in primitives and "scan" not in primitives
+        assert (primitives["while"], primitives["pallas_call"],
+                primitives["scatter-add"]) == loops
 
 
 def test_layer_that_holds_every_expert_has_no_loop():
@@ -756,7 +887,7 @@ def test_layer_that_holds_every_expert_has_no_loop():
     for program in (forward, jax.grad(lambda h: jnp.sum(forward(h) ** 2))):
         found, primitives = _grouped_products(jax.make_jaxpr(program)(h))
         assert found and all(2 * h.shape[0] in sizes for _, sizes in found)
-        assert not primitives & {"cond", "while", "scan"}
+        assert not set(primitives) & {"cond", "while", "scan"}
 
 
 def test_held_layer_names_add_no_operation(monkeypatch):
@@ -791,18 +922,23 @@ def test_held_layer_names_add_no_operation(monkeypatch):
 def test_held_layers_are_counted_by_what_they_hold():
     from horovod_tpu import metrics
 
-    def count(held, round_rows):
+    def count(held, round_rows, move_rows):
         m = metrics.registry().get("hvt_moe_layers_traced_total")
         return m.labels(experts="8", top_k="3", product=moe.PRODUCT,
-                        held=held, round_rows=round_rows).value if m else 0.0
+                        held=held, round_rows=round_rows,
+                        move_rows=move_rows).value if m else 0.0
 
     layer, params, buffers, h = _latent_layer((4, 4))
-    rows = str(h.shape[0])          # a round is one row a token
-    before = count("4", rows), count("8", "all")
+    # a round is one row a token; a piece of it the largest part of 2,048
+    # rows that divides it: 8 of 40, the whole of 2,048 at a cell's 16,384
+    rows, piece = str(h.shape[0]), str(moe.move_rows(h.shape[0]))
+    assert (rows, piece) == ("40", "8")
+    assert moe.move_rows(16384) == moe._PIECE == 2048
+    before = count("4", rows, piece), count("8", "all", "all")
     jax.jit(lambda p, h: layer.apply(
         {"params": p, "buffers": buffers}, h)[0]).lower(params, h)
-    assert (count("4", rows), count("8", "all")) == (before[0] + 1,
-                                                     before[1])
+    assert (count("4", rows, piece), count("8", "all", "all")) == (
+        before[0] + 1, before[1])
 
 
 @pytest.mark.parametrize("field, value, match", [
