@@ -445,14 +445,14 @@ class GPT(nn.Module):
     def __call__(self, tokens, return_hidden: bool = False,
                  return_aux: bool = False):
         """Logits by default; ``return_hidden=True`` returns the final
-        (post-ln) hidden states instead, for memory-bounded losses that
-        fuse the vocab projection (``ops.losses
-        .softmax_cross_entropy_fused`` with the embedding, or with
-        ``params["lm_head"]`` of an untied model) — the
-        [batch, seq, vocab] logits tensor is then never materialized.
-        ``return_aux=True`` returns ``(that, aux)``: the expert layers'
-        auxiliary losses summed over the layers, each unweighted
-        (``{"load_balance", "router_z"}``; ``{}`` for a dense model)."""
+        (post-ln) hidden states instead, for ``ops.losses
+        .softmax_cross_entropy_fused`` (with the embedding, or with an untied
+        model's ``params["lm_head"]``): no [batch, seq, vocab] logits, at
+        most [batch, chunk, vocab]. That loss makes both gradients beside each
+        chunk's logits and keeps them for the backward pass, so it is
+        differentiable once, in reverse mode. ``return_aux=True`` returns
+        ``(that, aux)``: the expert layers' auxiliary losses summed over the
+        layers unweighted (``{"load_balance", "router_z"}``; dense: ``{}``)."""
         cfg = self.cfg
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[-1]), tokens.shape)
