@@ -1,6 +1,6 @@
 """The causal depthwise convolution's two Pallas kernels alone, on the chip
 (``ops/causal_conv.py``), beside the plain ``jax.numpy`` body
-(``ssm.causal_conv_plain``), at the two shapes the benchmark's cells run:
+(``causal_conv_plain`` there), at the two shapes the benchmark's cells run:
 ``qwen3next-s8192``'s ``[2, 8192, 8192]`` without a bias and
 ``nemotron3s-s8192``'s ``[2, 8192, 1280]`` with one, bf16, four taps.
 
@@ -99,8 +99,7 @@ def measure(shape, taps, blocks, calls):
     import jax
     from chip_smoke import rel_l2
 
-    from horovod_tpu.models import ssm
-    from horovod_tpu.ops import causal_conv as kernels
+    from horovod_tpu.ops import causal_conv as conv_op
 
     args, g = _inputs(shape, taps)
     b, s, c, _ = shape
@@ -108,12 +107,12 @@ def measure(shape, taps, blocks, calls):
     out = {"shape": list(shape), "taps": taps,
            "least_ms_forward": 1e3 * 2 * array / HBM_BYTES_PER_S,
            "least_ms_forward_and_backward": 1e3 * 5 * array / HBM_BYTES_PER_S}
-    paths = [("plain", ssm.causal_conv_plain)]
+    paths = [("plain", conv_op.causal_conv_plain)]
     for block in blocks:
         named = ({} if block == "derived" else dict(zip(
             ("rows", "lanes", "sub"), (int(n) for n in block.split("x")))))
         paths.append((f"kernels_{block}", lambda *a, named=named:
-                      kernels.causal_conv(*a, **named)))
+                      conv_op.causal_conv_kernels(*a, **named)))
     first = None
     for name, conv in paths:
         both = jax.jit(lambda *a, conv=conv: (lambda o, vjp: (o, *vjp(g)))(

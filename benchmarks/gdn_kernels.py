@@ -10,7 +10,7 @@ no inverse at all (``T = I - N``: a wrong program, timed only) and with
 other numbers of value heads a grid step (``--heads-a-step``). Also whether
 Mosaic honours the float32 precision: one ``[128, 128]`` system inverted in
 a kernel at ``HIGHEST`` and at the default against numpy's float64 inverse,
-beside ``gdn.unit_lower_inverse`` as XLA runs it.
+beside the plain body's ``unit_lower_inverse`` as XLA runs it.
 
 A microbenchmark: the step's own cost is a traced run of the cell
 (``python3 -m chipbench.run --workload qwen3next-s8192 --trace 1``).
@@ -33,14 +33,15 @@ def _inputs(shape):
     import jax.numpy as jnp
     import numpy as np
 
-    from horovod_tpu.models import gdn
+    from horovod_tpu.ops import head_norm as norm_op
 
     b, s, h_k, h_v, d_k, d_v = shape
     rng = np.random.RandomState(0)
     normal = lambda *dims: jnp.asarray(rng.normal(size=dims), jnp.float32)
     low = lambda t: t.astype(jnp.bfloat16)
-    q = low(gdn.l2_normalise(normal(b, s, h_k, d_k)) * d_k ** -0.5)
-    k = low(gdn.l2_normalise(normal(b, s, h_k, d_k)))
+    unit = lambda x: norm_op.l2_norm(x, eps=1e-6)
+    q = low(unit(normal(b, s, h_k, d_k)) * d_k ** -0.5)
+    k = low(unit(normal(b, s, h_k, d_k)))
     v, do = low(normal(b, s, h_v, d_v)), low(normal(b, s, h_v, d_v))
     g = -jnp.exp(normal(h_v)) * jax.nn.softplus(normal(b, s, h_v) + 1.0) / 16
     beta = jax.nn.sigmoid(normal(b, s, h_v))
@@ -60,7 +61,6 @@ def _ms(call, args, calls):
 def time_paths(shape, chunk, calls, heads_a_step):
     import jax
 
-    from horovod_tpu.models import gdn
     from horovod_tpu.ops import gated_delta_rule as kernels
 
     args, do = _inputs(shape)
@@ -72,20 +72,21 @@ def time_paths(shape, chunk, calls, heads_a_step):
         return {"ms_forward": _ms(jax.jit(rule), args, calls),
                 "ms_forward_and_backward": _ms(step, args, calls)}
 
-    out = {"plain": both(functools.partial(gdn.gated_delta_rule_plain,
+    out = {"plain": both(functools.partial(kernels.gated_delta_rule_plain,
                                            chunk=chunk))}
     as_it_is = kernels._HEADS_A_STEP
     for heads in heads_a_step:      # value heads a grid step
         kernels._HEADS_A_STEP = heads
         jax.clear_caches()
         out[f"kernels_{heads}_heads_a_step"] = both(functools.partial(
-            kernels.gated_delta_rule, chunk=chunk))
+            kernels.gated_delta_rule_kernels, chunk=chunk))
     kernels._HEADS_A_STEP = as_it_is
     jax.clear_caches()
     for name, precision in (("kernels", highest),
                             ("kernels_inverse_at_default", default)):
         out[name] = both(functools.partial(
-            kernels.gated_delta_rule, chunk=chunk, precision=precision))
+            kernels.gated_delta_rule_kernels, chunk=chunk,
+            precision=precision))
     # what is left without the inverse: T = I - N, timed and not compared
     whole = kernels._unit_lower_inverse
     kernels._unit_lower_inverse = lambda n, row, col, plan: (
@@ -93,7 +94,7 @@ def time_paths(shape, chunk, calls, heads_a_step):
     jax.clear_caches()
     try:
         out["kernels_without_inverse"] = both(functools.partial(
-            kernels.gated_delta_rule, chunk=chunk))
+            kernels.gated_delta_rule_kernels, chunk=chunk))
     finally:
         kernels._unit_lower_inverse = whole
         jax.clear_caches()
@@ -108,7 +109,7 @@ def inverse_precision(c=128):
     import numpy as np
     from jax.experimental import pallas as pl
 
-    from horovod_tpu.models import gdn
+    from horovod_tpu.ops import _pallas
     from horovod_tpu.ops import gated_delta_rule as kernels
 
     rng = np.random.RandomState(1)
@@ -118,12 +119,12 @@ def inverse_precision(c=128):
     want = np.linalg.inv(np.eye(c) + system)
     err = lambda got: float(np.linalg.norm(np.asarray(got, np.float64) - want)
                             / np.linalg.norm(want))
-    out = {"xla_unit_lower_inverse": err(jax.jit(gdn.unit_lower_inverse)(
+    out = {"xla_unit_lower_inverse": err(jax.jit(kernels.unit_lower_inverse)(
         jnp.asarray(system, jnp.float32)))}
     for name in ("HIGHEST", "DEFAULT"):
         plan = kernels._Plan(c, 1, 1, c, c, 1, jnp.dtype(jnp.float32),
                              getattr(jax.lax.Precision, name),
-                             kernels._interpret())
+                             _pallas.interpret())
 
         def body(n_ref, t_ref, plan=plan):
             row, col = kernels._positions(c)

@@ -1,6 +1,6 @@
 """The Gated DeltaNet mixer's per-head norms alone, on the chip: the four
 Pallas kernels of ``ops/head_norm.py`` beside the plain ``jax.numpy`` bodies
-(``gdn.gated_head_norm_plain``, ``gdn.l2_normalise_plain``), at the shapes
+(``gated_norm_plain``, ``l2_norm_plain``), at the shapes
 ``qwen3next-s8192`` runs them: ``RMSNorm(o) w silu(z)`` over 32 heads of
 128, ``[2, 8192, 4096]``, and the L2 norm over 16 heads of 128, ``[2, 8192,
 2048]``, bf16, flat ``[b, s, H d]`` in and out, which is what the mixer's
@@ -45,7 +45,6 @@ SHAPES = {"gated": ((2, 8192, 32, 128), 3, 8), "l2": ((2, 8192, 16, 128), 2, 5)}
 
 
 def _paths(norm, dim, blocks):
-    from horovod_tpu.models import gdn
     from horovod_tpu.ops import head_norm as kernels
 
     def named(block):
@@ -53,12 +52,13 @@ def _paths(norm, dim, blocks):
             ("rows", "lanes", "sub"), (int(n) for n in block.split("x"))))
 
     if norm == "gated":
-        plain = lambda o, z, w: gdn.gated_head_norm_plain(o, z, w, EPS)
-        kernel = lambda block: lambda o, z, w: kernels.gated_norm(
+        plain = lambda o, z, w: kernels.gated_norm_plain(o, z, w, eps=EPS)
+        kernel = lambda block: lambda o, z, w: kernels.gated_norm_kernels(
             o, z, w, eps=EPS, **named(block))
     else:
-        plain = lambda x: gdn.l2_normalise_plain(x, dim, dim ** -0.5)
-        kernel = lambda block: lambda x: kernels.l2_norm(
+        plain = lambda x: kernels.l2_norm_plain(
+            x, dim, eps=EPS, scale=dim ** -0.5)
+        kernel = lambda block: lambda x: kernels.l2_norm_kernels(
             x, dim, eps=EPS, scale=dim ** -0.5, **named(block))
     return [("plain", plain)] + [(f"kernels_{b}", kernel(b)) for b in blocks]
 

@@ -60,7 +60,7 @@ SHAPES = {
 
 def route_parent(h, router, k, *, score, bias, scale, held, renormalise):
     """``moe_route`` as it was until PR 37 (the formulation
-    ``tests/test_moe.py`` keeps too): ``top_k``, the ``[T, k, E]`` mask,
+    ``tests/test_moe_held.py`` keeps too): ``top_k``, the ``[T, k, E]`` mask,
     a stable ``argsort`` of every slot and the ``argsort`` of that."""
     import jax
     import jax.numpy as jnp

@@ -17,8 +17,8 @@ a router whose product is left at the TPU's default precision (one bf16
 pass), the chosen weights not renormalised, q and k not L2-normalised,
 ``beta`` left out, the decay left out, the gate applied before the
 mixer's norm, and the rotary over the whole head. (On the chip the per-head
-norms are the Pallas kernels of ``ops/head_norm.py``; ``gdn.l2_normalise``
-and ``gdn.gated_head_norm`` are the names the mixer calls them by, so the
+norms are the Pallas kernels of ``ops/head_norm.py``; ``l2_norm`` and
+``gated_norm`` there are the names the mixer calls them by, so the
 two programs that swap those names run without the kernel they replace:
 the swap steers the kernel path.) Then the loss of the
 whole model on a fresh initialisation against the reference's, and the
@@ -76,9 +76,9 @@ def _rule_given(**fixed):
     constant."""
     import jax.numpy as jnp
 
-    from horovod_tpu.models import gdn
+    from horovod_tpu.ops import gated_delta_rule as rule_op
 
-    right = gdn.gated_delta_rule
+    right = rule_op.gated_delta_rule
 
     def rule(q, k, v, g, beta, **options):
         given = dict(g=g, beta=beta)
@@ -86,11 +86,11 @@ def _rule_given(**fixed):
                       for name, value in fixed.items()})
         return right(q, k, v, given["g"], given["beta"], **options)
 
-    return _swapped(gdn, "gated_delta_rule", rule)
+    return _swapped(rule_op, "gated_delta_rule", rule)
 
 
 def _gate_first(o, z, scale, eps):
-    """``gdn.gated_head_norm``'s arguments (``o`` and ``z`` ``[b, s, H
+    """``head_norm.gated_norm``'s arguments (``o`` and ``z`` ``[b, s, H
     d]``, ``scale [d]``), the gate applied before the norm."""
     import jax
     import jax.numpy as jnp
@@ -103,8 +103,8 @@ def _gate_first(o, z, scale, eps):
                                       o.shape)
 
 
-def _not_normalised(x, dim=None, scale=1.0):
-    """``gdn.l2_normalise``'s arguments, the norm left out."""
+def _not_normalised(x, dim=None, eps=None, scale=1.0):
+    """``head_norm.l2_norm``'s arguments, the norm left out."""
     import jax.numpy as jnp
 
     return (x.astype(jnp.float32) * scale).astype(x.dtype)
@@ -134,6 +134,7 @@ def main():
     from chipbench.reference import qwen3_next as reference
     from chipbench.setup_sources import enable_compile_cache
     from horovod_tpu.models import gdn
+    from horovod_tpu.ops import head_norm
 
     enable_compile_cache()
     config = harness.read_json("chipbench", "configs", "qwen3-next-80b.json")
@@ -166,11 +167,11 @@ def main():
             ("chosen weights not renormalised",
              _route_with(lambda o: ({**o, "renormalise": False}, None))),
             ("q and k not L2-normalised",
-             _swapped(gdn, "l2_normalise", _not_normalised)),
+             _swapped(head_norm, "l2_norm", _not_normalised)),
             ("beta left out", _rule_given(beta=1.0)),
             ("the decay left out", _rule_given(g=0.0)),
             ("the gate before the mixer's norm",
-             _swapped(gdn, "gated_head_norm", _gate_first)),
+             _swapped(head_norm, "gated_norm", _gate_first)),
             ("the rotary over the whole head",
              _swapped(qwen3_next, "_model_config", whole_head))
     ) if not args.loss_only else ():

@@ -269,9 +269,9 @@ def phase_device(n_chips):
 def require_compiled_flash(lowered_text):
     """The flash path under test must be the Mosaic kernel, not the
     interpreter's emulation of it."""
-    from horovod_tpu.ops import flash_attention as fa
+    from horovod_tpu.ops import _pallas
 
-    check(not fa._interpret(),
+    check(not _pallas.interpret(),
           "flash attention would run in Pallas interpret mode here")
     check("tpu_custom_call" in lowered_text,
           "no tpu_custom_call in the lowered flash program")
@@ -372,8 +372,8 @@ def phase_gdn8192(shape=(2, 8192, 16, 32, 128, 128), chunk=128):
     """The chunked gated delta rule at the cell ``qwen3next-s8192``'s
     shape, (batch, seq, key heads, value heads, d_k, d_v) in bf16, by its
     two paths side by side: the Pallas kernels of
-    ``ops/gated_delta_rule.py`` and the plain ``jax.numpy`` body of
-    ``models/gdn.py``. Each: output and gradients against the float32
+    ``ops/gated_delta_rule.py`` and the plain ``jax.numpy`` body beside
+    them. Each: output and gradients against the float32
     recurrence taken one position after another (the benchmark's
     reference's, ``chipbench/reference/qwen3_next.py``), and the seconds a
     forward alone and a forward and backward take (host clock around
@@ -383,15 +383,16 @@ def phase_gdn8192(shape=(2, 8192, 16, 32, 128, 128), chunk=128):
     import numpy as np
 
     from chipbench.reference import qwen3_next as reference
-    from horovod_tpu.models import gdn
-    from horovod_tpu.ops import gated_delta_rule as kernels
+    from horovod_tpu.ops import gated_delta_rule as rule_op
+    from horovod_tpu.ops import head_norm as norm_op
 
     b, s, h_k, h_v, d_k, d_v = shape
     rng = np.random.RandomState(0)
     normal = lambda *dims: jnp.asarray(rng.normal(size=dims), jnp.float32)
     low = lambda t: t.astype(jnp.bfloat16)
-    q = low(gdn.l2_normalise(normal(b, s, h_k, d_k)) * d_k ** -0.5)
-    k = low(gdn.l2_normalise(normal(b, s, h_k, d_k)))
+    unit = lambda x: norm_op.l2_norm(x, eps=1e-6)
+    q = low(unit(normal(b, s, h_k, d_k)) * d_k ** -0.5)
+    k = low(unit(normal(b, s, h_k, d_k)))
     v, do = low(normal(b, s, h_v, d_v)), low(normal(b, s, h_v, d_v))
     g = -jnp.exp(normal(h_v)) * jax.nn.softplus(normal(b, s, h_v) + 1.0) / 16
     beta = jax.nn.sigmoid(normal(b, s, h_v))
@@ -408,9 +409,9 @@ def phase_gdn8192(shape=(2, 8192, 16, 32, 128, 128), chunk=128):
                 f32(q), f32(k), f32(v), g, beta)
     out = {"shape": list(shape), "chunk": chunk,
            "kernels_compiled": jax.default_backend() != "cpu"}
-    paths = {"kernels": functools.partial(kernels.gated_delta_rule,
+    paths = {"kernels": functools.partial(rule_op.gated_delta_rule_kernels,
                                           chunk=chunk),
-             "plain": functools.partial(gdn.gated_delta_rule_plain,
+             "plain": functools.partial(rule_op.gated_delta_rule_plain,
                                         chunk=chunk)}
     for name, rule in paths.items():
         step = with_gradients(rule, do)
@@ -435,8 +436,8 @@ def phase_conv8192(shapes=((2, 8192, 8192, False), (2, 8192, 1280, True)),
     """The causal depthwise convolution and its ``silu`` at the two cells'
     shapes, (batch, seq, channels, bias) in bf16: ``qwen3next-s8192``'s
     without a bias and ``nemotron3s-s8192``'s with one, by the Pallas
-    kernels of ``ops/causal_conv.py`` and the plain ``jax.numpy`` body of
-    ``models/ssm.py`` side by side. Each: output and the gradients of
+    kernels of ``ops/causal_conv.py`` and the plain ``jax.numpy`` body
+    beside them, side by side. Each: output and the gradients of
     ``x``, ``weight`` and ``bias`` against the plain body on float32
     operands (the same bf16 numbers, so what differs is where each path
     rounds), and the milliseconds a forward alone and a forward and
@@ -447,8 +448,7 @@ def phase_conv8192(shapes=((2, 8192, 8192, False), (2, 8192, 1280, True)),
     import jax.numpy as jnp
     import numpy as np
 
-    from horovod_tpu.models import ssm
-    from horovod_tpu.ops import causal_conv as kernels
+    from horovod_tpu.ops import causal_conv as conv_op
 
     out = {"taps": taps, "kernels_compiled": jax.default_backend() != "cpu"}
     for b, s, c, with_bias in shapes:
@@ -461,11 +461,11 @@ def phase_conv8192(shapes=((2, 8192, 8192, False), (2, 8192, 1280, True)),
 
         seconds = lambda call: mean_seconds(call, x, weight, bias)
         f32 = lambda t: t.astype(jnp.float32)
-        want = with_gradients(ssm.causal_conv_plain, f32(g))(
+        want = with_gradients(conv_op.causal_conv_plain, f32(g))(
             f32(x), weight, bias)
         here = out[f"{b}x{s}x{c}"] = {"bias": with_bias}
-        for name, conv in (("kernels", kernels.causal_conv),
-                           ("plain", ssm.causal_conv_plain)):
+        for name, conv in (("kernels", conv_op.causal_conv_kernels),
+                           ("plain", conv_op.causal_conv_plain)):
             step = with_gradients(conv, g)
             got = jax.block_until_ready(step(x, weight, bias))
             errs = {what: rel_l2(one, w) for what, one, w in
@@ -490,8 +490,8 @@ def phase_norms8192(shapes=((2, 8192, 32, 128), (1, 1040, 3, 256))):
     d]``: its 32 value heads of 128, and three heads of 256 over a
     sequence the kernels' block does not divide. ``RMSNorm(o) w
     silu(z)`` and the L2 norm at q's scale, by the Pallas kernels of
-    ``ops/head_norm.py`` and the plain ``jax.numpy`` bodies of
-    ``models/gdn.py`` side by side. Each: output and every gradient against
+    ``ops/head_norm.py`` and the plain ``jax.numpy`` bodies beside them,
+    side by side. Each: output and every gradient against
     the plain body on float32 operands (the same bf16 numbers, so what
     differs is where each path rounds), and the milliseconds a forward
     alone and a forward and backward take (host clock around
@@ -501,8 +501,7 @@ def phase_norms8192(shapes=((2, 8192, 32, 128), (1, 1040, 3, 256))):
     import jax.numpy as jnp
     import numpy as np
 
-    from horovod_tpu.models import gdn
-    from horovod_tpu.ops import head_norm as kernels
+    from horovod_tpu.ops import head_norm as norm_op
 
     eps = 1e-6
     out = {"kernels_compiled": jax.default_backend() != "cpu"}
@@ -515,13 +514,15 @@ def phase_norms8192(shapes=((2, 8192, 32, 128), (1, 1040, 3, 256))):
         here = out[f"{b}x{s}x{heads}x{dim}"] = {}
         for norm, args, names, paths in (
                 ("gated", (o, z, w), ("y", "do", "dz", "dw"), {
-                    "kernels": lambda *a: kernels.gated_norm(*a, eps=eps),
-                    "plain": lambda *a: gdn.gated_head_norm_plain(*a, eps)}),
+                    "kernels": lambda *a: norm_op.gated_norm_kernels(
+                        *a, eps=eps),
+                    "plain": lambda *a: norm_op.gated_norm_plain(
+                        *a, eps=eps)}),
                 ("l2", (o,), ("y", "dx"), {
-                    "kernels": lambda x: kernels.l2_norm(
+                    "kernels": lambda x: norm_op.l2_norm_kernels(
                         x, dim, eps=eps, scale=dim ** -0.5),
-                    "plain": lambda x: gdn.l2_normalise_plain(
-                        x, dim, dim ** -0.5)})):
+                    "plain": lambda x: norm_op.l2_norm_plain(
+                        x, dim, eps=eps, scale=dim ** -0.5)})):
             want = with_gradients(paths["plain"], f32(g))(
                 *(f32(a) if a.ndim == 3 else a for a in args))
             for name, fn in paths.items():

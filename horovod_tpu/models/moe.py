@@ -48,47 +48,39 @@ chooses over all ``n_experts``; only an assignment to a held expert gets
 a row, and the layer returns the held experts' part of the sum (plus what
 every chip computes alike: the latent projections and the shared expert).
 Nothing is dropped: a token has one slot a held expert, assigned or not by
-``[T, E]`` comparisons with the token's ``k``-th score (``_chosen``; no
-``[T, k, E]`` mask), and the slots are sorted by expert with the
-unassigned last. The assigned slots are worked
-through in **rounds of ``T`` rows, one row a token** (``held_rows``,
-``_held_experts``): round ``r`` takes the sorted slots ``[r T, (r + 1)
-T)``, gathers their tokens' rows, runs the same grouped products on a
-``[T, width]`` operand with the part of each group that falls into the
-round, and adds the weighted rows to their tokens. The most that can be
-assigned is ``min(k, count)`` rounds, a token choosing an expert once;
-how many run is the router's to say, ``ceil(sum(group_sizes) / T)``, one
-at the loads a share sees as a rule: a loop whose trips the data decide,
-with a backward pass written to match (``_held_experts_bwd``: the same
-loop, each round's forward made again for its pullback). So a step pays
-for the rows assigned, a program holds the round once (``_held_round`` is
-a ``jax.jit`` that every layer of one shape shares) and the grouped
-product at one row count, and nothing is traced, compiled or run for a
-round that is not needed. What the rounds summed to carries the name
-``HELD_SUM`` for a caller's ``jax.checkpoint`` to keep. The group sizes
-of a round sum to the rows really assigned, and the grouped product does
-not visit the tiles past them (``megablox`` takes group sizes that sum to
-fewer rows than it is given); what it leaves there nothing reads.
+``[T, E]`` comparisons with the token's ``k``-th score (``_chosen``), and
+the slots are sorted by expert with the unassigned last. The assigned
+slots are worked through in **rounds of ``T`` rows, one row a token**
+(``held_rows``, ``_held_experts``): round ``r`` takes the sorted slots
+``[r T, (r + 1) T)``, gathers their tokens' rows, runs the same grouped
+products on a ``[T, width]`` operand with the part of each group that
+falls into the round, and adds the weighted rows to their tokens. How many
+rounds run is the router's to say, ``ceil(sum(group_sizes) / T)``
+(``min(k, count)`` at most, one at the loads a share sees as a rule): a
+loop whose trips the data decide, with a backward pass written to match
+(``_held_experts_bwd``: the same loop, each round's forward made again for
+its pullback). So a step pays for the rows assigned, a program holds the
+round once (``_held_round`` is a ``jax.jit`` that every layer of one shape
+shares) and nothing is traced, compiled or run for a round that is not
+needed. What the rounds summed to carries the name ``HELD_SUM`` for a
+caller's ``jax.checkpoint`` to keep. The group sizes of a round sum to the
+rows really assigned, and the grouped product does not visit the tiles
+past them (``megablox`` takes group sizes that sum to fewer rows than it
+is given); what it leaves there nothing reads.
 
-**Inside a round the same rule holds for what is not a grouped product
-and does not already run at the memory's rate** (a gather of a round's
-``T`` rows does: ``_rows_of_tokens``). The weighted sum by token and its
-twin in the backward pass, the transpose of the gather, were scatter-adds
-of ``T`` rows, which a TPU takes a row at a time, a row of zeros like any
-other, at a twelfth of the memory's rate (PERF.md, PR 41), and a round is
-half empty at the loads a share sees: they are ``ops/sum_by_token.py``'s
-kernel (``_add_to_tokens``, ``_sum_by_token``), which takes the rows in
-the order of their tokens and makes a tile of tokens' sum on the MXU from
-the chunks of sorted rows that hold its own, one grid step a chunk and
-tile that meet and none past the rows assigned. The cotangent of the sum,
-a gather of float32 rows with the weights' products and the weights' own
-gradient on it, goes by the assigned rows **a piece of ``_PIECE`` rows a
-trip** of a loop that the round's count bounds (``_pieces``;
+**Inside a round** what is not a grouped product goes by the assigned rows
+too, a round being half empty at the loads a share sees. The gather of a
+round's ``T`` rows runs at the memory's rate as it is
+(``_rows_of_tokens``). The weighted sum by token and its twin in the
+backward pass, the transpose of the gather, would be scatter-adds, which a
+TPU takes a row at a time at a twelfth of the memory's rate: they are
+``ops/sum_by_token.py``'s kernel (``_add_to_tokens``, ``_sum_by_token``).
+The cotangent of the sum, a gather of float32 rows with the weights'
+products and the weights' own gradient on it, goes **a piece of ``_PIECE``
+rows a trip** of a loop that the round's count bounds (``_pieces``;
 ``move_rows``; ``_add_to_tokens_transposed``), so that no float32 pass
 over ``[T, width]`` is left beside the grouped products but the total
-itself. A program holds the kernel and the piece's body once, a round
-with no row runs no trip, and a full round costs no more than one pass
-over it did. Gradients of the expert stacks are summed over rounds in the
+itself. Gradients of the expert stacks are summed over rounds in the
 layer's ``dtype``, where one pass rounds once. No code stands in for the
 other chips or for the exchange with them.
 
@@ -110,6 +102,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from horovod_tpu.ops import _pallas
 from horovod_tpu.ops import kth_largest as kth_kernel
 from horovod_tpu.ops import sum_by_token as token_sum
 
@@ -134,24 +127,15 @@ HELD_CHOICE = "moe_held_choice"
 
 
 def _count_trace(n_experts, top_k, held, n_tokens):
-    """The engagement counter: one count a traced layer. Trace-time
-    Python only."""
-    try:
-        from horovod_tpu import metrics
-
-        metrics.counter(
-            "hvt_moe_layers_traced_total",
-            "mixture-of-experts layers traced into compiled programs "
-            "(counted per trace, not per execution)",
-            ("experts", "top_k", "product", "held", "round_rows",
-             "move_rows"),
-        ).labels(experts=str(n_experts), top_k=str(top_k), product=PRODUCT,
-                 held=str(held[1] if held else n_experts),
-                 round_rows=str(n_tokens) if held else "all",
-                 move_rows=str(move_rows(n_tokens)) if held else "all"
-                 ).inc()
-    except Exception:
-        pass  # telemetry must never break a trace
+    """One count a traced layer."""
+    _pallas.count_trace(
+        "hvt_moe_layers_traced_total",
+        "mixture-of-experts layers traced into compiled programs "
+        "(counted per trace, not per execution)",
+        experts=n_experts, top_k=top_k, product=PRODUCT,
+        held=held[1] if held else n_experts,
+        round_rows=n_tokens if held else "all",
+        move_rows=move_rows(n_tokens) if held else "all")
 
 
 def _rows(x, index):
@@ -223,11 +207,10 @@ def _unpack(bits, tokens):
 def _slots_by_expert(assigned):
     """``assigned [T, count]`` bool -> the ``T x count`` slots
     (token-major: slot ``t count + e``), the assigned first, by expert and
-    within an expert by token: one stable sort. Writing each assigned slot
-    to its place (its expert's offset plus the earlier tokens that chose
-    the expert, two ``cumsum``s) took 0.65 and 2.50 ms on a v5e at 16,384
-    x 8 and x 32 slots where this sort takes 0.12 and 0.55
-    (``benchmarks/moe_route_pieces.py``; PERF.md section 6, PR 37)."""
+    within an expert by token: one stable sort (0.12 and 0.55 ms on a v5e
+    at 16,384 x 8 and x 32 slots, a fifth of what two ``cumsum``s and a
+    scatter to each slot's place took: ``benchmarks/moe_route_pieces.py``;
+    PERF.md section 6, PR 37)."""
     count = assigned.shape[-1]
     return jnp.argsort(jnp.where(assigned, jnp.arange(count), count)
                        .reshape(-1), stable=True)
@@ -334,11 +317,6 @@ def moe_dispatch(h, order, inverse, k):
     return _take_rows(h, order, inverse, k)
 
 
-def _interpret() -> bool:
-    # only the CPU interprets (it has no Mosaic compiler)
-    return jax.default_backend() == "cpu"
-
-
 def _grouped_product(lhs, rhs, group_sizes):
     """``lhs [m, k]`` by ``rhs [g, k, n]``: rows of group ``i`` (the
     ``group_sizes[i]`` rows after those of group ``i - 1``) times
@@ -349,7 +327,7 @@ def _grouped_product(lhs, rhs, group_sizes):
     tile = (math.gcd(m, _GMM_TILE[0]), min(k, _GMM_TILE[1]),
             min(n, _GMM_TILE[2]))
     return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-               tiling=tile, interpret=_interpret())
+               tiling=tile, interpret=_pallas.interpret())
 
 
 def _relu2(x):
@@ -437,10 +415,7 @@ def _add_to_tokens(total, rows, weight, where):
     their tokens, and a tile of tokens' sum made on the MXU from the
     chunks of sorted rows that hold its own, grid steps past the assigned
     rows skipped. What the grouped product left in ``rows`` past the
-    assigned is not read. Sorting the rows by token and summing the runs
-    with shifted adds took twice a whole round's scatter-add and a gather
-    over all ``T x count`` slots four times
-    (``benchmarks/moe_rows_to_tokens.py``; PERF.md, PR 32)."""
+    assigned is not read."""
     return token_sum.sum_by_token(rows, where[1], weight=weight, total=total)
 
 

@@ -41,7 +41,8 @@ class _SpaceToDepthStem(nn.Module):
     the channel dim (224x224x3 -> 112x112x12) and apply the SAME
     weights as an equivalent 4x4 stride-1 convolution. This is a pure
     reindexing of the 7x7 stride-2 conv — numerically identical, pinned
-    by tests/test_models.py — with 4x the input channels per MXU pass.
+    by tests/test_models_vision.py — with 4x the input channels per MXU
+    pass.
 
     The parameter keeps the standard ``(7, 7, 3, width)`` shape and the
     ``{"conv_init": {"kernel"}}`` checkpoint layout of the ``nn.Conv``

@@ -12,12 +12,12 @@ them:
 2. ``sconv_gate_conv``: ``v = B * u``; ``c_t = sum_j w_j v_{t - taps + 1
    + j}`` a channel (zeros before the sequence, no bias, **no
    activation**); ``y = C * c``. Float32 inside, the layer's ``dtype`` in
-   and out (``gated_conv``). The taps are ``ssm.causal_conv_plain``'s, the
-   package's one plain convolution, told to leave its ``silu`` out; the
-   Pallas kernels of ``ops/causal_conv.py`` compute ``silu(conv(x) + b)``
-   alone and are not called: a kernel that reads ``B``, ``C`` and ``u``
-   once and writes ``y`` once is not written yet (PERF.md section 7 prices
-   it).
+   and out (``gated_conv``). The taps are ``causal_conv_plain``'s
+   (``ops/causal_conv.py``), the package's one plain convolution, told to
+   leave its ``silu`` out; that module's Pallas kernels compute
+   ``silu(conv(x) + b)`` alone and are not called: a kernel that reads
+   ``B``, ``C`` and ``u`` once and writes ``y`` once is not written yet
+   (PERF.md section 7 prices it).
 3. ``sconv_out_proj``: ``y W_out``, no bias.
 
 For a caller that asks for the collection ``intermediates`` the mixer's
@@ -34,23 +34,17 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.models import ssm
+from horovod_tpu.ops import _pallas
+from horovod_tpu.ops import causal_conv as conv_op
 
 
 def _count_trace(channels, taps):
-    """The engagement counter: one count a traced layer. Trace-time
-    Python only."""
-    try:
-        from horovod_tpu import metrics
-
-        metrics.counter(
-            "hvt_sconv_layers_traced_total",
-            "gated short-convolution layers traced into compiled programs "
-            "(counted per trace, not per execution)",
-            ("channels", "taps"),
-        ).labels(channels=str(channels), taps=str(taps)).inc()
-    except Exception:
-        pass  # telemetry must never break a trace
+    """One count a traced layer."""
+    _pallas.count_trace(
+        "hvt_sconv_layers_traced_total",
+        "gated short-convolution layers traced into compiled programs "
+        "(counted per trace, not per execution)",
+        channels=channels, taps=taps)
 
 
 def gated_conv(b, c, u, weight):
@@ -59,7 +53,7 @@ def gated_conv(b, c, u, weight):
     weight[j] v[t - taps + 1 + j]`` (zeros before the sequence). Float32
     inside, ``u.dtype`` out."""
     v = b.astype(jnp.float32) * u.astype(jnp.float32)
-    conv = ssm.causal_conv_plain(v, weight, activation=None)
+    conv = conv_op.causal_conv_plain(v, weight, activation=None)
     return (c.astype(jnp.float32) * conv).astype(u.dtype)
 
 

@@ -11,12 +11,8 @@ them:
    ``D``, ``G N``, ``G N``, ``heads``; one product, no bias.
 2. ``ssm_conv``: ``silu(conv(xBC) + b)``, a causal depthwise convolution
    of ``conv`` taps over the sequence, on ``x``, ``B`` and ``C`` together
-   (``causal_conv`` below: two programs of the same arithmetic, chosen
-   from what can be observed by ``conv_kernels_serve``, no option: on a
-   TPU, from 2048 channels up and at channels and a sequence that 128
-   divides, the Pallas kernels of ``ops/causal_conv.py``; everywhere else
-   ``causal_conv_plain``, which is also what this mixer's own 1280
-   channels in the cell ``nemotron3s-s8192`` take).
+   (``ops/causal_conv.py``, whose rule sends this mixer's own 1280
+   channels in the cell ``nemotron3s-s8192`` to its plain body).
 3. ``ssm_scan``: ``delta = softplus(dt + dt_bias)``, ``a = -exp(A_log)``
    a head, both float32; a state ``h [head_dim, N]`` a head,
 
@@ -56,7 +52,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.ops import causal_conv as conv_kernels
+from horovod_tpu.ops import _pallas
+from horovod_tpu.ops import causal_conv as conv_op
 
 # What the decays, their cumulative sums and the carried state are
 # computed in, whatever the products run in. A module constant and no
@@ -72,80 +69,20 @@ CHUNK = 256
 # A uniform in [1, 16], stored as its logarithm.
 DT_MIN, DT_MAX, DT_FLOOR = 1e-3, 1e-1, 1e-4
 A_RANGE = (1.0, 16.0)
-# The narrowest convolution that goes to the Pallas kernels. A measured
-# boundary, not the kernels': alone they beat the plain body at this
-# mixer's 1280 channels too (0.48 against 2.51 ms forward and backward),
-# but inside nemotron3s-s8192's step XLA then copies the slices of a
-# narrow operand and of a result it can no longer write in the layout the
-# scan's heads of 64 want (``ssm_conv`` 10.1 -> 5.2 ms a step, its
-# neighbours +8.8: the step 3.0 ms longer), where at a Gated DeltaNet's
-# 8192 in qwen3next-s8192 the step is 48.9 ms shorter. Nothing between the
-# two was measured (PERF.md section 6, PR 36).
-CONV_KERNELS_FROM = 2048
 
 
 def _count_trace(heads, state, chunk):
-    """The engagement counter: one count a traced layer. Trace-time
-    Python only."""
-    try:
-        from horovod_tpu import metrics
-
-        metrics.counter(
-            "hvt_ssm_layers_traced_total",
-            "state-space (Mamba-2) layers traced into compiled programs "
-            "(counted per trace, not per execution)",
-            ("heads", "state", "chunk"),
-        ).labels(heads=str(heads), state=str(state), chunk=str(chunk)).inc()
-    except Exception:
-        pass  # telemetry must never break a trace
+    """One count a traced layer."""
+    _pallas.count_trace(
+        "hvt_ssm_layers_traced_total",
+        "state-space (Mamba-2) layers traced into compiled programs "
+        "(counted per trace, not per execution)",
+        heads=heads, state=state, chunk=chunk)
 
 
 def chunk_for(seq_len: int, chunk: Optional[int] = None) -> int:
     """The chunk length the scan uses for ``seq_len`` positions."""
     return max(1, min(chunk or CHUNK, seq_len))
-
-
-def conv_kernels_serve(seq_len: int, channels: int, taps: int) -> bool:
-    """Whether the convolution goes to the Pallas kernels of
-    ``ops/causal_conv.py``, from what can be observed (static trace-time
-    facts, so the choice compiles away): a TPU backend (elsewhere the
-    kernels are interpreted, far slower than ``jax.numpy``), channels that
-    fill whole 128-lane tiles and are as many as the kernels were seen to
-    pay for inside a step (``CONV_KERNELS_FROM``: a Gated DeltaNet's 8192
-    go, this file's own mixer's 1280 stay), a sequence
-    their shortest block divides and taps that reach no further back than
-    a tile. Everything else stays on ``causal_conv_plain``, so the choice
-    never raises for a shape that serves."""
-    return (jax.default_backend() == "tpu" and channels % 128 == 0
-            and channels >= CONV_KERNELS_FROM
-            and seq_len % conv_kernels.ROWS_MIN == 0
-            and taps - 1 <= conv_kernels.EDGE)
-
-
-def causal_conv(x, weight, bias=None):
-    """``x [b, s, c]``, ``weight [taps, c]``, ``bias [c]`` or none:
-    position t gets ``sum_j weight[j] x[t - taps + 1 + j] + bias`` (zeros
-    before the sequence), then ``silu``; float32 inside, ``x.dtype`` out.
-    By the Pallas kernels where ``conv_kernels_serve`` says so and by
-    ``causal_conv_plain`` everywhere else."""
-    if conv_kernels_serve(x.shape[1], x.shape[2], weight.shape[0]):
-        return conv_kernels.causal_conv(x, weight, bias)
-    return causal_conv_plain(x, weight, bias)
-
-
-def causal_conv_plain(x, weight, bias=None, activation=jax.nn.silu):
-    """``causal_conv`` in plain ``jax.numpy``: the path of every backend
-    and shape the kernels do not serve, and their reference. The operand
-    is cast to float32 and padded, each tap a slice of that copy.
-    ``activation=None`` leaves the sum of the taps as it is (the gated
-    short convolution of ``models/sconv.py``, which the kernels, ``silu``
-    alone, do not compute)."""
-    taps, seq = weight.shape[0], x.shape[1]
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    out = 0.0 if bias is None else bias.astype(jnp.float32)
-    for j in range(taps):
-        out = out + weight[j].astype(jnp.float32) * padded[:, j:j + seq]
-    return (out if activation is None else activation(out)).astype(x.dtype)
 
 
 def ssm_scan(x, delta, a, b, c, *, chunk: Optional[int] = None):
@@ -308,7 +245,7 @@ class Mamba2Mixer(nn.Module):
             zxbcdt = jnp.dot(u, w_in.astype(self.dtype))
             z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * bc], -1)
         with jax.named_scope("ssm_conv"):
-            xbc = causal_conv(xbc, conv_kernel, conv_bias)
+            xbc = conv_op.causal_conv(xbc, conv_kernel, conv_bias)
             x, b, c = jnp.split(xbc, [inner, inner + bc], -1)
         with jax.named_scope("ssm_scan"):
             x = x.reshape(*x.shape[:-1], heads, self.head_dim)

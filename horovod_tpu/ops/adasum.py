@@ -93,7 +93,7 @@ def adasum_reduce(t, axis_name, axis_index_groups=None, start_level=None):
     orig_dtype = t.dtype
     v = t.astype(jnp.float32)
 
-    from horovod_tpu.ops.collective_ops import Sum, _grouped_reduce
+    from horovod_tpu.ops.collective_ops import Sum, grouped_reduce
 
     levels = int(max_size).bit_length() - 1
     for k in range(levels):
@@ -113,7 +113,7 @@ def adasum_reduce(t, axis_name, axis_index_groups=None, start_level=None):
                 # keep the partition covering the whole axis
                 pair_groups.extend([r] for r in g)
 
-        s = _grouped_reduce(v, Sum, axis_name, pair_groups)  # a + b
+        s = grouped_reduce(v, Sum, axis_name, pair_groups)  # a + b
         if stride < start_level:
             # below start_level: plain average of the pair; members whose
             # group is done (singletons) must keep their value

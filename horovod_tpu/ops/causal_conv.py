@@ -1,13 +1,18 @@
-"""The causal depthwise convolution of ``models/ssm.py`` (``causal_conv``:
-a few taps down the sequence, a bias, ``silu``) as two Pallas TPU kernels
-under one custom VJP.
+"""The causal depthwise convolution of the mixers (a few taps down the
+sequence, a bias, ``silu``): its plain ``jax.numpy`` body, two Pallas TPU
+kernels under one custom VJP, and the rule that chooses between them.
+
+``causal_conv`` is what a mixer calls; ``serves`` sends it to the kernels
+(``causal_conv_kernels``) or to ``causal_conv_plain``, which is also the
+kernels' reference and, with ``activation=None``, the bare taps of
+``models/sconv.py``.
 
 In plain ``jax.numpy`` the operand is cast to float32 and padded, the taps
 are slices of that copy at rows 0, 1, 2, ... of the sequence axis (the
 tile's sublane axis, so all but one are misaligned reads that XLA does not
 keep in one fusion) and automatic differentiation keeps float32 residuals
-of the operand's size. Here nothing float32 of size ``[b, s, c]`` exists
-outside VMEM:
+of the operand's size. In the kernels nothing float32 of size ``[b, s, c]``
+exists outside VMEM:
 
 - ``hvt_causal_conv_fwd`` reads a block of ``x`` in ``x.dtype`` and the
   tile of rows before it, walks the block a few sublane tiles at a time
@@ -21,15 +26,13 @@ outside VMEM:
   ``dbias``, which XLA adds up (``taps + 1`` rows a block).
 
 The residuals are the operands. Channels are lanes and positions
-sublanes; a grid step takes ``rows x lanes`` of one sequence, every step
-is independent of every other (the halo is a second block spec over the
-same array, not a carry), and ``ssm.causal_conv_plain`` is the reference
-and the path of every backend and shape the kernels do not serve.
+sublanes; a grid step takes ``rows x lanes`` of one sequence, and every
+step is independent of every other (the halo is a second block spec over
+the same array, not a carry).
 
 On the CPU the same kernel code runs through the Pallas interpreter, at
 any width; compiled, Mosaic wants lanes in multiples of 128 and rows in
-multiples of the bf16 tile's 16 (``ssm.conv_kernels_serve`` sends it
-nothing else).
+multiples of the bf16 tile's 16 (``serves`` sends it nothing else).
 """
 
 from __future__ import annotations
@@ -42,7 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.flash_attention import _interpret, _out
+from horovod_tpu.ops import _pallas
 
 _F32 = jnp.float32
 # The rows of a halo block (a bf16 tile's, so that it is a whole tile in
@@ -58,8 +61,58 @@ HALO, EDGE = 16, 8
 # within 0.1 ms of 1024 (benchmarks/causal_conv.py; PERF.md section 6,
 # PR 36).
 ROWS, LANES, SUB = 1024, 512, 32
-# The shortest block ``ssm.conv_kernels_serve`` sends here.
+# The shortest block ``serves`` sends to the kernels.
 ROWS_MIN = 128
+# The narrowest convolution that goes to the kernels. A measured boundary,
+# not the kernels': alone they beat the plain body at a Mamba-2 mixer's
+# 1280 channels too (0.48 against 2.51 ms forward and backward), but
+# inside nemotron3s-s8192's step XLA then copies the slices of a narrow
+# operand and of a result it can no longer write in the layout the scan's
+# heads of 64 want (``ssm_conv`` 10.1 -> 5.2 ms a step, its neighbours
+# +8.8: the step 3.0 ms longer), where at a Gated DeltaNet's 8192 in
+# qwen3next-s8192 the step is 48.9 ms shorter. Nothing between the two was
+# measured (PERF.md section 6, PR 36).
+KERNELS_FROM = 2048
+
+
+def serves(seq_len: int, channels: int, taps: int) -> bool:
+    """Whether the convolution goes to the kernels, from what can be
+    observed (static trace-time facts, so the choice compiles away): a TPU
+    backend, channels that fill whole 128-lane tiles and are as many as
+    the kernels were seen to pay for inside a step (``KERNELS_FROM``: a
+    Gated DeltaNet's 8192 go, a Mamba-2 mixer's 1280 stay), a sequence
+    their shortest block divides and taps that reach no further back than
+    a tile. Everything else stays on ``causal_conv_plain``, so the choice
+    never raises for a shape that serves."""
+    return (_pallas.on_tpu() and channels % 128 == 0
+            and channels >= KERNELS_FROM and seq_len % ROWS_MIN == 0
+            and taps - 1 <= EDGE)
+
+
+def causal_conv(x, weight, bias=None):
+    """``x [b, s, c]``, ``weight [taps, c]``, ``bias [c]`` or none:
+    position t gets ``sum_j weight[j] x[t - taps + 1 + j] + bias`` (zeros
+    before the sequence), then ``silu``; float32 inside, ``x.dtype`` out.
+    By the kernels where ``serves`` says so and by ``causal_conv_plain``
+    everywhere else."""
+    if serves(x.shape[1], x.shape[2], weight.shape[0]):
+        return causal_conv_kernels(x, weight, bias)
+    return causal_conv_plain(x, weight, bias)
+
+
+def causal_conv_plain(x, weight, bias=None, activation=jax.nn.silu):
+    """``causal_conv`` in plain ``jax.numpy``: the path of every backend
+    and shape the kernels do not serve, and their reference. The operand
+    is cast to float32 and padded, each tap a slice of that copy.
+    ``activation=None`` leaves the sum of the taps as it is (the gated
+    short convolution of ``models/sconv.py``, which the kernels, ``silu``
+    alone, do not compute)."""
+    taps, seq = weight.shape[0], x.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    out = 0.0 if bias is None else bias.astype(jnp.float32)
+    for j in range(taps):
+        out = out + weight[j].astype(jnp.float32) * padded[:, j:j + seq]
+    return (out if activation is None else activation(out)).astype(x.dtype)
 
 
 class _Plan(NamedTuple):
@@ -75,35 +128,21 @@ class _Plan(NamedTuple):
 
 
 def _count_trace(kernel, plan, channels):
-    """The engagement counter: which kernels a job got, by the taps, the
-    width and the block. Trace-time Python only."""
-    try:
-        from horovod_tpu import metrics
-
-        metrics.counter(
-            "hvt_causal_conv_kernel_traces_total",
-            "causal depthwise convolution kernels traced into compiled "
-            "programs (counted per trace, not per execution)",
-            ("kernel", "taps", "channels", "block"),
-        ).labels(kernel=kernel, taps=str(plan.taps), channels=str(channels),
-                 block=f"{plan.rows}x{plan.lanes}").inc()
-    except Exception:
-        pass  # telemetry must never break a trace
-
-
-def _largest(total, most, step):
-    """The largest multiple of ``step`` up to ``most`` that divides
-    ``total``; ``total`` itself where there is none."""
-    return next((n for n in range(min(most, total) // step * step, 0, -step)
-                 if total % n == 0), total)
+    """Which kernels a job got, by the taps, the width and the block."""
+    _pallas.count_trace(
+        "hvt_causal_conv_kernel_traces_total",
+        "causal depthwise convolution kernels traced into compiled "
+        "programs (counted per trace, not per execution)",
+        kernel=kernel, taps=plan.taps, channels=channels,
+        block=f"{plan.rows}x{plan.lanes}")
 
 
 def _plan(x, weight, rows, lanes, sub):
     _, seq, channels = x.shape
     taps = weight.shape[0]
-    rows = rows or _largest(seq, ROWS, HALO)
-    lanes = lanes or _largest(channels, LANES, 128)
-    sub = sub or _largest(rows, SUB, EDGE)
+    rows = rows or _pallas.largest(seq, ROWS, HALO)
+    lanes = lanes or _pallas.largest(channels, LANES, 128)
+    sub = sub or _pallas.largest(rows, SUB, EDGE)
     if (seq % rows or rows % HALO or channels % lanes or rows % sub
             or sub % EDGE or taps - 1 > EDGE):
         raise ValueError(
@@ -111,7 +150,7 @@ def _plan(x, weight, rows, lanes, sub):
             f"tile [{seq}, {channels}] with {taps} taps: rows in multiples "
             f"of {HALO} that divide the sequence, passes in multiples of "
             f"{EDGE} that divide the rows, at most {EDGE + 1} taps")
-    return _Plan(taps, rows, lanes, sub, _interpret())
+    return _Plan(taps, rows, lanes, sub, _pallas.interpret())
 
 
 # ---------------------------------------------------------------- kernels
@@ -259,7 +298,7 @@ def _call(kernel, name, plan, operands, in_specs, out_specs, out_shape):
         functools.partial(kernel, plan=plan),
         grid=(batch, channels // plan.lanes, seq // plan.rows),
         in_specs=in_specs, out_specs=out_specs,
-        out_shape=[_out(shape, dtype, *operands)
+        out_shape=[_pallas.out(shape, dtype, *operands)
                    for shape, dtype in out_shape],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3),
@@ -323,9 +362,10 @@ def _conv_bwd(plan, res, g):
 _conv.defvjp(_conv_fwd, _conv_bwd)
 
 
-def causal_conv(x, weight, bias=None, *, rows: Optional[int] = None,
-                lanes: Optional[int] = None, sub: Optional[int] = None):
-    """``ssm.causal_conv_plain`` through the kernels: ``x [b, s, c]``,
+def causal_conv_kernels(x, weight, bias=None, *, rows: Optional[int] = None,
+                        lanes: Optional[int] = None,
+                        sub: Optional[int] = None):
+    """``causal_conv_plain`` through the kernels: ``x [b, s, c]``,
     ``weight [taps, c]``, ``bias [c]`` or none -> ``silu(sum_j weight[j]
     x[t - taps + 1 + j] + bias)`` like ``x`` (zeros before the sequence),
     float32 inside. Differentiable in all three. ``rows``, ``lanes`` and

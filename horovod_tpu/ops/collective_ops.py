@@ -219,7 +219,7 @@ def allreduce(tensor, average=None, name=None, op=None,
             process_set=process_set)))
 
 
-def _grouped_reduce(t, op, axis, groups):
+def grouped_reduce(t, op, axis, groups):
     """Reduce within replica groups.
 
     Native ``axis_index_groups`` is used when the installed jax supports it
@@ -273,7 +273,7 @@ def _traced_allreduce(t, op, axis, process_set, prescale, postscale):
     if prescale != 1.0:
         t = t * jnp.asarray(prescale, t.dtype)
     if op in (Average, Sum, Min, Max):
-        r = _grouped_reduce(t, op, axis, groups)
+        r = grouped_reduce(t, op, axis, groups)
     elif op is Product:
         # No native pprod collective; product = exp(psum(log)) is unstable,
         # so gather the factors and multiply.

@@ -13,8 +13,7 @@ loop is long enough to hide its own overhead) unless the caller names one.
 Backward pass recomputes score tiles (FLOPs-for-HBM trade, the same choice
 ``jax.checkpoint`` makes) from the saved logsumexp in one kernel gridded
 over K/V blocks: each tile is made once and gives its share of dQ, dK and
-dV (five products; dQ and dK/dV kernels of their own made seven, and the
-element-wise pass twice: PERF.md section 6, PR 29).
+dV (five products).
 
 No reference-framework counterpart (Horovod ships gradients, not kernels);
 this is part of the TPU framework's compute path. On the CPU the same
@@ -31,9 +30,11 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from horovod_tpu.ops import _pallas
+from horovod_tpu.ops._pallas import NN, NT, TN, dot
 
 _NEG_INF = -1e30
 _LANES = 128  # TPU vector lane width: scratch statistics are stored
@@ -70,14 +71,8 @@ def resolve_flash(use_flash, local_seq) -> bool:
                 f"use_flash must be True, False, or 'auto'; got "
                 f"{use_flash!r}")
         return (local_seq >= _AUTO_FROM and local_seq % _LANES == 0
-                and jax.default_backend() == "tpu")
+                and _pallas.on_tpu())
     return bool(use_flash)
-
-
-def _interpret() -> bool:
-    # only the CPU interprets (it has no Mosaic compiler; the CPU test
-    # mesh runs the same kernel code)
-    return jax.default_backend() == "cpu"
 
 
 # Two-level decomposition: the sequence operand STREAMS through the
@@ -93,16 +88,6 @@ def _interpret() -> bool:
 # call is masked, the ones wholly below the diagonal too: a second,
 # unmasked loop for those measured 3 to 5% slower than the mask it saves.
 # Online-softmax statistics live in VMEM scratch across the tile axis.
-
-_NT = (((1,), (1,)), ((), ()))   # [m, d] x [n, d] -> [m, n]
-_NN = (((1,), (0,)), ((), ()))   # [m, n] x [n, d] -> [m, d]
-_TN = (((0,), (0,)), ((), ()))   # [n, m] x [n, d] -> [m, d]
-
-
-def _dot(a, b, dims):
-    return jax.lax.dot_general(a, b, dims,
-                               preferred_element_type=jnp.float32)
-
 
 def _scaled(x, scale):
     """``(x', rest)`` with ``x' @ y * rest == x @ y * scale``: a power of
@@ -156,7 +141,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
             acc, m, l = carry
             k = k_ref[0, 0, _sub_block(j, block_k), :]
             v = v_ref[0, 0, _sub_block(j, block_k), :]
-            sc = _dot(q, k, _NT)                      # [bq, bk]
+            sc = dot(q, k, NT)                        # [bq, bk]
             if rest is not None:
                 sc = sc * rest
             if causal:
@@ -167,7 +152,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
             p = jnp.exp(sc - m_new)
             corr = jnp.exp(m - m_new)
             l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-            acc_new = acc * corr + _dot(p.astype(v.dtype), v, _NN)
+            acc_new = acc * corr + dot(p.astype(v.dtype), v, NN)
             return acc_new, m_new, l_new
 
         n_sub = tile // block_k
@@ -230,7 +215,7 @@ def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
             do = do_ref[0, 0, rows, :].astype(jnp.float32)
             lse = lse_ref[0, 0, rows, :]              # [block_q, 1]
             delta = delta_ref[0, 0, rows, :]
-            sc = _dot(q, k_sc, _NT)                   # [bq, bk]
+            sc = dot(q, k_sc, NT)                     # [bq, bk]
             if rest is not None:
                 sc = sc * rest
             if causal:
@@ -238,12 +223,12 @@ def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                     _visible(ti * tile + i * block_q, ki * block_k,
                              sc.shape), sc, _NEG_INF)
             p = jnp.exp(sc - lse)
-            dv_new = dv + _dot(p, do, _TN)
-            dp = _dot(do, v, _NT)
+            dv_new = dv + dot(p, do, TN)
+            dp = dot(do, v, NT)
             ds = p * (dp - delta)
-            dk_new = dk + _dot(ds, q.astype(jnp.float32), _TN)
-            dq_acc_ref[_sub_block(ti * n_sub + i, block_q), :] += _dot(
-                ds, k, _NN)
+            dk_new = dk + dot(ds, q.astype(jnp.float32), TN)
+            dq_acc_ref[_sub_block(ti * n_sub + i, block_q), :] += dot(
+                ds, k, NN)
             return dk_new, dv_new
 
         if causal:
@@ -369,9 +354,9 @@ def _derive_tile(kernel, s, d, itemsize, causal):
     divide ``s``, one dividing the other so the streamed tile is
     unaffected, neither above the kernel's preferred size — whose buffers
     fit ``_VMEM_BUDGET``; of equal areas the wider key block. A sequence
-    that is no multiple of 128 gets the one block the old default gave,
-    and so does one whose streamed tiles alone overflow (wide or f32
-    operands).
+    that is no multiple of 128 gets one square block of at most 128, and
+    one whose streamed tiles alone overflow (wide or f32 operands) 128 x
+    128.
 
     A causal sequence no longer than the preferred tile's long side
     (1024) leaves that tile one sub-block a grid step, so the skipping of
@@ -405,7 +390,7 @@ def _derive_tile(kernel, s, d, itemsize, causal):
 
 def _score_tile(kernel, s, d, itemsize, causal, block_q, block_k):
     """``(block_q, block_k, derived)`` for one of the two kernels: an
-    explicit integer is honoured as ever (clipped to divide ``s``);
+    explicit integer is honoured (clipped to divide ``s``);
     ``None`` takes that side of the tile derived from the shape."""
     derived = block_q is None or block_k is None
     if derived:
@@ -416,20 +401,14 @@ def _score_tile(kernel, s, d, itemsize, causal, block_q, block_k):
 
 
 def _count_trace(kernel, block_q, block_k, derived):
-    """The engagement counter: which score tile each traced kernel got and
-    whether the rule or the caller chose it. Trace-time Python only."""
-    try:
-        from horovod_tpu import metrics
-
-        metrics.counter(
-            "hvt_flash_kernel_traces_total",
-            "flash-attention kernels traced into compiled programs, by "
-            "score tile (counted per trace, not per execution)",
-            ("kernel", "block_q", "block_k", "derived"),
-        ).labels(kernel=kernel, block_q=str(block_q), block_k=str(block_k),
-                 derived=str(int(derived))).inc()
-    except Exception:
-        pass  # telemetry must never break a trace
+    """Which score tile each traced kernel got and whether the rule or the
+    caller chose it."""
+    _pallas.count_trace(
+        "hvt_flash_kernel_traces_total",
+        "flash-attention kernels traced into compiled programs, by "
+        "score tile (counted per trace, not per execution)",
+        kernel=kernel, block_q=block_q, block_k=block_k,
+        derived=int(derived))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -467,14 +446,6 @@ def _seq_tile(s, block_q, block_k):
     return best
 
 
-def _out(shape, dtype, *operands):
-    """A kernel's output: under ``jax.shard_map`` it varies over every
-    mesh axis an operand varies over (``check_vma``, the default there,
-    refuses an output that does not say)."""
-    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
-    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-
-
 class _Plan(NamedTuple):
     """All a kernel call is built from besides its operands' shapes."""
     scale: float
@@ -494,7 +465,7 @@ def _plan(kernel, q, scale, causal, block_q, block_k):
     block_q, block_k, derived = _score_tile(
         kernel, s, d, q.dtype.itemsize, causal, block_q, block_k)
     return _Plan(scale, causal, block_q, block_k, derived,
-                 _seq_tile(s, block_q, block_k), _interpret())
+                 _seq_tile(s, block_q, block_k), _pallas.interpret())
 
 
 def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype):
@@ -532,8 +503,8 @@ def _fwd_call(q, k, v, *, plan, out_dtype):
         out_specs=[qspec,
                    pl.BlockSpec((1, 1, block_q, 1),
                                 lambda bi, hi, qi, ti: (bi, hi, qi, 0))],
-        out_shape=[_out(q.shape, out_dtype, q, k, v),
-                   _out((b, h, s, 1), jnp.float32, q, k, v)],
+        out_shape=[_pallas.out(q.shape, out_dtype, q, k, v),
+                   _pallas.out((b, h, s, 1), jnp.float32, q, k, v)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
@@ -597,9 +568,9 @@ def _bwd_call(q, k, v, do, lse, delta, *, plan):
         grid=(b, h, n_k, s // tile),
         in_specs=[kv_in_ki, kv_in_ki, q_tile, q_tile, vec_tile, vec_tile],
         out_specs=[dq_tile, dkv_out_ki, dkv_out_ki],
-        out_shape=[_out(q.shape, q.dtype, *operands),
-                   _out(q.shape, k.dtype, *operands),
-                   _out(q.shape, v.dtype, *operands)],
+        out_shape=[_pallas.out(q.shape, q.dtype, *operands),
+                   _pallas.out(q.shape, k.dtype, *operands),
+                   _pallas.out(q.shape, v.dtype, *operands)],
         scratch_shapes=[pltpu.VMEM((s, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
@@ -665,7 +636,7 @@ def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
         scale = d ** -0.5
     bq, bk, _ = _score_tile("fwd", s, d, q.dtype.itemsize, causal,
                              block_q, block_k)
-    if not _interpret() and (bq % 8 or bk % 8):
+    if not _pallas.interpret() and (bq % 8 or bk % 8):
         # Mosaic refuses the kernel ("cannot statically prove that index
         # in dimension 2 is a multiple of 8"); the interpreter has no
         # such limit, so name it here rather than inside the compiler

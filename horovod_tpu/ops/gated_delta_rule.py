@@ -1,12 +1,19 @@
-"""The chunked gated delta rule (``models/gdn.py``) as three Pallas TPU
-kernels under one custom VJP.
+"""The chunked gated delta rule of ``models/gdn.py``: its plain
+``jax.numpy`` body, three Pallas TPU kernels under one custom VJP, and the
+rule that chooses between them.
+
+``gated_delta_rule`` is what the mixer calls; ``serves`` sends it to the
+kernels (``gated_delta_rule_kernels``) or to ``gated_delta_rule_plain``,
+which holds the equations, is the kernels' reference and has ``jax.grad``
+of itself for a backward pass.
 
 In plain ``jax.numpy`` a chunk's ``[c, c]`` float32 matrices (the decay
 mask, ``k k^T``, ``q k^T``, the triangular system, its inverse and that
 inverse's intermediates) each pass through HBM, the operands are copied
 between ``[b, s, h, d]`` and a chunked layout, and the carry over the
-chunks is an XLA loop of small batched products. Here a chunk of one value
-head is a few hundred KB of VMEM and none of that exists outside it:
+chunks is an XLA loop of small batched products. In the kernels a chunk of
+one value head is a few hundred KB of VMEM and none of that exists outside
+it:
 
 - ``hvt_gdn_inverse`` forms a chunk's decays and system in registers and
   inverts it by blocks. It depends on no state, so it carries nothing and a
@@ -24,24 +31,23 @@ All read ``q``, ``k``, ``v`` as ``[b, s, H d]`` (a block ``(1, c, d)`` at a
 head's columns: the convolution's own output layout) and write the same
 way.
 
-The equations are ``models/gdn.py``'s (``gated_delta_rule_plain`` there is
-this module's reference and the path every other shape and backend
-takes): the decays, their sums and exponents, the system, its inverse and
-that inverse's products are float32 at the caller's precision, the carried
-``S`` and ``dS`` are float32, the other products run in the operands' dtype
-and accumulate in float32; every exponent is of a non-positive number.
-The cumulative sum of ``g`` inside a chunk and its transpose for ``dg`` are
-XLA operations around the kernels (``[b, s, H_v]`` float32).
+Both bodies compute the decays, their sums and exponents, the system, its
+inverse and that inverse's products in float32 at the caller's precision
+and carry ``S`` (the kernels ``dS`` too) in float32; the other products
+run in the operands' dtype and accumulate in float32; every exponent is of
+a non-positive number. The cumulative sum of ``g`` inside a chunk and its
+transpose for ``dg`` are XLA operations around the kernels (``[b, s,
+H_v]`` float32).
 
 On the CPU the same kernel code runs through the Pallas interpreter, at
 any chunk and head width; compiled, Mosaic wants a chunk and head widths
-that are multiples of 128 (``models/gdn.py`` sends it nothing else).
+that are multiples of 128 (``serves`` sends it nothing else).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, NamedTuple
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -49,10 +55,199 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.flash_attention import _NN, _NT, _TN, _interpret, _out
+from horovod_tpu.ops import _pallas
+from horovod_tpu.ops._pallas import NN, NT, TN, dot
 
 _F32 = jnp.float32
+# The chunk the rule takes where the caller names none: the longest the
+# sequence allows up to this, and the one chunk the kernels serve (a
+# chunk's [c, c] matrices are then whole 128 x 128 tiles). The inverse
+# costs by the chunk's square a position and the carry by the number of
+# chunks. On a v5e at 2 x 8192, 32 value heads of 128 x 128 on 16 key
+# heads, bf16, a forward and backward of the plain body took 50.4 ms at 64
+# (the source's kernel's), 49.8 at 128 and 87.1 at 256 (PERF.md section 6,
+# PR 33); at 128 the states kept for the backward pass are half as many as
+# at 64. The kernels at 128: chip_smoke.py's gdn8192 and PERF.md section
+# 6, PR 34.
+CHUNK = 128
+_HIGHEST = jax.lax.Precision.HIGHEST
 
+
+def chunk_for(seq_len: int, chunk: Optional[int] = None) -> int:
+    """The chunk length the rule uses for ``seq_len`` positions."""
+    return max(1, min(chunk or CHUNK, seq_len))
+
+
+def serves(chunk: int, key_dim: int, value_dim: int) -> bool:
+    """Whether the rule goes to the kernels, from what can be observed
+    (static trace-time facts, so the choice compiles away): a TPU backend,
+    the chunk they were measured at, and heads that fill whole 128-lane
+    tiles. Everything else stays on ``gated_delta_rule_plain``, so the
+    choice never raises for a shape that serves."""
+    return (_pallas.on_tpu() and chunk == CHUNK
+            and key_dim % 128 == 0 and value_dim % 128 == 0)
+
+
+def gated_delta_rule(q, k, v, g, beta, *, chunk: Optional[int] = None,
+                     state_dtype=_F32, precision=_HIGHEST):
+    """The gated delta rule, chunked: ``gated_delta_rule_plain``'s
+    arguments and result, by the kernels where ``serves`` says so and by
+    ``gated_delta_rule_plain`` itself everywhere else."""
+    c = chunk_for(q.shape[1], chunk)
+    rule = (gated_delta_rule_kernels
+            if serves(c, q.shape[-1], v.shape[-1])
+            else gated_delta_rule_plain)
+    return rule(q, k, v, g, beta, chunk=c, state_dtype=state_dtype,
+                precision=precision)
+
+
+# ------------------------------------------------------------- plain body
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def unit_lower_inverse(lower, precision=_HIGHEST):
+    """``(I + N)^-1`` for ``N`` the strictly lower triangle of ``lower
+    [..., c, c]`` (what is on or above the diagonal is not read), by
+    blocks: the inverse of the diagonal blocks of size ``m`` is that of
+    the blocks of size ``2 m`` once ``T <- T - T O T`` has been taken with
+    ``O`` the part of ``N`` in the lower left quarter of each ``2 m``
+    block (``[[A, 0], [B, D]]^-1 = [[A^-1, 0], [-D^-1 B A^-1, D^-1]]``).
+    From ``m = 1``, where the inverse is the identity, ``ceil(log2 c)``
+    such steps, two ``[c, c]`` products each, every intermediate the true
+    inverse of a block-diagonal part of the system (no power of ``N`` is
+    ever formed). In ``lower``'s dtype, its products at ``precision``. The
+    backward pass is the inverse's own, ``-T^T g T^T``, so that it keeps
+    ``T`` and no step's intermediate."""
+    c = lower.shape[-1]
+    row, col = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    n = jnp.tril(lower, -1)
+    inverse = jnp.broadcast_to(jnp.eye(c, dtype=lower.dtype), n.shape)
+    m = 1
+    while m < c:
+        quarter = ((row // (2 * m) == col // (2 * m))
+                   & ((row // m) % 2 == 1) & ((col // m) % 2 == 0))
+        inverse = inverse - jnp.matmul(
+            jnp.matmul(inverse, jnp.where(quarter, n, 0.0),
+                       precision=precision), inverse, precision=precision)
+        m *= 2
+    return inverse
+
+
+def _unit_lower_inverse_fwd(lower, precision):
+    inverse = unit_lower_inverse(lower, precision)
+    return inverse, inverse
+
+
+def _unit_lower_inverse_bwd(precision, inverse, g):
+    transposed = jnp.swapaxes(inverse, -1, -2)
+    return (jnp.tril(-jnp.matmul(
+        jnp.matmul(transposed, g, precision=precision), transposed,
+        precision=precision), -1),)
+
+
+unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def gated_delta_rule_plain(q, k, v, g, beta, *, chunk: Optional[int] = None,
+                           state_dtype=_F32, precision=_HIGHEST):
+    """The gated delta rule, chunked, in plain ``jax.numpy``: the path of
+    every backend and shape the kernels do not serve, and their reference.
+
+    ``q``, ``k`` ``[batch, s, H_k, d_k]`` (normalised and scaled by the
+    caller), ``v [batch, s, H_v, d_v]``, ``g`` and ``beta`` ``[batch, s,
+    H_v]`` float32 (``g <= 0``); value head ``h`` reads key head ``h //
+    (H_v / H_k)``. Returns ``o [batch, s, H_v, d_v]`` in ``v.dtype`` with
+    ``S_t = exp(g_t) S_{t-1} + k_t (beta_t (v_t - exp(g_t) S_{t-1}^T
+    k_t))^T`` from ``S = 0`` and ``o_t = S_t^T q_t``.
+
+    With ``G_i`` the cumulative sum of ``g`` inside a chunk, the
+    corrections ``u_i = beta_i (v_i - S'_i^T k_i)`` of a chunk entered
+    with state ``S`` solve ``(I + N) u = beta v - (beta k exp(G)) S``,
+    ``N`` the strictly lower triangle of ``beta_i (k_i . k_j) exp(G_i -
+    G_j)``: with ``T = (I + N)^-1`` (``unit_lower_inverse``), ``W = T
+    (beta v)`` and ``U = T (beta k exp(G))`` are known before the carry
+    and ``u = W - U S`` inside it. A position reads ``o_i = (q_i exp(G_i))
+    S + sum_{j <= i} (q_i . k_j) exp(G_i - G_j) u_j`` and the chunk hands
+    on ``exp(G_last) S + (k exp(G_last - G))^T u``. Every exponent is of a
+    non-positive number. The carry is a ``lax.scan`` over the chunks (the
+    corrections of a chunk depend on the state that enters it); all else
+    is batched over them. A sequence the chunk does not divide is padded
+    with positions whose ``g`` and ``beta`` are 0 (they decay nothing,
+    write nothing and are cut off again). ``state_dtype`` is what the
+    decays, their sums, the inverse and the carried state are computed in
+    and ``precision`` that of the float32 products that invert the system:
+    the caller's constants (``gdn.STATE_DTYPE``, ``gdn.INVERSE_PRECISION``).
+    """
+    batch, seq, key_heads, d_k = q.shape
+    value_heads, d_v = v.shape[-2:]
+    per_key = value_heads // key_heads
+    c = chunk_for(seq, chunk)
+    pad = -seq % c
+    if pad:
+        grow = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        q, k, v, g, beta = grow(q), grow(k), grow(v), grow(g), grow(beta)
+    chunks = (seq + pad) // c
+    dtype = v.dtype
+    # [b, n, key head, value head of it, position, ...]: a chunk's [c, c]
+    # matrices with the positions last, so that they are the tile
+    to_key = lambda t: jnp.transpose(
+        t.reshape(batch, chunks, c, key_heads, d_k), (0, 1, 3, 2, 4))
+    to_value = lambda t, *last: jnp.transpose(
+        t.reshape(batch, chunks, c, key_heads, per_key, *last),
+        (0, 1, 3, 4, 2) + tuple(range(5, 5 + len(last))))
+    q, k = to_key(q), to_key(k)                     # [b, n, K, c, d_k]
+    v = to_value(v, d_v)                            # [b, n, K, r, c, d_v]
+    g = to_value(g.astype(state_dtype))             # [b, n, K, r, c]
+    beta = to_value(beta.astype(state_dtype))
+
+    cum = jnp.cumsum(g, axis=-1)                    # G_i, inclusive
+    last = cum[..., -1]                             # [b, n, K, r]
+    lag = cum[..., :, None] - cum[..., None, :]     # G_i - G_j
+    at, before = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    decay = jnp.exp(jnp.where(at >= before, lag, -jnp.inf)).astype(_F32)
+    kk = jnp.einsum("bnkid,bnkjd->bnkij", k, k, preferred_element_type=_F32)
+    qk = jnp.einsum("bnkid,bnkjd->bnkij", q, k, preferred_element_type=_F32)
+    # T = (I + N)^-1, N_ij = beta_i (k_i . k_j) exp(G_i - G_j) for j < i
+    inverse = unit_lower_inverse(
+        (beta[..., :, None] * kk[:, :, :, None] * decay).astype(state_dtype),
+        precision).astype(dtype)
+    by_key = lambda t: t[:, :, :, None]             # beside its value heads
+    k_in = by_key(k).astype(_F32) * (beta * jnp.exp(cum))[..., None]
+    w = jnp.einsum("bnkrij,bnkrjd->bnkrid", inverse,
+                   (v.astype(_F32) * beta[..., None]).astype(dtype),
+                   preferred_element_type=_F32)
+    u = jnp.einsum("bnkrij,bnkrjd->bnkrid", inverse, k_in.astype(dtype),
+                   preferred_element_type=_F32)
+    k_out = (by_key(k).astype(_F32)
+             * jnp.exp(last[..., None] - cum)[..., None]).astype(dtype)
+
+    def carry(state, chunk_in):
+        w_c, u_c, k_c, keep = chunk_in      # a chunk's, [b, K, r, ...]
+        new = (w_c - jnp.einsum("bkrid,bkrde->bkrie", u_c,
+                                state.astype(dtype),
+                                preferred_element_type=_F32)).astype(dtype)
+        added = jnp.einsum("bkrid,bkrie->bkrde", k_c, new,
+                           preferred_element_type=_F32)
+        return ((state * keep[..., None, None] + added).astype(state_dtype),
+                (new, state))
+
+    first = lambda t: jnp.moveaxis(t, 1, 0)
+    _, (new, entering) = jax.lax.scan(
+        carry, jnp.zeros((batch, key_heads, per_key, d_k, d_v), state_dtype),
+        (first(w), first(u.astype(dtype)), first(k_out),
+         first(jnp.exp(last))))
+    new, entering = jnp.moveaxis(new, 0, 1), jnp.moveaxis(entering, 0, 1)
+    inside = (qk[:, :, :, None] * decay).astype(dtype)
+    o = jnp.einsum("bnkrij,bnkrjd->bnkrid", inside, new,
+                   preferred_element_type=_F32)
+    q_in = (by_key(q).astype(_F32) * jnp.exp(cum)[..., None]).astype(dtype)
+    o = o + jnp.einsum("bnkrid,bnkrde->bnkrie", q_in, entering.astype(dtype),
+                       preferred_element_type=_F32)
+    o = jnp.transpose(o, (0, 1, 4, 2, 3, 5)).reshape(
+        batch, seq + pad, value_heads, d_v)[:, :seq]
+    return o.astype(dtype)
+
+
+# ------------------------------------------------- the kernels' arithmetic
 
 class _Plan(NamedTuple):
     """All a kernel call is built from besides its operands' shapes. Made
@@ -72,25 +267,13 @@ class _Plan(NamedTuple):
 
 
 def _count_trace(kernel, plan):
-    """The engagement counter: which kernels a job got, by the chunk and
-    head widths. Trace-time Python only."""
-    try:
-        from horovod_tpu import metrics
-
-        metrics.counter(
-            "hvt_gdn_kernel_traces_total",
-            "gated delta rule kernels traced into compiled programs "
-            "(counted per trace, not per execution)",
-            ("kernel", "chunk", "key_dim", "value_dim"),
-        ).labels(kernel=kernel, chunk=str(plan.chunk),
-                 key_dim=str(plan.key_dim),
-                 value_dim=str(plan.value_dim)).inc()
-    except Exception:
-        pass  # telemetry must never break a trace
-
-
-def _dot(a, b, dims):
-    return jax.lax.dot_general(a, b, dims, preferred_element_type=_F32)
+    """Which kernels a job got, by the chunk and head widths."""
+    _pallas.count_trace(
+        "hvt_gdn_kernel_traces_total",
+        "gated delta rule kernels traced into compiled programs "
+        "(counted per trace, not per execution)",
+        kernel=kernel, chunk=plan.chunk, key_dim=plan.key_dim,
+        value_dim=plan.value_dim)
 
 
 def _rounder(state_dtype):
@@ -122,7 +305,7 @@ def _positions(c):
 
 def _unit_lower_inverse(n, row, col, plan):
     """``(I + N)^-1`` for a strictly lower ``n [c, c]`` float32, by blocks
-    as ``gdn.unit_lower_inverse`` has it: ``T <- T - T O T`` with ``O`` the
+    as ``unit_lower_inverse`` has it: ``T <- T - T O T`` with ``O`` the
     part of ``N`` in the lower left quarter of each ``2 m`` block, from
     ``m = 1``; float32 products at ``plan.precision``. ``T O T`` is zero
     outside the rows of the blocks' second halves, so where those are
@@ -132,7 +315,7 @@ def _unit_lower_inverse(n, row, col, plan):
     c = n.shape[0]
     low = _rounder(plan.state_dtype)
     dot32 = lambda a, b: jax.lax.dot_general(
-        a, b, _NN, precision=plan.precision, preferred_element_type=_F32)
+        a, b, NN, precision=plan.precision, preferred_element_type=_F32)
     # m = 1: the blocks' inverses are the identity, so T O T is O itself
     inverse = low((row == col).astype(_F32) - jnp.where(
         ((row ^ col) == 1) & ((row & 1) == 1), n, 0.0))
@@ -166,7 +349,7 @@ def _system_inverse(k, g_col, g_row, beta, row, col, plan):
     beta_i (k_i . k_j) exp(G_i - G_j)`` for ``j < i``."""
     low = _rounder(plan.state_dtype)
     system = jnp.where(row > col, low(
-        beta * _dot(k, k, _NT) * _decay(g_col, g_row, row, col)), 0.0)
+        beta * dot(k, k, NT) * _decay(g_col, g_row, row, col)), 0.0)
     return _unit_lower_inverse(system, row, col, plan)
 
 
@@ -199,18 +382,18 @@ def _chunk(state, q, k, v, g_col, g_row, beta, inverse, row, col, plan):
     low = _rounder(plan.state_dtype)
     c = q.shape[0]
     decay = _decay(g_col, g_row, row, col)
-    qk = _dot(q, k, _NT)
+    qk = dot(q, k, NT)
     grown = jnp.exp(g_col)
     k32 = k.astype(_F32)
     v_in = (v.astype(_F32) * beta).astype(dtype)
     k_in = (k32 * (beta * grown)).astype(dtype)
-    w = _dot(inverse, v_in, _NN)
-    u = _dot(inverse, k_in, _NN).astype(dtype)
+    w = dot(inverse, v_in, NN)
+    u = dot(inverse, k_in, NN).astype(dtype)
     entered = state.astype(dtype)
-    new = (w - _dot(u, entered, _NN)).astype(dtype)
+    new = (w - dot(u, entered, NN)).astype(dtype)
     inside = (qk * decay).astype(dtype)
     q_in = (q.astype(_F32) * grown).astype(dtype)
-    o = _dot(inside, new, _NN) + _dot(q_in, entered, _NN)
+    o = dot(inside, new, NN) + dot(q_in, entered, NN)
     # G_last as [1, 1]; Mosaic broadcasts along one of sublanes and lanes
     # at a time, so what scales the state is a row
     last = jnp.sum(jnp.where(col[:1] == c - 1, g_row, 0.0), axis=1,
@@ -218,7 +401,7 @@ def _chunk(state, q, k, v, g_col, g_row, beta, inverse, row, col, plan):
     left = jnp.exp(last - g_col)
     kept = jnp.exp(jnp.broadcast_to(last, (1, state.shape[1])))
     k_out = (k32 * left).astype(dtype)
-    handed = low(state * kept + _dot(k_out, new, _TN))
+    handed = low(state * kept + dot(k_out, new, TN))
     return _Chunk(o, handed, decay, qk, grown, v_in, k_in, u, new, inside,
                   q_in, k_out, left, kept)
 
@@ -243,32 +426,32 @@ def _chunk_backward(at, state, q, k, v, g_col, g_row, beta, inverse, do,
     handed = d_handed.astype(dtype)
 
     # o = inside new + q_in S;  S' = kept S + k_out^T new
-    d_inside = _dot(do, at.new, _NT)                        # [c, c]
-    d_new = _dot(at.inside, do, _TN) + _dot(at.k_out, handed, _NN)
-    d_q_in = _dot(do, entered, _NT)                         # [c, d_k]
-    d_k_out = _dot(at.new, handed, _NT)                     # [c, d_k]
+    d_inside = dot(do, at.new, NT)                        # [c, c]
+    d_new = dot(at.inside, do, TN) + dot(at.k_out, handed, NN)
+    d_q_in = dot(do, entered, NT)                         # [c, d_k]
+    d_k_out = dot(at.new, handed, NT)                     # [c, d_k]
     # new = T v_in - u S
     d_new = d_new.astype(dtype)
-    d_u = -_dot(d_new, entered, _NT)                        # [c, d_k]
-    d_state = (d_handed * at.kept + _dot(at.q_in, do, _TN)
-               - _dot(at.u, d_new, _TN))
+    d_u = -dot(d_new, entered, NT)                        # [c, d_k]
+    d_state = (d_handed * at.kept + dot(at.q_in, do, TN)
+               - dot(at.u, d_new, TN))
     d_u = d_u.astype(dtype)
-    d_inverse = _dot(d_new, at.v_in, _NT) + _dot(d_u, at.k_in, _NT)
-    d_v_in = _dot(inverse, d_new, _TN)                      # [c, d_v]
-    d_k_in = _dot(inverse, d_u, _TN)                        # [c, d_k]
+    d_inverse = dot(d_new, at.v_in, NT) + dot(d_u, at.k_in, NT)
+    d_v_in = dot(inverse, d_new, TN)                      # [c, d_v]
+    d_k_in = dot(inverse, d_u, TN)                        # [c, d_k]
     # T = (I + N)^-1
     inverse32 = inverse.astype(_F32)
     d_system = jnp.where(row > col, -dot32(
-        inverse32, dot32(d_inverse, inverse32, _NT), _TN), 0.0)
+        inverse32, dot32(d_inverse, inverse32, NT), TN), 0.0)
     # N = beta kk decay (strictly lower);  inside = qk decay
-    kk = _dot(k, k, _NT)
+    kk = dot(k, k, NT)
     d_kk = d_system * beta * at.decay
     d_qk = d_inside * at.decay
     d_decay = d_system * beta * kk + d_inside * at.qk
     d_lag = d_decay * at.decay                  # decay = exp(G_i - G_j)
     d_kk, d_qk = d_kk.astype(dtype), d_qk.astype(dtype)
-    dq = _dot(d_qk, k, _NN) + d_q_in * at.grown
-    dk = (_dot(d_kk, k, _NN) + _dot(d_kk, k, _TN) + _dot(d_qk, q, _TN)
+    dq = dot(d_qk, k, NN) + d_q_in * at.grown
+    dk = (dot(d_kk, k, NN) + dot(d_kk, k, TN) + dot(d_qk, q, TN)
           + d_k_in * (beta * at.grown) + d_k_out * at.left)
     dv = d_v_in * beta
     # k_in = k beta exp(G), v_in = v beta, q_in = q exp(G),
@@ -444,7 +627,7 @@ def _call(kernel, name, plan, operands, in_specs, out_specs, out_shape, *,
         functools.partial(kernel, plan=plan),
         grid=(batch, seq // plan.chunk, plan.key_heads // plan.key_block),
         in_specs=in_specs, out_specs=out_specs,
-        out_shape=[_out(shape, dtype, *operands)
+        out_shape=[_pallas.out(shape, dtype, *operands)
                    for shape, dtype in out_shape],
         scratch_shapes=[pltpu.VMEM(
             (plan.value_heads, plan.key_dim, plan.value_dim), _F32)]
@@ -572,19 +755,16 @@ def _prepare(q, k, v, g, beta, chunk, state_dtype, precision):
     beta = stored(beta)
     plan = _Plan(chunk, key_heads, value_heads, d_k, d_v,
                  _key_block(key_heads, value_heads // key_heads),
-                 jnp.dtype(state_dtype), precision, _interpret())
+                 jnp.dtype(state_dtype), precision, _pallas.interpret())
     return plan, (flat(q), flat(k), flat(v), g_col, beta), pad
 
 
-def gated_delta_rule(q, k, v, g, beta, *, chunk, state_dtype=_F32,
-                     precision=jax.lax.Precision.HIGHEST):
-    """``models/gdn.py``'s ``gated_delta_rule`` through the kernels:
-    ``q``, ``k`` ``[batch, s, H_k, d_k]``, ``v [batch, s, H_v, d_v]``,
-    ``g`` and ``beta`` ``[batch, s, H_v]`` float32 (``g <= 0``) -> ``o``
-    like ``v``. Differentiable in all five. A sequence the chunk does not
-    divide is padded with positions whose ``g`` and ``beta`` are 0.
-    ``state_dtype`` and ``precision`` are the caller's constants
-    (``gdn.STATE_DTYPE``, ``gdn.INVERSE_PRECISION``)."""
+def gated_delta_rule_kernels(q, k, v, g, beta, *, chunk, state_dtype=_F32,
+                             precision=_HIGHEST):
+    """``gated_delta_rule_plain`` through the kernels: its arguments (the
+    chunk named) and its result. Differentiable in all five. A sequence
+    the chunk does not divide is padded with positions whose ``g`` and
+    ``beta`` are 0."""
     seq = q.shape[1]
     plan, operands, pad = _prepare(q, k, v, g, beta, chunk, state_dtype,
                                    precision)

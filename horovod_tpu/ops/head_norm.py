@@ -1,15 +1,20 @@
 """The per-head norms of ``models/gdn.py`` (the L2 norm of q and k before
-the rule, ``RMSNorm(o) w silu(z)`` after it) as four Pallas TPU kernels
-under two custom VJPs, on the flat ``[b, s, H d]`` layout the mixer's
-other kernels read and write.
+the rule, ``RMSNorm(o) w silu(z)`` after it): their plain ``jax.numpy``
+bodies, four Pallas TPU kernels under two custom VJPs on the flat ``[b, s,
+H d]`` layout the mixer's other kernels read and write, and the rule that
+chooses between them.
+
+``l2_norm`` and ``gated_norm`` are what the mixer calls; ``serves`` sends
+them to the kernels (``l2_norm_kernels``, ``gated_norm_kernels``) or to
+``l2_norm_plain`` and ``gated_norm_plain``, the kernels' references.
 
 In plain ``jax.numpy`` a norm over a head is taken on ``[b, s, H, d]``: at
 ``d`` = 128 that array and ``[b, s, H d]`` tile differently on the chip, so
 each reshape between the convolution's result, the rule's operands, its
 result and the out-projection's operand is a copy through HBM, and the
 norms' float32 values pass through HBM beside them. A head is ``d``
-adjacent lanes, so here a norm over a head is a reduction inside a block's
-columns and nothing leaves ``[b, s, H d]``:
+adjacent lanes, so in the kernels a norm over a head is a reduction inside
+a block's columns and nothing leaves ``[b, s, H d]``:
 
 - ``hvt_l2_norm_fwd``: ``x rsqrt(sum x^2 + eps) scale`` a head.
 - ``hvt_l2_norm_bwd``: the reciprocal root made again from ``x``; ``dx``.
@@ -27,9 +32,6 @@ lanes`` of one sequence with ``lanes`` a whole number of heads, walks it
 ``sub`` rows at a time and a head at a time, and is independent of every
 other step. A sequence the block does not divide ends in a block whose
 rows past the end are never written (and are masked out of ``dw``).
-``gdn.l2_normalise_plain`` and ``gdn.gated_head_norm_plain`` are the
-references and the path of every backend and shape the kernels do not
-serve.
 
 On the CPU the same kernel code runs through the Pallas interpreter, at
 any head width; compiled, Mosaic wants heads in whole 128-lane tiles and
@@ -47,8 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.causal_conv import _largest
-from horovod_tpu.ops.flash_attention import _interpret, _out
+from horovod_tpu.ops import _pallas
 
 _F32 = jnp.float32
 # What a grid step takes where the caller names nothing: ROWS positions
@@ -82,43 +83,80 @@ class _Plan(NamedTuple):
 
 
 def _count_trace(kernel, plan, channels):
-    """The engagement counter: which kernels a job got, by the heads and
-    their width. Trace-time Python only."""
-    try:
-        from horovod_tpu import metrics
-
-        metrics.counter(
-            "hvt_head_norm_kernel_traces_total",
-            "per-head norm kernels traced into compiled programs "
-            "(counted per trace, not per execution)",
-            ("kernel", "heads", "dim"),
-        ).labels(kernel=kernel, heads=str(channels // plan.dim),
-                 dim=str(plan.dim)).inc()
-    except Exception:
-        pass  # telemetry must never break a trace
+    """Which kernels a job got, by the heads and their width."""
+    _pallas.count_trace(
+        "hvt_head_norm_kernel_traces_total",
+        "per-head norm kernels traced into compiled programs "
+        "(counted per trace, not per execution)",
+        kernel=kernel, heads=channels // plan.dim, dim=plan.dim)
 
 
 def serves(seq_len: int, dim: int) -> bool:
     """Whether a norm over heads of ``dim`` channels of ``[b, seq_len, H
     dim]`` goes to the kernels, from what can be observed (static
-    trace-time facts, so the choice compiles away): a TPU backend
-    (elsewhere the kernels are interpreted, far slower than
-    ``jax.numpy``), heads that fill whole 128-lane tiles and positions in
-    whole bf16 tiles. Everything else stays on the plain bodies, so the
-    choice never raises for a shape that serves."""
-    return (jax.default_backend() == "tpu" and dim % 128 == 0
-            and seq_len % 16 == 0)
+    trace-time facts, so the choice compiles away): a TPU backend, heads
+    that fill whole 128-lane tiles and positions in whole bf16 tiles.
+    Everything else stays on the plain bodies, so the choice never raises
+    for a shape that serves."""
+    return _pallas.on_tpu() and dim % 128 == 0 and seq_len % 16 == 0
+
+
+def l2_norm(x, dim: Optional[int] = None, *, eps: float, scale: float = 1.0):
+    """``x / sqrt(sum x^2 + eps) * scale`` over each group of ``dim``
+    adjacent channels of the last axis (a head of ``[..., H dim]``; the
+    whole axis where none is named), float32 inside, like ``x``. By the
+    kernels where ``serves`` says so and by ``l2_norm_plain`` everywhere
+    else."""
+    dim = dim or x.shape[-1]
+    if x.ndim == 3 and serves(x.shape[1], dim):
+        return l2_norm_kernels(x, dim, eps=eps, scale=scale)
+    return l2_norm_plain(x, dim, eps=eps, scale=scale)
+
+
+def gated_norm(o, z, w, *, eps: float):
+    """``RMSNorm(o) * w * silu(z)`` with the mean square over each group
+    of ``w.shape[-1]`` adjacent channels of the last axis (a head of
+    ``[..., H d]``, or of ``[..., H, d]``), the norm before the gate;
+    float32 inside, like ``o``. By the kernels where ``serves`` says so
+    and by ``gated_norm_plain`` everywhere else."""
+    if o.ndim == 3 and serves(o.shape[1], w.shape[-1]):
+        return gated_norm_kernels(o, z, w, eps=eps)
+    return gated_norm_plain(o, z, w, eps=eps)
+
+
+def _by_heads(x, dim):
+    return x.reshape(*x.shape[:-1], x.shape[-1] // dim, dim)
+
+
+def l2_norm_plain(x, dim: Optional[int] = None, *, eps: float,
+                  scale: float = 1.0):
+    """``l2_norm`` in plain ``jax.numpy``: the path of every backend and
+    shape the kernels do not serve, and their reference."""
+    heads = _by_heads(x.astype(_F32), dim or x.shape[-1])
+    return (heads * jax.lax.rsqrt(
+        jnp.sum(heads * heads, axis=-1, keepdims=True) + eps) * scale
+            ).astype(x.dtype).reshape(x.shape)
+
+
+def gated_norm_plain(o, z, w, *, eps: float):
+    """``gated_norm`` in plain ``jax.numpy``: the path of every backend
+    and shape the kernels do not serve, and their reference."""
+    o32 = _by_heads(o.astype(_F32), w.shape[-1])
+    normed = o32 * jax.lax.rsqrt(
+        jnp.mean(o32 * o32, axis=-1, keepdims=True) + eps)
+    gate = jax.nn.silu(_by_heads(z.astype(_F32), w.shape[-1]))
+    return (normed * w * gate).astype(o.dtype).reshape(o.shape)
 
 
 def _plan(x, dim, eps, scale, rows, lanes, sub):
     _, seq, channels = x.shape
     if channels % dim:
         raise ValueError(f"heads of {dim} do not divide {channels} channels")
-    lanes = lanes or _largest(channels, max(LANES, dim), dim)
+    lanes = lanes or _pallas.largest(channels, max(LANES, dim), dim)
     # ROWS x LANES elements a block, in whole passes of SUB rows
     most = max(ROWS * LANES // max(lanes, LANES) // SUB, 1) * SUB
     rows = rows or min(most, seq)
-    sub = sub or _largest(rows, SUB, _FOLD)
+    sub = sub or _pallas.largest(rows, SUB, _FOLD)
     if channels % lanes or lanes % dim or rows % sub or (
             sub % _FOLD and sub != rows):
         raise ValueError(
@@ -127,7 +165,7 @@ def _plan(x, dim, eps, scale, rows, lanes, sub):
             f"heads that divide the channels, passes in multiples of "
             f"{_FOLD} that divide the rows")
     return _Plan(dim, float(eps), float(scale), rows, lanes, sub,
-                 _interpret())
+                 _pallas.interpret())
 
 
 # ---------------------------------------------------------------- kernels
@@ -252,7 +290,7 @@ def _call(kernel, name, plan, operands, in_specs, out_specs, out_shape):
         kernel,
         grid=(batch, channels // plan.lanes, pl.cdiv(seq, plan.rows)),
         in_specs=in_specs, out_specs=out_specs,
-        out_shape=[_out(shape, dtype, *operands)
+        out_shape=[_pallas.out(shape, dtype, *operands)
                    for shape, dtype in out_shape],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3),
@@ -340,21 +378,22 @@ def _gated_bwd(plan, res, g):
 _gated.defvjp(_gated_fwd, _gated_bwd)
 
 
-def l2_norm(x, dim: int, *, eps: float, scale: float = 1.0,
-            rows: Optional[int] = None, lanes: Optional[int] = None,
-            sub: Optional[int] = None):
-    """``gdn.l2_normalise_plain`` through the kernels: ``x [b, s, H dim]``
-    -> ``x rsqrt(sum x^2 + eps) scale`` with the sum over each head's
-    ``dim`` channels, float32 inside, like ``x``. Differentiable.
-    ``rows``, ``lanes`` and ``sub`` name a grid step's block and the rows a
-    pass inside it takes (a test's or a microbenchmark's; a model names
+def l2_norm_kernels(x, dim: int, *, eps: float, scale: float = 1.0,
+                    rows: Optional[int] = None, lanes: Optional[int] = None,
+                    sub: Optional[int] = None):
+    """``l2_norm_plain`` through the kernels: ``x [b, s, H dim]`` -> ``x
+    rsqrt(sum x^2 + eps) scale`` with the sum over each head's ``dim``
+    channels, float32 inside, like ``x``. Differentiable. ``rows``,
+    ``lanes`` and ``sub`` name a grid step's block and the rows a pass
+    inside it takes (a test's or a microbenchmark's; a model names
     none)."""
     return _l2(x, _plan(x, dim, eps, scale, rows, lanes, sub))
 
 
-def gated_norm(o, z, w, *, eps: float, rows: Optional[int] = None,
-               lanes: Optional[int] = None, sub: Optional[int] = None):
-    """``gdn.gated_head_norm_plain`` through the kernels: ``o``, ``z``
+def gated_norm_kernels(o, z, w, *, eps: float, rows: Optional[int] = None,
+                       lanes: Optional[int] = None,
+                       sub: Optional[int] = None):
+    """``gated_norm_plain`` through the kernels: ``o``, ``z``
     ``[b, s, H d]``, ``w [d]`` -> ``o rsqrt(mean o^2 + eps) w silu(z)``
     with the mean over each head's ``d`` channels, float32 inside, like
     ``o``. Differentiable in all three."""

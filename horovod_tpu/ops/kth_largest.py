@@ -30,8 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.causal_conv import _largest
-from horovod_tpu.ops.flash_attention import _interpret, _out
+from horovod_tpu.ops import _pallas
 
 # Tokens a grid step takes where the caller names nothing (the largest
 # multiple of LANES up to it that divides the rows), and tokens a walk
@@ -49,28 +48,20 @@ class _Plan(NamedTuple):
 
 
 def _count_trace(plan, width):
-    """The engagement counter. Trace-time Python only."""
-    try:
-        from horovod_tpu import metrics
-
-        metrics.counter(
-            "hvt_moe_kth_kernel_traces_total",
-            "k-th largest score kernels traced into compiled programs "
-            "(counted per trace, not per execution)",
-            ("k", "width", "rows"),
-        ).labels(k=str(plan.k), width=str(width), rows=str(plan.rows)).inc()
-    except Exception:
-        pass  # telemetry must never break a trace
+    _pallas.count_trace(
+        "hvt_moe_kth_kernel_traces_total",
+        "k-th largest score kernels traced into compiled programs "
+        "(counted per trace, not per execution)",
+        k=plan.k, width=width, rows=plan.rows)
 
 
 def serves(rows: int, width: int, k: int) -> bool:
     """Whether a ``[rows, width]`` operand goes to the kernel, from what
     can be observed (static trace-time facts, so the choice compiles
-    away): a TPU backend (elsewhere the kernel is interpreted, far slower
-    than a sort), and rows and a width in whole 128-lane tiles, the block
-    is turned in."""
-    return (jax.default_backend() == "tpu" and rows % LANES == 0
-            and width % LANES == 0 and 0 < k <= width)
+    away): a TPU backend, and rows and a width in whole 128-lane tiles,
+    the block is turned in."""
+    return (_pallas.on_tpu() and rows % LANES == 0 and width % LANES == 0
+            and 0 < k <= width)
 
 
 def _kernel(x_ref, o_ref, turned_ref, *, plan):
@@ -111,7 +102,7 @@ def _call(x, *, plan):
         grid=(n // plan.rows,),
         in_specs=[pl.BlockSpec((plan.rows, width), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, plan.rows), lambda i: (0, i)),
-        out_shape=_out((1, n), jnp.float32, x),
+        out_shape=_pallas.out((1, n), jnp.float32, x),
         scratch_shapes=[pltpu.VMEM((width, plan.rows), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
@@ -132,9 +123,9 @@ def kth_largest(x, k: int, *, rows: Optional[int] = None):
     hands it what it has stopped one at. ``rows`` names a grid step's
     tokens (a test's or a microbenchmark's; a model names none)."""
     n = x.shape[0]
-    rows = rows or _largest(n, ROWS, LANES)
+    rows = rows or _pallas.largest(n, ROWS, LANES)
     if n % rows or rows % LANES:
         raise ValueError(f"blocks of {rows} rows do not tile {n} rows in "
                          f"walks of {LANES}")
     return _call(x.astype(jnp.float32),
-                 plan=_Plan(int(k), rows, _interpret()))
+                 plan=_Plan(int(k), rows, _pallas.interpret()))

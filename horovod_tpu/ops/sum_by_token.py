@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.flash_attention import _interpret, _out
+from horovod_tpu.ops import _pallas
 
 # Tokens a tile and sorted rows a chunk, where they divide the round (the
 # largest part of each that does, elsewhere: ``tile_rows``).
@@ -162,7 +162,7 @@ def _call(rows, weight, total, plan, *, dtype, interpret):
             num_scalar_prefetch=4, grid=(plan.tile_of.shape[0],),
             in_specs=specs, out_specs=a_tile,
             scratch_shapes=[pltpu.VMEM((tile, width), jnp.float32)]),
-        out_shape=_out((n, width), dtype, rows),
+        out_shape=_pallas.out((n, width), dtype, rows),
         input_output_aliases=({4 + len(operands) - 1: 0}
                               if total is not None and total.dtype == dtype
                               else {}),
@@ -179,7 +179,7 @@ def sum_by_token(rows, plan: Plan, *, weight=None, total=None, dtype=None):
     ``dtype`` (None: ``total``'s, else the rows')."""
     dtype = dtype or (rows.dtype if total is None else total.dtype)
     return _call(rows, weight, total, plan, dtype=jnp.dtype(dtype),
-                 interpret=_interpret())
+                 interpret=_pallas.interpret())
 
 
 def sum_by_token_plain(rows, token, assigned, *, weight=None, total=None):

@@ -84,7 +84,10 @@ _SLOW_MODULES = {
     "test_sequence_parallel",    # ring/ulysses 8-device compiles
     "test_serving",              # 4-proc serving gangs + loadgen replay
     "test_serving_soak",         # mixed-tenant MiniEngine soak smoke
-    "test_models",               # GPT/ResNet init + flash paths
+    "test_models_vision",        # ResNet/VGG/Inception init
+    "test_models_gpt",           # GPT init + flash and ring paths
+    "test_models_hybrid",        # sparse and hybrid models vs references
+    "test_models_qwen3_next",    # Qwen3-Next's layers vs the reference
     "test_sanitizers",           # TSAN/ASAN rebuilds
     "test_self_healing",         # reconnect/replay chaos gangs
     "test_telemetry",            # fault-injected telemetry gangs

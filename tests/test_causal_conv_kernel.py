@@ -1,7 +1,7 @@
 """The causal depthwise convolution's Pallas kernels
 (``ops/causal_conv.py``), interpreted on the CPU: output and the three
-gradients against ``jax.grad`` of the plain ``jax.numpy`` body of
-``models/ssm.py``; causality and the zeros before the sequence; what is
+gradients against ``jax.grad`` of the module's plain ``jax.numpy``
+body; causality and the zeros before the sequence; what is
 float32 inside the kernels; what the custom VJP keeps; and which program
 gets the kernels, under which names."""
 
@@ -10,8 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import ssm
-from horovod_tpu.ops import causal_conv as kernels
+from horovod_tpu.ops import causal_conv as conv_op
 from tests.test_gdn import _equations
 from tests.test_gdn_kernel import _close, _has_pallas, _stacks
 
@@ -55,9 +54,9 @@ def test_kernels_match_the_plain_body(width, with_bias, blocks):
     the backward), passes of one and two sublane tiles."""
     (channels, lanes), (seq, rows, sub) = WIDTHS[width], BLOCKS[blocks]
     args, cot = _operands(seq, channels, with_bias)
-    got = _with_gradients(lambda *a: kernels.causal_conv(
+    got = _with_gradients(lambda *a: conv_op.causal_conv_kernels(
         *a, rows=rows, lanes=lanes, sub=sub), cot)(*args)
-    want = _with_gradients(ssm.causal_conv_plain, cot)(*args)
+    want = _with_gradients(conv_op.causal_conv_plain, cot)(*args)
     assert (got[3] is None) == (want[3] is None) == (not with_bias)
     for name, x, same in zip(NAMES, got, want):
         if same is not None:
@@ -72,17 +71,17 @@ def test_the_block_the_kernels_derive_and_other_taps(taps):
     and serve from one tap to the nine a float32 tile before a block can
     hold; a tenth is refused by name."""
     args, cot = _operands(48, 24, True, taps=taps)
-    plan = kernels._plan(*args[:2], None, None, None)
+    plan = conv_op._plan(*args[:2], None, None, None)
     assert (plan.rows, plan.lanes, plan.sub) == (48, 24, 24)
-    got = _with_gradients(kernels.causal_conv, cot)(*args)
+    got = _with_gradients(conv_op.causal_conv_kernels, cot)(*args)
     for name, x, same in zip(NAMES, got, _with_gradients(
-            ssm.causal_conv_plain, cot)(*args)):
+            conv_op.causal_conv_plain, cot)(*args)):
         _close(x, same, name)
     if taps == 9:
         with pytest.raises(ValueError, match="at most 9 taps"):
-            kernels.causal_conv(args[0], jnp.zeros((10, 24)))
+            conv_op.causal_conv_kernels(args[0], jnp.zeros((10, 24)))
         with pytest.raises(ValueError, match="does not tile"):
-            kernels.causal_conv(*args, rows=32)
+            conv_op.causal_conv_kernels(*args, rows=32)
 
 
 @pytest.mark.parametrize("at", [0, 2, 15, 16, 17, 31, 32, 47])
@@ -94,7 +93,8 @@ def test_nothing_reaches_back_and_zeros_come_first(at):
     ``taps - 1`` positions see zeros: position 0 is ``silu(weight[-1] x[0]
     + bias)``."""
     (x, weight, bias), _ = _operands(48, 16, True, batch=1)
-    conv = lambda x: kernels.causal_conv(x, weight, bias, rows=16, sub=8)
+    conv = lambda x: conv_op.causal_conv_kernels(x, weight, bias, rows=16,
+                                                 sub=8)
     moved = np.asarray(conv(x.at[0, at].add(1.0)) - conv(x))[0]
     touched = np.flatnonzero(np.abs(moved).max(axis=1))
     assert touched.min() == at, touched
@@ -127,7 +127,7 @@ def test_float32_inside_the_kernels():
     bf16-inside convolution several times further and the weight's
     gradient further still."""
     args, cot = _operands(64, 32, True, dtype=jnp.bfloat16)
-    conv = lambda *a: kernels.causal_conv(*a, rows=32, lanes=8)
+    conv = lambda *a: conv_op.causal_conv_kernels(*a, rows=32, lanes=8)
     jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(conv, *a)[1](cot))(*args)
     calls = {eqn.params["name"]: eqn for eqn in _equations(jaxpr.jaxpr)
              if eqn.primitive.name == "pallas_call"}
@@ -146,12 +146,12 @@ def test_float32_inside_the_kernels():
     assert sums.dtype == jnp.float32 and sums.shape == (2, 2, TAPS + 1, 32)
 
     f32 = lambda t: t.astype(jnp.float32)
-    want = _with_gradients(ssm.causal_conv_plain, f32(cot))(
+    want = _with_gradients(conv_op.causal_conv_plain, f32(cot))(
         f32(args[0]), *args[1:])
     far = lambda got: [float(np.linalg.norm(f32(a) - w) / np.linalg.norm(w))
                        for a, w in zip(got, want)]
     through = far(_with_gradients(conv, cot)(*args))
-    plain = far(_with_gradients(ssm.causal_conv_plain, cot)(*args))
+    plain = far(_with_gradients(conv_op.causal_conv_plain, cot)(*args))
     inside = far(_with_gradients(_inside_bf16, cot)(*args))
     for name, k, p, low in zip(NAMES, through, plain, inside):
         assert k <= 1.05 * p + 1e-6, (name, k, p)
@@ -166,14 +166,14 @@ def test_the_vjp_keeps_its_operands_and_nothing_float32_of_their_size():
     args, _ = _operands(64, 32, True, dtype=jnp.bfloat16)
     big = lambda kept: [a for a in jax.tree.leaves(kept)
                         if a.size >= args[0].size and a.dtype != jnp.bfloat16]
-    plan = kernels._plan(*args[:2], None, None, None)
-    _, residuals = kernels._conv_fwd(*args, plan)
+    plan = conv_op._plan(*args[:2], None, None, None)
+    _, residuals = conv_op._conv_fwd(*args, plan)
     assert [r is a for r, a in zip(residuals, args)] == [True] * 3
-    _, pullback = jax.vjp(kernels.causal_conv, *args)
+    _, pullback = jax.vjp(conv_op.causal_conv_kernels, *args)
     assert not big(pullback)
     kept = [a for a in jax.tree.leaves(pullback) if a.size >= args[0].size]
     assert len(kept) == 1 and kept[0].dtype == jnp.bfloat16
-    _, plain = jax.vjp(ssm.causal_conv_plain, *args)
+    _, plain = jax.vjp(conv_op.causal_conv_plain, *args)
     assert big(plain)
 
 
@@ -190,8 +190,8 @@ def _kernel_counts(channels, block):
 
 
 def test_the_choice(monkeypatch):
-    """On the CPU ``ssm.causal_conv`` lowers to no ``pallas_call`` and is
-    the plain body to the letter; on a TPU backend qwen3next-s8192's shape
+    """On the CPU ``causal_conv`` lowers to no ``pallas_call`` and is the
+    plain body to the letter; on a TPU backend qwen3next-s8192's shape
     goes to the kernels, and nemotron3s-s8192's 1280 channels (narrower
     than the kernels were seen to pay for inside a step), a width 128 does
     not divide, a sequence the shortest block does not divide or taps that
@@ -199,28 +199,28 @@ def test_the_choice(monkeypatch):
     cell, _ = _operands(128, 2048, False, batch=1)
     ragged, _ = _operands(100, 2048, True, batch=1)
     narrow, _ = _operands(128, 1280, True, batch=1)
-    assert not ssm.conv_kernels_serve(8192, 8192, TAPS)
-    assert not _has_pallas(ssm.causal_conv, *cell)
-    assert (jax.jit(ssm.causal_conv).lower(*cell).as_text()
-            == jax.jit(ssm.causal_conv_plain).lower(*cell).as_text().replace(
-                "causal_conv_plain", "causal_conv"))
+    assert not conv_op.serves(8192, 8192, TAPS)
+    assert not _has_pallas(conv_op.causal_conv, *cell)
+    assert (jax.jit(conv_op.causal_conv).lower(*cell).as_text()
+            == jax.jit(conv_op.causal_conv_plain).lower(*cell).as_text()
+            .replace("causal_conv_plain", "causal_conv"))
     with monkeypatch.context() as m:
         m.setattr(jax, "default_backend", lambda: "tpu")
-        assert ssm.conv_kernels_serve(8192, 8192, TAPS)     # qwen3next-s8192
-        assert ssm.conv_kernels_serve(2048, 8192, TAPS)     # its probe
-        assert ssm.conv_kernels_serve(128, 2048, 9)
+        assert conv_op.serves(8192, 8192, TAPS)     # qwen3next-s8192
+        assert conv_op.serves(2048, 8192, TAPS)     # its probe
+        assert conv_op.serves(128, 2048, 9)
         # nemotron3s-s8192 and its probe: the step was seen to lose there
-        assert not ssm.conv_kernels_serve(8192, 1280, TAPS)
-        assert not ssm.conv_kernels_serve(2048, 1280, TAPS)
-        assert not ssm.conv_kernels_serve(8192, 8192 + 64, TAPS)
-        assert not ssm.conv_kernels_serve(8192 + 64, 8192, TAPS)
-        assert not ssm.conv_kernels_serve(100, 8192, TAPS)
-        assert not ssm.conv_kernels_serve(128, 2048, 10)
+        assert not conv_op.serves(8192, 1280, TAPS)
+        assert not conv_op.serves(2048, 1280, TAPS)
+        assert not conv_op.serves(8192, 8192 + 64, TAPS)
+        assert not conv_op.serves(8192 + 64, 8192, TAPS)
+        assert not conv_op.serves(100, 8192, TAPS)
+        assert not conv_op.serves(128, 2048, 10)
         # a function of its own each: a trace is cached by the function
-        assert _has_pallas(lambda *a: ssm.causal_conv(*a), *cell)
-        assert not _has_pallas(lambda *a: ssm.causal_conv(*a), *ragged)
-        assert not _has_pallas(lambda *a: ssm.causal_conv(*a), *narrow)
-        assert not _has_pallas(jax.grad(lambda *a: ssm.causal_conv(
+        assert _has_pallas(lambda *a: conv_op.causal_conv(*a), *cell)
+        assert not _has_pallas(lambda *a: conv_op.causal_conv(*a), *ragged)
+        assert not _has_pallas(lambda *a: conv_op.causal_conv(*a), *narrow)
+        assert not _has_pallas(jax.grad(lambda *a: conv_op.causal_conv(
             *a).sum(), (0, 1, 2)), *ragged)
 
 
@@ -251,7 +251,7 @@ def test_the_names(pattern, scope, module, channels, monkeypatch):
 
     before = _kernel_counts(channels, f"32x{channels}")
     with monkeypatch.context() as m:
-        m.setattr(ssm, "conv_kernels_serve", lambda *shape: True)
+        m.setattr(conv_op, "serves", lambda *shape: True)
         jax.clear_caches()
         jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
     jax.clear_caches()
@@ -287,7 +287,8 @@ def test_the_counter_is_served():
 
     args, cot = _operands(32, 24, False, seed=7)
     before = _kernel_counts(24, "16x24")
-    step = _with_gradients(lambda *a: kernels.causal_conv(*a, rows=16), cot)
+    step = _with_gradients(
+        lambda *a: conv_op.causal_conv_kernels(*a, rows=16), cot)
     step(*args)
     step(*args)
     after = _kernel_counts(24, "16x24")

@@ -18,13 +18,13 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from horovod_tpu.models import gdn, moe, ssm
+from horovod_tpu.models import moe, ssm
+from horovod_tpu.ops import _pallas
 from horovod_tpu.ops import causal_conv as conv
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops import gated_delta_rule as gdr
 from horovod_tpu.ops import head_norm
 from horovod_tpu.ops import kth_largest as kth
-from horovod_tpu.ops import sum_by_token as token_sum
 from horovod_tpu.parallel.sequence import ring_attention
 
 
@@ -43,19 +43,14 @@ def v5e_devices():
 
 @pytest.fixture()
 def compiled_kernel(monkeypatch):
-    """Steer the kernel to its compiled form from the test (the code asks
-    ``jax.default_backend()``, which is the CPU here), with the persistent
-    cache off: an entry compiled for a described device is written but
-    cannot be read back without a chip."""
+    """Steer the kernels to their compiled form from the test (every
+    family asks ``_pallas.interpret()``, which reads
+    ``jax.default_backend()``, the CPU here), with the persistent cache
+    off: an entry compiled for a described device is written but cannot be
+    read back without a chip."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
-    monkeypatch.setattr(fa, "_interpret", lambda: False)
-    monkeypatch.setattr(moe, "_interpret", lambda: False)
-    monkeypatch.setattr(gdr, "_interpret", lambda: False)
-    monkeypatch.setattr(conv, "_interpret", lambda: False)
-    monkeypatch.setattr(kth, "_interpret", lambda: False)
-    monkeypatch.setattr(head_norm, "_interpret", lambda: False)
-    monkeypatch.setattr(token_sum, "_interpret", lambda: False)
+    monkeypatch.setattr(_pallas, "interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -333,7 +328,7 @@ def test_gated_delta_rule_kernels_compile_for_v5e(shape, compiled_kernel,
     g = like(b, s, h_v, dtype=jnp.float32)
 
     def loss(*a):
-        return jnp.mean(gdr.gated_delta_rule(*a, chunk=128).astype(
+        return jnp.mean(gdr.gated_delta_rule_kernels(*a, chunk=128).astype(
             jnp.float32) ** 2)
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
@@ -360,7 +355,7 @@ def test_gdn_layers_share_one_lowered_kernel_under_their_scope(
     ``chipbench/regions.py`` splits the step by)."""
     from horovod_tpu.models import GPT, GPTConfig
 
-    monkeypatch.setattr(gdn, "kernels_serve", lambda *shape: True)
+    monkeypatch.setattr(gdr, "serves", lambda *shape: True)
     one_chip = SingleDeviceSharding(v5e_devices[0])
 
     def step(pattern):
@@ -424,7 +419,7 @@ def test_causal_conv_kernels_compile_for_v5e(shape, compiled_kernel,
     bias = like(c) if with_bias else None
 
     def loss(*a):
-        return jnp.mean(conv.causal_conv(*a).astype(jnp.float32) ** 2)
+        return jnp.mean(conv.causal_conv_kernels(*a).astype(jnp.float32) ** 2)
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2)[:2 + with_bias])
                        ).lower(x, weight, bias).compile()
@@ -453,7 +448,7 @@ def test_mixers_share_one_lowered_conv_kernel_under_their_scope(
     ``chipbench/regions.py`` splits the step by)."""
     from horovod_tpu.models import GPT, GPTConfig
 
-    monkeypatch.setattr(ssm, "conv_kernels_serve", lambda *shape: True)
+    monkeypatch.setattr(conv, "serves", lambda *shape: True)
     one_chip = SingleDeviceSharding(v5e_devices[0])
 
     def step(pattern):
@@ -519,8 +514,8 @@ def test_head_norm_kernels_compile_for_v5e(shape, compiled_kernel,
     o, w = like(b, s, heads * dim), like(dim, dtype=jnp.float32)
 
     def loss(o, z, w):
-        y = head_norm.gated_norm(o, z, w, eps=1e-6)
-        x = head_norm.l2_norm(o, dim, eps=1e-6, scale=dim ** -0.5)
+        y = head_norm.gated_norm_kernels(o, z, w, eps=1e-6)
+        x = head_norm.l2_norm_kernels(o, dim, eps=1e-6, scale=dim ** -0.5)
         return jnp.mean(y.astype(jnp.float32) ** 2) + jnp.mean(
             x.astype(jnp.float32) ** 2)
 
@@ -550,8 +545,8 @@ def test_gdn_layers_hold_the_norm_kernels_and_stay_flat(
     from custom call to custom call as they are."""
     from horovod_tpu.models import GPT, GPTConfig
 
-    monkeypatch.setattr(gdn, "kernels_serve", lambda *shape: True)
-    monkeypatch.setattr(ssm, "conv_kernels_serve", lambda *shape: True)
+    monkeypatch.setattr(gdr, "serves", lambda *shape: True)
+    monkeypatch.setattr(conv, "serves", lambda *shape: True)
     monkeypatch.setattr(head_norm, "serves", lambda *shape: True)
     one_chip = SingleDeviceSharding(v5e_devices[0])
     batch, seq, heads = 2, 256, 4

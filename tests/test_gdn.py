@@ -16,6 +16,8 @@ from chipbench.families import qwen3_next as family
 from chipbench.reference import qwen3_next as reference
 from horovod_tpu.models import gdn
 from horovod_tpu.models.gdn import GatedDeltaNet
+from horovod_tpu.ops import gated_delta_rule as rule_op
+from horovod_tpu.ops import head_norm as norm_op
 
 REL = 2e-5      # float32 on both sides: summation order is all that differs
 # ... but for what reaches the decays (A_log, dt_bias, g): the chunked form
@@ -101,8 +103,9 @@ def test_rule_alone_against_the_recurrence(chunk, per_key):
     b, s, key_heads = 2, 40, 2
     heads = key_heads * per_key
     normal = lambda *dims: jnp.asarray(rng.normal(size=dims), jnp.float32)
-    q = gdn.l2_normalise(normal(b, s, key_heads, D_K)) * D_K ** -0.5
-    k = gdn.l2_normalise(normal(b, s, key_heads, D_K))
+    unit = lambda x: norm_op.l2_norm(x, eps=gdn.L2_EPS)
+    q = unit(normal(b, s, key_heads, D_K)) * D_K ** -0.5
+    k = unit(normal(b, s, key_heads, D_K))
     v, cot = normal(b, s, heads, D_V), normal(b, s, heads, D_V)
     g = -jnp.asarray(rng.uniform(0, 2, (b, s, heads)), jnp.float32)
     beta = jnp.asarray(rng.uniform(0, 1, (b, s, heads)), jnp.float32)
@@ -111,7 +114,7 @@ def test_rule_alone_against_the_recurrence(chunk, per_key):
         wide = lambda t: jnp.repeat(t, per_key, axis=2)
         return jax.vmap(reference.delta_rule)(wide(q), wide(k), v, g, beta)
 
-    program = lambda *a: gdn.gated_delta_rule(*a, chunk=chunk)
+    program = lambda *a: rule_op.gated_delta_rule(*a, chunk=chunk)
     _close(program(q, k, v, g, beta), plain(q, k, v, g, beta), "output")
     got = jax.grad(lambda *a: jnp.sum(program(*a) * cot),
                    argnums=range(5))(q, k, v, g, beta)
@@ -133,18 +136,20 @@ def test_inverse_by_blocks_is_the_inverse(size):
     full = jnp.asarray(0.3 * rng.normal(size=(3, size, size)), jnp.float32)
     strict = np.tril(np.asarray(full), -1)
     want = np.linalg.inv(np.eye(size) + strict.astype(np.float64))
-    _close(gdn.unit_lower_inverse(full), want, "inverse", rel=1e-4)
+    _close(rule_op.unit_lower_inverse(full), want, "inverse", rel=1e-4)
     cot = jnp.asarray(rng.normal(size=(3, size, size)), jnp.float32)
     solve = lambda n: jnp.linalg.inv(
         jnp.eye(size) + jnp.tril(n, -1))
-    got = jax.grad(lambda n: jnp.sum(gdn.unit_lower_inverse(n) * cot))(full)
+    got = jax.grad(
+        lambda n: jnp.sum(rule_op.unit_lower_inverse(n) * cot))(full)
     _close(got, jax.grad(lambda n: jnp.sum(solve(n) * cot))(full),
            "d inverse", rel=1e-4)
     assert not np.any(np.triu(np.asarray(got)))
     ones = jnp.ones((size, size), jnp.float32) * 0.999
     exact = np.linalg.inv(np.eye(size) + np.tril(np.asarray(ones, np.float64),
                                                  -1))
-    _close(gdn.unit_lower_inverse(ones), exact, "correlated keys", rel=1e-4)
+    _close(rule_op.unit_lower_inverse(ones), exact, "correlated keys",
+           rel=1e-4)
 
 
 def _equations(jaxpr):
@@ -243,7 +248,7 @@ def _swapped(owner, name, value):
 
 def _rule_given(**fixed):
     """``gated_delta_rule`` with an argument replaced by a constant."""
-    right = gdn.gated_delta_rule
+    right = rule_op.gated_delta_rule
 
     def rule(q, k, v, g, beta, **options):
         given = dict(g=g, beta=beta)
@@ -255,7 +260,7 @@ def _rule_given(**fixed):
 
 
 def _gate_first(o, z, scale, eps):
-    """``gated_head_norm``'s arguments (``o`` and ``z`` ``[b, s, H d]``,
+    """``gated_norm``'s arguments (``o`` and ``z`` ``[b, s, H d]``,
     ``scale [d]``), the gate applied before the norm."""
     heads = lambda t: t.astype(jnp.float32).reshape(
         *t.shape[:-1], -1, scale.shape[-1])
@@ -265,8 +270,8 @@ def _gate_first(o, z, scale, eps):
                                       o.shape)
 
 
-def _not_normalised(x, dim=None, scale=1.0):
-    """``l2_normalise``'s arguments, the norm left out."""
+def _not_normalised(x, dim=None, eps=None, scale=1.0):
+    """``l2_norm``'s arguments, the norm left out."""
     return (x.astype(jnp.float32) * scale).astype(x.dtype)
 
 
@@ -274,15 +279,15 @@ WRONG_MIXERS = {
     "state-and-cumulative-sums-in-bf16": (
         jnp.bfloat16, lambda: _swapped(gdn, "STATE_DTYPE", jnp.bfloat16)),
     "l2-norm-left-out": (
-        jnp.float32, lambda: _swapped(gdn, "l2_normalise", _not_normalised)),
+        jnp.float32, lambda: _swapped(norm_op, "l2_norm", _not_normalised)),
     "beta-left-out": (
-        jnp.float32, lambda: _swapped(gdn, "gated_delta_rule",
+        jnp.float32, lambda: _swapped(rule_op, "gated_delta_rule",
                                       _rule_given(beta=1.0))),
     "decay-left-out": (
-        jnp.float32, lambda: _swapped(gdn, "gated_delta_rule",
+        jnp.float32, lambda: _swapped(rule_op, "gated_delta_rule",
                                       _rule_given(g=0.0))),
     "gate-before-the-norm": (
-        jnp.float32, lambda: _swapped(gdn, "gated_head_norm", _gate_first)),
+        jnp.float32, lambda: _swapped(norm_op, "gated_norm", _gate_first)),
 }
 
 
@@ -303,7 +308,7 @@ def test_wrong_mixers_are_refused(wrong, monkeypatch):
     served = wrong.endswith(KERNELS_SERVE)
     dtype, swap = WRONG_MIXERS[wrong.removesuffix(KERNELS_SERVE)]
     if served:
-        monkeypatch.setattr(gdn.norm_kernels, "serves", lambda *shape: True)
+        monkeypatch.setattr(norm_op, "serves", lambda *shape: True)
     config = _config(2, 4) | {"linear_key_head_dim": 32,
                               "linear_value_head_dim": 32}
     layer, params, u = _mixer(key_heads=2, value_heads=4, dtype=dtype,

@@ -15,7 +15,8 @@ import pytest
 from chipbench.families import qwen3_next as family
 from chipbench.reference import qwen3_next as reference
 from horovod_tpu.models import gdn
-from horovod_tpu.ops import gated_delta_rule as kernels
+from horovod_tpu.ops import gated_delta_rule as rule_op
+from horovod_tpu.ops import head_norm as norm_op
 from tests.test_gdn import _equations
 
 REL = 2e-5          # float32 on both sides: summation order alone differs
@@ -36,8 +37,9 @@ def _operands(seq, per_key, batch=2, key_heads=2, d_k=D_K, d_v=D_V,
     rng = np.random.RandomState(seed + seq + per_key)
     heads = key_heads * per_key
     normal = lambda *dims: jnp.asarray(rng.normal(size=dims), jnp.float32)
-    q = gdn.l2_normalise(normal(batch, seq, key_heads, d_k)) * d_k ** -0.5
-    k = gdn.l2_normalise(normal(batch, seq, key_heads, d_k))
+    unit = lambda x: norm_op.l2_norm(x, eps=gdn.L2_EPS)
+    q = unit(normal(batch, seq, key_heads, d_k)) * d_k ** -0.5
+    k = unit(normal(batch, seq, key_heads, d_k))
     v, cot = normal(batch, seq, heads, d_v), normal(batch, seq, heads, d_v)
     g = -jnp.asarray(rng.uniform(0, 2, (batch, seq, heads)), jnp.float32)
     beta = jnp.asarray(rng.uniform(0, 1, (batch, seq, heads)), jnp.float32)
@@ -70,9 +72,9 @@ def test_kernels_match_the_plain_path_and_the_recurrence(seq, per_key):
         wide = lambda t: jnp.repeat(t, per_key, axis=2)
         return jax.vmap(reference.delta_rule)(wide(q), wide(k), v, g, beta)
 
-    got = _with_gradients(lambda *a: kernels.gated_delta_rule(
+    got = _with_gradients(lambda *a: rule_op.gated_delta_rule_kernels(
         *a, chunk=CHUNK), cot)(*args)
-    plain = _with_gradients(lambda *a: gdn.gated_delta_rule_plain(
+    plain = _with_gradients(lambda *a: rule_op.gated_delta_rule_plain(
         *a, chunk=CHUNK), cot)(*args)
     slow = _with_gradients(recurrence, cot)(*args)
     assert got[0].shape == args[2].shape and got[0].dtype == args[2].dtype
@@ -104,9 +106,9 @@ def test_the_state_a_chunk_is_entered_with_is_the_recurrences(per_key):
         (wide(q), wide(k), v[0], g[0], beta[0]))
     _close(o, reference.delta_rule(wide(q), wide(k), v[0], g[0], beta[0]),
            "the test's recurrence against the reference's")
-    plan, operands, _ = kernels._prepare(
+    plan, operands, _ = rule_op._prepare(
         q, k, v, g, beta, CHUNK, jnp.float32, gdn.INVERSE_PRECISION)
-    entering = kernels._rule_fwd(*operands, plan)[1][-1]
+    entering = rule_op._rule_fwd(*operands, plan)[1][-1]
     assert entering.shape == (1, 3, heads, D_K, D_V)
     assert entering.dtype == jnp.float32
     assert not np.any(np.asarray(entering[0, 0]))
@@ -124,7 +126,7 @@ def test_decays_inverse_and_states_are_float32_inside_the_kernels():
     ``hvt_gdn_fwd``; the scratch states ``S`` and ``dS`` and the kept
     states float32; all other products bf16."""
     args, cot = _operands(32, 2, batch=1, dtype=jnp.bfloat16)
-    rule = lambda *a: kernels.gated_delta_rule(
+    rule = lambda *a: rule_op.gated_delta_rule_kernels(
         *a, chunk=CHUNK, state_dtype=gdn.STATE_DTYPE,
         precision=gdn.INVERSE_PRECISION)
     jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(rule, *a)[1](cot))(*args)
@@ -171,21 +173,22 @@ def test_decays_inverse_and_states_are_float32_inside_the_kernels():
 @pytest.mark.parametrize("size", [16, 24, 32, 128])
 def test_inverse_through_half_the_rows_is_the_inverse(size):
     """The kernels' inverse by blocks, which from blocks of 8 up sends
-    only the second halves' rows through its products, against
-    ``gdn.unit_lower_inverse`` and numpy's float64 inverse, at sizes that
-    are and are not powers of two, with strongly correlated keys (every
-    entry of ``N`` near one)."""
+    only the second halves' rows through its products, against the plain
+    body's ``unit_lower_inverse`` and numpy's float64 inverse, at sizes
+    that are and are not powers of two, with strongly correlated keys
+    (every entry of ``N`` near one)."""
     rng = np.random.RandomState(size)
-    plan = kernels._Plan(size, 1, 1, 8, 8, 1, jnp.dtype(jnp.float32),
+    plan = rule_op._Plan(size, 1, 1, 8, 8, 1, jnp.dtype(jnp.float32),
                          gdn.INVERSE_PRECISION, True)
-    row, col = kernels._positions(size)
+    row, col = rule_op._positions(size)
     for system in (0.3 * rng.normal(size=(size, size)),
                    np.full((size, size), 0.999)):
         system = np.tril(system, -1)
-        got = kernels._unit_lower_inverse(jnp.asarray(system, jnp.float32),
+        got = rule_op._unit_lower_inverse(jnp.asarray(system, jnp.float32),
                                           row, col, plan)
         _close(got, np.linalg.inv(np.eye(size) + system), "inverse", 1e-4)
-        _close(got, gdn.unit_lower_inverse(jnp.asarray(system, jnp.float32)),
+        _close(got,
+               rule_op.unit_lower_inverse(jnp.asarray(system, jnp.float32)),
                "against the plain path's", 1e-5)
 
 
@@ -199,7 +202,7 @@ def _mixer_distance(dtype, monkeypatch, *, through_kernels):
     layer, params, u = _mixer(key_heads=2, value_heads=4, dtype=dtype,
                               seq=256, batch=1, d_model=32, d_k=32, d_v=32)
     if through_kernels:
-        monkeypatch.setattr(gdn, "kernels_serve", lambda *shape: True)
+        monkeypatch.setattr(rule_op, "serves", lambda *shape: True)
     _, sown = layer.apply({"params": params}, u, mutable=["intermediates"])
     return family.mixer_distance(
         {k: v[0] for k, v in sown["intermediates"].items()}, params, config)
@@ -224,11 +227,11 @@ def test_a_recomputed_forward_keeps_the_inverses():
     inverses' (they carry the name ``KEPT_INVERSE``), and the gradients
     are the ones without it."""
     args, cot = _operands(40, 2, batch=1)
-    rule = lambda *a: jnp.sum(kernels.gated_delta_rule(*a, chunk=CHUNK)
-                              * cot)
+    rule = lambda *a: jnp.sum(
+        rule_op.gated_delta_rule_kernels(*a, chunk=CHUNK) * cot)
     kept = jax.checkpoint(
         rule, policy=jax.checkpoint_policies.save_only_these_names(
-            kernels.KEPT_INVERSE))
+            rule_op.KEPT_INVERSE))
     all_five = tuple(range(5))
     jaxpr = jax.make_jaxpr(jax.grad(kept, all_five))(*args)
     made = [eqn.params["name"] for eqn in _equations(jaxpr.jaxpr)
@@ -271,7 +274,7 @@ def _stacks(jaxpr, prefix=""):
 
 
 def test_the_choice_and_the_names(monkeypatch):
-    """On the CPU ``gdn.gated_delta_rule`` lowers to no ``pallas_call``
+    """On the CPU ``gated_delta_rule`` lowers to no ``pallas_call``
     and a small ``G`` model's lowered step is what the plain path gives;
     on a TPU backend a chunk of 128 with heads of 128 goes to the kernels
     and a head of 64 or a chunk of 8 to ``jax.numpy`` without raising;
@@ -285,8 +288,8 @@ def test_the_choice_and_the_names(monkeypatch):
 
     wide, _ = _operands(128, 2, batch=1, key_heads=1, d_k=128, d_v=128)
     narrow, _ = _operands(128, 2, batch=1, key_heads=1, d_k=64, d_v=128)
-    rule = lambda *a: gdn.gated_delta_rule(*a)
-    assert not gdn.kernels_serve(128, 128, 128)
+    rule = lambda *a: rule_op.gated_delta_rule(*a)
+    assert not rule_op.serves(128, 128, 128)
     assert not _has_pallas(rule, *wide)
 
     model = GPT(GPTConfig(
@@ -300,26 +303,27 @@ def test_the_choice_and_the_names(monkeypatch):
     as_it_is = step.lower(params).as_text()
     assert "hvt_gdn" not in as_it_is
     with monkeypatch.context() as m:
-        m.setattr(gdn, "gated_delta_rule", gdn.gated_delta_rule_plain)
+        m.setattr(rule_op, "gated_delta_rule",
+                  rule_op.gated_delta_rule_plain)
         jax.clear_caches()
         assert step.lower(params).as_text() == as_it_is
 
     with monkeypatch.context() as m:
         m.setattr(jax, "default_backend", lambda: "tpu")
-        assert gdn.kernels_serve(128, 128, 128)
-        assert gdn.kernels_serve(128, 256, 128)
-        assert not gdn.kernels_serve(8, 128, 128)
-        assert not gdn.kernels_serve(128, 64, 128)
-        assert not gdn.kernels_serve(256, 128, 128)
+        assert rule_op.serves(128, 128, 128)
+        assert rule_op.serves(128, 256, 128)
+        assert not rule_op.serves(8, 128, 128)
+        assert not rule_op.serves(128, 64, 128)
+        assert not rule_op.serves(256, 128, 128)
         assert not _has_pallas(rule, *narrow)
-        assert not _has_pallas(lambda *a: gdn.gated_delta_rule(
+        assert not _has_pallas(lambda *a: rule_op.gated_delta_rule(
             *a, chunk=8), *wide)
-    assert _has_pallas(lambda *a: kernels.gated_delta_rule(
+    assert _has_pallas(lambda *a: rule_op.gated_delta_rule_kernels(
         *a, chunk=128), *wide)
 
     before = _kernel_counts()
     with monkeypatch.context() as m:
-        m.setattr(gdn, "kernels_serve", lambda *shape: True)
+        m.setattr(rule_op, "serves", lambda *shape: True)
         jax.clear_caches()
         jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
     jax.clear_caches()

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import gdn
-from horovod_tpu.ops import head_norm as kernels
+from horovod_tpu.ops import head_norm as norm_op
 from tests.test_gdn import _equations
 from tests.test_gdn_kernel import _close, _has_pallas, _stacks
 
@@ -53,15 +53,15 @@ def _named(dim, heads, rows):
 @pytest.mark.parametrize("dim", [128, 256])
 def test_the_gated_norm_matches_the_plain_body(dim, heads, rows, dtype):
     """``hvt_gated_norm_fwd`` and ``hvt_gated_norm_bwd`` against
-    ``gated_head_norm_plain`` and ``jax.grad`` of it (``y``, ``do``,
+    ``gated_norm_plain`` and ``jax.grad`` of it (``y``, ``do``,
     ``dz``, ``dw``): heads of 128 and 256, head counts that fill a block's
     lanes and that do not, one and two blocks of positions, a sequence the
     block does not divide (the last block's rows past the end are never
     written and stay out of ``dw``) and the block the kernels derive."""
     args, cot = _operands(ROWS[rows][0], HEADS[heads][0], dim, dtype)
-    got = _with_gradients(lambda *a: kernels.gated_norm(
+    got = _with_gradients(lambda *a: norm_op.gated_norm_kernels(
         *a, eps=EPS, **_named(dim, heads, rows)), cot)(*args)
-    want = _with_gradients(lambda *a: gdn.gated_head_norm_plain(*a, EPS),
+    want = _with_gradients(lambda *a: norm_op.gated_norm_plain(*a, eps=EPS),
                            cot)(*args)
     for name, x, same in zip(("y", "do", "dz", "dw"), got, want):
         assert x.shape == same.shape and x.dtype == same.dtype, name
@@ -75,15 +75,15 @@ def test_the_gated_norm_matches_the_plain_body(dim, heads, rows, dtype):
 @pytest.mark.parametrize("dim", [128, 256])
 def test_the_l2_norm_matches_the_plain_body(dim, heads, rows, dtype):
     """``hvt_l2_norm_fwd`` and ``hvt_l2_norm_bwd`` against
-    ``l2_normalise_plain`` and ``jax.grad`` of it, with q's scale
+    ``l2_norm_plain`` and ``jax.grad`` of it, with q's scale
     ``dim^-1/2`` and with k's 1, over the same shapes and blocks."""
     (x, _, _), cot = _operands(ROWS[rows][0], HEADS[heads][0], dim, dtype)
     for scale in (dim ** -0.5, 1.0):
-        got = _with_gradients(lambda x: kernels.l2_norm(
+        got = _with_gradients(lambda x: norm_op.l2_norm_kernels(
             x, dim, eps=gdn.L2_EPS, scale=scale,
             **_named(dim, heads, rows)), cot)(x)
-        want = _with_gradients(
-            lambda x: gdn.l2_normalise_plain(x, dim, scale), cot)(x)
+        want = _with_gradients(lambda x: norm_op.l2_norm_plain(
+            x, dim, eps=gdn.L2_EPS, scale=scale), cot)(x)
         for name, a, same in zip(("y", "dx"), got, want):
             assert a.shape == same.shape and a.dtype == same.dtype, name
             _close(a, same, f"{name} at scale {scale}", REL[dtype])
@@ -94,7 +94,7 @@ def test_the_blocks_the_kernels_derive_and_refuse():
     whole heads up to 1024 lanes that divide the channels; at the cell's
     own shapes 512 positions by eight heads. Heads that do not divide the
     channels and blocks that do not tile them are refused by name."""
-    plan = lambda shape, dim, **named: kernels._plan(
+    plan = lambda shape, dim, **named: norm_op._plan(
         jax.ShapeDtypeStruct(shape, jnp.bfloat16), dim, EPS, 1.0,
         *(named.get(n) for n in ("rows", "lanes", "sub")))
     at = lambda p: (p.rows, p.lanes, p.sub)
@@ -123,9 +123,9 @@ def test_float32_inside_the_kernels():
     rounding alone)."""
     dim = 128
     args, cot = _operands(32, 3, dim, jnp.bfloat16)
-    gated = lambda *a: kernels.gated_norm(*a, eps=EPS, rows=16)
-    l2 = lambda x: kernels.l2_norm(x, dim, eps=gdn.L2_EPS, scale=dim ** -0.5,
-                                   rows=16)
+    gated = lambda *a: norm_op.gated_norm_kernels(*a, eps=EPS, rows=16)
+    l2 = lambda x: norm_op.l2_norm_kernels(
+        x, dim, eps=gdn.L2_EPS, scale=dim ** -0.5, rows=16)
     jaxpr = jax.make_jaxpr(lambda *a: (
         jax.vjp(gated, *a)[1](cot), jax.vjp(l2, a[0])[1](cot)))(*args)
     calls = {eqn.params["name"]: eqn for eqn in _equations(jaxpr.jaxpr)
@@ -151,13 +151,13 @@ def test_float32_inside_the_kernels():
     assert sums.dtype == jnp.float32 and sums.shape == (2, 2, 1, dim)
 
     f32 = lambda t: t.astype(jnp.float32)
-    want = _with_gradients(lambda *a: gdn.gated_head_norm_plain(*a, EPS),
+    want = _with_gradients(lambda *a: norm_op.gated_norm_plain(*a, eps=EPS),
                            f32(cot))(f32(args[0]), f32(args[1]), args[2])
     far = lambda got: [float(np.linalg.norm(f32(a) - w) / np.linalg.norm(w))
                        for a, w in zip(got, want)]
     through = far(_with_gradients(gated, cot)(*args))
     plain = far(_with_gradients(
-        lambda *a: gdn.gated_head_norm_plain(*a, EPS), cot)(*args))
+        lambda *a: norm_op.gated_norm_plain(*a, eps=EPS), cot)(*args))
     for name, k, p in zip(("y", "do", "dz", "dw"), through, plain):
         assert k <= 1.05 * p + 1e-6, (name, k, p)
 
@@ -173,19 +173,22 @@ def test_the_vjps_keep_their_operands_and_nothing_float32_of_their_size():
                         if a.size >= args[0].size and a.dtype != jnp.bfloat16]
     wide = lambda kept: [a for a in jax.tree.leaves(kept)
                          if a.size >= args[0].size]
-    plan = kernels._plan(args[0], dim, EPS, 1.0, None, None, None)
-    _, residuals = kernels._gated_fwd(*args, plan)
+    plan = norm_op._plan(args[0], dim, EPS, 1.0, None, None, None)
+    _, residuals = norm_op._gated_fwd(*args, plan)
     assert [r is a for r, a in zip(residuals, args)] == [True] * 3
-    _, residual = kernels._l2_fwd(args[0], plan)
+    _, residual = norm_op._l2_fwd(args[0], plan)
     assert residual is args[0]
-    _, pullback = jax.vjp(lambda *a: kernels.gated_norm(*a, eps=EPS), *args)
+    _, pullback = jax.vjp(
+        lambda *a: norm_op.gated_norm_kernels(*a, eps=EPS), *args)
     assert not big(pullback) and len(wide(pullback)) == 2
-    _, pullback = jax.vjp(lambda x: kernels.l2_norm(x, dim, eps=EPS),
+    _, pullback = jax.vjp(lambda x: norm_op.l2_norm_kernels(x, dim, eps=EPS),
                           args[0])
     assert not big(pullback) and len(wide(pullback)) == 1
-    _, plain = jax.vjp(lambda *a: gdn.gated_head_norm_plain(*a, EPS), *args)
+    _, plain = jax.vjp(lambda *a: norm_op.gated_norm_plain(*a, eps=EPS),
+                       *args)
     assert big(plain)
-    _, plain = jax.vjp(lambda x: gdn.l2_normalise_plain(x, dim), args[0])
+    _, plain = jax.vjp(lambda x: norm_op.l2_norm_plain(x, dim, eps=EPS),
+                       args[0])
     assert big(plain)
 
 
@@ -201,42 +204,43 @@ def _kernel_counts(heads, dim):
 
 
 def test_the_choice(monkeypatch):
-    """On the CPU ``gdn.l2_normalise`` and ``gdn.gated_head_norm`` lower
-    to no ``pallas_call`` and are the plain bodies to the letter; on a TPU
+    """On the CPU ``l2_norm`` and ``gated_norm`` lower to no
+    ``pallas_call`` and are the plain bodies to the letter; on a TPU
     backend heads of 128 and 256 on ``[b, s, H d]`` go to the kernels, and
     heads of 64, positions in no whole bf16 tile and operands that are
     ``[b, s, H, d]`` already to ``jax.numpy`` without raising."""
     (o, z, w), _ = _operands(32, 2, 128, jnp.bfloat16, batch=1)
     (narrow, _, w64), _ = _operands(32, 4, 64, jnp.bfloat16, batch=1)
     (ragged, _, _), _ = _operands(20, 2, 128, jnp.bfloat16, batch=1)
-    l2 = lambda x: gdn.l2_normalise(x, 128, 128 ** -0.5)
-    gated = lambda o, z, w: gdn.gated_head_norm(o, z, w, EPS)
-    assert not kernels.serves(8192, 128)
+    l2 = lambda x: norm_op.l2_norm(x, 128, eps=EPS, scale=128 ** -0.5)
+    gated = lambda o, z, w: norm_op.gated_norm(o, z, w, eps=EPS)
+    assert not norm_op.serves(8192, 128)
     assert not _has_pallas(l2, o) and not _has_pallas(gated, o, z, w)
     assert (jax.jit(gated).lower(o, z, w).as_text()
-            == jax.jit(lambda *a: gdn.gated_head_norm_plain(*a, EPS)).lower(
-                o, z, w).as_text())
+            == jax.jit(lambda *a: norm_op.gated_norm_plain(
+                *a, eps=EPS)).lower(o, z, w).as_text())
     assert (jax.jit(l2).lower(o).as_text() == jax.jit(
-        lambda x: gdn.l2_normalise_plain(x, 128, 128 ** -0.5)).lower(
-            o).as_text())
+        lambda x: norm_op.l2_norm_plain(
+            x, 128, eps=EPS, scale=128 ** -0.5)).lower(o).as_text())
     with monkeypatch.context() as m:
         m.setattr(jax, "default_backend", lambda: "tpu")
-        assert kernels.serves(8192, 128)        # qwen3next-s8192
-        assert kernels.serves(2048, 128)        # its probe
-        assert kernels.serves(8192, 256)
-        assert not kernels.serves(8192, 64)
-        assert not kernels.serves(8192, 192)
-        assert not kernels.serves(8200, 128)
+        assert norm_op.serves(8192, 128)        # qwen3next-s8192
+        assert norm_op.serves(2048, 128)        # its probe
+        assert norm_op.serves(8192, 256)
+        assert not norm_op.serves(8192, 64)
+        assert not norm_op.serves(8192, 192)
+        assert not norm_op.serves(8200, 128)
         # a function of its own each: a trace is cached by the function
         assert _has_pallas(lambda x: l2(x), o)
         assert _has_pallas(lambda *a: gated(*a), o, z, w)
         assert _has_pallas(jax.grad(lambda *a: gated(*a).sum().astype(
             jnp.float32), (0, 1, 2)), o, z, w)
-        assert not _has_pallas(lambda x: gdn.l2_normalise(x, 64), narrow)
+        assert not _has_pallas(lambda x: norm_op.l2_norm(x, 64, eps=EPS),
+                               narrow)
         assert not _has_pallas(lambda *a: gated(*a), narrow, narrow, w64)
         assert not _has_pallas(lambda x: l2(x), ragged)
         by_heads = o.reshape(1, 32, 2, 128)
-        assert not _has_pallas(lambda x: gdn.l2_normalise(x), by_heads)
+        assert not _has_pallas(lambda x: norm_op.l2_norm(x, eps=EPS), by_heads)
         assert not _has_pallas(lambda *a: gated(*a), by_heads, by_heads, w)
 
 
@@ -262,7 +266,7 @@ def test_the_names(monkeypatch):
 
     before = _kernel_counts(2, 8), _kernel_counts(4, 8)
     with monkeypatch.context() as m:
-        m.setattr(kernels, "serves", lambda *shape: True)
+        m.setattr(norm_op, "serves", lambda *shape: True)
         jax.clear_caches()
         jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
     jax.clear_caches()
@@ -303,8 +307,10 @@ def test_the_counter_is_served():
 
     args, cot = _operands(16, 5, 128, seed=7)
     before = _kernel_counts(5, 128)
-    gated = _with_gradients(lambda *a: kernels.gated_norm(*a, eps=EPS), cot)
-    l2 = _with_gradients(lambda x: kernels.l2_norm(x, 128, eps=EPS), cot)
+    gated = _with_gradients(
+        lambda *a: norm_op.gated_norm_kernels(*a, eps=EPS), cot)
+    l2 = _with_gradients(
+        lambda x: norm_op.l2_norm_kernels(x, 128, eps=EPS), cot)
     for _ in range(2):
         gated(*args)
         l2(args[0])

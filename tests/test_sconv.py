@@ -17,10 +17,11 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-import test_models as others
+import small_models as others
 from chipbench.reference import lfm2_moe as reference
-from horovod_tpu.models import GPT, GPTConfig, sconv, ssm
+from horovod_tpu.models import GPT, GPTConfig, sconv
 from horovod_tpu.models.moe import MoEMlp
+from horovod_tpu.ops import causal_conv as conv_op
 
 _LFM2 = {"norm_eps": 1e-5, "conv_L_cache": 3,
          "rope_parameters": {"rope_theta": 1e6}, "num_experts_per_tok": 4,
@@ -115,16 +116,16 @@ def test_plain_convolution_without_its_activation_and_with_it_as_before():
     x = jax.random.normal(jax.random.key(0), (2, 12, 8))
     weight = jax.random.normal(jax.random.key(1), (4, 8))
     bias = jax.random.normal(jax.random.key(2), (8,))
-    bare = ssm.causal_conv_plain(x, weight, bias, activation=None)
+    bare = conv_op.causal_conv_plain(x, weight, bias, activation=None)
     np.testing.assert_allclose(
         np.asarray(jax.nn.silu(bare)),
-        np.asarray(ssm.causal_conv_plain(x, weight, bias)), rtol=1e-6)
-    as_before = jax.jit(lambda x, w, b: ssm.causal_conv_plain(
+        np.asarray(conv_op.causal_conv_plain(x, weight, bias)), rtol=1e-6)
+    as_before = jax.jit(lambda x, w, b: conv_op.causal_conv_plain(
         x, w, b)).lower(x, weight, bias).as_text()
-    named = jax.jit(lambda x, w, b: ssm.causal_conv_plain(
+    named = jax.jit(lambda x, w, b: conv_op.causal_conv_plain(
         x, w, b, activation=jax.nn.silu)).lower(x, weight, bias).as_text()
     assert as_before == named and "@silu" in as_before
-    assert "silu" not in jax.jit(lambda x, w: ssm.causal_conv_plain(
+    assert "silu" not in jax.jit(lambda x, w: conv_op.causal_conv_plain(
         x, w, activation=None)).lower(x, weight).as_text()
 
 
@@ -336,15 +337,15 @@ def _other(name):
         return cfg, (lambda model: lambda p: model.apply(
             {"params": p}, tokens).astype(jnp.float32).sum()), params
     if name == "olmoe":
-        model, params, tokens = others._sparse_model(remat=True)
-        return model.cfg, (lambda model: lambda p: others._sparse_loss(
+        model, params, tokens = others.sparse_model(remat=True)
+        return model.cfg, (lambda model: lambda p: others.sparse_loss(
             model, p, tokens)), params
     if name == "nemotron_h":
-        model, params, buffers, tokens = others._hybrid_model(remat=True)
-        return model.cfg, (lambda model: lambda p: others._hybrid_loss(
+        model, params, buffers, tokens = others.hybrid_model(remat=True)
+        return model.cfg, (lambda model: lambda p: others.hybrid_loss(
             model, p, buffers, tokens)), params
-    model, params, tokens = others._qwen_model(remat=True)
-    return model.cfg, (lambda model: lambda p: others._qwen_loss(
+    model, params, tokens = others.qwen_model(remat=True)
+    return model.cfg, (lambda model: lambda p: others.qwen_loss(
         model, p, tokens)), params
 
 
