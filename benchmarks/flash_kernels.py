@@ -3,10 +3,14 @@
 
     chiprun -- python benchmarks/flash_kernels.py \
         --shape 2,4096,20,20,64 --blocks derived,128x128,bwd=512x512
+    chiprun -- python benchmarks/flash_kernels.py --shape 2,8192,32,32,192,128
 
 For every score tile asked for it jits one forward + ``vjp`` of
-``flash_attention`` at ``--shape`` (batch, seq, heads, kv_heads, head_dim;
-bf16, causal unless ``--no-causal``), runs it ``--iters`` times inside one
+``flash_attention`` at ``--shape`` (batch, seq, heads, kv_heads, head_dim
+and, since PR 48, optionally the values' width where it is not the keys':
+``2,8192,32,32,192,128`` is ``kanana2-s8192``'s latent attention, and
+``..,256,128`` and ``..,256`` beside it price the zeros a padded width
+would multiply; bf16, causal unless ``--no-causal``), runs it ``--iters`` times inside one
 profiler trace and prints one JSON line: the device milliseconds a call of
 ``hvt_flash_fwd`` and ``hvt_flash_bwd`` (median over the iterations, read
 from the trace by the kernels' names; ``hvt_flash_dq`` and
@@ -53,12 +57,10 @@ def make_inputs(shape):
     import jax.numpy as jnp
     import numpy as np
 
-    b, s, h, h_kv, d = shape
+    b, s, h, h_kv, d, d_v = (*shape, shape[-1])[:6]
     rng = np.random.RandomState(0)
-    q, do = (jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.bfloat16)
-             for _ in range(2))
-    k, v = (jnp.asarray(rng.normal(size=(b, s, h_kv, d)), jnp.bfloat16)
-            for _ in range(2))
+    q, k, v, do = (jnp.asarray(rng.normal(size=(b, s, *dims)), jnp.bfloat16)
+                   for dims in ((h, d), (h_kv, d), (h_kv, d_v), (h, d_v)))
     return q, k, v, do
 
 

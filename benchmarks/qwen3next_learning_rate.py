@@ -1,7 +1,8 @@
 #!/usr/bin/env python
 """qwen3next_learning_rate.py — which constant learning rate a cell of a
 held expert layer (``--cell``: ``qwen3next-s8192`` by default, since PR 40
-``lfm2moe-s8192`` too) can repeat one batch at: for each of ``--rates`` it
+``lfm2moe-s8192`` and since PR 48 ``kanana2-s8192`` too) can repeat one
+batch at: for each of ``--seeds`` and ``--rates`` it
 trains the cell's model from the same initialisation on the cell's batch
 for ``--steps`` steps and prints, every ``--every`` steps, the loss and
 the rows that land on the held experts of each expert layer (a round is
@@ -12,6 +13,9 @@ step serves every rate (the rate is part of the optimizer's state,
     chiprun -- python benchmarks/qwen3next_learning_rate.py --rates 1e-6 4e-6
     chiprun -- python benchmarks/qwen3next_learning_rate.py \
         --cell lfm2moe-s8192 --steps 80 --every 20
+    chiprun -- python benchmarks/qwen3next_learning_rate.py \
+        --cell kanana2-s8192 --steps 0 --seeds 1 2 3   (the rows a fresh
+        initialisation gives, seed by seed)
 
 A builder's script: it decides nothing. It refuses to run without a TPU.
 """
@@ -30,7 +34,7 @@ def main():
                    default=[1e-6, 4e-6, 1.6e-5])
     p.add_argument("--steps", type=int, default=48)
     p.add_argument("--every", type=int, default=12)
-    p.add_argument("--seed", type=int, default=2147488301)
+    p.add_argument("--seeds", type=int, nargs="+", default=[2147488301])
     p.add_argument("--cell", default="qwen3next-s8192")
     args = p.parse_args()
 
@@ -78,22 +82,24 @@ def main():
         return [jnp.sum(family.held_rows(block["experts"], cfg))
                 for block in sown.values() if "experts" in block]
 
-    k_init, k_batch = jax.random.split(jax.random.key(args.seed))
-    batch = jax.jit(lambda k: job.make_batch(k, 1))(k_batch)
-    for rate in args.rates:
+    make_batch = jax.jit(lambda k: job.make_batch(k, 1))
+    for seed, rate in ((s, r) for s in args.seeds for r in args.rates):
+        k_init, k_batch = jax.random.split(jax.random.key(seed))
+        batch = make_batch(k_batch)
         params, extra, state = init(k_init, rate)
         for i in range(args.steps + 1):
             if i % args.every == 0:
                 print(json.dumps({
-                    "learning_rate": rate, "step": i,
+                    "seed": seed, "learning_rate": rate, "step": i,
                     "rows_on_held_experts": [
                         int(n) for n in rows(params, extra, batch)],
                     "round": int(batch.size)}), flush=True)
             if i < args.steps:
                 params, extra, state, loss = step(params, extra, state, batch)
                 if i % args.every == 0 or i == args.steps - 1:
-                    print(json.dumps({"learning_rate": rate, "step": i,
-                                      "loss": float(loss)}), flush=True)
+                    print(json.dumps({"seed": seed, "learning_rate": rate,
+                                      "step": i, "loss": float(loss)}),
+                          flush=True)
         del params, extra, state
 
 

@@ -26,7 +26,9 @@ cell ``nemotron3s-s8192``, against the float32 formula: only at that length
 do they stream more than one sequence tile a grid step on a chip, and the
 cell compares its gradients at 2048 positions), ``flash256`` (the same
 kernels at a head of 256, 16 query heads on 2 key-value heads, the attention
-of the cell ``qwen3next-s8192``), ``gdn8192`` (the chunked gated delta rule
+of the cell ``qwen3next-s8192``), ``mla8192`` (the same kernels at a
+query-key width of 192 on a value width of 128, 32 heads, the products over
+positions of the cell ``kanana2-s8192``'s latent attention), ``gdn8192`` (the chunked gated delta rule
 of ``models/gdn.py`` at that cell's shape, the Pallas kernels and the plain
 ``jax.numpy`` path side by side, each against the float32 recurrence and
 timed, forward alone and forward and backward), ``conv8192`` (the causal
@@ -279,7 +281,8 @@ def require_compiled_flash(lowered_text):
 
 def flash_kernel_vs_f32(shape):
     """One forward+backward of ``flash_attention`` alone at
-    (batch, seq, heads, kv_heads, head_dim) against the einsum formula in
+    (batch, seq, heads, kv_heads, head_dim) and, where the values' width
+    is not the keys', that width after them, against the einsum formula in
     float32, one head at a time (at seq 8192 twelve heads of float32
     scores would not fit the chip at once)."""
     import jax
@@ -288,12 +291,10 @@ def flash_kernel_vs_f32(shape):
 
     from horovod_tpu.ops.flash_attention import flash_attention
 
-    b, s, h, h_kv, d = shape
+    b, s, h, h_kv, d, d_v = (*shape, shape[-1])[:6]
     rng = np.random.RandomState(0)
-    q, do = (jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.bfloat16)
-             for _ in range(2))
-    k, v = (jnp.asarray(rng.normal(size=(b, s, h_kv, d)), jnp.bfloat16)
-            for _ in range(2))
+    q, k, v, do = (jnp.asarray(rng.normal(size=(b, s, *dims)), jnp.bfloat16)
+                   for dims in ((h, d), (h_kv, d), (h_kv, d_v), (h, d_v)))
 
     o, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, causal=True),
                      q, k, v)
@@ -311,7 +312,7 @@ def flash_kernel_vs_f32(shape):
         return (o, *vjp(do))
 
     f32 = lambda x, i: x[:, :, i].astype(jnp.float32)
-    want = [np.zeros(x.shape, np.float32) for x in (q, q, k, v)]
+    want = [np.zeros(x.shape, np.float32) for x in (do, q, k, v)]
     with jax.default_matmul_precision("highest"):
         for i in range(h):
             j = i // (h // h_kv)
@@ -329,23 +330,29 @@ def flash_kernel_vs_f32(shape):
     return {"shape": list(shape), "rel_l2": errs}
 
 
+def compiled_flash_vs_f32(shape):
+    """``flash_kernel_vs_f32`` once the lowered call at ``shape`` is seen to
+    hold the Mosaic kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    b, s, h, h_kv, d, d_v = (*shape, shape[-1])[:6]
+    q, k, v = (jax.ShapeDtypeStruct((b, s, n, width), jnp.bfloat16)
+               for n, width in ((h, d), (h_kv, d), (h_kv, d_v)))
+    require_compiled_flash(jax.jit(functools.partial(
+        flash_attention, causal=True)).lower(q, k, v).as_text())
+    return flash_kernel_vs_f32(shape)
+
+
 def phase_flash8192(shape=(2, 8192, 4, 1, 128)):
     """The compiled kernels where each streams two tiles of 4096 positions
     a grid step, forward and backward, at the attention shape of the cell
     ``nemotron3s-s8192`` (2 x 8192, four query heads reading one key-value
     head, d = 128): that cell's own comparison of gradients runs at 2048
     positions, where a sequence is one tile."""
-    import jax
-    import jax.numpy as jnp
-
-    from horovod_tpu.ops.flash_attention import flash_attention
-
-    b, s, h, h_kv, d = shape
-    q, k = (jax.ShapeDtypeStruct((b, s, n, d), jnp.bfloat16)
-            for n in (h, h_kv))
-    require_compiled_flash(jax.jit(functools.partial(
-        flash_attention, causal=True)).lower(q, k, k).as_text())
-    return flash_kernel_vs_f32(shape)
+    return compiled_flash_vs_f32(shape)
 
 
 def phase_flash256(shape=(2, 8192, 16, 2, 256)):
@@ -353,17 +360,16 @@ def phase_flash256(shape=(2, 8192, 16, 2, 256)):
     key-value head, 2 x 8192: the attention shape of the cell
     ``qwen3next-s8192`` (the kernels' tiles and VMEM estimate had been
     checked at 64 and 128 alone)."""
-    import jax
-    import jax.numpy as jnp
+    return compiled_flash_vs_f32(shape)
 
-    from horovod_tpu.ops.flash_attention import flash_attention
 
-    b, s, h, h_kv, d = shape
-    q, k = (jax.ShapeDtypeStruct((b, s, n, d), jnp.bfloat16)
-            for n in (h, h_kv))
-    require_compiled_flash(jax.jit(functools.partial(
-        flash_attention, causal=True)).lower(q, k, k).as_text())
-    return flash_kernel_vs_f32(shape)
+def phase_mla8192(shape=(2, 8192, 32, 32, 192, 128)):
+    """The compiled kernels at a query-key width apart from the value
+    width, 2 x 8192, 32 heads of 192 on 128: the products over positions of
+    the cell ``kanana2-s8192``'s latent attention (its own comparison of
+    gradients runs at 2048 positions), output and the three gradients
+    against the float32 formula."""
+    return compiled_flash_vs_f32(shape)
 
 
 # -------------------------------------------------------------------- gdn8192
@@ -677,6 +683,7 @@ def main(argv=None):
     if args.chips == 1:
         for name, phase in (("flash8192", phase_flash8192),
                             ("flash256", phase_flash256),
+                            ("mla8192", phase_mla8192),
                             ("gdn8192", phase_gdn8192),
                             ("conv8192", phase_conv8192),
                             ("norms8192", phase_norms8192),

@@ -94,8 +94,8 @@ class GPTConfig:
     # mixer(RMSNorm(x))`` with the mixer ``layer_pattern[i]`` names, "*"
     # attention, "M" a Mamba-2 mixer (models/ssm.py), "G" a Gated
     # DeltaNet mixer (models/gdn.py), "C" a gated short convolution
-    # (models/sconv.py), "E" the expert
-    # layer (models/moe.py), "-" the dense MLP. None (default) = every
+    # (models/sconv.py), "L" latent attention (models/mla.py), "E" the
+    # expert layer (models/moe.py), "-" the dense MLP. None (default) = every
     # layer the attention + MLP (or expert) pair of ``Block``.
     layer_pattern: Optional[str] = None
     # False leaves q and k unrotated: attention without a positional
@@ -173,6 +173,17 @@ class GPTConfig:
     # The gated short-convolution mixers' taps (models/sconv.py, pattern
     # letter "C"); their channels are d_model.
     sconv_taps: int = 3
+    # The latent-attention mixers' sizes (models/mla.py, pattern letter
+    # "L"; DeepSeek-V3's names): n_heads heads whose keys and values come
+    # out of a latent of mla_kv_rank channels (``kv_lora_rank``; 0: the
+    # model has no such layer), a query-key width of mla_nope_dim unrotated
+    # (``qk_nope_head_dim``) and mla_rope_dim rotated channels
+    # (``qk_rope_head_dim``, one rotated key a position for all heads, at
+    # ``rotary_base``) on values of mla_value_dim (``v_head_dim``).
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 128
+    mla_rope_dim: int = 64
+    mla_value_dim: int = 128
 
 
 # The crossover policy lives with the kernel (ops/flash_attention.py);
@@ -426,6 +437,15 @@ class MixerBlock(nn.Module):
 
             out = ShortConv(cfg.sconv_taps, dtype=cfg.dtype,
                             name="sconv")(h)
+        elif self.kind == "L":
+            from horovod_tpu.models.mla import LatentAttention
+
+            out = LatentAttention(
+                cfg.n_heads, cfg.mla_kv_rank, cfg.mla_nope_dim,
+                cfg.mla_rope_dim, cfg.mla_value_dim,
+                rotary_base=cfg.rotary_base, norm_eps=cfg.norm_eps,
+                use_flash=cfg.use_flash, dtype=cfg.dtype,
+                name="mla")(h, positions)
         elif self.kind == "E":
             out, aux = _expert_layer(cfg)(h)
         elif self.kind == "-":
@@ -434,7 +454,8 @@ class MixerBlock(nn.Module):
             raise ValueError(
                 f"layer_pattern holds {self.kind!r}: a layer is one of "
                 f"'*' (attention), 'M' (Mamba-2), 'G' (Gated DeltaNet), "
-                f"'C' (gated short convolution), 'E' (experts), '-' (MLP)")
+                f"'C' (gated short convolution), 'L' (latent attention), "
+                f"'E' (experts), '-' (MLP)")
         return x + out, aux
 
 
@@ -547,6 +568,10 @@ def param_partition_spec(params, *, tp_axis="tp", tp_size=None,
             from horovod_tpu.models.sconv import sconv_leaf_spec
 
             return sconv_leaf_spec(names[-1], tp_axis)
+        if "mla" in names:
+            from horovod_tpu.models.mla import mla_leaf_spec
+
+            return mla_leaf_spec(names[-1], tp_axis)
         if any(n in ("q", "k", "v") for n in names):
             heads = leaf.shape[1] if hasattr(leaf, "shape") else None
             if tp_size and heads is not None and heads % tp_size:

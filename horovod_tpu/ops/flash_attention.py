@@ -292,21 +292,26 @@ _XLA_VMEM = 3 * 2 ** 20
 _VMEM_BUDGET = {"fwd": _SCOPED_VMEM, "bwd": _VMEM - _XLA_VMEM}
 
 
-def _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile, s):
+def _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile, s, d_v=None):
     """Estimate of the VMEM one grid step of ``kernel`` holds: the f32
     [block_q, block_k] score tiles alive at once (one in the forward,
     the compiler strip-mines the rest of the softmax; two in the
     backward, p beside ds), the streamed sequence tiles and the resident
     blocks, both double-buffered, and the f32 accumulators, of which the
-    backward's dQ is as long as the sequence. A position of an operand
-    takes whole 128-lane rows whatever ``d`` is, and so does a position
+    backward's dQ is as long as the sequence. ``d`` is the width of q and
+    k (and of dQ and dK), ``d_v`` that of v and o (and dO and dV; None:
+    ``d``, and every term is then what it was before the two were told
+    apart). A position of an operand
+    takes whole 128-lane rows whatever its width is, and so does a position
     of a [.., 1] statistic. Checked against the least limit the compiler
     takes for the backward alone (v5e, bf16, 20 heads of 64): 19.0 MiB
     at 2 x 4096 and 1024 x 512 (estimate 21.5), 26.25 at 1024 x 1024
     (27.0), 21.0 at 1 x 8192 (23.5), 8.0 at 8 x 1024 and 512 x 512
     (7.5), 13.0 at 4 x 2048 (13.5)."""
     lanes = -(-d // _LANES) * _LANES
-    row, acc, stat = lanes * itemsize, lanes * 4, _LANES * 4
+    lanes_v = lanes if d_v is None else -(-d_v // _LANES) * _LANES
+    row, row_v = lanes * itemsize, lanes_v * itemsize
+    acc, acc_v, stat = lanes * 4, lanes_v * 4, _LANES * 4
     score = 4 * block_q * block_k
     # past 128 lanes, what the float32 [block, d] values of a sub-block
     # (the scaled q, the rescaled accumulator, dQ's, dK's and dV's
@@ -314,18 +319,21 @@ def _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile, s):
     # with: at d = 256, 2 x 8192, 16 heads on 2 (qwen3-next-80b) the
     # compiler took 16.31 MiB for the forward alone and 38.27 for the
     # backward inside the model, where the terms before this one and
-    # XLA's share came to 15.5 and 38.0
-    wide = 3 * (block_q + block_k) * (acc - stat)
+    # XLA's share came to 15.5 and 38.0. The wider of the two widths
+    # stands for all of them
+    wide = 3 * (block_q + block_k) * (max(acc, acc_v) - stat)
     if kernel == "fwd":     # K V stream; q o lse blocks; acc m l scratch
-        return (score + 2 * 2 * tile * row
-                + block_q * (2 * 2 * row + 2 * stat + acc + 2 * stat) + wide)
+        return (score + 2 * tile * (row + row_v)
+                + block_q * (2 * (row + row_v) + 2 * stat + acc_v + 2 * stat)
+                + wide)
     # bwd: Q dO lse delta stream in, a dq tile out; k v dk dv blocks and
     # the two accumulators of a block; dq's accumulator
-    return (2 * score + 2 * tile * (2 * (row + stat) + row)
-            + block_k * (4 * 2 * row + 2 * acc) + s * acc + wide)
+    return (2 * score + 2 * tile * (row + stat + row_v + stat + row)
+            + block_k * (2 * 2 * (row + row_v) + acc + acc_v) + s * acc
+            + wide)
 
 
-def _compiler_params(kernel, block_q, block_k, d, itemsize, tile, s):
+def _compiler_params(kernel, block_q, block_k, d, itemsize, tile, s, d_v=None):
     """The default scope where the estimate and XLA's share fit it; else
     just that much as the kernel's own ``vmem_limit_bytes``, and no more:
     the VMEM a kernel's scope takes is taken from the program around it.
@@ -334,8 +342,8 @@ def _compiler_params(kernel, block_q, block_k, d, itemsize, tile, s):
     again as the estimate, PR 25's rule) and in 700.4 at 24.5: XLA kept
     fewer operands of the fusions around each call in VMEM, and they and
     the kernel itself were slower; from 24.5 down to 20 nothing moved."""
-    limit = _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile,
-                        s) + _XLA_VMEM
+    limit = _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile, s,
+                        d_v) + _XLA_VMEM
     if limit <= _SCOPED_VMEM:
         return None
     if limit > _VMEM:
@@ -348,9 +356,10 @@ def _compiler_params(kernel, block_q, block_k, d, itemsize, tile, s):
     return pltpu.CompilerParams(vmem_limit_bytes=limit)
 
 
-def _derive_tile(kernel, s, d, itemsize, causal):
+def _derive_tile(kernel, s, d, itemsize, causal, d_v=None):
     """The score tile of ``kernel`` ("fwd", "bwd") for a sequence of ``s``
-    positions: the largest (block_q, block_k) — multiples of 128 that
+    positions, q and k ``d`` wide and v and o ``d_v`` (None: ``d``): the
+    largest (block_q, block_k) — multiples of 128 that
     divide ``s``, one dividing the other so the streamed tile is
     unaffected, neither above the kernel's preferred size — whose buffers
     fit ``_VMEM_BUDGET``; of equal areas the wider key block. A sequence
@@ -383,32 +392,34 @@ def _derive_tile(kernel, s, d, itemsize, causal):
            for bk in sizes if bk <= most_k
            if max(bq, bk) % min(bq, bk) == 0
            and _vmem_bytes(kernel, bq, bk, d, itemsize,
-                           _seq_tile(s, bq, bk), s) <= _VMEM_BUDGET[kernel]]
+                           _seq_tile(s, bq, bk), s, d_v)
+           <= _VMEM_BUDGET[kernel]]
     return max(fit, key=lambda t: (t[0] * t[1], t[1]),
                default=(_LANES, _LANES))
 
 
-def _score_tile(kernel, s, d, itemsize, causal, block_q, block_k):
+def _score_tile(kernel, s, d, itemsize, causal, block_q, block_k, d_v=None):
     """``(block_q, block_k, derived)`` for one of the two kernels: an
     explicit integer is honoured (clipped to divide ``s``);
     ``None`` takes that side of the tile derived from the shape."""
     derived = block_q is None or block_k is None
     if derived:
-        auto_q, auto_k = _derive_tile(kernel, s, d, itemsize, causal)
+        auto_q, auto_k = _derive_tile(kernel, s, d, itemsize, causal, d_v)
     bq = auto_q if block_q is None else _blocks(s, block_q)
     bk = auto_k if block_k is None else _blocks(s, block_k)
     return bq, bk, derived
 
 
-def _count_trace(kernel, block_q, block_k, derived):
-    """Which score tile each traced kernel got and whether the rule or the
-    caller chose it."""
+def _count_trace(kernel, block_q, block_k, derived, d, d_v):
+    """Which score tile each traced kernel got, whether the rule or the
+    caller chose it, and the two widths it was built for (q and k's, v
+    and o's)."""
     _pallas.count_trace(
         "hvt_flash_kernel_traces_total",
         "flash-attention kernels traced into compiled programs, by "
         "score tile (counted per trace, not per execution)",
         kernel=kernel, block_q=block_q, block_k=block_k,
-        derived=int(derived))
+        derived=int(derived), d_qk=d, d_v=d_v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -457,20 +468,21 @@ class _Plan(NamedTuple):
     interpret: bool
 
 
-def _plan(kernel, q, scale, causal, block_q, block_k):
+def _plan(kernel, q, scale, causal, block_q, block_k, d_v=None):
     """Made outside the jitted calls below, so that what the process
     holds besides the operands (the backend) is part of their cache's
     key and never read under a cached trace."""
     _, _, s, d = q.shape
     block_q, block_k, derived = _score_tile(
-        kernel, s, d, q.dtype.itemsize, causal, block_q, block_k)
+        kernel, s, d, q.dtype.itemsize, causal, block_q, block_k, d_v)
     return _Plan(scale, causal, block_q, block_k, derived,
                  _seq_tile(s, block_q, block_k), _pallas.interpret())
 
 
 def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype):
     return _fwd_call(q, k, v, out_dtype=out_dtype,
-                     plan=_plan("fwd", q, scale, causal, block_q, block_k))
+                     plan=_plan("fwd", q, scale, causal, block_q, block_k,
+                                v.shape[-1]))
 
 
 # Each of the two calls is a ``jax.jit`` of its own: a model's layers
@@ -481,8 +493,9 @@ def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype):
 @functools.partial(jax.jit, static_argnames=("plan", "out_dtype"))
 def _fwd_call(q, k, v, *, plan, out_dtype):
     b, h, s, d = q.shape
+    d_v = v.shape[-1]
     block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
-    _count_trace("fwd", block_q, block_k, plan.derived)
+    _count_trace("fwd", block_q, block_k, plan.derived, d, d_v)
     # Grouped-query attention is served ZERO-COPY: query head hi reads
     # K/V head hi // group through the block index map — no repeat
     # materialization, and the shared K/V tile stays VMEM-resident
@@ -491,25 +504,24 @@ def _fwd_call(q, k, v, *, plan, out_dtype):
     # K/V stream through the grid's sequential LAST axis in VMEM tiles;
     # scratch accumulators carry the online softmax across tiles
     grid = (b, h, s // block_q, s // tile)
-    qspec = pl.BlockSpec((1, 1, block_q, d),
-                         lambda bi, hi, qi, ti: (bi, hi, qi, 0))
-    kvspec = pl.BlockSpec((1, 1, tile, d),
-                          lambda bi, hi, qi, ti: (bi, hi // group, ti, 0))
+    # q and k are ``d`` wide, v and o ``d_v``
+    by_query = lambda width: pl.BlockSpec(
+        (1, 1, block_q, width), lambda bi, hi, qi, ti: (bi, hi, qi, 0))
+    by_tile = lambda width: pl.BlockSpec(
+        (1, 1, tile, width), lambda bi, hi, qi, ti: (bi, hi // group, ti, 0))
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=plan.scale,
                           causal=plan.causal, block_k=block_k),
         grid=grid,
-        in_specs=[qspec, kvspec, kvspec],
-        out_specs=[qspec,
-                   pl.BlockSpec((1, 1, block_q, 1),
-                                lambda bi, hi, qi, ti: (bi, hi, qi, 0))],
-        out_shape=[_pallas.out(q.shape, out_dtype, q, k, v),
+        in_specs=[by_query(d), by_tile(d), by_tile(d_v)],
+        out_specs=[by_query(d_v), by_query(1)],
+        out_shape=[_pallas.out((b, h, s, d_v), out_dtype, q, k, v),
                    _pallas.out((b, h, s, 1), jnp.float32, q, k, v)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_q, d_v), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
         compiler_params=_compiler_params("fwd", block_q, block_k, d,
-                                         q.dtype.itemsize, tile, s),
+                                         q.dtype.itemsize, tile, s, d_v),
         interpret=plan.interpret,
         name="hvt_flash_fwd",
     )(q, k, v)
@@ -530,7 +542,8 @@ def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
     # lse cotangent: ds gains + P∘dlse, i.e. delta shifts by −dlse
     delta = delta - dlse.astype(jnp.float32)
     return _bwd_call(q, k, v, do, lse, delta,
-                     plan=_plan("bwd", q, scale, causal, block_q, block_k))
+                     plan=_plan("bwd", q, scale, causal, block_q, block_k,
+                                v.shape[-1]))
 
 
 @functools.partial(jax.jit, static_argnames="plan")
@@ -546,18 +559,19 @@ def _bwd_call(q, k, v, do, lse, delta, *, plan):
     which are then group-summed — each K/V head's gradient is the sum
     over its query group."""
     b, h, s, d = q.shape
+    d_v = v.shape[-1]
     block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
-    _count_trace("bwd", block_q, block_k, plan.derived)
+    _count_trace("bwd", block_q, block_k, plan.derived, d, d_v)
     group = h // k.shape[1]
     n_k = s // block_k
-    kv_in_ki = pl.BlockSpec((1, 1, block_k, d),
-                            lambda bi, hi, ki, ti: (bi, hi // group, ki, 0))
-    dkv_out_ki = pl.BlockSpec((1, 1, block_k, d),
-                              lambda bi, hi, ki, ti: (bi, hi, ki, 0))
-    q_tile = pl.BlockSpec((1, 1, tile, d),
-                          lambda bi, hi, ki, ti: (bi, hi, ti, 0))
-    vec_tile = pl.BlockSpec((1, 1, tile, 1),
-                            lambda bi, hi, ki, ti: (bi, hi, ti, 0))
+    # q, k, dq and dk are ``d`` wide, v, do and dv ``d_v``
+    kv_in_ki = lambda width: pl.BlockSpec(
+        (1, 1, block_k, width),
+        lambda bi, hi, ki, ti: (bi, hi // group, ki, 0))
+    dkv_out_ki = lambda width: pl.BlockSpec(
+        (1, 1, block_k, width), lambda bi, hi, ki, ti: (bi, hi, ki, 0))
+    q_tile = lambda width: pl.BlockSpec(
+        (1, 1, tile, width), lambda bi, hi, ki, ti: (bi, hi, ti, 0))
     dq_tile = pl.BlockSpec(
         (1, 1, tile, d),
         lambda bi, hi, ki, ti: (bi, hi, jnp.where(ki == n_k - 1, ti, 0), 0))
@@ -566,16 +580,17 @@ def _bwd_call(q, k, v, do, lse, delta, *, plan):
         functools.partial(_bwd_kernel, scale=plan.scale,
                           causal=plan.causal, block_q=block_q),
         grid=(b, h, n_k, s // tile),
-        in_specs=[kv_in_ki, kv_in_ki, q_tile, q_tile, vec_tile, vec_tile],
-        out_specs=[dq_tile, dkv_out_ki, dkv_out_ki],
+        in_specs=[kv_in_ki(d), kv_in_ki(d_v), q_tile(d), q_tile(d_v),
+                  q_tile(1), q_tile(1)],
+        out_specs=[dq_tile, dkv_out_ki(d), dkv_out_ki(d_v)],
         out_shape=[_pallas.out(q.shape, q.dtype, *operands),
                    _pallas.out(q.shape, k.dtype, *operands),
-                   _pallas.out(q.shape, v.dtype, *operands)],
+                   _pallas.out((b, h, s, d_v), v.dtype, *operands)],
         scratch_shapes=[pltpu.VMEM((s, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, d_v), jnp.float32)],
         compiler_params=_compiler_params("bwd", block_q, block_k, d,
-                                         q.dtype.itemsize, tile, s),
+                                         q.dtype.itemsize, tile, s, d_v),
         interpret=plan.interpret,
         name="hvt_flash_bwd",
     )(k, v, q, do, lse, delta)
@@ -584,7 +599,7 @@ def _bwd_call(q, k, v, do, lse, delta, *, plan):
         dk = dk.astype(jnp.float32).reshape(
             b, h_kv, group, s, d).sum(axis=2).astype(k.dtype)
         dv = dv.astype(jnp.float32).reshape(
-            b, h_kv, group, s, d).sum(axis=2).astype(v.dtype)
+            b, h_kv, group, s, -1).sum(axis=2).astype(v.dtype)
     return dq, dk, dv
 
 
@@ -596,15 +611,18 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
     """Fused multi-head attention.
 
     Args:
-      q, k, v: [batch, seq, heads, head_dim] (BSHD, matching
-        :mod:`horovod_tpu.models.transformer`).
+      q, k: [batch, seq, heads, head_dim] (BSHD, matching
+        :mod:`horovod_tpu.models.transformer`); ``k`` may have fewer
+        heads (grouped queries).
+      v: [batch, seq, k's heads, value_dim]: a value width apart from
+        the query-key width (latent attention: 192 on 128) or the same.
       causal: apply causal masking.
       scale: softmax scale, default ``head_dim ** -0.5``.
       block_q / block_k: the score tile; ``None`` (the default) derives
         it from the shape, one tile a kernel (``_derive_tile``); an
         integer is honoured, clipped to divide seq.
 
-    Returns [batch, seq, heads, head_dim] in q.dtype. Differentiable
+    Returns [batch, seq, heads, value_dim] in q.dtype. Differentiable
     (custom VJP with a recompute-based backward kernel).
     """
     o, _ = flash_attention_with_lse(q, k, v, causal=causal, scale=scale,
@@ -615,6 +633,8 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
 def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
                              block_q=None, block_k=None, out_dtype=None):
     """Fused attention returning ``(o, lse)``; both are differentiable.
+    ``q`` and ``k`` share one width and ``v`` and ``o`` another, which may
+    be the same (``flash_attention``).
 
     ``lse[b, s, h]`` is the log-sum-exp of the (scaled, masked) scores for
     each query — exactly what blockwise/ring composition needs to combine
@@ -632,10 +652,14 @@ def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
         raise ValueError(
             f"GQA requires n_heads ({h}) divisible by n_kv_heads "
             f"({h_kv})")
+    if k.shape[-1] != d or v.shape[:-1] != k.shape[:-1]:
+        raise ValueError(
+            f"q {q.shape} and k {k.shape} share the query-key width, and "
+            f"v {v.shape} has k's positions and heads")
     if scale is None:
         scale = d ** -0.5
     bq, bk, _ = _score_tile("fwd", s, d, q.dtype.itemsize, causal,
-                             block_q, block_k)
+                             block_q, block_k, v.shape[-1])
     if not _pallas.interpret() and (bq % 8 or bk % 8):
         # Mosaic refuses the kernel ("cannot statically prove that index
         # in dimension 2 is a multiple of 8"); the interpreter has no

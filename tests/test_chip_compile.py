@@ -67,18 +67,20 @@ def _loss(attend):
 
 
 def _qkv(shape, sharding):
-    b, s, h, h_kv, d = shape
-    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=sharding)
-    kv = jax.ShapeDtypeStruct((b, s, h_kv, d), jnp.bfloat16,
-                              sharding=sharding)
-    return q, kv, kv
+    """``(batch, seq, heads, kv_heads, head_dim)`` and, where the values'
+    width is not the keys', that width after them."""
+    b, s, h, h_kv, d, d_v = (*shape, shape[-1])[:6]
+    like = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16,
+                                              sharding=sharding)
+    return like(b, s, h, d), like(b, s, h_kv, d), like(b, s, h_kv, d_v)
 
 
-# (batch, seq, heads, kv_heads, head_dim): the shapes a 12 x 768 GPT and
+# (batch, seq, heads, kv_heads, head_dim[, value_dim]): the shapes a 12 x 768 GPT and
 # chip_smoke.py run, the benchmark cells gpt2l-s1024's, gpt2l-s4096's and
 # olmoe-s4096's and nemotron3s-s8192's own (four query heads on one
 # key-value head at 8192 positions) and qwen3next-s8192's (a head of 256,
-# sixteen query heads on two key-value heads), gpt2l-dp4's (four chips' batch under a shard_map
+# sixteen query heads on two key-value heads) and kanana2-s8192's (latent
+# attention: 32 heads, queries and keys of 192 on values of 128), gpt2l-dp4's (four chips' batch under a shard_map
 # that checks vma), gpt2-large's heads at 4 x 2048 and at 1 x 8192 (two
 # streamed tiles: the backward's dQ accumulator is addressed by a dynamic
 # slice and leaves a tile at a time), one grouped-query shape, the 4-chip
@@ -100,6 +102,8 @@ def _qkv(shape, sharding):
     pytest.param("flash", (2, 4096, 16, 16, 128), id="flash-olmoe-s4096"),
     pytest.param("flash", (2, 8192, 4, 1, 128), id="flash-nemotron3s-s8192"),
     pytest.param("flash", (2, 8192, 16, 2, 256), id="flash-qwen3next-s8192"),
+    pytest.param("flash", (2, 8192, 32, 32, 192, 128),
+                 id="flash-kanana2-s8192"),
     pytest.param("ring", (1, 16384, 12, 12, 64), id="ring-sp4-16384"),
     pytest.param("refused", (1, 100, 2, 2, 64), id="block-not-multiple-of-8"),
 ])
@@ -168,6 +172,58 @@ def test_layers_share_one_lowered_kernel(compiled_kernel, v5e_devices):
     assert one == two == 3, (one, two)
     assert calls_two > calls_one
     assert names == {"hvt_flash_fwd", "hvt_flash_bwd"}
+
+
+def test_latent_attention_layers_share_the_kernels_under_their_scope(
+        compiled_kernel, v5e_devices):
+    """A three-layer ``models.GPT`` of latent-attention mixers at the
+    published widths (queries and keys of 128 | 64 on values of 128) holds
+    three ``tpu_custom_call`` sites however many layers it has (the
+    forward's, the recomputed forward's and the one backward's), the two
+    flash kernels by name, and, compiled, each of the nine calls'
+    ``op_name`` has the scope ``mla_core`` in it (what
+    ``chipbench/layer_metrics/mla_core_roofline.py`` chooses the kernels
+    by) and the pass it belongs to (what ``chipbench/regions.py`` splits
+    the step by)."""
+    from horovod_tpu.models import GPT, GPTConfig
+
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def step(pattern):
+        model = GPT(GPTConfig(
+            vocab_size=512, n_layers=len(pattern), layer_pattern=pattern,
+            d_model=128, n_heads=2, d_ff=256, max_seq_len=1024, remat=True,
+            use_flash=True, mla_kv_rank=64, rotary_base=1e6))
+        tokens = jax.ShapeDtypeStruct((2, 1024), jnp.int32,
+                                      sharding=one_chip)
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(model.init, jax.random.key(0), tokens))
+        assert params["params"]["block_0"]["mla"]["kv_up"].shape == (
+            64, 2, 256)
+        loss = lambda p, t: model.apply(p, t).astype(jnp.float32).mean()
+        return jax.jit(jax.grad(loss)).lower(params, tokens)
+
+    one, three = step("L"), step("LLL")
+    sites = lambda lowered: lowered.as_text().count(
+        "stablehlo.custom_call @tpu_custom_call")
+    assert sites(one) == sites(three) == 3, (sites(one), sites(three))
+    assert set(re.findall(r"hvt_flash_\w+", three.as_text())) == {
+        "hvt_flash_fwd", "hvt_flash_bwd"}
+    text = three.compile().as_text()
+    assert "bf16[2,2,1024,192]" in text and "bf16[2,2,1024,128]" in text
+    # inlined, each call keeps its call site's whole op_name
+    names = re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*?op_name="([^"]*)"', text)
+    assert len(names) == 9 and all("/mla_core/" in n for n in names), names
+    kinds = lambda kernel, inside: [
+        n for n in names if f"/{kernel}/" in n and inside(n)]
+    assert len(kinds("hvt_flash_fwd", lambda n: "transpose(" not in n)) == 3
+    assert len(kinds("hvt_flash_fwd",
+                     lambda n: "rematted_computation" in n)) == 3
+    assert len(kinds("hvt_flash_bwd", lambda n: "transpose(jvp(" in n
+                     and "rematted_computation" not in n)) == 3
 
 
 def test_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices):

@@ -51,7 +51,8 @@ def test_flash_must_be_the_compiled_kernel():
 
 
 # what no cell of the benchmark decides, in the order it runs
-PHASES = {1: ["launcher", "device", "flash8192", "flash256", "gdn8192",
+PHASES = {1: ["launcher", "device", "flash8192", "flash256", "mla8192",
+              "gdn8192",
               "conv8192", "norms8192", "eager"],
           4: ["device", "ring4", "dryrun4"]}
 
@@ -174,10 +175,13 @@ def test_norm_kernels_beside_the_plain_bodies():
                 assert got["ms_forward_and_backward"] > 0
 
 
-def test_flash_kernel_against_the_float32_formula():
-    # grouped-query heads, interpreter: the comparison itself at a size
-    # the CPU can afford; errors are a few bf16 eps
-    out = chip_smoke.flash_kernel_vs_f32((1, 64, 4, 2, 16))
+@pytest.mark.parametrize("shape", [(1, 64, 4, 2, 16), (1, 64, 2, 2, 24, 16)],
+                         ids=["grouped-queries", "24-on-16"])
+def test_flash_kernel_against_the_float32_formula(shape):
+    # grouped-query heads, and a value width apart from the key width (the
+    # mla8192 phase's), interpreter: the comparison itself at a size the
+    # CPU can afford; errors are a few bf16 eps
+    out = chip_smoke.flash_kernel_vs_f32(shape)
     assert set(out["rel_l2"]) == {"o", "dq", "dk", "dv"}
     assert max(out["rel_l2"].values()) < chip_smoke.BF16_REL_L2
 

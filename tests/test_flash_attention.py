@@ -527,7 +527,8 @@ def test_trace_counter_carries_the_tile_and_who_chose_it():
                       if derived else (32, 64))
             out.append(_trace_count(kernel=kern, block_q=str(bq),
                                     block_k=str(bk),
-                                    derived=str(int(derived))))
+                                    derived=str(int(derived)),
+                                    d_qk=q.shape[-1], d_v=v.shape[-1]))
         return out
 
     # two layers of one shape: each kernel is a jit of its own, traced
@@ -557,11 +558,12 @@ def test_trace_counter_adds_nothing_to_the_program(monkeypatch):
         lambda q, k, v: fa.flash_attention(q, k, v).sum(),
         (0, 1, 2))).lower(q, k, v).as_text()
     jax.clear_caches()
+    widths = dict(d_qk=q.shape[-1], d_v=v.shape[-1])
     before = _trace_count(kernel="fwd", block_q="128", block_k="128",
-                          derived="1")
+                          derived="1", **widths)
     counted = lowered()
     assert _trace_count(kernel="fwd", block_q="128", block_k="128",
-                        derived="1") == before + 1
+                        derived="1", **widths) == before + 1
     monkeypatch.setattr(fa, "_count_trace", lambda *a: None)
     jax.clear_caches()      # or the kernels' cached traces are served
     assert lowered() == counted
