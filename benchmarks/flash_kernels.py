@@ -4,6 +4,8 @@
     chiprun -- python benchmarks/flash_kernels.py \
         --shape 2,4096,20,20,64 --blocks derived,128x128,bwd=512x512
     chiprun -- python benchmarks/flash_kernels.py --shape 2,8192,32,32,192,128
+    chiprun -- python benchmarks/flash_kernels.py \
+        --shape 2,8192,32,32,192,128 --rotated 64
 
 For every score tile asked for it jits one forward + ``vjp`` of
 ``flash_attention`` at ``--shape`` (batch, seq, heads, kv_heads, head_dim
@@ -24,7 +26,16 @@ the einsum attention of ``models/transformer.py`` at the same shape, wall
 clock only, and alone: inside a training step XLA schedules it otherwise
 (PERF.md section 6, PR 27: 7.82 ms here, 4.70 in the step), so it does not
 say where ``"auto"``'s crossover belongs; the cells do. ``--source FILE`` times another copy of
-``ops/flash_attention.py`` (the parent commit's) instead.
+``ops/flash_attention.py`` (the parent commit's) instead. ``--rotated E``
+(since PR 49) takes the last ``E`` of the query-key width as a rotated pair,
+``q_r`` a head and **one ``k_r`` a position**, and times every tile twice on
+the same numbers: ``"entry": "whole"`` assembles ``q_n | q_r`` and ``k_n |
+k_r`` (``k_r`` broadcast over the heads) for the one-width entry, as latent
+attention did until PR 49, and ``"entry": "parts"`` hands the kernels the
+four parts (``flash_attention(q_n, k_n, v, q_r=.., k_r=..)``); both give
+``(o, dq_n, dq_r, dk_n, dk_r, dv)``, so ``rel_l2_vs_first`` compares the
+second with the first. A ``--source`` without the two-part entry runs
+``whole`` alone.
 
 A microbenchmark, not the yardstick: the cell that decides is
 ``gpt2l-s4096`` of ``BENCHMARK.json``. It refuses to run without a TPU.
@@ -53,7 +64,9 @@ def load_flash(source):
     return module
 
 
-def make_inputs(shape):
+def make_inputs(shape, rotated=0):
+    """``(q, k, v), do``, or with a rotated width ``(q_n, q_r, k_n, k_r,
+    v), do``: the same numbers, ``k_r`` the first head's."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -61,7 +74,10 @@ def make_inputs(shape):
     rng = np.random.RandomState(0)
     q, k, v, do = (jnp.asarray(rng.normal(size=(b, s, *dims)), jnp.bfloat16)
                    for dims in ((h, d), (h_kv, d), (h_kv, d_v), (h, d_v)))
-    return q, k, v, do
+    if not rotated:
+        return (q, k, v), do
+    n = d - rotated
+    return (q[..., :n], q[..., n:], k[..., :n], k[:, :, 0, n:], v), do
 
 
 def einsum_attention(q, k, v, causal):
@@ -81,11 +97,26 @@ def einsum_attention(q, k, v, causal):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def whole_width(attend):
+    """``attend(q, k, v)`` as a function of the four parts: the query and
+    the key assembled, the one rotated key a position broadcast over the
+    heads (``models/mla.py``'s ``whole_key``)."""
+    import jax.numpy as jnp
+
+    from horovod_tpu.models.mla import whole_key
+
+    def of_parts(q_n, q_r, k_n, k_r, v):
+        return attend(jnp.concatenate([q_n, q_r], -1), whole_key(k_n, k_r),
+                      v)
+
+    return of_parts
+
+
 def call_and_vjp(attend):
     import jax
 
-    def run(q, k, v, do):
-        o, vjp = jax.vjp(attend, q, k, v)
+    def run(operands, do):
+        o, vjp = jax.vjp(attend, *operands)
         return (o, *vjp(do))
 
     return jax.jit(run)
@@ -113,27 +144,41 @@ def kernel_durations(trace_dir):
     return out
 
 
-def measure(flash, shape, tiles, causal=True, iters=5, einsum=False):
-    """One dict a tile (and one for the einsum path), as the module
-    docstring describes."""
+def measure(flash, shape, tiles, causal=True, iters=5, einsum=False,
+            rotated=0):
+    """One dict a tile (with a rotated width two, one an entry; and one
+    for the einsum path), as the module docstring describes."""
+    import inspect
+
     import jax
     from chip_smoke import rel_l2
 
-    args = make_inputs(shape)
+    args = make_inputs(shape, rotated)
     derive = flash._derive_tile
     runs = []
     for tile in tiles:
         blocks = (dict(zip(("block_q", "block_k"), tile))
                   if isinstance(tile, tuple) else {})
-        runs.append((tile, call_and_vjp(
-            lambda q, k, v, blocks=blocks: flash.flash_attention(
-                q, k, v, causal=causal, **blocks))))
+        whole = lambda q, k, v, blocks=blocks: flash.flash_attention(
+            q, k, v, causal=causal, **blocks)
+        if not rotated:
+            runs.append((tile, None, call_and_vjp(whole)))
+            continue
+        runs.append((tile, "whole", call_and_vjp(whole_width(whole))))
+        if "q_r" in inspect.signature(flash.flash_attention).parameters:
+            runs.append((tile, "parts", call_and_vjp(
+                lambda q_n, q_r, k_n, k_r, v, blocks=blocks:
+                flash.flash_attention(q_n, k_n, v, q_r=q_r, k_r=k_r,
+                                      causal=causal, **blocks))))
     if einsum:
-        runs.append(("einsum", call_and_vjp(
-            lambda q, k, v: einsum_attention(q, k, v, causal))))
+        plain = lambda q, k, v: einsum_attention(q, k, v, causal)
+        runs.append(("einsum", None, call_and_vjp(
+            whole_width(plain) if rotated else plain)))
     first, lines = None, []
-    for tile, fn in runs:                     # compile, warm up, compare
+    for tile, entry, fn in runs:              # compile, warm up, compare
         line = {"tile": tile, "shape": list(shape), "causal": causal}
+        if entry:
+            line.update(entry=entry, rotated=rotated)
         lines.append(line)
         if isinstance(tile, str) and "=" in tile:
             # traced here, once: the rule is read outside the jitted call
@@ -149,7 +194,7 @@ def measure(flash, shape, tiles, causal=True, iters=5, einsum=False):
             flash._derive_tile = derive
         first = got if first is None else first
         line["rel_l2_vs_first"] = rel_l2(got, first)
-    ran = [(line, fn) for line, (_, fn) in zip(lines, runs)
+    ran = [(line, fn) for line, (*_, fn) in zip(lines, runs)
            if "refused" not in line]
     with tempfile.TemporaryDirectory() as trace_dir:
         jax.profiler.start_trace(trace_dir)
@@ -187,6 +232,7 @@ def main(argv=None):
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--einsum", action="store_true")
     ap.add_argument("--source")
+    ap.add_argument("--rotated", type=int, default=0, metavar="E")
     a = ap.parse_args(argv)
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -199,7 +245,7 @@ def main(argv=None):
     tiles = [parse_tile(t) for t in a.blocks.split(",")]
     for line in measure(load_flash(a.source), shape, tiles,
                         causal=not a.no_causal, iters=a.iters,
-                        einsum=a.einsum):
+                        einsum=a.einsum, rotated=a.rotated):
         line["device"] = jax.devices()[0].device_kind
         print(json.dumps(line), flush=True)
 

@@ -17,7 +17,10 @@ pairs (the weights' columns not regrouped), the rotated key taken a head
 instead of shared (head ``h`` reads it rolled by ``h`` channels), the scale
 at ``128^-1/2``, the route scale at 1, the chosen weights not
 renormalised, a router whose product is left at the TPU's default
-precision (one bf16 pass), and the softmax in bf16. Then the loss of the
+precision (one bf16 pass), and the softmax in bf16 (the last and the key a
+head both replace ``mla.causal_attention``, the seam at which the mixer
+hands the kernels its four parts since PR 49: on the chip ``whole_key`` is
+no longer called). Then the loss of the
 whole model on a fresh initialisation against the reference's, and the
 reference itself at the TPU's default precision: what the step-loss
 comparison can and cannot tell. One JSON line each.
@@ -41,22 +44,34 @@ from benchmarks.qwen3next_wrong_programs import _route_with, _swapped
 QUERY_BLOCK = 512
 
 
-def _key_a_head(k_n, k_r):
-    """``mla.whole_key``'s arguments, the rotated key no longer shared:
-    head ``h`` reads it rolled by ``h`` channels."""
+def _attention_with_a_key_a_head(q_n, q_r, k_n, k_r, v, positions, scale,
+                                 use_flash):
+    """``mla.causal_attention``'s arguments, the rotated key no longer
+    shared: head ``h`` reads it rolled by ``h`` channels. The kernels take
+    one ``k_r`` a position, so a key a head goes to them assembled, at one
+    width (what the mixer ran until PR 49)."""
     import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as flash
 
     rolled = jnp.stack([jnp.roll(k_r, h, axis=-1)
                         for h in range(k_n.shape[2])], axis=2)
-    return jnp.concatenate([k_n, rolled], axis=-1)
+    return flash.flash_attention(
+        jnp.concatenate([q_n, q_r], axis=-1),
+        jnp.concatenate([k_n, rolled], axis=-1), v, causal=True, scale=scale)
 
 
-def _attention_with_a_bf16_softmax(q, k, v, positions, scale, use_flash):
+def _attention_with_a_bf16_softmax(q_n, q_r, k_n, k_r, v, positions, scale,
+                                   use_flash):
     """``mla.causal_attention``'s arguments: the scores rounded to bf16 and
-    the softmax computed in bf16, a block of queries at a time."""
+    the softmax computed in bf16, a block of queries at a time, on the
+    query and the key assembled whole."""
     import jax
     import jax.numpy as jnp
 
+    from horovod_tpu.models import mla
+
+    q, k = jnp.concatenate([q_n, q_r], axis=-1), mla.whole_key(k_n, k_r)
     b, s, h, _ = q.shape
     block = min(QUERY_BLOCK, s)
 
@@ -125,7 +140,8 @@ def main():
             ("the rotary on halves without the permutation",
              _swapped(mla, "pairs_to_halves", lambda w, width: w)),
             ("the rotated key taken a head instead of shared",
-             _swapped(mla, "whole_key", _key_a_head)),
+             _swapped(mla, "causal_attention",
+                      _attention_with_a_key_a_head)),
             ("the scale at 128^-1/2",
              _swapped(mla, "score_scale", lambda nope, rope: nope ** -0.5)),
             ("the route scale at 1",

@@ -279,12 +279,16 @@ def require_compiled_flash(lowered_text):
           "no tpu_custom_call in the lowered flash program")
 
 
-def flash_kernel_vs_f32(shape):
+def flash_kernel_vs_f32(shape, rotated=0):
     """One forward+backward of ``flash_attention`` alone at
     (batch, seq, heads, kv_heads, head_dim) and, where the values' width
     is not the keys', that width after them, against the einsum formula in
     float32, one head at a time (at seq 8192 twelve heads of float32
-    scores would not fit the chip at once)."""
+    scores would not fit the chip at once). With ``rotated`` the last that
+    many of the query-key columns go to the kernels as a pair of their
+    own, ``q_r`` a head and the first head's ``k_r`` for every head
+    (latent attention's), and the gradients come back by part: ``k_r``'s
+    is the heads' sum."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -292,12 +296,20 @@ def flash_kernel_vs_f32(shape):
     from horovod_tpu.ops.flash_attention import flash_attention
 
     b, s, h, h_kv, d, d_v = (*shape, shape[-1])[:6]
+    n = d - rotated
     rng = np.random.RandomState(0)
     q, k, v, do = (jnp.asarray(rng.normal(size=(b, s, *dims)), jnp.bfloat16)
                    for dims in ((h, d), (h_kv, d), (h_kv, d_v), (h, d_v)))
-
-    o, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, causal=True),
-                     q, k, v)
+    if rotated:
+        k = k.at[..., n:].set(k[:, :, :1, n:])    # one rotated key for all
+        o, vjp = jax.vjp(
+            lambda q_n, q_r, k_n, k_r, v: flash_attention(
+                q_n, k_n, v, q_r=q_r, k_r=k_r, causal=True),
+            q[..., :n], q[..., n:], k[..., :n], k[:, :, 0, n:], v)
+    else:
+        o, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v,
+                                                         causal=True),
+                         q, k, v)
     got = jax.device_get((o, *vjp(do)))
 
     @jax.jit
@@ -321,8 +333,14 @@ def flash_kernel_vs_f32(shape):
             want[0][:, :, i], want[1][:, :, i] = o_i, dq_i
             want[2][:, :, j] += dk_i
             want[3][:, :, j] += dv_i
+    names = ("o", "dq", "dk", "dv")
+    if rotated:
+        o_w, dq_w, dk_w, dv_w = want
+        names = ("o", "dq_n", "dq_r", "dk_n", "dk_r", "dv")
+        want = (o_w, dq_w[..., :n], dq_w[..., n:], dk_w[..., :n],
+                dk_w[..., n:].sum(axis=2), dv_w)
     errs = {name: rel_l2(g, w) for name, g, w in
-            zip(("o", "dq", "dk", "dv"), got, want)}
+            zip(names, got, want, strict=True)}
     check(all(np.isfinite(list(errs.values()))) and
           max(errs.values()) <= BF16_REL_L2,
           f"flash_attention at {shape} differs from the float32 einsum "
@@ -330,7 +348,7 @@ def flash_kernel_vs_f32(shape):
     return {"shape": list(shape), "rel_l2": errs}
 
 
-def compiled_flash_vs_f32(shape):
+def compiled_flash_vs_f32(shape, rotated=0):
     """``flash_kernel_vs_f32`` once the lowered call at ``shape`` is seen to
     hold the Mosaic kernel."""
     import jax
@@ -339,11 +357,13 @@ def compiled_flash_vs_f32(shape):
     from horovod_tpu.ops.flash_attention import flash_attention
 
     b, s, h, h_kv, d, d_v = (*shape, shape[-1])[:6]
-    q, k, v = (jax.ShapeDtypeStruct((b, s, n, width), jnp.bfloat16)
-               for n, width in ((h, d), (h_kv, d), (h_kv, d_v)))
+    like = lambda *dims: jax.ShapeDtypeStruct((b, s, *dims), jnp.bfloat16)
+    q, k, v = like(h, d - rotated), like(h_kv, d - rotated), like(h_kv, d_v)
+    pair = ({"q_r": like(h, rotated), "k_r": like(rotated)} if rotated
+            else {})
     require_compiled_flash(jax.jit(functools.partial(
-        flash_attention, causal=True)).lower(q, k, v).as_text())
-    return flash_kernel_vs_f32(shape)
+        flash_attention, causal=True)).lower(q, k, v, **pair).as_text())
+    return flash_kernel_vs_f32(shape, rotated)
 
 
 def phase_flash8192(shape=(2, 8192, 4, 1, 128)):
@@ -363,13 +383,17 @@ def phase_flash256(shape=(2, 8192, 16, 2, 256)):
     return compiled_flash_vs_f32(shape)
 
 
-def phase_mla8192(shape=(2, 8192, 32, 32, 192, 128)):
+def phase_mla8192(shape=(2, 8192, 32, 32, 192, 128), rotated=64):
     """The compiled kernels at a query-key width apart from the value
     width, 2 x 8192, 32 heads of 192 on 128: the products over positions of
     the cell ``kanana2-s8192``'s latent attention (its own comparison of
-    gradients runs at 2048 positions), output and the three gradients
-    against the float32 formula."""
-    return compiled_flash_vs_f32(shape)
+    gradients runs at 2048 positions), output and gradients against the
+    float32 formula, by both entries: ``whole`` with q and k 192 wide
+    (what the mixer assembled until PR 49) and ``parts`` with the last 64
+    columns as a rotated pair, one ``k_r`` a position for all 32 heads
+    (what it hands over since)."""
+    return {"whole": compiled_flash_vs_f32(shape),
+            "parts": compiled_flash_vs_f32(shape, rotated)}
 
 
 # -------------------------------------------------------------------- gdn8192

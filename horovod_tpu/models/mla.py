@@ -13,11 +13,16 @@ under a ``jax.named_scope`` of its name so that a device trace can be split
 by them:
 
 1. ``mla_q_proj``: ``q = x W_q``, ``W_q [d, H, n + e]``, a head's columns
-   ``q_n | q_r``; no bias, no query latent (``q_lora_rank`` null).
+   ``q_n | q_r``; no bias, no query latent (``q_lora_rank`` null). Two
+   products, ``q_n = x W_q[.., :n]`` and ``q_r = x W_q[.., n:]`` (its
+   columns regrouped: step 4): the
+   *weight's* columns are cut (a few megabytes a layer), so that no
+   activation is ever sliced, and none put beside another, for step 5.
 2. ``mla_kv_down``: ``[c | k_r] = x W_kva``, ``W_kva [d, r + e]``; ``c <-
    RMSNorm_r(c)`` in float32 with a weight ``[r]``. ``k_r [b, s, e]`` has
    no head axis.
-3. ``mla_kv_up``: ``[k_n | v] = c W_kvb``, ``W_kvb [r, H, n + v]``.
+3. ``mla_kv_up``: ``[k_n | v] = c W_kvb``, ``W_kvb [r, H, n + v]``, again
+   two products over the weight's two groups of columns.
 4. ``mla_rope``: the rotary at ``rotary_base`` over the ``e`` channels of
    ``q_r`` (every head) and of ``k_r``. The parameters hold the rotated
    columns in the published order, interleaved pairs ``(2j, 2j + 1)`` at
@@ -29,12 +34,18 @@ by them:
    sums the same ``e`` products in another order.
 5. ``mla_core``: ``score_h(t, u) = (q_n,h(t) . k_n,h(u) + q_r,h(t) .
    k_r(u)) (n + e)^-1/2``, causal softmax in float32, ``o_h = sum_u p
-   v_h(u)``. The key is assembled whole, ``k_h = k_n,h | k_r`` with ``k_r``
-   broadcast over the heads, and the products over positions are
-   ``ops/flash_attention.py``'s kernels at a query-key width of ``n + e``
-   and a value width of ``v`` where ``resolve_flash`` says so (the rule
-   every attention of the package goes by: the shape decides), else
-   einsums.
+   v_h(u)`` (``causal_attention``). Where ``resolve_flash`` says so (the
+   rule every attention of the package goes by: the shape decides) the
+   products over positions are ``ops/flash_attention.py``'s kernels, **and
+   they take the four parts as the projections and the rotary left them**:
+   the score's two products are summed inside the kernel, every head's
+   grid steps read the one ``k_r`` a position, and nothing ``n + e`` wide
+   exists in either pass (``k_r``'s gradient is the heads' sum of what
+   the kernel gives a head). Everywhere else (the CPU, a short or odd
+   length) the query and the key are assembled whole, ``q_h = q_n,h |
+   q_r,h`` and ``k_h = k_n,h | k_r`` with ``k_r`` broadcast over the heads
+   (``whole_key``), for einsums with a float32 softmax: the plain body
+   the kernels are compared with.
 6. ``mla_out_proj``: ``out = [o_1 .. o_H] W_o``, ``W_o [H, v, d]``.
 
 For a caller that asks for the collection ``intermediates`` the mixer's
@@ -109,13 +120,17 @@ def score_scale(nope: int, rope: int) -> float:
     return 1.0 / np.sqrt(nope + rope)
 
 
-def causal_attention(q, k, v, positions, scale, use_flash):
-    """``softmax(q k^T scale) v`` over the positions before and at a
-    query's, ``q`` and ``k`` ``[b, s, H, n + e]`` and ``v [b, s, H, v]``:
-    the flash kernels where ``resolve_flash`` says so, else einsums with a
-    float32 softmax."""
-    if flash.resolve_flash(use_flash, q.shape[1]):
-        return flash.flash_attention(q, k, v, causal=True, scale=scale)
+def causal_attention(q_n, q_r, k_n, k_r, v, positions, scale, use_flash):
+    """``softmax((q_n k_n^T + q_r k_r^T) scale) v`` over the positions
+    before and at a query's: ``q_n`` and ``k_n [b, s, H, n]``, ``q_r [b, s,
+    H, e]``, the shared ``k_r [b, s, e]`` and ``v [b, s, H, v]``. The
+    flash kernels on the parts as they are where ``resolve_flash`` says
+    so; else the query and the key whole, for einsums with a float32
+    softmax."""
+    if flash.resolve_flash(use_flash, q_n.shape[1]):
+        return flash.flash_attention(q_n, k_n, v, q_r=q_r, k_r=k_r,
+                                     causal=True, scale=scale)
+    q, k = jnp.concatenate([q_n, q_r], axis=-1), whole_key(k_n, k_r)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32)
     seen = positions[:, None, :] <= positions[:, :, None]      # [b, q, k]
@@ -163,24 +178,27 @@ class LatentAttention(nn.Module):
         x = x.reshape(-1, seq, d).astype(self.dtype)
         positions = jnp.broadcast_to(positions, lead + (seq,)).reshape(
             -1, seq)
+        # the weights' columns are cut, never an activation's: a head's
+        # unrotated and rotated query columns, its key's and its value's
+        by_head = lambda t, w: jnp.einsum("bsd,dhk->bshk", t, w)
         with jax.named_scope("mla_q_proj"):
-            q = jnp.einsum("bsd,dhk->bshk", x,
-                           pairs_to_halves(w_q.astype(self.dtype), e))
+            w_q = w_q.astype(self.dtype)
+            q_n = by_head(x, w_q[..., :n])
+            q_r = by_head(x, pairs_to_halves(w_q[..., n:], e))
         with jax.named_scope("mla_kv_down"):
             down = jnp.dot(x, pairs_to_halves(w_kva.astype(self.dtype), e))
             c, k_r = down[..., :r], down[..., r:]
             c = latent_norm(c, kv_norm, self.norm_eps)
         with jax.named_scope("mla_kv_up"):
-            up = jnp.einsum("bsr,rhk->bshk", c, w_kvb.astype(self.dtype))
-            k_n, values = up[..., :n], up[..., n:]
+            w_kvb = w_kvb.astype(self.dtype)
+            k_n = by_head(c, w_kvb[..., :n])
+            values = by_head(c, w_kvb[..., n:])
         with jax.named_scope("mla_rope"):
-            q_r = rotate_halves(q[..., n:], positions, self.rotary_base)
+            q_r = rotate_halves(q_r, positions, self.rotary_base)
             k_r = rotate_halves(k_r, positions, self.rotary_base)
         with jax.named_scope("mla_core"):
-            out = causal_attention(
-                jnp.concatenate([q[..., :n], q_r], axis=-1),
-                whole_key(k_n, k_r), values, positions, score_scale(n, e),
-                self.use_flash)
+            out = causal_attention(q_n, q_r, k_n, k_r, values, positions,
+                                   score_scale(n, e), self.use_flash)
         with jax.named_scope("mla_out_proj"):
             out = jnp.einsum("bshv,hvd->bsd", out, w_o.astype(self.dtype))
         out = out.reshape(*lead, seq, d)

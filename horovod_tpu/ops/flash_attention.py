@@ -15,6 +15,21 @@ Backward pass recomputes score tiles (FLOPs-for-HBM trade, the same choice
 over K/V blocks: each tile is made once and gives its share of dQ, dK and
 dV (five products).
 
+A caller whose queries and keys come in two parts (latent attention: 128
+columns a head from one projection, 64 rotated ones from another, **the
+rotated key one vector a position that every head shares**) passes the
+second part as a pair of its own, ``q_r [b, s, h, e]`` and ``k_r [b, s,
+e]``: the score tile is then ``q k^T + q_r k_r^T``, two products summed in
+float32 before the scale, the mask and the softmax, which are as they
+were, and the backward makes seven products from the one ``p`` and ``ds``
+(``dq_r = ds k_r``, a head's ``dk_r = ds^T q_r``, summed over the heads
+outside). The same MXU passes as one width of ``d + e`` (128 deep is a
+pass, 64 deep is a pass), and nothing ``d + e`` wide is written on either
+side of the call: no key assembled 32 times, no gradient cut apart. The
+pair's refs exist only in a call that passes it; a call without one
+traces the kernel bodies, block specs, scratch shapes and compiler
+parameters it traced before there was such a pair.
+
 No reference-framework counterpart (Horovod ships gradients, not kernels);
 this is part of the TPU framework's compute path. On the CPU the same
 kernel code runs through the Pallas interpreter (the CPU has no Mosaic
@@ -119,8 +134,10 @@ def _sub_block(j, block):
     return pl.ds(pl.multiple_of(j * block, block), block)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                l_ref, *, scale, causal, block_k):
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k):
+    # a rotated pair, where the caller passed one, comes after the three
+    # operands every call has: q_r's block and the shared k_r's tile
+    *rotated, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     block_q = q_ref.shape[2]
     tile = k_ref.shape[2]
     qi = pl.program_id(2)
@@ -135,6 +152,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
 
     def _tile():
         q, rest = _scaled(q_ref[0, 0], scale)         # [block_q, D]
+        if rotated:
+            qr_ref, kr_ref = rotated
+            qr, _ = _scaled(qr_ref[0, 0], scale)
 
         def body(j, carry):
             # the statistics stay [block_q, 1] columns throughout
@@ -142,6 +162,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
             k = k_ref[0, 0, _sub_block(j, block_k), :]
             v = v_ref[0, 0, _sub_block(j, block_k), :]
             sc = dot(q, k, NT)                        # [bq, bk]
+            if rotated:
+                sc = sc + dot(
+                    qr, kr_ref[0, _sub_block(j, block_k), :], NT)
             if rest is not None:
                 sc = sc * rest
             if causal:
@@ -178,9 +201,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l)
 
 
-def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref,
-                *, scale, causal, block_q):
+def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *refs,
+                scale, causal, block_q):
+    # with a rotated pair each group of refs (in, out, scratch) has two
+    # more at its end: k_r's block and q_r's tile, dq_r and a head's dk_r,
+    # their accumulators
+    rotated = len(refs) > 6
+    if rotated:
+        (kr_ref, qr_ref, dq_ref, dk_ref, dv_ref, dqr_ref, dkr_ref,
+         dq_acc_ref, dk_acc_ref, dv_acc_ref, dqr_acc_ref, dkr_acc_ref) = refs
+    else:
+        dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref = refs
     block_k = k_ref.shape[2]
     tile = q_ref.shape[2]
     ki = pl.program_id(2)
@@ -192,6 +223,8 @@ def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
+        if rotated:
+            dkr_acc_ref[...] = jnp.zeros_like(dkr_acc_ref)
 
     @pl.when(ki == 0)
     def _init_dq():
@@ -200,22 +233,32 @@ def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         rows = _sub_block(ti, tile)
         dq_acc_ref[rows, :] = jnp.zeros((tile, dq_acc_ref.shape[1]),
                                         jnp.float32)
+        if rotated:
+            dqr_acc_ref[rows, :] = jnp.zeros((tile, dqr_acc_ref.shape[1]),
+                                             jnp.float32)
 
     def _tile():
         k = k_ref[0, 0]                               # [block_k, D]
         k_sc, rest = _scaled(k, scale)
         k = k.astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
+        if rotated:
+            kr = kr_ref[0]                            # [block_k, e]
+            kr_sc, _ = _scaled(kr, scale)
+            kr = kr.astype(jnp.float32)
         n_sub = tile // block_q
 
         def body(i, carry):
-            dk, dv = carry
+            dk, dv, *dkr = carry
             rows = _sub_block(i, block_q)
             q = q_ref[0, 0, rows, :]
             do = do_ref[0, 0, rows, :].astype(jnp.float32)
             lse = lse_ref[0, 0, rows, :]              # [block_q, 1]
             delta = delta_ref[0, 0, rows, :]
             sc = dot(q, k_sc, NT)                     # [bq, bk]
+            if rotated:
+                qr = qr_ref[0, 0, rows, :]
+                sc = sc + dot(qr, kr_sc, NT)
             if rest is not None:
                 sc = sc * rest
             if causal:
@@ -229,7 +272,12 @@ def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
             dk_new = dk + dot(ds, q.astype(jnp.float32), TN)
             dq_acc_ref[_sub_block(ti * n_sub + i, block_q), :] += dot(
                 ds, k, NN)
-            return dk_new, dv_new
+            if not rotated:
+                return dk_new, dv_new
+            dqr_acc_ref[_sub_block(ti * n_sub + i, block_q), :] += dot(
+                ds, kr, NN)
+            return dk_new, dv_new, dkr[0] + dot(
+                ds, qr.astype(jnp.float32), TN)
 
         if causal:
             # Q sub-blocks strictly before this K block see nothing
@@ -237,10 +285,14 @@ def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                              0, n_sub)
         else:
             start = 0
-        dk, dv = jax.lax.fori_loop(
-            start, n_sub, body, (dk_acc_ref[...], dv_acc_ref[...]))
+        carry = (dk_acc_ref[...], dv_acc_ref[...])
+        if rotated:
+            carry += (dkr_acc_ref[...],)
+        dk, dv, *dkr = jax.lax.fori_loop(start, n_sub, body, carry)
         dk_acc_ref[...] = dk
         dv_acc_ref[...] = dv
+        if rotated:
+            dkr_acc_ref[...], = dkr
 
     if causal:
         # tiles whose every Q position precedes this K block are skipped
@@ -253,12 +305,17 @@ def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         # dS^T Q and dS K carry the scale once, here, not once a sub-block
         dk_ref[0, 0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc_ref[...].astype(dv_ref.dtype)
+        if rotated:
+            dkr_ref[0, 0] = (dkr_acc_ref[...] * scale).astype(dkr_ref.dtype)
 
     @pl.when(ki == n_k - 1)
     def _finalize_dq():
         # the last K block has passed this tile's rows: dQ leaves once
         dq_ref[0, 0] = (dq_acc_ref[_sub_block(ti, tile), :]
                         * scale).astype(dq_ref.dtype)
+        if rotated:
+            dqr_ref[0, 0] = (dqr_acc_ref[_sub_block(ti, tile), :]
+                             * scale).astype(dqr_ref.dtype)
 
 
 def _blocks(s, requested):
@@ -410,25 +467,29 @@ def _score_tile(kernel, s, d, itemsize, causal, block_q, block_k, d_v=None):
     return bq, bk, derived
 
 
-def _count_trace(kernel, block_q, block_k, derived, d, d_v):
+def _count_trace(kernel, block_q, block_k, derived, d, d_v, d_rot):
     """Which score tile each traced kernel got, whether the rule or the
-    caller chose it, and the two widths it was built for (q and k's, v
-    and o's)."""
+    caller chose it, the two widths it was built for (q and k's whole
+    width, v and o's) and how many of q and k's columns came as a rotated
+    pair of their own (0: q and k came whole)."""
     _pallas.count_trace(
         "hvt_flash_kernel_traces_total",
         "flash-attention kernels traced into compiled programs, by "
         "score tile (counted per trace, not per execution)",
         kernel=kernel, block_q=block_q, block_k=block_k,
-        derived=int(derived), d_qk=d, d_v=d_v)
+        derived=int(derived), d_qk=d, d_v=d_v, d_rot=d_rot)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, block_q, block_k, out_dtype):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q, k, v, rotated, scale, causal, block_q, block_k, out_dtype):
     """Differentiable (o, lse). The lse output carries its own gradient:
     d lse/dS = P, so a dlse cotangent folds into the backward kernel as
-    delta := rowsum(do∘o) − dlse — the kernel is unchanged."""
-    o, lse = _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k,
-                             out_dtype)
+    delta := rowsum(do∘o) − dlse — the kernel is unchanged.
+
+    q, k, v are ``[b, h, s, d]``; ``rotated`` is None or the pair
+    ``(q_r [b, h, s, e], k_r [b, s, e])`` (``_rotated_width``)."""
+    o, lse = _flash_fwd_impl(q, k, v, rotated, scale, causal, block_q,
+                             block_k, out_dtype)
     return o, lse
 
 
@@ -457,6 +518,16 @@ def _seq_tile(s, block_q, block_k):
     return best
 
 
+def _rotated_width(rotated):
+    """``e`` of a call's rotated pair ``(q_r [b, h, s, e], k_r [b, s, e])``
+    beside ``q [b, h, s, d]`` and ``k``: one key a position, which every
+    head's grid steps read through an index without a head in it; 0 where
+    the caller passed none. The tile, the VMEM estimate and the limit go
+    by q and k's whole width ``d + e`` (128 + 64 takes the 256 lanes 192
+    takes)."""
+    return 0 if rotated is None else rotated[0].shape[-1]
+
+
 class _Plan(NamedTuple):
     """All a kernel call is built from besides its operands' shapes."""
     scale: float
@@ -468,21 +539,24 @@ class _Plan(NamedTuple):
     interpret: bool
 
 
-def _plan(kernel, q, scale, causal, block_q, block_k, d_v=None):
+def _plan(kernel, q, scale, causal, block_q, block_k, d_v=None, d_rot=0):
     """Made outside the jitted calls below, so that what the process
     holds besides the operands (the backend) is part of their cache's
-    key and never read under a cached trace."""
+    key and never read under a cached trace. ``d_rot``: the width of a
+    rotated pair beside q and k, which the tile goes by as by q's own."""
     _, _, s, d = q.shape
     block_q, block_k, derived = _score_tile(
-        kernel, s, d, q.dtype.itemsize, causal, block_q, block_k, d_v)
+        kernel, s, d + d_rot, q.dtype.itemsize, causal, block_q, block_k,
+        d_v)
     return _Plan(scale, causal, block_q, block_k, derived,
                  _seq_tile(s, block_q, block_k), _pallas.interpret())
 
 
-def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype):
-    return _fwd_call(q, k, v, out_dtype=out_dtype,
+def _flash_fwd_impl(q, k, v, rotated, scale, causal, block_q, block_k,
+                    out_dtype):
+    return _fwd_call(q, k, v, rotated, out_dtype=out_dtype,
                      plan=_plan("fwd", q, scale, causal, block_q, block_k,
-                                v.shape[-1]))
+                                v.shape[-1], _rotated_width(rotated)))
 
 
 # Each of the two calls is a ``jax.jit`` of its own: a model's layers
@@ -491,11 +565,11 @@ def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, out_dtype):
 # traced and lowered one by one were 20 s of every start (PERF.md section
 # 6, PR 27).
 @functools.partial(jax.jit, static_argnames=("plan", "out_dtype"))
-def _fwd_call(q, k, v, *, plan, out_dtype):
+def _fwd_call(q, k, v, rotated=None, *, plan, out_dtype):
     b, h, s, d = q.shape
-    d_v = v.shape[-1]
+    d_v, e = v.shape[-1], _rotated_width(rotated)
     block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
-    _count_trace("fwd", block_q, block_k, plan.derived, d, d_v)
+    _count_trace("fwd", block_q, block_k, plan.derived, d + e, d_v, e)
     # Grouped-query attention is served ZERO-COPY: query head hi reads
     # K/V head hi // group through the block index map — no repeat
     # materialization, and the shared K/V tile stays VMEM-resident
@@ -509,45 +583,50 @@ def _fwd_call(q, k, v, *, plan, out_dtype):
         (1, 1, block_q, width), lambda bi, hi, qi, ti: (bi, hi, qi, 0))
     by_tile = lambda width: pl.BlockSpec(
         (1, 1, tile, width), lambda bi, hi, qi, ti: (bi, hi // group, ti, 0))
+    in_specs = [by_query(d), by_tile(d), by_tile(d_v)]
+    if rotated is not None:
+        # q_r as q; the one k_r a position, whatever the head
+        in_specs += [by_query(e), pl.BlockSpec(
+            (1, tile, e), lambda bi, hi, qi, ti: (bi, ti, 0))]
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=plan.scale,
                           causal=plan.causal, block_k=block_k),
         grid=grid,
-        in_specs=[by_query(d), by_tile(d), by_tile(d_v)],
+        in_specs=in_specs,
         out_specs=[by_query(d_v), by_query(1)],
         out_shape=[_pallas.out((b, h, s, d_v), out_dtype, q, k, v),
                    _pallas.out((b, h, s, 1), jnp.float32, q, k, v)],
         scratch_shapes=[pltpu.VMEM((block_q, d_v), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
-        compiler_params=_compiler_params("fwd", block_q, block_k, d,
+        compiler_params=_compiler_params("fwd", block_q, block_k, d + e,
                                          q.dtype.itemsize, tile, s, d_v),
         interpret=plan.interpret,
         name="hvt_flash_fwd",
-    )(q, k, v)
+    )(q, k, v, *(rotated or ()))
     return o, lse
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, out_dtype):
-    o, lse = _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k,
-                             out_dtype)
-    return (o, lse), (q, k, v, o, lse)
+def _flash_fwd(q, k, v, rotated, scale, causal, block_q, block_k, out_dtype):
+    o, lse = _flash_fwd_impl(q, k, v, rotated, scale, causal, block_q,
+                             block_k, out_dtype)
+    return (o, lse), (q, k, v, rotated, o, lse)
 
 
 def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
     do, dlse = cot
-    q, k, v, o, lse = res
+    q, k, v, rotated, o, lse = res
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)        # [B, H, S, 1]
     # lse cotangent: ds gains + P∘dlse, i.e. delta shifts by −dlse
     delta = delta - dlse.astype(jnp.float32)
-    return _bwd_call(q, k, v, do, lse, delta,
+    return _bwd_call(q, k, v, do, lse, delta, rotated,
                      plan=_plan("bwd", q, scale, causal, block_q, block_k,
-                                v.shape[-1]))
+                                v.shape[-1], _rotated_width(rotated)))
 
 
 @functools.partial(jax.jit, static_argnames="plan")
-def _bwd_call(q, k, v, do, lse, delta, *, plan):
+def _bwd_call(q, k, v, do, lse, delta, rotated=None, *, plan):
     """dq, dk, dv: grid (b, h, ki, ti) — Q/dO/lse/delta tiles stream past
     each K/V block (the reduction axis must be LAST), and every score
     sub-block is made once for all three. dk/dv accumulate a K block;
@@ -557,11 +636,12 @@ def _bwd_call(q, k, v, do, lse, delta, *, plan):
     written back. Under GQA the kernel reads the shared K/V head
     zero-copy via the index map but emits per-QUERY-head dk/dv (full h),
     which are then group-summed — each K/V head's gradient is the sum
-    over its query group."""
+    over its query group. A shared rotated key's gradient goes the same
+    way: the kernel emits a head's, and the heads' sum is the key's."""
     b, h, s, d = q.shape
-    d_v = v.shape[-1]
+    d_v, e = v.shape[-1], _rotated_width(rotated)
     block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
-    _count_trace("bwd", block_q, block_k, plan.derived, d, d_v)
+    _count_trace("bwd", block_q, block_k, plan.derived, d + e, d_v, e)
     group = h // k.shape[1]
     n_k = s // block_k
     # q, k, dq and dk are ``d`` wide, v, do and dv ``d_v``
@@ -572,41 +652,60 @@ def _bwd_call(q, k, v, do, lse, delta, *, plan):
         (1, 1, block_k, width), lambda bi, hi, ki, ti: (bi, hi, ki, 0))
     q_tile = lambda width: pl.BlockSpec(
         (1, 1, tile, width), lambda bi, hi, ki, ti: (bi, hi, ti, 0))
-    dq_tile = pl.BlockSpec(
-        (1, 1, tile, d),
+    dq_tile = lambda width: pl.BlockSpec(
+        (1, 1, tile, width),
         lambda bi, hi, ki, ti: (bi, hi, jnp.where(ki == n_k - 1, ti, 0), 0))
     operands = (q, k, v, do, lse, delta)
-    dq, dk, dv = pl.pallas_call(
+    inputs = (k, v, q, do, lse, delta)
+    in_specs = [kv_in_ki(d), kv_in_ki(d_v), q_tile(d), q_tile(d_v),
+                q_tile(1), q_tile(1)]
+    out_specs = [dq_tile(d), dkv_out_ki(d), dkv_out_ki(d_v)]
+    out_shape = [_pallas.out(q.shape, q.dtype, *operands),
+                 _pallas.out(q.shape, k.dtype, *operands),
+                 _pallas.out((b, h, s, d_v), v.dtype, *operands)]
+    scratch = [(s, d), (block_k, d), (block_k, d_v)]
+    if rotated is not None:
+        # k_r's block whatever the head and q_r's tile in; dq_r and a
+        # head's dk_r out, each beside what it is a part of
+        q_r, k_r = rotated
+        inputs += (k_r, q_r)
+        in_specs += [pl.BlockSpec((1, block_k, e),
+                                  lambda bi, hi, ki, ti: (bi, ki, 0)),
+                     q_tile(e)]
+        out_specs += [dq_tile(e), dkv_out_ki(e)]
+        out_shape += [_pallas.out(q_r.shape, q_r.dtype, *operands),
+                      _pallas.out(q_r.shape, k_r.dtype, *operands)]
+        scratch += [(s, e), (block_k, e)]
+    dq, dk, dv, *d_rotated = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=plan.scale,
                           causal=plan.causal, block_q=block_q),
         grid=(b, h, n_k, s // tile),
-        in_specs=[kv_in_ki(d), kv_in_ki(d_v), q_tile(d), q_tile(d_v),
-                  q_tile(1), q_tile(1)],
-        out_specs=[dq_tile, dkv_out_ki(d), dkv_out_ki(d_v)],
-        out_shape=[_pallas.out(q.shape, q.dtype, *operands),
-                   _pallas.out(q.shape, k.dtype, *operands),
-                   _pallas.out((b, h, s, d_v), v.dtype, *operands)],
-        scratch_shapes=[pltpu.VMEM((s, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d_v), jnp.float32)],
-        compiler_params=_compiler_params("bwd", block_q, block_k, d,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
+        compiler_params=_compiler_params("bwd", block_q, block_k, d + e,
                                          q.dtype.itemsize, tile, s, d_v),
         interpret=plan.interpret,
         name="hvt_flash_bwd",
-    )(k, v, q, do, lse, delta)
+    )(*inputs)
     if group > 1:
         h_kv = h // group
         dk = dk.astype(jnp.float32).reshape(
             b, h_kv, group, s, d).sum(axis=2).astype(k.dtype)
         dv = dv.astype(jnp.float32).reshape(
             b, h_kv, group, s, -1).sum(axis=2).astype(v.dtype)
-    return dq, dk, dv
+    if rotated is None:
+        return dq, dk, dv, None
+    dq_r, dk_r = d_rotated
+    return dq, dk, dv, (dq_r, dk_r.astype(jnp.float32).sum(axis=1).astype(
+        dk_r.dtype))
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, *, causal=True, scale=None,
+def flash_attention(q, k, v, *, q_r=None, k_r=None, causal=True, scale=None,
                     block_q=None, block_k=None):
     """Fused multi-head attention.
 
@@ -616,6 +715,13 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
         heads (grouped queries).
       v: [batch, seq, k's heads, value_dim]: a value width apart from
         the query-key width (latent attention: 192 on 128) or the same.
+      q_r, k_r: None, or a second part of the queries and keys that the
+        caller did not put beside the first (latent attention's rotated
+        pair): ``q_r [batch, seq, heads, e]`` and **one key a position
+        that every head reads, ``k_r [batch, seq, e]``**. The score is
+        ``q k^T + q_r k_r^T`` inside the kernels, the softmax scale's
+        default ``(head_dim + e) ** -0.5``, and ``k_r``'s gradient the
+        sum over the heads (``flash_attention_with_lse``).
       causal: apply causal masking.
       scale: softmax scale, default ``head_dim ** -0.5``.
       block_q / block_k: the score tile; ``None`` (the default) derives
@@ -625,16 +731,26 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
     Returns [batch, seq, heads, value_dim] in q.dtype. Differentiable
     (custom VJP with a recompute-based backward kernel).
     """
-    o, _ = flash_attention_with_lse(q, k, v, causal=causal, scale=scale,
-                                    block_q=block_q, block_k=block_k)
+    o, _ = flash_attention_with_lse(q, k, v, q_r=q_r, k_r=k_r, causal=causal,
+                                    scale=scale, block_q=block_q,
+                                    block_k=block_k)
     return o
 
 
-def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
-                             block_q=None, block_k=None, out_dtype=None):
+def flash_attention_with_lse(q, k, v, *, q_r=None, k_r=None, causal=True,
+                             scale=None, block_q=None, block_k=None,
+                             out_dtype=None):
     """Fused attention returning ``(o, lse)``; both are differentiable.
     ``q`` and ``k`` share one width and ``v`` and ``o`` another, which may
     be the same (``flash_attention``).
+
+    With ``q_r [b, s, h, e]`` and ``k_r [b, s, e]`` the score is ``q k^T +
+    q_r k_r^T``, two products summed in float32 a score tile, so a caller
+    whose queries and keys come in two parts (latent attention: 128
+    columns a head beside 64 rotated ones, the rotated key one a position)
+    never writes them side by side. The tile, the streamed tile and the
+    VMEM limit are those of one width of ``d + e``; ``k`` has q's heads
+    then (grouped queries with a rotated pair are not built).
 
     ``lse[b, s, h]`` is the log-sum-exp of the (scaled, masked) scores for
     each query — exactly what blockwise/ring composition needs to combine
@@ -656,9 +772,25 @@ def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
         raise ValueError(
             f"q {q.shape} and k {k.shape} share the query-key width, and "
             f"v {v.shape} has k's positions and heads")
+    e = 0
+    if q_r is not None or k_r is not None:
+        if q_r is None or k_r is None:
+            raise ValueError(
+                "a rotated pair is q_r and k_r together: got "
+                f"{'k_r' if q_r is None else 'q_r'} alone")
+        e = q_r.shape[-1]
+        if q_r.shape != (b, s, h, e) or k_r.shape != (b, s, e):
+            raise ValueError(
+                f"q_r {q_r.shape} has q's {(b, s, h)} and a width of its "
+                f"own, and k_r {k_r.shape} that width at every position, "
+                f"without a head axis")
+        if h_kv != h:
+            raise ValueError(
+                f"a rotated pair goes with as many key heads as query "
+                f"heads; got {h} on {h_kv}")
     if scale is None:
-        scale = d ** -0.5
-    bq, bk, _ = _score_tile("fwd", s, d, q.dtype.itemsize, causal,
+        scale = (d + e) ** -0.5
+    bq, bk, _ = _score_tile("fwd", s, d + e, q.dtype.itemsize, causal,
                              block_q, block_k, v.shape[-1])
     if not _pallas.interpret() and (bq % 8 or bk % 8):
         # Mosaic refuses the kernel ("cannot statically prove that index
@@ -672,6 +804,7 @@ def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
     # Kernels are gridded (batch, head, block): BHSD layout.
     to_bhsd = lambda x: jnp.transpose(x, (0, 2, 1, 3))
     o, lse = _flash(to_bhsd(q), to_bhsd(k), to_bhsd(v),
+                    None if q_r is None else (to_bhsd(q_r), k_r),
                     float(scale), bool(causal), block_q, block_k,
                     jnp.dtype(out_dtype or q.dtype))
     # lse: [B, H, S, 1] → [B, S, H]

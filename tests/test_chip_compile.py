@@ -80,7 +80,9 @@ def _qkv(shape, sharding):
 # olmoe-s4096's and nemotron3s-s8192's own (four query heads on one
 # key-value head at 8192 positions) and qwen3next-s8192's (a head of 256,
 # sixteen query heads on two key-value heads) and kanana2-s8192's (latent
-# attention: 32 heads, queries and keys of 192 on values of 128), gpt2l-dp4's (four chips' batch under a shard_map
+# attention: 32 heads, queries and keys of 192 on values of 128, whole and
+# as the mixer hands them over since PR 49: 128 a head beside a rotated
+# pair of 64 whose key has no head axis), gpt2l-dp4's (four chips' batch under a shard_map
 # that checks vma), gpt2-large's heads at 4 x 2048 and at 1 x 8192 (two
 # streamed tiles: the backward's dQ accumulator is addressed by a dynamic
 # slice and leaves a tile at a time), one grouped-query shape, the 4-chip
@@ -104,6 +106,8 @@ def _qkv(shape, sharding):
     pytest.param("flash", (2, 8192, 16, 2, 256), id="flash-qwen3next-s8192"),
     pytest.param("flash", (2, 8192, 32, 32, 192, 128),
                  id="flash-kanana2-s8192"),
+    pytest.param("rotated", (2, 8192, 32, 32, 192, 128),
+                 id="flash-kanana2-s8192-rotated-pair"),
     pytest.param("ring", (1, 16384, 12, 12, 64), id="ring-sp4-16384"),
     pytest.param("refused", (1, 100, 2, 2, 64), id="block-not-multiple-of-8"),
 ])
@@ -117,7 +121,18 @@ def test_main_path_kernel_compiles_for_v5e(kind, shape, compiled_kernel,
                 *_qkv(shape, None))
         return
     devices = request.getfixturevalue("v5e_devices")
-    if kind == "flash":
+    if kind == "rotated":
+        one = SingleDeviceSharding(devices[0])
+        (b, seq, h, _, d, _), e = shape, 64
+        q_n, k_n, v = _qkv((*shape[:4], d - e, shape[5]), one)
+        q_r = jax.ShapeDtypeStruct((b, seq, h, e), jnp.bfloat16, sharding=one)
+        k_r = jax.ShapeDtypeStruct((b, seq, e), jnp.bfloat16, sharding=one)
+        step = jax.jit(jax.value_and_grad(
+            lambda q_n, q_r, k_n, k_r, v: (fa.flash_attention(
+                q_n, k_n, v, q_r=q_r, k_r=k_r, causal=True).astype(
+                    jnp.float32) ** 2).mean(), argnums=(0, 1, 2, 3, 4)))
+        args = (q_n, q_r, k_n, k_r, v)
+    elif kind == "flash":
         step = _loss(lambda q, k, v: fa.flash_attention(q, k, v,
                                                         causal=True))
         args = _qkv(shape, SingleDeviceSharding(devices[0]))
@@ -184,7 +199,10 @@ def test_latent_attention_layers_share_the_kernels_under_their_scope(
     ``op_name`` has the scope ``mla_core`` in it (what
     ``chipbench/layer_metrics/mla_core_roofline.py`` chooses the kernels
     by) and the pass it belongs to (what ``chipbench/regions.py`` splits
-    the step by)."""
+    the step by). Since PR 49 the kernels take the query and the key in
+    their two parts: nothing 192 wide is under ``mla_core`` in either
+    pass, and no activation 192 wide exists at all (no key assembled, no
+    query concatenated, no gradient padded back or cut apart)."""
     from horovod_tpu.models import GPT, GPTConfig
 
     one_chip = SingleDeviceSharding(v5e_devices[0])
@@ -212,7 +230,16 @@ def test_latent_attention_layers_share_the_kernels_under_their_scope(
     assert set(re.findall(r"hvt_flash_\w+", three.as_text())) == {
         "hvt_flash_fwd", "hvt_flash_bwd"}
     text = three.compile().as_text()
-    assert "bf16[2,2,1024,192]" in text and "bf16[2,2,1024,128]" in text
+    # q_n, k_n, v and o a head; q_r a head; the one k_r a position
+    assert all(shape in text for shape in (
+        "bf16[2,2,1024,128]", "bf16[2,2,1024,64]", "bf16[2,1024,64]"))
+    assert not re.search(r"\[2,(1024,2|2,1024),192\]", text)
+    core = [line for line in text.splitlines() if "/mla_core/" in line]
+    assert core and not [line for line in core if ",192]" in line]
+    for opcode in ("concatenate", "pad", "broadcast"):
+        assert not [line for line in core
+                    if re.search(rf" {opcode}\(", line)
+                    and re.search(r"bf16\[2,\d+,\d+,\d+\]", line)], opcode
     # inlined, each call keeps its call site's whole op_name
     names = re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*?op_name="([^"]*)"', text)

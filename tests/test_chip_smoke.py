@@ -175,14 +175,19 @@ def test_norm_kernels_beside_the_plain_bodies():
                 assert got["ms_forward_and_backward"] > 0
 
 
-@pytest.mark.parametrize("shape", [(1, 64, 4, 2, 16), (1, 64, 2, 2, 24, 16)],
-                         ids=["grouped-queries", "24-on-16"])
-def test_flash_kernel_against_the_float32_formula(shape):
-    # grouped-query heads, and a value width apart from the key width (the
-    # mla8192 phase's), interpreter: the comparison itself at a size the
-    # CPU can afford; errors are a few bf16 eps
-    out = chip_smoke.flash_kernel_vs_f32(shape)
-    assert set(out["rel_l2"]) == {"o", "dq", "dk", "dv"}
+@pytest.mark.parametrize("shape, rotated", [
+    ((1, 64, 4, 2, 16), 0), ((1, 64, 2, 2, 24, 16), 0),
+    ((1, 64, 3, 3, 24, 16), 8)],
+    ids=["grouped-queries", "24-on-16", "16-and-8-on-16"])
+def test_flash_kernel_against_the_float32_formula(shape, rotated):
+    # grouped-query heads, a value width apart from the key width and the
+    # key's last columns as one rotated key a position (the mla8192
+    # phase's two entries), interpreter: the comparison itself at a size
+    # the CPU can afford; errors are a few bf16 eps
+    out = chip_smoke.flash_kernel_vs_f32(shape, rotated)
+    assert set(out["rel_l2"]) == (
+        {"o", "dq_n", "dq_r", "dk_n", "dk_r", "dv"} if rotated
+        else {"o", "dq", "dk", "dv"})
     assert max(out["rel_l2"].values()) < chip_smoke.BF16_REL_L2
 
 

@@ -509,6 +509,7 @@ def test_derived_tile_of_a_sequence_that_is_no_multiple_of_128(s, want):
 def _trace_count(**labels):
     from horovod_tpu import metrics
 
+    labels.setdefault("d_rot", "0")     # q and k came whole
     m = metrics.registry().get("hvt_flash_kernel_traces_total")
     return m.labels(**labels).value if m else 0.0
 
@@ -733,3 +734,168 @@ def test_kernels_trace_under_shard_map_with_check_vma():
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
+
+
+# ---- a rotated pair beside q and k: the score q k^T + q_r k_r^T
+
+def _parts(b, s, h, n, e, d_v, dtype, seed=0):
+    """``q_n, q_r, k_n, k_r, v`` and weights for o and lse: ``k_r`` has no
+    head axis."""
+    keys = jax.random.split(jax.random.key(seed), 7)
+    shapes = ((b, s, h, n), (b, s, h, e), (b, s, h, n), (b, s, e),
+              (b, s, h, d_v), (b, s, h, d_v), (b, s, h))
+    *operands, w_o, w_lse = (jax.random.normal(key, shape).astype(dtype)
+                             for key, shape in zip(keys, shapes))
+    return operands, w_o.astype(jnp.float32), w_lse.astype(jnp.float32)
+
+
+def _whole(q_n, q_r, k_n, k_r, v):
+    """The operands of the one-width entry: ``q_n | q_r`` and ``k_n | k_r``
+    with the one rotated key a position broadcast over the heads."""
+    k_r = jnp.broadcast_to(k_r[:, :, None, :], k_n.shape[:-1] + k_r.shape[-1:])
+    return (jnp.concatenate([q_n, q_r], -1),
+            jnp.concatenate([k_n, k_r], -1), v)
+
+
+def _formula_o_lse(q, k, v, causal, scale):
+    """softmax(q k^T scale) v and the scores' log-sum-exp, float32."""
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    if causal:
+        pos = jnp.arange(q.shape[1])
+        scores = jnp.where(pos[None, :] <= pos[:, None], scores, -jnp.inf)
+    lse = jax.scipy.special.logsumexp(scores, axis=-1)
+    o = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(scores - lse[..., None]), v)
+    return o, lse.transpose(0, 2, 1)
+
+
+@pytest.mark.parametrize("s, blocks, seq_tile, causal, dtype", [
+    pytest.param(256, (128, 64), None, True, jnp.float32, id="causal"),
+    pytest.param(256, (64, 128), None, False, jnp.float32, id="not-causal"),
+    pytest.param(256, (None, None), None, True, jnp.bfloat16,
+                 id="bf16-derived"),
+    pytest.param(256, (128, 128), None, False, jnp.bfloat16,
+                 id="bf16-not-causal"),
+    pytest.param(768, (None, None), 256, True, jnp.float32,
+                 id="three-tiles-derived"),
+    pytest.param(768, (128, 256), 256, True, jnp.float32,
+                 id="three-tiles-mixed-blocks"),
+])
+def test_rotated_pair_matches_the_formula_and_the_one_width_entry(
+        s, blocks, seq_tile, causal, dtype, monkeypatch):
+    """``flash_attention_with_lse(q_n, k_n, v, q_r=.., k_r=..)``: o, lse
+    and the five gradients under cotangents for both outputs, against the
+    float32 formula on the assembled operands and against the one-width
+    entry on them; ``k_r``'s gradient is the sum over the heads, which
+    the broadcast's transpose makes on the other two paths. 16 and 8 on
+    24, three heads, a scale that is no power of two (24^-1/2)."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    if seq_tile:
+        monkeypatch.setattr(fa, "_SEQ_TILE", seq_tile)
+    operands, w_o, w_lse = _parts(2, s, 3, 16, 8, 24, dtype)
+    scale = 24 ** -0.5
+    tile = dict(zip(("block_q", "block_k"), blocks))
+
+    def run(attend):
+        def weighed(*operands):
+            o, lse = attend(*operands)
+            return (jnp.sum(o.astype(jnp.float32) * w_o)
+                    + jnp.sum(lse * w_lse)), (o, lse)
+
+        return jax.jit(jax.value_and_grad(
+            weighed, argnums=(0, 1, 2, 3, 4), has_aux=True))(*operands)
+
+    with jax.default_matmul_precision("highest"):
+        got = run(lambda q_n, q_r, k_n, k_r, v: fa.flash_attention_with_lse(
+            q_n, k_n, v, q_r=q_r, k_r=k_r, causal=causal, **tile))
+        one_width = run(lambda *parts: fa.flash_attention_with_lse(
+            *_whole(*parts), causal=causal, **tile))
+        formula = run(lambda *parts: _formula_o_lse(*_whole(*parts), causal,
+                                                    scale))
+    (_, (o, lse)), grads = got
+    assert o.shape == (2, s, 3, 24) and o.dtype == dtype
+    assert lse.shape == (2, s, 3) and lse.dtype == jnp.float32
+    assert [g.shape for g in grads] == [t.shape for t in operands]
+    assert grads[3].shape == (2, s, 8)          # dk_r: no head axis
+    # bf16: each path rounds o and the gradients once; float32: the sums'
+    # order alone
+    tol = 3e-2 if dtype == jnp.bfloat16 else 2e-4
+    for want in (one_width, formula):
+        for a, w in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                        strict=True):
+            a, w = (np.asarray(t, np.float32) for t in (a, w))
+            np.testing.assert_allclose(a, w, rtol=tol,
+                                       atol=tol * np.abs(w).max())
+
+
+def test_shared_rotated_keys_gradient_is_the_sum_over_the_heads():
+    """With the heads' unrotated parts zero and one head's values alone
+    read by the loss, every head still sends its share to the one
+    ``k_r``: its gradient is the sum of what the one-width entry gives
+    each head's copy."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    (q_n, q_r, k_n, k_r, v), w_o, _ = _parts(1, 128, 4, 16, 8, 16,
+                                             jnp.float32, seed=3)
+    loss = lambda attend: lambda *t: jnp.sum(attend(*t) * w_o)
+    with jax.default_matmul_precision("highest"):
+        dk_r = jax.grad(loss(lambda q_n, q_r, k_n, k_r, v: fa.flash_attention(
+            q_n, k_n, v, q_r=q_r, k_r=k_r)), argnums=3)(q_n, q_r, k_n, k_r, v)
+        q, k, _ = _whole(q_n, q_r, k_n, k_r, v)
+        dk = jax.grad(loss(fa.flash_attention), argnums=1)(q, k, v)
+    assert dk_r.shape == k_r.shape
+    a_head = np.asarray(dk[..., 16:])
+    assert np.abs(a_head[:, :, 0] - a_head[:, :, 1]).max() > 1e-2
+    np.testing.assert_allclose(np.asarray(dk_r), a_head.sum(axis=2),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_rotated_pair_refusals():
+    """A ``q_r`` without a ``k_r`` and the other way, widths and shapes
+    that do not pair, a ``k_r`` with a head axis, and grouped queries
+    (not built: the rotated key is already shared by every head)."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    z = lambda *shape: jnp.zeros(shape, jnp.float32)
+    q, v, q_r, k_r = z(1, 128, 4, 16), z(1, 128, 4, 8), z(1, 128, 4, 8), \
+        z(1, 128, 8)
+    with pytest.raises(ValueError, match="q_r alone"):
+        fa.flash_attention(q, q, v, q_r=q_r)
+    with pytest.raises(ValueError, match="k_r alone"):
+        fa.flash_attention_with_lse(q, q, v, k_r=k_r)
+    for bad_q_r, bad_k_r in ((q_r, z(1, 128, 4)),         # another width
+                             (q_r, z(1, 128, 4, 8)),      # a key a head
+                             (z(1, 128, 2, 8), k_r),      # not q's heads
+                             (q_r, z(1, 64, 8))):         # not q's length
+        with pytest.raises(ValueError, match="without a head axis"):
+            fa.flash_attention(q, q, v, q_r=bad_q_r, k_r=bad_k_r)
+    with pytest.raises(ValueError, match="as many key heads"):
+        fa.flash_attention(q, z(1, 128, 2, 16), z(1, 128, 2, 8), q_r=q_r,
+                           k_r=k_r)
+    # and the pair changes nothing about what q, k and v must be
+    with pytest.raises(ValueError, match="query-key width"):
+        fa.flash_attention(q, z(1, 128, 4, 24), v, q_r=q_r, k_r=k_r)
+
+
+def test_trace_counter_says_how_wide_a_rotated_pair_was():
+    """``d_rot`` is 0 for a call whose q and k came whole and the pair's
+    width for one that passed it, with ``d_qk`` the whole query-key width
+    either way."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    (q_n, q_r, k_n, k_r, v), _, _ = _parts(1, 128, 2, 16, 8, 16, jnp.float32)
+    labels = dict(block_q="128", block_k="128", derived="1", d_v=16)
+    count = lambda d_qk, d_rot: [
+        _trace_count(kernel=kernel, d_qk=d_qk, d_rot=d_rot, **labels)
+        for kernel in ("fwd", "bwd")]
+    jax.clear_caches()
+    before = count(24, 8), count(24, 0), count(16, 0)
+    jax.eval_shape(jax.grad(lambda *t: fa.flash_attention(
+        t[0], t[2], t[4], q_r=t[1], k_r=t[3]).sum()), q_n, q_r, k_n, k_r, v)
+    assert (count(24, 8), count(24, 0), count(16, 0)) == (
+        [n + 1 for n in before[0]], before[1], before[2])
+    jax.eval_shape(jax.grad(lambda q, k, v: fa.flash_attention(
+        q, k, v).sum()), *_whole(q_n, q_r, k_n, k_r, v))
+    assert (count(24, 8), count(24, 0), count(16, 0)) == (
+        [n + 1 for n in before[0]], [n + 1 for n in before[1]], before[2])

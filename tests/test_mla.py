@@ -63,6 +63,97 @@ def test_mixer_matches_the_reference(seq, use_flash, dtype, monkeypatch):
     assert err <= (3e-2 if dtype == jnp.bfloat16 else 2e-5), err
 
 
+@pytest.mark.parametrize("seq, dtype", [(128, jnp.float32),
+                                        (256, jnp.float32),
+                                        (128, jnp.bfloat16)])
+def test_mixer_on_the_kernels_equals_its_einsum_path(seq, dtype):
+    """``use_flash=True`` hands the kernels the four parts (interpreted
+    here) and ``False`` assembles the query and the key whole for einsums:
+    the output and the gradients of every parameter and of the input
+    agree, the shared rotated key's among them (``kv_down``'s last
+    columns), to float32's sums or to bf16's rounding."""
+    x32 = None
+    results = []
+    for use_flash in (True, False):
+        layer, params, x, positions = _mixer(seq, dtype, use_flash)
+        x32 = x
+
+        def loss(params, x):
+            out = layer.apply({"params": params}, x, positions)
+            return jnp.sum(out.astype(jnp.float32) * jnp.cos(
+                jnp.arange(out.size, dtype=jnp.float32).reshape(out.shape)))
+
+        with jax.default_matmul_precision("highest"):
+            results.append(jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(
+                params, x))
+    tol = 4e-2 if dtype == jnp.bfloat16 else 2e-4
+    (got, g_got), (want, g_want) = results
+    assert abs(float(got) - float(want)) <= tol * max(abs(float(want)), 1.0)
+    for a, w in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want),
+                    strict=True):
+        assert a.shape == w.shape and a.dtype == w.dtype
+        err = float(jnp.linalg.norm(a - w) / jnp.linalg.norm(w))
+        assert err <= tol, err
+    assert x32.shape == (2, seq, 32)
+
+
+def test_the_kernels_get_the_parts_and_never_a_whole_key(monkeypatch):
+    """On the kernels' path neither ``whole_key`` nor a concatenation of
+    the query runs: the entry is called with ``q_n``, ``k_n`` and ``v`` a
+    head and the pair ``q_r [b, s, H, e]``, ``k_r [b, s, e]``; on the
+    einsum path the key is assembled whole, once."""
+    calls = []
+    real = mla.flash.flash_attention
+    monkeypatch.setattr(mla.flash, "flash_attention", lambda *a, **kw: (
+        calls.append(([t.shape for t in a],
+                      {k: v.shape if getattr(v, "ndim", 0) else v
+                       for k, v in kw.items()})),
+        real(*a, **kw))[1])
+    keys = []
+    whole = mla.whole_key
+    monkeypatch.setattr(mla, "whole_key", lambda k_n, k_r: (
+        keys.append(k_n.shape), whole(k_n, k_r))[1])
+    layer, params, x, positions = _mixer(128, use_flash=True)
+    calls.clear()                   # the initialisation's own trace
+    jax.eval_shape(layer.apply, {"params": params}, x, positions)
+    assert keys == [] and calls == [(
+        [(2, 128, 4, 8), (2, 128, 4, 8), (2, 128, 4, 8)],
+        {"q_r": (2, 128, 4, 4), "k_r": (2, 128, 4), "causal": True,
+         "scale": mla.score_scale(8, 4)})]
+    layer, params, x, positions = _mixer(128, use_flash=False)
+    keys.clear()
+    jax.eval_shape(layer.apply, {"params": params}, x, positions)
+    assert keys == [(2, 128, 4, 8)] and len(calls) == 1
+
+
+def test_parameters_are_the_published_ones_whatever_the_path():
+    """Names, shapes, dtypes, initialisation and ``mla_leaf_spec`` of the
+    mixer's parameters: ``q_proj [d, H, n + e]`` and ``kv_up [r, H, n +
+    v]`` whole, a head's columns in the published order, so a checkpoint
+    written before the kernels took the parts loads as it is."""
+    trees = []
+    for use_flash in (True, False):
+        layer = mla.LatentAttention(4, 16, 8, 4, 6, rotary_base=1e6,
+                                    use_flash=use_flash, dtype=jnp.bfloat16)
+        x = jnp.zeros((2, 128, 32), jnp.float32)
+        positions = jnp.broadcast_to(jnp.arange(128), (2, 128))
+        trees.append(jax.jit(layer.init)(jax.random.key(0), x,
+                                         positions)["params"])
+    assert {name: (w.shape, w.dtype) for name, w in trees[0].items()} == {
+        "q_proj": ((32, 4, 12), jnp.float32),
+        "kv_down": ((32, 20), jnp.float32),
+        "kv_norm": ((16,), jnp.float32),
+        "kv_up": ((16, 4, 14), jnp.float32),
+        "o_proj": ((4, 6, 32), jnp.float32)}
+    for a, b in zip(jax.tree.leaves(trees[0]), jax.tree.leaves(trees[1]),
+                    strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    from jax.sharding import PartitionSpec as P
+    assert [mla.mla_leaf_spec(name, "tp") for name in sorted(trees[0])] == [
+        P(), P(), P(None, "tp", None), P("tp", None, None),
+        P(None, "tp", None)]
+
+
 def test_rotary_of_halves_is_the_published_pairs_under_the_permutation():
     """``pairs_to_halves`` then half against half is the published rotary
     over interleaved pairs, regrouped: the columns before the rotated
@@ -224,6 +315,34 @@ def test_the_cells_two_widths_get_a_narrower_estimate_than_256():
                                128) is None
     assert fa._compiler_params("bwd", 1024, 512, 192, 2, 4096, 8192,
                                128).vmem_limit_bytes == 39321600
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_a_rotated_pair_gets_the_tile_and_the_limit_of_the_whole_width(
+        causal, monkeypatch):
+    """128 + 64 on 128 at 8192 positions, traced through both entries:
+    each kernel's score tile, streamed tile and VMEM limit are those of
+    192 on 128, argument for argument (128 + 64 takes the 256 lanes 192
+    takes)."""
+    asked = []
+    real = fa._compiler_params
+    monkeypatch.setattr(fa, "_compiler_params", lambda *a: (
+        asked.append((a, getattr(real(*a), "vmem_limit_bytes", None))),
+        real(*a))[1])
+    like = lambda *dims: jax.ShapeDtypeStruct((1, 8192, *dims), jnp.bfloat16)
+    jax.clear_caches()
+    jax.eval_shape(jax.grad(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=causal).astype(jnp.float32).sum(), (0, 1, 2)),
+        like(2, 192), like(2, 192), like(2, 128))
+    whole, asked[:] = list(asked), []
+    jax.eval_shape(jax.grad(lambda q_n, q_r, k_n, k_r, v: fa.flash_attention(
+        q_n, k_n, v, q_r=q_r, k_r=k_r, causal=causal).astype(
+            jnp.float32).sum(), (0, 1, 2, 3, 4)),
+        like(2, 128), like(2, 64), like(2, 128), like(64), like(2, 128))
+    assert asked == whole and [a[0][0] for a in asked] == ["fwd", "bwd"]
+    if causal:
+        assert [(a[1:3], a[5], limit) for a, limit in asked] == [
+            ((512, 1024), 4096, None), ((1024, 512), 4096, 39321600)]
 
 
 def test_mixer_sows_its_input_and_output():
