@@ -35,7 +35,13 @@ attention did until PR 49, and ``"entry": "parts"`` hands the kernels the
 four parts (``flash_attention(q_n, k_n, v, q_r=.., k_r=..)``); both give
 ``(o, dq_n, dq_r, dk_n, dk_r, dv)``, so ``rel_l2_vs_first`` compares the
 second with the first. A ``--source`` without the two-part entry runs
-``whole`` alone.
+``whole`` alone. ``--against FILE`` (since PR 50) times FILE's kernels
+first, in the same process, and ``rel_l2_vs_first`` of every line is then
+against FILE's first tile: ``--against _parent/horovod_tpu/ops/
+flash_attention.py`` puts the parent beside the change, and 0.0 says the
+change gives ``o`` and every gradient to the last bit. ``--shape`` takes
+several shapes, separated by ``+``, and ``/E`` after one is its own
+``--rotated``.
 
 A microbenchmark, not the yardstick: the cell that decides is
 ``gpt2l-s4096`` of ``BENCHMARK.json``. It refuses to run without a TPU.
@@ -145,9 +151,10 @@ def kernel_durations(trace_dir):
 
 
 def measure(flash, shape, tiles, causal=True, iters=5, einsum=False,
-            rotated=0):
+            rotated=0, first=None):
     """One dict a tile (with a rotated width two, one an entry; and one
-    for the einsum path), as the module docstring describes."""
+    for the einsum path), as the module docstring describes, and what
+    they were compared with: ``first``, or the first tile's results."""
     import inspect
 
     import jax
@@ -174,7 +181,7 @@ def measure(flash, shape, tiles, causal=True, iters=5, einsum=False,
         plain = lambda q, k, v: einsum_attention(q, k, v, causal)
         runs.append(("einsum", None, call_and_vjp(
             whole_width(plain) if rotated else plain)))
-    first, lines = None, []
+    lines = []
     for tile, entry, fn in runs:              # compile, warm up, compare
         line = {"tile": tile, "shape": list(shape), "causal": causal}
         if entry:
@@ -216,7 +223,7 @@ def measure(flash, shape, tiles, causal=True, iters=5, einsum=False,
         for i, line in enumerate(timed):
             line[name.removeprefix("hvt_flash_") + "_ms"] = (
                 statistics.median(ms[i * iters:(i + 1) * iters]))
-    return lines
+    return lines, first
 
 
 def parse_tile(text):
@@ -232,6 +239,7 @@ def main(argv=None):
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--einsum", action="store_true")
     ap.add_argument("--source")
+    ap.add_argument("--against")
     ap.add_argument("--rotated", type=int, default=0, metavar="E")
     a = ap.parse_args(argv)
     sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -241,13 +249,23 @@ def main(argv=None):
     if jax.default_backend() != "tpu":
         raise SystemExit("flash_kernels.py times kernels on a TPU; found "
                          f"{jax.default_backend()}")
-    shape = tuple(int(x) for x in a.shape.split(","))
     tiles = [parse_tile(t) for t in a.blocks.split(",")]
-    for line in measure(load_flash(a.source), shape, tiles,
-                        causal=not a.no_causal, iters=a.iters,
-                        einsum=a.einsum, rotated=a.rotated):
-        line["device"] = jax.devices()[0].device_kind
-        print(json.dumps(line), flush=True)
+    sources = ([a.against] if a.against else []) + [a.source]
+    for shape in a.shape.split("+"):
+        shape, _, own = shape.partition("/")
+        rotated = int(own) if own else a.rotated
+        first = None
+        for source in sources:
+            flash = load_flash(source)
+            lines, first = measure(
+                flash, tuple(int(x) for x in shape.split(",")), tiles,
+                causal=not a.no_causal, iters=a.iters, einsum=a.einsum,
+                rotated=rotated, first=first)
+            for line in lines:
+                line["source"] = os.path.relpath(flash.__file__)
+                line["device"] = jax.devices()[0].device_kind
+                print(json.dumps(line), flush=True)
+            jax.clear_caches()      # the next source's kernels are traced
 
 
 if __name__ == "__main__":

@@ -9,6 +9,10 @@ out for the MXU (fp32 accumulation and statistics, operands as they
 arrive). The score tile is derived from the shape (``_derive_tile``:
 hundreds of rows by hundreds of columns, so that one pass of the inner
 loop is long enough to hide its own overhead) unless the caller names one.
+A pass of the forward's loop takes its sub-block as two halves by query
+rows (``_chains``), both score products before either softmax, so that the
+MXU and the vector unit work at once; a row depends on no other row, so
+the result is the one-chain pass's to the last bit.
 
 Backward pass recomputes score tiles (FLOPs-for-HBM trade, the same choice
 ``jax.checkpoint`` makes) from the saved logsumexp in one kernel gridded
@@ -54,6 +58,8 @@ from horovod_tpu.ops._pallas import NN, NT, TN, dot
 _NEG_INF = -1e30
 _LANES = 128  # TPU vector lane width: scratch statistics are stored
               # broadcast across a full lane tile
+_SUBLANES = 8   # rows of a float32 vector tile; a narrower dtype packs
+                # 4 // itemsize times as many
 
 # The shortest sequence at which the kernels were measured to beat the
 # einsum path inside a training step: gpt2-large's 8192 tokens a step on
@@ -102,7 +108,22 @@ def resolve_flash(use_flash, local_seq) -> bool:
 # allows (``_derive_tile``), not one MXU pass. Every sub-block of a causal
 # call is masked, the ones wholly below the diagonal too: a second,
 # unmasked loop for those measured 3 to 5% slower than the mask it saves.
-# Online-softmax statistics live in VMEM scratch across the tile axis.
+#
+# What a pass of the forward's loop does (v5e, PERF.md section 6, PR 50).
+# The compiler schedules a pass as one basic block, close to program
+# order, and a 512 x 1024 float32 score is 512 vector registers of 64: every
+# stage of score product -> mask -> row maximum -> exp -> row sum -> value
+# product goes through VMEM and waits for the stage before. So (1) the
+# sub-block goes as ``chains`` pieces by query rows, each with its own rows
+# of m, l and acc, and every piece's score product is issued before any
+# piece's softmax: the MXU makes the second piece's scores while the vector
+# unit is in the first piece's softmax, and the first piece's value product
+# while it is in the second's. And (2) the loop carries nothing: acc, m and
+# l (192 registers' worth) stay in their VMEM scratch across passes and
+# tiles and a pass reads and writes each piece's rows once, where carried
+# values were copied at the top and the bottom of every pass with no
+# product in flight. m and l hold a row's value in every lane, so the
+# maximum, the correction and the sum are whole-register operations.
 
 def _scaled(x, scale):
     """``(x', rest)`` with ``x' @ y * rest == x @ y * scale``: a power of
@@ -134,11 +155,12 @@ def _sub_block(j, block):
     return pl.ds(pl.multiple_of(j * block, block), block)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k):
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains):
     # a rotated pair, where the caller passed one, comes after the three
     # operands every call has: q_r's block and the shared k_r's tile
     *rotated, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     block_q = q_ref.shape[2]
+    rows = block_q // chains
     tile = k_ref.shape[2]
     qi = pl.program_id(2)
     ti = pl.program_id(3)
@@ -157,35 +179,52 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k):
             qr, _ = _scaled(qr_ref[0, 0], scale)
 
         def body(j, carry):
-            # the statistics stay [block_q, 1] columns throughout
-            acc, m, l = carry
             k = k_ref[0, 0, _sub_block(j, block_k), :]
             v = v_ref[0, 0, _sub_block(j, block_k), :]
-            sc = dot(q, k, NT)                        # [bq, bk]
             if rotated:
-                sc = sc + dot(
-                    qr, kr_ref[0, _sub_block(j, block_k), :], NT)
-            if rest is not None:
-                sc = sc * rest
-            if causal:
-                sc = jnp.where(
-                    _visible(qi * block_q, ti * tile + j * block_k,
-                             sc.shape), sc, _NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
-            p = jnp.exp(sc - m_new)
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-            acc_new = acc * corr + dot(p.astype(v.dtype), v, NN)
-            return acc_new, m_new, l_new
+                kr = kr_ref[0, _sub_block(j, block_k), :]
+
+            def score(c):
+                mine = slice(c * rows, (c + 1) * rows)
+                sc = dot(q[mine], k, NT)              # [rows, bk]
+                if rotated:
+                    sc = sc + dot(qr[mine], kr, NT)
+                if rest is not None:
+                    sc = sc * rest
+                if causal:
+                    sc = jnp.where(
+                        _visible(qi * block_q + c * rows,
+                                 ti * tile + j * block_k, sc.shape),
+                        sc, _NEG_INF)
+                return sc
+
+            def softmax_and_values(c, sc):
+                # the statistics are read and written where they live,
+                # every lane of a row holding the row's
+                mine = pl.ds(c * rows, rows)
+                m, l = m_ref[mine, :], l_ref[mine, :]
+                m_new = jnp.maximum(m, jnp.broadcast_to(
+                    jnp.max(sc, axis=-1, keepdims=True), m.shape))
+                p = jnp.exp(sc - m_new[:, :1])
+                corr = jnp.exp(m - m_new)
+                m_ref[mine, :] = m_new
+                l_ref[mine, :] = l * corr + jnp.broadcast_to(
+                    jnp.sum(p, axis=-1, keepdims=True), l.shape)
+                acc_ref[mine, :] = acc_ref[mine, :] * corr[:, :1] + dot(
+                    p.astype(v.dtype), v, NN)
+
+            # every chain's score before any chain's softmax: the second
+            # product is what the MXU does while the vector unit is in the
+            # first softmax
+            scores = [score(c) for c in range(chains)]
+            for c, sc in enumerate(scores):
+                softmax_and_values(c, sc)
+            return carry
 
         n_sub = tile // block_k
         n_eff = (_causal_n_eff(qi, block_q, ti, tile, block_k, n_sub)
                  if causal else n_sub)
-        acc, m, l = jax.lax.fori_loop(
-            0, n_eff, body, (acc_ref[...], m_ref[:, :1], l_ref[:, :1]))
-        acc_ref[...] = acc
-        m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l, l_ref.shape)
+        jax.lax.fori_loop(0, n_eff, body, 0)
 
     if causal:
         # tiles entirely above the diagonal still stream past (the
@@ -351,8 +390,10 @@ _VMEM_BUDGET = {"fwd": _SCOPED_VMEM, "bwd": _VMEM - _XLA_VMEM}
 
 def _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile, s, d_v=None):
     """Estimate of the VMEM one grid step of ``kernel`` holds: the f32
-    [block_q, block_k] score tiles alive at once (one in the forward,
-    the compiler strip-mines the rest of the softmax; two in the
+    [block_q, block_k] score tiles alive at once (one in the forward: its
+    chains' scores are pieces of the one tile, each alive until its own
+    softmax has made ``p`` of it, and the compiler strip-mines the rest;
+    two in the
     backward, p beside ds), the streamed sequence tiles and the resident
     blocks, both double-buffered, and the f32 accumulators, of which the
     backward's dQ is as long as the sequence. ``d`` is the width of q and
@@ -467,17 +508,19 @@ def _score_tile(kernel, s, d, itemsize, causal, block_q, block_k, d_v=None):
     return bq, bk, derived
 
 
-def _count_trace(kernel, block_q, block_k, derived, d, d_v, d_rot):
+def _count_trace(kernel, block_q, block_k, derived, d, d_v, d_rot, chains):
     """Which score tile each traced kernel got, whether the rule or the
     caller chose it, the two widths it was built for (q and k's whole
-    width, v and o's) and how many of q and k's columns came as a rotated
-    pair of their own (0: q and k came whole)."""
+    width, v and o's), how many of q and k's columns came as a rotated
+    pair of their own (0: q and k came whole) and how many chains of query
+    rows a pass of its loop runs side by side (``_chains``; the backward
+    runs one)."""
     _pallas.count_trace(
         "hvt_flash_kernel_traces_total",
         "flash-attention kernels traced into compiled programs, by "
         "score tile (counted per trace, not per execution)",
         kernel=kernel, block_q=block_q, block_k=block_k,
-        derived=int(derived), d_qk=d, d_v=d_v, d_rot=d_rot)
+        derived=int(derived), d_qk=d, d_v=d_v, d_rot=d_rot, chains=chains)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -528,6 +571,18 @@ def _rotated_width(rotated):
     return 0 if rotated is None else rotated[0].shape[-1]
 
 
+def _chains(kernel, block_q, itemsize):
+    """How many independent chains of query rows one pass of ``kernel``'s
+    loop runs: the forward's sub-block as two halves wherever a half is
+    whole sublane tiles of the operands (16 rows of bf16, 8 of float32),
+    so that neither q's half nor its rows of the scratch is cut inside a
+    tile; a block that does not halve so (the few rows a sequence that is
+    no multiple of 128 is clipped to) goes through as the one chain it is.
+    The backward keeps its K block whole: one."""
+    packed_rows = _SUBLANES * 4 // itemsize
+    return 2 if kernel == "fwd" and block_q % (2 * packed_rows) == 0 else 1
+
+
 class _Plan(NamedTuple):
     """All a kernel call is built from besides its operands' shapes."""
     scale: float
@@ -536,6 +591,7 @@ class _Plan(NamedTuple):
     block_k: int
     derived: bool       # the rule chose the tile, not the caller
     tile: int           # positions of the streamed operand a grid step
+    chains: int         # query-row pieces of a sub-block a pass interleaves
     interpret: bool
 
 
@@ -549,7 +605,9 @@ def _plan(kernel, q, scale, causal, block_q, block_k, d_v=None, d_rot=0):
         kernel, s, d + d_rot, q.dtype.itemsize, causal, block_q, block_k,
         d_v)
     return _Plan(scale, causal, block_q, block_k, derived,
-                 _seq_tile(s, block_q, block_k), _pallas.interpret())
+                 _seq_tile(s, block_q, block_k),
+                 _chains(kernel, block_q, q.dtype.itemsize),
+                 _pallas.interpret())
 
 
 def _flash_fwd_impl(q, k, v, rotated, scale, causal, block_q, block_k,
@@ -569,7 +627,8 @@ def _fwd_call(q, k, v, rotated=None, *, plan, out_dtype):
     b, h, s, d = q.shape
     d_v, e = v.shape[-1], _rotated_width(rotated)
     block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
-    _count_trace("fwd", block_q, block_k, plan.derived, d + e, d_v, e)
+    _count_trace("fwd", block_q, block_k, plan.derived, d + e, d_v, e,
+                 plan.chains)
     # Grouped-query attention is served ZERO-COPY: query head hi reads
     # K/V head hi // group through the block index map — no repeat
     # materialization, and the shared K/V tile stays VMEM-resident
@@ -590,7 +649,8 @@ def _fwd_call(q, k, v, rotated=None, *, plan, out_dtype):
             (1, tile, e), lambda bi, hi, qi, ti: (bi, ti, 0))]
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=plan.scale,
-                          causal=plan.causal, block_k=block_k),
+                          causal=plan.causal, block_k=block_k,
+                          chains=plan.chains),
         grid=grid,
         in_specs=in_specs,
         out_specs=[by_query(d_v), by_query(1)],
@@ -641,7 +701,8 @@ def _bwd_call(q, k, v, do, lse, delta, rotated=None, *, plan):
     b, h, s, d = q.shape
     d_v, e = v.shape[-1], _rotated_width(rotated)
     block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
-    _count_trace("bwd", block_q, block_k, plan.derived, d + e, d_v, e)
+    _count_trace("bwd", block_q, block_k, plan.derived, d + e, d_v, e,
+                 plan.chains)
     group = h // k.shape[1]
     n_k = s // block_k
     # q, k, dq and dk are ``d`` wide, v, do and dv ``d_v``

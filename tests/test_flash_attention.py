@@ -510,6 +510,11 @@ def _trace_count(**labels):
     from horovod_tpu import metrics
 
     labels.setdefault("d_rot", "0")     # q and k came whole
+    # the backward runs one chain of query rows, the forward two wherever
+    # its block halves on whole sublane tiles (``_chains``)
+    from horovod_tpu.ops import flash_attention as fa
+    labels.setdefault("chains", fa._chains(
+        labels["kernel"], int(labels["block_q"]), 4))
     m = metrics.registry().get("hvt_flash_kernel_traces_total")
     return m.labels(**labels).value if m else 0.0
 
@@ -549,6 +554,14 @@ def test_trace_counter_carries_the_tile_and_who_chose_it():
     trace()
     assert counts(True) == [n + 1 for n in derived]
     assert counts(False) == [n + 1 for n in explicit]
+    # and how many chains of query rows each traced kernel runs: a forward
+    # block of 32 float32 rows halves on whole sublane tiles, the backward
+    # runs one whatever its block
+    tile = dict(block_q="32", block_k="64", derived="0",
+                d_qk=q.shape[-1], d_v=v.shape[-1])
+    assert [_trace_count(kernel=kern, chains=n, **tile)
+            for kern, n in (("fwd", 2), ("fwd", 1), ("bwd", 1), ("bwd", 2))
+            ] == [explicit[0] + 1, 0, explicit[1] + 1, 0]
 
 
 def test_trace_counter_adds_nothing_to_the_program(monkeypatch):
@@ -899,3 +912,120 @@ def test_trace_counter_says_how_wide_a_rotated_pair_was():
         q, k, v).sum()), *_whole(q_n, q_r, k_n, k_r, v))
     assert (count(24, 8), count(24, 0), count(16, 0)) == (
         [n + 1 for n in before[0]], [n + 1 for n in before[1]], before[2])
+    # the pair changes nothing about the chains: two in the forward
+    assert [_trace_count(kernel="fwd", d_qk=24, d_rot=8, chains=n, **labels)
+            for n in (2, 1)] == [before[0][0] + 1, 0]
+
+
+# (kernel, block_q, itemsize) -> chains: the forward's block in two halves
+# wherever a half is whole sublane tiles of the operands (16 rows of bf16,
+# 8 of float32), one chain below that and in the backward
+@pytest.mark.parametrize("kernel, block_q, itemsize, want", [
+    ("fwd", 1024, 2, 2), ("fwd", 512, 2, 2), ("fwd", 32, 2, 2),
+    ("fwd", 16, 2, 1), ("fwd", 16, 4, 2), ("fwd", 8, 4, 1),
+    ("fwd", 100, 4, 1), ("fwd", 96, 4, 2), ("bwd", 1024, 2, 1),
+    ("bwd", 512, 4, 1)])
+def test_chains_go_by_the_block_and_the_operands_itemsize(
+        kernel, block_q, itemsize, want):
+    from horovod_tpu.ops import flash_attention as fa
+
+    assert fa._chains(kernel, block_q, itemsize) == want
+    q = jax.ShapeDtypeStruct(
+        (1, 1, block_q, 64), {2: jnp.bfloat16, 4: jnp.float32}[itemsize])
+    assert fa._plan(kernel, q, 0.125, True, block_q, block_q).chains == want
+
+
+def _chain_case(s, h, h_kv, d, d_v, e, dtype, seed):
+    """``q, k, v, q_r, k_r`` (the pair None where ``e`` is 0) and weights
+    for o and lse."""
+    keys = jax.random.split(jax.random.key(seed), 7)
+    shapes = ((1, s, h, d), (1, s, h_kv, d), (1, s, h_kv, d_v),
+              (1, s, h, e), (1, s, e), (1, s, h, d_v), (1, s, h))
+    q, k, v, q_r, k_r, w_o, w_lse = (
+        jax.random.normal(key, shape) for key, shape in zip(keys, shapes))
+    operands = [t.astype(dtype) for t in ((q, k, v, q_r, k_r) if e
+                                          else (q, k, v))]
+    return operands, w_o, w_lse
+
+
+@pytest.mark.parametrize(
+    "s, h, h_kv, d, d_v, e, causal, dtype, out_dtype, chains", [
+        pytest.param(1024, 1, 1, 64, 64, 0, True, jnp.float32, None, 2,
+                     id="s1024-d64-causal-one-sub-block-a-grid-step"),
+        pytest.param(2048, 1, 1, 64, 64, 0, True, jnp.float32, None, 2,
+                     id="s2048-d64-causal"),
+        pytest.param(1024, 1, 1, 128, 128, 0, False, jnp.float32, None, 2,
+                     id="s1024-d128-not-causal"),
+        pytest.param(2048, 1, 1, 128, 128, 0, True, jnp.float32, None, 2,
+                     id="s2048-d128-causal"),
+        pytest.param(1024, 4, 1, 64, 64, 0, True, jnp.float32, None, 2,
+                     id="grouped-4-on-1"),
+        pytest.param(1024, 2, 2, 128, 128, 64, True, jnp.float32, None, 2,
+                     id="rotated-128+64-on-128"),
+        pytest.param(1024, 2, 2, 64, 64, 0, False, jnp.bfloat16,
+                     jnp.float32, 2, id="bf16-out-float32-not-causal"),
+        pytest.param(200, 2, 2, 64, 64, 0, True, jnp.float32, None, 1,
+                     id="s200-no-multiple-of-128-one-chain"),
+        pytest.param(96, 2, 2, 32, 32, 0, True, jnp.float32, None, 2,
+                     id="s96-one-block-of-96-in-halves-of-48"),
+    ])
+def test_forward_in_chains_matches_the_formula_and_the_one_chain_pass(
+        s, h, h_kv, d, d_v, e, causal, dtype, out_dtype, chains,
+        monkeypatch):
+    """The forward at the tile the rule derives, with as many chains of
+    query rows a pass as the rule gives that tile: o, lse and every
+    gradient (cotangents for both outputs) against the float32 formula,
+    and equal to the last bit to what the same call gives with the rule
+    held to one chain: a row of the score tile depends on no other row
+    (to float32 rounding where the interpreter's products are of blocks
+    the CPU's dot treats differently)."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    operands, w_o, w_lse = _chain_case(s, h, h_kv, d, d_v, e, dtype, seed=s)
+    scale = (d + e) ** -0.5
+    q_bhsd = jax.ShapeDtypeStruct((1, h, s, d), dtype)
+    assert fa._plan("fwd", q_bhsd, scale, causal, None, None, d_v,
+                    e).chains == chains
+
+    def attend(q, k, v, q_r=None, k_r=None):
+        return fa.flash_attention_with_lse(
+            q, k, v, q_r=q_r, k_r=k_r, causal=causal, out_dtype=out_dtype)
+
+    def formula(q, k, v, q_r=None, k_r=None):
+        if q_r is not None:
+            q, k, v = _whole(q, q_r, k, k_r, v)
+        k, v = (jnp.repeat(t, h // h_kv, axis=2) for t in (k, v))
+        return _formula_o_lse(q, k, v, causal, scale)
+
+    def run(attend):
+        def weighed(*operands):
+            o, lse = attend(*operands)
+            return (jnp.sum(o.astype(jnp.float32) * w_o)
+                    + jnp.sum(lse * w_lse)), (o, lse)
+
+        return jax.jit(jax.value_and_grad(
+            weighed, argnums=tuple(range(len(operands))),
+            has_aux=True))(*operands)
+
+    with jax.default_matmul_precision("highest"):
+        got, want = run(attend), run(formula)
+        jax.clear_caches()
+        monkeypatch.setattr(fa, "_chains", lambda *a: 1)
+        one_chain = run(attend)
+        jax.clear_caches()
+    (_, (o, lse)), grads = got
+    assert o.dtype == (out_dtype or dtype) and lse.dtype == jnp.float32
+    assert [g.shape for g in grads] == [t.shape for t in operands]
+    tol = 3e-2 if dtype == jnp.bfloat16 else 2e-4
+    for a, w in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                    strict=True):
+        a, w = (np.asarray(t, np.float32) for t in (a, w))
+        np.testing.assert_allclose(a, w, rtol=tol,
+                                   atol=tol * np.abs(w).max())
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(one_chain),
+                    strict=True):
+        if s % 128 == 0:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        else:   # the CPU's dot sums a product of 48 rows in another order
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-6)
