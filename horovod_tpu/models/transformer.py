@@ -94,8 +94,9 @@ class GPTConfig:
     # mixer(RMSNorm(x))`` with the mixer ``layer_pattern[i]`` names, "*"
     # attention, "M" a Mamba-2 mixer (models/ssm.py), "G" a Gated
     # DeltaNet mixer (models/gdn.py), "C" a gated short convolution
-    # (models/sconv.py), "L" latent attention (models/mla.py), "E" the
-    # expert layer (models/moe.py), "-" the dense MLP. None (default) = every
+    # (models/sconv.py), "L" latent attention (models/mla.py), "S" attention
+    # over the keys an indexer chooses (models/dsa.py), "E" the expert
+    # layer (models/moe.py), "-" the dense MLP. None (default) = every
     # layer the attention + MLP (or expert) pair of ``Block``.
     layer_pattern: Optional[str] = None
     # False leaves q and k unrotated: attention without a positional
@@ -184,6 +185,17 @@ class GPTConfig:
     mla_nope_dim: int = 128
     mla_rope_dim: int = 64
     mla_value_dim: int = 128
+    # The sparse-attention mixers' indexer (models/dsa.py, pattern letter
+    # "S"; DeepSeek's sparse attention under the names of ``sa_config``):
+    # dsa_index_heads index heads (``indexer_num_heads``; 0: the model has
+    # no such layer) of dsa_index_dim channels (``indexer_head_dim``) on
+    # one index key a position, each query attending the dsa_topk causal
+    # keys it scores highest (``topk``). The attention around it is
+    # n_heads on n_kv_heads of head_dim with a norm a head, at
+    # ``rotary_base``.
+    dsa_index_heads: int = 0
+    dsa_index_dim: int = 64
+    dsa_topk: int = 2048
 
 
 # The crossover policy lives with the kernel (ops/flash_attention.py);
@@ -446,6 +458,17 @@ class MixerBlock(nn.Module):
                 rotary_base=cfg.rotary_base, norm_eps=cfg.norm_eps,
                 use_flash=cfg.use_flash, dtype=cfg.dtype,
                 name="mla")(h, positions)
+        elif self.kind == "S":
+            from horovod_tpu.models.dsa import SparseAttention
+
+            out, index_loss = SparseAttention(
+                cfg.n_heads, cfg.n_kv_heads or cfg.n_heads,
+                cfg.head_dim or cfg.d_model // cfg.n_heads,
+                cfg.dsa_index_heads, cfg.dsa_index_dim, cfg.dsa_topk,
+                rotary_base=cfg.rotary_base, norm_eps=cfg.norm_eps,
+                use_flash=cfg.use_flash, dtype=cfg.dtype,
+                name="dsa")(h, positions)
+            aux = {"dsa_index": index_loss}
         elif self.kind == "E":
             out, aux = _expert_layer(cfg)(h)
         elif self.kind == "-":
@@ -455,7 +478,8 @@ class MixerBlock(nn.Module):
                 f"layer_pattern holds {self.kind!r}: a layer is one of "
                 f"'*' (attention), 'M' (Mamba-2), 'G' (Gated DeltaNet), "
                 f"'C' (gated short convolution), 'L' (latent attention), "
-                f"'E' (experts), '-' (MLP)")
+                f"'S' (attention over chosen keys), 'E' (experts), "
+                f"'-' (MLP)")
         return x + out, aux
 
 
@@ -473,7 +497,10 @@ class GPT(nn.Module):
         chunk's logits and keeps them for the backward pass, so it is
         differentiable once, in reverse mode. ``return_aux=True`` returns
         ``(that, aux)``: the expert layers' auxiliary losses summed over the
-        layers unweighted (``{"load_balance", "router_z"}``; dense: ``{}``)."""
+        layers unweighted (``{"load_balance", "router_z"}``; dense: ``{}``)
+        and, of a model with sparse-attention mixers, their indexers' loss
+        (``"dsa_index"``, ``models/dsa.py``), which a training script adds
+        to its own."""
         cfg = self.cfg
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[-1]), tokens.shape)
@@ -499,23 +526,26 @@ class GPT(nn.Module):
             # recomputed block then leaves out; and what a held layer's
             # router chose (``moe.HELD_CHOICE``: a bit a token and expert
             # and the slots' order), which has no gradient and so is not
-            # chosen and sorted again. No name is in any other model's
-            # program
+            # chosen and sorted again; and the keys a sparse-attention
+            # mixer's indexer chose (``dsa.KEPT_CHOICE``: a byte a query
+            # and key), for the same reason. No name is in any other
+            # model's program
+            from horovod_tpu.models.dsa import KEPT_CHOICE
             from horovod_tpu.models.moe import HELD_CHOICE, HELD_SUM
             from horovod_tpu.ops.gated_delta_rule import KEPT_INVERSE
 
             block = nn.remat(
                 block, static_argnums=(),
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    HELD_SUM, HELD_CHOICE, KEPT_INVERSE))
+                    HELD_SUM, HELD_CHOICE, KEPT_INVERSE, KEPT_CHOICE))
         aux = {}
         for i in range(cfg.n_layers):
             kind = () if cfg.layer_pattern is None else (
                 cfg.layer_pattern[i],)
             x, layer_aux = block(cfg, *kind, name=f"block_{i}")(x, positions)
             if layer_aux is not None:
-                aux = {name: aux.get(name, 0.0) + value
-                       for name, value in layer_aux.items()}
+                aux = {**aux, **{name: aux.get(name, 0.0) + value
+                                 for name, value in layer_aux.items()}}
         x = _norm(cfg, "ln_f")(x)
         head = emb if cfg.tie_embeddings else self.param(
             "lm_head", nn.initializers.normal(0.02),
@@ -572,6 +602,10 @@ def param_partition_spec(params, *, tp_axis="tp", tp_size=None,
             from horovod_tpu.models.mla import mla_leaf_spec
 
             return mla_leaf_spec(names[-1], tp_axis)
+        if "dsa" in names:
+            from horovod_tpu.models.dsa import dsa_leaf_spec
+
+            return dsa_leaf_spec(names[-1], tp_axis)
         if any(n in ("q", "k", "v") for n in names):
             heads = leaf.shape[1] if hasattr(leaf, "shape") else None
             if tp_size and heads is not None and heads % tp_size:

@@ -34,6 +34,29 @@ pair's refs exist only in a call that passes it; a call without one
 traces the kernel bodies, block specs, scratch shapes and compiler
 parameters it traced before there was such a pair.
 
+A caller whose queries see only some of the keys (attention over the keys
+a learned indexer chose) passes ``choice [b, s, s]`` int8, 1 at a key the
+query row attends: one row of keys a query, **the same for every head**.
+The mask is applied where the causal mask would be, in the forward kernel
+(a block's rows against the streamed tile's keys) and in the backward
+kernel (the streamed tile's rows against the block's keys), with ``-inf``
+for what is not chosen, so that a row may see no key of a sub-block.
+``causal`` still says which tiles no row can see. Like the pair, the
+choice's ref exists only in a call that passes it.
+
+**The kernels' surface, in one place.** Every call: ``q [b, s, h, d]``,
+``k [b, s, h_kv, d]`` (``h_kv`` divides ``h``: grouped queries, read
+zero-copy), ``v [b, s, h_kv, d_v]`` (``d_v`` may differ from ``d``),
+``causal``, ``scale``, a score tile or none. Optional operands, each
+absent from the traced kernels of a call that does not pass it: a rotated
+pair ``q_r [b, s, h, e]``, ``k_r [b, s, e]`` (needs ``h_kv == h``); a
+choice ``[b, s, s]`` int8 (grouped queries allowed). What is not built is
+refused by name (``flash_attention_with_lse``): one of the pair alone, a
+pair whose shapes do not pair, **grouped queries with a rotated pair**, **a
+choice beside a rotated pair**, a choice that is not int8 ``[b, s, s]``,
+and sequence blocks that are not multiples of 8 where the kernel is
+compiled.
+
 No reference-framework counterpart (Horovod ships gradients, not kernels);
 this is part of the TPU framework's compute path. On the CPU the same
 kernel code runs through the Pallas interpreter (the CPU has no Mosaic
@@ -142,6 +165,15 @@ def _visible(q0, k0, shape):
     return k_pos <= q_pos
 
 
+def _chosen(mask):
+    """A tile of a caller's choice (int8, 1 at the keys a query row sees)
+    as the mask of a score sub-block. What is not chosen is filled with
+    ``-inf`` and not ``_NEG_INF``: a row may see no key of a sub-block
+    (the causal mask always shows it the first), and ``exp(-inf - m)`` is
+    0 whatever ``m`` the row has reached."""
+    return mask.astype(jnp.int32) != 0
+
+
 def _causal_n_eff(qi, block_q, ti, tile, block_k, n_sub):
     """Number of k sub-blocks of this tile a causal Q block attends to
     (sub-blocks entirely above the diagonal are skipped); the backward
@@ -155,10 +187,14 @@ def _sub_block(j, block):
     return pl.ds(pl.multiple_of(j * block, block), block)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains):
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
+                choice=False):
     # a rotated pair, where the caller passed one, comes after the three
-    # operands every call has: q_r's block and the shared k_r's tile
+    # operands every call has: q_r's block and the shared k_r's tile; a
+    # choice, where the caller passed one, after those: the block's rows
+    # of the mask against the tile's keys
     *rotated, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    choice_ref = rotated.pop() if choice else None
     block_q = q_ref.shape[2]
     rows = block_q // chains
     tile = k_ref.shape[2]
@@ -191,7 +227,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains):
                     sc = sc + dot(qr[mine], kr, NT)
                 if rest is not None:
                     sc = sc * rest
-                if causal:
+                if choice:
+                    sc = jnp.where(_chosen(choice_ref[
+                        0, mine, _sub_block(j, block_k)]), sc, -jnp.inf)
+                elif causal:
                     sc = jnp.where(
                         _visible(qi * block_q + c * rows,
                                  ti * tile + j * block_k, sc.shape),
@@ -241,10 +280,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains):
 
 
 def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *refs,
-                scale, causal, block_q):
+                scale, causal, block_q, choice=False):
     # with a rotated pair each group of refs (in, out, scratch) has two
     # more at its end: k_r's block and q_r's tile, dq_r and a head's dk_r,
-    # their accumulators
+    # their accumulators; a choice (never beside a pair) is one more
+    # input: the tile's rows of the mask against the block's keys
+    if choice:
+        choice_ref, *refs = refs
     rotated = len(refs) > 6
     if rotated:
         (kr_ref, qr_ref, dq_ref, dk_ref, dv_ref, dqr_ref, dkr_ref,
@@ -300,7 +342,10 @@ def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *refs,
                 sc = sc + dot(qr, kr_sc, NT)
             if rest is not None:
                 sc = sc * rest
-            if causal:
+            if choice:
+                sc = jnp.where(_chosen(choice_ref[0, rows, :]), sc,
+                               -jnp.inf)
+            elif causal:
                 sc = jnp.where(
                     _visible(ti * tile + i * block_q, ki * block_k,
                              sc.shape), sc, _NEG_INF)
@@ -388,7 +433,8 @@ _XLA_VMEM = 3 * 2 ** 20
 _VMEM_BUDGET = {"fwd": _SCOPED_VMEM, "bwd": _VMEM - _XLA_VMEM}
 
 
-def _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile, s, d_v=None):
+def _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile, s, d_v=None,
+                choice=False):
     """Estimate of the VMEM one grid step of ``kernel`` holds: the f32
     [block_q, block_k] score tiles alive at once (one in the forward: its
     chains' scores are pieces of the one tile, each alive until its own
@@ -401,7 +447,10 @@ def _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile, s, d_v=None):
     ``d``, and every term is then what it was before the two were told
     apart). A position of an operand
     takes whole 128-lane rows whatever its width is, and so does a position
-    of a [.., 1] statistic. Checked against the least limit the compiler
+    of a [.., 1] statistic. ``choice``: the caller's mask streams beside
+    the sequence tiles, a byte a query row and key, double-buffered (the
+    forward a block's rows against the tile's keys, the backward the
+    tile's rows against the block's keys). Checked against the least limit the compiler
     takes for the backward alone (v5e, bf16, 20 heads of 64): 19.0 MiB
     at 2 x 4096 and 1024 x 512 (estimate 21.5), 26.25 at 1024 x 1024
     (27.0), 21.0 at 1 x 8192 (23.5), 8.0 at 8 x 1024 and 512 x 512
@@ -420,18 +469,20 @@ def _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile, s, d_v=None):
     # XLA's share came to 15.5 and 38.0. The wider of the two widths
     # stands for all of them
     wide = 3 * (block_q + block_k) * (max(acc, acc_v) - stat)
+    mask = 2 * tile * (block_q if kernel == "fwd" else block_k) * bool(choice)
     if kernel == "fwd":     # K V stream; q o lse blocks; acc m l scratch
-        return (score + 2 * tile * (row + row_v)
+        return (score + mask + 2 * tile * (row + row_v)
                 + block_q * (2 * (row + row_v) + 2 * stat + acc_v + 2 * stat)
                 + wide)
     # bwd: Q dO lse delta stream in, a dq tile out; k v dk dv blocks and
     # the two accumulators of a block; dq's accumulator
-    return (2 * score + 2 * tile * (row + stat + row_v + stat + row)
+    return (2 * score + mask + 2 * tile * (row + stat + row_v + stat + row)
             + block_k * (2 * 2 * (row + row_v) + acc + acc_v) + s * acc
             + wide)
 
 
-def _compiler_params(kernel, block_q, block_k, d, itemsize, tile, s, d_v=None):
+def _compiler_params(kernel, block_q, block_k, d, itemsize, tile, s, d_v=None,
+                     choice=False):
     """The default scope where the estimate and XLA's share fit it; else
     just that much as the kernel's own ``vmem_limit_bytes``, and no more:
     the VMEM a kernel's scope takes is taken from the program around it.
@@ -441,7 +492,7 @@ def _compiler_params(kernel, block_q, block_k, d, itemsize, tile, s, d_v=None):
     fewer operands of the fusions around each call in VMEM, and they and
     the kernel itself were slower; from 24.5 down to 20 nothing moved."""
     limit = _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile, s,
-                        d_v) + _XLA_VMEM
+                        d_v, choice) + _XLA_VMEM
     if limit <= _SCOPED_VMEM:
         return None
     if limit > _VMEM:
@@ -454,7 +505,7 @@ def _compiler_params(kernel, block_q, block_k, d, itemsize, tile, s, d_v=None):
     return pltpu.CompilerParams(vmem_limit_bytes=limit)
 
 
-def _derive_tile(kernel, s, d, itemsize, causal, d_v=None):
+def _derive_tile(kernel, s, d, itemsize, causal, d_v=None, choice=False):
     """The score tile of ``kernel`` ("fwd", "bwd") for a sequence of ``s``
     positions, q and k ``d`` wide and v and o ``d_v`` (None: ``d``): the
     largest (block_q, block_k) — multiples of 128 that
@@ -490,19 +541,21 @@ def _derive_tile(kernel, s, d, itemsize, causal, d_v=None):
            for bk in sizes if bk <= most_k
            if max(bq, bk) % min(bq, bk) == 0
            and _vmem_bytes(kernel, bq, bk, d, itemsize,
-                           _seq_tile(s, bq, bk), s, d_v)
+                           _seq_tile(s, bq, bk), s, d_v, choice)
            <= _VMEM_BUDGET[kernel]]
     return max(fit, key=lambda t: (t[0] * t[1], t[1]),
                default=(_LANES, _LANES))
 
 
-def _score_tile(kernel, s, d, itemsize, causal, block_q, block_k, d_v=None):
+def _score_tile(kernel, s, d, itemsize, causal, block_q, block_k, d_v=None,
+                choice=False):
     """``(block_q, block_k, derived)`` for one of the two kernels: an
     explicit integer is honoured (clipped to divide ``s``);
     ``None`` takes that side of the tile derived from the shape."""
     derived = block_q is None or block_k is None
     if derived:
-        auto_q, auto_k = _derive_tile(kernel, s, d, itemsize, causal, d_v)
+        auto_q, auto_k = _derive_tile(kernel, s, d, itemsize, causal, d_v,
+                                      choice)
     bq = auto_q if block_q is None else _blocks(s, block_q)
     bk = auto_k if block_k is None else _blocks(s, block_k)
     return bq, bk, derived
@@ -523,16 +576,18 @@ def _count_trace(kernel, block_q, block_k, derived, d, d_v, d_rot, chains):
         derived=int(derived), d_qk=d, d_v=d_v, d_rot=d_rot, chains=chains)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, rotated, scale, causal, block_q, block_k, out_dtype):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash(q, k, v, rotated, choice, scale, causal, block_q, block_k,
+           out_dtype):
     """Differentiable (o, lse). The lse output carries its own gradient:
     d lse/dS = P, so a dlse cotangent folds into the backward kernel as
     delta := rowsum(do∘o) − dlse — the kernel is unchanged.
 
     q, k, v are ``[b, h, s, d]``; ``rotated`` is None or the pair
-    ``(q_r [b, h, s, e], k_r [b, s, e])`` (``_rotated_width``)."""
-    o, lse = _flash_fwd_impl(q, k, v, rotated, scale, causal, block_q,
-                             block_k, out_dtype)
+    ``(q_r [b, h, s, e], k_r [b, s, e])`` (``_rotated_width``);
+    ``choice`` is None or the mask ``[b, s, s]`` int8 (no gradient)."""
+    o, lse = _flash_fwd_impl(q, k, v, rotated, choice, scale, causal,
+                             block_q, block_k, out_dtype)
     return o, lse
 
 
@@ -595,26 +650,29 @@ class _Plan(NamedTuple):
     interpret: bool
 
 
-def _plan(kernel, q, scale, causal, block_q, block_k, d_v=None, d_rot=0):
+def _plan(kernel, q, scale, causal, block_q, block_k, d_v=None, d_rot=0,
+          choice=False):
     """Made outside the jitted calls below, so that what the process
     holds besides the operands (the backend) is part of their cache's
     key and never read under a cached trace. ``d_rot``: the width of a
-    rotated pair beside q and k, which the tile goes by as by q's own."""
+    rotated pair beside q and k, which the tile goes by as by q's own;
+    ``choice``: the call streams a mask, which the tile leaves room for."""
     _, _, s, d = q.shape
     block_q, block_k, derived = _score_tile(
         kernel, s, d + d_rot, q.dtype.itemsize, causal, block_q, block_k,
-        d_v)
+        d_v, choice)
     return _Plan(scale, causal, block_q, block_k, derived,
                  _seq_tile(s, block_q, block_k),
                  _chains(kernel, block_q, q.dtype.itemsize),
                  _pallas.interpret())
 
 
-def _flash_fwd_impl(q, k, v, rotated, scale, causal, block_q, block_k,
-                    out_dtype):
-    return _fwd_call(q, k, v, rotated, out_dtype=out_dtype,
+def _flash_fwd_impl(q, k, v, rotated, choice, scale, causal, block_q,
+                    block_k, out_dtype):
+    return _fwd_call(q, k, v, rotated, choice, out_dtype=out_dtype,
                      plan=_plan("fwd", q, scale, causal, block_q, block_k,
-                                v.shape[-1], _rotated_width(rotated)))
+                                v.shape[-1], _rotated_width(rotated),
+                                choice is not None))
 
 
 # Each of the two calls is a ``jax.jit`` of its own: a model's layers
@@ -623,12 +681,13 @@ def _flash_fwd_impl(q, k, v, rotated, scale, causal, block_q, block_k,
 # traced and lowered one by one were 20 s of every start (PERF.md section
 # 6, PR 27).
 @functools.partial(jax.jit, static_argnames=("plan", "out_dtype"))
-def _fwd_call(q, k, v, rotated=None, *, plan, out_dtype):
+def _fwd_call(q, k, v, rotated=None, choice=None, *, plan, out_dtype):
     b, h, s, d = q.shape
     d_v, e = v.shape[-1], _rotated_width(rotated)
     block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
-    _count_trace("fwd", block_q, block_k, plan.derived, d + e, d_v, e,
-                 plan.chains)
+    chosen = choice is not None
+    _count_trace("fwd" + "_choice" * chosen, block_q, block_k, plan.derived,
+                 d + e, d_v, e, plan.chains)
     # Grouped-query attention is served ZERO-COPY: query head hi reads
     # K/V head hi // group through the block index map — no repeat
     # materialization, and the shared K/V tile stays VMEM-resident
@@ -647,10 +706,15 @@ def _fwd_call(q, k, v, rotated=None, *, plan, out_dtype):
         # q_r as q; the one k_r a position, whatever the head
         in_specs += [by_query(e), pl.BlockSpec(
             (1, tile, e), lambda bi, hi, qi, ti: (bi, ti, 0))]
+    if chosen:
+        # the block's rows of the mask against the tile's keys, whatever
+        # the head
+        in_specs += [pl.BlockSpec(
+            (1, block_q, tile), lambda bi, hi, qi, ti: (bi, qi, ti))]
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=plan.scale,
                           causal=plan.causal, block_k=block_k,
-                          chains=plan.chains),
+                          chains=plan.chains, choice=chosen),
         grid=grid,
         in_specs=in_specs,
         out_specs=[by_query(d_v), by_query(1)],
@@ -660,33 +724,36 @@ def _fwd_call(q, k, v, rotated=None, *, plan, out_dtype):
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
         compiler_params=_compiler_params("fwd", block_q, block_k, d + e,
-                                         q.dtype.itemsize, tile, s, d_v),
+                                         q.dtype.itemsize, tile, s, d_v,
+                                         chosen),
         interpret=plan.interpret,
         name="hvt_flash_fwd",
-    )(q, k, v, *(rotated or ()))
+    )(q, k, v, *(rotated or ()), *((choice,) if chosen else ()))
     return o, lse
 
 
-def _flash_fwd(q, k, v, rotated, scale, causal, block_q, block_k, out_dtype):
-    o, lse = _flash_fwd_impl(q, k, v, rotated, scale, causal, block_q,
-                             block_k, out_dtype)
-    return (o, lse), (q, k, v, rotated, o, lse)
+def _flash_fwd(q, k, v, rotated, choice, scale, causal, block_q, block_k,
+               out_dtype):
+    o, lse = _flash_fwd_impl(q, k, v, rotated, choice, scale, causal,
+                             block_q, block_k, out_dtype)
+    return (o, lse), (q, k, v, rotated, choice, o, lse)
 
 
 def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
     do, dlse = cot
-    q, k, v, rotated, o, lse = res
+    q, k, v, rotated, choice, o, lse = res
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)        # [B, H, S, 1]
     # lse cotangent: ds gains + P∘dlse, i.e. delta shifts by −dlse
     delta = delta - dlse.astype(jnp.float32)
-    return _bwd_call(q, k, v, do, lse, delta, rotated,
+    return _bwd_call(q, k, v, do, lse, delta, rotated, choice,
                      plan=_plan("bwd", q, scale, causal, block_q, block_k,
-                                v.shape[-1], _rotated_width(rotated)))
+                                v.shape[-1], _rotated_width(rotated),
+                                choice is not None))
 
 
 @functools.partial(jax.jit, static_argnames="plan")
-def _bwd_call(q, k, v, do, lse, delta, rotated=None, *, plan):
+def _bwd_call(q, k, v, do, lse, delta, rotated=None, choice=None, *, plan):
     """dq, dk, dv: grid (b, h, ki, ti) — Q/dO/lse/delta tiles stream past
     each K/V block (the reduction axis must be LAST), and every score
     sub-block is made once for all three. dk/dv accumulate a K block;
@@ -697,12 +764,14 @@ def _bwd_call(q, k, v, do, lse, delta, rotated=None, *, plan):
     zero-copy via the index map but emits per-QUERY-head dk/dv (full h),
     which are then group-summed — each K/V head's gradient is the sum
     over its query group. A shared rotated key's gradient goes the same
-    way: the kernel emits a head's, and the heads' sum is the key's."""
+    way: the kernel emits a head's, and the heads' sum is the key's.
+    A choice has no gradient: the fifth result is None."""
     b, h, s, d = q.shape
     d_v, e = v.shape[-1], _rotated_width(rotated)
     block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
-    _count_trace("bwd", block_q, block_k, plan.derived, d + e, d_v, e,
-                 plan.chains)
+    chosen = choice is not None
+    _count_trace("bwd" + "_choice" * chosen, block_q, block_k, plan.derived,
+                 d + e, d_v, e, plan.chains)
     group = h // k.shape[1]
     n_k = s // block_k
     # q, k, dq and dk are ``d`` wide, v, do and dv ``d_v``
@@ -737,16 +806,23 @@ def _bwd_call(q, k, v, do, lse, delta, rotated=None, *, plan):
         out_shape += [_pallas.out(q_r.shape, q_r.dtype, *operands),
                       _pallas.out(q_r.shape, k_r.dtype, *operands)]
         scratch += [(s, e), (block_k, e)]
+    if chosen:
+        # the tile's rows of the mask against the block's keys
+        inputs += (choice,)
+        in_specs += [pl.BlockSpec(
+            (1, tile, block_k), lambda bi, hi, ki, ti: (bi, ti, ki))]
     dq, dk, dv, *d_rotated = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=plan.scale,
-                          causal=plan.causal, block_q=block_q),
+                          causal=plan.causal, block_q=block_q,
+                          choice=chosen),
         grid=(b, h, n_k, s // tile),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
         compiler_params=_compiler_params("bwd", block_q, block_k, d + e,
-                                         q.dtype.itemsize, tile, s, d_v),
+                                         q.dtype.itemsize, tile, s, d_v,
+                                         chosen),
         interpret=plan.interpret,
         name="hvt_flash_bwd",
     )(*inputs)
@@ -757,17 +833,17 @@ def _bwd_call(q, k, v, do, lse, delta, rotated=None, *, plan):
         dv = dv.astype(jnp.float32).reshape(
             b, h_kv, group, s, -1).sum(axis=2).astype(v.dtype)
     if rotated is None:
-        return dq, dk, dv, None
+        return dq, dk, dv, None, None
     dq_r, dk_r = d_rotated
     return dq, dk, dv, (dq_r, dk_r.astype(jnp.float32).sum(axis=1).astype(
-        dk_r.dtype))
+        dk_r.dtype)), None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, *, q_r=None, k_r=None, causal=True, scale=None,
-                    block_q=None, block_k=None):
+def flash_attention(q, k, v, *, q_r=None, k_r=None, choice=None, causal=True,
+                    scale=None, block_q=None, block_k=None):
     """Fused multi-head attention.
 
     Args:
@@ -783,6 +859,12 @@ def flash_attention(q, k, v, *, q_r=None, k_r=None, causal=True, scale=None,
         ``q k^T + q_r k_r^T`` inside the kernels, the softmax scale's
         default ``(head_dim + e) ** -0.5``, and ``k_r``'s gradient the
         sum over the heads (``flash_attention_with_lse``).
+      choice: None, or which keys each query sees, ``[batch, seq, seq]``
+        int8, 1 at a key the row attends and 0 elsewhere, the same for
+        every head (attention over keys an indexer chose). It takes the
+        place of the causal mask, so it holds the causal limit itself
+        where there is one; ``causal`` still says which tiles no row can
+        see. No gradient.
       causal: apply causal masking.
       scale: softmax scale, default ``head_dim ** -0.5``.
       block_q / block_k: the score tile; ``None`` (the default) derives
@@ -792,15 +874,15 @@ def flash_attention(q, k, v, *, q_r=None, k_r=None, causal=True, scale=None,
     Returns [batch, seq, heads, value_dim] in q.dtype. Differentiable
     (custom VJP with a recompute-based backward kernel).
     """
-    o, _ = flash_attention_with_lse(q, k, v, q_r=q_r, k_r=k_r, causal=causal,
-                                    scale=scale, block_q=block_q,
-                                    block_k=block_k)
+    o, _ = flash_attention_with_lse(q, k, v, q_r=q_r, k_r=k_r, choice=choice,
+                                    causal=causal, scale=scale,
+                                    block_q=block_q, block_k=block_k)
     return o
 
 
-def flash_attention_with_lse(q, k, v, *, q_r=None, k_r=None, causal=True,
-                             scale=None, block_q=None, block_k=None,
-                             out_dtype=None):
+def flash_attention_with_lse(q, k, v, *, q_r=None, k_r=None, choice=None,
+                             causal=True, scale=None, block_q=None,
+                             block_k=None, out_dtype=None):
     """Fused attention returning ``(o, lse)``; both are differentiable.
     ``q`` and ``k`` share one width and ``v`` and ``o`` another, which may
     be the same (``flash_attention``).
@@ -812,6 +894,13 @@ def flash_attention_with_lse(q, k, v, *, q_r=None, k_r=None, causal=True,
     never writes them side by side. The tile, the streamed tile and the
     VMEM limit are those of one width of ``d + e``; ``k`` has q's heads
     then (grouped queries with a rotated pair are not built).
+
+    With ``choice [b, s, s]`` int8 a query row sees the keys its row of the
+    mask marks and no other: the mask is applied where the causal mask
+    would be, in the forward kernel and in the backward kernel, every head
+    reading the same tile of it; ``lse`` is then over the chosen keys. A
+    row has to see at least one key. Grouped queries are served as without
+    one; a rotated pair beside a choice is not built.
 
     ``lse[b, s, h]`` is the log-sum-exp of the (scaled, masked) scores for
     each query — exactly what blockwise/ring composition needs to combine
@@ -849,10 +938,21 @@ def flash_attention_with_lse(q, k, v, *, q_r=None, k_r=None, causal=True,
             raise ValueError(
                 f"a rotated pair goes with as many key heads as query "
                 f"heads; got {h} on {h_kv}")
+    if choice is not None:
+        if e:
+            raise ValueError(
+                "a choice of keys beside a rotated pair is not built: pass "
+                "q and k whole, or no choice")
+        if choice.shape != (b, s, s) or choice.dtype != jnp.int8:
+            raise ValueError(
+                f"a choice is int8 [batch, seq, seq] = {(b, s, s)}, one row "
+                f"of keys a query for every head; got {choice.dtype} "
+                f"{choice.shape}")
     if scale is None:
         scale = (d + e) ** -0.5
     bq, bk, _ = _score_tile("fwd", s, d + e, q.dtype.itemsize, causal,
-                             block_q, block_k, v.shape[-1])
+                             block_q, block_k, v.shape[-1],
+                             choice is not None)
     if not _pallas.interpret() and (bq % 8 or bk % 8):
         # Mosaic refuses the kernel ("cannot statically prove that index
         # in dimension 2 is a multiple of 8"); the interpreter has no
@@ -865,7 +965,7 @@ def flash_attention_with_lse(q, k, v, *, q_r=None, k_r=None, causal=True,
     # Kernels are gridded (batch, head, block): BHSD layout.
     to_bhsd = lambda x: jnp.transpose(x, (0, 2, 1, 3))
     o, lse = _flash(to_bhsd(q), to_bhsd(k), to_bhsd(v),
-                    None if q_r is None else (to_bhsd(q_r), k_r),
+                    None if q_r is None else (to_bhsd(q_r), k_r), choice,
                     float(scale), bool(causal), block_q, block_k,
                     jnp.dtype(out_dtype or q.dtype))
     # lse: [B, H, S, 1] → [B, S, H]

@@ -679,3 +679,88 @@ def test_gdn_layers_hold_the_norm_kernels_and_stay_flat(
     size = lambda dims: int(np.prod([int(n) for n in dims.split(",")]))
     assert moved and max(map(size, moved)) <= batch * seq * 128, sorted(
         set(moved), key=size)[-3:]
+
+
+# ---- attention over the keys an indexer chooses (keyevl2-s16384)
+
+DSA_SHAPE = dict(b=1, s=16384, h=32, h_kv=4, d=128, j=16, e=64, topk=2048)
+
+
+def _dsa_like(device):
+    sharding = SingleDeviceSharding(device)
+    return lambda dtype, *dims: jax.ShapeDtypeStruct(dims, dtype,
+                                                     sharding=sharding)
+
+
+def test_flash_kernels_with_a_choice_compile_for_v5e(compiled_kernel,
+                                                     v5e_devices):
+    """The cell's attention: 16,384 positions, 32 query heads on 4
+    key-value heads of 128, a ``[1, 16384, 16384]`` int8 choice: forward
+    and backward, one Pallas call each, the mask an operand of both."""
+    like, z = _dsa_like(v5e_devices[0]), DSA_SHAPE
+    q = like(jnp.bfloat16, z["b"], z["s"], z["h"], z["d"])
+    kv = like(jnp.bfloat16, z["b"], z["s"], z["h_kv"], z["d"])
+    choice = like(jnp.int8, z["b"], z["s"], z["s"])
+
+    def loss(q, k, v, choice):
+        return (fa.flash_attention(q, k, v, choice=choice).astype(
+            jnp.float32) ** 2).mean()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv, choice).compile().as_text()
+    calls = re.findall(r" custom-call\((.*?)\), custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert len(calls) == 2 and all("s8[" in call or "%" in call
+                                   for call in calls)
+    assert "hvt_flash_fwd" in text and "hvt_flash_bwd" in text
+
+
+@pytest.mark.parametrize("rows", [512, 16384])
+def test_choice_of_2048_keys_compiles_for_v5e(rows, compiled_kernel,
+                                              v5e_devices):
+    """The selection at ``[512, 16384]`` (a sequence's last rows, as one
+    of several sequences' would be handed over) and at the cell's whole
+    ``[16384, 16384]``: one Pallas call, no sort beside it."""
+    from horovod_tpu.ops import dsa
+
+    like, z = _dsa_like(v5e_devices[0]), DSA_SHAPE
+    fn = lambda x: dsa._choice_call(x, topk=z["topk"], interpret=False)
+    x = like(jnp.float32, rows, z["s"])
+    text = jax.jit(fn).lower(x).compile().as_text()
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) == 1
+    assert "hvt_dsa_choice" in text and " sort(" not in text
+
+
+def test_index_scores_and_indexer_loss_compile_for_v5e(compiled_kernel,
+                                                       v5e_devices):
+    """16 index heads of 64 on one index key at 16,384 positions: the
+    index scores' kernel, and the indexer's loss with its three gradients
+    beside 32 heads on 4 of 128 (one Pallas call each: the backward pass
+    scales what the forward made)."""
+    from horovod_tpu.ops import dsa
+
+    like, z = _dsa_like(v5e_devices[0]), DSA_SHAPE
+    q_i = like(jnp.bfloat16, z["b"], z["s"], z["j"], z["e"])
+    k_i = like(jnp.bfloat16, z["b"], z["s"], z["e"])
+    w = like(jnp.float32, z["b"], z["s"], z["j"])
+    text = jax.jit(dsa.index_scores).lower(q_i, k_i, w).compile().as_text()
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) == 1
+    assert "hvt_dsa_index" in text
+
+    def loss(q, k, lse, q_i, k_i, w, scores, choice):
+        return dsa.index_loss(q, k, lse, q_i, k_i, w, scores, choice,
+                              z["d"] ** -0.5)
+
+    operands = (
+        like(jnp.bfloat16, z["b"], z["s"], z["h"], z["d"]),
+        like(jnp.bfloat16, z["b"], z["s"], z["h_kv"], z["d"]),
+        like(jnp.float32, z["b"], z["s"], z["h"]), q_i, k_i, w,
+        like(jnp.float32, z["b"], z["s"], z["s"]),
+        like(jnp.int8, z["b"], z["s"], z["s"]))
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(3, 4, 5))).lower(
+        *operands).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) == 1
+    assert "hvt_dsa_loss" in text
+    # nothing [s, s] wide but the operands: no float32 score matrix made
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30

@@ -1,0 +1,152 @@
+"""``ops/dsa.py``: the index scores, the choice of the ``topk`` best causal
+keys a query and the indexer's loss, each kernel in the Pallas interpreter
+against its plain body, and the plain choice against ``jax.lax.top_k``."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu import metrics
+from horovod_tpu.models.dsa import chosen_attention
+from horovod_tpu.ops import dsa
+
+B, S, H, H_KV, D, J, E = 2, 256, 4, 2, 32, 4, 16
+
+
+@pytest.fixture(scope="module")
+def parts():
+    keys = jax.random.split(jax.random.key(7), 6)
+    normal = lambda key, *shape: jax.random.normal(key, shape, jnp.float32)
+    return {"q": normal(keys[0], B, S, H, D), "k": normal(keys[1], B, S, H_KV, D),
+            "v": normal(keys[2], B, S, H_KV, D),
+            "q_i": normal(keys[3], B, S, J, E), "k_i": normal(keys[4], B, S, E),
+            "w": 0.1 * normal(keys[5], B, S, J)}
+
+
+def _top_k_mask(scores, topk):
+    """``jax.lax.top_k`` of every causal row, its indices scattered."""
+    b, s, _ = scores.shape
+    causal = np.tril(np.ones((s, s), bool))
+    _, index = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf),
+                             min(topk, s))
+    mask = np.zeros((b, s, s), bool)
+    np.put_along_axis(mask, np.asarray(index), True, axis=-1)
+    return mask & causal
+
+
+def test_index_scores_kernel_is_the_plain_body(parts):
+    want = dsa.index_scores_plain(parts["q_i"], parts["k_i"], parts["w"])
+    got = dsa.index_scores(parts["q_i"], parts["k_i"], parts["w"])
+    below = np.tril(np.ones((S, S), bool))
+    assert np.all(np.isneginf(np.asarray(got))[:, ~below])
+    assert np.all(np.isneginf(np.asarray(want))[:, ~below])
+    np.testing.assert_allclose(np.asarray(got)[:, below],
+                               np.asarray(want)[:, below], atol=2e-6)
+    # sum_j w_j relu(q_j . k), by hand at one pair
+    t, u = 200, 17
+    by_hand = sum(float(parts["w"][1, t, j]) * max(0.0, float(
+        parts["q_i"][1, t, j] @ parts["k_i"][1, u])) for j in range(J))
+    assert float(want[1, t, u]) == pytest.approx(by_hand, abs=1e-5)
+
+
+@pytest.mark.parametrize("topk", [40, 256, 300, 1])
+@pytest.mark.parametrize("ties", ["planted", "none", "all_equal"])
+def test_choice_is_top_k_with_ties_to_the_lower_position(parts, topk, ties):
+    """Rows with planted ties (scores rounded to halves, zeros of both
+    signs among them), a causal limit, ``topk`` below the row's length, at
+    it and above it: the plain body and the kernel are ``top_k``'s set,
+    every row holds ``min(t + 1, topk)`` keys and none above ``t``."""
+    scores = dsa.index_scores_plain(parts["q_i"], parts["k_i"], parts["w"])
+    if ties == "planted":
+        scores = jnp.round(scores * 2) / 2
+        assert bool(jnp.any((scores == 0) & jnp.signbit(scores)))
+    elif ties == "all_equal":
+        scores = jnp.zeros_like(scores)
+    want = _top_k_mask(jnp.where(scores == 0, 0.0, scores), topk)
+    plain = np.asarray(dsa.choose_plain(scores, topk))
+    kernel = np.asarray(dsa.choose(scores, topk))
+    assert plain.dtype == kernel.dtype == np.int8
+    assert np.array_equal(plain != 0, want)
+    assert np.array_equal(kernel, plain)
+    counts = np.minimum(np.arange(S) + 1, topk)
+    assert np.array_equal(kernel.sum(-1), np.broadcast_to(counts, (B, S)))
+    assert not kernel[:, ~np.tril(np.ones((S, S), bool))].any()
+
+
+def test_choice_reads_nothing_above_the_diagonal(parts):
+    scores = dsa.index_scores_plain(parts["q_i"], parts["k_i"], parts["w"])
+    noisy = jnp.where(jnp.isneginf(scores), 1e9, scores)
+    for choose in (dsa.choose_plain, dsa.choose):
+        assert np.array_equal(np.asarray(choose(noisy, 40)),
+                              np.asarray(choose(scores, 40)))
+
+
+def test_index_loss_kernel_is_the_plain_body_with_its_gradients(parts):
+    """The value and the three gradients the kernel makes beside it against
+    autodiff of the plain body; q, k and lse get none."""
+    scale = D ** -0.5
+    scores = dsa.index_scores_plain(parts["q_i"], parts["k_i"], parts["w"])
+    choice = dsa.choose_plain(scores, 40)
+    _, lse = chosen_attention(parts["q"], parts["k"], parts["v"], choice,
+                              scale, False)
+    plain = lambda q, k, q_i, k_i, w: dsa.index_loss_plain(
+        q, k, lse, q_i, k_i, w, choice, scale)
+    kernel = lambda q, k, q_i, k_i, w: dsa.index_loss(
+        q, k, lse, q_i, k_i, w, scores, choice, scale)
+    args = tuple(parts[n] for n in ("q", "k", "q_i", "k_i", "w"))
+    want, want_grads = jax.value_and_grad(plain, argnums=(0, 1, 2, 3, 4))(*args)
+    got, got_grads = jax.value_and_grad(kernel, argnums=(0, 1, 2, 3, 4))(*args)
+    assert float(want) > 0.01
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    assert float(kernel(*args)) == pytest.approx(float(want), rel=1e-5)
+    for name, g, w in zip(("q", "k", "q_i", "k_i", "w"), got_grads,
+                          want_grads):
+        if name in ("q", "k"):
+            assert not np.any(np.asarray(g)) and not np.any(np.asarray(w))
+        else:
+            assert float(jnp.linalg.norm(w)) > 0
+            assert float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w)) < 1e-5, name
+
+
+def test_index_loss_is_the_kl_by_hand(parts):
+    """One row by hand: pbar the heads' mean of the softmax over the chosen
+    keys, r the softmax of the index scores over the same keys."""
+    scale = D ** -0.5
+    scores = dsa.index_scores_plain(parts["q_i"], parts["k_i"], parts["w"])
+    choice = dsa.choose_plain(scores, 40)
+    _, lse = chosen_attention(parts["q"], parts["k"], parts["v"], choice,
+                              scale, False)
+    each = []
+    for b in range(B):
+        for t in range(S):
+            seen = np.asarray(choice[b, t]) != 0
+            k = np.repeat(np.asarray(parts["k"][b]), H // H_KV, axis=1)
+            main = np.einsum("hd,khd->hk", np.asarray(parts["q"][b, t]),
+                             k)[:, seen] * scale
+            pbar = np.mean(np.exp(main - main.max(-1, keepdims=True))
+                           / np.exp(main - main.max(-1, keepdims=True)).sum(
+                               -1, keepdims=True), axis=0)
+            index = np.asarray(scores[b, t])[seen]
+            log_r = index - index.max() - np.log(
+                np.exp(index - index.max()).sum())
+            each.append(float(np.sum(pbar * (np.log(pbar) - log_r))))
+    got = dsa.index_loss_plain(parts["q"], parts["k"], lse, parts["q_i"],
+                               parts["k_i"], parts["w"], choice, scale)
+    assert float(got) == pytest.approx(np.mean(each), rel=1e-4)
+
+
+def test_each_kernel_counts_its_trace_with_its_shape(parts):
+    family = metrics.counter(
+        "hvt_dsa_kernel_traces_total", "", ("kernel", "heads", "width", "seq",
+                                            "topk"))
+    at = lambda **labels: family.labels(
+        **{k: str(v) for k, v in labels.items()}).value
+    before = at(kernel="choice", heads=0, width=0, seq=128, topk=9)
+    dsa.choose(jnp.zeros((1, 128, 128)), 9)
+    assert at(kernel="choice", heads=0, width=0, seq=128, topk=9) \
+        == before + 1
+    before = at(kernel="index", heads=J, width=E, seq=128, topk=0)
+    dsa.index_scores(parts["q_i"][:1, :128], parts["k_i"][:1, :128],
+                     parts["w"][:1, :128])
+    assert at(kernel="index", heads=J, width=E, seq=128, topk=0) == before + 1

@@ -1029,3 +1029,134 @@ def test_forward_in_chains_matches_the_formula_and_the_one_chain_pass(
         else:   # the CPU's dot sums a product of 48 rows in another order
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-5, atol=1e-6)
+
+
+# ---- a choice of keys: a mask a query row, the same for every head
+
+def _choice(b, s, keep, causal=True, seed=3):
+    """A mask ``[b, s, s]`` int8 of about ``keep`` of each row's (causal)
+    keys, every row seeing at least its first key."""
+    rng = np.random.RandomState(seed)
+    mask = rng.uniform(size=(b, s, s)) < keep
+    if causal:
+        mask &= np.tril(np.ones((s, s), bool))
+    mask[:, :, 0] = True
+    return jnp.asarray(mask, jnp.int8)
+
+
+def _dense_chosen(q, k, v, choice, scale=None):
+    """Einsums with the same mask, float32 softmax, ``(o, lse)``."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
+    scale = scale or q.shape[-1] ** -0.5
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where((choice != 0)[:, None], scores, -jnp.inf)
+    lse = jax.nn.logsumexp(scores, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(scores - lse[..., None]), v)
+    return out, jnp.transpose(lse, (0, 2, 1))
+
+
+@pytest.mark.parametrize("s, h, h_kv, tile, causal, keep", [
+    (256, 4, 2, {}, True, 0.3),
+    (256, 4, 1, dict(block_q=64, block_k=128), True, 0.1),
+    (384, 2, 2, dict(block_q=128, block_k=64), True, 0.02),
+    (256, 4, 2, dict(block_q=128, block_k=128), False, 0.3),
+])
+def test_choice_matches_the_einsum_with_the_same_mask(s, h, h_kv, tile,
+                                                      causal, keep):
+    """Forward (o and lse) and the three gradients, grouped queries, rows
+    that see no key of a sub-block (``keep`` 0.02 leaves most sub-blocks
+    of a row empty), derived and named tiles, in the interpreter."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    rng = np.random.RandomState(5)
+    mk = lambda heads: jnp.asarray(rng.normal(size=(2, s, heads, 32)),
+                                   jnp.float32)
+    q, k, v = mk(h), mk(h_kv), mk(h_kv)
+    choice = _choice(2, s, keep, causal)
+    w_o, w_lse = mk(h), jnp.asarray(rng.normal(size=(2, s, h)), jnp.float32)
+
+    def loss(attend):
+        def f(q, k, v):
+            o, lse = attend(q, k, v)
+            return jnp.sum(o * w_o) + jnp.sum(lse * w_lse), (o, lse)
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    (_, (o, lse)), grads = loss(lambda q, k, v: fa.flash_attention_with_lse(
+        q, k, v, choice=choice, causal=causal, **tile))(q, k, v)
+    (_, (o_w, lse_w)), want = loss(lambda q, k, v: _dense_chosen(
+        q, k, v, choice))(q, k, v)
+    np.testing.assert_allclose(o, o_w, atol=2e-5)
+    np.testing.assert_allclose(lse, lse_w, atol=2e-5)
+    for g, w in zip(grads, want):
+        np.testing.assert_allclose(g, w, atol=1e-4)
+
+
+def test_a_causal_choice_is_the_causal_call():
+    """The lower triangle as a choice gives what ``causal=True`` gives."""
+    q, k, v = _qkv(b=1, s=256, h=2)
+    lower = jnp.asarray(np.tril(np.ones((1, 256, 256))), jnp.int8)
+    np.testing.assert_allclose(
+        flash_attention(q, k, v, choice=lower), flash_attention(q, k, v),
+        atol=1e-6)
+
+
+@pytest.mark.parametrize("bad, match", [
+    (dict(q_r=True), "beside a rotated pair is not built"),
+    (dict(dtype=jnp.int32), "a choice is int8"),
+    (dict(dtype=jnp.bool_), "a choice is int8"),
+    (dict(shape=(1, 128, 64)), "a choice is int8"),
+    (dict(shape=(1, 2, 128, 128)), "a choice is int8"),
+])
+def test_choice_refusals(bad, match):
+    """What the kernels do not build is refused by name: a choice beside a
+    rotated pair, a mask of another type, and one a head or of another
+    length."""
+    z = lambda *shape: jnp.zeros(shape, jnp.float32)
+    q = z(1, 128, 2, 16)
+    choice = jnp.ones(bad.get("shape", (1, 128, 128)),
+                      bad.get("dtype", jnp.int8))
+    pair = dict(q_r=z(1, 128, 2, 8), k_r=z(1, 128, 8)) if "q_r" in bad else {}
+    with pytest.raises(ValueError, match=match):
+        flash_attention(q, q, q, choice=choice, **pair)
+
+
+def test_grouped_queries_beside_a_rotated_pair_are_refused_by_name():
+    z = lambda *shape: jnp.zeros(shape, jnp.float32)
+    with pytest.raises(ValueError, match="as many key heads as query heads"):
+        flash_attention(z(1, 128, 4, 16), z(1, 128, 2, 16), z(1, 128, 2, 16),
+                        q_r=z(1, 128, 4, 8), k_r=z(1, 128, 8))
+
+
+def test_a_call_without_a_choice_traces_what_it_traced_before():
+    """A call with a choice counts under ``fwd_choice`` and ``bwd_choice``
+    (same label names); a call without one under ``fwd`` and ``bwd`` with
+    the labels it had, its kernels' arguments hold no choice, and its tile
+    and VMEM estimate are the ones it had."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    q, k, v = _qkv(b=1, s=256, h=2)
+    choice = _choice(1, 256, 0.3)
+    labels = dict(block_q="256", block_k="256", derived="1", d_qk=32, d_v=32)
+    count = lambda suffix: [
+        _trace_count(kernel=kernel + suffix, chains=fa._chains(kernel, 256, 4),
+                     **labels) for kernel in ("fwd", "bwd")]
+    jax.clear_caches()
+    before = count(""), count("_choice")
+    with_choice = jax.make_jaxpr(jax.grad(lambda q, k, v: fa.flash_attention(
+        q, k, v, choice=choice).sum(), (0, 1, 2)))(q, k, v)
+    assert (count(""), count("_choice")) == (
+        before[0], [n + 1 for n in before[1]])
+    without = jax.make_jaxpr(jax.grad(lambda q, k, v: fa.flash_attention(
+        q, k, v).sum(), (0, 1, 2)))(q, k, v)
+    assert (count(""), count("_choice")) == (
+        [n + 1 for n in before[0]], [n + 1 for n in before[1]])
+    assert "i8[" in str(with_choice) and "i8[" not in str(without)
+    for kernel in ("fwd", "bwd"):
+        assert fa._derive_tile(kernel, 16384, 128, 2, True) == \
+            fa._derive_tile(kernel, 16384, 128, 2, True, None, False)
+        args = (kernel, 512, 512, 128, 2, 4096, 16384)
+        assert fa._vmem_bytes(*args) == fa._vmem_bytes(*args, None, False)
+        assert fa._vmem_bytes(*args, None, True) == \
+            fa._vmem_bytes(*args) + 2 * 4096 * 512
