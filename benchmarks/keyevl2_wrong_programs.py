@@ -138,8 +138,10 @@ def main():
                     mantissa_bits=7)))),
             ("no norm a head", both((mixer, "head_rms",
                                      lambda x, weight, eps: x))),
-            ("L_I dropped", both((ops, "index_loss",
-                                  lambda *a: jnp.zeros((), jnp.float32)))),
+            ("L_I dropped", both((
+                ops, "index_loss", lambda q, k, lse, q_i, k_i, w, *rest: (
+                    jnp.zeros((), jnp.float32),
+                    jax.tree.map(jnp.zeros_like, (q_i, k_i, w)))))),
             ("pbar of one head", both((
                 ops, "index_loss", lambda q, k, lse, *rest: right[
                     "index_loss"](q[:, :, :1], k[:, :, :1], lse[:, :, :1],

@@ -22,13 +22,18 @@ them:
    over the halves of ``e`` on both, ``w = x~ W_w J^-1/2 e^-1/2`` (``[d,
    J]``, float32 out), and the scores ``I(t, u) = sum_j w_j(t)
    relu(q^I_j(t) . k^I(u))`` for ``u <= t``, **made and compared in
-   float32** (``ops/dsa.py``, ``index_scores``).
+   float32** (``ops/dsa.py``, ``index_scores``). The three parts are one
+   function of the indexer's four leaves, taken with its pullback
+   (``jax.vjp``) for step 5.
 3. ``dsa_select``: ``S_t``, every ``u <= t`` while ``t < topk`` and from
    there on the ``topk`` causal keys of the largest ``I(t, u)``, equal
    scores to the lower ``u``; handed on as a mask ``[b, s, s]`` int8 made
    once a layer and read by every head (``choose``). Under ``remat`` the
-   mask and nothing else of steps 2 and 3 is kept for the backward pass
-   (``KEPT_CHOICE``): the choice has no gradient, so it is not made twice.
+   mask (``KEPT_CHOICE``: the choice has no gradient, so it is not made
+   twice) and step 5's four gradients (``KEPT_INDEX_GRADS``) are kept for
+   the backward pass and nothing else of steps 2, 3 and 5, **which
+   therefore run once a layer and step**: the recomputed layer holds no
+   indexer, no index scores, no choice and no loss.
 4. ``dsa_core``: ``score_h(t, u) = q_h(t) . k_{h // g}(u) head_dim^-1/2``
    **for ``u`` in ``S_t`` only**, softmax in float32 over ``S_t``, ``o_h =
    sum p v``. Where ``resolve_flash`` says so, ``ops/flash_attention.py``'s
@@ -38,7 +43,18 @@ them:
    (log pbar - log r)``, ``pbar`` the main attention's probabilities
    averaged over the heads (detached), ``r`` the softmax over ``S_t`` of
    ``I`` (``index_loss``). ``L_I`` reaches the indexer's four leaves and
-   nothing else; the language-model loss reaches everything but them.
+   nothing else, on a detached input, so its whole gradient is known here:
+   the loss is made **with its gradients by the three parts** (one kernel
+   call, or ``jax.value_and_grad`` of the plain body), step 2's pullback
+   takes them to the four leaves at once (float32 in the leaves' shapes:
+   2,261,120 numbers, 9 MB a layer at 2,048 wide, 16 index heads of 64),
+   those carry the name ``KEPT_INDEX_GRADS``, and ``ops.with_gradient``
+   ties them to the value: the backward pass scales them by the
+   cotangent. (They are added onto zeros made as the layer begins, and the
+   layer's output waits for them: two of ``jax.lax.optimization_barrier``
+   that change no number and decide where the compiled step keeps the four;
+   the reason is beside them.) The language-model loss reaches everything
+   but the four.
 6. ``dsa_out_proj``: ``out = [o_1 .. o_H] W_o``.
 
 The mixer returns ``(out, L_I)``; ``models.GPT`` hands the layers' sum on
@@ -72,6 +88,9 @@ from horovod_tpu.ops import flash_attention as flash
 # The name (``jax.ad_checkpoint.checkpoint_name``) of the choice: a byte a
 # query and key, kept by ``models.GPT``'s ``remat`` policy.
 KEPT_CHOICE = "dsa_choice"
+# and of ``L_I``'s gradients by the indexer's four leaves, float32 in the
+# leaves' shapes (``d (J e + e + J) + 2 e`` numbers a layer), kept likewise.
+KEPT_INDEX_GRADS = "dsa_index_grads"
 # Rows of the index scores (a sequence's last) sown beside the choice.
 SOWN_ROWS = 128
 
@@ -155,6 +174,7 @@ class SparseAttention(nn.Module):
             "index_k_norm", lambda key, shape: jnp.stack(
                 [jnp.ones(shape[1:]), jnp.zeros(shape[1:])]), (2, e))
         w_w = self.param("index_w", init, (d, j))
+        indexer = (w_qi, w_ki, ki_norm, w_w)
         _count_trace(h, h_kv, hd, j, e, self.topk)
         kernels = flash.resolve_flash(self.use_flash, seq)
 
@@ -163,6 +183,10 @@ class SparseAttention(nn.Module):
         x = x.reshape(-1, seq, d).astype(self.dtype)
         positions = jnp.broadcast_to(positions, lead + (seq,)).reshape(
             -1, seq)
+        # where step 5's four gradients will be kept, made as the layer
+        # begins (see there)
+        x, held = jax.lax.optimization_barrier(
+            (x, jax.tree.map(jnp.zeros_like, indexer)))
         by_head = lambda t, w: jnp.einsum("bsd,dhk->bshk", t,
                                           w.astype(self.dtype))
         scale = 1.0 / np.sqrt(hd)
@@ -172,8 +196,10 @@ class SparseAttention(nn.Module):
                               self.rotary_base)
             k = rotate_halves(head_rms(k, k_norm, self.norm_eps), positions,
                               self.rotary_base)
-        with jax.named_scope("dsa_index"):
-            detached = jax.lax.stop_gradient(x)
+        detached = jax.lax.stop_gradient(x)
+
+        def index_parts(w_qi, w_ki, ki_norm, w_w):
+            """``q^I``, ``k^I`` and ``w`` of the detached input."""
             q_i = rotate_halves(
                 by_head(detached, w_qi).astype(jnp.float32), positions,
                 self.rotary_base).astype(self.dtype)
@@ -183,7 +209,14 @@ class SparseAttention(nn.Module):
                     self.dtype)
             w = jnp.dot(detached, w_w.astype(self.dtype),
                         preferred_element_type=jnp.float32) / np.sqrt(j * e)
-            parts = jax.lax.stop_gradient((q_i, k_i, w))
+            return q_i, k_i, w
+
+        with jax.named_scope("dsa_index"):
+            # of detached leaves: neither the parts nor what their
+            # pullback returns carries a derivative, so no pass
+            # differentiates the pullback again
+            parts, to_leaves = jax.vjp(
+                index_parts, *jax.lax.stop_gradient(indexer))
             scores = (ops.index_scores if kernels
                       else ops.index_scores_plain)(*parts)
         with jax.named_scope("dsa_select"):
@@ -194,11 +227,31 @@ class SparseAttention(nn.Module):
             out, lse = chosen_attention(q, k, v, choice, scale, kernels)
         with jax.named_scope("dsa_target"):
             if kernels:
-                index_loss = ops.index_loss(q, k, lse, q_i, k_i, w, scores,
-                                            choice, scale)
+                kl, by_parts = ops.index_loss(q, k, lse, *parts, scores,
+                                              choice, scale)
             else:
-                index_loss = ops.index_loss_plain(q, k, lse, q_i, k_i, w,
-                                                  choice, scale)
+                kl, by_parts = jax.value_and_grad(
+                    ops.index_loss_plain, argnums=(3, 4, 5))(
+                        q, k, lse, *parts, choice, scale)
+            _pallas.count_trace(
+                "hvt_dsa_index_grads_kept_total",
+                "sparse-attention layers traced whose indexer gradients "
+                "are made in the first pass and named for remat (counted "
+                "per trace, not per execution)")
+            # Nothing reads the four before the backward pass, and left to
+            # itself XLA's scheduler puts every layer's pullback off until
+            # then with its operands alive meanwhile; made on time but
+            # written wherever the heap has room between the layer's large
+            # buffers, eight layers' small long-lived arrays cut it up. So
+            # they are added onto zeros that exist from the layer's first
+            # operation, and the layer's output waits for them: the
+            # compiled step of the published size takes 0.52 GiB less than
+            # with the loss made twice, where either half alone takes 0.5
+            # to 2.3 GiB more (PERF.md, PR 52).
+            kept = checkpoint_name(jax.tree.map(
+                jnp.add, held, to_leaves(by_parts)), KEPT_INDEX_GRADS)
+            out, kept = jax.lax.optimization_barrier((out, kept))
+            index_loss = ops.with_gradient(kl, indexer, kept)
         with jax.named_scope("dsa_out_proj"):
             out = jnp.einsum("bshv,hvd->bsd", out, w_o.astype(self.dtype))
         out = out.reshape(*lead, seq, d)

@@ -528,16 +528,21 @@ class GPT(nn.Module):
             # and the slots' order), which has no gradient and so is not
             # chosen and sorted again; and the keys a sparse-attention
             # mixer's indexer chose (``dsa.KEPT_CHOICE``: a byte a query
-            # and key), for the same reason. No name is in any other
+            # and key), for the same reason; and the gradients of that
+            # indexer's loss by its four leaves (``dsa.KEPT_INDEX_GRADS``:
+            # float32 in the leaves' shapes), which are whole at the end
+            # of the first pass, so the recomputed block runs no indexer,
+            # no index scores and no loss. No name is in any other
             # model's program
-            from horovod_tpu.models.dsa import KEPT_CHOICE
+            from horovod_tpu.models.dsa import KEPT_CHOICE, KEPT_INDEX_GRADS
             from horovod_tpu.models.moe import HELD_CHOICE, HELD_SUM
             from horovod_tpu.ops.gated_delta_rule import KEPT_INVERSE
 
             block = nn.remat(
                 block, static_argnums=(),
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    HELD_SUM, HELD_CHOICE, KEPT_INVERSE, KEPT_CHOICE))
+                    HELD_SUM, HELD_CHOICE, KEPT_INVERSE, KEPT_CHOICE,
+                    KEPT_INDEX_GRADS))
         aux = {}
         for i in range(cfg.n_layers):
             kind = () if cfg.layer_pattern is None else (

@@ -35,9 +35,13 @@ flash kernel never writes a probability, so the kernel makes each
 ``lse`` (one product a head), the tile of ``I`` from the indexer's parts,
 and **beside the value the three gradients**, since ``dL/dI = (r - pbar)``
 on ``S_t`` is there in the same tile: ``dw``, ``dq^I`` and ``dk^I`` leave
-with the loss and the backward pass only scales them (as
-``ops/losses.py``'s chunked loss keeps its gradients). Nothing ``[s, s]``
-wide is written.
+with the loss (as ``ops/losses.py``'s chunked loss keeps its gradients),
+divided by the rows here as the value is: ``index_loss`` returns ``(value,
+gradients of that value)`` and has no derivative itself. The caller owns
+what becomes of them: the mixer pulls them back to its leaves in the same
+pass and ties the result to the value with ``with_gradient``, whose
+backward rule does the one thing left, scaling by the cotangent. Nothing
+``[s, s]`` wide is written.
 
 The mixer takes all of these or none, by the flash kernels' rule
 (``resolve_flash``): there is no second rule here.
@@ -367,37 +371,43 @@ def _loss_call(q, k, lse, q_i, k_i, w, lse_i, choice, *, scale, interpret):
         interpret=interpret, name="hvt_dsa_loss")(*operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
-def _index_loss(q, k, lse, q_i, k_i, w, lse_i, choice, scale):
-    return _index_loss_fwd(q, k, lse, q_i, k_i, w, lse_i, choice, scale)[0]
-
-
-def _index_loss_fwd(q, k, lse, q_i, k_i, w, lse_i, choice, scale):
-    kl, dq, dw, dk = _loss_call(
-        _to_bhsd(q), _to_bhsd(k), lse, _to_bhsd(q_i), k_i, w, lse_i, choice,
-        scale=scale, interpret=_pallas.interpret())
-    n = kl.size
-    grads = (_to_bhsd(dq).astype(q_i.dtype), dk.astype(k_i.dtype),
-             dw.astype(w.dtype))
-    return jnp.sum(kl) / n, (grads, n)
-
-
-def _index_loss_bwd(scale, res, ct):
-    (dq, dk, dw), n = res
-    by = lambda g: (g.astype(jnp.float32) * (ct / n)).astype(g.dtype)
-    return None, None, None, by(dq), by(dk), by(dw), None, None
-
-
-_index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
-
-
 def index_loss(q, k, lse, q_i, k_i, w, scores, choice, scale):
-    """``index_loss_plain`` through the kernel. ``scores`` are
-    ``index_scores`` of the same parts (they give the indexer's
-    log-sum-exp over ``S_t``; no gradient flows through them: the kernel's
-    own gradients are the whole of it)."""
-    lse_i = jax.nn.logsumexp(
-        jnp.where(choice != 0, jax.lax.stop_gradient(scores), -jnp.inf),
-        axis=-1, keepdims=True)
-    return _index_loss(q, k, lse.astype(jnp.float32), q_i, k_i,
-                       w.astype(jnp.float32), lse_i, choice, float(scale))
+    """``(value, (dq_i, dk_i, dw))``: ``index_loss_plain`` through the
+    kernel, and the value's gradients by ``q_i``, ``k_i`` and ``w``, in
+    their types, which the same call makes (``jax.value_and_grad`` of the
+    plain body, ``argnums=(3, 4, 5)``). Every operand is detached, so the
+    pair has no derivative of its own: ``with_gradient`` ties gradients
+    to a value. ``scores`` are ``index_scores`` of the same parts (they
+    give the indexer's log-sum-exp over ``S_t``)."""
+    q, k, lse, q_i, k_i, w, scores = jax.lax.stop_gradient(
+        (q, k, lse, q_i, k_i, w, scores))
+    lse_i = jax.nn.logsumexp(jnp.where(choice != 0, scores, -jnp.inf),
+                             axis=-1, keepdims=True)
+    kl, dq, dw, dk = _loss_call(
+        _to_bhsd(q), _to_bhsd(k), lse.astype(jnp.float32), _to_bhsd(q_i),
+        k_i, w.astype(jnp.float32), lse_i, choice, scale=float(scale),
+        interpret=_pallas.interpret())
+    by_row = lambda g, like: (g / kl.size).astype(like.dtype)
+    return jnp.sum(kl) / kl.size, (
+        by_row(_to_bhsd(dq), q_i), by_row(dk, k_i), by_row(dw, w))
+
+
+@jax.custom_vjp
+def with_gradient(value, leaves, grads):
+    """``value``, with ``grads`` (a tree like ``leaves``) as its gradient
+    by ``leaves`` and no other: for a value whose gradients were made
+    beside it. The backward rule only scales them by the cotangent, and
+    they are all it keeps."""
+    return value
+
+
+def _with_gradient_fwd(value, leaves, grads):
+    return value, grads
+
+
+def _with_gradient_bwd(grads, ct):
+    return None, jax.tree.map(lambda g: (ct * g).astype(g.dtype), grads), None
+
+
+with_gradient.defvjp(_with_gradient_fwd, _with_gradient_bwd)
+

@@ -735,8 +735,8 @@ def test_index_scores_and_indexer_loss_compile_for_v5e(compiled_kernel,
                                                        v5e_devices):
     """16 index heads of 64 on one index key at 16,384 positions: the
     index scores' kernel, and the indexer's loss with its three gradients
-    beside 32 heads on 4 of 128 (one Pallas call each: the backward pass
-    scales what the forward made)."""
+    beside 32 heads on 4 of 128 (one Pallas call each: the value and the
+    gradients leave together)."""
     from horovod_tpu.ops import dsa
 
     like, z = _dsa_like(v5e_devices[0]), DSA_SHAPE
@@ -757,8 +757,7 @@ def test_index_scores_and_indexer_loss_compile_for_v5e(compiled_kernel,
         like(jnp.float32, z["b"], z["s"], z["h"]), q_i, k_i, w,
         like(jnp.float32, z["b"], z["s"], z["s"]),
         like(jnp.int8, z["b"], z["s"], z["s"]))
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(3, 4, 5))).lower(
-        *operands).compile()
+    compiled = jax.jit(loss).lower(*operands).compile()
     text = compiled.as_text()
     assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) == 1
     assert "hvt_dsa_loss" in text

@@ -84,7 +84,9 @@ def test_choice_reads_nothing_above_the_diagonal(parts):
 
 def test_index_loss_kernel_is_the_plain_body_with_its_gradients(parts):
     """The value and the three gradients the kernel makes beside it against
-    autodiff of the plain body; q, k and lse get none."""
+    autodiff of the plain body; q, k and lse get none, and tied to the
+    value (``with_gradient``) the three are ``jax.grad``'s, scaled by the
+    cotangent."""
     scale = D ** -0.5
     scores = dsa.index_scores_plain(parts["q_i"], parts["k_i"], parts["w"])
     choice = dsa.choose_plain(scores, 40)
@@ -92,21 +94,26 @@ def test_index_loss_kernel_is_the_plain_body_with_its_gradients(parts):
                               scale, False)
     plain = lambda q, k, q_i, k_i, w: dsa.index_loss_plain(
         q, k, lse, q_i, k_i, w, choice, scale)
-    kernel = lambda q, k, q_i, k_i, w: dsa.index_loss(
-        q, k, lse, q_i, k_i, w, scores, choice, scale)
+
+    def kernel(q, k, q_i, k_i, w):
+        value, grads = dsa.index_loss(q, k, lse, q_i, k_i, w, scores, choice,
+                                      scale)
+        return 3.0 * dsa.with_gradient(value, (q_i, k_i, w), grads)
+
     args = tuple(parts[n] for n in ("q", "k", "q_i", "k_i", "w"))
     want, want_grads = jax.value_and_grad(plain, argnums=(0, 1, 2, 3, 4))(*args)
     got, got_grads = jax.value_and_grad(kernel, argnums=(0, 1, 2, 3, 4))(*args)
     assert float(want) > 0.01
-    assert float(got) == pytest.approx(float(want), rel=1e-5)
-    assert float(kernel(*args)) == pytest.approx(float(want), rel=1e-5)
+    assert float(got) == pytest.approx(3.0 * float(want), rel=1e-5)
+    assert float(kernel(*args)) == pytest.approx(3.0 * float(want), rel=1e-5)
     for name, g, w in zip(("q", "k", "q_i", "k_i", "w"), got_grads,
                           want_grads):
         if name in ("q", "k"):
             assert not np.any(np.asarray(g)) and not np.any(np.asarray(w))
         else:
             assert float(jnp.linalg.norm(w)) > 0
-            assert float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w)) < 1e-5, name
+            assert float(jnp.linalg.norm(g - 3.0 * w)
+                         / jnp.linalg.norm(3.0 * w)) < 1e-5, name
 
 
 def test_index_loss_is_the_kl_by_hand(parts):

@@ -2,10 +2,14 @@
 ``models.GPT``'s normal path against the plain reference
 (``chipbench/reference/keye_vl2.py``) on seeded weights, at a size where
 most queries choose: forward, both losses, gradients, which leaves each
-loss reaches, the kernels' path against the plain one, the share tied to
-the model, the leaf rule, and that no other configuration's tree moves."""
+loss reaches, the kernels' path against the plain one, what ``remat`` keeps
+of the indexer (its loss and its gradients are made once a layer), the
+share tied to the model, the leaf rule, and that no other configuration's
+tree moves."""
 
+import collections
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -35,7 +39,7 @@ INDEXER = ("index_q", "index_k", "index_k_norm", "index_w")
 @pytest.fixture(scope="module")
 def model():
     tokens = jax.random.randint(jax.random.key(1), (2, SEQ), 0, 128)
-    params = GPT(CFG).init(jax.random.key(0), tokens)["params"]
+    params = jax.jit(GPT(CFG).init)(jax.random.key(0), tokens)["params"]
     # norms and the LayerNorm's bias off their initial ones and zeros
     params = jax.tree_util.tree_map_with_path(
         lambda path, a: a + 0.1 * jax.random.normal(jax.random.key(
@@ -121,6 +125,134 @@ def test_kernel_path_is_the_plain_path(model):
         assert _rel(g, w) < 5e-5, jax.tree_util.keystr(path)
 
 
+# one mixer and one expert layer at half the length: the cases below trace
+# the gradient's program eight times over
+SMALL = dataclasses.replace(CFG, n_layers=2, layer_pattern="SE",
+                            max_seq_len=SEQ // 2)
+
+
+@functools.cache
+def _small_model():
+    tokens = jax.random.randint(jax.random.key(2), (2, SEQ // 2), 0, 128)
+    return jax.jit(GPT(SMALL).init)(jax.random.key(3), tokens)["params"], \
+        tokens
+
+
+def _small_grad(kernels, remat):
+    """``jax.grad`` of ``L_LM + L_I`` of the small model by its tree."""
+    _, tokens = _small_model()
+    cfg = dataclasses.replace(SMALL, use_flash=kernels, remat=remat)
+    return jax.grad(lambda p: sum(_losses(cfg, p, tokens)[:2]))
+
+
+@functools.cache
+def _small_gradient(kernels, remat):
+    return jax.jit(_small_grad(kernels, remat))(_small_model()[0])
+
+
+@functools.cache
+def _small_reference():
+    """The reference's gradient, on the model's own choice and experts."""
+    params, tokens = _small_model()
+    sown = jax.jit(lambda p: _losses(SMALL, p, tokens, sow=True)[2])(params)
+    (_, _), want = reference.loss_and_grad(
+        params, tokens, CONFIG, [sown["block_1"]["moe"]["experts"][0]],
+        [sown["block_0"]["dsa"]["dsa_choice"][0] != 0])
+    return want
+
+
+@pytest.mark.parametrize("remat", [False, True])
+@pytest.mark.parametrize("kernels", [False, True], ids=["plain", "kernels"])
+def test_gradients_are_the_references_with_remat_and_without(kernels, remat):
+    """The indexer's gradients leave the first pass under a name that
+    ``remat`` keeps: with ``remat`` as without, on either path, the whole
+    tree's gradient is the reference's (the indexer's four leaves to the
+    first test's tolerance), and with ``remat`` it is what the same path
+    gives without."""
+    want = _small_reference()
+    got = _small_gradient(kernels, remat)
+    without = _small_gradient(kernels, False)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for (path, g), w, same in zip(jax.tree_util.tree_leaves_with_path(got),
+                                  jax.tree.leaves(want),
+                                  jax.tree.leaves(without)):
+        name = jax.tree_util.keystr(path)
+        indexer = path[-1].key in INDEXER
+        assert float(jnp.linalg.norm(w)) > 0, name
+        assert _rel(g, w) < (2e-5 if indexer or not kernels else 5e-5), name
+        assert _rel(g, same) < 1e-6, name
+
+
+def _calls(jaxpr, counts=None):
+    """Of a jaxpr and every jaxpr inside it: Pallas calls by name, the
+    ``[b, s, s]`` row maxima (the plain indexer loss's ``log_softmax``; the
+    attention's are ``[b, H, s, s]``), and the names applied."""
+    counts = collections.Counter() if counts is None else counts
+    for eqn in jaxpr.eqns:
+        kind = eqn.primitive.name
+        if kind == "pallas_call":
+            counts[eqn.params["name"]] += 1
+            continue
+        if kind == "reduce_max" and eqn.invars[0].aval.ndim == 3 \
+                and eqn.invars[0].aval.shape[1] == eqn.invars[0].aval.shape[2]:
+            counts["row_max"] += 1
+        if kind == "name":
+            counts["named " + eqn.params["name"]] += 1
+        if kind == "top_k":
+            counts["top_k"] += 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else (
+                    value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _calls(inner, counts)
+    return counts
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["plain", "kernels"])
+def test_recomputed_layer_runs_no_indexer_and_no_indexer_loss(kernels):
+    """The gradient's program of a ``remat`` model holds the indexer's loss
+    **once a layer**, as without ``remat`` (the parent's held it twice: a
+    ``custom_vjp``'s forward rule is what the recomputation runs), and so
+    the index scores and the choice; the attention itself runs again."""
+    with_remat, without = (_calls(jax.make_jaxpr(_small_grad(kernels, remat))(
+        _small_model()[0]).jaxpr) for remat in (True, False))
+    once = (("hvt_dsa_loss", "hvt_dsa_index", "hvt_dsa_choice",
+             "hvt_flash_bwd") if kernels else ("row_max", "top_k"))
+    for name in once:
+        assert with_remat[name] == without[name] == (
+            2 if name == "top_k" else 1), name    # (the router's beside it)
+    if kernels:
+        assert (with_remat["hvt_flash_fwd"], without["hvt_flash_fwd"]) == (
+            2, 1)
+
+
+def test_index_gradients_are_named_for_the_policy_and_counted():
+    """``KEPT_INDEX_GRADS`` is on the four leaves' gradients of a traced
+    layer, ``models.GPT``'s ``remat`` policy saves a value of that name,
+    and ``hvt_dsa_index_grads_kept_total`` counts the layer once."""
+    from horovod_tpu.models.dsa import KEPT_CHOICE, KEPT_INDEX_GRADS
+
+    params, tokens = _small_model()
+    family = metrics.counter("hvt_dsa_index_grads_kept_total")  # no label
+    before = family.value
+    jaxpr = jax.make_jaxpr(lambda p: _losses(SMALL, p, tokens)[1])(
+        params).jaxpr
+    assert family.value == before + 1
+    counts = _calls(jaxpr)
+    assert counts["named " + KEPT_INDEX_GRADS] == len(INDEXER)
+    assert counts["named " + KEPT_CHOICE] == 1
+    (block,) = [e for e in jaxpr.eqns if "policy" in e.params
+                and "dsa_index" in str(e.params["jaxpr"])]
+    named = [e for e in block.params["jaxpr"].eqns
+             if e.primitive.name == "name"]
+    for eqn in named:
+        assert block.params["policy"](eqn.primitive, **eqn.params), eqn
+    assert {e.params["name"] for e in named} == {KEPT_CHOICE,
+                                                 KEPT_INDEX_GRADS}
+    assert not block.params["policy"](named[0].primitive, name="another")
+
+
 def test_the_16_shares_of_the_expert_layer_add_up_to_the_uncut_reference():
     """The share tied to the model: an expert layer of 32 experts cut 16
     ways (2 held a chip; the router whole, 4 a token, renormalised): the
@@ -173,11 +305,11 @@ def test_mixer_refuses_what_it_does_not_build():
     for bad in (dict(dsa_index_heads=0), dict(n_kv_heads=3),
                 dict(dsa_topk=0), dict(dsa_index_dim=15)):
         with pytest.raises(ValueError, match="sparse attention needs"):
-            GPT(dataclasses.replace(CFG, **bad)).init(jax.random.key(0),
-                                                      tokens)
+            jax.eval_shape(GPT(dataclasses.replace(CFG, **bad)).init,
+                           jax.random.key(0), tokens)
     with pytest.raises(ValueError, match="attention over chosen keys"):
-        GPT(dataclasses.replace(CFG, layer_pattern="SEXE")).init(
-            jax.random.key(0), tokens)
+        jax.eval_shape(GPT(dataclasses.replace(CFG, layer_pattern="SEXE")).init,
+                       jax.random.key(0), tokens)
 
 
 def test_the_new_fields_default_to_no_such_layer():
@@ -198,6 +330,7 @@ def test_aux_keeps_every_layers_names():
     of both returns both, each summed over its own layers."""
     cfg = dataclasses.replace(CFG, experts_held=None)
     tokens = jax.random.randint(jax.random.key(1), (1, 64), 0, 128)
-    params = GPT(cfg).init(jax.random.key(0), tokens)["params"]
-    _, aux = GPT(cfg).apply({"params": params}, tokens, return_aux=True)
+    params = jax.jit(GPT(cfg).init)(jax.random.key(0), tokens)["params"]
+    _, aux = jax.jit(lambda p: GPT(cfg).apply(
+        {"params": p}, tokens, return_aux=True))(params)
     assert set(aux) == {"dsa_index", "load_balance", "router_z"}
