@@ -13,8 +13,10 @@ name starts with one of ``--scopes``, the ms a step of chip 0's operations
 whose naming part holds ``/<scope>/``, forward / recompute / backward, and
 the ``--top`` labels (``Op.label`` with XLA's name for a fusion) with most
 of it, and how often a step each Pallas kernel runs, by name
-(``kernel_calls_a_step``, whatever its scope). ``while`` events are left
-out: a loop's event spans its body's.
+(``kernel_calls_a_step``, whatever its scope; counted by
+``chipbench/kernel_calls.py``, as the per-layer metrics that read a
+kernel's calls count them). ``while`` events are left out: a loop's event
+spans its body's.
 What section 5 of PERF.md gives "by scope and pass" is this script's.
 
 A builder's script: it decides nothing, and reads no chip.
@@ -30,10 +32,10 @@ sys.path.insert(0, os.getcwd())
 
 
 def by_scope(path, prefixes, top):
-    from chipbench import regions, xplane
+    from chipbench import kernel_calls, regions, xplane
 
     trace, names = xplane.load(path), regions.name_stacks(path)
-    scopes, labels, calls, loops = {}, {}, {}, 0.0
+    scopes, labels, loops = {}, {}, 0.0
     # chip 0's operations inside its window, ms a step, collectives out:
     # what ``regions.region_ms`` sums
     for op, ms in regions._ops_ms(trace):
@@ -43,8 +45,6 @@ def by_scope(path, prefixes, top):
         part, region = regions.naming_part(names.get(op.name, ""))
         # %maximum_bitcast_fusion.3 = ... -> maximum_bitcast_fusion
         what = re.sub(r"[.\d]+$", "", op.name.lstrip("%"))
-        if op.kind == "kernel":
-            calls[what] = calls.get(what, 0) + 1
         for scope in re.findall(r"/(\w+)(?=/)", part):
             if scope.startswith(prefixes):
                 split = scopes.setdefault(scope, {})
@@ -54,9 +54,9 @@ def by_scope(path, prefixes, top):
                 break
     for split in scopes.values():
         split["all"] = sum(split.values())
-    steps = trace.window(trace.devices[0])[2]
-    return {"trace": path, "steps": steps, "while_events_ms": loops,
-            "kernel_calls_a_step": {k: n / steps for k, n in calls.items()},
+    walked = kernel_calls.walk(trace, names)
+    return {"trace": path, "steps": walked.steps, "while_events_ms": loops,
+            "kernel_calls_a_step": kernel_calls.calls_a_step(walked),
             "by_scope": scopes,
             "top": {s: sorted(map(list, found.items()),
                               key=lambda kv: -kv[1])[:top]

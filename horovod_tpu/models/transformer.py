@@ -27,6 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from horovod_tpu.ops import _pallas
+
 
 @dataclasses.dataclass(frozen=True)
 class GPTConfig:
@@ -276,6 +278,64 @@ def held_heads(n_heads, n_kv, held):
     return count, max(1, count // group)
 
 
+def _count_trace(heads, kv_heads, head_dim, core):
+    """One count a traced layer."""
+    _pallas.count_trace(
+        "hvt_attn_layers_traced_total",
+        "attention layers traced into compiled programs, by the path "
+        "their products over positions take: ring, flash or einsum "
+        "(counted per trace, not per execution)",
+        heads=heads, kv_heads=kv_heads, head_dim=head_dim, core=core)
+
+
+def _attend(cfg, q, k, v, positions, core):
+    """The products over positions by the path ``core`` names: the
+    ring schedule, the flash kernels, or two einsums and a softmax."""
+    *_, n_heads, head_dim = q.shape
+    n_kv = k.shape[-2]
+    if core == "ring":
+        from horovod_tpu.parallel.sequence import ring_attention
+
+        # GQA K/V go to the ring UN-repeated: the schedule
+        # circulates the small h_kv buffers over ICI (payload
+        # shrinks by the group factor — the point of GQA at long
+        # context) and broadcasts locally per block (einsum path)
+        # or aliases heads zero-copy in the kernel (flash path).
+        # Exception: a 'tp' mesh axis shards the head dim, and the
+        # small K/V head count may not divide it — repeat up front
+        # there (the pre-r5 behavior) so the sharding stays valid.
+        tp = dict(cfg.ring_mesh.shape).get("tp", 1)
+        if n_kv % tp:
+            k, v = _repeat_kv(k, v, n_heads // n_kv)
+        # "auto" passes through UNRESOLVED: the ring shard function
+        # resolves it against its local (post-shard_map) block
+        # length, where the shape is unambiguous — dividing the
+        # trace-time shape by the mesh factor here would divide
+        # twice when a user invokes the model inside their own
+        # shard_map (ADVICE r4)
+        return ring_attention(q, k, v, mesh=cfg.ring_mesh,
+                              causal=True,
+                              scale=1.0 / np.sqrt(head_dim),
+                              use_flash=cfg.use_flash)
+    if core == "flash":
+        from horovod_tpu.ops.flash_attention import flash_attention
+
+        # the kernel serves GQA zero-copy (K/V head index aliasing)
+        return flash_attention(q, k, v, causal=True,
+                               scale=1.0 / np.sqrt(head_dim))
+    # XLA turns the repeat into a broadcast inside the dot
+    k, v = _repeat_kv(k, v, n_heads // n_kv)
+    scores = jnp.einsum("...qhd,...khd->...hqk", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores / np.sqrt(head_dim)
+    qpos = positions[..., :, None]
+    kpos = positions[..., None, :]
+    causal = (kpos <= qpos)[..., None, :, :]
+    scores = jnp.where(causal, scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+    return jnp.einsum("...hqk,...khd->...qhd", probs, v)
+
+
 class Attention(nn.Module):
     cfg: GPTConfig
 
@@ -295,71 +355,41 @@ class Attention(nn.Module):
         if cfg.qk_norm and cfg.head_norm:
             raise ValueError("qk_norm (over the whole projected width) and "
                              "head_norm (a head) are one or the other")
-        q = dense((n_heads, head_dim * (2 if cfg.attn_gate else 1)), "q")(x)
-        if cfg.attn_gate:
-            q, gate = jnp.split(q, 2, axis=-1)
-        k = dense((n_kv, head_dim), "k")(x)
-        v = dense((n_kv, head_dim), "v")(x)
+        core = ("ring" if cfg.ring_mesh is not None
+                else "flash" if _resolve_flash(cfg.use_flash, x.shape[-2])
+                else "einsum")
+        _count_trace(n_heads, n_kv, head_dim, core)
+        with jax.named_scope("attn_proj"):
+            q = dense((n_heads, head_dim * (2 if cfg.attn_gate else 1)),
+                      "q")(x)
+            if cfg.attn_gate:
+                q, gate = jnp.split(q, 2, axis=-1)
+            k = dense((n_kv, head_dim), "k")(x)
+            v = dense((n_kv, head_dim), "v")(x)
         if cfg.qk_norm:
             full_width = lambda t, name: RMSNorm(cfg.norm_eps, name=name)(
                 t.reshape(*t.shape[:-2], -1)).reshape(t.shape)
-            q, k = full_width(q, "q_norm"), full_width(k, "k_norm")
+            with jax.named_scope("attn_norm"):
+                q, k = full_width(q, "q_norm"), full_width(k, "k_norm")
         if cfg.head_norm:
-            q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
+            with jax.named_scope("attn_norm"):
+                q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
         if cfg.rotary:
             turned = (None if cfg.rotary_fraction == 1.0
                       else int(cfg.rotary_fraction * head_dim))
-            q = _rotary(q, positions, cfg.rotary_base, turned)
-            k = _rotary(k, positions, cfg.rotary_base, turned)
-
-        if cfg.ring_mesh is not None:
-            from horovod_tpu.parallel.sequence import ring_attention
-
-            # GQA K/V go to the ring UN-repeated: the schedule
-            # circulates the small h_kv buffers over ICI (payload
-            # shrinks by the group factor — the point of GQA at long
-            # context) and broadcasts locally per block (einsum path)
-            # or aliases heads zero-copy in the kernel (flash path).
-            # Exception: a 'tp' mesh axis shards the head dim, and the
-            # small K/V head count may not divide it — repeat up front
-            # there (the pre-r5 behavior) so the sharding stays valid.
-            tp = dict(cfg.ring_mesh.shape).get("tp", 1)
-            if n_kv % tp:
-                k, v = _repeat_kv(k, v, n_heads // n_kv)
-            # "auto" passes through UNRESOLVED: the ring shard function
-            # resolves it against its local (post-shard_map) block
-            # length, where the shape is unambiguous — dividing the
-            # trace-time shape by the mesh factor here would divide
-            # twice when a user invokes the model inside their own
-            # shard_map (ADVICE r4)
-            out = ring_attention(q, k, v, mesh=cfg.ring_mesh,
-                                 causal=True,
-                                 scale=1.0 / np.sqrt(head_dim),
-                                 use_flash=cfg.use_flash)
-        elif _resolve_flash(cfg.use_flash, q.shape[-3]):
-            from horovod_tpu.ops.flash_attention import flash_attention
-
-            # the kernel serves GQA zero-copy (K/V head index aliasing)
-            out = flash_attention(q, k, v, causal=True,
-                                  scale=1.0 / np.sqrt(head_dim))
-        else:
-            # XLA turns the repeat into a broadcast inside the dot
-            k, v = _repeat_kv(k, v, n_heads // n_kv)
-            scores = jnp.einsum("...qhd,...khd->...hqk", q, k,
-                                preferred_element_type=jnp.float32)
-            scores = scores / np.sqrt(head_dim)
-            qpos = positions[..., :, None]
-            kpos = positions[..., None, :]
-            causal = (kpos <= qpos)[..., None, :, :]
-            scores = jnp.where(causal, scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            out = jnp.einsum("...hqk,...khd->...qhd", probs, v)
+            with jax.named_scope("attn_rope"):
+                q = _rotary(q, positions, cfg.rotary_base, turned)
+                k = _rotary(k, positions, cfg.rotary_base, turned)
+        with jax.named_scope("attn_core"):
+            out = _attend(cfg, q, k, v, positions, core)
         if cfg.attn_gate:
-            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
-                gate.astype(jnp.float32))).astype(cfg.dtype)
-        return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False,
-                               dtype=cfg.dtype, param_dtype=jnp.float32,
-                               name="o")(out)
+            with jax.named_scope("attn_gate"):
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(cfg.dtype)
+        with jax.named_scope("attn_out_proj"):
+            return nn.DenseGeneral(cfg.d_model, axis=(-2, -1),
+                                   use_bias=False, dtype=cfg.dtype,
+                                   param_dtype=jnp.float32, name="o")(out)
 
 
 class MLP(nn.Module):
