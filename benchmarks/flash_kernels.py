@@ -41,7 +41,16 @@ against FILE's first tile: ``--against _parent/horovod_tpu/ops/
 flash_attention.py`` puts the parent beside the change, and 0.0 says the
 change gives ``o`` and every gradient to the last bit. ``--shape`` takes
 several shapes, separated by ``+``, and ``/E`` after one is its own
-``--rotated``.
+``--rotated``. Since PR 54 every line also says the same result by
+result (``rel_l2_by_result``: ``o``, ``dq``, ``dk``, ``dv``, or with a
+rotated pair ``o``, ``dq_n``, ``dq_r``, ``dk_n``, ``dk_r``, ``dv``), so a
+change of the backward kernel alone shows which gradient moved;
+``--source`` takes several files joined by ``,`` (variants of one kernel
+beside the parent in one process); and ``/choice`` after a shape hands
+the kernels a choice of keys (``keyevl2-s16384``'s masked calls: int8
+``[b, s, s]``, the 1,024 keys before a query and every sixteenth of the
+causal rest, made on the device; the kernels walk every causal tile
+whatever the mask holds): ``--shape 1,16384,32,4,128/choice``.
 
 A microbenchmark, not the yardstick: the cell that decides is
 ``gpt2l-s4096`` of ``BENCHMARK.json``. It refuses to run without a TPU.
@@ -103,6 +112,28 @@ def einsum_attention(q, k, v, causal):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def make_choice(b, s):
+    """A choice of keys ``[b, s, s]`` int8 for the masked calls: the 1,024
+    keys before a query and a sixteenth of the causal rest, so every row
+    sees a key and no causal tile is empty, as no tile of an indexer's
+    choice is."""
+    import jax
+    import jax.numpy as jnp
+
+    def mask():
+        t = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
+        u = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
+        some = (u * 7 + t * 13) % 16 == 0
+        keep = (u <= t) & ((t - u < 1024) | some)
+        return jnp.broadcast_to(keep.astype(jnp.int8), (b, s, s))
+
+    return jax.jit(mask)()
+
+
+RESULTS = {4: ("o", "dq", "dk", "dv"),
+           6: ("o", "dq_n", "dq_r", "dk_n", "dk_r", "dv")}
+
+
 def whole_width(attend):
     """``attend(q, k, v)`` as a function of the four parts: the query and
     the key assembled, the one rotated key a position broadcast over the
@@ -111,9 +142,9 @@ def whole_width(attend):
 
     from horovod_tpu.models.mla import whole_key
 
-    def of_parts(q_n, q_r, k_n, k_r, v):
+    def of_parts(q_n, q_r, k_n, k_r, v, **choice):
         return attend(jnp.concatenate([q_n, q_r], -1), whole_key(k_n, k_r),
-                      v)
+                      v, **choice)
 
     return of_parts
 
@@ -121,8 +152,10 @@ def whole_width(attend):
 def call_and_vjp(attend):
     import jax
 
-    def run(operands, do):
-        o, vjp = jax.vjp(attend, *operands)
+    def run(operands, do, chosen=None):
+        # a choice is an operand of the jitted call and no constant of it
+        choice = {} if chosen is None else {"choice": chosen}
+        o, vjp = jax.vjp(lambda *parts: attend(*parts, **choice), *operands)
         return (o, *vjp(do))
 
     return jax.jit(run)
@@ -151,7 +184,7 @@ def kernel_durations(trace_dir):
 
 
 def measure(flash, shape, tiles, causal=True, iters=5, einsum=False,
-            rotated=0, first=None):
+            rotated=0, first=None, choice=False):
     """One dict a tile (with a rotated width two, one an entry; and one
     for the einsum path), as the module docstring describes, and what
     they were compared with: ``first``, or the first tile's results."""
@@ -161,24 +194,26 @@ def measure(flash, shape, tiles, causal=True, iters=5, einsum=False,
     from chip_smoke import rel_l2
 
     args = make_inputs(shape, rotated)
+    if choice:
+        args += (make_choice(shape[0], shape[1]),)
     derive = flash._derive_tile
     runs = []
     for tile in tiles:
         blocks = (dict(zip(("block_q", "block_k"), tile))
                   if isinstance(tile, tuple) else {})
-        whole = lambda q, k, v, blocks=blocks: flash.flash_attention(
-            q, k, v, causal=causal, **blocks)
+        whole = lambda q, k, v, blocks=blocks, **chosen: (
+            flash.flash_attention(q, k, v, causal=causal, **blocks, **chosen))
         if not rotated:
             runs.append((tile, None, call_and_vjp(whole)))
             continue
         runs.append((tile, "whole", call_and_vjp(whole_width(whole))))
         if "q_r" in inspect.signature(flash.flash_attention).parameters:
             runs.append((tile, "parts", call_and_vjp(
-                lambda q_n, q_r, k_n, k_r, v, blocks=blocks:
+                lambda q_n, q_r, k_n, k_r, v, blocks=blocks, **chosen:
                 flash.flash_attention(q_n, k_n, v, q_r=q_r, k_r=k_r,
-                                      causal=causal, **blocks))))
+                                      causal=causal, **blocks, **chosen))))
     if einsum:
-        plain = lambda q, k, v: einsum_attention(q, k, v, causal)
+        plain = lambda q, k, v, **none: einsum_attention(q, k, v, causal)
         runs.append(("einsum", None, call_and_vjp(
             whole_width(plain) if rotated else plain)))
     lines = []
@@ -186,6 +221,8 @@ def measure(flash, shape, tiles, causal=True, iters=5, einsum=False,
         line = {"tile": tile, "shape": list(shape), "causal": causal}
         if entry:
             line.update(entry=entry, rotated=rotated)
+        if choice:
+            line["choice"] = True
         lines.append(line)
         if isinstance(tile, str) and "=" in tile:
             # traced here, once: the rule is read outside the jitted call
@@ -201,6 +238,9 @@ def measure(flash, shape, tiles, causal=True, iters=5, einsum=False,
             flash._derive_tile = derive
         first = got if first is None else first
         line["rel_l2_vs_first"] = rel_l2(got, first)
+        line["rel_l2_by_result"] = {
+            name: rel_l2(mine, theirs)
+            for name, mine, theirs in zip(RESULTS[len(got)], got, first)}
     ran = [(line, fn) for line, (*_, fn) in zip(lines, runs)
            if "refused" not in line]
     with tempfile.TemporaryDirectory() as trace_dir:
@@ -250,17 +290,19 @@ def main(argv=None):
         raise SystemExit("flash_kernels.py times kernels on a TPU; found "
                          f"{jax.default_backend()}")
     tiles = [parse_tile(t) for t in a.blocks.split(",")]
-    sources = ([a.against] if a.against else []) + [a.source]
+    sources = ([a.against] if a.against else []) + (
+        a.source.split(",") if a.source else [None])
     for shape in a.shape.split("+"):
         shape, _, own = shape.partition("/")
-        rotated = int(own) if own else a.rotated
+        choice = own == "choice"
+        rotated = a.rotated if choice or not own else int(own)
         first = None
         for source in sources:
             flash = load_flash(source)
             lines, first = measure(
                 flash, tuple(int(x) for x in shape.split(",")), tiles,
                 causal=not a.no_causal, iters=a.iters, einsum=a.einsum,
-                rotated=rotated, first=first)
+                rotated=rotated, first=first, choice=choice)
             for line in lines:
                 line["source"] = os.path.relpath(flash.__file__)
                 line["device"] = jax.devices()[0].device_kind

@@ -17,7 +17,16 @@ the result is the one-chain pass's to the last bit.
 Backward pass recomputes score tiles (FLOPs-for-HBM trade, the same choice
 ``jax.checkpoint`` makes) from the saved logsumexp in one kernel gridded
 over K/V blocks: each tile is made once and gives its share of dQ, dK and
-dV (five products).
+dV (five products). A pass of its loop is bound by the MXUs as it stands
+(each product streams the sub-block's rows through them once), so the
+loop does no less work by another order, only by doing none that is
+wasted: it carries nothing (dK's and dV's sums are added to in their VMEM
+scratch, as dQ's are), and where a causal K block begins past the first
+half of a query sub-block, that pass takes the second half alone
+(``_chains``); and the grid steps of a causal K block that come before
+the tile it begins in, which compute nothing, fetch nothing either (their
+block index is that tile's). The gradients are the parent kernel's to the
+last bit.
 
 A caller whose queries and keys come in two parts (latent attention: 128
 columns a head from one projection, 64 rotated ones from another, **the
@@ -147,6 +156,27 @@ def resolve_flash(use_flash, local_seq) -> bool:
 # values were copied at the top and the bottom of every pass with no
 # product in flight. m and l hold a row's value in every lane, so the
 # maximum, the correction and the sum are whole-register operations.
+#
+# What a pass of the backward's loop does (v5e, PERF.md section 6, PR 54).
+# Five products (seven with a rotated pair) on a 1024 x 512 sub-block, and
+# each streams its 1,024 rows through one of the four MXUs once: by the
+# compiler's schedule 92% of a pass's bundles have an MXU busy, the exp, the
+# mask, dS and the casts ride under them, and two chains of query rows only
+# push k and v (the stationary operands of three of the products) a second
+# time. What was not MXU work was (1) the loop's carried dK, dV and dk_r,
+# 128 to 160 registers' worth copied, spilled and filled at the two ends of
+# every pass and read from and written back to their scratch around the
+# loop (150 to 530 bundles a pass, 550 to 950 a grid step): the sums are
+# now added to in the scratch, as dQ's always were; and (2) the rows that
+# see nothing: a K block of 512 that begins in the middle of a sub-block of
+# 1,024 leaves the first half without a visible key, so that pass (one a K
+# block in two) takes the second half alone. p is exactly 0 where nothing
+# is visible and the sums' order is unchanged, so every gradient is the
+# parent's to the last bit. Around the loop, (3): a causal K block's grid
+# steps before the tile it begins in computed nothing and still fetched
+# their tile of q, dO, lse and delta (and a choice's rows), 10 us a step
+# that no computation hid: a quarter of the steps at 8,192 positions, three
+# eighths at 16,384 (``_bwd_call``'s ``seen``).
 
 def _scaled(x, scale):
     """``(x', rest)`` with ``x' @ y * rest == x @ y * scale``: a power of
@@ -280,7 +310,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
 
 
 def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *refs,
-                scale, causal, block_q, choice=False):
+                scale, causal, block_q, chains, choice=False):
     # with a rotated pair each group of refs (in, out, scratch) has two
     # more at its end: k_r's block and q_r's tile, dq_r and a head's dk_r,
     # their accumulators; a choice (never beside a pair) is one more
@@ -327,56 +357,60 @@ def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *refs,
             kr = kr_ref[0]                            # [block_k, e]
             kr_sc, _ = _scaled(kr, scale)
             kr = kr.astype(jnp.float32)
-        n_sub = tile // block_q
 
-        def body(i, carry):
-            dk, dv, *dkr = carry
-            rows = _sub_block(i, block_q)
-            q = q_ref[0, 0, rows, :]
-            do = do_ref[0, 0, rows, :].astype(jnp.float32)
-            lse = lse_ref[0, 0, rows, :]              # [block_q, 1]
-            delta = delta_ref[0, 0, rows, :]
-            sc = dot(q, k_sc, NT)                     # [bq, bk]
+        def sub_block(j, rows):
+            """The ``j``-th ``rows`` query rows of the tile against the
+            block's keys: a pass. Its sums are added to where they live,
+            dK's and dV's in the block's scratch, dQ's in its rows of the
+            sequence's: the loop around it carries nothing."""
+            mine = _sub_block(j, rows)
+            in_seq = _sub_block(ti * (tile // rows) + j, rows)
+            q = q_ref[0, 0, mine, :]
+            do = do_ref[0, 0, mine, :].astype(jnp.float32)
+            lse = lse_ref[0, 0, mine, :]              # [rows, 1]
+            delta = delta_ref[0, 0, mine, :]
+            sc = dot(q, k_sc, NT)                     # [rows, bk]
             if rotated:
-                qr = qr_ref[0, 0, rows, :]
+                qr = qr_ref[0, 0, mine, :]
                 sc = sc + dot(qr, kr_sc, NT)
             if rest is not None:
                 sc = sc * rest
             if choice:
-                sc = jnp.where(_chosen(choice_ref[0, rows, :]), sc,
+                sc = jnp.where(_chosen(choice_ref[0, mine, :]), sc,
                                -jnp.inf)
             elif causal:
                 sc = jnp.where(
-                    _visible(ti * tile + i * block_q, ki * block_k,
-                             sc.shape), sc, _NEG_INF)
+                    _visible(ti * tile + j * rows, ki * block_k, sc.shape),
+                    sc, _NEG_INF)
             p = jnp.exp(sc - lse)
-            dv_new = dv + dot(p, do, TN)
+            dv_acc_ref[...] += dot(p, do, TN)
             dp = dot(do, v, NT)
             ds = p * (dp - delta)
-            dk_new = dk + dot(ds, q.astype(jnp.float32), TN)
-            dq_acc_ref[_sub_block(ti * n_sub + i, block_q), :] += dot(
-                ds, k, NN)
-            if not rotated:
-                return dk_new, dv_new
-            dqr_acc_ref[_sub_block(ti * n_sub + i, block_q), :] += dot(
-                ds, kr, NN)
-            return dk_new, dv_new, dkr[0] + dot(
-                ds, qr.astype(jnp.float32), TN)
+            dk_acc_ref[...] += dot(ds, q.astype(jnp.float32), TN)
+            dq_acc_ref[in_seq, :] += dot(ds, k, NN)
+            if rotated:
+                dqr_acc_ref[in_seq, :] += dot(ds, kr, NN)
+                dkr_acc_ref[...] += dot(ds, qr.astype(jnp.float32), TN)
 
+        def whole(i, carry):
+            sub_block(i, block_q)
+            return carry
+
+        n_sub = tile // block_q
         if causal:
-            # Q sub-blocks strictly before this K block see nothing
-            start = jnp.clip((ki * block_k - ti * tile) // block_q,
-                             0, n_sub)
+            # query rows strictly before this K block see nothing
+            piece = block_q // chains
+            start = first = jnp.clip((ki * block_k - ti * tile) // piece,
+                                     0, chains * n_sub)
+            if chains == 2:
+                # the K block may begin in the middle of a sub-block, whose
+                # first half then sees none of its keys: that pass takes
+                # the second half alone (the first half's p is 0, adds 0)
+                start = (first + 1) // 2
+                pl.when(first % 2 == 1)(lambda: sub_block(first, piece))
         else:
             start = 0
-        carry = (dk_acc_ref[...], dv_acc_ref[...])
-        if rotated:
-            carry += (dkr_acc_ref[...],)
-        dk, dv, *dkr = jax.lax.fori_loop(start, n_sub, body, carry)
-        dk_acc_ref[...] = dk
-        dv_acc_ref[...] = dv
-        if rotated:
-            dkr_acc_ref[...], = dkr
+        jax.lax.fori_loop(start, n_sub, whole, 0)
 
     if causal:
         # tiles whose every Q position precedes this K block are skipped
@@ -454,7 +488,12 @@ def _vmem_bytes(kernel, block_q, block_k, d, itemsize, tile, s, d_v=None,
     takes for the backward alone (v5e, bf16, 20 heads of 64): 19.0 MiB
     at 2 x 4096 and 1024 x 512 (estimate 21.5), 26.25 at 1024 x 1024
     (27.0), 21.0 at 1 x 8192 (23.5), 8.0 at 8 x 1024 and 512 x 512
-    (7.5), 13.0 at 4 x 2048 (13.5)."""
+    (7.5), 13.0 at 4 x 2048 (13.5). Since the backward's loop carries
+    nothing (PR 54) the compiler takes 1.25 to 2.75 MiB less than it did
+    (17.5 at 2 x 4096, 6.75 at 8 x 1024; 32.5 for 34.5 at 128 + 64 on 128,
+    35.25 for 38.0 at d = 256, 31.0 for 32.5 with a choice at 16,384): the
+    carried dK and dV were copies beside their scratch, which no term
+    here ever counted, so the estimate stands and its margin is wider."""
     lanes = -(-d // _LANES) * _LANES
     lanes_v = lanes if d_v is None else -(-d_v // _LANES) * _LANES
     row, row_v = lanes * itemsize, lanes_v * itemsize
@@ -565,9 +604,10 @@ def _count_trace(kernel, block_q, block_k, derived, d, d_v, d_rot, chains):
     """Which score tile each traced kernel got, whether the rule or the
     caller chose it, the two widths it was built for (q and k's whole
     width, v and o's), how many of q and k's columns came as a rotated
-    pair of their own (0: q and k came whole) and how many chains of query
-    rows a pass of its loop runs side by side (``_chains``; the backward
-    runs one)."""
+    pair of their own (0: q and k came whole) and into how many pieces by
+    query rows a pass of its loop takes its sub-block (``_chains``: the
+    forward's two chains side by side; the backward's two halves, of which
+    a causal pass leaves out the one that sees nothing)."""
     _pallas.count_trace(
         "hvt_flash_kernel_traces_total",
         "flash-attention kernels traced into compiled programs, by "
@@ -626,16 +666,24 @@ def _rotated_width(rotated):
     return 0 if rotated is None else rotated[0].shape[-1]
 
 
-def _chains(kernel, block_q, itemsize):
-    """How many independent chains of query rows one pass of ``kernel``'s
-    loop runs: the forward's sub-block as two halves wherever a half is
-    whole sublane tiles of the operands (16 rows of bf16, 8 of float32),
-    so that neither q's half nor its rows of the scratch is cut inside a
-    tile; a block that does not halve so (the few rows a sequence that is
-    no multiple of 128 is clipped to) goes through as the one chain it is.
-    The backward keeps its K block whole: one."""
-    packed_rows = _SUBLANES * 4 // itemsize
-    return 2 if kernel == "fwd" and block_q % (2 * packed_rows) == 0 else 1
+def _chains(kernel, block_q, block_k, itemsize, causal):
+    """Into how many pieces by query rows a pass of ``kernel``'s loop takes
+    its sub-block: two halves wherever a half is whole sublane tiles of
+    the operands (16 rows of bf16, 8 of float32), so that neither q's half
+    nor its rows of a scratch is cut inside a tile, and the kernel has a
+    use for them. The forward always has: it runs the halves as two
+    independent chains side by side. The backward keeps its K block whole
+    and its pass is bound by the MXUs, so two chains only push k and v
+    twice (PERF.md section 6, PR 54); it tells the halves apart where a K
+    block can begin past the first half of a sub-block (a causal call
+    whose key block is shorter than the sub-block: the derived 1024 x
+    512), and takes that one pass by the half that sees the block.
+    Everything else goes through as the one piece it is: the few rows a
+    sequence that is no multiple of 128 is clipped to, a backward that is
+    not causal or whose sub-blocks begin where its K blocks do."""
+    if block_q % (2 * _SUBLANES * 4 // itemsize):
+        return 1
+    return 2 if kernel == "fwd" or (causal and block_k % block_q) else 1
 
 
 class _Plan(NamedTuple):
@@ -646,7 +694,7 @@ class _Plan(NamedTuple):
     block_k: int
     derived: bool       # the rule chose the tile, not the caller
     tile: int           # positions of the streamed operand a grid step
-    chains: int         # query-row pieces of a sub-block a pass interleaves
+    chains: int         # query-row pieces of a sub-block (``_chains``)
     interpret: bool
 
 
@@ -663,7 +711,7 @@ def _plan(kernel, q, scale, causal, block_q, block_k, d_v=None, d_rot=0,
         d_v, choice)
     return _Plan(scale, causal, block_q, block_k, derived,
                  _seq_tile(s, block_q, block_k),
-                 _chains(kernel, block_q, q.dtype.itemsize),
+                 _chains(kernel, block_q, block_k, q.dtype.itemsize, causal),
                  _pallas.interpret())
 
 
@@ -760,7 +808,9 @@ def _bwd_call(q, k, v, do, lse, delta, rotated=None, choice=None, *, plan):
     dq accumulates over the K blocks in a float32 VMEM scratch of the
     whole sequence and leaves a tile at a time while the last K block
     passes: until then its block index stays where it is, so nothing is
-    written back. Under GQA the kernel reads the shared K/V head
+    written back. A causal K block's steps before the tile it begins in
+    compute nothing and name that tile, so nothing is fetched for them.
+    Under GQA the kernel reads the shared K/V head
     zero-copy via the index map but emits per-QUERY-head dk/dv (full h),
     which are then group-summed — each K/V head's gradient is the sum
     over its query group. A shared rotated key's gradient goes the same
@@ -780,8 +830,16 @@ def _bwd_call(q, k, v, do, lse, delta, rotated=None, choice=None, *, plan):
         lambda bi, hi, ki, ti: (bi, hi // group, ki, 0))
     dkv_out_ki = lambda width: pl.BlockSpec(
         (1, 1, block_k, width), lambda bi, hi, ki, ti: (bi, hi, ki, 0))
+    # A causal K block sees nothing of the tiles before the one it begins
+    # in: those grid steps compute nothing, and with the tile's own index
+    # they would still fetch it (q, dO, lse, delta and a choice's rows,
+    # about 10 us a step that nothing hides: PERF.md section 6, PR 54). So
+    # they name the first tile the block does see, which the pipeline
+    # fetches once and keeps until the grid has passed it.
+    seen = lambda ki, ti: (jnp.maximum(ti, ki * block_k // tile)
+                           if plan.causal else ti)
     q_tile = lambda width: pl.BlockSpec(
-        (1, 1, tile, width), lambda bi, hi, ki, ti: (bi, hi, ti, 0))
+        (1, 1, tile, width), lambda bi, hi, ki, ti: (bi, hi, seen(ki, ti), 0))
     dq_tile = lambda width: pl.BlockSpec(
         (1, 1, tile, width),
         lambda bi, hi, ki, ti: (bi, hi, jnp.where(ki == n_k - 1, ti, 0), 0))
@@ -810,11 +868,12 @@ def _bwd_call(q, k, v, do, lse, delta, rotated=None, choice=None, *, plan):
         # the tile's rows of the mask against the block's keys
         inputs += (choice,)
         in_specs += [pl.BlockSpec(
-            (1, tile, block_k), lambda bi, hi, ki, ti: (bi, ti, ki))]
+            (1, tile, block_k),
+            lambda bi, hi, ki, ti: (bi, seen(ki, ti), ki))]
     dq, dk, dv, *d_rotated = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=plan.scale,
                           causal=plan.causal, block_q=block_q,
-                          choice=chosen),
+                          chains=plan.chains, choice=chosen),
         grid=(b, h, n_k, s // tile),
         in_specs=in_specs,
         out_specs=out_specs,

@@ -683,6 +683,51 @@ def test_gdn_layers_hold_the_norm_kernels_and_stay_flat(
 
 # ---- attention over the keys an indexer chooses (keyevl2-s16384)
 
+# (batch, seq, heads, kv_heads, d, d_v), the rotated pair's width, a choice,
+# and how far under its estimate the backward alone still compiles: since
+# PR 54 its loop carries nothing, and the compiler's least scope fell by
+# 1.25 to 2.75 MiB (the carried dK, dV and dk_r were copies in VMEM beside
+# their scratch). The parent's kernel needed 34.5, 38.0, 19.0 and 32.5 MiB
+# at these four, this one 32.5, 35.25, 17.5 and 31.0 (compiler, PR 54)
+@pytest.mark.parametrize("shape, e, choice, under", [
+    pytest.param((2, 8192, 32, 32, 192, 128), 64, False, 1.5,
+                 id="kanana2-s8192"),
+    pytest.param((2, 8192, 16, 2, 256, 256), 0, False, 1.5,
+                 id="qwen3next-s8192"),
+    pytest.param((2, 4096, 20, 20, 64, 64), 0, False, 3.5,
+                 id="gpt2l-s4096"),
+    pytest.param((1, 16384, 32, 4, 128, 128), 0, True, 0.25,
+                 id="keyevl2-s16384"),
+])
+def test_backward_alone_compiles_under_its_vmem_estimate(
+        shape, e, choice, under, compiled_kernel, v5e_devices, monkeypatch):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, h, h_kv, d, d_v = shape
+    one = SingleDeviceSharding(v5e_devices[0])
+    like = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one)
+    q, do = like(b, h, s, d - e), like(b, h, s, d_v)
+    k, v = like(b, h_kv, s, d - e), like(b, h_kv, s, d_v)
+    lse = like(b, h, s, 1, dtype=jnp.float32)
+    rotated = (like(b, h, s, e), like(b, s, e)) if e else None
+    chosen = like(b, s, s, dtype=jnp.int8) if choice else None
+    plan = fa._plan("bwd", q, d ** -0.5, True, None, None, d_v, e, choice)
+    assert (plan.block_q, plan.block_k, plan.chains) == (1024, 512, 2)
+    estimate = fa._vmem_bytes("bwd", 1024, 512, d, 2, plan.tile, s, d_v,
+                              choice)
+    limit = estimate - int(under * 2 ** 20)
+    monkeypatch.setattr(fa, "_compiler_params", lambda *a: (
+        pltpu.CompilerParams(vmem_limit_bytes=limit)))
+    jax.clear_caches()
+    try:
+        text = fa._bwd_call.lower(q, k, v, do, lse, lse, rotated, chosen,
+                                  plan=plan).compile().as_text()
+    finally:
+        jax.clear_caches()
+    assert "hvt_flash_bwd" in text
+
+
 DSA_SHAPE = dict(b=1, s=16384, h=32, h_kv=4, d=128, j=16, e=64, topk=2048)
 
 

@@ -510,11 +510,12 @@ def _trace_count(**labels):
     from horovod_tpu import metrics
 
     labels.setdefault("d_rot", "0")     # q and k came whole
-    # the backward runs one chain of query rows, the forward two wherever
-    # its block halves on whole sublane tiles (``_chains``)
+    # the pieces by query rows a pass takes its sub-block in (``_chains``),
+    # for float32 operands and a causal call
     from horovod_tpu.ops import flash_attention as fa
     labels.setdefault("chains", fa._chains(
-        labels["kernel"], int(labels["block_q"]), 4))
+        labels["kernel"].removesuffix("_choice"), int(labels["block_q"]),
+        int(labels["block_k"]), 4, True))
     m = metrics.registry().get("hvt_flash_kernel_traces_total")
     return m.labels(**labels).value if m else 0.0
 
@@ -554,14 +555,23 @@ def test_trace_counter_carries_the_tile_and_who_chose_it():
     trace()
     assert counts(True) == [n + 1 for n in derived]
     assert counts(False) == [n + 1 for n in explicit]
-    # and how many chains of query rows each traced kernel runs: a forward
-    # block of 32 float32 rows halves on whole sublane tiles, the backward
-    # runs one whatever its block
+    # and in how many pieces by query rows each traced kernel takes its
+    # sub-block: a forward block of 32 float32 rows halves on whole sublane
+    # tiles; the backward's sub-blocks of 32 begin where its K blocks of
+    # 64 do, so it has no use for the halves
     tile = dict(block_q="32", block_k="64", derived="0",
                 d_qk=q.shape[-1], d_v=v.shape[-1])
     assert [_trace_count(kernel=kern, chains=n, **tile)
             for kern, n in (("fwd", 2), ("fwd", 1), ("bwd", 1), ("bwd", 2))
             ] == [explicit[0] + 1, 0, explicit[1] + 1, 0]
+    # a key block shorter than the sub-block: the backward says that it
+    # tells the sub-block's halves apart (PR 54)
+    tile.update(block_q="128", block_k="64")
+    halves = lambda: [_trace_count(kernel=kern, chains=n, **tile)
+                      for kern, n in (("fwd", 2), ("bwd", 2), ("bwd", 1))]
+    before = halves()
+    trace(block_q=128, block_k=64)
+    assert halves() == [before[0] + 1, before[1] + 1, before[2]]
 
 
 def test_trace_counter_adds_nothing_to_the_program(monkeypatch):
@@ -917,22 +927,32 @@ def test_trace_counter_says_how_wide_a_rotated_pair_was():
             for n in (2, 1)] == [before[0][0] + 1, 0]
 
 
-# (kernel, block_q, itemsize) -> chains: the forward's block in two halves
-# wherever a half is whole sublane tiles of the operands (16 rows of bf16,
-# 8 of float32), one chain below that and in the backward
-@pytest.mark.parametrize("kernel, block_q, itemsize, want", [
-    ("fwd", 1024, 2, 2), ("fwd", 512, 2, 2), ("fwd", 32, 2, 2),
-    ("fwd", 16, 2, 1), ("fwd", 16, 4, 2), ("fwd", 8, 4, 1),
-    ("fwd", 100, 4, 1), ("fwd", 96, 4, 2), ("bwd", 1024, 2, 1),
-    ("bwd", 512, 4, 1)])
-def test_chains_go_by_the_block_and_the_operands_itemsize(
-        kernel, block_q, itemsize, want):
+# (kernel, block_q, block_k, itemsize, causal) -> chains: a block in two
+# halves wherever a half is whole sublane tiles of the operands (16 rows of
+# bf16, 8 of float32) and the kernel has a use for them: the forward always
+# (two chains side by side), the backward where it is causal and its key
+# block is shorter than the sub-block, so that a K block can begin past
+# the sub-block's first half (that pass takes the second half alone)
+@pytest.mark.parametrize("kernel, block_q, block_k, itemsize, causal, want", [
+    ("fwd", 1024, 1024, 2, True, 2), ("fwd", 512, 512, 2, True, 2),
+    ("fwd", 32, 32, 2, True, 2), ("fwd", 16, 16, 2, True, 1),
+    ("fwd", 16, 16, 4, True, 2), ("fwd", 8, 8, 4, True, 1),
+    ("fwd", 100, 100, 4, True, 1), ("fwd", 96, 96, 4, False, 2),
+    ("bwd", 1024, 1024, 2, True, 1), ("bwd", 512, 512, 4, True, 1),
+    ("bwd", 1024, 512, 2, True, 2), ("bwd", 1024, 512, 2, False, 1),
+    ("bwd", 1024, 256, 2, True, 2), ("bwd", 512, 1024, 2, True, 1),
+    ("bwd", 24, 12, 4, True, 1), ("bwd", 32, 16, 2, True, 2),
+    ("bwd", 16, 8, 2, True, 1)])
+def test_chains_go_by_the_tile_and_the_operands_itemsize(
+        kernel, block_q, block_k, itemsize, causal, want):
     from horovod_tpu.ops import flash_attention as fa
 
-    assert fa._chains(kernel, block_q, itemsize) == want
+    assert fa._chains(kernel, block_q, block_k, itemsize, causal) == want
     q = jax.ShapeDtypeStruct(
-        (1, 1, block_q, 64), {2: jnp.bfloat16, 4: jnp.float32}[itemsize])
-    assert fa._plan(kernel, q, 0.125, True, block_q, block_q).chains == want
+        (1, 1, block_q * block_k, 64),
+        {2: jnp.bfloat16, 4: jnp.float32}[itemsize])
+    assert fa._plan(kernel, q, 0.125, causal, block_q,
+                    block_k).chains == want
 
 
 def _chain_case(s, h, h_kv, d, d_v, e, dtype, seed):
@@ -1031,6 +1051,152 @@ def test_forward_in_chains_matches_the_formula_and_the_one_chain_pass(
                                        rtol=1e-5, atol=1e-6)
 
 
+# ---- the backward's loop: nothing carried, and the sub-block a K block
+# begins inside taken by its visible half (PR 54)
+
+@pytest.mark.parametrize(
+    "s, h, h_kv, d, d_v, e, causal, dtype, blocks, seq_tile, halved", [
+        pytest.param(512, 2, 2, 32, 32, 0, True, jnp.float32, (128, 64),
+                     None, True, id="plain"),
+        pytest.param(512, 4, 1, 32, 32, 0, True, jnp.float32, (256, 128),
+                     None, True, id="grouped-4-on-1"),
+        pytest.param(512, 2, 2, 32, 16, 8, True, jnp.float32, (128, 64),
+                     None, True, id="rotated-24+8-on-16"),
+        pytest.param(512, 2, 2, 48, 32, 0, True, jnp.float32, (128, 64),
+                     None, True, id="values-narrower-than-keys"),
+        pytest.param(512, 2, 2, 32, 32, 0, True, jnp.bfloat16, (128, 64),
+                     None, True, id="bf16"),
+        pytest.param(768, 2, 2, 32, 32, 0, True, jnp.float32, (256, 128),
+                     256, True, id="three-streamed-tiles"),
+        pytest.param(2048, 1, 1, 64, 64, 0, True, jnp.float32, (None, None),
+                     None, True, id="s2048-derived-1024x512"),
+        pytest.param(512, 2, 2, 32, 32, 0, False, jnp.float32, (128, 64),
+                     None, False, id="not-causal-every-pass-whole"),
+        pytest.param(512, 2, 2, 32, 32, 0, True, jnp.float32, (128, 128),
+                     None, False, id="square-tile-no-block-begins-inside"),
+        pytest.param(512, 2, 2, 32, 32, 0, True, jnp.float32, (128, 32),
+                     None, True, id="k-block-a-quarter-of-the-sub-block"),
+        pytest.param(192, 2, 2, 32, 32, 0, True, jnp.float32, (24, 12),
+                     None, False, id="24-rows-halve-inside-a-sublane-tile"),
+    ])
+def test_backward_carries_nothing_and_takes_the_visible_half(
+        s, h, h_kv, d, d_v, e, causal, dtype, blocks, seq_tile, halved,
+        monkeypatch):
+    """Every gradient (cotangents for o and lse) against the float32
+    formula, at tiles whose K block begins in the middle of a query
+    sub-block and at tiles where none does; and against the same call
+    with every pass taken whole (``_chains`` held to one): the half that
+    is left out sees no key of the block, so its p is 0 and it added 0.
+    ``halved`` says whether the traced backward kernel holds the one
+    branch more that takes the half pass."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    if seq_tile:
+        monkeypatch.setattr(fa, "_SEQ_TILE", seq_tile)
+    operands, w_o, w_lse = _chain_case(s, h, h_kv, d - e, d_v, e, dtype,
+                                       seed=s + e)
+    scale = d ** -0.5
+    tile = dict(zip(("block_q", "block_k"), blocks))
+
+    def attend(q, k, v, q_r=None, k_r=None):
+        return fa.flash_attention_with_lse(q, k, v, q_r=q_r, k_r=k_r,
+                                           causal=causal, **tile)
+
+    def formula(q, k, v, q_r=None, k_r=None):
+        if q_r is not None:
+            q, k, v = _whole(q, q_r, k, k_r, v)
+        k, v = (jnp.repeat(t, h // h_kv, axis=2) for t in (k, v))
+        return _formula_o_lse(q, k, v, causal, scale)
+
+    def grads(attend):
+        def weighed(*operands):
+            o, lse = attend(*operands)
+            return (jnp.sum(o.astype(jnp.float32) * w_o)
+                    + jnp.sum(lse * w_lse))
+
+        return jax.jit(jax.grad(weighed, argnums=tuple(range(
+            len(operands)))))(*operands)
+
+    def branches():
+        """``cond``s in the traced backward kernel."""
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda *t: attend(*t)[0].astype(jnp.float32).sum(),
+            argnums=0))(*operands)
+        return str(jaxpr).count(" cond[")
+
+    with jax.default_matmul_precision("highest"):
+        jax.clear_caches()
+        got, want, n_halved = grads(attend), grads(formula), branches()
+        jax.clear_caches()
+        monkeypatch.setattr(fa, "_chains", lambda *a: 1)
+        whole, n_whole = grads(attend), branches()
+        jax.clear_caches()
+    assert n_halved - n_whole == int(halved)
+    tol = 3e-2 if dtype == jnp.bfloat16 else 2e-4
+    for g, w, t in zip(got, want, operands, strict=True):
+        assert g.shape == t.shape and g.dtype == t.dtype
+        g, w = (np.asarray(x, np.float32) for x in (g, w))
+        np.testing.assert_allclose(g, w, rtol=tol, atol=tol * np.abs(w).max())
+    for g, w in zip(got, whole, strict=True):
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(w, np.float32),
+            rtol=1e-5, atol=1e-6 * float(np.abs(np.asarray(w,
+                                                           np.float32)).max()))
+
+
+def _pallas_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_eqns(sub)
+
+
+@pytest.mark.parametrize("causal, with_choice", [
+    (True, False), (True, True), (False, False)])
+def test_backward_fetches_no_tile_its_k_block_sees_nothing_of(
+        causal, with_choice, monkeypatch):
+    """The block indices the backward call's streamed operands (q, dO, lse,
+    delta and a choice's rows) take over the grid, read from the traced
+    ``pallas_call``: a causal K block's grid steps before the tile it
+    begins in name that tile, so the pipeline fetches nothing for the
+    steps that compute nothing; k, v and the outputs go by the step."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_SEQ_TILE", 256)
+    s, block_q, block_k = 1024, 256, 128
+    q, k, v = _qkv(b=1, s=s, h=1)
+    choice = ({"choice": jnp.asarray(np.tril(np.ones((1, s, s))), jnp.int8)}
+              if with_choice else {})
+    jax.clear_caches()
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        **choice).sum(), (0, 1, 2)))(q, k, v)
+    jax.clear_caches()
+    bwd, = (e for e in _pallas_eqns(jaxpr.jaxpr)
+            if e.params["name"] == "hvt_flash_bwd")
+    mappings = bwd.params["grid_mapping"].block_mappings
+    n_in = 7 if with_choice else 6
+
+    def block(mapping, *step):
+        closed = mapping.index_map_jaxpr
+        return tuple(int(i) for i in jax.core.eval_jaxpr(
+            closed.jaxpr, closed.consts, *(jnp.int32(i) for i in step)))
+
+    for ki in range(s // block_k):
+        for ti in range(s // 256):
+            first = max(ti, ki * block_k // 256) if causal else ti
+            got = [block(m, 0, 0, ki, ti) for m in mappings]
+            assert got[:2] == [(0, 0, ki, 0)] * 2            # k, v
+            assert got[2:6] == [(0, 0, first, 0)] * 4   # q, dO, lse, delta
+            if with_choice:
+                assert got[6] == (0, first, ki)
+            # dq leaves with the last K block; dk and dv go by the block
+            last = ki == s // block_k - 1
+            assert got[n_in:] == [(0, 0, ti if last else 0, 0),
+                                  (0, 0, ki, 0), (0, 0, ki, 0)]
+
+
 # ---- a choice of keys: a mask a query row, the same for every head
 
 def _choice(b, s, keep, causal=True, seed=3):
@@ -1062,6 +1228,11 @@ def _dense_chosen(q, k, v, choice, scale=None):
     (256, 4, 1, dict(block_q=64, block_k=128), True, 0.1),
     (384, 2, 2, dict(block_q=128, block_k=64), True, 0.02),
     (256, 4, 2, dict(block_q=128, block_k=128), False, 0.3),
+    # a K block that begins in the middle of a sub-block: the backward
+    # takes that sub-block's second half alone (PR 54)
+    (256, 4, 2, dict(block_q=128, block_k=64), True, 0.3),
+    (512, 2, 1, dict(block_q=256, block_k=128), True, 0.05),
+    (256, 2, 2, dict(block_q=128, block_k=64), False, 0.3),
 ])
 def test_choice_matches_the_einsum_with_the_same_mask(s, h, h_kv, tile,
                                                       causal, keep):
@@ -1140,8 +1311,8 @@ def test_a_call_without_a_choice_traces_what_it_traced_before():
     choice = _choice(1, 256, 0.3)
     labels = dict(block_q="256", block_k="256", derived="1", d_qk=32, d_v=32)
     count = lambda suffix: [
-        _trace_count(kernel=kernel + suffix, chains=fa._chains(kernel, 256, 4),
-                     **labels) for kernel in ("fwd", "bwd")]
+        _trace_count(kernel=kernel + suffix, **labels)
+        for kernel in ("fwd", "bwd")]
     jax.clear_caches()
     before = count(""), count("_choice")
     with_choice = jax.make_jaxpr(jax.grad(lambda q, k, v: fa.flash_attention(
