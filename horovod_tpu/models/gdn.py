@@ -49,6 +49,17 @@ to ``[b, s, H, d]`` (whose tiles differ from ``[b, s, H d]``'s at ``d`` =
 For a caller that asks for the collection ``intermediates`` the mixer's
 own input and output are sown there (``gdn_input``, ``gdn_output``), for
 a comparison with a position-by-position reference on the same input.
+
+The sibling with **a decay a key channel** is ``models/kda.py`` (Kimi
+Delta Attention) over ``ops/channel_delta_rule.py``. It shares this
+mixer's convolution, L2 norms, ``beta``, norm-then-gate and
+out-projection; it differs in the gate's shape (``g [b, s, H, d_k]`` from
+a low-rank pair of projections, with ``dt_bias`` a channel), in key and
+value heads being equal in number, in the output gate's activation
+(sigmoid, from a low-rank pair too) and in the rule it calls: there the
+decay sits inside the sum over channels and is folded into the products'
+operands, here it is a ``[c, c]`` mask after them. Both rules keep one
+contract, stated in the same words in both ``ops/`` modules.
 """
 
 from __future__ import annotations

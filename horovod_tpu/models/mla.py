@@ -31,7 +31,12 @@ by them:
    ``e`` columns of ``W_q`` a head and of ``W_kva``, a few megabytes a
    layer) and the activations rotated half against half, which a TPU does
    with two slices where pairs are a shuffle across lanes. ``q_r . k_r``
-   sums the same ``e`` products in another order.
+   sums the same ``e`` products in another order. **A model whose latent
+   attention carries no position** (``rotary`` false: Kimi Linear's
+   ``mla_use_nope``, where the delta-rule layers carry the order) has the
+   same ``e`` channels of ``q_r`` and the one shared ``k_r`` and leaves
+   this step and the weights' regrouping out: step 5 takes the four parts
+   as the projections left them, the same kernel call shape for shape.
 5. ``mla_core``: ``score_h(t, u) = (q_n,h(t) . k_n,h(u) + q_r,h(t) .
    k_r(u)) (n + e)^-1/2``, causal softmax in float32, ``o_h = sum_u p
    v_h(u)`` (``causal_attention``). Where ``resolve_flash`` says so (the
@@ -155,6 +160,7 @@ class LatentAttention(nn.Module):
     norm_eps: float = 1e-6
     use_flash: Union[bool, str] = False
     dtype: Any = jnp.bfloat16
+    rotary: bool = True     # False: q_r and k_r are not turned (step 4)
 
     @nn.compact
     def __call__(self, x, positions):
@@ -181,21 +187,23 @@ class LatentAttention(nn.Module):
         # the weights' columns are cut, never an activation's: a head's
         # unrotated and rotated query columns, its key's and its value's
         by_head = lambda t, w: jnp.einsum("bsd,dhk->bshk", t, w)
+        regroup = pairs_to_halves if self.rotary else (lambda w, _: w)
         with jax.named_scope("mla_q_proj"):
             w_q = w_q.astype(self.dtype)
             q_n = by_head(x, w_q[..., :n])
-            q_r = by_head(x, pairs_to_halves(w_q[..., n:], e))
+            q_r = by_head(x, regroup(w_q[..., n:], e))
         with jax.named_scope("mla_kv_down"):
-            down = jnp.dot(x, pairs_to_halves(w_kva.astype(self.dtype), e))
+            down = jnp.dot(x, regroup(w_kva.astype(self.dtype), e))
             c, k_r = down[..., :r], down[..., r:]
             c = latent_norm(c, kv_norm, self.norm_eps)
         with jax.named_scope("mla_kv_up"):
             w_kvb = w_kvb.astype(self.dtype)
             k_n = by_head(c, w_kvb[..., :n])
             values = by_head(c, w_kvb[..., n:])
-        with jax.named_scope("mla_rope"):
-            q_r = rotate_halves(q_r, positions, self.rotary_base)
-            k_r = rotate_halves(k_r, positions, self.rotary_base)
+        if self.rotary:
+            with jax.named_scope("mla_rope"):
+                q_r = rotate_halves(q_r, positions, self.rotary_base)
+                k_r = rotate_halves(k_r, positions, self.rotary_base)
         with jax.named_scope("mla_core"):
             out = causal_attention(q_n, q_r, k_n, k_r, values, positions,
                                    score_scale(n, e), self.use_flash)

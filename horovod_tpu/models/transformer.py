@@ -95,14 +95,17 @@ class GPTConfig:
     # Nemotron-H's ``hybrid_override_pattern``): layer i is ``x +
     # mixer(RMSNorm(x))`` with the mixer ``layer_pattern[i]`` names, "*"
     # attention, "M" a Mamba-2 mixer (models/ssm.py), "G" a Gated
-    # DeltaNet mixer (models/gdn.py), "C" a gated short convolution
-    # (models/sconv.py), "L" latent attention (models/mla.py), "S" attention
-    # over the keys an indexer chooses (models/dsa.py), "E" the expert
-    # layer (models/moe.py), "-" the dense MLP. None (default) = every
-    # layer the attention + MLP (or expert) pair of ``Block``.
+    # DeltaNet mixer (models/gdn.py), "K" a Kimi Delta Attention mixer
+    # (models/kda.py: the delta rule with a decay a key channel), "C" a
+    # gated short convolution (models/sconv.py), "L" latent attention
+    # (models/mla.py), "S" attention over the keys an indexer chooses
+    # (models/dsa.py), "E" the expert layer (models/moe.py), "-" the dense
+    # MLP. None (default) = every layer the attention + MLP (or expert)
+    # pair of ``Block``.
     layer_pattern: Optional[str] = None
     # False leaves q and k unrotated: attention without a positional
-    # term (the state-space layers of a hybrid carry the order).
+    # term (the state-space or delta-rule layers of a hybrid carry the
+    # order). Read by ``Attention`` and by ``LatentAttention``.
     rotary: bool = True
     # The dense MLP's activation: "gelu", "relu2" (relu(x)^2), or
     # "swiglu": the gated MLP ``down(silu(gate(x)) * up(x))``, a third
@@ -198,6 +201,15 @@ class GPTConfig:
     dsa_index_heads: int = 0
     dsa_index_dim: int = 64
     dsa_topk: int = 2048
+    # The Kimi Delta Attention mixers' sizes (models/kda.py, pattern letter
+    # "K"): kda_heads heads (0: the model has no such layer) of
+    # kda_head_dim key and value channels, kda_conv taps, and the rank
+    # kda_gate_rank of the decay's and the output gate's two-matrix
+    # projections.
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    kda_gate_rank: int = 128
 
 
 # The crossover policy lives with the kernel (ops/flash_attention.py);
@@ -474,6 +486,13 @@ class MixerBlock(nn.Module):
                 cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim,
                 cfg.gdn_value_dim, cfg.gdn_conv, norm_eps=cfg.norm_eps,
                 dtype=cfg.dtype, name="gdn")(h)
+        elif self.kind == "K":
+            from horovod_tpu.models.kda import KimiDeltaAttention
+
+            out = KimiDeltaAttention(
+                cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv,
+                cfg.kda_gate_rank, norm_eps=cfg.norm_eps, dtype=cfg.dtype,
+                name="kda")(h)
         elif self.kind == "C":
             from horovod_tpu.models.sconv import ShortConv
 
@@ -487,7 +506,7 @@ class MixerBlock(nn.Module):
                 cfg.mla_rope_dim, cfg.mla_value_dim,
                 rotary_base=cfg.rotary_base, norm_eps=cfg.norm_eps,
                 use_flash=cfg.use_flash, dtype=cfg.dtype,
-                name="mla")(h, positions)
+                rotary=cfg.rotary, name="mla")(h, positions)
         elif self.kind == "S":
             from horovod_tpu.models.dsa import SparseAttention
 
@@ -507,6 +526,7 @@ class MixerBlock(nn.Module):
             raise ValueError(
                 f"layer_pattern holds {self.kind!r}: a layer is one of "
                 f"'*' (attention), 'M' (Mamba-2), 'G' (Gated DeltaNet), "
+                f"'K' (Kimi Delta Attention), "
                 f"'C' (gated short convolution), 'L' (latent attention), "
                 f"'S' (attention over chosen keys), 'E' (experts), "
                 f"'-' (MLP)")
@@ -562,17 +582,21 @@ class GPT(nn.Module):
             # indexer's loss by its four leaves (``dsa.KEPT_INDEX_GRADS``:
             # float32 in the leaves' shapes), which are whole at the end
             # of the first pass, so the recomputed block runs no indexer,
-            # no index scores and no loss. No name is in any other
-            # model's program
+            # no index scores and no loss; and a Kimi Delta Attention
+            # mixer's rule's result (``KEPT_OUTPUT``: bf16 [b, s, H d]),
+            # whose plain body makes every pass of heads again for its own
+            # backward pass, so the recomputed block runs no rule. No name
+            # is in any other model's program
             from horovod_tpu.models.dsa import KEPT_CHOICE, KEPT_INDEX_GRADS
             from horovod_tpu.models.moe import HELD_CHOICE, HELD_SUM
+            from horovod_tpu.ops.channel_delta_rule import KEPT_OUTPUT
             from horovod_tpu.ops.gated_delta_rule import KEPT_INVERSE
 
             block = nn.remat(
                 block, static_argnums=(),
                 policy=jax.checkpoint_policies.save_only_these_names(
                     HELD_SUM, HELD_CHOICE, KEPT_INVERSE, KEPT_CHOICE,
-                    KEPT_INDEX_GRADS))
+                    KEPT_INDEX_GRADS, KEPT_OUTPUT))
         aux = {}
         for i in range(cfg.n_layers):
             kind = () if cfg.layer_pattern is None else (
@@ -629,6 +653,10 @@ def param_partition_spec(params, *, tp_axis="tp", tp_size=None,
             from horovod_tpu.models.gdn import gdn_leaf_spec
 
             return gdn_leaf_spec(names[-1], tp_axis)
+        if "kda" in names:
+            from horovod_tpu.models.kda import kda_leaf_spec
+
+            return kda_leaf_spec(names[-1], tp_axis)
         if "sconv" in names:
             from horovod_tpu.models.sconv import sconv_leaf_spec
 

@@ -31,13 +31,33 @@ All read ``q``, ``k``, ``v`` as ``[b, s, H d]`` (a block ``(1, c, d)`` at a
 head's columns: the convolution's own output layout) and write the same
 way.
 
-Both bodies compute the decays, their sums and exponents, the system, its
-inverse and that inverse's products in float32 at the caller's precision
-and carry ``S`` (the kernels ``dS`` too) in float32; the other products
-run in the operands' dtype and accumulate in float32; every exponent is of
-a non-positive number. The cumulative sum of ``g`` inside a chunk and its
-transpose for ``dg`` are XLA operations around the kernels (``[b, s,
-H_v]`` float32).
+**The contract both delta rules keep** (this module and
+``ops/channel_delta_rule.py``, the rule with a decay a key channel, say it
+in the same words):
+
+- *What is float32.* The decays, their cumulative sums and exponents, a
+  chunk's system, its inverse and that inverse's products are float32 at
+  the caller's precision, and the state ``S`` is carried in float32
+  (``state_dtype``, the mixer's ``STATE_DTYPE``); the other products run in
+  the operands' dtype and accumulate in float32.
+- *Which exponents are taken.* Only ``exp(G_i - G_j)`` for ``j <= i``,
+  ``exp(G_i)`` and ``exp(G_last - G_i)``, ``G`` the cumulative sum of ``g
+  <= 0`` inside a chunk: **every exponent is of a non-positive number**,
+  so every factor lies in ``[0, 1]`` whatever the decay. No ``exp(-G)`` is
+  ever formed (it overflows float32 inside one chunk at these families'
+  decays).
+- *Which shapes ``serves`` sends to kernels.* A TPU backend, the chunk
+  ``CHUNK`` and heads in whole 128-lane tiles; everything else, and every
+  shape of a rule whose kernels are not built, runs the plain
+  ``jax.numpy`` body, which has ``jax.grad`` of itself.
+
+Here the decay is one number a head and position, so ``exp(G_i - G_j)`` is
+a ``[c, c]`` mask that multiplies ``k k^T`` and ``q k^T`` *after* the
+products; a decay a channel sits inside the sum over channels and is
+folded into the operands through reference rows (the sibling module).
+Both bodies here keep the contract, the kernels carry ``dS`` in float32
+too, and the cumulative sum of ``g`` inside a chunk and its transpose for
+``dg`` are XLA operations around the kernels (``[b, s, H_v]`` float32).
 
 On the CPU the same kernel code runs through the Pallas interpreter, at
 any chunk and head width; compiled, Mosaic wants a chunk and head widths

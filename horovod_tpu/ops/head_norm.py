@@ -1,5 +1,6 @@
 """The per-head norms of ``models/gdn.py`` (the L2 norm of q and k before
-the rule, ``RMSNorm(o) w silu(z)`` after it): their plain ``jax.numpy``
+the rule, ``RMSNorm(o) w silu(z)`` after it; ``models/kda.py``'s gate is
+``sigmoid(z)``, the same functions with ``gate="sigmoid"``): their plain ``jax.numpy``
 bodies, four Pallas TPU kernels under two custom VJPs on the flat ``[b, s,
 H d]`` layout the mixer's other kernels read and write, and the rule that
 chooses between them.
@@ -80,6 +81,7 @@ class _Plan(NamedTuple):
     lanes: int
     sub: int
     interpret: bool
+    gate: str = "silu"      # the gated norm's activation of z
 
 
 def _count_trace(kernel, plan, channels):
@@ -113,15 +115,24 @@ def l2_norm(x, dim: Optional[int] = None, *, eps: float, scale: float = 1.0):
     return l2_norm_plain(x, dim, eps=eps, scale=scale)
 
 
-def gated_norm(o, z, w, *, eps: float):
-    """``RMSNorm(o) * w * silu(z)`` with the mean square over each group
+# The gated norm's activations of z, by name: the function and, for the
+# kernels' backward pass, its derivative from z and sigmoid(z).
+_GATES = {
+    "silu": (lambda z, s: z * s, lambda z, s: s * (1.0 + z * (1.0 - s))),
+    "sigmoid": (lambda z, s: s, lambda z, s: s * (1.0 - s)),
+}
+
+
+def gated_norm(o, z, w, *, eps: float, gate: str = "silu"):
+    """``RMSNorm(o) * w * gate(z)`` with the mean square over each group
     of ``w.shape[-1]`` adjacent channels of the last axis (a head of
-    ``[..., H d]``, or of ``[..., H, d]``), the norm before the gate;
-    float32 inside, like ``o``. By the kernels where ``serves`` says so
-    and by ``gated_norm_plain`` everywhere else."""
+    ``[..., H d]``, or of ``[..., H, d]``), the norm before the gate,
+    ``gate`` ``"silu"`` (Gated DeltaNet) or ``"sigmoid"`` (Kimi Delta
+    Attention); float32 inside, like ``o``. By the kernels where
+    ``serves`` says so and by ``gated_norm_plain`` everywhere else."""
     if o.ndim == 3 and serves(o.shape[1], w.shape[-1]):
-        return gated_norm_kernels(o, z, w, eps=eps)
-    return gated_norm_plain(o, z, w, eps=eps)
+        return gated_norm_kernels(o, z, w, eps=eps, gate=gate)
+    return gated_norm_plain(o, z, w, eps=eps, gate=gate)
 
 
 def _by_heads(x, dim):
@@ -138,17 +149,18 @@ def l2_norm_plain(x, dim: Optional[int] = None, *, eps: float,
             ).astype(x.dtype).reshape(x.shape)
 
 
-def gated_norm_plain(o, z, w, *, eps: float):
+def gated_norm_plain(o, z, w, *, eps: float, gate: str = "silu"):
     """``gated_norm`` in plain ``jax.numpy``: the path of every backend
     and shape the kernels do not serve, and their reference."""
     o32 = _by_heads(o.astype(_F32), w.shape[-1])
     normed = o32 * jax.lax.rsqrt(
         jnp.mean(o32 * o32, axis=-1, keepdims=True) + eps)
-    gate = jax.nn.silu(_by_heads(z.astype(_F32), w.shape[-1]))
-    return (normed * w * gate).astype(o.dtype).reshape(o.shape)
+    z32 = _by_heads(z.astype(_F32), w.shape[-1])
+    gated = _GATES[gate][0](z32, jax.nn.sigmoid(z32))
+    return (normed * w * gated).astype(o.dtype).reshape(o.shape)
 
 
-def _plan(x, dim, eps, scale, rows, lanes, sub):
+def _plan(x, dim, eps, scale, rows, lanes, sub, gate="silu"):
     _, seq, channels = x.shape
     if channels % dim:
         raise ValueError(f"heads of {dim} do not divide {channels} channels")
@@ -164,8 +176,10 @@ def _plan(x, dim, eps, scale, rows, lanes, sub):
             f"tile [{seq}, {channels}] in heads of {dim}: lanes in whole "
             f"heads that divide the channels, passes in multiples of "
             f"{_FOLD} that divide the rows")
+    if gate not in _GATES:
+        raise ValueError(f"gate {gate!r}: one of {sorted(_GATES)}")
     return _Plan(dim, float(eps), float(scale), rows, lanes, sub,
-                 _pallas.interpret())
+                 _pallas.interpret(), gate)
 
 
 # ---------------------------------------------------------------- kernels
@@ -223,12 +237,13 @@ def _l2_bwd_kernel(x_ref, g_ref, dx_ref, *, plan):
 
 def _gated_fwd_kernel(o_ref, z_ref, w_ref, y_ref, *, plan):
     w = w_ref[...].astype(_F32)                         # [1, dim]
+    act, _ = _GATES[plan.gate]
 
     def body(at, head, _):
         o = o_ref[0, at, head].astype(_F32)
         z = z_ref[0, at, head].astype(_F32)
         root = jax.lax.rsqrt(_sum(o * o) * (1.0 / plan.dim) + plan.eps)
-        y_ref[0, at, head] = (o * root * w * (z * jax.nn.sigmoid(z))
+        y_ref[0, at, head] = (o * root * w * act(z, jax.nn.sigmoid(z))
                               ).astype(y_ref.dtype)
 
     _passes(plan, body)
@@ -236,10 +251,11 @@ def _gated_fwd_kernel(o_ref, z_ref, w_ref, y_ref, *, plan):
 
 def _gated_bwd_kernel(o_ref, z_ref, w_ref, g_ref, do_ref, dz_ref, sums_ref,
                       *, plan, seq):
-    # y = n w silu(z), n = o root, root = (mean o^2 + eps)^-1/2:
-    # t = g w silu(z) is n's gradient, do = root (t - n mean(t n)),
-    # dz = g n w silu'(z), dw = sum of g n silu(z) over rows and heads
+    # y = n w act(z), n = o root, root = (mean o^2 + eps)^-1/2, act the
+    # plan's gate: t = g w act(z) is n's gradient, do = root (t - n mean(t
+    # n)), dz = g n w act'(z), dw = sum of g n act(z) over rows and heads
     w = w_ref[...].astype(_F32)
+    act, slope = _GATES[plan.gate]
     ragged = seq % plan.rows != 0
     first = pl.program_id(2) * plan.rows
     folds = plan.sub % _FOLD == 0   # whole float32 tiles a pass
@@ -251,11 +267,11 @@ def _gated_bwd_kernel(o_ref, z_ref, w_ref, g_ref, do_ref, dz_ref, sums_ref,
         root = jax.lax.rsqrt(_sum(o * o) * (1.0 / plan.dim) + plan.eps)
         n = o * root
         s = jax.nn.sigmoid(z)
-        gated = g * (z * s)                             # g silu(z)
+        gated = g * act(z, s)                           # g act(z)
         t = gated * w
         do_ref[0, at, head] = ((t - n * (_sum(t * n) * (1.0 / plan.dim)))
                                * root).astype(do_ref.dtype)
-        dz_ref[0, at, head] = (g * n * w * (s * (1.0 + z * (1.0 - s)))
+        dz_ref[0, at, head] = (g * n * w * slope(z, s)
                                ).astype(dz_ref.dtype)
         dw = gated * n
         if ragged:      # rows past the sequence's end hold anything
@@ -390,11 +406,13 @@ def l2_norm_kernels(x, dim: int, *, eps: float, scale: float = 1.0,
     return _l2(x, _plan(x, dim, eps, scale, rows, lanes, sub))
 
 
-def gated_norm_kernels(o, z, w, *, eps: float, rows: Optional[int] = None,
+def gated_norm_kernels(o, z, w, *, eps: float, gate: str = "silu",
+                       rows: Optional[int] = None,
                        lanes: Optional[int] = None,
                        sub: Optional[int] = None):
     """``gated_norm_plain`` through the kernels: ``o``, ``z``
-    ``[b, s, H d]``, ``w [d]`` -> ``o rsqrt(mean o^2 + eps) w silu(z)``
+    ``[b, s, H d]``, ``w [d]`` -> ``o rsqrt(mean o^2 + eps) w gate(z)``
     with the mean over each head's ``d`` channels, float32 inside, like
     ``o``. Differentiable in all three."""
-    return _gated(o, z, w, _plan(o, w.shape[-1], eps, 1.0, rows, lanes, sub))
+    return _gated(o, z, w, _plan(o, w.shape[-1], eps, 1.0, rows, lanes, sub,
+                                 gate))

@@ -613,6 +613,56 @@ def test_head_norm_kernels_compile_for_v5e(shape, compiled_kernel,
         3 * 2 * b * s * heads * dim)
 
 
+def test_gated_norm_with_a_sigmoid_gate_compiles_for_v5e(compiled_kernel,
+                                                        v5e_devices):
+    """The gated norm's two kernels with ``gate="sigmoid"`` at the cell
+    kimilinear-s8192's own sizes (2 x 8192 positions, 32 heads of 128,
+    bf16), forward and backward: the same two calls as ``silu``'s, in the
+    default VMEM scope."""
+    one = SingleDeviceSharding(v5e_devices[0])
+    like = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one)
+    o, w = like(2, 8192, 32 * 128), like(128, dtype=jnp.float32)
+
+    def loss(o, z, w):
+        return jnp.mean(head_norm.gated_norm_kernels(
+            o, z, w, eps=1e-5, gate="sigmoid").astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        o, o, w).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "hvt_gated_norm_fwd" in text and "hvt_gated_norm_bwd" in text
+    assert "vmem_limit_bytes" not in text
+
+
+def test_channel_delta_rule_compiles_for_v5e_within_its_passes(v5e_devices):
+    """The delta rule with a decay a channel at the cell kimilinear-s8192's
+    heads (32 of 128 x 128, bf16, float32 decays) over one sequence of
+    2,040 positions, which its chunks of 32 do not divide, forward and
+    backward, its plain body (no kernel is built): it compiles for the
+    chip, and its temporaries are the operands, the gradients and one pass
+    of 8 heads' chunks, 0.51 GiB, where all 32 heads at once are 1.2 GiB
+    (at the cell's 2 x 8,192 positions 4.3 GiB, which do not fit beside
+    the cell's state: that size compiles on the chip, in every run of the
+    cell)."""
+    from horovod_tpu.ops import channel_delta_rule as rule
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    like = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one)
+    q = like(1, 2040, 32, 128)
+    g = like(1, 2040, 32, 128, dtype=jnp.float32)
+    beta = like(1, 2040, 32, dtype=jnp.float32)
+
+    def loss(*a):
+        return jnp.mean(rule.channel_delta_rule(*a).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        q, q, q, g, beta).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6 * 2 ** 30
+
+
 def test_gdn_layers_hold_the_norm_kernels_and_stay_flat(
         compiled_kernel, v5e_devices, monkeypatch):
     """With the mixer's three choices steered to their kernels (they are

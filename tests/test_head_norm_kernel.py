@@ -70,6 +70,43 @@ def test_the_gated_norm_matches_the_plain_body(dim, heads, rows, dtype):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bf16"])
+@pytest.mark.parametrize("rows", ["two-blocks",
+                                  "rows-the-block-does-not-divide"])
+@pytest.mark.parametrize("gate", ["silu", "sigmoid"])
+def test_the_gated_norm_takes_the_gates_activation(gate, rows, dtype):
+    """``gate`` names what ``z`` passes through (``silu``: Gated DeltaNet,
+    the default every caller had; ``sigmoid``: Kimi Delta Attention): the
+    plain body is ``RMSNorm(o) w act(z)`` written out, the kernels are the
+    plain body, output and all three gradients, and the default is
+    ``silu``; any other name is refused."""
+    args, cot = _operands(ROWS[rows][0], 3, 128, dtype)
+    o, z, w = (t.astype(jnp.float32) for t in args)
+    heads = lambda t: t.reshape(*t.shape[:-1], 3, 128)
+    act = {"silu": jax.nn.silu, "sigmoid": jax.nn.sigmoid}[gate]
+    written_out = (heads(o) * jax.lax.rsqrt(
+        jnp.mean(heads(o) ** 2, -1, keepdims=True) + EPS) * w
+        * act(heads(z))).reshape(o.shape)
+    _close(norm_op.gated_norm_plain(*args, eps=EPS, gate=gate), written_out,
+           "plain", REL[dtype])
+    got = _with_gradients(lambda *a: norm_op.gated_norm_kernels(
+        *a, eps=EPS, gate=gate, **_named(128, "heads-fill-no-block", rows)),
+        cot)(*args)
+    want = _with_gradients(lambda *a: norm_op.gated_norm_plain(
+        *a, eps=EPS, gate=gate), cot)(*args)
+    for name, x, same in zip(("y", "do", "dz", "dw"), got, want):
+        assert x.shape == same.shape and x.dtype == same.dtype, name
+        _close(x, same, name, REL[dtype])
+    if gate == "silu":
+        np.testing.assert_array_equal(
+            np.asarray(norm_op.gated_norm(*args, eps=EPS), np.float32),
+            np.asarray(norm_op.gated_norm_plain(*args, eps=EPS, gate="silu"),
+                       np.float32))
+    with pytest.raises((KeyError, ValueError)):
+        norm_op.gated_norm_kernels(*args, eps=EPS, gate="tanh")
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
 @pytest.mark.parametrize("rows", list(ROWS))
 @pytest.mark.parametrize("heads", list(HEADS))
 @pytest.mark.parametrize("dim", [128, 256])

@@ -355,3 +355,62 @@ def test_mixer_sows_its_input_and_output():
                                   np.asarray(x))
     np.testing.assert_array_equal(np.asarray(kept["mla_output"][0]),
                                   np.asarray(out))
+
+
+# ---- without a rotary (``rotary`` false: Kimi Linear's ``mla_use_nope``)
+
+@pytest.mark.parametrize("seq, use_flash, dtype", [
+    (24, False, jnp.float32), (128, True, jnp.float32),
+    (128, True, jnp.bfloat16)])
+def test_the_unrotated_mixer_matches_its_reference(seq, use_flash, dtype,
+                                                   monkeypatch):
+    """``rotary=False`` leaves step 4 and the weights' regrouping out: the
+    mixer is ``chipbench/reference/kimi_linear.py``'s attention, a whole
+    key ``k_n | k_r`` a head and nothing turned, by the einsum path and by
+    the kernels on the four parts; its tree is the rotated mixer's, its
+    output another."""
+    from chipbench.reference import kimi_linear
+
+    monkeypatch.setattr(kimi_linear, "QUERY_BLOCK", 16)
+    layer, params, x, positions = _mixer(seq, dtype, use_flash)
+    plain = layer.clone(rotary=False)
+    assert jax.tree.map(jnp.shape, jax.eval_shape(
+        plain.init, jax.random.key(0), x, positions)["params"]) \
+        == jax.tree.map(jnp.shape, params)
+    got = jax.jit(plain.apply)({"params": params}, x, positions)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(jax.vmap(
+            lambda one, p: kimi_linear.latent_attention(one, p, _KANANA),
+            in_axes=(0, None)))(x, params)
+    rel = lambda a: float(jnp.linalg.norm(a - want) / jnp.linalg.norm(want))
+    assert rel(got) <= (3e-2 if dtype == jnp.bfloat16 else 2e-5)
+    assert rel(jax.jit(layer.apply)({"params": params}, x, positions)) > 0.1
+
+
+def test_the_unrotated_mixer_names_no_rope_scope_and_hands_over_the_parts(
+        monkeypatch):
+    """No operation under ``mla_rope`` and no permutation of a weight's
+    columns; the kernels get ``q_r`` and the one shared ``k_r`` as the
+    projections left them; the rotated mixer's lowered text is what it was
+    with the field named or not."""
+    seen = {}
+    right = fa.flash_attention
+
+    def watch(q, k, v, **options):
+        seen.update(q=q.shape, q_r=options["q_r"].shape,
+                    k_r=options["k_r"].shape)
+        return right(q, k, v, **options)
+
+    monkeypatch.setattr(fa, "flash_attention", watch)
+    layer, params, x, positions = _mixer(128, use_flash=True)
+    plain = layer.clone(rotary=False)
+    text = jax.jit(plain.apply).lower(
+        {"params": params}, x, positions).as_text(debug_info=True)
+    assert "mla_rope" not in text and "mla_core" in text
+    assert seen == {"q": (2, 128, 4, 8), "q_r": (2, 128, 4, 4),
+                    "k_r": (2, 128, 4)}
+    turned = lambda m: jax.jit(m.apply).lower(
+        {"params": params}, x, positions).as_text()
+    assert "mla_rope" in jax.jit(layer.apply).lower(
+        {"params": params}, x, positions).as_text(debug_info=True)
+    assert turned(layer) == turned(layer.clone(rotary=True))
