@@ -12,11 +12,16 @@ split by operation from a profiler trace (the 40 that take most, with the
 name stack each carries) beside its compiled text under ``chiprun_out/``.
 ``--scalar`` times ``ops/gated_delta_rule.py``'s plain body and kernels at
 the same heads, a decay a head, for the price of the channels.
+``--kernels`` times the rule's own Pallas kernels beside them
+(``CHUNKxHEADS``: the chunk and the heads a grid step takes), with each
+one's gradients' distance from the plain body's on the checked positions
+and its operations from a trace (``hvt_kda_inverse``, ``hvt_kda_fwd``,
+``hvt_kda_bwd`` a call).
 
 A microbenchmark: the step's own cost is a traced run of the cell
 (``python3 -m chipbench.run --workload kimilinear-s8192 --trace 1``).
 
-    chiprun -- python benchmarks/kda_rule.py --variants 64x8x8,128x8x8
+    chiprun -- python benchmarks/kda_rule.py --variants 32x8x8 --kernels 128x2,64x2
 """
 
 import argparse
@@ -127,6 +132,7 @@ def main(argv=None):
     ap.add_argument("--check", type=int, default=2048)
     ap.add_argument("--profile", default=None, metavar="VARIANT")
     ap.add_argument("--scalar", action="store_true")
+    ap.add_argument("--kernels", default="", metavar="CHUNKxHEADS,...")
     a = ap.parse_args(argv)
 
     import jax
@@ -156,6 +162,36 @@ def main(argv=None):
                 f.write(both.lower(*args).compile().as_text())
         print(json.dumps({variant: {k: v for k, v in here.items()
                                     if k != "profile"}}), flush=True)
+    short = tuple(t[:1, :a.check] for t in args)
+    # the mixer's own layout: the operands come and the results leave as
+    # [b, s, H d] (on the chip [b, s, H, d] is tiled by (H, d), another
+    # layout, and a 4-D operand here would be timed with its relayout)
+    flat = lambda ts: tuple(t.reshape(*t.shape[:2], -1) for t in ts)
+    heads = lambda t, like: t.reshape(like.shape)
+    for variant in filter(None, a.kernels.split(",")):
+        chunk, rule_op._HEADS_A_STEP = (int(x) for x in variant.split("x"))
+        rule = lambda *t: rule_op.channel_delta_rule_kernels(*t, chunk=chunk)
+        forward, both = _calls(rule, do[:1, :a.check])
+        _, plain = _calls(lambda *t: rule_op.channel_delta_rule_plain(
+            *t, chunk=chunk), do[:1, :a.check])
+        got = forward(*short)[0].astype(jnp.float32)
+        far = lambda x, y: float(
+            jnp.linalg.norm(x.astype(jnp.float32) - y.astype(jnp.float32))
+            / jnp.linalg.norm(y.astype(jnp.float32)))
+        here = out[f"kernels_{variant}"] = {
+            "rel_l2_vs_recurrence": far(got, want),
+            "grads_rel_l2_vs_plain": dict(zip(
+                ("dq", "dk", "dv", "dg", "dbeta"),
+                map(far, both(*short), plain(*short))))}
+        flat_rule = lambda *t: rule(*map(heads, t, args)).reshape(
+            *do.shape[:2], -1)
+        forward, both = _calls(flat_rule, flat([do])[0])
+        here.update(fwd_ms=_ms(forward, flat(args), a.calls),
+                    fwd_bwd_ms=_ms(both, flat(args), a.calls))
+        profile = _profile(both, flat(args), a.calls, f"kernels_{variant}")
+        here["by_operation"] = profile and [
+            [ms, n, name] for ms, n, name, _ in profile["top"][:8]]
+        print(json.dumps({f"kernels_{variant}": here}), flush=True)
     if a.scalar:
         q, k, v, g, beta = args
         one = (q, k, v, jnp.mean(g, -1), beta)

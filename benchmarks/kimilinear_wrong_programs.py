@@ -72,14 +72,21 @@ def _as_bf16(x):
     return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
 
 
+@contextlib.contextmanager
 def _state_rounded():
-    """``channel_delta_rule``'s passes with the carried state rounded to
-    bf16 after every chunk (what a bf16 carry holds) and nothing else
-    changed: ``jax.lax.scan``, which a pass calls for its carry alone,
-    rounds the state it hands on while the pass is traced."""
+    """``channel_delta_rule`` with the carried state rounded to bf16 after
+    every chunk (what a bf16 carry holds) and nothing else changed, in
+    both bodies: the kernels round what a chunk hands on by
+    ``channel_delta_rule._carried`` (the plan's state dtype, float32 in
+    the program), and the plain body's pass calls ``jax.lax.scan`` for its
+    carry alone, which rounds the state it hands on while the pass is
+    traced. The kernels' calls are jitted by their plan, which this does
+    not change: their traces are dropped on the way in and out."""
     import jax
+    import jax.numpy as jnp
 
     from horovod_tpu.ops import channel_delta_rule as rule_op
+    from horovod_tpu.ops.gated_delta_rule import _rounder
 
     right = rule_op._one_pass
 
@@ -99,7 +106,13 @@ def _state_rounded():
         finally:
             jax.lax.scan = real
 
-    return _swapped(rule_op, "_one_pass", one_pass)
+    jax.clear_caches()
+    try:
+        with _swapped(rule_op, "_one_pass", one_pass), _swapped(
+                rule_op, "_carried", lambda plan: _rounder(jnp.bfloat16)):
+            yield
+    finally:
+        jax.clear_caches()
 
 
 def _gate_first(o, z, scale, eps, gate):

@@ -31,7 +31,10 @@ query-key width of 192 on a value width of 128, 32 heads, the products over
 positions of the cell ``kanana2-s8192``'s latent attention), ``gdn8192`` (the chunked gated delta rule
 of ``models/gdn.py`` at that cell's shape, the Pallas kernels and the plain
 ``jax.numpy`` path side by side, each against the float32 recurrence and
-timed, forward alone and forward and backward), ``conv8192`` (the causal
+timed, forward alone and forward and backward), ``kda8192`` (the same for
+the delta rule with a decay a key channel of ``models/kda.py`` at the cell
+``kimilinear-s8192``'s shape, ``ops/channel_delta_rule.py``'s kernels and
+plain body, ``dg`` by channel), ``conv8192`` (the causal
 depthwise convolution in front of that rule and of Mamba-2's scan, at the
 two cells' widths, the Pallas kernels of ``ops/causal_conv.py`` and the
 plain body side by side, forward and the three gradients), ``norms8192``
@@ -396,6 +399,34 @@ def phase_mla8192(shape=(2, 8192, 32, 32, 192, 128), rotated=64):
             "parts": compiled_flash_vs_f32(shape, rotated)}
 
 
+def rule_paths_vs_recurrence(kernels, plain, chunk, operands, do, want):
+    """A chunked delta rule by its two paths side by side, the Pallas
+    kernels and the plain ``jax.numpy`` body at one chunk: each one's
+    output and five gradients against ``want`` (the float32 recurrence's,
+    ``(o, dq, dk, dv, dg, dbeta)``), held to ``BF16_REL_L2``, and the
+    milliseconds a forward alone and a forward and backward take."""
+    import jax
+    import numpy as np
+
+    out = {"chunk": chunk, "kernels_compiled": jax.default_backend() != "cpu"}
+    for name, body in (("kernels", kernels), ("plain", plain)):
+        rule = functools.partial(body, chunk=chunk)
+        step = with_gradients(rule, do)
+        got = jax.block_until_ready(step(*operands))
+        errs = {what: rel_l2(one, w) for what, one, w in
+                zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want)}
+        check(all(np.isfinite(list(errs.values())))
+              and max(errs.values()) <= BF16_REL_L2,
+              f"the chunked rule by its {name} path differs from the "
+              f"float32 recurrence: {errs} (relative L2), bound "
+              f"{BF16_REL_L2}")
+        out[name] = {
+            "ms_forward": 1e3 * mean_seconds(jax.jit(rule), *operands),
+            "ms_forward_and_backward": 1e3 * mean_seconds(step, *operands),
+            "rel_l2": errs}
+    return out
+
+
 # -------------------------------------------------------------------- gdn8192
 
 def phase_gdn8192(shape=(2, 8192, 16, 32, 128, 128), chunk=128):
@@ -431,32 +462,47 @@ def phase_gdn8192(shape=(2, 8192, 16, 32, 128, 128), chunk=128):
         wide = lambda t: jnp.repeat(t, h_v // h_k, axis=1)
         return reference.delta_rule(wide(q), wide(k), v, g, beta)
 
-    seconds = lambda call: mean_seconds(call, q, k, v, g, beta)
     f32 = lambda t: t.astype(jnp.float32)
     with jax.default_matmul_precision("highest"):
         want = with_gradients(lambda *a: jax.lax.map(
             lambda one: recurrence(*one), a), f32(do))(
                 f32(q), f32(k), f32(v), g, beta)
-    out = {"shape": list(shape), "chunk": chunk,
-           "kernels_compiled": jax.default_backend() != "cpu"}
-    paths = {"kernels": functools.partial(rule_op.gated_delta_rule_kernels,
-                                          chunk=chunk),
-             "plain": functools.partial(rule_op.gated_delta_rule_plain,
-                                        chunk=chunk)}
-    for name, rule in paths.items():
-        step = with_gradients(rule, do)
-        got = jax.block_until_ready(step(q, k, v, g, beta))
-        errs = {what: rel_l2(one, w) for what, one, w in
-                zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want)}
-        check(all(np.isfinite(list(errs.values())))
-              and max(errs.values()) <= BF16_REL_L2,
-              f"the chunked rule by its {name} path differs from the "
-              f"float32 recurrence: {errs} (relative L2), bound "
-              f"{BF16_REL_L2}")
-        out[name] = {"ms_forward": 1e3 * seconds(jax.jit(rule)),
-                     "ms_forward_and_backward": 1e3 * seconds(step),
-                     "rel_l2": errs}
-    return out
+    return {"shape": list(shape), **rule_paths_vs_recurrence(
+        rule_op.gated_delta_rule_kernels, rule_op.gated_delta_rule_plain,
+        chunk, (q, k, v, g, beta), do, want)}
+
+
+# -------------------------------------------------------------------- kda8192
+
+def phase_kda8192(shape=(2, 8192, 32, 128)):
+    """The chunked delta rule with a decay a key channel at the cell
+    ``kimilinear-s8192``'s shape, (batch, seq, heads, d) in bf16 with the
+    initialisation's decays, by its two paths side by side: the Pallas
+    kernels of ``ops/channel_delta_rule.py`` and the plain ``jax.numpy``
+    body beside them, each at the module's chunk. Each: output and the
+    five gradients (``dg`` by channel) against the float32 recurrence
+    taken one position after another (the benchmark's reference's,
+    ``chipbench/reference/kimi_linear.py``), and the milliseconds a
+    forward alone and a forward and backward take (host clock around
+    ``block_until_ready``, the mean of five calls after one;
+    ``benchmarks/kda_rule.py`` reads the kernels' own from a trace)."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.kda_rule import _inputs
+    from chipbench.reference import kimi_linear as reference
+    from horovod_tpu.ops import channel_delta_rule as rule_op
+
+    (q, k, v, g, beta), do = _inputs(shape)
+    f32 = lambda t: t.astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = with_gradients(lambda *a: jax.lax.map(
+            lambda one: reference.delta_rule(*one), a), f32(do))(
+                f32(q), f32(k), f32(v), g, beta)
+    chunk = rule_op.chunk_for(shape[1])
+    return {"shape": list(shape), **rule_paths_vs_recurrence(
+        rule_op.channel_delta_rule_kernels, rule_op.channel_delta_rule_plain,
+        chunk, (q, k, v, g, beta), do, want)}
 
 
 # ------------------------------------------------------------------- conv8192
@@ -709,6 +755,7 @@ def main(argv=None):
                             ("flash256", phase_flash256),
                             ("mla8192", phase_mla8192),
                             ("gdn8192", phase_gdn8192),
+                            ("kda8192", phase_kda8192),
                             ("conv8192", phase_conv8192),
                             ("norms8192", phase_norms8192),
                             ("eager", phase_eager)):

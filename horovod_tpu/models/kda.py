@@ -26,13 +26,17 @@ them:
        S_t = S' + k_t (beta_t (v_t - S'^T k_t))^T
        o_t = S_t^T q_t
 
-   Computed **chunked** (``ops/channel_delta_rule.py``). With ``g_t`` the
-   same in every channel it is ``models/gdn.py``'s rule; what differs is
-   that the decay sits inside the sum over channels, so it is folded into
-   the products' operands through reference rows and not applied as a
-   ``[c, c]`` mask after them. The decays, their cumulative sums, the
-   inverse and the carried state are float32 (``STATE_DTYPE``), the
-   products run in the layer's ``dtype`` and accumulate in float32.
+   Computed **chunked** (``ops/channel_delta_rule.py``: on a TPU, at
+   heads in whole 128-lane tiles, its three Pallas kernels, which read
+   ``q``, ``k``, ``v`` and ``g`` as the ``[b, s, H d]`` arrays the
+   convolution and the norms hand over; its plain ``jax.numpy`` body
+   everywhere else). With ``g_t`` the same in every channel it is
+   ``models/gdn.py``'s rule; what differs is that the decay sits inside
+   the sum over channels, so it is folded into the products' operands
+   through reference rows and not applied as a ``[c, c]`` mask after
+   them. The decays, their cumulative sums, the inverse and the carried
+   state are float32 (``STATE_DTYPE``), the products run in the layer's
+   ``dtype`` and accumulate in float32.
 4. ``kda_gate_norm``: ``RMSNorm(o) w * sigmoid(z)`` a head, the norm
    before the gate, ``w [d_h]`` shared by the heads (``ops/head_norm.py``'s
    ``gated_norm`` with ``gate="sigmoid"``; Gated DeltaNet's is ``silu``).
@@ -147,12 +151,13 @@ class KimiDeltaAttention(nn.Module):
         with jax.named_scope("kda_rule"):
             heads = lambda t: t.reshape(*t.shape[:-1], h, d_h)
             beta = jax.nn.sigmoid(b)
-            g = -jnp.exp(a_log.astype(STATE_DTYPE))[:, None] * heads(
+            # [b, s, H d] like q, k and v: the kernels read a head's columns
+            g = -jnp.repeat(jnp.exp(a_log.astype(STATE_DTYPE)), d_h) * (
                 jax.nn.softplus(a + dt_bias.astype(STATE_DTYPE)))
             q = norm_op.l2_norm(q, d_h, eps=L2_EPS, scale=d_h ** -0.5)
             k = norm_op.l2_norm(k, d_h, eps=L2_EPS)
             o = rule_op.channel_delta_rule(
-                heads(q), heads(k), heads(v), g, beta, chunk=chunk,
+                heads(q), heads(k), heads(v), heads(g), beta, chunk=chunk,
                 state_dtype=STATE_DTYPE, precision=INVERSE_PRECISION)
         with jax.named_scope("kda_gate_norm"):
             y = norm_op.gated_norm(o.reshape(*o.shape[:-2], width), z,

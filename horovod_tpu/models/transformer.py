@@ -582,21 +582,23 @@ class GPT(nn.Module):
             # indexer's loss by its four leaves (``dsa.KEPT_INDEX_GRADS``:
             # float32 in the leaves' shapes), which are whole at the end
             # of the first pass, so the recomputed block runs no indexer,
-            # no index scores and no loss; and a Kimi Delta Attention
-            # mixer's rule's result (``KEPT_OUTPUT``: bf16 [b, s, H d]),
-            # whose plain body makes every pass of heads again for its own
-            # backward pass, so the recomputed block runs no rule. No name
-            # is in any other model's program
+            # no index scores and no loss; and the inverses of a Kimi Delta
+            # Attention mixer's chunks where its rule runs as kernels
+            # (``channel_delta_rule.KEPT_INVERSE``, as Gated DeltaNet's):
+            # the recomputed block runs the rule's forward walk alone, for
+            # the states its backward pass reads. No name is in any other
+            # model's program
             from horovod_tpu.models.dsa import KEPT_CHOICE, KEPT_INDEX_GRADS
             from horovod_tpu.models.moe import HELD_CHOICE, HELD_SUM
-            from horovod_tpu.ops.channel_delta_rule import KEPT_OUTPUT
+            from horovod_tpu.ops.channel_delta_rule import (
+                KEPT_INVERSE as KEPT_CHANNEL_INVERSE)
             from horovod_tpu.ops.gated_delta_rule import KEPT_INVERSE
 
             block = nn.remat(
                 block, static_argnums=(),
                 policy=jax.checkpoint_policies.save_only_these_names(
                     HELD_SUM, HELD_CHOICE, KEPT_INVERSE, KEPT_CHOICE,
-                    KEPT_INDEX_GRADS, KEPT_OUTPUT))
+                    KEPT_INDEX_GRADS, KEPT_CHANNEL_INVERSE))
         aux = {}
         for i in range(cfg.n_layers):
             kind = () if cfg.layer_pattern is None else (

@@ -47,14 +47,15 @@ in the same words):
   ever formed (it overflows float32 inside one chunk at these families'
   decays).
 - *Which shapes ``serves`` sends to kernels.* A TPU backend, the chunk
-  ``CHUNK`` and heads in whole 128-lane tiles; everything else, and every
-  shape of a rule whose kernels are not built, runs the plain
-  ``jax.numpy`` body, which has ``jax.grad`` of itself.
+  ``CHUNK`` and heads in whole 128-lane tiles; everything else runs the
+  plain ``jax.numpy`` body, which has ``jax.grad`` of itself.
 
 Here the decay is one number a head and position, so ``exp(G_i - G_j)`` is
 a ``[c, c]`` mask that multiplies ``k k^T`` and ``q k^T`` *after* the
 products; a decay a channel sits inside the sum over channels and is
-folded into the operands through reference rows (the sibling module).
+folded into the operands through reference rows (the sibling module,
+whose three kernels take the inverse by blocks, ``_unit_lower_inverse``,
+and the rounders of a state dtype steered from outside from here).
 Both bodies here keep the contract, the kernels carry ``dS`` in float32
 too, and the cumulative sum of ``g`` inside a chunk and its transpose for
 ``dg`` are XLA operations around the kernels (``[b, s, H_v]`` float32).

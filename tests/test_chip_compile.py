@@ -635,18 +635,25 @@ def test_gated_norm_with_a_sigmoid_gate_compiles_for_v5e(compiled_kernel,
     assert "vmem_limit_bytes" not in text
 
 
-def test_channel_delta_rule_compiles_for_v5e_within_its_passes(v5e_devices):
+@pytest.mark.parametrize("body", ["kernels", "plain"])
+def test_channel_delta_rule_compiles_for_v5e_within_its_passes(
+        body, compiled_kernel, v5e_devices, monkeypatch):
     """The delta rule with a decay a channel at the cell kimilinear-s8192's
     heads (32 of 128 x 128, bf16, float32 decays) over one sequence of
-    2,040 positions, which its chunks of 32 do not divide, forward and
-    backward, its plain body (no kernel is built): it compiles for the
-    chip, and its temporaries are the operands, the gradients and one pass
-    of 8 heads' chunks, 0.51 GiB, where all 32 heads at once are 1.2 GiB
-    (at the cell's 2 x 8,192 positions 4.3 GiB, which do not fit beside
-    the cell's state: that size compiles on the chip, in every run of the
-    cell)."""
+    2,040 positions, which its chunks do not divide, forward and backward,
+    through ``channel_delta_rule`` with ``serves`` steered (it is decided
+    from the backend, which is the CPU here). The kernels: the three by
+    name, one call each, in the default VMEM scope, and the temporaries
+    are what the forward keeps for the backward (the states 34 MB, the
+    bf16 inverses 17 MB) beside the padded operands and the gradients on
+    their way out. The plain body, the path of every shape that does not
+    serve (chunks of 32 here, what it was measured at): no kernel, and its
+    temporaries are the operands, the gradients and one pass of 8 heads'
+    chunks, 0.51 GiB, where all 32 heads at once are 1.2 GiB."""
     from horovod_tpu.ops import channel_delta_rule as rule
 
+    kernels = body == "kernels"
+    monkeypatch.setattr(rule, "serves", lambda *shape: kernels)
     one = SingleDeviceSharding(v5e_devices[0])
     like = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         dims, dtype, sharding=one)
@@ -655,12 +662,21 @@ def test_channel_delta_rule_compiles_for_v5e_within_its_passes(v5e_devices):
     beta = like(1, 2040, 32, dtype=jnp.float32)
 
     def loss(*a):
-        return jnp.mean(rule.channel_delta_rule(*a).astype(jnp.float32) ** 2)
+        return jnp.mean(rule.channel_delta_rule(
+            *a, chunk=None if kernels else 32).astype(jnp.float32) ** 2)
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         q, q, q, g, beta).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.6 * 2 ** 30
+    text = compiled.as_text()
+    if kernels:
+        assert text.count("tpu_custom_call") == 3
+        assert all(f"hvt_kda_{kernel}" in text
+                   for kernel in ("inverse", "fwd", "bwd"))
+        assert "vmem_limit_bytes" not in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * 2 ** 30
+    else:
+        assert "tpu_custom_call" not in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.6 * 2 ** 30
 
 
 def test_gdn_layers_hold_the_norm_kernels_and_stay_flat(

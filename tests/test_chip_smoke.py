@@ -52,7 +52,7 @@ def test_flash_must_be_the_compiled_kernel():
 
 # what no cell of the benchmark decides, in the order it runs
 PHASES = {1: ["launcher", "device", "flash8192", "flash256", "mla8192",
-              "gdn8192",
+              "gdn8192", "kda8192",
               "conv8192", "norms8192", "eager"],
           4: ["device", "ring4", "dryrun4"]}
 
