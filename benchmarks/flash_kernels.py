@@ -50,7 +50,11 @@ beside the parent in one process); and ``/choice`` after a shape hands
 the kernels a choice of keys (``keyevl2-s16384``'s masked calls: int8
 ``[b, s, s]``, the 1,024 keys before a query and every sixteenth of the
 causal rest, made on the device; the kernels walk every causal tile
-whatever the mask holds): ``--shape 1,16384,32,4,128/choice``.
+whatever the mask holds): ``--shape 1,16384,32,4,128/choice``; and
+``/window=N`` a window of ``N`` keys (``trinitymini-s16384``'s windowed
+calls beside its full ones: ``--shape
+1,16384,32,4,128+1,16384,32,4,128/window=2048``; an einsum path has no
+window here).
 
 A microbenchmark, not the yardstick: the cell that decides is
 ``gpt2l-s4096`` of ``BENCHMARK.json``. It refuses to run without a TPU.
@@ -184,7 +188,7 @@ def kernel_durations(trace_dir):
 
 
 def measure(flash, shape, tiles, causal=True, iters=5, einsum=False,
-            rotated=0, first=None, choice=False):
+            rotated=0, first=None, choice=False, window=None):
     """One dict a tile (with a rotated width two, one an entry; and one
     for the einsum path), as the module docstring describes, and what
     they were compared with: ``first``, or the first tile's results."""
@@ -201,8 +205,10 @@ def measure(flash, shape, tiles, causal=True, iters=5, einsum=False,
     for tile in tiles:
         blocks = (dict(zip(("block_q", "block_k"), tile))
                   if isinstance(tile, tuple) else {})
+        banded = {} if window is None else {"window": window}
         whole = lambda q, k, v, blocks=blocks, **chosen: (
-            flash.flash_attention(q, k, v, causal=causal, **blocks, **chosen))
+            flash.flash_attention(q, k, v, causal=causal, **banded, **blocks,
+                                  **chosen))
         if not rotated:
             runs.append((tile, None, call_and_vjp(whole)))
             continue
@@ -223,6 +229,8 @@ def measure(flash, shape, tiles, causal=True, iters=5, einsum=False,
             line.update(entry=entry, rotated=rotated)
         if choice:
             line["choice"] = True
+        if window is not None:
+            line["window"] = window
         lines.append(line)
         if isinstance(tile, str) and "=" in tile:
             # traced here, once: the rule is read outside the jitted call
@@ -295,14 +303,15 @@ def main(argv=None):
     for shape in a.shape.split("+"):
         shape, _, own = shape.partition("/")
         choice = own == "choice"
-        rotated = a.rotated if choice or not own else int(own)
+        window = int(own[7:]) if own.startswith("window=") else None
+        rotated = a.rotated if choice or window or not own else int(own)
         first = None
         for source in sources:
             flash = load_flash(source)
             lines, first = measure(
                 flash, tuple(int(x) for x in shape.split(",")), tiles,
                 causal=not a.no_causal, iters=a.iters, einsum=a.einsum,
-                rotated=rotated, first=first, choice=choice)
+                rotated=rotated, first=first, choice=choice, window=window)
             for line in lines:
                 line["source"] = os.path.relpath(flash.__file__)
                 line["device"] = jax.devices()[0].device_kind

@@ -94,7 +94,8 @@ class GPTConfig:
     # One mixer a layer, in the order a string gives (hybrid models:
     # Nemotron-H's ``hybrid_override_pattern``): layer i is ``x +
     # mixer(RMSNorm(x))`` with the mixer ``layer_pattern[i]`` names, "*"
-    # attention, "M" a Mamba-2 mixer (models/ssm.py), "G" a Gated
+    # attention, "W" attention inside a window (below), "M" a Mamba-2
+    # mixer (models/ssm.py), "G" a Gated
     # DeltaNet mixer (models/gdn.py), "K" a Kimi Delta Attention mixer
     # (models/kda.py: the delta rule with a decay a key channel), "C" a
     # gated short convolution (models/sconv.py), "L" latent attention
@@ -210,6 +211,26 @@ class GPTConfig:
     kda_head_dim: int = 128
     kda_conv: int = 4
     kda_gate_rank: int = 128
+    # A second kind of attention layer, pattern letter "W": ``Attention``
+    # in which a query sees the last attn_window causal keys, itself among
+    # them (``t - attn_window < s <= t``; 0: the model has no such layer).
+    # **Which letter reads what, in one place.** Both kinds read the
+    # sizes and pieces above (n_heads, n_kv_heads, head_dim, heads_held,
+    # qk_norm, head_norm, attn_gate, rotary_base, rotary_fraction,
+    # use_flash, ring_mesh). "*" sees every causal key and turns q and k as
+    # ``rotary`` says; "W" sees its window, always turns them, and reads
+    # neither ``rotary`` nor anything else of its own. A model of local
+    # layers with positions and global ones without is ``rotary=False``
+    # beside a window here. A window on the ring path is refused by name.
+    attn_window: int = 0
+    # A norm after the mixer as well, in a patterned model: a layer is ``x
+    # + post_norm(mixer(norm(x)))``, the second RMSNorm with a weight of
+    # its own on the mixer's output before the residual sum, for every
+    # letter alike.
+    post_norm: bool = False
+    # The embedding's rows times this before the first layer (a model under
+    # muP multiplies them by sqrt(d_model)), in the activations' dtype.
+    embed_scale: float = 1.0
 
 
 # The crossover policy lives with the kernel (ops/flash_attention.py);
@@ -290,23 +311,32 @@ def held_heads(n_heads, n_kv, held):
     return count, max(1, count // group)
 
 
-def _count_trace(heads, kv_heads, head_dim, core):
-    """One count a traced layer."""
+def _count_trace(heads, kv_heads, head_dim, core, window):
+    """One count a traced layer; ``window`` 0 in a layer that sees every
+    causal key."""
     _pallas.count_trace(
         "hvt_attn_layers_traced_total",
         "attention layers traced into compiled programs, by the path "
         "their products over positions take: ring, flash or einsum "
         "(counted per trace, not per execution)",
-        heads=heads, kv_heads=kv_heads, head_dim=head_dim, core=core)
+        heads=heads, kv_heads=kv_heads, head_dim=head_dim, core=core,
+        window=window)
 
 
-def _attend(cfg, q, k, v, positions, core):
+def _attend(cfg, q, k, v, positions, core, window=0):
     """The products over positions by the path ``core`` names: the
-    ring schedule, the flash kernels, or two einsums and a softmax."""
+    ring schedule, the flash kernels, or two einsums and a softmax; of the
+    causal keys the last ``window`` alone where there is one."""
     *_, n_heads, head_dim = q.shape
     n_kv = k.shape[-2]
     if core == "ring":
         from horovod_tpu.parallel.sequence import ring_attention
+
+        if window:
+            raise ValueError(
+                f"a window ({window}) on the ring path is not built "
+                f"(parallel/sequence.py's schedule sends every shard's keys "
+                f"to every shard): unset ring_mesh, or attn_window")
 
         # GQA K/V go to the ring UN-repeated: the schedule
         # circulates the small h_kv buffers over ICI (payload
@@ -334,7 +364,8 @@ def _attend(cfg, q, k, v, positions, core):
 
         # the kernel serves GQA zero-copy (K/V head index aliasing)
         return flash_attention(q, k, v, causal=True,
-                               scale=1.0 / np.sqrt(head_dim))
+                               scale=1.0 / np.sqrt(head_dim),
+                               **({"window": window} if window else {}))
     # XLA turns the repeat into a broadcast inside the dot
     k, v = _repeat_kv(k, v, n_heads // n_kv)
     scores = jnp.einsum("...qhd,...khd->...hqk", q, k,
@@ -342,14 +373,22 @@ def _attend(cfg, q, k, v, positions, core):
     scores = scores / np.sqrt(head_dim)
     qpos = positions[..., :, None]
     kpos = positions[..., None, :]
-    causal = (kpos <= qpos)[..., None, :, :]
+    causal = kpos <= qpos
+    if window:
+        causal = causal & (kpos > qpos - window)
+    causal = causal[..., None, :, :]
     scores = jnp.where(causal, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
     return jnp.einsum("...hqk,...khd->...qhd", probs, v)
 
 
 class Attention(nn.Module):
+    """The attention both letters build: "*" turned as ``cfg.rotary``
+    says, "W" turned and inside the configuration's window."""
+
     cfg: GPTConfig
+    rotary: bool        # whether q and k are turned
+    window: int = 0     # keys a query sees (0: every causal key)
 
     @nn.compact
     def __call__(self, x, positions):
@@ -370,7 +409,13 @@ class Attention(nn.Module):
         core = ("ring" if cfg.ring_mesh is not None
                 else "flash" if _resolve_flash(cfg.use_flash, x.shape[-2])
                 else "einsum")
-        _count_trace(n_heads, n_kv, head_dim, core)
+        _count_trace(n_heads, n_kv, head_dim, core, self.window)
+        # a model with both kinds of layer sows a layer's own input and
+        # output where its caller collects ``intermediates``, as the other
+        # mixers do: a check of one layer against a reference
+        sows = bool(cfg.attn_window)
+        if sows:
+            self.sow("intermediates", "attn_input", x)
         with jax.named_scope("attn_proj"):
             q = dense((n_heads, head_dim * (2 if cfg.attn_gate else 1)),
                       "q")(x)
@@ -386,22 +431,31 @@ class Attention(nn.Module):
         if cfg.head_norm:
             with jax.named_scope("attn_norm"):
                 q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
-        if cfg.rotary:
+        if self.rotary:
             turned = (None if cfg.rotary_fraction == 1.0
                       else int(cfg.rotary_fraction * head_dim))
             with jax.named_scope("attn_rope"):
                 q = _rotary(q, positions, cfg.rotary_base, turned)
                 k = _rotary(k, positions, cfg.rotary_base, turned)
         with jax.named_scope("attn_core"):
-            out = _attend(cfg, q, k, v, positions, core)
+            if self.window:
+                # a scope of its own inside the core's, so that a reader
+                # of ``attn_core`` has both kinds and one of this the one
+                with jax.named_scope("attn_window"):
+                    out = _attend(cfg, q, k, v, positions, core, self.window)
+            else:
+                out = _attend(cfg, q, k, v, positions, core)
         if cfg.attn_gate:
             with jax.named_scope("attn_gate"):
                 out = (out.astype(jnp.float32) * jax.nn.sigmoid(
                     gate.astype(jnp.float32))).astype(cfg.dtype)
         with jax.named_scope("attn_out_proj"):
-            return nn.DenseGeneral(cfg.d_model, axis=(-2, -1),
-                                   use_bias=False, dtype=cfg.dtype,
-                                   param_dtype=jnp.float32, name="o")(out)
+            out = nn.DenseGeneral(cfg.d_model, axis=(-2, -1),
+                                  use_bias=False, dtype=cfg.dtype,
+                                  param_dtype=jnp.float32, name="o")(out)
+        if sows:
+            self.sow("intermediates", "attn_output", out)
+        return out
 
 
 class MLP(nn.Module):
@@ -450,7 +504,8 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        x = x + Attention(cfg, name="attn")(_norm(cfg, "ln1")(x), positions)
+        x = x + Attention(cfg, rotary=cfg.rotary, name="attn")(
+            _norm(cfg, "ln1")(x), positions)
         h = _norm(cfg, "ln2")(x)
         if not cfg.n_experts:
             return x + MLP(cfg, name="mlp")(h), None
@@ -460,8 +515,9 @@ class Block(nn.Module):
 
 class MixerBlock(nn.Module):
     """One layer of a patterned model: ``x + mixer(RMSNorm(x))`` with the
-    one mixer ``kind`` names (``GPTConfig.layer_pattern``). Returns
-    ``(x, aux)`` as ``Block`` does."""
+    one mixer ``kind`` names (``GPTConfig.layer_pattern``), or under
+    ``post_norm`` ``x + RMSNorm(mixer(RMSNorm(x)))``. Returns ``(x, aux)``
+    as ``Block`` does."""
 
     cfg: GPTConfig
     kind: str
@@ -471,7 +527,16 @@ class MixerBlock(nn.Module):
         cfg, aux = self.cfg, None
         h = _norm(cfg, "norm")(x)
         if self.kind == "*":
-            out = Attention(cfg, name="attn")(h, positions)
+            out = Attention(cfg, rotary=cfg.rotary, name="attn")(
+                h, positions)
+        elif self.kind == "W":
+            if cfg.attn_window < 1:
+                raise ValueError(
+                    f"layer_pattern holds 'W' and attn_window is "
+                    f"{cfg.attn_window}: a windowed layer sees at least "
+                    f"its own position")
+            out = Attention(cfg, rotary=True, window=cfg.attn_window,
+                            name="attn")(h, positions)
         elif self.kind == "M":
             from horovod_tpu.models.ssm import Mamba2Mixer
 
@@ -525,11 +590,15 @@ class MixerBlock(nn.Module):
         else:
             raise ValueError(
                 f"layer_pattern holds {self.kind!r}: a layer is one of "
-                f"'*' (attention), 'M' (Mamba-2), 'G' (Gated DeltaNet), "
+                f"'*' (attention), 'W' (attention inside a window), "
+                f"'M' (Mamba-2), 'G' (Gated DeltaNet), "
                 f"'K' (Kimi Delta Attention), "
                 f"'C' (gated short convolution), 'L' (latent attention), "
                 f"'S' (attention over chosen keys), 'E' (experts), "
                 f"'-' (MLP)")
+        if cfg.post_norm:
+            with jax.named_scope("post_norm"):
+                out = _norm(cfg, "post_norm")(out)
         return x + out, aux
 
 
@@ -558,12 +627,18 @@ class GPT(nn.Module):
                          (cfg.vocab_size, cfg.d_model), jnp.float32)
         with jax.named_scope("embed"):
             x = emb[tokens].astype(cfg.dtype)
+            if cfg.embed_scale != 1.0:
+                x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
         if cfg.layer_pattern is not None and (
                 len(cfg.layer_pattern) != cfg.n_layers):
             raise ValueError(
                 f"layer_pattern {cfg.layer_pattern!r} names "
                 f"{len(cfg.layer_pattern)} layers, n_layers is "
                 f"{cfg.n_layers}")
+        if cfg.post_norm and cfg.layer_pattern is None:
+            raise ValueError(
+                "post_norm without a layer_pattern is not built: the norm "
+                "after the mixer is a patterned model's (MixerBlock)")
         block = Block if cfg.layer_pattern is None else MixerBlock
         if cfg.remat:
             # everything recomputed but what a held expert layer's rounds

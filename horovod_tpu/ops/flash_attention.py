@@ -56,15 +56,24 @@ choice's ref exists only in a call that passes it.
 **The kernels' surface, in one place.** Every call: ``q [b, s, h, d]``,
 ``k [b, s, h_kv, d]`` (``h_kv`` divides ``h``: grouped queries, read
 zero-copy), ``v [b, s, h_kv, d_v]`` (``d_v`` may differ from ``d``),
-``causal``, ``scale``, a score tile or none. Optional operands, each
-absent from the traced kernels of a call that does not pass it: a rotated
-pair ``q_r [b, s, h, e]``, ``k_r [b, s, e]`` (needs ``h_kv == h``); a
-choice ``[b, s, s]`` int8 (grouped queries allowed). What is not built is
-refused by name (``flash_attention_with_lse``): one of the pair alone, a
-pair whose shapes do not pair, **grouped queries with a rotated pair**, **a
-choice beside a rotated pair**, a choice that is not int8 ``[b, s, s]``,
-and sequence blocks that are not multiples of 8 where the kernel is
-compiled.
+``causal``, ``scale``, a score tile or none. Optional, each absent from
+the traced kernels of a call that does not pass it: a rotated pair ``q_r
+[b, s, h, e]``, ``k_r [b, s, e]`` (needs ``h_kv == h``); a choice ``[b, s,
+s]`` int8 (grouped queries allowed); a ``window``, a static count of keys
+beside ``causal`` (grouped queries allowed): row ``t`` sees ``t - window <
+s <= t``, and **neither kernel visits a tile outside the band**: the
+forward's query block ``[q0, q1)`` walks the key sub-blocks from the one
+holding ``q0 - window + 1`` to the diagonal's, the backward's K block
+``[k0, k1)`` the query sub-blocks from its own to the one holding ``k1 +
+window - 2``, the streamed tile is one sub-block and the grid's last axis
+a band's tiles (``_band``, ``_band_steps``), so the tiles wholly before
+the window are neither computed nor fetched, as those past the diagonal
+are not. What is not built is refused by name
+(``flash_attention_with_lse``): one of the pair alone, a pair whose shapes
+do not pair, **grouped queries with a rotated pair**, **a choice beside a
+rotated pair**, a choice that is not int8 ``[b, s, s]``, **a window
+without ``causal``, beside a choice or beside a rotated pair**, and
+sequence blocks that are not multiples of 8 where the kernel is compiled.
 
 No reference-framework counterpart (Horovod ships gradients, not kernels);
 this is part of the TPU framework's compute path. On the CPU the same
@@ -187,12 +196,15 @@ def _scaled(x, scale):
     return x, scale
 
 
-def _visible(q0, k0, shape):
+def _visible(q0, k0, shape, window=None):
     """Causal mask of a [queries, keys] score sub-block whose first query
-    position is ``q0`` and first key position ``k0``."""
+    position is ``q0`` and first key position ``k0``; with a ``window``,
+    of the causal keys the last ``window`` alone: ``t - window < s <= t``."""
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return k_pos <= q_pos
+    if window is None:
+        return k_pos <= q_pos
+    return (k_pos <= q_pos) & (k_pos > q_pos - window)
 
 
 def _chosen(mask):
@@ -217,8 +229,30 @@ def _sub_block(j, block):
     return pl.ds(pl.multiple_of(j * block, block), block)
 
 
+def _band(kernel, i, block, tile, window):
+    """``(first, last)`` tile of the streamed operand that holds anything
+    for block ``i`` of the kept one under a window: the forward's query
+    block ``[q0, q1)`` sees the keys ``q0 - window + 1 .. q1 - 1``, and the
+    backward's K block ``[k0, k1)`` is seen by the rows ``k0 .. k1 + window
+    - 2`` (``last`` may lie past the sequence's end). ``i`` a grid index or
+    a Python int."""
+    most = max if isinstance(i, int) else jnp.maximum
+    if kernel == "fwd":
+        return most(i * block - window + 1, 0) // tile, (
+            (i + 1) * block - 1) // tile
+    return i * block // tile, ((i + 1) * block + window - 2) // tile
+
+
+def _band_steps(kernel, s, block, tile, window):
+    """The most tiles a block's band holds: a windowed call's last grid
+    axis, where a call without one has ``s // tile``."""
+    spans = (_band(kernel, i, block, tile, window)
+             for i in range(s // block))
+    return max(min(last, s // tile - 1) - first + 1 for first, last in spans)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
-                choice=False):
+                choice=False, window=None):
     # a rotated pair, where the caller passed one, comes after the three
     # operands every call has: q_r's block and the shared k_r's tile; a
     # choice, where the caller passed one, after those: the block's rows
@@ -229,10 +263,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
     rows = block_q // chains
     tile = k_ref.shape[2]
     qi = pl.program_id(2)
-    ti = pl.program_id(3)
+    step = pl.program_id(3)
     n_t = pl.num_programs(3)
+    # the tile this step holds: the grid's own, or with a window the
+    # ``step``-th of the block's band (``_band``)
+    ti = step if window is None else step + _band(
+        "fwd", qi, block_q, tile, window)[0]
 
-    @pl.when(ti == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -261,10 +299,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
                     sc = jnp.where(_chosen(choice_ref[
                         0, mine, _sub_block(j, block_k)]), sc, -jnp.inf)
                 elif causal:
+                    # past a window's far edge a row may see no key of a
+                    # sub-block, as under a choice: ``-inf`` there
                     sc = jnp.where(
                         _visible(qi * block_q + c * rows,
-                                 ti * tile + j * block_k, sc.shape),
-                        sc, _NEG_INF)
+                                 ti * tile + j * block_k, sc.shape, window),
+                        sc, _NEG_INF if window is None else -jnp.inf)
                 return sc
 
             def softmax_and_values(c, sc):
@@ -293,7 +333,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
         n_sub = tile // block_k
         n_eff = (_causal_n_eff(qi, block_q, ti, tile, block_k, n_sub)
                  if causal else n_sub)
-        jax.lax.fori_loop(0, n_eff, body, 0)
+        # the sub-block holding the first key the block's first row sees
+        first = 0 if window is None else jnp.clip(
+            (qi * block_q - window + 1 - ti * tile) // block_k, 0, n_sub)
+        jax.lax.fori_loop(first, n_eff, body, 0)
 
     if causal:
         # tiles entirely above the diagonal still stream past (the
@@ -302,7 +345,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
     else:
         _tile()
 
-    @pl.when(ti == n_t - 1)
+    @pl.when(step == n_t - 1)
     def _finalize():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
@@ -310,7 +353,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
 
 
 def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *refs,
-                scale, causal, block_q, chains, choice=False):
+                scale, causal, block_q, chains, choice=False, window=None):
     # with a rotated pair each group of refs (in, out, scratch) has two
     # more at its end: k_r's block and q_r's tile, dq_r and a head's dk_r,
     # their accumulators; a choice (never beside a pair) is one more
@@ -326,21 +369,37 @@ def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *refs,
     block_k = k_ref.shape[2]
     tile = q_ref.shape[2]
     ki = pl.program_id(2)
-    ti = pl.program_id(3)     # Q/dO/lse/delta tiles stream
+    step = pl.program_id(3)   # Q/dO/lse/delta tiles stream
     n_k = pl.num_programs(2)
     n_t = pl.num_programs(3)
+    if window is None:
+        ti = step
+        first_block, last_block = (lambda: ki == 0), (lambda: ki == n_k - 1)
+    else:
+        # the ``step``-th tile of the K block's band (``_band``), which may
+        # lie past it or past the sequence; a tile's first K block is the
+        # one holding the first key its first row sees, and its last (the
+        # band begins in the tile the block lies in) ends where it ends
+        first, last = _band("bwd", ki, block_k, tile, window)
+        ti = step + first
+        in_band = ti <= jnp.minimum(last, dq_acc_ref.shape[0] // tile - 1)
+        first_block = lambda: in_band & (ki == jnp.maximum(
+            ti * tile - window + 1, 0) // block_k)
+        last_block = lambda: (step == 0) & (
+            (ki + 1) % (tile // block_k) == 0)
 
-    @pl.when(ti == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
         if rotated:
             dkr_acc_ref[...] = jnp.zeros_like(dkr_acc_ref)
 
-    @pl.when(ki == 0)
+    @pl.when(first_block())
     def _init_dq():
         # this tile's rows of the whole-sequence accumulator: every K
-        # block adds to them, in the order the grid visits the blocks
+        # block that sees them adds to them, in the order the grid visits
+        # the blocks
         rows = _sub_block(ti, tile)
         dq_acc_ref[rows, :] = jnp.zeros((tile, dq_acc_ref.shape[1]),
                                         jnp.float32)
@@ -380,8 +439,9 @@ def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *refs,
                                -jnp.inf)
             elif causal:
                 sc = jnp.where(
-                    _visible(ti * tile + j * rows, ki * block_k, sc.shape),
-                    sc, _NEG_INF)
+                    _visible(ti * tile + j * rows, ki * block_k, sc.shape,
+                             window),
+                    sc, _NEG_INF if window is None else -jnp.inf)
             p = jnp.exp(sc - lse)
             dv_acc_ref[...] += dot(p, do, TN)
             dp = dot(do, v, NT)
@@ -410,15 +470,22 @@ def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *refs,
                 pl.when(first % 2 == 1)(lambda: sub_block(first, piece))
         else:
             start = 0
-        jax.lax.fori_loop(start, n_sub, whole, 0)
+        # with a window, no sub-block whose first row is past the last row
+        # that sees the block's last key
+        stop = n_sub if window is None else jnp.clip(
+            ((ki + 1) * block_k + window - 2 - ti * tile) // block_q + 1,
+            0, n_sub)
+        jax.lax.fori_loop(start, stop, whole, 0)
 
-    if causal:
+    if window is not None:
+        pl.when(in_band)(_tile)
+    elif causal:
         # tiles whose every Q position precedes this K block are skipped
         pl.when((ti + 1) * tile > ki * block_k)(_tile)
     else:
         _tile()
 
-    @pl.when(ti == n_t - 1)
+    @pl.when(step == n_t - 1)
     def _finalize():
         # dS^T Q and dS K carry the scale once, here, not once a sub-block
         dk_ref[0, 0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
@@ -426,7 +493,7 @@ def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *refs,
         if rotated:
             dkr_ref[0, 0] = (dkr_acc_ref[...] * scale).astype(dkr_ref.dtype)
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(last_block())
     def _finalize_dq():
         # the last K block has passed this tile's rows: dQ leaves once
         dq_ref[0, 0] = (dq_acc_ref[_sub_block(ti, tile), :]
@@ -600,25 +667,28 @@ def _score_tile(kernel, s, d, itemsize, causal, block_q, block_k, d_v=None,
     return bq, bk, derived
 
 
-def _count_trace(kernel, block_q, block_k, derived, d, d_v, d_rot, chains):
+def _count_trace(kernel, block_q, block_k, derived, d, d_v, d_rot, chains,
+                 window):
     """Which score tile each traced kernel got, whether the rule or the
     caller chose it, the two widths it was built for (q and k's whole
     width, v and o's), how many of q and k's columns came as a rotated
     pair of their own (0: q and k came whole) and into how many pieces by
     query rows a pass of its loop takes its sub-block (``_chains``: the
     forward's two chains side by side; the backward's two halves, of which
-    a causal pass leaves out the one that sees nothing)."""
+    a causal pass leaves out the one that sees nothing), and the window it
+    was built with (0: none, and the schedule has one bound)."""
     _pallas.count_trace(
         "hvt_flash_kernel_traces_total",
         "flash-attention kernels traced into compiled programs, by "
         "score tile (counted per trace, not per execution)",
         kernel=kernel, block_q=block_q, block_k=block_k,
-        derived=int(derived), d_qk=d, d_v=d_v, d_rot=d_rot, chains=chains)
+        derived=int(derived), d_qk=d, d_v=d_v, d_rot=d_rot, chains=chains,
+        window=window or 0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, rotated, choice, scale, causal, block_q, block_k,
-           out_dtype):
+           out_dtype, window=None):
     """Differentiable (o, lse). The lse output carries its own gradient:
     d lse/dS = P, so a dlse cotangent folds into the backward kernel as
     delta := rowsum(do∘o) − dlse — the kernel is unchanged.
@@ -627,7 +697,7 @@ def _flash(q, k, v, rotated, choice, scale, causal, block_q, block_k,
     ``(q_r [b, h, s, e], k_r [b, s, e])`` (``_rotated_width``);
     ``choice`` is None or the mask ``[b, s, s]`` int8 (no gradient)."""
     o, lse = _flash_fwd_impl(q, k, v, rotated, choice, scale, causal,
-                             block_q, block_k, out_dtype)
+                             block_q, block_k, out_dtype, window)
     return o, lse
 
 
@@ -637,10 +707,14 @@ def _flash(q, k, v, rotated, choice, scale, causal, block_q, block_k,
 _SEQ_TILE = 4096
 
 
-def _seq_tile(s, block_q, block_k):
+def _seq_tile(s, block_q, block_k, window=None):
     """Streamed-sequence VMEM tile (elements of the seq axis per grid
     step): the largest multiple of lcm(block_q, block_k) that divides
-    ``s`` and is at most ``_SEQ_TILE``.
+    ``s`` and is at most ``_SEQ_TILE``; with a window that one multiple
+    itself, the least the loops can walk, so that the tiles a block's band
+    fetches cover it closely (at 16,384 positions, a window of 2,048 and
+    512 x 1024 three tiles of 1,024 keys a query block, where two of 4,096
+    would be fetched for the same 2,559).
 
     The tile must divide ``s`` AND be a multiple of both block sizes —
     the kernels walk ``tile // block`` sub-blocks, so a remainder would
@@ -649,7 +723,7 @@ def _seq_tile(s, block_q, block_k):
     exists."""
     base = math.lcm(block_q, block_k)
     best, m = base, 2
-    while m * base <= _SEQ_TILE:
+    while window is None and m * base <= _SEQ_TILE:
         if s % (m * base) == 0:
             best = m * base
         m += 1
@@ -696,10 +770,11 @@ class _Plan(NamedTuple):
     tile: int           # positions of the streamed operand a grid step
     chains: int         # query-row pieces of a sub-block (``_chains``)
     interpret: bool
+    window: int | None = None   # keys a query sees, itself among them
 
 
 def _plan(kernel, q, scale, causal, block_q, block_k, d_v=None, d_rot=0,
-          choice=False):
+          choice=False, window=None):
     """Made outside the jitted calls below, so that what the process
     holds besides the operands (the backend) is part of their cache's
     key and never read under a cached trace. ``d_rot``: the width of a
@@ -710,17 +785,17 @@ def _plan(kernel, q, scale, causal, block_q, block_k, d_v=None, d_rot=0,
         kernel, s, d + d_rot, q.dtype.itemsize, causal, block_q, block_k,
         d_v, choice)
     return _Plan(scale, causal, block_q, block_k, derived,
-                 _seq_tile(s, block_q, block_k),
+                 _seq_tile(s, block_q, block_k, window),
                  _chains(kernel, block_q, block_k, q.dtype.itemsize, causal),
-                 _pallas.interpret())
+                 _pallas.interpret(), window)
 
 
 def _flash_fwd_impl(q, k, v, rotated, choice, scale, causal, block_q,
-                    block_k, out_dtype):
+                    block_k, out_dtype, window=None):
     return _fwd_call(q, k, v, rotated, choice, out_dtype=out_dtype,
                      plan=_plan("fwd", q, scale, causal, block_q, block_k,
                                 v.shape[-1], _rotated_width(rotated),
-                                choice is not None))
+                                choice is not None, window))
 
 
 # Each of the two calls is a ``jax.jit`` of its own: a model's layers
@@ -734,8 +809,9 @@ def _fwd_call(q, k, v, rotated=None, choice=None, *, plan, out_dtype):
     d_v, e = v.shape[-1], _rotated_width(rotated)
     block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
     chosen = choice is not None
+    window = plan.window
     _count_trace("fwd" + "_choice" * chosen, block_q, block_k, plan.derived,
-                 d + e, d_v, e, plan.chains)
+                 d + e, d_v, e, plan.chains, window)
     # Grouped-query attention is served ZERO-COPY: query head hi reads
     # K/V head hi // group through the block index map — no repeat
     # materialization, and the shared K/V tile stays VMEM-resident
@@ -743,12 +819,25 @@ def _fwd_call(q, k, v, rotated=None, choice=None, *, plan, out_dtype):
     group = h // k.shape[1]
     # K/V stream through the grid's sequential LAST axis in VMEM tiles;
     # scratch accumulators carry the online softmax across tiles
-    grid = (b, h, s // block_q, s // tile)
+    # with a window the last axis is as long as a block's band and not as
+    # the sequence, and a step names its tile of the band: the tiles
+    # wholly before the window are neither walked nor fetched, and the
+    # steps past the diagonal name the diagonal's tile again
+    grid = (b, h, s // block_q, s // tile if window is None
+            else _band_steps("fwd", s, block_q, tile, window))
+
+    def held(qi, ti):
+        if window is None:
+            return ti
+        first, last = _band("fwd", qi, block_q, tile, window)
+        return jnp.minimum(first + ti, last)
+
     # q and k are ``d`` wide, v and o ``d_v``
     by_query = lambda width: pl.BlockSpec(
         (1, 1, block_q, width), lambda bi, hi, qi, ti: (bi, hi, qi, 0))
     by_tile = lambda width: pl.BlockSpec(
-        (1, 1, tile, width), lambda bi, hi, qi, ti: (bi, hi // group, ti, 0))
+        (1, 1, tile, width),
+        lambda bi, hi, qi, ti: (bi, hi // group, held(qi, ti), 0))
     in_specs = [by_query(d), by_tile(d), by_tile(d_v)]
     if rotated is not None:
         # q_r as q; the one k_r a position, whatever the head
@@ -762,7 +851,7 @@ def _fwd_call(q, k, v, rotated=None, choice=None, *, plan, out_dtype):
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=plan.scale,
                           causal=plan.causal, block_k=block_k,
-                          chains=plan.chains, choice=chosen),
+                          chains=plan.chains, choice=chosen, window=window),
         grid=grid,
         in_specs=in_specs,
         out_specs=[by_query(d_v), by_query(1)],
@@ -781,13 +870,13 @@ def _fwd_call(q, k, v, rotated=None, choice=None, *, plan, out_dtype):
 
 
 def _flash_fwd(q, k, v, rotated, choice, scale, causal, block_q, block_k,
-               out_dtype):
+               out_dtype, window=None):
     o, lse = _flash_fwd_impl(q, k, v, rotated, choice, scale, causal,
-                             block_q, block_k, out_dtype)
+                             block_q, block_k, out_dtype, window)
     return (o, lse), (q, k, v, rotated, choice, o, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
+def _flash_bwd(scale, causal, block_q, block_k, out_dtype, window, res, cot):
     do, dlse = cot
     q, k, v, rotated, choice, o, lse = res
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -797,7 +886,7 @@ def _flash_bwd(scale, causal, block_q, block_k, out_dtype, res, cot):
     return _bwd_call(q, k, v, do, lse, delta, rotated, choice,
                      plan=_plan("bwd", q, scale, causal, block_q, block_k,
                                 v.shape[-1], _rotated_width(rotated),
-                                choice is not None))
+                                choice is not None, window))
 
 
 @functools.partial(jax.jit, static_argnames="plan")
@@ -820,8 +909,9 @@ def _bwd_call(q, k, v, do, lse, delta, rotated=None, choice=None, *, plan):
     d_v, e = v.shape[-1], _rotated_width(rotated)
     block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
     chosen = choice is not None
+    window = plan.window
     _count_trace("bwd" + "_choice" * chosen, block_q, block_k, plan.derived,
-                 d + e, d_v, e, plan.chains)
+                 d + e, d_v, e, plan.chains, window)
     group = h // k.shape[1]
     n_k = s // block_k
     # q, k, dq and dk are ``d`` wide, v, do and dv ``d_v``
@@ -836,13 +926,30 @@ def _bwd_call(q, k, v, do, lse, delta, rotated=None, choice=None, *, plan):
     # about 10 us a step that nothing hides: PERF.md section 6, PR 54). So
     # they name the first tile the block does see, which the pipeline
     # fetches once and keeps until the grid has passed it.
-    seen = lambda ki, ti: (jnp.maximum(ti, ki * block_k // tile)
-                           if plan.causal else ti)
+    # With a window the last axis is as long as a K block's band (the
+    # tiles whose rows see a key of it) and a step names its tile of the
+    # band, the steps past the band's end the last again; and a tile of
+    # dQ leaves when the last K block that its rows see has passed it,
+    # which is the block that ends where the tile ends, at its band's
+    # first step: until then the index stays at the next tile to leave.
+    def seen(ki, ti):
+        if window is not None:
+            first, last = _band("bwd", ki, block_k, tile, window)
+            return jnp.minimum(first + ti, jnp.minimum(last, s // tile - 1))
+        return jnp.maximum(ti, ki * block_k // tile) if plan.causal else ti
+
+    def leaving(ki, ti):
+        if window is None:
+            return jnp.where(ki == n_k - 1, ti, 0)
+        blocks = tile // block_k
+        return jnp.where(ti == 0, ki // blocks, jnp.minimum(
+            (ki + 1) // blocks, s // tile - 1))
+
     q_tile = lambda width: pl.BlockSpec(
         (1, 1, tile, width), lambda bi, hi, ki, ti: (bi, hi, seen(ki, ti), 0))
     dq_tile = lambda width: pl.BlockSpec(
         (1, 1, tile, width),
-        lambda bi, hi, ki, ti: (bi, hi, jnp.where(ki == n_k - 1, ti, 0), 0))
+        lambda bi, hi, ki, ti: (bi, hi, leaving(ki, ti), 0))
     operands = (q, k, v, do, lse, delta)
     inputs = (k, v, q, do, lse, delta)
     in_specs = [kv_in_ki(d), kv_in_ki(d_v), q_tile(d), q_tile(d_v),
@@ -873,8 +980,9 @@ def _bwd_call(q, k, v, do, lse, delta, rotated=None, choice=None, *, plan):
     dq, dk, dv, *d_rotated = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=plan.scale,
                           causal=plan.causal, block_q=block_q,
-                          chains=plan.chains, choice=chosen),
-        grid=(b, h, n_k, s // tile),
+                          chains=plan.chains, choice=chosen, window=window),
+        grid=(b, h, n_k, s // tile if window is None
+              else _band_steps("bwd", s, block_k, tile, window)),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -902,7 +1010,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, q_r=None, k_r=None, choice=None, causal=True,
-                    scale=None, block_q=None, block_k=None):
+                    window=None, scale=None, block_q=None, block_k=None):
     """Fused multi-head attention.
 
     Args:
@@ -925,6 +1033,11 @@ def flash_attention(q, k, v, *, q_r=None, k_r=None, choice=None, causal=True,
         where there is one; ``causal`` still says which tiles no row can
         see. No gradient.
       causal: apply causal masking.
+      window: None, or how many keys a query sees, itself among them: row
+        ``t`` attends ``t - window < s <= t`` (sliding-window attention).
+        A static count; needs ``causal``. Neither kernel visits a tile
+        that lies wholly outside the band
+        (``flash_attention_with_lse``).
       scale: softmax scale, default ``head_dim ** -0.5``.
       block_q / block_k: the score tile; ``None`` (the default) derives
         it from the shape, one tile a kernel (``_derive_tile``); an
@@ -934,14 +1047,15 @@ def flash_attention(q, k, v, *, q_r=None, k_r=None, choice=None, causal=True,
     (custom VJP with a recompute-based backward kernel).
     """
     o, _ = flash_attention_with_lse(q, k, v, q_r=q_r, k_r=k_r, choice=choice,
-                                    causal=causal, scale=scale,
-                                    block_q=block_q, block_k=block_k)
+                                    causal=causal, window=window,
+                                    scale=scale, block_q=block_q,
+                                    block_k=block_k)
     return o
 
 
 def flash_attention_with_lse(q, k, v, *, q_r=None, k_r=None, choice=None,
-                             causal=True, scale=None, block_q=None,
-                             block_k=None, out_dtype=None):
+                             causal=True, window=None, scale=None,
+                             block_q=None, block_k=None, out_dtype=None):
     """Fused attention returning ``(o, lse)``; both are differentiable.
     ``q`` and ``k`` share one width and ``v`` and ``o`` another, which may
     be the same (``flash_attention``).
@@ -960,6 +1074,20 @@ def flash_attention_with_lse(q, k, v, *, q_r=None, k_r=None, choice=None,
     reading the same tile of it; ``lse`` is then over the chosen keys. A
     row has to see at least one key. Grouped queries are served as without
     one; a rotated pair beside a choice is not built.
+
+    With ``window`` (a static count of keys, beside ``causal``) row ``t``
+    sees the keys ``t - window < s <= t``: the mask gains its second bound
+    and so does the schedule. The forward's query block walks the
+    sub-blocks from the one holding ``q0 - window + 1`` to the diagonal's;
+    the backward's K block the query sub-blocks up to the one holding ``k1
+    + window - 2``. The streamed tile is one sub-block long, the grid's
+    last axis as long as a block's band (``_band_steps``) and not as the
+    sequence, and a step's block index is its tile of the band, so what
+    lies outside is neither computed nor fetched. A tile of dQ leaves as
+    soon as the last K block its rows see has passed. A window no shorter
+    than the sequence hides nothing, and the results are those of the call
+    without one to the last bit. Refused: a window without ``causal``,
+    beside a choice, beside a rotated pair.
 
     ``lse[b, s, h]`` is the log-sum-exp of the (scaled, masked) scores for
     each query — exactly what blockwise/ring composition needs to combine
@@ -1007,6 +1135,24 @@ def flash_attention_with_lse(q, k, v, *, q_r=None, k_r=None, choice=None,
                 f"a choice is int8 [batch, seq, seq] = {(b, s, s)}, one row "
                 f"of keys a query for every head; got {choice.dtype} "
                 f"{choice.shape}")
+    if window is not None:
+        if isinstance(window, bool) or not isinstance(window, int) or (
+                window < 1):
+            raise ValueError(
+                f"a window is a static count of keys, at least 1 (the "
+                f"query's own position); got {window!r}")
+        if not causal:
+            raise ValueError(
+                "a window without causal is not built: the window is the "
+                "last `window` of the causal keys")
+        if choice is not None:
+            raise ValueError(
+                "a window beside a choice of keys is not built: put the "
+                "window into the choice, or pass no choice")
+        if e:
+            raise ValueError(
+                "a window beside a rotated pair is not built: pass q and k "
+                "whole, or no window")
     if scale is None:
         scale = (d + e) ** -0.5
     bq, bk, _ = _score_tile("fwd", s, d + e, q.dtype.itemsize, causal,
@@ -1026,7 +1172,7 @@ def flash_attention_with_lse(q, k, v, *, q_r=None, k_r=None, choice=None,
     o, lse = _flash(to_bhsd(q), to_bhsd(k), to_bhsd(v),
                     None if q_r is None else (to_bhsd(q_r), k_r), choice,
                     float(scale), bool(causal), block_q, block_k,
-                    jnp.dtype(out_dtype or q.dtype))
+                    jnp.dtype(out_dtype or q.dtype), window)
     # lse: [B, H, S, 1] → [B, S, H]
     return jnp.transpose(o, (0, 2, 1, 3)), jnp.transpose(lse[..., 0],
                                                          (0, 2, 1))

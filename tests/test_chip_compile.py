@@ -826,6 +826,34 @@ def test_flash_kernels_with_a_choice_compile_for_v5e(compiled_kernel,
     assert "hvt_flash_fwd" in text and "hvt_flash_bwd" in text
 
 
+def test_flash_kernels_with_a_window_compile_for_v5e(compiled_kernel,
+                                                     v5e_devices):
+    """``trinitymini-s16384``'s windowed calls: 16,384 positions, 32 query
+    heads on 4 key-value heads of 128, a window of 2,048: forward and
+    backward, one Pallas call each, and the schedule's second bound: both
+    grids' last axis is three tiles of 1,024 keys (rows), where a full
+    walk's is four of 4,096."""
+    like, z = _dsa_like(v5e_devices[0]), DSA_SHAPE
+    q = like(jnp.bfloat16, z["b"], z["s"], z["h"], z["d"])
+    kv = like(jnp.bfloat16, z["b"], z["s"], z["h_kv"], z["d"])
+
+    def step(window):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: (fa.flash_attention(q, k, v, **window).astype(
+                jnp.float32) ** 2).mean(), argnums=(0, 1, 2)))
+
+    from test_flash_window import _grids
+
+    walk = lambda window: _grids(step(window), q, kv, kv)
+    assert walk({}) == [(1, 32, 32, 4), (1, 32, 32, 4)]
+    assert walk({"window": 2048}) == [(1, 32, 32, 3), (1, 32, 32, 3)]
+    text = step({"window": 2048}).lower(q, kv, kv).compile().as_text()
+    calls = re.findall(r" custom-call\((.*?)\), custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert len(calls) == 2
+    assert "hvt_flash_fwd" in text and "hvt_flash_bwd" in text
+
+
 @pytest.mark.parametrize("rows", [512, 16384])
 def test_choice_of_2048_keys_compiles_for_v5e(rows, compiled_kernel,
                                               v5e_devices):

@@ -510,6 +510,7 @@ def _trace_count(**labels):
     from horovod_tpu import metrics
 
     labels.setdefault("d_rot", "0")     # q and k came whole
+    labels.setdefault("window", "0")    # and every causal key is seen
     # the pieces by query rows a pass takes its sub-block in (``_chains``),
     # for float32 operands and a causal call
     from horovod_tpu.ops import flash_attention as fa

@@ -154,14 +154,26 @@ def _mixer(chunk=None, dtype=jnp.float32, seq=24, batch=2, seed=0):
     that the gates and the decays are not all alike) and its input."""
     layer = KimiDeltaAttention(HEADS, D_H, 4, RANK, chunk=chunk, dtype=dtype)
     u = jax.random.normal(jax.random.key(seed), (batch, seq, D_MODEL))
-    params = layer.init(jax.random.key(seed + 1), u)["params"]
-    keys = iter(jax.random.split(jax.random.key(seed + 2), len(params)))
     moved = {"norm_scale": 0.3, "dt_bias": 0.3, "in_proj_qkv": 0.3,
              "in_proj_beta": 0.5, "decay_down": 0.5, "decay_up": 0.5,
              "gate_down": 0.5, "gate_up": 0.5, "out_proj": 0.2}
-    params = {name: w + moved.get(name, 0.0) * jax.random.normal(
-        next(keys), w.shape) for name, w in params.items()}
-    return layer, params, u
+
+    # (one compiled program: op by op, this is most of a case's seconds)
+    @jax.jit
+    def init(u):
+        params = layer.init(jax.random.key(seed + 1), u)["params"]
+        keys = iter(jax.random.split(jax.random.key(seed + 2), len(params)))
+        return {name: w + moved.get(name, 0.0) * jax.random.normal(
+            next(keys), w.shape) for name, w in params.items()}
+
+    return layer, init(u), u
+
+
+def _run(layer, params, u, **collect):
+    """``layer.apply`` as one compiled program, traced anew a call (so
+    what a test steers from outside is read again)."""
+    return jax.jit(lambda p, u: layer.apply({"params": p}, u, **collect))(
+        params, u)
 
 
 def _reference(params, u):
@@ -206,12 +218,12 @@ def test_state_and_decays_are_float32_in_a_bf16_layer():
                              dtype=jnp.bfloat16)
     rel = lambda got: float(jnp.linalg.norm(got.astype(jnp.float32) - want)
                             / jnp.linalg.norm(want))
-    assert rel(low.apply({"params": params}, u)) < 3e-2
-    assert rel(layer.apply({"params": params}, u)) < 1e-5
+    assert rel(_run(low, params, u)) < 3e-2
+    assert rel(_run(layer, params, u)) < 1e-5
     was = kda.STATE_DTYPE
     try:
         kda.STATE_DTYPE = jnp.bfloat16
-        assert rel(layer.apply({"params": params}, u)) > 1e-3
+        assert rel(_run(layer, params, u)) > 1e-3
     finally:
         kda.STATE_DTYPE = was
     # the cumulative sums and the carried state of the bf16 layer, a head
@@ -226,7 +238,7 @@ def test_traced_layers_are_counted_and_sown():
     from horovod_tpu import metrics
 
     layer, params, u = _mixer(chunk=8)
-    out, sown = layer.apply({"params": params}, u, mutable=["intermediates"])
+    out, sown = _run(layer, params, u, mutable=["intermediates"])
     mine = sown["intermediates"]
     np.testing.assert_array_equal(np.asarray(mine["kda_input"][0]),
                                   np.asarray(u))
@@ -304,10 +316,9 @@ def test_wrong_mixers_are_told_from_the_sound_one(wrong, monkeypatch):
     want = _reference(params, u)
     rel = lambda got: float(jnp.linalg.norm(got - want)
                             / jnp.linalg.norm(want))
-    assert rel(layer.apply({"params": params}, u)) < 1e-5
+    assert rel(_run(layer, params, u)) < 1e-5
     WRONG_MIXERS[wrong](monkeypatch)
-    assert rel(layer.apply({"params": params}, u)) > family.MIXER_BOUNDS[
-        "kda", "mixer"]
+    assert rel(_run(layer, params, u)) > family.MIXER_BOUNDS["kda", "mixer"]
 
 
 def _lower_precisions():
@@ -337,7 +348,7 @@ def test_a_float32_part_in_bf16_is_told_by_the_float32_products(part):
                              dtype=jnp.bfloat16)
 
     def found():
-        _, sown = low.apply({"params": params}, u, mutable=["intermediates"])
+        _, sown = _run(low, params, u, mutable=["intermediates"])
         return family.mixer_distances(
             {name: value[0] for name, value in sown["intermediates"].items()},
             params, _CONFIG, "kda", layer)

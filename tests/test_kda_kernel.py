@@ -216,7 +216,7 @@ def test_float32_parts_in_bf16_reach_the_kernels(monkeypatch):
     from benchmarks import kimilinear_wrong_programs as script
     from benchmarks.qwen3next_wrong_programs import _swapped
     from chipbench.families import kimi_linear as family
-    from tests.test_kda import _CONFIG, HEADS, RANK, D_H, _mixer
+    from tests.test_kda import _CONFIG, HEADS, RANK, D_H, _mixer, _run
 
     monkeypatch.setattr(rule_op, "serves", lambda *shape: True)
     layer, params, u = _mixer(chunk=8, seq=32, batch=1)
@@ -224,7 +224,7 @@ def test_float32_parts_in_bf16_reach_the_kernels(monkeypatch):
                                  dtype=jnp.bfloat16)
 
     def found():
-        _, sown = low.apply({"params": params}, u, mutable=["intermediates"])
+        _, sown = _run(low, params, u, mutable=["intermediates"])
         return family.mixer_distances(
             {name: value[0] for name, value in sown["intermediates"].items()},
             params, _CONFIG, "kda", layer)

@@ -211,17 +211,18 @@ def test_the_shares_of_the_attention_heads_add_up(count):
                      dtype=jnp.float32, use_flash=False)
     x = jax.random.normal(jax.random.key(0), (2, 12, 32))
     positions = jnp.broadcast_to(jnp.arange(12), (2, 12))
-    whole = Attention(base).init(jax.random.key(1), x, positions)["params"]
+    layer = lambda cfg: Attention(cfg, rotary=cfg.rotary)
+    whole = layer(base).init(jax.random.key(1), x, positions)["params"]
     want = jax.lax.map(lambda one: reference.attention(one, whole), x)
     total = 0.0
     for first in range(0, 8, count):
         cfg = dataclasses.replace(base, heads_held=(first, count))
         mine = _take_attention_heads(whole, first, count, 4)
-        shapes = jax.eval_shape(Attention(cfg).init, jax.random.key(1), x,
+        shapes = jax.eval_shape(layer(cfg).init, jax.random.key(1), x,
                                 positions)["params"]
         assert jax.tree.map(lambda a: a.shape, mine) == jax.tree.map(
             lambda a: a.shape, jax.tree.map(lambda a: a, dict(shapes)))
-        total = total + Attention(cfg).apply({"params": mine}, x, positions)
+        total = total + layer(cfg).apply({"params": mine}, x, positions)
     np.testing.assert_allclose(np.asarray(total), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
 

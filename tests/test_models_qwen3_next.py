@@ -71,7 +71,7 @@ def test_gated_attention_matches_the_formula():
     from horovod_tpu.models.transformer import Attention
 
     model, _, _ = small.qwen_model()
-    layer = Attention(model.cfg)
+    layer = Attention(model.cfg, rotary=model.cfg.rotary)
     x = jax.random.normal(jax.random.key(3), (2, 24, 32))
     positions = jnp.broadcast_to(jnp.arange(24), (2, 24))
     params = layer.init(jax.random.key(4), x, positions)["params"]

@@ -336,17 +336,22 @@ def _other(name):
         params = jax.jit(GPT(cfg).init)(jax.random.key(0), tokens)["params"]
         return cfg, (lambda model: lambda p: model.apply(
             {"params": p}, tokens).astype(jnp.float32).sum()), params
-    if name == "olmoe":
-        model, params, tokens = others.sparse_model(remat=True)
-        return model.cfg, (lambda model: lambda p: others.sparse_loss(
-            model, p, tokens)), params
-    if name == "nemotron_h":
-        model, params, buffers, tokens = others.hybrid_model(remat=True)
-        return model.cfg, (lambda model: lambda p: others.hybrid_loss(
-            model, p, buffers, tokens)), params
-    model, params, tokens = others.qwen_model(remat=True)
-    return model.cfg, (lambda model: lambda p: others.qwen_loss(
-        model, p, tokens)), params
+    # the sparse and hybrid models by their shapes alone: nothing here
+    # reads a value, and their builders initialise op by op
+    make = {"olmoe": others.sparse_model, "nemotron_h": others.hybrid_model,
+            "qwen3_next": others.qwen_model}[name]
+    seen = []
+
+    def shapes():
+        model, *rest = make(remat=True)
+        seen.append(model.cfg)
+        return rest
+
+    params, *given = jax.eval_shape(shapes)
+    given = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), given)
+    loss_of = {"olmoe": others.sparse_loss, "nemotron_h": others.hybrid_loss,
+               "qwen3_next": others.qwen_loss}[name]
+    return seen[0], (lambda model: lambda p: loss_of(model, p, *given)), params
 
 
 @pytest.mark.parametrize("name", _OTHERS)
