@@ -4,8 +4,9 @@
 # is the same intent for one TPU/CPU host).
 #
 #   ./ci.sh            # full: build + lint + tests + dryrun
-#   ./ci.sh --fast     # inner loop: quick-marked tests only (~minutes
-#                      # vs ~37 min full on the 1-core host)
+#   ./ci.sh --fast     # inner loop: quick-marked tests only (~minutes;
+#                      # the whole of tests/ is 9,000 CPU-seconds,
+#                      # ~21 min with six workers on eight cores)
 #   ./ci.sh --chaos    # build + the fault-injection / failure-
 #                      # containment suite only (SIGKILL/SIGSTOP gangs,
 #                      # deadline bounds, abort metrics)
@@ -14,7 +15,13 @@
 #                      # kinds / frame flags, env-var docs coverage
 #   ./ci.sh --sanitize # TSan + UBSan engine builds + the sanitizer
 #                      # gang suite (one command instead of the
-#                      # hand-assembled HVT_CORE_LIB/LD_PRELOAD dance)
+#                      # hand-assembled HVT_CORE_LIB/LD_PRELOAD dance).
+#                      # The TSan gang and the ASan and UBSan replays of
+#                      # the fuzz corpus are marked `slow`: they run here
+#                      # and in the full run, not under `-m 'not slow'`;
+#                      # the shm-against-ring timing of
+#                      # tests/test_engine_scaling.py, `slow` too, runs
+#                      # in the full run only
 #   ./ci.sh --loadtest # build + a tiny loopback ReplicaGang replay
 #                      # (horovod_tpu.serving.loadgen --smoke) + the
 #                      # artifact schema check

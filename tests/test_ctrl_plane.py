@@ -87,7 +87,7 @@ def run_gang(body, np=4, hosts=2, topology="tree", timeout=120,
                     timeout=max(1.0, deadline - time.monotonic()))
             except subprocess.TimeoutExpired:
                 p.kill()
-                out, _ = p.communicate()
+                out, _ = p.communicate(timeout=30)
                 raise AssertionError(
                     f"rank {r} timed out after {timeout}s:\n{out}")
             outs[r] = out

@@ -23,6 +23,8 @@ engine_scaling = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(engine_scaling)
 
 
+@pytest.mark.slow  # a ratio of timings on a CPU the rest of tier-1 shares is
+#                    not a speed; the full `./ci.sh` run keeps it
 @pytest.mark.timeout(1500)
 def test_shm_not_slower_than_ring_at_16mb_and_64mb_2proc():
     """No retry loop (round-4): the historical flake source was the shm

@@ -17,8 +17,11 @@ TF_OPS_LIB = os.path.join(REPO, "horovod_tpu", "csrc", "build",
 
 def _run_example(argv, timeout=300, np_procs=None, extra_env=None):
     env = dict(os.environ)
+    # One OpenMP thread a process: with a thread a core in each, two ranks
+    # of torch spin against each other and against the other test workers
+    # (the ResNet-50 example: 108 s and 387 CPU-seconds, for 16 s and 23).
     env.update({"JAX_PLATFORMS": "cpu", "XLA_FLAGS": "",
-                "TF_CPP_MIN_LOG_LEVEL": "3",
+                "TF_CPP_MIN_LOG_LEVEL": "3", "OMP_NUM_THREADS": "1",
                 "PYTHONPATH": REPO + os.pathsep
                 + env.get("PYTHONPATH", "")})
     env.update(extra_env or {})

@@ -100,7 +100,7 @@ def finish_gang(procs, logs, timeout):
             codes.append(p.wait(timeout=left))
         except subprocess.TimeoutExpired:
             p.kill()
-            codes.append(p.wait())
+            codes.append(p.wait(timeout=30))
     outs = []
     for log in logs:
         log.flush()
@@ -235,10 +235,10 @@ def test_heartbeat_detects_silent_peer(tmp_path):
                 codes.append(p.wait(timeout=5 * hb_ms / 1000 + 60))
             except subprocess.TimeoutExpired:
                 p.kill()
-                codes.append(p.wait())
+                codes.append(p.wait(timeout=30))
     finally:
         procs[2].kill()  # SIGKILL works on a stopped process
-        procs[2].wait()
+        procs[2].wait(timeout=30)
     outs = []
     for log in logs:
         log.flush()

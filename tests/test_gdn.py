@@ -49,13 +49,25 @@ def _mixer(chunk=None, key_heads=2, value_heads=4, dtype=jnp.float32,
     layer = GatedDeltaNet(key_heads, value_heads, d_k, d_v, chunk=chunk,
                           dtype=dtype)
     u = jax.random.normal(jax.random.key(seed), (batch, seq, d_model))
-    params = layer.init(jax.random.key(seed + 1), u)["params"]
-    keys = iter(jax.random.split(jax.random.key(seed + 2), len(params)))
     moved = {"norm_scale": 0.3, "dt_bias": 0.3, "in_proj_qkvz": 0.3,
              "in_proj_ba": 0.5, "out_proj": 0.2}
-    params = {name: w + moved.get(name, 0.0) * jax.random.normal(
-        next(keys), w.shape) for name, w in params.items()}
-    return layer, params, u
+
+    # (one compiled program: op by op, this is most of a case's seconds)
+    @jax.jit
+    def init(u):
+        params = layer.init(jax.random.key(seed + 1), u)["params"]
+        keys = iter(jax.random.split(jax.random.key(seed + 2), len(params)))
+        return {name: w + moved.get(name, 0.0) * jax.random.normal(
+            next(keys), w.shape) for name, w in params.items()}
+
+    return layer, init(u), u
+
+
+def _run(layer, params, u, **collect):
+    """``layer.apply`` as one compiled program, traced anew a call (so
+    what a test steers from outside is read again)."""
+    return jax.jit(lambda p, u: layer.apply({"params": p}, u, **collect))(
+        params, u)
 
 
 def _reference(params, u, config):
@@ -79,8 +91,7 @@ def test_mixer_matches_the_position_by_position_reference(chunk,
     cot = jax.random.normal(jax.random.key(9), u.shape)
     program = lambda p, u: jnp.sum(layer.apply({"params": p}, u) * cot)
     plain = lambda p, u: jnp.sum(_reference(p, u, config) * cot)
-    _close(layer.apply({"params": params}, u), _reference(params, u, config),
-           "output")
+    _close(_run(layer, params, u), _reference(params, u, config), "output")
     got = jax.jit(jax.grad(program, argnums=(0, 1)))(params, u)
     want = jax.grad(plain, argnums=(0, 1))(params, u)
     assert set(got[0]) == {"in_proj_qkvz", "in_proj_ba", "conv_kernel",
@@ -115,9 +126,10 @@ def test_rule_alone_against_the_recurrence(chunk, per_key):
         return jax.vmap(reference.delta_rule)(wide(q), wide(k), v, g, beta)
 
     program = lambda *a: rule_op.gated_delta_rule(*a, chunk=chunk)
-    _close(program(q, k, v, g, beta), plain(q, k, v, g, beta), "output")
-    got = jax.grad(lambda *a: jnp.sum(program(*a) * cot),
-                   argnums=range(5))(q, k, v, g, beta)
+    _close(jax.jit(program)(q, k, v, g, beta), plain(q, k, v, g, beta),
+           "output")
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(program(*a) * cot),
+                           argnums=range(5)))(q, k, v, g, beta)
     want = jax.grad(lambda *a: jnp.sum(plain(*a) * cot),
                     argnums=range(5))(q, k, v, g, beta)
     for name, x, y in zip(("q", "k", "v", "g", "beta"), got, want):
@@ -191,7 +203,7 @@ def test_state_and_decays_are_float32_in_a_bf16_layer():
                 assert eqn.params["precision"] is not None, eqn
     assert all(seen.values()), seen
     config = _config(2, 4)
-    got = layer.apply({"params": params}, u).astype(jnp.float32)
+    got = _run(layer, params, u).astype(jnp.float32)
     _close(got, _reference(params, u, config), "bf16 output", rel=3e-2)
 
 
@@ -207,7 +219,7 @@ def test_traced_layers_are_counted_and_sown():
     before = count()
     jax.jit(lambda p, u: layer.apply({"params": p}, u)).lower(params, u)
     assert count() == before + 1
-    out, sown = layer.apply({"params": params}, u, mutable=["intermediates"])
+    out, sown = _run(layer, params, u, mutable=["intermediates"])
     sown = {k: v[0] for k, v in sown["intermediates"].items()}
     assert set(sown) == {"gdn_input", "gdn_output"}
     np.testing.assert_array_equal(np.asarray(sown["gdn_input"]),
@@ -221,7 +233,8 @@ def test_traced_layers_are_counted_and_sown():
 
 def test_initialisation_is_the_sources():
     layer, _, u = _mixer(value_heads=4)
-    p = GatedDeltaNet(2, 4, D_K, D_V).init(jax.random.key(3), u)["params"]
+    p = jax.jit(GatedDeltaNet(2, 4, D_K, D_V).init)(
+        jax.random.key(3), u)["params"]
     assert p["in_proj_qkvz"].shape == (D_MODEL, 2 * 2 * D_K + 2 * 4 * D_V)
     assert p["in_proj_ba"].shape == (D_MODEL, 8)
     assert p["conv_kernel"].shape == (4, 2 * 2 * D_K + 4 * D_V)
@@ -315,8 +328,7 @@ def test_wrong_mixers_are_refused(wrong, monkeypatch):
                               seq=512, batch=1, d_model=32, d_k=32, d_v=32)
 
     def distance():
-        _, sown = layer.apply({"params": params}, u,
-                              mutable=["intermediates"])
+        _, sown = _run(layer, params, u, mutable=["intermediates"])
         return family.mixer_distance(
             {k: v[0] for k, v in sown["intermediates"].items()}, params,
             config)

@@ -134,9 +134,10 @@ def test_a_decay_a_head_is_the_scalar_rule(chunk):
     q, k, v, g, beta = _operands(40)
     g = jnp.broadcast_to(g[..., :1], g.shape)
     with jax.default_matmul_precision("highest"):
-        got = rule_op.channel_delta_rule(q, k, v, g, beta, chunk=chunk)
-        want = scalar_op.gated_delta_rule_plain(q, k, v, g[..., 0], beta,
-                                                chunk=chunk)
+        got = jax.jit(lambda *a: rule_op.channel_delta_rule(
+            *a, chunk=chunk))(q, k, v, g, beta)
+        want = jax.jit(lambda *a: scalar_op.gated_delta_rule_plain(
+            *a, chunk=chunk))(q, k, v, g[..., 0], beta)
     _close(got, want, "o")
 
 

@@ -28,10 +28,10 @@ def test_fused_ce_matches_naive_value_and_grads(chunk):
 
     l0, (gh0, ge0) = jax.value_and_grad(_naive, argnums=(0, 1))(
         hidden, emb, targets)
-    l1, (gh1, ge1) = jax.value_and_grad(
+    l1, (gh1, ge1) = jax.jit(jax.value_and_grad(
         lambda h, e: softmax_cross_entropy_fused(h, e, targets,
                                                  chunk=chunk),
-        argnums=(0, 1))(hidden, emb)
+        argnums=(0, 1)))(hidden, emb)
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l0), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(gh1), np.asarray(gh0),
                                rtol=1e-5, atol=1e-7)
@@ -64,9 +64,9 @@ def test_fused_ce_non_divisible_seq_pads_and_masks(s):
     emb = jnp.asarray(rng.randn(21, 8) * 0.1, jnp.float32)
     targets = jnp.asarray(rng.randint(0, 21, (2, s)))
     l0, g0 = jax.value_and_grad(_naive)(hidden, emb, targets)
-    l1, g1 = jax.value_and_grad(
+    l1, g1 = jax.jit(jax.value_and_grad(
         lambda h: softmax_cross_entropy_fused(h, emb, targets,
-                                              chunk=8))(hidden)
+                                              chunk=8)))(hidden)
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l0), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g0),
                                rtol=1e-5, atol=1e-7)
@@ -91,7 +91,7 @@ def test_gpt_chunked_ce_trains_identically():
                     d_ff=64, dtype=jnp.float32)
     model = GPT(cfg)
     tokens = jnp.asarray(np.random.RandomState(3).randint(0, 64, (2, 16)))
-    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)["params"]
     targets = jnp.roll(tokens, -1, axis=-1)
 
     def loss_logits(p):
@@ -104,8 +104,8 @@ def test_gpt_chunked_ce_trains_identically():
         return softmax_cross_entropy_fused(
             hidden[:, :-1], p["embedding"], targets[:, :-1], chunk=5)
 
-    l0, g0 = jax.value_and_grad(loss_logits)(params)
-    l1, g1 = jax.value_and_grad(loss_fused)(params)
+    l0, g0 = jax.jit(jax.value_and_grad(loss_logits))(params)
+    l1, g1 = jax.jit(jax.value_and_grad(loss_fused))(params)
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l0), rtol=1e-6)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -139,9 +139,9 @@ def test_fused_ce_bf16_hidden_value_and_grads(emb_dtype):
     hidden, emb, targets = _case(4, 2, 24, 16, 33, jnp.bfloat16, emb_dtype)
     l0, (gh0, ge0) = jax.value_and_grad(_naive, argnums=(0, 1))(
         hidden, emb, targets)
-    l1, (gh1, ge1) = jax.value_and_grad(
+    l1, (gh1, ge1) = jax.jit(jax.value_and_grad(
         lambda h, e: softmax_cross_entropy_fused(h, e, targets, chunk=8),
-        argnums=(0, 1))(hidden, emb)
+        argnums=(0, 1)))(hidden, emb)
     assert gh1.dtype == jnp.bfloat16 and ge1.dtype == emb_dtype
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l0), rtol=1e-6)
     # one rounding to bf16 of the same float32 product on both sides
@@ -209,9 +209,9 @@ def test_fused_ce_padded_length_grads_at_the_requested_chunk(s):
     from horovod_tpu.ops.losses import softmax_cross_entropy_fused
 
     gh0, ge0 = jax.grad(_naive, argnums=(0, 1))(hidden, emb, targets)
-    gh1, ge1 = jax.grad(
+    gh1, ge1 = jax.jit(jax.grad(
         lambda h, e: softmax_cross_entropy_fused(h, e, targets, chunk=8),
-        argnums=(0, 1))(hidden, emb)
+        argnums=(0, 1)))(hidden, emb)
     assert gh1.shape == hidden.shape
     np.testing.assert_allclose(np.asarray(gh1), np.asarray(gh0),
                                rtol=1e-5, atol=1e-7)

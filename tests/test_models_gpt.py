@@ -18,8 +18,8 @@ def test_gpt_forward():
                     d_ff=64, dtype=jnp.float32)
     model = GPT(cfg)
     tokens = jnp.asarray(np.random.RandomState(0).randint(0, 64, (2, 16)))
-    params = model.init(jax.random.PRNGKey(0), tokens)
-    logits = model.apply(params, tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
+    logits = jax.jit(model.apply)(params, tokens)
     assert logits.shape == (2, 16, 64)
 
 
@@ -35,8 +35,8 @@ def test_gpt_causality():
     t2 = t1.copy()
     t2[0, -1] = (t2[0, -1] + 1) % 64
     params = model.init(jax.random.PRNGKey(0), jnp.asarray(t1))
-    l1 = model.apply(params, jnp.asarray(t1))
-    l2 = model.apply(params, jnp.asarray(t2))
+    l1 = jax.jit(model.apply)(params, jnp.asarray(t1))
+    l2 = jax.jit(model.apply)(params, jnp.asarray(t2))
     np.testing.assert_allclose(np.asarray(l1[0, :-1]),
                                np.asarray(l2[0, :-1]), atol=1e-5)
     assert not np.allclose(np.asarray(l1[0, -1]), np.asarray(l2[0, -1]))
@@ -50,7 +50,8 @@ def test_param_partition_spec():
                     d_ff=64, dtype=jnp.float32)
     model = GPT(cfg)
     tokens = jnp.zeros((1, 8), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            tokens)["params"]
     specs = param_partition_spec(params)
     flat = jax.tree_util.tree_flatten_with_path(
         specs, is_leaf=lambda x: isinstance(x, P))[0]
@@ -80,14 +81,15 @@ def test_gpt_flash_attention_matches_einsum_path():
                     d_ff=64, dtype=jnp.float32)
     tokens = jnp.asarray(np.random.RandomState(1).randint(0, 64, (2, 16)))
     model = GPT(cfg)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     model_f = GPT(dataclasses.replace(cfg, use_flash=True))
 
     def loss(m, p):
         return (m.apply(p, tokens).astype(jnp.float32) ** 2).mean()
 
-    l0, g0 = jax.value_and_grad(lambda p: loss(model, p))(params)
-    l1, g1 = jax.value_and_grad(lambda p: loss(model_f, p))(params)
+    l0, g0 = jax.jit(jax.value_and_grad(lambda p: loss(model, p)))(params)
+    l1, g1 = jax.jit(jax.value_and_grad(lambda p: loss(model_f, p)))(
+        params)
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l0),
                                rtol=2e-5, atol=2e-6)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
@@ -113,7 +115,7 @@ def test_gpt_ring_mesh_matches_plain(use_flash):
                     d_ff=64, dtype=jnp.float32)
     tokens = jnp.asarray(np.random.RandomState(2).randint(0, 64, (2, 32)))
     model = GPT(cfg)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     cfg_ring = dataclasses.replace(cfg, ring_mesh=mesh,
                                    use_flash=use_flash)
     model_r = GPT(cfg_ring)
@@ -123,9 +125,10 @@ def test_gpt_ring_mesh_matches_plain(use_flash):
     def loss(m, p, t):
         return (m.apply(p, t).astype(jnp.float32) ** 2).mean()
 
-    l0, g0 = jax.value_and_grad(lambda p: loss(model, p, tokens))(params)
-    l1, g1 = jax.value_and_grad(
-        lambda p: loss(model_r, p, tokens_sp))(params)
+    l0, g0 = jax.jit(jax.value_and_grad(
+        lambda p: loss(model, p, tokens)))(params)
+    l1, g1 = jax.jit(jax.value_and_grad(
+        lambda p: loss(model_r, p, tokens_sp)))(params)
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l0),
                                rtol=2e-5, atol=2e-6)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
@@ -177,8 +180,8 @@ def test_gpt_use_flash_auto_resolves_by_sequence_length(monkeypatch):
     tokens_short = jnp.asarray(
         np.random.RandomState(0).randint(0, 64, (1, 16)))
     model = GPT(cfg)
-    params = model.init(jax.random.PRNGKey(0), tokens_short)
-    model.apply(params, tokens_short)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens_short)
+    jax.jit(model.apply)(params, tokens_short)
     assert not calls, "auto must use einsum at short sequences"
 
     # a sequence the einsum path serves and the kernels would refuse or
@@ -203,7 +206,7 @@ def test_gpt_gqa_all_attention_paths_agree():
                     n_kv_heads=2, d_ff=64, dtype=jnp.float32)
     tokens = jnp.asarray(np.random.RandomState(2).randint(0, 64, (2, 16)))
     model = GPT(cfg)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
 
     # K/V kernels carry n_kv_heads
     att0 = params["params"]["block_0"]["attn"]
@@ -214,9 +217,10 @@ def test_gpt_gqa_all_attention_paths_agree():
     def loss(m, p):
         return (m.apply(p, tokens).astype(jnp.float32) ** 2).mean()
 
-    l0, g0 = jax.value_and_grad(lambda p: loss(model, p))(params)
+    l0, g0 = jax.jit(jax.value_and_grad(lambda p: loss(model, p)))(params)
     model_f = GPT(dataclasses.replace(cfg, use_flash=True))
-    l1, g1 = jax.value_and_grad(lambda p: loss(model_f, p))(params)
+    l1, g1 = jax.jit(jax.value_and_grad(lambda p: loss(model_f, p)))(
+        params)
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l0),
                                rtol=2e-5, atol=2e-6)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
@@ -226,8 +230,8 @@ def test_gpt_gqa_all_attention_paths_agree():
     # MQA (n_kv_heads=1) also runs
     cfg_mqa = dataclasses.replace(cfg, n_kv_heads=1)
     m2 = GPT(cfg_mqa)
-    p2 = m2.init(jax.random.PRNGKey(0), tokens)
-    assert np.isfinite(float(loss(m2, p2)))
+    p2 = jax.jit(m2.init)(jax.random.PRNGKey(0), tokens)
+    assert np.isfinite(float(jax.jit(lambda p: loss(m2, p))(p2)))
 
     with pytest.raises(ValueError, match="divide"):
         GPT(dataclasses.replace(cfg, n_kv_heads=3)).init(
@@ -249,11 +253,11 @@ def test_gpt_gqa_ring_mesh_matches_plain():
                     n_kv_heads=2, d_ff=64, dtype=jnp.float32)
     tokens = jnp.asarray(np.random.RandomState(3).randint(0, 64, (2, 32)))
     model = GPT(cfg)
-    params = model.init(jax.random.PRNGKey(0), tokens)
-    base = model.apply(params, tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
+    base = jax.jit(model.apply)(params, tokens)
 
     ring = GPT(dataclasses.replace(cfg, ring_mesh=mesh))
-    out = ring.apply(params, tokens)
+    out = jax.jit(ring.apply)(params, tokens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(base),
                                rtol=2e-4, atol=2e-4)
 
@@ -268,8 +272,8 @@ def test_param_partition_spec_gqa_tp_fallback():
 
     cfg = GPTConfig(vocab_size=64, n_layers=1, d_model=32, n_heads=8,
                     n_kv_heads=2, d_ff=64, dtype=jnp.float32)
-    params = GPT(cfg).init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, 8), jnp.int32))["params"]
+    params = jax.eval_shape(GPT(cfg).init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
     att = params["block_0"]["attn"]
 
     specs4 = param_partition_spec(params, tp_size=4)
@@ -302,7 +306,8 @@ def test_gpt_gradient_program_names_its_regions(remat):
                     use_flash=False)
     model = GPT(cfg)
     tokens = jnp.zeros((2, 8), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            tokens)["params"]
     grad = jax.jit(jax.grad(
         lambda p: model.apply({"params": p}, tokens).sum()))
     names = set(re.findall(r'op_name="([^"]*)"',
@@ -330,7 +335,7 @@ def test_dense_gpt_is_the_parents():
                     use_flash="auto")
     model = GPT(cfg)
     tokens = jnp.zeros((2, 8), jnp.int32)
-    params = model.init(jax.random.key(0), tokens)["params"]
+    params = jax.jit(model.init)(jax.random.key(0), tokens)["params"]
     shapes = {jax.tree_util.keystr(path): leaf.shape for path, leaf
               in jax.tree_util.tree_leaves_with_path(params)}
     block = lambda i: {
@@ -344,7 +349,8 @@ def test_dense_gpt_is_the_parents():
         f"['block_{i}']['mlp']['down']['kernel']": (128, 32)}
     assert shapes == {"['embedding']": (64, 32), "['ln_f']['scale']": (32,),
                       **block(0), **block(1)}
-    out, aux = model.apply({"params": params}, tokens, return_aux=True)
+    out, aux = jax.jit(lambda p: model.apply(
+        {"params": p}, tokens, return_aux=True))(params)
     assert aux == {} and out.shape == (2, 8, 64)
     names = set(re.findall(r'loc\("([^"]*)"', jax.jit(jax.grad(
         lambda p: model.apply({"params": p}, tokens).sum())).lower(
@@ -352,7 +358,8 @@ def test_dense_gpt_is_the_parents():
     assert any("/block_1/mlp/" in n for n in names)
     for new in ("moe", "q_norm", "k_norm", "ssm", "/norm/"):
         assert not [n for n in names if new in n], new
-    assert set(model.init(jax.random.key(0), tokens)) == {"params"}
+    assert set(jax.eval_shape(model.init, jax.random.key(0), tokens)) == {
+        "params"}
 
 
 @pytest.mark.parametrize("field, value, new_leaves", [
@@ -373,13 +380,14 @@ def test_gpt_config_field_changes_its_part_only(field, value, new_leaves):
                      d_ff=64, dtype=jnp.float32, use_flash=False)
     cfg = dataclasses.replace(base, **{field: value})
     tokens = jax.random.randint(jax.random.key(2), (1, 12), 0, 64)
-    params = GPT(cfg).init(jax.random.key(0), tokens)["params"]
-    base_params = GPT(base).init(jax.random.key(0), tokens)["params"]
+    params = jax.jit(GPT(cfg).init)(jax.random.key(0), tokens)["params"]
+    base_params = jax.jit(GPT(base).init)(
+        jax.random.key(0), tokens)["params"]
     names = lambda tree: {str(getattr(k, "key", k)) for path, _ in
                           jax.tree_util.tree_leaves_with_path(tree)
                           for k in path}
     assert names(params) - names(base_params) == new_leaves
-    got = GPT(cfg).apply({"params": params}, tokens)
-    want = GPT(base).apply({"params": base_params}, tokens)
+    got = jax.jit(GPT(cfg).apply)({"params": params}, tokens)
+    want = jax.jit(GPT(base).apply)({"params": base_params}, tokens)
     assert got.shape == want.shape
     assert float(jnp.max(jnp.abs(got - want))) > 1e-4

@@ -11,6 +11,25 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import small_models as small
+from horovod_tpu.models import GPT
+
+
+def _shapes(model, seq=20):
+    """What ``model.init`` returns, as shapes: for a test that reads the
+    tree, the names or a lowered text and no value."""
+    return jax.eval_shape(model.init, jax.random.key(0),
+                          jnp.zeros((2, seq), jnp.int32))
+
+
+def _hybrid_unbuilt(**changes):
+    """The small hybrid for a test that lowers its step and runs nothing:
+    the parameters as shapes, the buffers (closed over by the loss, so
+    arrays) as zeros."""
+    model = GPT(small.hybrid_config(**changes))
+    variables = _shapes(model)
+    buffers = jax.tree.map(lambda leaf: jnp.zeros(leaf.shape, leaf.dtype),
+                           variables.get("buffers", {}))
+    return model, variables["params"], buffers, jnp.zeros((2, 20), jnp.int32)
 
 
 @pytest.mark.parametrize("remat", [False, True])
@@ -37,21 +56,22 @@ def test_sparse_gpt_matches_reference(remat):
     for (path, g), (_, w) in zip(flat, want_flat, strict=True):
         err = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
         assert err <= 1e-5, (jax.tree_util.keystr(path), err)
-    plain, _, _ = small.sparse_model(not remat)
-    assert float(small.sparse_loss(plain, params, tokens)) == pytest.approx(
-        float(got), rel=1e-6)
+    plain = GPT(small.sparse_config(not remat))
+    assert float(jax.jit(lambda p: small.sparse_loss(plain, p, tokens))(
+        params)) == pytest.approx(float(got), rel=1e-6)
     # the hidden states and the head a memory-bounded loss multiplies
-    hidden = model.apply({"params": params}, tokens, return_hidden=True)
+    hidden, logits = jax.jit(lambda p: (
+        model.apply({"params": p}, tokens, return_hidden=True),
+        model.apply({"params": p}, tokens)))(params)
     np.testing.assert_allclose(
-        np.asarray(hidden @ params["lm_head"].T),
-        np.asarray(model.apply({"params": params}, tokens)), rtol=1e-5,
-        atol=1e-5)
+        np.asarray(hidden @ params["lm_head"].T), np.asarray(logits),
+        rtol=1e-5, atol=1e-5)
 
 
 def test_param_partition_spec_of_a_sparse_model():
     from horovod_tpu.models.transformer import param_partition_spec
 
-    _, params, _ = small.sparse_model(False)
+    params = _shapes(GPT(small.sparse_config(False)), seq=16)["params"]
     specs = param_partition_spec(params, ep_axis="ep")
     moe = specs["block_1"]["moe"]
     assert moe["gate"] == moe["up"] == P("ep", None, "tp")
@@ -71,13 +91,17 @@ def test_sparse_gpt_is_the_parents():
     carries the four scopes it had and none of the new ones."""
     import re
 
-    model, params, tokens = small.sparse_model(remat=True)
-    assert set(model.init(jax.random.key(0), tokens)) == {"params"}
+    model = GPT(small.sparse_config(remat=True))
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    variables = _shapes(model, seq=16)
+    assert set(variables) == {"params"}
+    params = variables["params"]
     assert {k: v.shape for k, v in params["block_1"]["moe"].items()} == {
         "router": (32, 64), "gate": (64, 32, 8), "up": (64, 32, 8),
         "down": (64, 8, 32)}
     assert set(params["block_1"]) == {"ln1", "attn", "ln2", "moe"}
-    _, aux = model.apply({"params": params}, tokens, return_aux=True)
+    _, aux = jax.eval_shape(lambda p: model.apply(
+        {"params": p}, tokens, return_aux=True), params)
     assert set(aux) == {"load_balance", "router_z"}
     names = set(re.findall(r'loc\("([^"]*)"', jax.jit(jax.grad(
         lambda p: small.sparse_loss(model, p, tokens))).lower(params).as_text(
@@ -119,14 +143,16 @@ def test_hybrid_gpt_matches_reference(remat):
     for (path, g), (_, w) in zip(flat, want_flat, strict=True):
         err = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
         assert err <= 2e-5, (jax.tree_util.keystr(path), err)
-    plain, _, _, _ = small.hybrid_model(not remat)
-    assert float(small.hybrid_loss(plain, params, buffers, tokens)) == \
-        pytest.approx(float(got), rel=1e-6)
+    plain = GPT(small.hybrid_config(not remat))
+    assert float(jax.jit(lambda p: small.hybrid_loss(
+        plain, p, buffers, tokens))(params)) == pytest.approx(
+            float(got), rel=1e-6)
     # no positional term in the attention: with the Mamba-2 layers' and
     # the causal mask's order taken away a permutation of the positions
     # permutes the logits
     attention_only, only_params, _, _ = small.hybrid_model(pattern="*")
-    apply = lambda t: attention_only.apply({"params": only_params}, t)
+    apply = jax.jit(
+        lambda t: attention_only.apply({"params": only_params}, t))
     np.testing.assert_allclose(np.asarray(apply(tokens)[:, -1]),
                                np.asarray(apply(tokens.at[:, :-1].set(
                                    tokens[:, -2::-1]))[:, -1]),
@@ -142,7 +168,7 @@ def test_hybrid_gradient_program_names_its_scopes_and_scatters_no_row():
     this test's own loss's are outside the blocks)."""
     import re
 
-    model, params, buffers, tokens = small.hybrid_model(remat=True)
+    model, params, buffers, tokens = _hybrid_unbuilt(remat=True)
     grad = jax.jit(jax.grad(
         lambda p: small.hybrid_loss(model, p, buffers, tokens)))
     # the names as the compiled program carries them, where a device trace
@@ -177,7 +203,7 @@ def test_recomputed_block_does_not_choose_its_experts_again(remat):
     scores it chose from are made again: their gradient needs them)."""
     import re
 
-    model, params, buffers, tokens = small.hybrid_model(remat=remat)
+    model, params, buffers, tokens = _hybrid_unbuilt(remat=remat)
     text = jax.jit(jax.grad(lambda p: small.hybrid_loss(
         model, p, buffers, tokens))).lower(params).as_text()
     slots = tokens.size * 4
@@ -212,7 +238,8 @@ def test_the_shares_of_the_attention_heads_add_up(count):
     x = jax.random.normal(jax.random.key(0), (2, 12, 32))
     positions = jnp.broadcast_to(jnp.arange(12), (2, 12))
     layer = lambda cfg: Attention(cfg, rotary=cfg.rotary)
-    whole = layer(base).init(jax.random.key(1), x, positions)["params"]
+    whole = jax.jit(layer(base).init)(jax.random.key(1), x, positions)[
+        "params"]
     want = jax.lax.map(lambda one: reference.attention(one, whole), x)
     total = 0.0
     for first in range(0, 8, count):
@@ -222,7 +249,8 @@ def test_the_shares_of_the_attention_heads_add_up(count):
                                 positions)["params"]
         assert jax.tree.map(lambda a: a.shape, mine) == jax.tree.map(
             lambda a: a.shape, jax.tree.map(lambda a: a, dict(shapes)))
-        total = total + layer(cfg).apply({"params": mine}, x, positions)
+        total = total + jax.jit(layer(cfg).apply)(
+            {"params": mine}, x, positions)
     np.testing.assert_allclose(np.asarray(total), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
 
@@ -238,7 +266,7 @@ def test_the_shares_of_the_attention_heads_add_up(count):
 ])
 def test_hybrid_config_is_refused_by_name(changes, match):
     with pytest.raises(ValueError, match=match):
-        small.hybrid_model(**changes)
+        _shapes(GPT(small.hybrid_config(**changes)))
 
 
 def test_dense_mlp_layer_of_a_pattern_and_param_partition_spec():
@@ -248,7 +276,7 @@ def test_dense_mlp_layer_of_a_pattern_and_param_partition_spec():
     replicated."""
     from horovod_tpu.models.transformer import param_partition_spec
 
-    _, params, _, _ = small.hybrid_model(pattern="-EM*")
+    params = _shapes(GPT(small.hybrid_config(pattern="-EM*")))["params"]
     assert set(params["block_0"]) == {"norm", "mlp"}
     specs = param_partition_spec(params, ep_axis="ep")
     ssm, moe = specs["block_2"]["ssm"], specs["block_1"]["moe"]
@@ -270,7 +298,7 @@ def test_ssm_and_held_counters_show_on_metrics():
     expert layers with what they hold."""
     from horovod_tpu import metrics
 
-    model, params, buffers, tokens = small.hybrid_model()
+    model, params, buffers, tokens = _hybrid_unbuilt()
     jax.jit(lambda p: small.hybrid_loss(model, p, buffers, tokens)).lower(
         params)
     text = metrics.prometheus_text()

@@ -69,8 +69,8 @@ def test_forward_both_losses_and_gradients_are_the_references(model):
         ce, index_loss, sown = _losses(CFG, p, tokens, sow=True)
         return ce + index_loss, (ce, index_loss, sown)
 
-    (value, (ce, index_loss, sown)), got = jax.value_and_grad(
-        loss, has_aux=True)(params)
+    (value, (ce, index_loss, sown)), got = jax.jit(jax.value_and_grad(
+        loss, has_aux=True))(params)
     own, (own_ce, own_index, routing) = reference.loss(params, tokens, CONFIG)
     # the reference's own top_k and its own experts
     assert float(ce) == pytest.approx(float(own_ce), rel=2e-6)
@@ -100,8 +100,8 @@ def test_each_loss_reaches_its_own_leaves_and_no_other(model):
     """``L_I`` reaches the indexer's four leaves of its own layer and
     nothing else; the language-model loss everything but them."""
     params, tokens = model
-    by_lm = jax.grad(lambda p: _losses(CFG, p, tokens)[0])(params)
-    by_index = jax.grad(lambda p: _losses(CFG, p, tokens)[1])(params)
+    by_lm = jax.jit(jax.grad(lambda p: _losses(CFG, p, tokens)[0]))(params)
+    by_index = jax.jit(jax.grad(lambda p: _losses(CFG, p, tokens)[1]))(params)
     for path, g in jax.tree_util.tree_leaves_with_path(by_lm):
         indexer = path[-1].key in INDEXER
         assert bool(jnp.any(g != 0)) != indexer, jax.tree_util.keystr(path)
@@ -116,8 +116,8 @@ def test_kernel_path_is_the_plain_path(model):
     ``"auto"`` takes off a TPU."""
     params, tokens = model
     kernels = dataclasses.replace(CFG, use_flash=True)
-    loss = lambda cfg: jax.value_and_grad(
-        lambda p: sum(_losses(cfg, p, tokens)[:2]))(params)
+    loss = lambda cfg: jax.jit(jax.value_and_grad(
+        lambda p: sum(_losses(cfg, p, tokens)[:2])))(params)
     (want, want_grads), (got, got_grads) = loss(CFG), loss(kernels)
     assert float(got) == pytest.approx(float(want), rel=2e-6)
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got_grads),

@@ -13,6 +13,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import small_models as small
+from horovod_tpu.models import GPT
 
 
 _QWEN_SCOPES = ("gdn_in_proj", "gdn_conv", "gdn_rule", "gdn_gate_norm",
@@ -57,9 +58,9 @@ def test_qwen3_next_gpt_matches_reference(remat):
         # A_log and dt_bias: see tests/test_gdn.py's DECAY_REL
         bound = 2e-4 if path[-1].key in ("A_log", "dt_bias") else 2e-5
         assert err <= bound, (jax.tree_util.keystr(path), err)
-    plain, _, _ = small.qwen_model(not remat)
-    assert float(small.qwen_loss(plain, params, tokens)) == pytest.approx(
-        float(got), rel=1e-6)
+    plain = GPT(small.qwen_config(not remat))
+    assert float(jax.jit(lambda p: small.qwen_loss(plain, p, tokens))(
+        params)) == pytest.approx(float(got), rel=1e-6)
 
 
 def test_gated_attention_matches_the_formula():
@@ -70,15 +71,21 @@ def test_gated_attention_matches_the_formula():
     from chipbench.reference import qwen3_next as reference
     from horovod_tpu.models.transformer import Attention
 
-    model, _, _ = small.qwen_model()
-    layer = Attention(model.cfg, rotary=model.cfg.rotary)
+    cfg = small.qwen_config()
+    layer = Attention(cfg, rotary=cfg.rotary)
     x = jax.random.normal(jax.random.key(3), (2, 24, 32))
     positions = jnp.broadcast_to(jnp.arange(24), (2, 24))
-    params = layer.init(jax.random.key(4), x, positions)["params"]
-    keys = iter(jax.random.split(jax.random.key(5), 8))
-    params = jax.tree.map(
-        lambda w: w + 0.3 * jax.random.normal(next(keys), w.shape), params)
-    got = layer.apply({"params": params}, x, positions)
+
+    @jax.jit
+    def init(x, positions):
+        params = layer.init(jax.random.key(4), x, positions)["params"]
+        keys = iter(jax.random.split(jax.random.key(5), 8))
+        return jax.tree.map(
+            lambda w: w + 0.3 * jax.random.normal(next(keys), w.shape),
+            params)
+
+    params = init(x, positions)
+    got = jax.jit(lambda p: layer.apply({"params": p}, x, positions))(params)
     want = jax.vmap(lambda h: reference.attention(h, params, small.QWEN))(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-5)
@@ -126,20 +133,21 @@ def test_qwen_attention_field_changes_its_part_only(field, value, new_leaves):
     """Each field Qwen3-Next's attention needed defaults to the layer as
     it was: the leaves it adds, and logits that differ from the default
     model's once the parameters are off their initial values."""
-    from horovod_tpu.models import GPT, GPTConfig
+    from horovod_tpu.models import GPTConfig
 
     base = GPTConfig(vocab_size=64, n_layers=1, d_model=32, n_heads=2,
                      d_ff=64, dtype=jnp.float32, use_flash=False)
     cfg = dataclasses.replace(base, **{field: value})
     tokens = jax.random.randint(jax.random.key(2), (1, 12), 0, 64)
-    params = GPT(cfg).init(jax.random.key(0), tokens)["params"]
-    base_params = GPT(base).init(jax.random.key(0), tokens)["params"]
+    params = jax.jit(GPT(cfg).init)(jax.random.key(0), tokens)["params"]
+    base_params = jax.jit(GPT(base).init)(
+        jax.random.key(0), tokens)["params"]
     names = lambda tree: {str(getattr(k, "key", k)) for path, _ in
                           jax.tree_util.tree_leaves_with_path(tree)
                           for k in path}
     assert names(params) - names(base_params) == new_leaves
-    got = GPT(cfg).apply({"params": params}, tokens)
-    want = GPT(base).apply({"params": base_params}, tokens)
+    got = jax.jit(GPT(cfg).apply)({"params": params}, tokens)
+    want = jax.jit(GPT(base).apply)({"params": base_params}, tokens)
     assert got.shape == want.shape
     assert float(jnp.max(jnp.abs(got - want))) > 1e-4
 
@@ -151,7 +159,10 @@ def test_qwen3_next_gradient_program_names_its_scopes():
     from horovod_tpu import metrics
     from horovod_tpu.models.transformer import param_partition_spec
 
-    model, params, tokens = small.qwen_model(remat=True)
+    # the lowered step and the specs read shapes and names alone
+    model = GPT(small.qwen_config(remat=True))
+    tokens = jnp.zeros((2, 20), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.key(0), tokens)["params"]
     names = set(re.findall(r'loc\("([^"]*)"', jax.jit(jax.grad(
         lambda p: small.qwen_loss(model, p, tokens))).lower(params).as_text(
             debug_info=True)))
@@ -177,7 +188,7 @@ def test_qwen3_next_gradient_program_names_its_scopes():
 
 
 def test_pattern_error_names_the_new_letter():
-    from horovod_tpu.models import GPT, GPTConfig
+    from horovod_tpu.models import GPTConfig
 
     cfg = GPTConfig(vocab_size=16, n_layers=1, d_model=8, n_heads=2,
                     layer_pattern="Q", dtype=jnp.float32)
@@ -191,11 +202,13 @@ def test_pattern_error_names_the_new_letter():
 
 @functools.cache
 def _qwen_sound():
-    """The small model with the reference's loss on it, made once."""
+    """The small model with its loss and the reference's, made once."""
     from chipbench.reference import qwen3_next as reference
 
     model, params, tokens = small.qwen_model()
-    return model, params, tokens, reference.loss(params, tokens, small.QWEN)[0]
+    loss = jax.jit(lambda p: small.qwen_loss(model, p, tokens))(params)
+    return (model, params, tokens, float(loss),
+            reference.loss(params, tokens, small.QWEN)[0])
 
 
 # wrong programs: each reads a loss the family's step-loss comparison
@@ -213,13 +226,12 @@ def test_wrong_qwen3_next_models_are_refused(wrong, changes):
     from chipbench import compare
     from chipbench.families import qwen3_next as family
 
-    model, params, tokens, want = _qwen_sound()
-    sound = compare.close(
-        "loss", float(small.qwen_loss(model, params, tokens)), want,
-        family.LOSS_REL_BOUND, floor=1.0)
+    model, params, tokens, loss, want = _qwen_sound()
+    sound = compare.close("loss", loss, want, family.LOSS_REL_BOUND,
+                          floor=1.0)
     assert sound.ok, sound.line()
-    other, _, _ = small.qwen_model(**changes)
-    theirs = other.init(jax.random.key(0), tokens)["params"]
+    other = GPT(small.qwen_config(**changes))
+    theirs = jax.jit(other.init)(jax.random.key(0), tokens)["params"]
 
     def fitted(path, leaf):
         """The right model's leaf, cut to the wrong one's shape (the q
@@ -236,6 +248,7 @@ def test_wrong_qwen3_next_models_are_refused(wrong, changes):
         return jnp.resize(mine, leaf.shape)
 
     theirs = jax.tree_util.tree_map_with_path(fitted, theirs)
-    far = compare.close("loss", float(small.qwen_loss(other, theirs, tokens)),
-                        want, family.LOSS_REL_BOUND, floor=1.0)
+    far = compare.close(
+        "loss", float(jax.jit(lambda p: small.qwen_loss(other, p, tokens))(
+            theirs)), want, family.LOSS_REL_BOUND, floor=1.0)
     assert not far.ok, (wrong, far.line())

@@ -7,14 +7,21 @@ import numpy as np
 import pytest
 
 
+def _init(model, x, seed=0, **kwargs):
+    """`model.init` as one program: eager, every operation of the
+    initialisation is a compile of its own."""
+    return jax.jit(lambda key, x: model.init(key, x, **kwargs))(
+        jax.random.PRNGKey(seed), x)
+
+
 def test_resnet50_forward_shape():
     from horovod_tpu.models import ResNet50
 
     model = ResNet50(num_classes=10, dtype=jnp.float32)
     x = jnp.zeros((2, 64, 64, 3))
-    variables = model.init(jax.random.PRNGKey(0), x, train=True)
-    logits, mutated = model.apply(variables, x, train=True,
-                                  mutable=["batch_stats"])
+    variables = _init(model, x, train=True)
+    logits, mutated = jax.jit(lambda v, x: model.apply(
+        v, x, train=True, mutable=["batch_stats"]))(variables, x)
     assert logits.shape == (2, 10)
     assert logits.dtype == jnp.float32
     assert "batch_stats" in mutated
@@ -47,11 +54,12 @@ def test_resnet_conv0_s2d_checkpoint_layout_matches_standard_stem():
     from horovod_tpu.models import ResNet50
 
     x = jnp.zeros((1, 64, 64, 3))
-    std = ResNet50(num_classes=10, dtype=jnp.float32).init(
-        jax.random.PRNGKey(0), x, train=True)
-    s2d = ResNet50(num_classes=10, dtype=jnp.float32,
-                   conv0_space_to_depth=True).init(
-        jax.random.PRNGKey(0), x, train=True)
+    # only shapes and the tree are read
+    std, s2d = (
+        jax.eval_shape(lambda: ResNet50(
+            num_classes=10, dtype=jnp.float32, **stem).init(
+                jax.random.PRNGKey(0), x, train=True))
+        for stem in ({}, {"conv0_space_to_depth": True}))
     assert (std["params"]["conv_init"]["kernel"].shape
             == s2d["params"]["conv_init"]["kernel"].shape)
     # a standard-stem checkpoint loads into an s2d model verbatim
@@ -65,8 +73,9 @@ def test_resnet_eval_mode():
 
     model = ResNet50(num_classes=10, dtype=jnp.float32)
     x = jnp.zeros((2, 64, 64, 3))
-    variables = model.init(jax.random.PRNGKey(0), x, train=True)
-    logits = model.apply(variables, x, train=False)
+    variables = _init(model, x, train=True)
+    logits = jax.jit(lambda v, x: model.apply(v, x, train=False))(
+        variables, x)
     assert logits.shape == (2, 10)
 
 
@@ -191,7 +200,7 @@ class TestTpuBatchNorm:
         def run(norm_impl):
             model = ResNet50(num_classes=10, dtype=jnp.float32,
                              norm_impl=norm_impl)
-            variables = model.init(jax.random.PRNGKey(0), x, train=True)
+            variables = _init(model, x, train=True)
             params, bs = variables["params"], variables["batch_stats"]
             tx = optax.sgd(0.05, momentum=0.9)
             opt = tx.init(params)
@@ -235,7 +244,7 @@ class TestTpuBatchNorm:
         def run(norm_impl):
             model = ResNet50(num_classes=10, dtype=jnp.bfloat16,
                              norm_impl=norm_impl)
-            variables = model.init(jax.random.PRNGKey(0), x, train=True)
+            variables = _init(model, x, train=True)
             params, bs = variables["params"], variables["batch_stats"]
             # small lr: a big step overfits 4 samples to ~0 loss in one
             # update, where relative comparison is meaningless
@@ -300,17 +309,17 @@ def test_vgg16_and_inception_forward_backward():
                          299)]:
         x = jnp.asarray(rs.randn(2, size, size, 3), jnp.float32)
         y = jnp.asarray(rs.randint(0, 10, (2,)))
-        variables = model.init(jax.random.PRNGKey(0), x, train=True)
+        variables = _init(model, x, train=True)
         params, bstats = variables["params"], variables["batch_stats"]
 
-        def loss_fn(p):
+        def loss_fn(p, bstats, x, y):
             logits, _ = model.apply(
                 {"params": p, "batch_stats": bstats}, x, train=True,
                 mutable=["batch_stats"])
             return optax.softmax_cross_entropy_with_integer_labels(
                 logits, y).mean()
 
-        l, g = jax.value_and_grad(loss_fn)(params)
+        l, g = jax.jit(jax.value_and_grad(loss_fn))(params, bstats, x, y)
         assert np.isfinite(float(l))
         leaves = jax.tree.leaves(g)
         assert leaves and all(np.all(np.isfinite(np.asarray(p)))

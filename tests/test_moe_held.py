@@ -30,6 +30,12 @@ _LATENT = {"num_experts_per_tok": 3, "norm_topk_prob": True,
            "routed_scaling_factor": 2.5}
 
 
+def _latent_moe(held, k=3, n_experts=8):
+    return MoEMlp(n_experts, 12, k, dtype=jnp.float32, score="sigmoid",
+                  route_scale=2.5, expert_act="relu2", latent=8,
+                  shared_ff=20, held=held)
+
+
 def _latent_layer(held, k=3, n_experts=8, tokens=40, d=16, seed=0,
                   skewed=False, crowd=None):
     """A float32 latent layer, its parameters (scaled up from 0.02, as
@@ -38,12 +44,12 @@ def _latent_layer(held, k=3, n_experts=8, tokens=40, d=16, seed=0,
     the second. ``crowd``: every token chooses the first ``crowd`` held
     experts (0: none of the held), so that ``crowd`` whole rounds of one
     row a token are assigned, and what the other choices add."""
-    layer = MoEMlp(n_experts, 12, k, dtype=jnp.float32, score="sigmoid",
-                   route_scale=2.5, expert_act="relu2", latent=8,
-                   shared_ff=20, held=held)
+    layer = _latent_moe(held, k, n_experts)
     h = jax.random.normal(jax.random.key(seed), (tokens, d))
-    variables = layer.init(jax.random.key(seed + 1), h)
-    params = jax.tree.map(lambda w: w * 20.0, variables["params"])
+    # (one compiled program: op by op, this is most of a case's seconds)
+    params = jax.jit(lambda h: jax.tree.map(
+        lambda w: w * 20.0, layer.init(jax.random.key(seed + 1), h)[
+            "params"]))(h)
     bias = 0.2 * jax.random.normal(jax.random.key(seed + 2), (n_experts,))
     if skewed or crowd is not None:
         first, count = held
@@ -275,9 +281,9 @@ def test_held_layer_drops_nothing_under_the_most_uneven_routing(
     gradients are still the reference's."""
     layer, params, buffers, h = _latent_layer(held, skewed=crowd is None,
                                               crowd=crowd)
-    *_, sizes, _, _ = moe.moe_route(
-        h, params["router"], 3, score="sigmoid",
-        bias=buffers["choice_bias"], scale=2.5, held=held)
+    *_, sizes, _, _ = jax.jit(lambda h, router, bias: moe.moe_route(
+        h, router, 3, score="sigmoid", bias=bias, scale=2.5, held=held))(
+            h, params["router"], buffers["choice_bias"])
     n_tokens = h.shape[0]
     if crowd is None:
         assert int(sizes[0]) == n_tokens and int(sizes[1]) == 0
@@ -290,7 +296,7 @@ def test_held_layer_drops_nothing_under_the_most_uneven_routing(
     program = lambda p, h: jnp.sum(layer.apply(variables(p), h)[0] * cot)
     plain = lambda p, h: jnp.sum(latent_reference.experts_layer(
         h, p, buffers["choice_bias"], _latent_config(held))[0] * cot)
-    _close(layer.apply(variables(params), h)[0],
+    _close(jax.jit(lambda p, h: layer.apply(variables(p), h)[0])(params, h),
            latent_reference.experts_layer(
                h, params, buffers["choice_bias"], _latent_config(held))[0],
            "output")
@@ -447,24 +453,25 @@ def test_the_shares_of_the_experts_add_up(crowd):
     def total(h):
         parts = 0.0
         for first in (0, 2, 4, 6):
-            share, _, _, _ = _latent_layer((first, 2))
+            share = _latent_moe((first, 2))
             mine = {**params, "up": params["up"][first:first + 2],
                     "down": params["down"][first:first + 2]}
             out, _ = share.apply({"params": mine, "buffers": buffers}, h)
             parts = parts + (out - shared(h))
         return parts, parts + shared(h)
 
-    parts, got = total(h)
+    parts, got = jax.jit(total)(h)
     want, _ = latent_reference.experts_layer(
         h, params, buffers["choice_bias"], _latent_config(None))
     _close(got, want, "sum of the shares")
     every = lambda h: whole.apply({"params": params, "buffers": buffers},
                                   h)[0]
-    _close(got, every(h), "sum of the shares against every expert held")
+    _close(got, jax.jit(every)(h),
+           "sum of the shares against every expert held")
     assert float(jnp.linalg.norm(parts)) > 0.1 * float(jnp.linalg.norm(want))
     cot = jax.random.normal(jax.random.key(7), h.shape)
-    _close(jax.grad(lambda h: jnp.sum(total(h)[1] * cot))(h),
-           jax.grad(lambda h: jnp.sum(every(h) * cot))(h), "d input")
+    _close(jax.jit(jax.grad(lambda h: jnp.sum(total(h)[1] * cot)))(h),
+           jax.jit(jax.grad(lambda h: jnp.sum(every(h) * cot)))(h), "d input")
     if crowd:
         *_, sizes, _, _ = moe.moe_route(
             h, params["router"], 3, score="sigmoid",
@@ -547,7 +554,7 @@ def test_held_layer_program_has_nothing_T_k_E_and_one_sort_of_its_slots(
     def lowered(held):
         layer = MoEMlp(n_experts, 12, k, dtype=jnp.float32, held=held,
                        **options)
-        variables = layer.init(jax.random.key(1), h)
+        variables = jax.jit(layer.init)(jax.random.key(1), h)
         forward = lambda h: jnp.sum(layer.apply(variables, h)[0] ** 2)
         return jax.jit(forward if program == "forward"
                        else jax.grad(forward)).lower(h)
@@ -621,7 +628,7 @@ def test_held_layer_holds_its_round_once(held, k, act, stacks):
     layer = MoEMlp(8, 12, k, dtype=jnp.float32, expert_act=act, held=held,
                    **({"score": "sigmoid"} if act == "relu2" else {}))
     h = jax.random.normal(jax.random.key(0), (tokens, 16))
-    variables = layer.init(jax.random.key(1), h)
+    variables = jax.jit(layer.init)(jax.random.key(1), h)
     forward = lambda h: layer.apply(variables, h)[0]
     for program, products, loops in (
             (forward, stacks, (1, 1, 0)),
@@ -716,11 +723,11 @@ def test_swiglu_layer_with_a_shared_expert_and_a_share():
     held experts' part against the loop over them."""
     layer = MoEMlp(4, 8, 2, dtype=jnp.float32, shared_ff=6, held=(1, 2))
     h = jax.random.normal(jax.random.key(0), (24, 16))
-    params = jax.tree.map(lambda w: w * 20.0, layer.init(
-        jax.random.key(1), h)["params"])
+    params = jax.jit(lambda h: jax.tree.map(lambda w: w * 20.0, layer.init(
+        jax.random.key(1), h)["params"]))(h)
     assert set(params) == {"router", "gate", "up", "down", "shared_up",
                            "shared_gate", "shared_down"}
-    out, aux = layer.apply({"params": params}, h)
+    out, aux = jax.jit(lambda p, h: layer.apply({"params": p}, h))(params, h)
     assert set(aux) == {"load_balance", "router_z"}
     probs, _, experts = reference.route(h, params["router"], 2)
     want = (jax.nn.silu(h @ params["shared_gate"]) * (h @ params["shared_up"])

@@ -173,7 +173,7 @@ def test_launcher_sigkill_leaves_no_orphan_workers(tmp_path):
             pids.append(int(line.rsplit(" ", 1)[1]))
     assert len(pids) == 2, f"workers did not start (got {pids})"
     launcher.kill()  # SIGKILL: launcher gets NO chance to clean up
-    launcher.wait()
+    launcher.wait(timeout=30)
     deadline = time.time() + 10
     alive = set(pids)
     while alive and time.time() < deadline:

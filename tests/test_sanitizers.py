@@ -31,7 +31,8 @@ from tests.test_engine_integration import REPO, _PORT
 def _gcc_lib(name):
     try:
         p = subprocess.run(["gcc", "-print-file-name=" + name],
-                           capture_output=True, text=True).stdout.strip()
+                           capture_output=True, text=True,
+                           timeout=60).stdout.strip()
     except (OSError, subprocess.SubprocessError):
         return ""
     return p if os.path.isabs(p) and os.path.exists(p) else ""
@@ -50,7 +51,7 @@ STDCXX_LIB = _gcc_lib("libstdc++.so.6")
 def _gcc_major():
     try:
         v = subprocess.run(["gcc", "-dumpversion"], capture_output=True,
-                           text=True).stdout.strip()
+                           text=True, timeout=60).stdout.strip()
         return int(v.split(".")[0])
     except (OSError, ValueError, subprocess.SubprocessError):
         return 0
@@ -92,7 +93,8 @@ WORKER = textwrap.dedent("""
 def _build_sanitized(target):
     rc = subprocess.run(["make", "-C",
                          os.path.join(REPO, "horovod_tpu", "csrc"),
-                         target], capture_output=True, text=True)
+                         target], capture_output=True, text=True,
+                        timeout=900)
     assert rc.returncode == 0, rc.stderr[-2000:]
     return os.path.join(REPO, "horovod_tpu", "csrc",
                         f"build-{target}", "libhvt_core.so")
@@ -133,6 +135,8 @@ def _run_sanitized_gang(tmp_path, target, preload, extra_env):
 @pytest.mark.skipif(not TSAN_TRUSTWORTHY,
                     reason="gcc<11 libtsan: known destroyed-mutex "
                            "false positives (see TSAN_TRUSTWORTHY note)")
+@pytest.mark.slow  # a cold `make tsan` under six workers; `./ci.sh
+#                    --sanitize` is this case's gate, as it is ASan's
 @pytest.mark.timeout(600)
 def test_engine_threading_clean_under_tsan(tmp_path):
     report = str(tmp_path / "sanitizer_report")
@@ -169,9 +173,8 @@ def _run_sanitized_fuzz(tmp_path, target, preload, extra_env):
     return proc, reports
 
 
-@pytest.mark.slow  # cold `make asan` is a multi-minute build; the
-#                    UBSan twin below shares its build with the engine
-#                    gang and stays in the tier-1 window
+@pytest.mark.slow  # cold `make asan` is a multi-minute build; `./ci.sh
+#                    --sanitize` and the full run are its gate
 @pytest.mark.skipif(not ASAN_LIB or not STDCXX_LIB,
                     reason="libasan/libstdc++ not available")
 @pytest.mark.timeout(600)
@@ -188,6 +191,8 @@ def test_fuzz_corpus_clean_under_asan(tmp_path):
         f"\n{proc.stderr[-2000:]}")
 
 
+@pytest.mark.slow  # with the ASan twin above: the build and the replay of
+#                    the corpus belong to `./ci.sh --sanitize`
 @pytest.mark.skipif(not UBSAN_LIB, reason="libubsan not available")
 @pytest.mark.timeout(600)
 def test_fuzz_corpus_clean_under_ubsan(tmp_path):

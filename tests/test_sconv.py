@@ -33,10 +33,10 @@ _SCOPES = ("sconv_in_proj", "sconv_gate_conv", "sconv_out_proj", "dense_mlp")
 def _mixer(seq, taps=3, d=16):
     layer = sconv.ShortConv(taps, dtype=jnp.float32)
     x = jax.random.normal(jax.random.key(1), (2, seq, d), jnp.float32)
-    params = layer.init(jax.random.key(0), x)["params"]
     # the projections off their 0.02, so that the gates matter
-    params = jax.tree.map(lambda w: w * 10.0 if w.ndim > 1 and w.shape[0] == d
-                          else w, params)
+    params = jax.jit(lambda x: jax.tree.map(
+        lambda w: w * 10.0 if w.ndim > 1 and w.shape[0] == d else w,
+        layer.init(jax.random.key(0), x)["params"]))(x)
     return layer, params, x
 
 
@@ -60,10 +60,10 @@ def test_mixer_matches_the_position_by_position_reference(seq, taps):
     got = lambda p, x: layer.apply({"params": p}, x)
     want = lambda p, x: _reference_mixer(p, x, taps)
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(np.asarray(got(params, x)),
+        np.testing.assert_allclose(np.asarray(jax.jit(got)(params, x)),
                                    np.asarray(want(params, x)),
                                    rtol=2e-5, atol=2e-5)
-        grads = jax.grad(loss(got), (0, 1))(params, x)
+        grads = jax.jit(jax.grad(loss(got), (0, 1)))(params, x)
         want_grads = jax.grad(loss(want), (0, 1))(params, x)
     for (path, g), (_, w) in zip(
             jax.tree_util.tree_leaves_with_path(grads),
@@ -207,9 +207,9 @@ def test_lfm2_moe_gpt_matches_reference(remat):
             jax.tree_util.tree_leaves_with_path(want_grads), strict=True):
         err = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
         assert err <= 2e-5, (jax.tree_util.keystr(path), err)
-    plain, _, _, _ = _lfm2_model(not remat)
-    assert float(_lfm2_loss(plain, params, buffers, tokens)) == pytest.approx(
-        float(got), rel=1e-6)
+    plain = GPT(dataclasses.replace(model.cfg, remat=not remat))
+    assert float(jax.jit(lambda p: _lfm2_loss(plain, p, buffers, tokens))(
+        params)) == pytest.approx(float(got), rel=1e-6)
 
 
 def test_the_choice_bias_enters_the_choice_alone():
@@ -219,7 +219,7 @@ def test_the_choice_bias_enters_the_choice_alone():
     model, params, buffers, tokens = _lfm2_model(pattern="CE")
     bias = jnp.zeros(64).at[8:12].set(0.3)
     moved = {"block_1": {"moe": {"choice_bias": bias}}}
-    loss = lambda b: _lfm2_loss(model, params, b, tokens, sow=True)
+    loss = jax.jit(lambda b: _lfm2_loss(model, params, b, tokens, sow=True))
     (base, sown), (with_bias, sown_moved) = loss(buffers), loss(moved)
     assert float(base) != float(with_bias)
     chosen = sown_moved["block_1"]["moe"]["experts"][0]
@@ -230,7 +230,8 @@ def test_the_choice_bias_enters_the_choice_alone():
         np.sort(np.asarray(chosen), -1),
         np.sort(np.asarray(routing[0]["own"]), -1))
     assert float(with_bias) == pytest.approx(want, rel=1e-5)
-    grad = jax.grad(lambda b: _lfm2_loss(model, params, b, tokens))(moved)
+    grad = jax.jit(jax.grad(
+        lambda b: _lfm2_loss(model, params, b, tokens)))(moved)
     assert float(jnp.abs(grad["block_1"]["moe"]["choice_bias"]).max()) == 0.0
 
 
@@ -271,8 +272,8 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_whole():
     assert rows == tokens * 4           # every assignment is some share's
     np.testing.assert_allclose(np.asarray(total), np.asarray(want),
                                rtol=2e-5, atol=2e-6)
-    got, _ = whole.apply({"params": params,
-                          "buffers": variables["buffers"]}, h)
+    got, _ = jax.jit(whole.apply)({"params": params,
+                                   "buffers": variables["buffers"]}, h)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
                                rtol=2e-5, atol=2e-6)
 

@@ -39,6 +39,15 @@ BACKENDS = ["tcp", pytest.param("io_uring", marks=pytest.mark.skipif(
     not _uring_ok(), reason="io_uring kernel probe failed"))]
 
 
+def _all_exited_0_and_said(word, codes, outs):
+    """A failure shows every rank's output: the rank that aborted first,
+    and says why, is rarely the one whose exit code is read first."""
+    for rank, code in enumerate(codes):
+        assert code == 0 and word in outs[rank], (
+            f"rank {rank} exited {code}\n" + "\n".join(
+                f"---- rank {r} ----\n{out}" for r, out in enumerate(outs)))
+
+
 # ------------------------------------------------------- transient heals
 
 @pytest.mark.parametrize("backend", BACKENDS)
@@ -73,9 +82,7 @@ def test_flaky_conn_heals_bit_identical(tmp_path, backend):
                    "HVT_LINK_BACKEND": backend,
                    "HVT_OP_TIMEOUT_MS": "30000"})
     codes, outs = finish_gang(procs, logs, timeout=150)
-    for rank in range(4):
-        assert codes[rank] == 0, f"rank {rank}\n{outs[rank]}"
-        assert "CLEAN" in outs[rank], f"rank {rank}\n{outs[rank]}"
+    _all_exited_0_and_said("CLEAN", codes, outs)
 
 
 def test_reset_storm_survives(tmp_path):
@@ -100,12 +107,9 @@ def test_reset_storm_survives(tmp_path):
         extra_env={"HVT_FAULT_INJECT": "reset_storm:every_ops=3",
                    "HVT_OP_TIMEOUT_MS": "30000"})
     codes, outs = finish_gang(procs, logs, timeout=150)
-    recon = 0
-    for rank in range(4):
-        assert codes[rank] == 0, f"rank {rank}\n{outs[rank]}"
-        assert "CLEAN" in outs[rank], f"rank {rank}\n{outs[rank]}"
-        recon += sum(int(ln.split()[1]) for ln in outs[rank].splitlines()
-                     if ln.startswith("RECONNECTS"))
+    _all_exited_0_and_said("CLEAN", codes, outs)
+    recon = sum(int(ln.split()[1]) for out in outs for ln in out.splitlines()
+                if ln.startswith("RECONNECTS"))
     assert recon >= 1, f"storm never cut a link\n{outs}"
 
 
@@ -165,13 +169,9 @@ def test_partition_heals_after_hold(tmp_path):
             stderr=subprocess.STDOUT))
         logs.append(log)
     codes, outs = finish_gang(procs, logs, timeout=150)
-    durs = []
-    for rank in range(4):
-        assert codes[rank] == 0, f"rank {rank}\n{outs[rank]}"
-        assert "CLEAN" in outs[rank], f"rank {rank}\n{outs[rank]}"
-        for ln in outs[rank].splitlines():
-            if ln.startswith("RECONNECTS"):
-                durs.append(int(ln.split()[3]))
+    _all_exited_0_and_said("CLEAN", codes, outs)
+    durs = [int(ln.split()[3]) for out in outs for ln in out.splitlines()
+            if ln.startswith("RECONNECTS")]
     # at least one rank's heal waited out the (ranks-local) 300 ms hold
     assert max(durs) >= 200_000, durs
 
@@ -230,9 +230,7 @@ def test_tree_mode_member_link_heals_via_leader_reaccept(tmp_path):
             stderr=subprocess.STDOUT))
         logs.append(log)
     codes, outs = finish_gang(procs, logs, timeout=150)
-    for rank in range(4):
-        assert codes[rank] == 0, f"rank {rank}\n{outs[rank]}"
-        assert "CLEAN" in outs[rank], f"rank {rank}\n{outs[rank]}"
+    _all_exited_0_and_said("CLEAN", codes, outs)
 
 
 # ------------------------------------------------- abort/recovery boundary
@@ -266,9 +264,7 @@ def test_replay_budget_exhaustion_escalates(tmp_path, backend):
                    "HVT_LINK_RETRY_WINDOW_MS": "4000"})
     codes, outs = finish_gang(procs, logs, timeout=150)
     blob = "\n".join(outs)
-    for rank in range(4):
-        assert codes[rank] == 0, f"rank {rank}\n{outs[rank]}"
-        assert "EXITED" in outs[rank], f"rank {rank}\n{outs[rank]}"
+    _all_exited_0_and_said("EXITED", codes, outs)
     # the cut rank (or its peer) must have named the budget in the abort
     assert "replay budget exhausted" in blob, blob
     assert "HVT_REPLAY_BUDGET_BYTES=256" in blob, blob
@@ -298,12 +294,8 @@ def test_reconnect_disabled_restores_pr4_abort(tmp_path):
                    "HVT_LINK_RECONNECT": "0",
                    "HVT_OP_TIMEOUT_MS": "10000"})
     codes, outs = finish_gang(procs, logs, timeout=120)
-    caught = 0
-    for rank in range(4):
-        assert codes[rank] == 0, f"rank {rank}\n{outs[rank]}"
-        assert "EXITED" in outs[rank], f"rank {rank}\n{outs[rank]}"
-        caught += outs[rank].count("CAUGHT")
-    assert caught >= 1, outs
+    _all_exited_0_and_said("EXITED", codes, outs)
+    assert sum(out.count("CAUGHT") for out in outs) >= 1, outs
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
@@ -357,9 +349,7 @@ def test_shutdown_during_inflight_reconnect_exits_cleanly(tmp_path, backend):
             stderr=subprocess.STDOUT))
         logs.append(log)
     codes, outs = finish_gang(procs, logs, timeout=90)
-    for rank in range(2):
-        assert codes[rank] == 0, f"rank {rank}\n{outs[rank]}"
-        assert "SHUTDOWN" in outs[rank], f"rank {rank}\n{outs[rank]}"
+    _all_exited_0_and_said("SHUTDOWN", codes, outs)
 
 
 def test_sigkill_still_converges_one_deadline(tmp_path):
@@ -410,13 +400,19 @@ def test_diagnostics_reports_link_state(tmp_path, backend):
     x = np.arange(65536, dtype=np.float32) + r
     for i in range(8):
         hvt.allreduce(x, op=hvt.Sum, name=f"dg.{i}")
-    time.sleep(0.3)  # let UpdateDiag refresh past its 10 Hz throttle
     hvt.allreduce(x, op=hvt.Sum, name="dg.9")
-    time.sleep(0.3)
-    d = native.diagnostics()
-    links = d.get("links") or []
     n_ctrl = (n - 1) if r == 0 else 1
     n_data = n - 1
+    # UpdateDiag refreshes the snapshot at 10 Hz when the engine thread
+    # gets to run: poll for the healed link, do not sleep and hope
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline:
+        d = native.diagnostics()
+        links = d.get("links") or []
+        if len(links) == n_ctrl + n_data and (
+                r != 1 or any(l["epoch"] >= 1 for l in links)):
+            break
+        time.sleep(0.05)
     assert len(links) == n_ctrl + n_data, (r, d)
     for l in links:
         assert l["plane"] in ("ctrl", "data"), l
@@ -434,6 +430,4 @@ def test_diagnostics_reports_link_state(tmp_path, backend):
                    "HVT_LINK_BACKEND": backend,
                    "HVT_OP_TIMEOUT_MS": "30000"})
     codes, outs = finish_gang(procs, logs, timeout=120)
-    for rank in range(3):
-        assert codes[rank] == 0, f"rank {rank}\n{outs[rank]}"
-        assert "CLEAN" in outs[rank], f"rank {rank}\n{outs[rank]}"
+    _all_exited_0_and_said("CLEAN", codes, outs)

@@ -282,7 +282,7 @@ def test_ring_flash_gradients_match_dense():
         return (_dense_reference(q, k, v, True).astype(jnp.float32)
                 ** 2).mean()
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(qs, ks, vs)
+    gf = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(qs, ks, vs)
     gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -6,9 +6,17 @@ pattern the Ray/Spark suites use). Real-TF coverage lives in
 _DistributedGradientTape."""
 
 import numpy as np
+import pytest
 
-import horovod_tpu.tensorflow as hvt_tf
-from horovod_tpu.tensorflow.compression import Compression
+
+@pytest.fixture(scope="module", autouse=True)
+def _binding():
+    """Imported by the worker that runs this file, not by all six at
+    collection: the binding imports TensorFlow where it is installed
+    (8 to 13 s each)."""
+    global hvt_tf, Compression
+    import horovod_tpu.tensorflow as hvt_tf
+    from horovod_tpu.tensorflow.compression import Compression
 
 
 class FakeTape:

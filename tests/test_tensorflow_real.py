@@ -7,9 +7,14 @@ territory)."""
 import numpy as np
 import pytest
 
-tf = pytest.importorskip("tensorflow")
 
-import horovod_tpu.tensorflow as hvt_tf  # noqa: E402
+@pytest.fixture(scope="module", autouse=True)
+def _tensorflow():
+    """Imported by the worker that runs this file, not by all six at
+    collection (8 to 13 s each)."""
+    global tf, hvt_tf
+    tf = pytest.importorskip("tensorflow")
+    import horovod_tpu.tensorflow as hvt_tf
 
 
 def test_allreduce_real_tensor_roundtrip():
