@@ -19,7 +19,8 @@ TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Union
+from importlib import import_module as _module
+from typing import Callable, Optional, Union
 
 import flax.linen as nn
 import jax
@@ -93,16 +94,11 @@ class GPTConfig:
     norm_eps: float = 1e-6
     # One mixer a layer, in the order a string gives (hybrid models:
     # Nemotron-H's ``hybrid_override_pattern``): layer i is ``x +
-    # mixer(RMSNorm(x))`` with the mixer ``layer_pattern[i]`` names, "*"
-    # attention, "W" attention inside a window (below), "M" a Mamba-2
-    # mixer (models/ssm.py), "G" a Gated
-    # DeltaNet mixer (models/gdn.py), "K" a Kimi Delta Attention mixer
-    # (models/kda.py: the delta rule with a decay a key channel), "C" a
-    # gated short convolution (models/sconv.py), "L" latent attention
-    # (models/mla.py), "S" attention over the keys an indexer chooses
-    # (models/dsa.py), "E" the expert layer (models/moe.py), "-" the dense
-    # MLP. None (default) = every layer the attention + MLP (or expert)
-    # pair of ``Block``.
+    # mixer(RMSNorm(x))`` with the mixer ``layer_pattern[i]`` names, a
+    # letter of ``KINDS`` below: the table holds a record a kind of layer
+    # (what it is, which fields here it reads, how its leaves are
+    # sharded). None (default) = every layer the attention + MLP (or
+    # expert) pair of ``Block``.
     layer_pattern: Optional[str] = None
     # False leaves q and k unrotated: attention without a positional
     # term (the state-space or delta-rule layers of a hybrid carry the
@@ -214,14 +210,14 @@ class GPTConfig:
     # A second kind of attention layer, pattern letter "W": ``Attention``
     # in which a query sees the last attn_window causal keys, itself among
     # them (``t - attn_window < s <= t``; 0: the model has no such layer).
-    # **Which letter reads what, in one place.** Both kinds read the
-    # sizes and pieces above (n_heads, n_kv_heads, head_dim, heads_held,
-    # qk_norm, head_norm, attn_gate, rotary_base, rotary_fraction,
-    # use_flash, ring_mesh). "*" sees every causal key and turns q and k as
-    # ``rotary`` says; "W" sees its window, always turns them, and reads
-    # neither ``rotary`` nor anything else of its own. A model of local
-    # layers with positions and global ones without is ``rotary=False``
-    # beside a window here. A window on the ring path is refused by name.
+    # Both kinds read the sizes and pieces above (n_heads, n_kv_heads,
+    # head_dim, heads_held, qk_norm, head_norm, attn_gate, rotary_base,
+    # rotary_fraction, use_flash, ring_mesh). "*" sees every causal key and
+    # turns q and k as ``rotary`` says; "W" sees its window, always turns
+    # them, and reads neither ``rotary`` nor anything else of its own. A
+    # model of local layers with positions and global ones without is
+    # ``rotary=False`` beside a window here. A window on the ring path is
+    # refused by name.
     attn_window: int = 0
     # A norm after the mixer as well, in a patterned model: a layer is ``x
     # + post_norm(mixer(norm(x)))``, the second RMSNorm with a weight of
@@ -231,16 +227,6 @@ class GPTConfig:
     # The embedding's rows times this before the first layer (a model under
     # muP multiplies them by sqrt(d_model)), in the activations' dtype.
     embed_scale: float = 1.0
-
-
-# The crossover policy lives with the kernel (ops/flash_attention.py);
-# this lazy shim keeps the established `_resolve_flash` import path
-# without making every transformer import pay the pallas module load
-# (ops/flash_attention imports jax.experimental.pallas at module top).
-def _resolve_flash(use_flash, local_seq) -> bool:
-    from horovod_tpu.ops.flash_attention import resolve_flash
-
-    return resolve_flash(use_flash, local_seq)
 
 
 def _rotary(x, positions, base=10000.0, width=None):
@@ -383,8 +369,9 @@ def _attend(cfg, q, k, v, positions, core, window=0):
 
 
 class Attention(nn.Module):
-    """The attention both letters build: "*" turned as ``cfg.rotary``
-    says, "W" turned and inside the configuration's window."""
+    """The attention both of its kinds build (``KINDS``): over every
+    causal key and turned as ``cfg.rotary`` says, or turned and inside the
+    configuration's window."""
 
     cfg: GPTConfig
     rotary: bool        # whether q and k are turned
@@ -406,9 +393,14 @@ class Attention(nn.Module):
         if cfg.qk_norm and cfg.head_norm:
             raise ValueError("qk_norm (over the whole projected width) and "
                              "head_norm (a head) are one or the other")
-        core = ("ring" if cfg.ring_mesh is not None
-                else "flash" if _resolve_flash(cfg.use_flash, x.shape[-2])
-                else "einsum")
+        if cfg.ring_mesh is not None:
+            core = "ring"
+        else:
+            # (loaded here: importing this file loads no pallas)
+            from horovod_tpu.ops.flash_attention import resolve_flash
+
+            core = ("flash" if resolve_flash(cfg.use_flash, x.shape[-2])
+                    else "einsum")
         _count_trace(n_heads, n_kv, head_dim, core, self.window)
         # a model with both kinds of layer sows a layer's own input and
         # output where its caller collects ``intermediates``, as the other
@@ -482,40 +474,170 @@ class MLP(nn.Module):
 
 
 def _expert_layer(cfg: GPTConfig):
-    from horovod_tpu.models.moe import MoEMlp
+    return _module("horovod_tpu.models.moe").MoEMlp(
+        cfg.n_experts, cfg.moe_expert_ff or cfg.d_ff, cfg.experts_per_token,
+        dtype=cfg.dtype, score=cfg.moe_score,
+        route_scale=cfg.moe_route_scale, expert_act=cfg.moe_expert_act,
+        latent=cfg.moe_latent, shared_ff=cfg.moe_shared_ff,
+        held=cfg.experts_held, renormalise=cfg.moe_renormalise,
+        shared_gate=cfg.moe_shared_gate, name="moe")
 
-    return MoEMlp(cfg.n_experts, cfg.moe_expert_ff or cfg.d_ff,
-                  cfg.experts_per_token,
-                  dtype=cfg.dtype, score=cfg.moe_score,
-                  route_scale=cfg.moe_route_scale,
-                  expert_act=cfg.moe_expert_act, latent=cfg.moe_latent,
-                  shared_ff=cfg.moe_shared_ff, held=cfg.experts_held,
-                  renormalise=cfg.moe_renormalise,
-                  shared_gate=cfg.moe_shared_gate, name="moe")
+
+def _windowed_attention(cfg: GPTConfig):
+    if cfg.attn_window < 1:
+        raise ValueError(
+            f"layer_pattern holds 'W' and attn_window is {cfg.attn_window}: "
+            f"a windowed layer sees at least its own position")
+    return _layer(Attention(cfg, rotary=True, window=cfg.attn_window,
+                            name="attn"), positional=True)
+
+
+def _sparse_attention(cfg: GPTConfig):
+    layer = _module("horovod_tpu.models.dsa").SparseAttention(
+        cfg.n_heads, cfg.n_kv_heads or cfg.n_heads,
+        cfg.head_dim or cfg.d_model // cfg.n_heads, cfg.dsa_index_heads,
+        cfg.dsa_index_dim, cfg.dsa_topk, rotary_base=cfg.rotary_base,
+        norm_eps=cfg.norm_eps, use_flash=cfg.use_flash, dtype=cfg.dtype,
+        name="dsa")
+
+    def call(h, positions):
+        out, index_loss = layer(h, positions)
+        return out, {"dsa_index": index_loss}
+
+    return call
+
+
+def _layer(module, positional=False):
+    """``module``, which has no auxiliary losses, called the one way a
+    block calls every kind, ``(h, positions) -> (out, aux or None)``;
+    ``positional`` where it takes the positions too."""
+    return lambda h, positions: (
+        module(h, positions) if positional else module(h), None)
+
+
+def _mixers_rule(module, rule):
+    """A mixer's own rule, ``(leaf's name, tp_axis)``, as a record's."""
+    return lambda names, leaf, tp_axis, *_: getattr(_module(module), rule)(
+        names[-1], tp_axis)
+
+
+def _attention_leaf_spec(names, leaf, tp_axis, tp_size, ep_axis):
+    """The attention's leaves, as ``param_partition_spec`` says."""
+    if names[0] in ("q", "k", "v"):
+        heads = leaf.shape[1] if hasattr(leaf, "shape") else None
+        if tp_size and heads is not None and heads % tp_size:
+            return P()                     # replicated GQA K/V
+        return P(None, tp_axis, None)      # (d_model, heads, head_dim)
+    # ``o``: (heads, head_dim, d_model)
+    return P(tp_axis, None, None) if names[0] == "o" else P()
+
+
+@dataclasses.dataclass(frozen=True)
+class Kind:
+    """What this file knows of one kind of layer, in the one place it
+    knows it: ``KINDS`` holds a record a letter of ``layer_pattern``, and
+    the blocks, the ``remat`` policy, ``param_partition_spec`` and the
+    pattern error read it. A new kind is its mixer's file (plain sizes: a
+    mixer knows nothing of ``GPTConfig``), its fields in ``GPTConfig`` and
+    its record here; a mixer's module is loaded when a record needs it."""
+
+    letter: str
+    subtree: str    # the layer's parameters are ``block_i/<subtree>``
+    words: str      # what the pattern error calls it
+    # ``build(cfg)``, inside a block's ``__call__``: the layer under the
+    # subtree's name as ``(h, positions) -> (out, aux or None)``, and the
+    # one place the kind's fields are read (tests/test_models_kinds.py)
+    build: Callable
+    # ``leaf_spec(names, leaf, tp_axis, tp_size, ep_axis)``: the
+    # PartitionSpec of the leaf at the path ``names`` below the subtree
+    leaf_spec: Callable
+    # ``kept()``: the names (``checkpoint_name``) of what ``remat`` keeps
+    # of the layer, each explained where it is defined
+    kept: Callable = tuple
+
+
+KINDS = {kind.letter: kind for kind in (
+    Kind("*", "attn", "attention",
+         lambda cfg: _layer(Attention(cfg, rotary=cfg.rotary, name="attn"),
+                            positional=True),
+         _attention_leaf_spec),
+    Kind("W", "attn", "attention inside a window", _windowed_attention,
+         _attention_leaf_spec),
+    Kind("M", "ssm", "Mamba-2",
+         lambda cfg: _layer(_module("horovod_tpu.models.ssm").Mamba2Mixer(
+             cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state,
+             cfg.ssm_conv, held=cfg.ssm_heads_held, norm_eps=cfg.norm_eps,
+             dtype=cfg.dtype, name="ssm")),
+         _mixers_rule("horovod_tpu.models.ssm", "ssm_leaf_spec")),
+    Kind("G", "gdn", "Gated DeltaNet",
+         lambda cfg: _layer(_module("horovod_tpu.models.gdn").GatedDeltaNet(
+             cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim,
+             cfg.gdn_value_dim, cfg.gdn_conv, norm_eps=cfg.norm_eps,
+             dtype=cfg.dtype, name="gdn")),
+         _mixers_rule("horovod_tpu.models.gdn", "gdn_leaf_spec"),
+         lambda: (_module("horovod_tpu.ops.gated_delta_rule").KEPT_INVERSE,)),
+    Kind("K", "kda", "Kimi Delta Attention",
+         lambda cfg: _layer(
+             _module("horovod_tpu.models.kda").KimiDeltaAttention(
+                 cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv,
+                 cfg.kda_gate_rank, norm_eps=cfg.norm_eps, dtype=cfg.dtype,
+                 name="kda")),
+         _mixers_rule("horovod_tpu.models.kda", "kda_leaf_spec"),
+         lambda: (
+             _module("horovod_tpu.ops.channel_delta_rule").KEPT_INVERSE,)),
+    Kind("C", "sconv", "gated short convolution",
+         lambda cfg: _layer(_module("horovod_tpu.models.sconv").ShortConv(
+             cfg.sconv_taps, dtype=cfg.dtype, name="sconv")),
+         _mixers_rule("horovod_tpu.models.sconv", "sconv_leaf_spec")),
+    Kind("L", "mla", "latent attention",
+         lambda cfg: _layer(
+             _module("horovod_tpu.models.mla").LatentAttention(
+                 cfg.n_heads, cfg.mla_kv_rank, cfg.mla_nope_dim,
+                 cfg.mla_rope_dim, cfg.mla_value_dim,
+                 rotary_base=cfg.rotary_base, norm_eps=cfg.norm_eps,
+                 use_flash=cfg.use_flash, dtype=cfg.dtype, rotary=cfg.rotary,
+                 name="mla"), positional=True),
+         _mixers_rule("horovod_tpu.models.mla", "mla_leaf_spec")),
+    Kind("S", "dsa", "attention over chosen keys", _sparse_attention,
+         _mixers_rule("horovod_tpu.models.dsa", "dsa_leaf_spec"),
+         lambda: (_module("horovod_tpu.models.dsa").KEPT_CHOICE,
+                  _module("horovod_tpu.models.dsa").KEPT_INDEX_GRADS)),
+    # (``_expert_layer`` by its name in this module, each time: a builder's
+    # script puts a wrong layer there)
+    Kind("E", "moe", "experts",
+         lambda cfg: lambda h, positions: _expert_layer(cfg)(h),
+         lambda names, leaf, tp_axis, tp_size, ep_axis: _module(
+             "horovod_tpu.models.moe").expert_leaf_spec(
+                 names[-1], leaf, ep_axis, tp_axis),
+         lambda: (_module("horovod_tpu.models.moe").HELD_SUM,
+                  _module("horovod_tpu.models.moe").HELD_CHOICE)),
+    Kind("-", "mlp", "MLP", lambda cfg: _layer(MLP(cfg, name="mlp")),
+         lambda names, leaf, tp_axis, *_: {
+             "up": P(None, tp_axis), "gate": P(None, tp_axis),
+             "down": P(tp_axis, None)}.get(names[0], P())),
+)}
 
 
 class Block(nn.Module):
-    """One pre-norm block. Returns ``(x, aux)``: ``aux`` is the expert
-    layer's auxiliary losses (``models/moe.py``), None for a dense
-    block."""
+    """One pre-norm block, the kinds "*" and "-" or "E" of ``KINDS``.
+    Returns ``(x, aux)``: ``aux`` is the expert layer's auxiliary losses
+    (``models/moe.py``), None for a dense block."""
 
     cfg: GPTConfig
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        x = x + Attention(cfg, rotary=cfg.rotary, name="attn")(
-            _norm(cfg, "ln1")(x), positions)
-        h = _norm(cfg, "ln2")(x)
-        if not cfg.n_experts:
-            return x + MLP(cfg, name="mlp")(h), None
-        out, aux = _expert_layer(cfg)(h)
+        out, _ = KINDS["*"].build(cfg)(_norm(cfg, "ln1")(x), positions)
+        x = x + out
+        out, aux = KINDS["E" if cfg.n_experts else "-"].build(cfg)(
+            _norm(cfg, "ln2")(x), positions)
         return x + out, aux
 
 
 class MixerBlock(nn.Module):
     """One layer of a patterned model: ``x + mixer(RMSNorm(x))`` with the
-    one mixer ``kind`` names (``GPTConfig.layer_pattern``), or under
+    one mixer ``kind`` names (a letter of ``KINDS``), or under
     ``post_norm`` ``x + RMSNorm(mixer(RMSNorm(x)))``. Returns ``(x, aux)``
     as ``Block`` does."""
 
@@ -524,78 +646,14 @@ class MixerBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions):
-        cfg, aux = self.cfg, None
-        h = _norm(cfg, "norm")(x)
-        if self.kind == "*":
-            out = Attention(cfg, rotary=cfg.rotary, name="attn")(
-                h, positions)
-        elif self.kind == "W":
-            if cfg.attn_window < 1:
-                raise ValueError(
-                    f"layer_pattern holds 'W' and attn_window is "
-                    f"{cfg.attn_window}: a windowed layer sees at least "
-                    f"its own position")
-            out = Attention(cfg, rotary=True, window=cfg.attn_window,
-                            name="attn")(h, positions)
-        elif self.kind == "M":
-            from horovod_tpu.models.ssm import Mamba2Mixer
-
-            out = Mamba2Mixer(
-                cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
-                cfg.ssm_state, cfg.ssm_conv, held=cfg.ssm_heads_held,
-                norm_eps=cfg.norm_eps, dtype=cfg.dtype, name="ssm")(h)
-        elif self.kind == "G":
-            from horovod_tpu.models.gdn import GatedDeltaNet
-
-            out = GatedDeltaNet(
-                cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim,
-                cfg.gdn_value_dim, cfg.gdn_conv, norm_eps=cfg.norm_eps,
-                dtype=cfg.dtype, name="gdn")(h)
-        elif self.kind == "K":
-            from horovod_tpu.models.kda import KimiDeltaAttention
-
-            out = KimiDeltaAttention(
-                cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv,
-                cfg.kda_gate_rank, norm_eps=cfg.norm_eps, dtype=cfg.dtype,
-                name="kda")(h)
-        elif self.kind == "C":
-            from horovod_tpu.models.sconv import ShortConv
-
-            out = ShortConv(cfg.sconv_taps, dtype=cfg.dtype,
-                            name="sconv")(h)
-        elif self.kind == "L":
-            from horovod_tpu.models.mla import LatentAttention
-
-            out = LatentAttention(
-                cfg.n_heads, cfg.mla_kv_rank, cfg.mla_nope_dim,
-                cfg.mla_rope_dim, cfg.mla_value_dim,
-                rotary_base=cfg.rotary_base, norm_eps=cfg.norm_eps,
-                use_flash=cfg.use_flash, dtype=cfg.dtype,
-                rotary=cfg.rotary, name="mla")(h, positions)
-        elif self.kind == "S":
-            from horovod_tpu.models.dsa import SparseAttention
-
-            out, index_loss = SparseAttention(
-                cfg.n_heads, cfg.n_kv_heads or cfg.n_heads,
-                cfg.head_dim or cfg.d_model // cfg.n_heads,
-                cfg.dsa_index_heads, cfg.dsa_index_dim, cfg.dsa_topk,
-                rotary_base=cfg.rotary_base, norm_eps=cfg.norm_eps,
-                use_flash=cfg.use_flash, dtype=cfg.dtype,
-                name="dsa")(h, positions)
-            aux = {"dsa_index": index_loss}
-        elif self.kind == "E":
-            out, aux = _expert_layer(cfg)(h)
-        elif self.kind == "-":
-            out = MLP(cfg, name="mlp")(h)
-        else:
+        cfg = self.cfg
+        if self.kind not in KINDS:
             raise ValueError(
                 f"layer_pattern holds {self.kind!r}: a layer is one of "
-                f"'*' (attention), 'W' (attention inside a window), "
-                f"'M' (Mamba-2), 'G' (Gated DeltaNet), "
-                f"'K' (Kimi Delta Attention), "
-                f"'C' (gated short convolution), 'L' (latent attention), "
-                f"'S' (attention over chosen keys), 'E' (experts), "
-                f"'-' (MLP)")
+                + ", ".join(f"{kind.letter!r} ({kind.words})"
+                            for kind in KINDS.values()))
+        out, aux = KINDS[self.kind].build(cfg)(
+            _norm(cfg, "norm")(x), positions)
         if cfg.post_norm:
             with jax.named_scope("post_norm"):
                 out = _norm(cfg, "post_norm")(out)
@@ -641,39 +699,12 @@ class GPT(nn.Module):
                 "after the mixer is a patterned model's (MixerBlock)")
         block = Block if cfg.layer_pattern is None else MixerBlock
         if cfg.remat:
-            # everything recomputed but what a held expert layer's rounds
-            # summed to (``moe.HELD_SUM``: [T, width] float32 a layer): its
-            # backward pass makes each round again for its pullback, so
-            # the recomputed block would only run the rounds a third time;
-            # and the inverses of a Gated DeltaNet's chunks where the rule
-            # runs as kernels (``KEPT_INVERSE``: [c, c] bf16 a chunk and
-            # value head): two thirds of the rule's forward, which the
-            # recomputed block then leaves out; and what a held layer's
-            # router chose (``moe.HELD_CHOICE``: a bit a token and expert
-            # and the slots' order), which has no gradient and so is not
-            # chosen and sorted again; and the keys a sparse-attention
-            # mixer's indexer chose (``dsa.KEPT_CHOICE``: a byte a query
-            # and key), for the same reason; and the gradients of that
-            # indexer's loss by its four leaves (``dsa.KEPT_INDEX_GRADS``:
-            # float32 in the leaves' shapes), which are whole at the end
-            # of the first pass, so the recomputed block runs no indexer,
-            # no index scores and no loss; and the inverses of a Kimi Delta
-            # Attention mixer's chunks where its rule runs as kernels
-            # (``channel_delta_rule.KEPT_INVERSE``, as Gated DeltaNet's):
-            # the recomputed block runs the rule's forward walk alone, for
-            # the states its backward pass reads. No name is in any other
-            # model's program
-            from horovod_tpu.models.dsa import KEPT_CHOICE, KEPT_INDEX_GRADS
-            from horovod_tpu.models.moe import HELD_CHOICE, HELD_SUM
-            from horovod_tpu.ops.channel_delta_rule import (
-                KEPT_INVERSE as KEPT_CHANNEL_INVERSE)
-            from horovod_tpu.ops.gated_delta_rule import KEPT_INVERSE
-
+            # everything recomputed but what the kinds' records name
             block = nn.remat(
                 block, static_argnums=(),
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    HELD_SUM, HELD_CHOICE, KEPT_INVERSE, KEPT_CHOICE,
-                    KEPT_INDEX_GRADS, KEPT_CHANNEL_INVERSE))
+                policy=jax.checkpoint_policies.save_only_these_names(*(
+                    name for kind in KINDS.values()
+                    for name in kind.kept())))
         aux = {}
         for i in range(cfg.n_layers):
             kind = () if cfg.layer_pattern is None else (
@@ -695,11 +726,12 @@ class GPT(nn.Module):
 
 def param_partition_spec(params, *, tp_axis="tp", tp_size=None,
                          ep_axis=None):
-    """PartitionSpec pytree for Megatron-style tensor parallelism.
+    """PartitionSpec pytree for Megatron-style tensor parallelism, a leaf
+    by the rule of the kind of layer (``KINDS``) whose subtree it is in.
 
-    Column-parallel: q/k/v and MLP up kernels shard their output dim over
-    ``tp_axis``; row-parallel: attention out and MLP down kernels shard
-    their input dim, so XLA inserts exactly one psum per row-parallel
+    Column-parallel: q/k/v and MLP up and gate kernels shard their output
+    dim over ``tp_axis``; row-parallel: attention out and MLP down kernels
+    shard their input dim, so XLA inserts exactly one psum per row-parallel
     matmul (the NCCL-allreduce-per-layer pattern, compiled).
     Embedding and an untied ``lm_head`` shard the vocab dim. Norm scales
     replicate. The expert stacks of a sparse model shard their expert
@@ -713,50 +745,16 @@ def param_partition_spec(params, *, tp_axis="tp", tp_size=None,
     Without ``tp_size`` the spec assumes divisibility, matching the
     pre-GQA behavior.
     """
+    by_subtree = {kind.subtree: kind for kind in KINDS.values()}
 
     def spec_for(path, leaf):
         names = [getattr(p, "key", None) for p in path]
         if "embedding" in names or "lm_head" in names:
             return P(tp_axis, None)
-        if "moe" in names:
-            from horovod_tpu.models.moe import expert_leaf_spec
-
-            return expert_leaf_spec(names[-1], leaf, ep_axis, tp_axis)
-        if "ssm" in names:
-            from horovod_tpu.models.ssm import ssm_leaf_spec
-
-            return ssm_leaf_spec(names[-1], tp_axis)
-        if "gdn" in names:
-            from horovod_tpu.models.gdn import gdn_leaf_spec
-
-            return gdn_leaf_spec(names[-1], tp_axis)
-        if "kda" in names:
-            from horovod_tpu.models.kda import kda_leaf_spec
-
-            return kda_leaf_spec(names[-1], tp_axis)
-        if "sconv" in names:
-            from horovod_tpu.models.sconv import sconv_leaf_spec
-
-            return sconv_leaf_spec(names[-1], tp_axis)
-        if "mla" in names:
-            from horovod_tpu.models.mla import mla_leaf_spec
-
-            return mla_leaf_spec(names[-1], tp_axis)
-        if "dsa" in names:
-            from horovod_tpu.models.dsa import dsa_leaf_spec
-
-            return dsa_leaf_spec(names[-1], tp_axis)
-        if any(n in ("q", "k", "v") for n in names):
-            heads = leaf.shape[1] if hasattr(leaf, "shape") else None
-            if tp_size and heads is not None and heads % tp_size:
-                return P()                     # replicated GQA K/V
-            return P(None, tp_axis, None)      # (d_model, heads, head_dim)
-        if "o" in names:
-            return P(tp_axis, None, None)      # (heads, head_dim, d_model)
-        if "up" in names or "gate" in names:
-            return P(None, tp_axis)
-        if "down" in names:
-            return P(tp_axis, None)
+        for depth, name in enumerate(names):
+            if name in by_subtree:
+                return by_subtree[name].leaf_spec(
+                    names[depth + 1:], leaf, tp_axis, tp_size, ep_axis)
         return P()
 
     return jax.tree_util.tree_map_with_path(spec_for, params)

@@ -146,7 +146,6 @@ def test_gpt_use_flash_auto_resolves_by_sequence_length(monkeypatch):
     import dataclasses
 
     from horovod_tpu.models import GPT, GPTConfig
-    from horovod_tpu.models import transformer as tr
     from horovod_tpu.ops import _pallas
     from horovod_tpu.ops import flash_attention as fa
 
@@ -164,15 +163,15 @@ def test_gpt_use_flash_auto_resolves_by_sequence_length(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(_pallas, "interpret", lambda: True)
     # the resolver: the boundary, a length that takes no proper tile
-    assert tr._resolve_flash("auto", fa._AUTO_FROM - 128) is False
-    assert tr._resolve_flash("auto", fa._AUTO_FROM) is True
-    assert tr._resolve_flash("auto", 4096) is True
+    assert fa.resolve_flash("auto", fa._AUTO_FROM - 128) is False
+    assert fa.resolve_flash("auto", fa._AUTO_FROM) is True
+    assert fa.resolve_flash("auto", 4096) is True
     for ragged in (fa._AUTO_FROM + 8, 3000, 4100):
-        assert tr._resolve_flash("auto", ragged) is False
-    assert tr._resolve_flash(True, 16) is True
-    assert tr._resolve_flash(False, 100000) is False
+        assert fa.resolve_flash("auto", ragged) is False
+    assert fa.resolve_flash(True, 16) is True
+    assert fa.resolve_flash(False, 100000) is False
     with pytest.raises(ValueError, match="auto"):
-        tr._resolve_flash("einsum", 16)
+        fa.resolve_flash("einsum", 16)
 
     cfg = GPTConfig(vocab_size=64, n_layers=1, d_model=32, n_heads=2,
                     d_ff=64, dtype=jnp.float32, max_seq_len=4096,

@@ -4,9 +4,9 @@ sigmoid router with a choice bias and two shared experts, an untied head)
 through ``models.GPT`` against ``chipbench/reference/deepseek_v3.py``,
 which shares no code with the package: loss and gradients, the choice
 bias, the eight shares of an expert layer against the whole, the six new
-scopes in this model's step and no other's, and the new fields' defaults
-leaving every other model's program as it was. The mixer alone and the
-flash kernels at two widths are ``test_mla.py``'s."""
+scopes in this model's step. The mixer alone and the flash kernels at two
+widths are ``test_mla.py``'s; that the other models are as they were
+whatever this kind's fields say is ``test_models_kinds.py``'s."""
 
 import dataclasses
 import functools
@@ -18,7 +18,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-import small_models as others
 from chipbench.reference import deepseek_v3 as reference
 from horovod_tpu.models import GPT, GPTConfig
 from horovod_tpu.models.moe import MoEMlp
@@ -242,76 +241,6 @@ def test_kanana_gradient_program_names_its_scopes():
     assert specs["lm_head"] == specs["embedding"] == P("tp", None)
 
 
-def _other(name):
-    """A small instance of a configuration the benchmark had before this
-    mixer, nothing initialised (``small_models`` initialises eagerly, ten
-    seconds and more a model): ``(config, loss of the parameters given a
-    model, the parameters' shapes)``."""
-    if name == "dense":
-        make = lambda: (GPT(GPTConfig(
-            vocab_size=64, n_layers=2, d_model=32, n_heads=4, d_ff=128,
-            max_seq_len=8, dtype=jnp.bfloat16, remat=True,
-            use_flash="auto")),)
-    else:
-        make = {"olmoe": others.sparse_model, "nemotron_h":
-                others.hybrid_model, "qwen3_next": others.qwen_model}[name]
-        make = functools.partial(make, remat=True)
-    seen = []
-
-    def shapes():
-        model, *rest = make()
-        seen.append(model.cfg)
-        return rest
-
-    rest = jax.eval_shape(shapes)
-    cfg = seen[0]
-    if name == "dense":
-        tokens = jnp.zeros((2, 8), jnp.int32)
-        params = jax.eval_shape(GPT(cfg).init, jax.random.key(0),
-                                tokens)["params"]
-        return cfg, (lambda model: lambda p: model.apply(
-            {"params": p}, tokens).astype(jnp.float32).sum()), params
-    params, *given = rest
-    given = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), given)
-    loss = {"olmoe": others.sparse_loss, "nemotron_h": others.hybrid_loss,
-            "qwen3_next": others.qwen_loss}[name]
-    return cfg, (lambda model: lambda p: loss(model, p, *given)), params
-
-
-# the dense decoder (``Block``) and the cheapest patterned model
-# (``MixerBlock``, whose branch of the letter alone reads the fields) lower
-# their steps; the other two show their patterns and trees
-@pytest.mark.parametrize("name, lowers", [
-    ("dense", True), ("nemotron_h", True), ("olmoe", False),
-    ("qwen3_next", False)])
-def test_other_models_are_as_they_were(name, lowers):
-    """No other configuration's pattern holds the letter, its tree no
-    ``mla`` leaf and its lowered step no ``mla_*`` scope; and the new
-    fields belong to that letter alone: naming another rank and other
-    widths gives the same tree and, instruction for instruction, the same
-    lowered step as naming none."""
-    cfg, loss, params = _other(name)
-    assert "L" not in (cfg.layer_pattern or "")
-    assert not [jax.tree_util.keystr(path) for path, _
-                in jax.tree_util.tree_leaves_with_path(params)
-                if "mla" in jax.tree_util.keystr(path)]
-    assert (cfg.mla_kv_rank, cfg.mla_nope_dim, cfg.mla_rope_dim,
-            cfg.mla_value_dim) == (0, 128, 64, 128)
-    named = dataclasses.replace(cfg, mla_kv_rank=16, mla_nope_dim=8,
-                                mla_rope_dim=4, mla_value_dim=8)
-    tokens = jnp.zeros((2, 8), jnp.int32)
-    shapes = lambda c: jax.tree.map(jnp.shape, jax.eval_shape(
-        GPT(c).init, jax.random.key(0), tokens))
-    assert shapes(named) == shapes(cfg)
-    if not lowers:
-        return
-    lowered = lambda c: jax.jit(jax.grad(loss(GPT(c)))).lower(params)
-    mine = lowered(cfg)
-    assert not [n for n in re.findall(
-        r'loc\("([^"]*)"', mine.as_text(debug_info=True)) if "/mla_" in n]
-    assert lowered(named).as_text() == mine.as_text()
-
-
 @pytest.mark.parametrize("field, value, changed", [
     ("mla_kv_rank", 24, {"kv_down": (32, 28), "kv_norm": (24,),
                          "kv_up": (24, 4, 16)}),
@@ -332,12 +261,8 @@ def test_each_width_moves_its_own_leaves(field, value, changed):
         f"block_0.mla.{k}": v for k, v in changed.items()}
 
 
-def test_pattern_error_names_the_letter_l_and_a_layer_needs_its_rank():
+def test_a_latent_layer_needs_its_rank():
     cfg = GPTConfig(vocab_size=16, n_layers=1, d_model=8, n_heads=2,
-                    layer_pattern="Q", dtype=jnp.float32)
-    tokens = jnp.zeros((1, 4), jnp.int32)
-    with pytest.raises(ValueError, match=r"'L' \(latent attention\)"):
-        GPT(cfg).init(jax.random.key(0), tokens)
+                    layer_pattern="L", dtype=jnp.float32)
     with pytest.raises(ValueError, match="latent rank"):
-        GPT(dataclasses.replace(cfg, layer_pattern="L")).init(
-            jax.random.key(0), tokens)
+        GPT(cfg).init(jax.random.key(0), jnp.zeros((1, 4), jnp.int32))

@@ -4,9 +4,9 @@ their own width behind a sigmoid router with a choice bias and one shared
 expert, an untied head) through ``models.GPT`` against
 ``chipbench/reference/kimi_linear.py``, which shares no code with the
 package: loss and gradients, the 32 shares of an expert layer against the
-whole, the five new scopes in this model's step and no other's, and the
-new fields' defaults leaving every other model's program as it was. The
-mixer and its rule alone are ``test_kda.py``'s."""
+whole, and the five new scopes in this model's step. The mixer and its rule
+alone are ``test_kda.py``'s; that the other models are as they were
+whatever this kind's fields say is ``test_models_kinds.py``'s."""
 
 import dataclasses
 import functools
@@ -18,7 +18,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-import small_models as others
 from chipbench.reference import kimi_linear as reference
 from horovod_tpu.models import GPT, GPTConfig
 from horovod_tpu.models.moe import MoEMlp
@@ -242,56 +241,6 @@ def test_kimi_gradient_program_names_its_scopes():
     assert specs["block_3"]["moe"]["gate"] == P("ep", None, "tp")
 
 
-def _other(name):
-    """A small instance of a configuration the benchmark had before this
-    mixer, nothing initialised: ``(config, loss of the parameters given a
-    model, the parameters' shapes)``."""
-    make = {"olmoe": others.sparse_model, "nemotron_h": others.hybrid_model,
-            "qwen3_next": others.qwen_model}[name]
-    seen = []
-
-    def shapes():
-        model, *rest = make(remat=True)
-        seen.append(model.cfg)
-        return rest
-
-    params, *given = jax.eval_shape(shapes)
-    given = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), given)
-    loss = {"olmoe": others.sparse_loss, "nemotron_h": others.hybrid_loss,
-            "qwen3_next": others.qwen_loss}[name]
-    return seen[0], (lambda model: lambda p: loss(model, p, *given)), params
-
-
-@pytest.mark.parametrize("name, lowers", [
-    ("nemotron_h", True), ("olmoe", False), ("qwen3_next", False)])
-def test_other_models_are_as_they_were(name, lowers):
-    """No other configuration's pattern holds the letter, its tree no
-    ``kda`` leaf and its lowered step no ``kda_*`` scope; and the new
-    fields belong to that letter alone: naming heads, a width, taps and a
-    rank gives the same tree and, instruction for instruction, the same
-    lowered step as naming none."""
-    cfg, loss, params = _other(name)
-    assert "K" not in (cfg.layer_pattern or "")
-    assert not [jax.tree_util.keystr(path) for path, _
-                in jax.tree_util.tree_leaves_with_path(params)
-                if "kda" in jax.tree_util.keystr(path)]
-    assert (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv,
-            cfg.kda_gate_rank) == (0, 128, 4, 128)
-    named = dataclasses.replace(cfg, kda_heads=4, kda_head_dim=8,
-                                kda_conv=3, kda_gate_rank=8)
-    tokens = jnp.zeros((2, 8), jnp.int32)
-    shapes = lambda c: jax.tree.map(jnp.shape, jax.eval_shape(
-        GPT(c).init, jax.random.key(0), tokens))
-    assert shapes(named) == shapes(cfg)
-    if not lowers:
-        return
-    lowered = lambda c: jax.jit(jax.grad(loss(GPT(c)))).lower(params)
-    mine = lowered(cfg)
-    assert not [n for n in re.findall(
-        r'loc\("([^"]*)"', mine.as_text(debug_info=True)) if "/kda_" in n]
-    assert lowered(named).as_text() == mine.as_text()
-
-
 @pytest.mark.parametrize("field, value, changed", [
     ("kda_heads", 2, {"in_proj_qkv": (32, 48), "conv_kernel": (4, 48),
                       "in_proj_beta": (32, 2), "decay_up": (8, 16),
@@ -312,10 +261,3 @@ def test_each_size_moves_its_own_leaves(field, value, changed):
     assert {name: shape for name, shape in after.items()
             if before[name] != shape} == {
         f"block_0.kda.{k}": v for k, v in changed.items()}
-
-
-def test_pattern_error_names_the_letter_k():
-    cfg = GPTConfig(vocab_size=16, n_layers=1, d_model=8, n_heads=2,
-                    layer_pattern="Q", dtype=jnp.float32)
-    with pytest.raises(ValueError, match=r"'K' \(Kimi Delta Attention\)"):
-        GPT(cfg).init(jax.random.key(0), jnp.zeros((1, 4), jnp.int32))

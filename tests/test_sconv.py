@@ -4,8 +4,8 @@ with per-head norms, a SwiGLU dense MLP beside experts of their own width
 behind a sigmoid router) against ``chipbench/reference/lfm2_moe.py``, which
 walks the convolution position by position and shares no code with the
 package; the shares of an expert layer against the whole; the new scopes
-in this model's step and no other's; and the new fields' defaults leaving
-every other model's program as it was."""
+in this model's step and no other's. That the other models are as they
+were whatever this kind's fields say is ``test_models_kinds.py``'s."""
 
 import dataclasses
 import functools
@@ -370,24 +370,6 @@ def test_no_other_models_step_holds_the_new_scopes(name):
                 or "['mlp']['gate']" in leaf]
 
 
-@pytest.mark.parametrize("name", _OTHERS)
-def test_new_fields_defaults_leave_the_other_models_as_they_were(name):
-    """The experts' own width defaults to ``d_ff`` and the taps belong to
-    a letter these patterns do not hold: naming ``d_ff`` as the experts'
-    width, or other taps, gives the same tree and, instruction for
-    instruction, the same lowered step as naming neither."""
-    cfg, loss, params = _other(name)
-    assert cfg.moe_expert_ff is None and cfg.sconv_taps == 3
-    named = dataclasses.replace(cfg, moe_expert_ff=cfg.d_ff, sconv_taps=5)
-    tokens = jnp.zeros((2, 8), jnp.int32)
-    shapes = lambda c: jax.tree.map(jnp.shape, jax.eval_shape(
-        GPT(c).init, jax.random.key(0), tokens))
-    assert shapes(named) == shapes(cfg)
-    lowered = lambda c: jax.jit(jax.grad(loss(GPT(c)))).lower(
-        params).as_text()
-    assert lowered(named) == lowered(cfg)
-
-
 @pytest.mark.parametrize("field, value, changed", [
     ("moe_expert_ff", 20, {"gate": (8, 32, 20), "up": (8, 32, 20),
                            "down": (8, 20, 32)}),
@@ -413,15 +395,11 @@ def test_lfm2_config_field_changes_its_part_only(field, value, changed):
     assert moved == {prefix[field] + k: v for k, v in changed.items()}
 
 
-def test_pattern_error_names_the_letter_c():
+def test_an_activation_the_mlp_does_not_have_is_refused():
     cfg = GPTConfig(vocab_size=16, n_layers=1, d_model=8, n_heads=2,
-                    layer_pattern="Q", dtype=jnp.float32)
-    with pytest.raises(ValueError,
-                       match=r"'C' \(gated short convolution\)"):
-        GPT(cfg).init(jax.random.key(0), jnp.zeros((1, 4), jnp.int32))
+                    layer_pattern="-", mlp_act="geglu", dtype=jnp.float32)
     with pytest.raises(KeyError):
-        GPT(dataclasses.replace(cfg, layer_pattern="-", mlp_act="geglu")
-            ).init(jax.random.key(0), jnp.zeros((1, 4), jnp.int32))
+        GPT(cfg).init(jax.random.key(0), jnp.zeros((1, 4), jnp.int32))
 
 
 def test_mixer_sows_its_input_and_output():
