@@ -190,6 +190,7 @@ def wrong_programs():
     import jax.numpy as jnp
 
     from horovod_tpu.models import transformer
+    from horovod_tpu.ops import rotary
 
     return (
         ("a window of 2,047", _configured(attn_window=2047)),
@@ -198,10 +199,10 @@ def wrong_programs():
         ("a window in the full layers too",
          _attend_with(lambda cfg, window: cfg.attn_window)),
         ("the full layers turned", _configured(rotary=True)),
-        # the full layers of this model turn nothing, so `_rotary` is the
-        # windowed layers' alone
+        # the full layers of this model turn nothing, so `rotary` (which
+        # `Attention` loads where it calls it) is the windowed layers' alone
         ("the windowed layers not turned", _swapped(
-            transformer, "_rotary", lambda x, *args: x)),
+            rotary, "rotary", lambda x, *args, **kwargs: x)),
         ("no gate", _attention_with_sigmoid(jnp.ones_like)),
         ("silu in the gate", _attention_with_sigmoid(jax.nn.silu)),
         ("no norm a head", _norms_left_out("q_norm", "k_norm")),

@@ -80,10 +80,10 @@ from jax.sharding import PartitionSpec as P
 
 # a head's norm is the latent's: RMSNorm over the last axis in float32
 from horovod_tpu.models.mla import latent_norm as head_rms
-from horovod_tpu.models.mla import rotate_halves
 from horovod_tpu.ops import _pallas
 from horovod_tpu.ops import dsa as ops
 from horovod_tpu.ops import flash_attention as flash
+from horovod_tpu.ops.rotary import rotary
 
 # The name (``jax.ad_checkpoint.checkpoint_name``) of the choice: a byte a
 # query and key, kept by ``models.GPT``'s ``remat`` policy.
@@ -192,18 +192,17 @@ class SparseAttention(nn.Module):
         scale = 1.0 / np.sqrt(hd)
         with jax.named_scope("dsa_proj"):
             q, k, v = by_head(x, w_q), by_head(x, w_k), by_head(x, w_v)
-            q = rotate_halves(head_rms(q, q_norm, self.norm_eps), positions,
-                              self.rotary_base)
-            k = rotate_halves(head_rms(k, k_norm, self.norm_eps), positions,
-                              self.rotary_base)
+            q, k = rotary((head_rms(q, q_norm, self.norm_eps),
+                           head_rms(k, k_norm, self.norm_eps)), positions,
+                          self.rotary_base)
         detached = jax.lax.stop_gradient(x)
 
         def index_parts(w_qi, w_ki, ki_norm, w_w):
             """``q^I``, ``k^I`` and ``w`` of the detached input."""
-            q_i = rotate_halves(
+            q_i = rotary(
                 by_head(detached, w_qi).astype(jnp.float32), positions,
                 self.rotary_base).astype(self.dtype)
-            k_i = rotate_halves(index_key_norm(
+            k_i = rotary(index_key_norm(
                 jnp.dot(detached, w_ki.astype(self.dtype)), ki_norm,
                 self.norm_eps), positions, self.rotary_base).astype(
                     self.dtype)

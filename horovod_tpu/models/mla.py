@@ -70,6 +70,7 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.ops import _pallas
 from horovod_tpu.ops import flash_attention as flash
+from horovod_tpu.ops.rotary import rotary
 
 
 def _count_trace(heads, rank, nope, rope, value):
@@ -88,20 +89,6 @@ def pairs_to_halves(w, width: int):
     keep = w.shape[-1] - width
     return jnp.concatenate(
         [w[..., :keep], w[..., keep::2], w[..., keep + 1::2]], axis=-1)
-
-
-def rotate_halves(x, positions, base: float):
-    """The rotary over the whole last axis of ``x [batch, seq, .., e]``,
-    its halves against each other: channel ``j`` of the first half and of
-    the second turn by ``positions x base^(-2j / e)``. Float32 phases,
-    ``x.dtype`` out. ``positions [batch, seq]``."""
-    half = x.shape[-1] // 2
-    freqs = 1.0 / (base ** (np.arange(half) / half))
-    angles = positions[..., None].astype(jnp.float32) * freqs
-    angles = angles.reshape(angles.shape[:2] + (1,) * (x.ndim - 3) + (half,))
-    cos, sin = jnp.cos(angles).astype(x.dtype), jnp.sin(angles).astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
 def latent_norm(c, weight, eps: float):
@@ -202,8 +189,7 @@ class LatentAttention(nn.Module):
             values = by_head(c, w_kvb[..., n:])
         if self.rotary:
             with jax.named_scope("mla_rope"):
-                q_r = rotate_halves(q_r, positions, self.rotary_base)
-                k_r = rotate_halves(k_r, positions, self.rotary_base)
+                q_r, k_r = rotary((q_r, k_r), positions, self.rotary_base)
         with jax.named_scope("mla_core"):
             out = causal_attention(q_n, q_r, k_n, k_r, values, positions,
                                    score_scale(n, e), self.use_flash)

@@ -229,23 +229,6 @@ class GPTConfig:
     embed_scale: float = 1.0
 
 
-def _rotary(x, positions, base=10000.0, width=None):
-    """Rotary position embeddings (fp32 phase math) at ``base`` over the
-    first ``width`` channels of a head (None: all of them), the halves of
-    that width rotated against each other; the rest pass as they are."""
-    *_, seq, heads, head_dim = x.shape
-    width = head_dim if width is None else width
-    half = width // 2
-    freqs = 1.0 / (base ** (np.arange(0, half) / half))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [.., seq, half]
-    cos = jnp.cos(angles)[..., None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[..., None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:width]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos]
-                           + ([x[..., width:]] if width < head_dim else []),
-                           axis=-1)
-
-
 def _repeat_kv(k, v, group):
     """Broadcast GQA K/V heads to the full query head count (no-op for
     MHA). The flash path never calls this — its kernel aliases the
@@ -426,9 +409,13 @@ class Attention(nn.Module):
         if self.rotary:
             turned = (None if cfg.rotary_fraction == 1.0
                       else int(cfg.rotary_fraction * head_dim))
+            # (loaded here, as the flash kernels are)
+            from horovod_tpu.ops.rotary import rotary
+
             with jax.named_scope("attn_rope"):
-                q = _rotary(q, positions, cfg.rotary_base, turned)
-                k = _rotary(k, positions, cfg.rotary_base, turned)
+                # a norm over the whole width leaves q and k [b, s, h d]
+                q, k = rotary((q, k), positions, cfg.rotary_base, turned,
+                              flat=cfg.qk_norm)
         with jax.named_scope("attn_core"):
             if self.window:
                 # a scope of its own inside the core's, so that a reader

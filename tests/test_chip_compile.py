@@ -25,6 +25,7 @@ from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops import gated_delta_rule as gdr
 from horovod_tpu.ops import head_norm
 from horovod_tpu.ops import kth_largest as kth
+from horovod_tpu.ops import rotary as rotary_op
 from horovod_tpu.parallel.sequence import ring_attention
 
 
@@ -902,3 +903,133 @@ def test_index_scores_and_indexer_loss_compile_for_v5e(compiled_kernel,
     assert "hvt_dsa_loss" in text
     # nothing [s, s] wide but the operands: no float32 score matrix made
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+@pytest.mark.parametrize("shape", [
+    pytest.param((8, 1024, 20, 20, 64, None), id="rotary-gpt2l-s1024"),
+    pytest.param((2, 4096, 20, 20, 64, None), id="rotary-gpt2l-s4096"),
+    pytest.param((1, 16384, 32, 4, 128, None), id="rotary-trinitymini-s16384"),
+    pytest.param((2, 8192, 32, 0, 64, None), id="rotary-kanana2-s8192-q_r"),
+    pytest.param((2, 8192, 16, 2, 256, 64), id="rotary-qwen3next-s8192"),
+    pytest.param((1, 1040, 2, 1, 256, None),
+                 id="rotary-five-blocks-of-208-the-partner-a-tile-away"),
+])
+def test_rotary_kernel_compiles_between_the_product_and_the_flash_kernels(
+        shape, compiled_kernel, v5e_devices):
+    """``ops/rotary.py``'s kernel at the benchmark cells' own sizes, bf16,
+    between a projection's product and the flash kernels' transposition,
+    forward and backward: one call a pass for ``q`` and ``k`` together
+    (alone where there is no second array), in the default VMEM scope; the
+    forward's operands are the products' own results, which XLA writes
+    heads major, with no copy or transposition between, and the program's
+    temporaries stay under two of ``q`` and ``k`` as a tile pads them (no
+    float32 of that size, no second layout)."""
+    b, s, h, h_k, dim, width = shape
+    heads = tuple(n for n in (h, h_k) if n)
+    one = SingleDeviceSharding(v5e_devices[0])
+    like = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one)
+    x, ws = like(b, s, 256), tuple(like(256, n, dim) for n in heads)
+    gs = tuple(like(b, n, s, dim) for n in heads)
+
+    def loss(ws, x, gs, positions):
+        turned = rotary_op.rotary_kernels(
+            tuple(jnp.einsum("bsd,dhk->bshk", x, w) for w in ws),
+            positions, 1e4, width)
+        # what flash_attention does with its operands
+        return sum(jnp.sum((jnp.transpose(t, (0, 2, 1, 3)) * g).astype(
+            jnp.float32) ** 2) for t, g in zip(turned, gs))
+
+    compiled = jax.jit(jax.grad(loss)).lower(
+        ws, x, gs, like(b, s, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = {kernel: re.findall(
+        rf" custom-call\(([^)]*)\).*tpu_custom_call.*hvt_rotary_{kernel}/",
+        text) for kernel in ("fwd", "bwd")}
+    assert len(calls["fwd"]) == len(calls["bwd"]) == 1
+    assert text.count("tpu_custom_call") == 2
+    operands = calls["fwd"][0].split(", ")[2:]      # after the two tables
+    assert len(operands) == len(heads)
+    for name in operands:       # each is made by the product itself
+        made, = re.findall(rf"^ *{re.escape(name)} = .*$", text, re.M)
+        assert "dot_general" in made and " copy(" not in made, made
+    assert "vmem_limit_bytes" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2.1 * (
+        2 * b * s * sum(heads) * max(dim, 128))
+
+
+def test_rotary_kernel_compiles_under_shard_map(compiled_kernel,
+                                                v5e_devices):
+    """gpt2l-dp4's rotary: four chips' batch under a ``shard_map`` that
+    checks varying axes, the positions made inside it (every chip's the
+    same: the tables vary over no mesh axis and the arrays over one): value
+    and gradients compile with one forward and one
+    backward call."""
+    mesh = Mesh(np.array(v5e_devices), ("world",))
+    rows = P("world")
+    like = lambda heads: jax.ShapeDtypeStruct(
+        (32, 1024, heads, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, rows))
+
+    def per_chip(q, k):
+        positions = jnp.broadcast_to(jnp.arange(q.shape[1]), q.shape[:2])
+        return sum(jnp.mean(t.astype(jnp.float32) ** 2)
+                   for t in rotary_op.rotary_kernels((q, k), positions, 1e4))
+
+    step = jax.jit(jax.shard_map(
+        lambda q, k: jax.tree.map(
+            lambda x: jax.lax.pmean(x, "world"),
+            jax.value_and_grad(per_chip, argnums=(0, 1))(q, k)),
+        mesh=mesh, in_specs=(rows, rows), out_specs=P()))
+    text = step.lower(like(20), like(20)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "hvt_rotary_fwd" in text and "hvt_rotary_bwd" in text
+
+
+def test_sparse_attention_turns_q_and_k_by_the_kernel_in_whole_blocks(
+        compiled_kernel, v5e_devices, monkeypatch):
+    """``keyevl2-s16384``'s mixer at its published widths (32 heads on 4 of
+    128, 16 index heads of 64, 16,384 positions) under ``remat``, as a TPU
+    backend traces it: ``q`` and ``k`` go through ``hvt_rotary_fwd`` in one
+    call (the first pass and the recomputed one) and their gradients
+    through ``hvt_rotary_bwd``, beside the mixer's own kernels; the index
+    queries (float32) and the index key (no head axis) stay plain; and
+    **every block of every call divides the positions**. With blocks of
+    112 the last step overhung the arrays by 80 positions, XLA had laid the
+    ``sin`` table and the recomputed ``k`` at the last byte of VMEM
+    (buffer assignment of the cell's step, PR 60), and the step never came
+    back on the chip."""
+    from horovod_tpu.models import GPT, GPTConfig
+
+    z, one_chip = DSA_SHAPE, SingleDeviceSharding(v5e_devices[0])
+    monkeypatch.setattr(_pallas, "on_tpu", lambda: True)
+    plans, real = [], rotary_op._turn
+    monkeypatch.setattr(rotary_op, "_turn", lambda xs, cos, sin, plan: (
+        plans.append((plan, [x.shape for x in xs])),
+        real(xs, cos, sin, plan))[1])
+    model = GPT(GPTConfig(
+        vocab_size=512, n_layers=1, layer_pattern="S", d_model=2048,
+        n_heads=z["h"], n_kv_heads=z["h_kv"], head_dim=z["d"], d_ff=256,
+        max_seq_len=z["s"], remat=True, use_flash=True, rotary_base=1e7,
+        dsa_index_heads=z["j"], dsa_index_dim=z["e"], dsa_topk=z["topk"]))
+    tokens = jax.ShapeDtypeStruct((z["b"], z["s"]), jnp.int32,
+                                  sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.key(0), tokens))
+    loss = lambda p, t: model.apply(p, t).astype(jnp.float32).mean()
+    text = jax.jit(jax.grad(loss)).lower(params, tokens).compile().as_text()
+    assert plans and all(
+        shapes == [(1, z["h"], z["s"], z["d"]), (1, z["h_kv"], z["s"], z["d"])]
+        and z["s"] % plan.rows == 0 and plan.rows % plan.sub == 0
+        for plan, shapes in plans), plans
+    names = re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*?op_name="([^"]*)"', text)
+    turns = [n for n in names if "/hvt_rotary_" in n]
+    assert all("/dsa_proj/" in n for n in turns), turns
+    assert sum("/hvt_rotary_fwd/" in n for n in turns) == 2
+    assert sum("/hvt_rotary_bwd/" in n for n in turns) == 1
+    for kernel in ("hvt_flash_fwd", "hvt_flash_bwd", "hvt_dsa_index",
+                   "hvt_dsa_choice"):
+        assert any(f"/{kernel}/" in n for n in names), kernel
+

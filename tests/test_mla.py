@@ -17,6 +17,7 @@ import pytest
 from chipbench.reference import deepseek_v3 as reference
 from horovod_tpu.models import mla
 from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.ops.rotary import rotary
 
 _KANANA = {"rms_norm_eps": 1e-6, "rope_theta": 1e6, "kv_lora_rank": 16,
            "qk_nope_head_dim": 8, "qk_rope_head_dim": 4}
@@ -168,7 +169,7 @@ def test_rotary_of_halves_is_the_published_pairs_under_the_permutation():
                                   np.asarray(x[..., 4::2]))
     np.testing.assert_array_equal(np.asarray(halves[..., 8:]),
                                   np.asarray(x[..., 5::2]))
-    rotate = jax.jit(lambda x: mla.rotate_halves(x, positions, 1e6))
+    rotate = jax.jit(lambda x: rotary(x, positions, 1e6))
     got = rotate(halves[..., 4:])
     want = jax.jit(jax.vmap(lambda one: reference.rotary_pairs(one, 1e6)))(
         x[..., 4:])

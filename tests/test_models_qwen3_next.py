@@ -91,16 +91,16 @@ def test_gated_attention_matches_the_formula():
                                rtol=2e-4, atol=2e-5)
     # positions past the rotated quarter carry no position: with q's and
     # k's first four channels zeroed the rotary changes nothing
-    from horovod_tpu.models.transformer import _rotary
+    from horovod_tpu.ops.rotary import rotary
 
     t = jax.random.normal(jax.random.key(6), (2, 24, 4, 16))
-    turned = _rotary(t, positions, 1e7, 4)
+    turned = rotary(t, positions, 1e7, 4)
     np.testing.assert_array_equal(np.asarray(turned[..., 4:]),
                                   np.asarray(t[..., 4:]))
     assert float(jnp.max(jnp.abs(turned[:, 1:, :, :4] - t[:, 1:, :, :4]))) > .1
     np.testing.assert_allclose(
-        np.asarray(_rotary(t, positions, 10000.0, None)),
-        np.asarray(_rotary(t, positions)), rtol=0, atol=0)
+        np.asarray(rotary(t, positions, 10000.0, None)),
+        np.asarray(rotary(t, positions, 10000.0)), rtol=0, atol=0)
 
 
 def test_unit_offset_norm_is_one_plus_its_weight():
