@@ -156,7 +156,7 @@ def main(argv=None):
                                    axis=-1)[:, None], n)[:, 0])
 
         def where(n):
-            return token, moe.token_sum.plan(token, n), n
+            return token, moe.token_sum.plan(token, n, n_tokens), n
 
         def scatter_pieces(rows, n):
             def add(at, live, total):
@@ -180,7 +180,8 @@ def main(argv=None):
             "segment_sum": lambda rows, n: jax.ops.segment_sum(
                 masked(rows, n).astype(jnp.float32), token,
                 num_segments=n_tokens).astype(rows.dtype),
-            "kernel": lambda rows, n: moe._sum_by_token(rows, where(n)),
+            "kernel": lambda rows, n: moe._sum_by_token(rows, where(n),
+                                                        n_tokens),
             "pieces": scatter_pieces,
             "segment_sum_sorted": sorted_sum,
             "doubling": doubling,

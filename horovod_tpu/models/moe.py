@@ -50,14 +50,19 @@ every chip computes alike: the latent projections and the shared expert).
 Nothing is dropped: a token has one slot a held expert, assigned or not by
 ``[T, E]`` comparisons with the token's ``k``-th score (``_chosen``), and
 the slots are sorted by expert with the unassigned last. The assigned
-slots are worked through in **rounds of ``T`` rows, one row a token**
-(``held_rows``, ``_held_experts``): round ``r`` takes the sorted slots
-``[r T, (r + 1) T)``, gathers their tokens' rows, runs the same grouped
-products on a ``[T, width]`` operand with the part of each group that
+slots are worked through in **rounds of ``R`` rows, a static whole
+multiple of ``T`` from what the share expects** (``held_rows``: ``T``, one
+row a token, for a share that expects under 0.8 of a row a token, which is
+every held cell of the benchmark until PR 61; ``3 T`` for 16 of 64 held
+with 8 a token, whose two expected rows a token ran two rounds of ``T`` or
+three by the seed; ``_held_experts``): round ``r`` takes the sorted slots
+``[r R, (r + 1) R)``, gathers their tokens' rows, runs the same grouped
+products on a ``[R, width]`` operand with the part of each group that
 falls into the round, and adds the weighted rows to their tokens. How many
-rounds run is the router's to say, ``ceil(sum(group_sizes) / T)``
-(``min(k, count)`` at most, one at the loads a share sees as a rule): a
-loop whose trips the data decide, with a backward pass written to match
+rounds run is the router's to say, ``ceil(sum(group_sizes) / R)``
+(``ceil(min(k, count) T / R)`` at most, one at the loads a share sees as a
+rule): a loop whose trips the data decide, with a backward pass written to
+match
 (``_held_experts_bwd``: the same loop, each round's forward made again for
 its pullback). So a step pays for the rows assigned, a program holds the
 round once (``_held_round`` is a ``jax.jit`` that every layer of one shape
@@ -127,15 +132,17 @@ HELD_CHOICE = "moe_held_choice"
 
 
 def _count_trace(n_experts, top_k, held, n_tokens):
-    """One count a traced layer."""
+    """One count a traced layer; ``round_rows`` is what a round of a share
+    holds (``held_rows``)."""
+    rows = held_rows(n_tokens, top_k, held, n_experts)[1] if held else None
     _pallas.count_trace(
         "hvt_moe_layers_traced_total",
         "mixture-of-experts layers traced into compiled programs "
         "(counted per trace, not per execution)",
         experts=n_experts, top_k=top_k, product=PRODUCT,
         held=held[1] if held else n_experts,
-        round_rows=n_tokens if held else "all",
-        move_rows=move_rows(n_tokens) if held else "all")
+        round_rows=rows if held else "all",
+        move_rows=move_rows(rows) if held else "all")
 
 
 def _rows(x, index):
@@ -298,17 +305,38 @@ def moe_route(h, router, k, *, score="softmax", bias=None, scale=1.0,
     return experts, weights, order, inverse, group_sizes, aux, probs
 
 
-def held_rows(n_tokens, k, held):
-    """``(rounds, rows a round)`` of a share ``held = (first, count)``:
-    its sorted slots are worked through ``n_tokens`` rows at a time (one
-    row a token), and the most that can be assigned, a token choosing
-    ``k`` experts and each at most once, is ``min(k, count)`` such rounds.
-    That is the static worst case, which nothing in the program is sized
-    by: the rounds that run are the router's to say (``ceil(sum(
-    group_sizes) / n_tokens)``, ``_rounds``), so the layer is dropless
-    without a bound. None for a layer that holds every expert: one pass
-    over its ``n_tokens x k`` rows, every one of them real."""
-    return None if held is None else (min(k, held[1]), n_tokens)
+# A round's rows over what a share expects (``held_rows``).
+_ROUND_ROOM = 1.25
+
+
+def held_rows(n_tokens, k, held, n_experts):
+    """``(rounds at most, rows a round)`` of a share ``held = (first,
+    count)`` of ``n_experts``: its sorted slots are worked through a
+    round's rows at a time. **A round's
+    rows are a static whole multiple of ``n_tokens``, from what the share
+    expects**: a uniform router sends it ``k x count / n_experts`` rows a
+    token, and a round holds ``ceil(1.25 x`` that ``)`` times ``n_tokens``
+    (a quarter of room: the loads a fresh router gives run a third over
+    and under their expectation), ``min(k, count)`` times at most. So a
+    share that expects under 0.8 of a row a token works in rounds of
+    ``n_tokens`` (one row a token: every held cell of the benchmark until
+    PR 61), and one that expects two rows a token (16 of 64 with 8 a
+    token) in rounds of three times that, where rounds of ``n_tokens``
+    would run two or three a layer by the seed, each for a few rows past
+    a whole multiple (5.1 ms a step a round that holds few rows; PERF.md
+    section 6, PR 61). The most that can be assigned, a token choosing
+    ``k`` experts and each at most once, is ``min(k, count) x n_tokens``
+    rows: that is the static worst case, which nothing in the program is
+    sized by: the rounds that run are the router's to say (``ceil(sum(
+    group_sizes) / rows)``, ``_rounds``), so the layer is dropless without
+    a bound. None for a layer that holds every expert: one pass over its
+    ``n_tokens x k`` rows, every one of them real."""
+    if held is None:
+        return None
+    most = min(k, held[1])
+    times = max(1, min(most, math.ceil(
+        _ROUND_ROOM * k * held[1] / n_experts)))
+    return -(-most // times), times * n_tokens
 
 
 def moe_dispatch(h, order, inverse, k):
@@ -416,31 +444,33 @@ def _add_to_tokens(total, rows, weight, where):
     chunks of sorted rows that hold its own, grid steps past the assigned
     rows skipped. What the grouped product left in ``rows`` past the
     assigned is not read."""
-    return token_sum.sum_by_token(rows, where[1], weight=weight, total=total)
+    return token_sum.sum_by_token(rows, where[1], total.shape[0],
+                                  weight=weight, total=total)
 
 
-def _sum_by_token(rows, where):
-    """``rows [T, d]`` -> ``[T, d]``: the sum of a round's assigned rows by
-    their token, in float32 (``_add_to_tokens`` onto zeros)."""
-    return token_sum.sum_by_token(rows, where[1])
+def _sum_by_token(rows, where, n_tokens):
+    """``rows [R, d]`` -> ``[T, d]``, ``T = n_tokens``: the sum of a
+    round's assigned rows by their token, in float32 (``_add_to_tokens``
+    onto zeros)."""
+    return token_sum.sum_by_token(rows, where[1], n_tokens)
 
 
-@jax.custom_vjp
-def _rows_of_tokens(x, where):
-    """``x [T, d]`` -> ``x[token] [T, d]``, the rows of a round (one a
-    token, so as many as there are tokens), in one pass: a gather runs at
-    the memory's rate, and the zeros that pieces would fill cost what it
-    does. Past the assigned they are the rows of tokens nobody assigned,
-    which the grouped product does not visit. Its transpose is the sum by
-    token of the assigned (``_sum_by_token``), written out because a loop
-    whose trips the data decide has no reverse mode, and so that it sums
-    in float32 whatever ``x`` is in."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_of_tokens(x, where, n_tokens):
+    """``x [T, d]`` -> ``x[token] [R, d]``, the rows of a round (``T =
+    n_tokens``), in one pass: a gather runs at the memory's rate, and the
+    zeros that pieces would fill cost what it does. Past the assigned they
+    are the rows of tokens nobody assigned, which the grouped product does
+    not visit. Its transpose is the sum by token of the assigned
+    (``_sum_by_token``), written out because a loop whose trips the data
+    decide has no reverse mode, and so that it sums in float32 whatever
+    ``x`` is in."""
     return _rows(x, where[0])
 
 
 _rows_of_tokens.defvjp(
-    lambda x, where: (_rows(x, where[0]), where),
-    lambda where, g: (_sum_by_token(g, where), None))
+    lambda x, where, n_tokens: (_rows(x, where[0]), where),
+    lambda n_tokens, where, g: (_sum_by_token(g, where, n_tokens), None))
 
 
 def _add_to_tokens_transposed(g, rows, weight, where):
@@ -462,15 +492,16 @@ def _add_to_tokens_transposed(g, rows, weight, where):
                    (jnp.zeros_like(rows), jnp.zeros_like(weight)))
 
 
-@jax.jit
-def _held_round(tokens, weights, stacks, route, r):
-    """Round ``r`` of a share: the sorted slots ``[r T, (r + 1) T)`` of
-    ``order``, their tokens' rows through the held experts (``stacks =
-    (gate, up, down)``): ``((rows [T, width], their weights [T] float32),
-    where)``, ``where = (their tokens [T], token_sum.plan of them, how
-    many of the rows are assigned)``. ``route = (order, group_sizes)`` as
+@functools.partial(jax.jit, static_argnames="rows")
+def _held_round(tokens, weights, stacks, route, r, *, rows):
+    """Round ``r`` of a share in rounds of ``R = rows`` rows
+    (``held_rows``): the sorted slots ``[r R, (r + 1) R)`` of ``order``,
+    their tokens' rows through the held experts (``stacks = (gate, up,
+    down)``): ``((rows [R, width], their weights [R] float32), where)``,
+    ``where = (their tokens [R], token_sum.plan of them, how many of the
+    rows are assigned)``. ``route = (order, group_sizes)`` as
     ``moe_route`` made them; the round's group sizes are the part of each
-    expert's group that falls into it. The grouped products take all ``T``
+    expert's group that falls into it. The grouped products take all ``R``
     rows in one call and visit the assigned; what they leave in the rows
     past them is whatever the memory held, which nothing reads: the sums
     on either side select the assigned rows by their count. A ``jax.jit``
@@ -478,14 +509,14 @@ def _held_round(tokens, weights, stacks, route, r):
     however many layers call it."""
     order, group_sizes = route
     n_tokens, count = weights.shape
-    ends = jnp.cumsum(group_sizes) - r * n_tokens
-    sizes = jnp.diff(jnp.clip(ends, 0, n_tokens), prepend=0)
+    ends = jnp.cumsum(group_sizes) - r * rows
+    sizes = jnp.diff(jnp.clip(ends, 0, rows), prepend=0)
     assigned = jnp.sum(sizes)
-    slots = jax.lax.dynamic_slice(order, (r * n_tokens,), (n_tokens,))
+    slots = jax.lax.dynamic_slice(order, (r * rows,), (rows,))
     token, expert = slots // count, slots % count
     with jax.named_scope("moe_dispatch"):
-        where = token, token_sum.plan(token, assigned), assigned
-        rows = _rows_of_tokens(tokens, where)
+        where = token, token_sum.plan(token, assigned, n_tokens), assigned
+        rows = _rows_of_tokens(tokens, where, n_tokens)
     with jax.named_scope("moe_experts"):
         rows = moe_experts(rows, *stacks, sizes)
     with jax.named_scope("moe_combine"):
@@ -495,51 +526,52 @@ def _held_round(tokens, weights, stacks, route, r):
     return (rows, weight), where
 
 
-def _rounds(route, n_tokens, body, carry):
-    """``carry`` after ``body(r, carry)`` for every round the router's
-    count asks for, ``ceil(assigned / n_tokens)`` of them (one, as a rule;
-    ``min(k, count)`` at most; none where no token chose a held expert): a
-    loop whose trips the data decide, so nothing stands in for a round
-    that does not run, no branch, no zeros, no copy of what is carried,
-    and a program holds one round however many it may run."""
+def _rounds(route, rows, body, carry):
+    """``carry`` after ``body(r, carry)`` for every round of ``rows`` rows
+    the router's count asks for, ``ceil(assigned / rows)`` of them (one,
+    as a rule; ``held_rows``' at most; none where no token chose a held
+    expert): a loop whose trips the data decide, so nothing stands in for
+    a round that does not run, no branch, no zeros, no copy of what is
+    carried, and a program holds one round however many it may run."""
     return jax.lax.fori_loop(
-        jnp.int32(0), -(-jnp.sum(route[1]) // n_tokens), body, carry)
+        jnp.int32(0), -(-jnp.sum(route[1]) // rows), body, carry)
 
 
-@jax.custom_vjp
-def _held_experts(tokens, weights, stacks, route):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _held_experts(tokens, weights, stacks, route, rows):
     """The held experts' part of the layer's sum, ``[T, width]`` float32,
-    in rounds of ``T`` rows (``held_rows``, ``_rounds``), each round's
+    in rounds of ``rows`` rows (``held_rows``, ``_rounds``), each round's
     weighted rows added to their tokens (``_add_to_tokens``, in place). A
     loop whose trips the data decide has no reverse mode of its own, so
     the backward pass is written here: the same loop, each round's forward
     made again for its pullback (a round's residuals then live for one
     trip, where one pass kept those of all ``T x min(k, count)`` rows)."""
     def one(r, total):
-        (rows, weight), where = _held_round(tokens, weights, stacks, route, r)
+        (mine, weight), where = _held_round(tokens, weights, stacks, route,
+                                            r, rows=rows)
         with jax.named_scope("moe_combine"):
-            return _add_to_tokens(total, rows, weight, where)
+            return _add_to_tokens(total, mine, weight, where)
 
-    return _rounds(route, tokens.shape[0], one,
-                   jnp.zeros(tokens.shape, jnp.float32))
+    return _rounds(route, rows, one, jnp.zeros(tokens.shape, jnp.float32))
 
 
-def _held_experts_bwd(res, g):
+def _held_experts_bwd(rows, res, g):
     *of, route = res
 
     def one(r, grads):
         out, pull, where = jax.vjp(
-            lambda *of: _held_round(*of, route, r), *of, has_aux=True)
+            lambda *of: _held_round(*of, route, r, rows=rows), *of,
+            has_aux=True)
         with jax.named_scope("moe_combine"):
             back = _add_to_tokens_transposed(g, *out, where)
         return jax.tree.map(jnp.add, grads, pull(back))
 
-    return *_rounds(route, g.shape[0], one,
+    return *_rounds(route, rows, one,
                     jax.tree.map(jnp.zeros_like, tuple(of))), None
 
 
 _held_experts.defvjp(
-    lambda *inputs: (_held_experts(*inputs), inputs), _held_experts_bwd)
+    lambda *inputs: (_held_experts(*inputs), inputs[:-1]), _held_experts_bwd)
 
 
 class MoEMlp(nn.Module):
@@ -623,9 +655,16 @@ class MoEMlp(nn.Module):
             with jax.named_scope("moe_experts"):   # cast once, not a round
                 stacks = tuple(None if w is None else w.astype(self.dtype)
                                for w in (gate, up, down))
+            rounds, rows = held_rows(h.shape[0], self.experts_per_token,
+                                     self.held, self.n_experts)
+            # (no round reaches past the slots: the last of a share whose
+            # rounds do not divide them reads slots nobody chose)
+            beyond = rounds * rows - order.shape[0]
+            if beyond > 0:
+                order = jnp.pad(order, (0, beyond))
             out = checkpoint_name(_held_experts(
                 tokens if self.latent else low(), weights, stacks,
-                (order, group_sizes)), HELD_SUM)
+                (order, group_sizes), rows), HELD_SUM)
         else:
             with jax.named_scope("moe_dispatch"):
                 rows = moe_dispatch(tokens if self.latent else low(), order,

@@ -213,12 +213,22 @@ class GPTConfig:
     # Both kinds read the sizes and pieces above (n_heads, n_kv_heads,
     # head_dim, heads_held, qk_norm, head_norm, attn_gate, rotary_base,
     # rotary_fraction, use_flash, ring_mesh). "*" sees every causal key and
-    # turns q and k as ``rotary`` says; "W" sees its window, always turns
-    # them, and reads neither ``rotary`` nor anything else of its own. A
+    # turns q and k as ``rotary`` and ``rotary_scaling`` say; "W" sees its
+    # window, always turns them plainly, and reads neither of the two nor
+    # anything else of its own. A
     # model of local layers with positions and global ones without is
     # ``rotary=False`` beside a window here. A window on the ring path is
     # refused by name.
     attn_window: int = 0
+    # The law the "*" layers turn q and k by where it is not the plain one
+    # at ``rotary_base``: an ``ops.rotary.Yarn`` (its own base in it), or
+    # what ``ops.rotary.law`` makes of a published ``rope_parameters``
+    # entry; None (default): the plain law. Read by "*" alone and only
+    # under ``rotary``: a model whose windowed layers turn plainly and
+    # whose full layers turn by a scaled law (one ``rope_parameters`` entry
+    # a kind of layer) is this beside ``attn_window``; "W" keeps turning
+    # plainly at ``rotary_base`` whatever this says.
+    rotary_scaling: Optional[tuple] = None
     # A norm after the mixer as well, in a patterned model: a layer is ``x
     # + post_norm(mixer(norm(x)))``, the second RMSNorm with a weight of
     # its own on the mixer's output before the residual sum, for every
@@ -280,16 +290,17 @@ def held_heads(n_heads, n_kv, held):
     return count, max(1, count // group)
 
 
-def _count_trace(heads, kv_heads, head_dim, core, window):
+def _count_trace(heads, kv_heads, head_dim, core, window, rotary):
     """One count a traced layer; ``window`` 0 in a layer that sees every
-    causal key."""
+    causal key; ``rotary`` the law its q and k turn by (``none``,
+    ``plain``, ``yarn``)."""
     _pallas.count_trace(
         "hvt_attn_layers_traced_total",
         "attention layers traced into compiled programs, by the path "
         "their products over positions take: ring, flash or einsum "
         "(counted per trace, not per execution)",
         heads=heads, kv_heads=kv_heads, head_dim=head_dim, core=core,
-        window=window)
+        window=window, rotary=rotary)
 
 
 def _attend(cfg, q, k, v, positions, core, window=0):
@@ -353,12 +364,16 @@ def _attend(cfg, q, k, v, positions, core, window=0):
 
 class Attention(nn.Module):
     """The attention both of its kinds build (``KINDS``): over every
-    causal key and turned as ``cfg.rotary`` says, or turned and inside the
-    configuration's window."""
+    causal key and turned as ``cfg.rotary`` says, by the law
+    ``cfg.rotary_scaling`` names where it names one, or turned plainly and
+    inside the configuration's window."""
 
     cfg: GPTConfig
     rotary: bool        # whether q and k are turned
     window: int = 0     # keys a query sees (0: every causal key)
+    # the law they turn by where it is not the plain one at
+    # ``cfg.rotary_base`` (an ``ops.rotary.Yarn``)
+    scaling: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, x, positions):
@@ -384,7 +399,12 @@ class Attention(nn.Module):
 
             core = ("flash" if resolve_flash(cfg.use_flash, x.shape[-2])
                     else "einsum")
-        _count_trace(n_heads, n_kv, head_dim, core, self.window)
+        # (loaded here, as the flash kernels are)
+        from horovod_tpu.ops.rotary import law_name, rotary
+
+        law = self.scaling or cfg.rotary_base
+        _count_trace(n_heads, n_kv, head_dim, core, self.window,
+                     law_name(law) if self.rotary else "none")
         # a model with both kinds of layer sows a layer's own input and
         # output where its caller collects ``intermediates``, as the other
         # mixers do: a check of one layer against a reference
@@ -409,12 +429,9 @@ class Attention(nn.Module):
         if self.rotary:
             turned = (None if cfg.rotary_fraction == 1.0
                       else int(cfg.rotary_fraction * head_dim))
-            # (loaded here, as the flash kernels are)
-            from horovod_tpu.ops.rotary import rotary
-
             with jax.named_scope("attn_rope"):
                 # a norm over the whole width leaves q and k [b, s, h d]
-                q, k = rotary((q, k), positions, cfg.rotary_base, turned,
+                q, k = rotary((q, k), positions, law, turned,
                               flat=cfg.qk_norm)
         with jax.named_scope("attn_core"):
             if self.window:
@@ -545,8 +562,9 @@ class Kind:
 
 KINDS = {kind.letter: kind for kind in (
     Kind("*", "attn", "attention",
-         lambda cfg: _layer(Attention(cfg, rotary=cfg.rotary, name="attn"),
-                            positional=True),
+         lambda cfg: _layer(Attention(cfg, rotary=cfg.rotary,
+                                      scaling=cfg.rotary_scaling,
+                                      name="attn"), positional=True),
          _attention_leaf_spec),
     Kind("W", "attn", "attention inside a window", _windowed_attention,
          _attention_leaf_spec),

@@ -9,8 +9,16 @@ rule that chooses between them.
 (``rotary_kernels``) or to ``rotary_plain``, the kernel's reference.
 
 The first ``width`` channels of a head turn as two halves: channel ``j``
-of the first half and of the second by ``positions x base^(-2j / width)``;
-the rest pass as they are. The flash kernels read ``[b, h, s, d]``, so on
+of the first half and of the second by ``positions x theta_j``; the rest
+pass as they are. **The law** of the ``theta_j`` is the caller's
+description, one argument (``base``) of every entry: a number is the plain
+law ``base^(-2j / width)``; a ``Yarn`` is that law scaled channel by
+channel (the fast channels as they are, the slow ones divided by a factor,
+a ramp between) with ``cos`` and ``sin`` times an attention factor;
+``law`` makes either from a ``rope_parameters`` entry of a published
+configuration and refuses by name what is not built. ``_angles`` is the
+one place a frequency is made: the kernel takes its tables from XLA and
+knows nothing of the law. The flash kernels read ``[b, h, s, d]``, so on
 a TPU XLA has the projection's product write its result heads major (the
 transposition is the product's output layout and costs no pass), and the
 plain body's slices of halves are computed there: at heads of 64 a half
@@ -33,9 +41,10 @@ way out. The tables ``[b, s, max(d, 128)]`` are made by XLA in ``x.dtype``
 with ``cos`` 1 and ``sin`` 0 past ``width``. All the arrays of a call (a
 layer's ``q`` and ``k``) go through one kernel call.
 
-The rotary is linear and orthogonal: the backward pass is the same kernel
-on the gradient with ``sin`` negated, and the residuals are the tables
-alone.
+The rotary is linear and orthogonal, times the law's factor: the backward
+pass is the same kernel on the gradient with ``sin`` negated (a scaled
+rotation's transpose is the scaled rotation by the negated angle), and the
+residuals are the tables alone.
 
 On the CPU the same kernel code runs through the Pallas interpreter;
 compiled, Mosaic wants whole 128-lane tiles and rows in multiples of the
@@ -51,7 +60,8 @@ them by 80, and the step never came back; blocks of 128 run).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+import math
+from typing import Mapping, NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +88,103 @@ _TILE = 128     # a register's lanes
 # positions a block at each of the benchmark's five shapes (36 to 40 heads
 # as VMEM pads them), 4.7 MiB of the kernel's 16.
 ELEMENTS, SUB = 640 * 1024, 16
+
+
+class Yarn(NamedTuple):
+    """The YaRN law (arXiv:2309.00071, as ``transformers``'
+    ``_compute_yarn_parameters`` computes it): with ``c(n) = width ln(
+    original_positions / (2 pi n)) / (2 ln base)``, ``low = c(beta_fast)``
+    and ``high = c(beta_slow)`` (floored and ceiled under ``truncate``,
+    clipped to ``[0, width - 1]``) and ``ramp_j = clip((j - low) / (high -
+    low), 0, 1)``,
+
+        theta_j = base^(-2j / width) x ((1 - ramp_j) + ramp_j / factor)
+
+    so the channels under ``low`` turn as the plain law's, those from
+    ``high`` up ``factor`` times slower, and ``cos`` and ``sin`` are both
+    multiplied by ``attention_factor`` (None: ``0.1 ln(factor) + 1``, 1
+    for a factor up to 1): a score of the turned q and k is its square
+    times the plain one."""
+    base: float
+    factor: float
+    original_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+    truncate: bool = True
+
+
+# A caller's description of the law: a base alone is the plain one.
+Law = Union[float, Yarn]
+
+
+def law(rope_parameters: Mapping) -> Law:
+    """The law a published configuration's ``rope_parameters`` entry (or
+    its ``rope_scaling`` with the ``rope_theta`` beside it) names, under
+    the source's keys: ``rope_type`` (or ``type``) ``"default"`` is the
+    base ``rope_theta``, ``"yarn"`` a ``Yarn`` of ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor`` and ``truncate``. What is not built is refused by
+    name: every other ``rope_type``, and ``mscale`` / ``mscale_all_dim``
+    (an attention factor from two further numbers)."""
+    kind = rope_parameters.get("rope_type", rope_parameters.get(
+        "type", "default"))
+    base = float(rope_parameters["rope_theta"])
+    if kind == "default":
+        return base
+    if kind != "yarn":
+        raise ValueError(
+            f"rope_type {kind!r} is not built: the rotary's law is "
+            f"'default' (base^(-2j / width)) or 'yarn'")
+    for key in ("mscale", "mscale_all_dim"):
+        if rope_parameters.get(key):
+            raise ValueError(
+                f"{key} ({rope_parameters[key]!r}) is not built: a yarn "
+                f"law's attention factor is given, or 0.1 ln(factor) + 1")
+    return Yarn(
+        base, float(rope_parameters["factor"]),
+        int(rope_parameters["original_max_position_embeddings"]),
+        float(rope_parameters.get("beta_fast") or 32.0),
+        float(rope_parameters.get("beta_slow") or 1.0),
+        rope_parameters.get("attention_factor"),
+        bool(rope_parameters.get("truncate", True)))
+
+
+def law_name(described: Law) -> str:
+    """``"yarn"`` or ``"plain"``: what an engagement counter calls it."""
+    return "yarn" if isinstance(described, Yarn) else "plain"
+
+
+def yarn_range(described: Yarn, width: int):
+    """``(low, high)``: the channels between which a ``Yarn`` law over
+    ``width`` channels goes from the plain frequencies to the divided
+    ones."""
+    at = lambda turns: (width * math.log(
+        described.original_positions / (turns * 2 * math.pi))
+        / (2 * math.log(described.base)))
+    low, high = at(described.beta_fast), at(described.beta_slow)
+    if described.truncate:
+        low, high = math.floor(low), math.ceil(high)
+    return max(low, 0), min(high, width - 1)
+
+
+def frequencies(described: Law, half: int):
+    """``(theta_j`` for the ``half`` channels ``j`` of a half, float64
+    ``[half]``, the factor on ``cos`` and ``sin``)`` of the law."""
+    scaled = isinstance(described, Yarn)
+    plain = 1.0 / ((described.base if scaled else described)
+                   ** (np.arange(0, half) / half))
+    if not scaled:
+        return plain, 1.0
+    low, high = yarn_range(described, 2 * half)
+    if low == high:
+        high += 0.001       # no singularity (the source's guard)
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    on_tables = described.attention_factor
+    if on_tables is None:
+        on_tables = (1.0 if described.factor <= 1
+                     else 0.1 * math.log(described.factor) + 1.0)
+    return plain * ((1.0 - ramp) + ramp / described.factor), float(on_tables)
 
 
 class _Plan(NamedTuple):
@@ -135,9 +242,10 @@ def serves(shape, dtype, width: Optional[int] = None,
             and (width <= _TILE or width % (2 * _TILE) == 0))
 
 
-def rotary(x, positions, base: float, width: Optional[int] = None,
+def rotary(x, positions, base: Law, width: Optional[int] = None,
            flat: bool = False):
-    """Rotary position embeddings at ``base`` over the first ``width``
+    """Rotary position embeddings by the law ``base`` describes (a number:
+    the plain law at that base; a ``Yarn``) over the first ``width``
     channels of a head (None: all of them) of ``x [.., seq, heads, d]`` or
     ``[.., seq, d]``, or of each array of a tuple (a layer's ``q`` and
     ``k``: one pass for both); ``positions [.., seq]``. Float32 phases,
@@ -157,13 +265,22 @@ def rotary(x, positions, base: float, width: Optional[int] = None,
 
 
 def _angles(positions, base, half):
-    """``positions x base^(-j / half)`` for the ``half`` channels ``j`` of
-    a half, float32 ``[.., seq, half]``."""
-    freqs = 1.0 / (base ** (np.arange(0, half) / half))
-    return positions[..., None].astype(_F32) * freqs
+    """``(positions x theta_j`` for the ``half`` channels ``j`` of a half,
+    float32 ``[.., seq, half]``, the law's factor on ``cos`` and
+    ``sin``)``: the one place a frequency is made (``frequencies``)."""
+    freqs, factor = frequencies(base, half)
+    return positions[..., None].astype(_F32) * freqs.astype(np.float32), factor
 
 
-def rotary_plain(x, positions, base: float, width: Optional[int] = None):
+def _cos_sin(angles, factor, dtype):
+    """``cos`` and ``sin`` of float32 ``angles`` times the law's factor,
+    rounded to ``dtype`` once: what both bodies turn by."""
+    # (under the plain law, factor 1, the program is what it was)
+    scaled = lambda t: (t if factor == 1.0 else t * factor).astype(dtype)
+    return scaled(jnp.cos(angles)), scaled(jnp.sin(angles))
+
+
+def rotary_plain(x, positions, base: Law, width: Optional[int] = None):
     """``rotary`` in plain ``jax.numpy``: the path of every backend and
     shape the kernel does not serve, and its reference. ``x [.., seq,
     heads, d]`` (any number of axes between the positions' and the
@@ -171,11 +288,10 @@ def rotary_plain(x, positions, base: float, width: Optional[int] = None):
     head_dim = x.shape[-1]
     width = head_dim if width is None else width
     half = width // 2
-    angles = _angles(positions, base, half)
+    angles, factor = _angles(positions, base, half)
     angles = angles.reshape(angles.shape[:-1]
                             + (1,) * (x.ndim - angles.ndim) + (half,))
-    cos = jnp.cos(angles).astype(x.dtype)
-    sin = jnp.sin(angles).astype(x.dtype)
+    cos, sin = _cos_sin(angles, factor, x.dtype)
     x1, x2 = x[..., :half], x[..., half:width]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos]
                            + ([x[..., width:]] if width < head_dim else []),
@@ -187,9 +303,9 @@ def _tables(positions, base, dim, width, dtype):
     ``dtype`` as the plain body rounds them, ``[b, seq, max(dim, 128)]``:
     a head's, side by side as often as a tile holds heads; ``cos`` 1 and
     ``sin`` 0 past ``width``."""
-    angles = _angles(positions, base, width // 2)
+    angles, factor = _angles(positions, base, width // 2)
     rest = angles.shape[:-1] + (dim - width,)
-    cos, sin = jnp.cos(angles).astype(dtype), jnp.sin(angles).astype(dtype)
+    cos, sin = _cos_sin(angles, factor, dtype)
     cos = jnp.concatenate([cos, cos, jnp.ones(rest, dtype)], axis=-1)
     sin = jnp.concatenate([-sin, sin, jnp.zeros(rest, dtype)], axis=-1)
     times = max(_TILE // dim, 1)
@@ -309,7 +425,7 @@ def _turn_bwd(plan, tables, gs):
 _turn.defvjp(_turn_fwd, _turn_bwd)
 
 
-def rotary_kernels(xs, positions, base: float, width: Optional[int] = None,
+def rotary_kernels(xs, positions, base: Law, width: Optional[int] = None,
                    *, rows: Optional[int] = None, sub: Optional[int] = None):
     """``rotary_plain`` through the kernel for every array of the tuple
     ``xs``, ``[b, s, h, d]`` each with the same ``b``, ``s`` and ``d``:

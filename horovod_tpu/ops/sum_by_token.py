@@ -44,40 +44,43 @@ from horovod_tpu.ops import _pallas
 TILE, CHUNK = 256, 128
 
 
-def tile_rows(n_rows: int):
-    """``(tile, chunk)`` of a round of ``n_rows`` rows, one a token."""
-    return math.gcd(n_rows, TILE), math.gcd(n_rows, CHUNK)
+def tile_rows(n_rows: int, n_tokens: int):
+    """``(tile, chunk)`` of a round of ``n_rows`` rows over ``n_tokens``
+    tokens: tiles of tokens, chunks of rows."""
+    return math.gcd(n_tokens, TILE), math.gcd(n_rows, CHUNK)
 
 
 class Plan(NamedTuple):
-    """What the sums of one round share (``plan``)."""
-    order: jax.Array        # [T] the rows by their token, the assigned first
-    token: jax.Array        # [T / chunk, 1, chunk] their tokens, T past them
+    """What the sums of one round share (``plan``); ``R`` rows a round
+    over ``T`` tokens."""
+    order: jax.Array        # [R] the rows by their token, the assigned first
+    token: jax.Array        # [R / chunk, 1, chunk] their tokens, R past them
     starts: jax.Array       # [T / tile + 1] the first sorted row of a tile
     tile_of: jax.Array      # [steps] the tile of a grid step
     chunk_of: jax.Array     # [steps] and its chunk of the sorted rows
     steps: jax.Array        # [1] how many of them there are
 
 
-def plan(token, assigned) -> Plan:
+def plan(token, assigned, n_tokens: int) -> Plan:
     """The order and the grid of the sums by token of a round whose row
-    ``i < assigned`` belongs to ``token[i]`` (one row a token at most a
-    round, so ``T`` rows and ``T`` tokens): a sort of ``T`` keys, and
-    integer work on a number a tile and a grid step."""
+    ``i < assigned`` belongs to ``token[i]``, a token of ``n_tokens`` (a
+    round holds a whole multiple of ``n_tokens`` rows, ``models/moe.py``'s
+    ``held_rows``): a sort of the round's keys, and integer work on a
+    number a tile and a grid step."""
     n = token.shape[0]
-    tile, chunk = tile_rows(n)
+    tile, chunk = tile_rows(n, n_tokens)
     key = jnp.where(jnp.arange(n) < assigned, token, n).astype(jnp.int32)
     key, order = jax.lax.sort_key_val(key, jnp.arange(n, dtype=jnp.int32))
     # comparisons with every boundary: a search would be a loop
-    starts = jnp.sum(key < jnp.arange(0, n + 1, tile)[:, None], axis=1,
-                     dtype=jnp.int32)
+    starts = jnp.sum(key < jnp.arange(0, n_tokens + 1, tile)[:, None],
+                     axis=1, dtype=jnp.int32)
     first = jnp.minimum(starts[:-1] // chunk, n // chunk - 1)
     last = jnp.maximum((starts[1:] - 1) // chunk, first)
     ends = jnp.cumsum(last - first + 1)                 # of steps, a tile
-    step = jnp.arange(n // chunk + n // tile)
+    step = jnp.arange(n // chunk + n_tokens // tile)
     tile_of = jnp.minimum(
         jnp.sum(ends <= step[:, None], axis=1, dtype=jnp.int32),
-        n // tile - 1)
+        n_tokens // tile - 1)
     chunk_of = jnp.minimum(
         first[tile_of] + step - (ends - (last - first + 1))[tile_of],
         last[tile_of])
@@ -133,12 +136,13 @@ def _kernel(tile_of, chunk_of, starts, steps, token_ref, *refs, weighted,
         out_ref[...] = sum_ref[...].astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
-def _call(rows, weight, total, plan, *, dtype, interpret):
+@functools.partial(jax.jit,
+                   static_argnames=("dtype", "interpret", "n_tokens"))
+def _call(rows, weight, total, plan, *, dtype, interpret, n_tokens):
     """A ``jax.jit`` of its own, as the other kernels' calls are: the
     layers of a model share one trace and one lowered function."""
     n, width = rows.shape
-    tile, chunk = tile_rows(n)
+    tile, chunk = tile_rows(n, n_tokens)
     a_chunk = pl.BlockSpec((1, 1, chunk),
                            lambda i, tile_of, chunk_of, *_: (chunk_of[i], 0, 0))
     a_tile = pl.BlockSpec((tile, width),
@@ -162,7 +166,7 @@ def _call(rows, weight, total, plan, *, dtype, interpret):
             num_scalar_prefetch=4, grid=(plan.tile_of.shape[0],),
             in_specs=specs, out_specs=a_tile,
             scratch_shapes=[pltpu.VMEM((tile, width), jnp.float32)]),
-        out_shape=_pallas.out((n, width), dtype, rows),
+        out_shape=_pallas.out((n_tokens, width), dtype, rows),
         input_output_aliases=({4 + len(operands) - 1: 0}
                               if total is not None and total.dtype == dtype
                               else {}),
@@ -172,14 +176,16 @@ def _call(rows, weight, total, plan, *, dtype, interpret):
             plan.tile_of, plan.chunk_of, plan.starts, plan.steps, *operands)
 
 
-def sum_by_token(rows, plan: Plan, *, weight=None, total=None, dtype=None):
-    """``rows [T, d]`` -> ``[T, d]``: the sum, in float32, of the rows
-    that ``plan`` calls assigned, each times its ``weight [T]`` (None: as
-    it is), by their token, onto ``total [T, d]`` (None: zeros), in
-    ``dtype`` (None: ``total``'s, else the rows')."""
+def sum_by_token(rows, plan: Plan, n_tokens: int, *, weight=None,
+                 total=None, dtype=None):
+    """``rows [R, d]`` -> ``[T, d]``, ``T = n_tokens`` as ``plan`` was made
+    for: the sum, in float32, of the rows that ``plan`` calls assigned,
+    each times its ``weight [R]`` (None: as it is), by their token, onto
+    ``total [T, d]`` (None: zeros), in ``dtype`` (None: ``total``'s, else
+    the rows')."""
     dtype = dtype or (rows.dtype if total is None else total.dtype)
     return _call(rows, weight, total, plan, dtype=jnp.dtype(dtype),
-                 interpret=_pallas.interpret())
+                 interpret=_pallas.interpret(), n_tokens=n_tokens)
 
 
 def sum_by_token_plain(rows, token, assigned, *, weight=None, total=None):

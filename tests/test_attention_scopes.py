@@ -108,11 +108,11 @@ def test_the_scopes_change_neither_the_program_nor_the_tree(case,
     assert jax.tree.leaves(bare_params) == jax.tree.leaves(params)
 
 
-def _counted(heads, kv_heads, head_dim, core, window=0):
+def _counted(heads, kv_heads, head_dim, core, window=0, rotary="plain"):
     m = metrics.registry().get("hvt_attn_layers_traced_total")
     return m.labels(heads=str(heads), kv_heads=str(kv_heads),
-                    head_dim=str(head_dim), core=core,
-                    window=str(window)).value if m else 0.0
+                    head_dim=str(head_dim), core=core, window=str(window),
+                    rotary=rotary).value if m else 0.0
 
 
 @pytest.mark.parametrize("use_flash, seq, on_tpu, core", [

@@ -378,7 +378,7 @@ def test_held_expert_layer_compiles_for_v5e(compiled_kernel, v5e_devices,
     sorts = re.findall(r"= \(?\w+\[([\d,]*)\][^=]* sort\(", text)
     # (the others are over a round's T rows: the sums by token)
     assert [n for n in sorts if n != str(tokens)] == [str(tokens * 8)], sorts
-    assert moe.held_rows(tokens, k, held) == (8, tokens)
+    assert moe.held_rows(tokens, k, held, 512) == (8, tokens)
     assert f"bf16[{tokens},1024]" in text
     assert f"[{tokens * 8},1024]" not in text
     tiles = tokens // 512
@@ -827,13 +827,17 @@ def test_flash_kernels_with_a_choice_compile_for_v5e(compiled_kernel,
     assert "hvt_flash_fwd" in text and "hvt_flash_bwd" in text
 
 
-def test_flash_kernels_with_a_window_compile_for_v5e(compiled_kernel,
+@pytest.mark.parametrize("window, tiles", [(2048, 3), (1024, 2)])
+def test_flash_kernels_with_a_window_compile_for_v5e(window, tiles,
+                                                     compiled_kernel,
                                                      v5e_devices):
-    """``trinitymini-s16384``'s windowed calls: 16,384 positions, 32 query
-    heads on 4 key-value heads of 128, a window of 2,048: forward and
-    backward, one Pallas call each, and the schedule's second bound: both
-    grids' last axis is three tiles of 1,024 keys (rows), where a full
-    walk's is four of 4,096."""
+    """``trinitymini-s16384``'s windowed calls and ``mellum2-s16384``'s:
+    16,384 positions, 32 query heads on 4 key-value heads of 128, a window
+    of 2,048 and of 1,024: forward and backward, one Pallas call each, and
+    the schedule's second bound: both grids' last axis is three tiles of
+    1,024 keys (rows), two at a window as wide as one tile (a query block
+    of 512 sees 1,535 keys, which two tiles hold), where a full walk's is
+    four of 4,096."""
     like, z = _dsa_like(v5e_devices[0]), DSA_SHAPE
     q = like(jnp.bfloat16, z["b"], z["s"], z["h"], z["d"])
     kv = like(jnp.bfloat16, z["b"], z["s"], z["h_kv"], z["d"])
@@ -847,8 +851,8 @@ def test_flash_kernels_with_a_window_compile_for_v5e(compiled_kernel,
 
     walk = lambda window: _grids(step(window), q, kv, kv)
     assert walk({}) == [(1, 32, 32, 4), (1, 32, 32, 4)]
-    assert walk({"window": 2048}) == [(1, 32, 32, 3), (1, 32, 32, 3)]
-    text = step({"window": 2048}).lower(q, kv, kv).compile().as_text()
+    assert walk({"window": window}) == [(1, 32, 32, tiles)] * 2
+    text = step({"window": window}).lower(q, kv, kv).compile().as_text()
     calls = re.findall(r" custom-call\((.*?)\), custom_call_target="
                        r"\"tpu_custom_call\"", text)
     assert len(calls) == 2

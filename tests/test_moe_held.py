@@ -160,9 +160,12 @@ def test_held_route_gives_a_slot_a_held_expert():
     key = np.where(held, np.arange(count), count).reshape(-1)
     np.testing.assert_array_equal(
         order[:assigned], np.argsort(key, kind="stable")[:assigned])
-    assert moe.held_rows(h.shape[0], k, (first, count)) == (3, h.shape[0])
-    assert moe.held_rows(h.shape[0], k, (0, 2)) == (2, h.shape[0])
-    assert moe.held_rows(h.shape[0], k, None) is None
+    # 4 of 8 with 3 a token expect 1.5 rows a token: rounds of two, two at
+    # most; 2 of 8 expect 0.75: rounds of one, two at most
+    assert moe.held_rows(h.shape[0], k, (first, count), 8) == (
+        2, 2 * h.shape[0])
+    assert moe.held_rows(h.shape[0], k, (0, 2), 8) == (2, h.shape[0])
+    assert moe.held_rows(h.shape[0], k, None, 8) is None
 
 
 def _held_route_as_it_was(h, router, k, *, score, bias, scale, held,
@@ -289,8 +292,11 @@ def test_held_layer_drops_nothing_under_the_most_uneven_routing(
         assert int(sizes[0]) == n_tokens and int(sizes[1]) == 0
     else:
         assert all(int(n) == n_tokens for n in sizes[:crowd])
+    # (``rounds``: the rows a token that are assigned, rounded up; the
+    # layer works them in rounds of ``held_rows``' rows, its most at most)
     assert -(-int(sizes.sum()) // n_tokens) == rounds
-    assert rounds <= moe.held_rows(n_tokens, 3, held)[0]
+    most, rows = moe.held_rows(n_tokens, 3, held, 8)
+    assert -(-int(sizes.sum()) // rows) <= most
     cot = jax.random.normal(jax.random.key(5), h.shape)
     variables = lambda p: {"params": p, "buffers": buffers}
     program = lambda p, h: jnp.sum(layer.apply(variables(p), h)[0] * cot)
@@ -315,18 +321,18 @@ def test_rows_past_the_assigned_are_masked_on_both_sides():
     and the cotangent that goes to the grouped product is zero past
     them."""
     token = jnp.array([3, 0, 3, 5, 1, 2])
-    where = token, moe.token_sum.plan(token, 2), 2
+    where = token, moe.token_sum.plan(token, 2, 6), 2
     np.testing.assert_array_equal(np.asarray(where[1].order)[:2], [1, 0])
     rows = jnp.full((6, 3), jnp.nan).at[:2].set(1.0)
     weight = jnp.full((6,), jnp.nan).at[:2].set(2.0)
     want = np.zeros((6, 3))
     want[[3, 0]] = 1.0
     np.testing.assert_array_equal(
-        np.asarray(moe._sum_by_token(rows, where)), want)
+        np.asarray(moe._sum_by_token(rows, where, 6)), want)
     np.testing.assert_array_equal(np.asarray(moe._add_to_tokens(
         jnp.zeros((6, 3)), rows, weight, where)), 2 * want)
     x = jnp.arange(18.0).reshape(6, 3)
-    got, pull = jax.vjp(lambda x: moe._rows_of_tokens(x, where), x)
+    got, pull = jax.vjp(lambda x: moe._rows_of_tokens(x, where, 6), x)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(x)[token])
     np.testing.assert_array_equal(np.asarray(pull(rows)[0]), want)
     d_rows, d_weight = moe._add_to_tokens_transposed(
@@ -377,7 +383,8 @@ def test_pieces_are_the_one_pass_movement(assigned, monkeypatch):
     sort by token, not in the rows')."""
     monkeypatch.setattr(moe, "_PIECE", 8)
     monkeypatch.setattr(moe, "_held_round",
-                        jax.jit(moe._held_round.__wrapped__))
+                        jax.jit(moe._held_round.__wrapped__,
+                                static_argnames="rows"))
     n_tokens, count, width = 32, 3, 8
     assert moe.move_rows(n_tokens) == 8
     keys = jax.random.split(jax.random.key(assigned), 6)
@@ -400,18 +407,19 @@ def test_pieces_are_the_one_pass_movement(assigned, monkeypatch):
 
     first = min(assigned, n_tokens)           # the first round's movements
     token = route[0][:n_tokens] // count
-    where = token, moe.token_sum.plan(token, first), first
+    where = token, moe.token_sum.plan(token, first, n_tokens), first
     by_token = np.asarray(where[1].order)[:first]   # the assigned, by token
     assert sorted(by_token) == list(range(first))
     assert (np.diff(np.asarray(token)[by_token]) >= 0).all()
     real = (jnp.arange(n_tokens) < first)[:, None]
     weight = jax.random.uniform(keys[2], (n_tokens,))
     g = jax.random.normal(keys[1], (n_tokens, width)) + 1.0
-    got, pull = jax.vjp(lambda x: moe._rows_of_tokens(x, where), tokens)
+    got, pull = jax.vjp(lambda x: moe._rows_of_tokens(x, where, n_tokens),
+                        tokens)
     want, plain = jax.vjp(lambda x: jnp.where(real, x[token], 0.0), tokens)
     same(got[:first], want[:first])
     near(pull(g), plain(g))
-    near(moe._sum_by_token(g, where), plain(g)[0])
+    near(moe._sum_by_token(g, where, n_tokens), plain(g)[0])
     add = lambda rows, weight: jnp.ones_like(rows).at[token].add(
         jnp.where(real, rows * weight[:, None], 0.0))
     want, plain = jax.vjp(add, tokens, weight)
@@ -422,7 +430,7 @@ def test_pieces_are_the_one_pass_movement(assigned, monkeypatch):
     rounds = -(-assigned // n_tokens)
     cot = jax.random.normal(keys[5], (n_tokens, width))
     got = jax.jit(jax.value_and_grad(lambda *of: jnp.sum(
-        moe._held_experts(*of, route) * cot), argnums=(0, 1, 2)))(
+        moe._held_experts(*of, route, n_tokens) * cot), argnums=(0, 1, 2)))(
             tokens, weights, stacks)
     want = jax.value_and_grad(lambda *of: jnp.sum(_one_pass_held_experts(
         *of, route, rounds) * cot), argnums=(0, 1, 2))(
@@ -545,7 +553,10 @@ def test_held_layer_program_has_nothing_T_k_E_and_one_sort_of_its_slots(
     its ``T x count`` slots once: the second sort, the inverse
     permutation that nothing read, is not traced. The layer that holds
     every expert still has both (its weights are one a choice)."""
-    tokens, n_experts, k, held = 44, 8, 5, (2, 4)
+    # (6 held of 8 with 5 a token: a round is 5 x 44 rows, not the 6 x 44
+    # slots)
+    tokens, n_experts, k, held = 44, 8, 5, (2, 6)
+    assert moe.held_rows(tokens, k, held, n_experts) == (1, 5 * tokens)
     options = (dict(score="sigmoid", route_scale=2.5, expert_act="relu2",
                     latent=16, shared_ff=20) if kind == "sigmoid-latent"
                else dict(renormalise=True, shared_ff=10, shared_gate=True))
@@ -612,7 +623,9 @@ def _grouped_products(jaxpr):
 def test_held_layer_holds_its_round_once(held, k, act, stacks):
     """The set-up's proxy a CPU can read: whatever ``min(k, count)`` is,
     the program of a held layer holds the round's grouped products at one
-    row count, ``T``, and once: as many products as the layer's one pass
+    row count, a round's (``held_rows``: ``T``, or the whole multiple of it
+    that a share expecting over 0.8 of a row a token works in: 4 ``T`` for
+    6 of 8 held with 4 a token), and once: as many products as the layer's one pass
     had, a stack each in the forward program; in the gradient that, the
     round made again in the backward loop (a stack each) and its pullback
     (two a stack). The rounds are a ``while`` that the router's count
@@ -636,9 +649,11 @@ def test_held_layer_holds_its_round_once(held, k, act, stacks):
              (3, 2, 1))):
         found, primitives = _grouped_products(jax.make_jaxpr(program)(h))
         assert len(found) == products, found
+        rows = moe.held_rows(tokens, k, held, 8)[1]
+        assert rows == {(2, 2): 1, (1, 6): 4, (3, 1): 1}[held] * tokens
         for _, sizes in found:
-            assert tokens in sizes
-            assert not [n for n in sizes if n > tokens and n % tokens == 0]
+            assert rows in sizes
+            assert not [n for n in sizes if n % tokens == 0 and n != rows]
         assert "while" in primitives
         assert "cond" not in primitives and "scan" not in primitives
         assert (primitives["while"], primitives["pallas_call"],
@@ -667,7 +682,8 @@ def test_held_layer_names_add_no_operation(monkeypatch):
     def lowered():
         # a round is a jax.jit that remembers its trace: build it anew
         monkeypatch.setattr(moe, "_held_round",
-                            jax.jit(moe._held_round.__wrapped__))
+                            jax.jit(moe._held_round.__wrapped__,
+                                static_argnames="rows"))
         layer, params, buffers, h = _latent_layer((4, 4))
         return jax.jit(jax.grad(lambda p, h: jnp.sum(layer.apply(
             {"params": p, "buffers": buffers}, h)[0] ** 2))).lower(params, h)
@@ -696,10 +712,12 @@ def test_held_layers_are_counted_by_what_they_hold():
                         move_rows=move_rows).value if m else 0.0
 
     layer, params, buffers, h = _latent_layer((4, 4))
-    # a round is one row a token; a piece of it the largest part of 2,048
-    # rows that divides it: 8 of 40, the whole of 2,048 at a cell's 16,384
-    rows, piece = str(h.shape[0]), str(moe.move_rows(h.shape[0]))
-    assert (rows, piece) == ("40", "8")
+    # a round of 4 of 8 held with 3 a token is two rows a token (1.25 x 1.5
+    # expected, rounded up); a piece of it the largest part of 2,048 rows
+    # that divides it: 16 of 80, the whole of 2,048 at a cell's 16,384
+    rows = moe.held_rows(h.shape[0], 3, (4, 4), 8)[1]
+    rows, piece = str(rows), str(moe.move_rows(rows))
+    assert (rows, piece) == ("80", "16")
     assert moe.move_rows(16384) == moe._PIECE == 2048
     before = count("4", rows, piece), count("8", "all", "all")
     jax.jit(lambda p, h: layer.apply(

@@ -220,6 +220,12 @@ def test_tree_mode_member_link_heals_via_leader_reaccept(tmp_path):
             "HVT_TOPO_HOST": "hA" if rank < 2 else "hB",
             "HVT_FAULT_INJECT": "flaky_conn:rank=3:count=2:after_ops=3",
             "HVT_OP_TIMEOUT_MS": "30000",
+            # the heal is what is tested, not its speed: in a whole run six
+            # workers hold the cores and the leader's re-accept has outlasted
+            # the default 10 dials in 10 s (two whole runs of three, PR 61);
+            # still inside the op deadline
+            "HVT_LINK_RETRIES": "40",
+            "HVT_LINK_RETRY_WINDOW_MS": "25000",
             "JAX_PLATFORMS": "cpu",
             "XLA_FLAGS": "",
         })

@@ -47,9 +47,9 @@ def test_kernel_is_the_scatter_add(n_tokens, width, assigned, dtype,
     weight = jax.random.uniform(keys[1], (n_tokens,)) if weighted else None
     total = (jax.random.normal(keys[2], (n_tokens, width)) if weighted
              else None)
-    plan = token_sum.plan(token, assigned)
-    got = token_sum.sum_by_token(rows, plan, weight=weight, total=total,
-                                 dtype=jnp.float32)
+    plan = token_sum.plan(token, assigned, n_tokens)
+    got = token_sum.sum_by_token(rows, plan, n_tokens, weight=weight,
+                                 total=total, dtype=jnp.float32)
     want = token_sum.sum_by_token_plain(rows, token, assigned,
                                         weight=weight, total=total)
     assert got.dtype == jnp.float32 and got.shape == rows.shape
@@ -57,10 +57,54 @@ def test_kernel_is_the_scatter_add(n_tokens, width, assigned, dtype,
                                rtol=1e-6, atol=1e-6)
     if not weighted:        # ones and zeros: one exact product
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-        low = token_sum.sum_by_token(rows, plan)
+        low = token_sum.sum_by_token(rows, plan, n_tokens)
         assert low.dtype == rows.dtype
         np.testing.assert_array_equal(np.asarray(low),
                                       np.asarray(want.astype(rows.dtype)))
+
+
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted-onto-a-total", "as-they-are"])
+@pytest.mark.parametrize("n_tokens, times, width, assigned", [
+    (32, 3, 8, 0), (32, 3, 8, 70), (32, 3, 8, 96), (40, 2, 8, 57),
+    (512, 3, 128, 1100), (256, 2, 128, 512)],
+    ids=["no-row", "over-two-rows-a-token", "every-row", "tiles-of-8",
+         "two-tiles-twelve-chunks", "one-tile-full"])
+def test_a_round_of_several_rows_a_token(n_tokens, times, width, assigned,
+                                         weighted):
+    """A round of ``times x T`` rows over ``T`` tokens (a share that
+    expects more than a row a token, ``models/moe.py``'s ``held_rows``): a
+    token owns up to ``times`` of the round's rows and more, the plan's
+    tiles are the tokens' and its chunks the rows', and the sum is the
+    scatter-add onto ``[T, d]``."""
+    held = times + 1
+    chosen = jnp.zeros(n_tokens * held, bool).at[jax.random.permutation(
+        jax.random.key(n_tokens + assigned), n_tokens * held)[
+            :assigned]].set(True)
+    order = jnp.argsort(jnp.where(chosen.reshape(n_tokens, held),
+                                  jnp.arange(held), held).reshape(-1),
+                        stable=True)
+    n_rows = times * n_tokens
+    token = (order[:n_rows] // held).astype(jnp.int32)
+    keys = jax.random.split(jax.random.key(width), 3)
+    rows = jax.random.normal(keys[0], (n_rows, width))
+    rows = jnp.where((jnp.arange(n_rows) < assigned)[:, None], rows, jnp.nan)
+    weight = jax.random.uniform(keys[1], (n_rows,)) if weighted else None
+    total = (jax.random.normal(keys[2], (n_tokens, width)) if weighted
+             else None)
+    plan = token_sum.plan(token, assigned, n_tokens)
+    assert plan.order.shape == (n_rows,)
+    tile, chunk = token_sum.tile_rows(n_rows, n_tokens)
+    assert plan.starts.shape == (n_tokens // tile + 1,)
+    assert plan.tile_of.shape == (n_rows // chunk + n_tokens // tile,)
+    got = token_sum.sum_by_token(rows, plan, n_tokens, weight=weight,
+                                 total=total, dtype=jnp.float32)
+    want = token_sum.sum_by_token_plain(
+        rows, token, assigned, weight=weight,
+        total=jnp.zeros((n_tokens, width)) if total is None else total)
+    assert got.shape == (n_tokens, width)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
 
 
 @pytest.mark.parametrize("assigned", [0, 1, 100, 256, 511, 512])
@@ -76,7 +120,8 @@ def test_plan_lists_a_step_a_tile_and_chunk_that_meet(assigned,
     monkeypatch.setattr(token_sum, "CHUNK", 32)
     n_tokens = 512
     token, assigned = _round(n_tokens, assigned, seed=assigned)
-    plan = jax.tree.map(np.asarray, token_sum.plan(token, assigned))
+    plan = jax.tree.map(np.asarray,
+                        token_sum.plan(token, assigned, n_tokens))
     sorted_tokens = plan.token.reshape(-1)
     np.testing.assert_array_equal(sorted_tokens[:assigned],
                                   np.sort(np.asarray(token)[:assigned]))
