@@ -12,7 +12,13 @@ loop is long enough to hide its own overhead) unless the caller names one.
 A pass of the forward's loop takes its sub-block as two halves by query
 rows (``_chains``), both score products before either softmax, so that the
 MXU and the vector unit work at once; a row depends on no other row, so
-the result is the one-chain pass's to the last bit.
+the result is the one-chain pass's to the last bit. Its schedule ends
+where its query block's sight ends: a causal block's grid steps past the
+tile that holds its diagonal, which compute nothing, fetch nothing either
+(their block index is that tile's, ``_fwd_call``'s ``held``), and where
+the block ends in the first half of the diagonal's sub-block, that pass
+takes the half alone (``_takes_the_half``); the results are the parent
+kernel's to the last bit.
 
 Backward pass recomputes score tiles (FLOPs-for-HBM trade, the same choice
 ``jax.checkpoint`` makes) from the saved logsumexp in one kernel gridded
@@ -166,6 +172,28 @@ def resolve_flash(use_flash, local_seq) -> bool:
 # product in flight. m and l hold a row's value in every lane, so the
 # maximum, the correction and the sum are whole-register operations.
 #
+# What the forward does not do (v5e, PERF.md section 6, PR 62). (1) A
+# causal query block's grid steps past the tile that holds its diagonal
+# computed nothing and still fetched their tile of K and V (and a rotated
+# k_r's, and a choice's columns), a quarter of the steps at 8,192 positions
+# and three eighths at 16,384: 1.70 ms of a 13.87 ms call at 128 + 64 on
+# 128 (its K tile 256 lanes wide) and 2.13 of 20.84 with a choice, whose
+# 512 x 4,096 bytes a step nothing hides; nothing at heads of 64, 128 or
+# 256 alone, whose fetches the passes cover. They name the diagonal's tile
+# again (``_fwd_call``'s ``held``). (2) ``_causal_n_eff`` rounds a block's
+# last sub-block up, so every even 512-row block multiplied and
+# exponentiated 512 keys of 1,024 that none of its rows sees: that pass
+# goes by the half (``_takes_the_half``). By the compiler's schedule a
+# half pass at heads of 128 has 55% of a whole one's operations in 78% of
+# its bundles (1,848 for 2,375): a pass is as long as its chain of score,
+# row maximum across lanes, exp, row sum, value product and accumulator,
+# which half the keys do not shorten. So the half is worth the whole of
+# its share at heads of 256 (7.82 -> 7.38 ms at 8,192), where the products
+# outweigh the chain, a quarter of it at 64 (1.516 -> 1.478 at 4,096) and
+# at 128 + 64 (12.17 -> 11.99), and nothing at 128 and 16,384, where the
+# calls under a window, one edge of every band a half, ran no faster and
+# walk whole sub-blocks.
+#
 # What a pass of the backward's loop does (v5e, PERF.md section 6, PR 54).
 # Five products (seven with a rotated pair) on a 1024 x 512 sub-block, and
 # each streams its 1,024 rows through one of the four MXUs once: by the
@@ -251,6 +279,35 @@ def _band_steps(kernel, s, block, tile, window):
     return max(min(last, s // tile - 1) - first + 1 for first, last in spans)
 
 
+def _takes_the_half(causal, block_q, block_k, window=None):
+    """Whether the forward's loop takes the sub-block that holds a query
+    block's diagonal by its first half where the block ends inside that
+    half. ``_causal_n_eff`` rounds a block's last sub-block up, and a
+    whole pass over a sub-block of which no row sees the second half
+    multiplies and exponentiates that half for an exact zero. It can only
+    happen where the query block is shorter than the key block (the
+    derived 512 x 1024: every even block), and a half is whole lane tiles
+    (of the score, and of a choice's columns) or is not taken. Not under a
+    window, where a half at either edge of a block's band was measured no
+    faster than the whole (the comment on what the forward does not do,
+    above)."""
+    return (causal and window is None and block_q < block_k
+            and block_k % (2 * _LANES) == 0)
+
+
+def _held_steps(causal, s, block_q, tile, window=None):
+    """How many grid steps of one head's forward walk name a tile again
+    and so fetch nothing (``_fwd_call``'s ``held``): a causal query block's
+    steps past the tile that holds its diagonal. None in a call of one
+    tile, and none counted under a window, whose grid is a band's steps
+    already."""
+    if not causal or window is not None:
+        return 0
+    tiles = s // tile
+    return sum(tiles - 1 - ((qi + 1) * block_q - 1) // tile
+               for qi in range(s // block_q))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
                 choice=False, window=None):
     # a rotated pair, where the caller passed one, comes after the three
@@ -282,11 +339,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
             qr_ref, kr_ref = rotated
             qr, _ = _scaled(qr_ref[0, 0], scale)
 
-        def body(j, carry):
-            k = k_ref[0, 0, _sub_block(j, block_k), :]
-            v = v_ref[0, 0, _sub_block(j, block_k), :]
+        def sub_block(j, width=block_k):
+            # a pass: the block's rows against the tile's ``j``-th ``width``
+            # keys, a sub-block or a half of one
+            k = k_ref[0, 0, _sub_block(j, width), :]
+            v = v_ref[0, 0, _sub_block(j, width), :]
             if rotated:
-                kr = kr_ref[0, _sub_block(j, block_k), :]
+                kr = kr_ref[0, _sub_block(j, width), :]
 
             def score(c):
                 mine = slice(c * rows, (c + 1) * rows)
@@ -297,13 +356,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
                     sc = sc * rest
                 if choice:
                     sc = jnp.where(_chosen(choice_ref[
-                        0, mine, _sub_block(j, block_k)]), sc, -jnp.inf)
+                        0, mine, _sub_block(j, width)]), sc, -jnp.inf)
                 elif causal:
                     # past a window's far edge a row may see no key of a
                     # sub-block, as under a choice: ``-inf`` there
                     sc = jnp.where(
                         _visible(qi * block_q + c * rows,
-                                 ti * tile + j * block_k, sc.shape, window),
+                                 ti * tile + j * width, sc.shape, window),
                         sc, _NEG_INF if window is None else -jnp.inf)
                 return sc
 
@@ -328,6 +387,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
             scores = [score(c) for c in range(chains)]
             for c, sc in enumerate(scores):
                 softmax_and_values(c, sc)
+
+        def whole(j, carry):
+            sub_block(j)
             return carry
 
         n_sub = tile // block_k
@@ -336,11 +398,24 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
         # the sub-block holding the first key the block's first row sees
         first = 0 if window is None else jnp.clip(
             (qi * block_q - window + 1 - ti * tile) // block_k, 0, n_sub)
-        jax.lax.fori_loop(first, n_eff, body, 0)
+        # where the block ends in the first half of its last sub-block, that
+        # sub-block goes by the half (``_takes_the_half``), after the whole
+        # ones, as the keys run
+        halved = _takes_the_half(causal, block_q, block_k, window)
+        if halved:
+            half = block_k // 2
+            cut = ((qi + 1) * block_q - ti * tile
+                   - (n_eff - 1) * block_k <= half)
+            n_eff -= cut.astype(jnp.int32)
+        jax.lax.fori_loop(first, n_eff, whole, 0)
+        if halved:
+            # the first half of the sub-block the loop stopped before
+            pl.when(cut)(lambda: sub_block(2 * n_eff, half))
 
     if causal:
-        # tiles entirely above the diagonal still stream past (the
-        # pipeline fetches every grid step) but do no MXU work
+        # tiles entirely above the diagonal do no MXU work, and their grid
+        # steps fetch nothing either: ``_fwd_call``'s ``held`` names the
+        # diagonal's tile for them
         pl.when(ti * tile < (qi + 1) * block_q)(_tile)
     else:
         _tile()
@@ -668,22 +743,27 @@ def _score_tile(kernel, s, d, itemsize, causal, block_q, block_k, d_v=None,
 
 
 def _count_trace(kernel, block_q, block_k, derived, d, d_v, d_rot, chains,
-                 window):
+                 window, held_steps=0, halves=False):
     """Which score tile each traced kernel got, whether the rule or the
     caller chose it, the two widths it was built for (q and k's whole
     width, v and o's), how many of q and k's columns came as a rotated
     pair of their own (0: q and k came whole) and into how many pieces by
     query rows a pass of its loop takes its sub-block (``_chains``: the
     forward's two chains side by side; the backward's two halves, of which
-    a causal pass leaves out the one that sees nothing), and the window it
-    was built with (0: none, and the schedule has one bound)."""
+    a causal pass leaves out the one that sees nothing), the window it
+    was built with (0: none, and the schedule has one bound), and what the
+    forward's schedule leaves undone: how many grid steps of one head's
+    walk name a tile again and fetch nothing (``_held_steps``) and whether
+    its loop takes the sub-block a block's diagonal ends in by its half
+    (``_takes_the_half``); both 0 for the backward, whose own rules
+    ``chains`` tells."""
     _pallas.count_trace(
         "hvt_flash_kernel_traces_total",
         "flash-attention kernels traced into compiled programs, by "
         "score tile (counted per trace, not per execution)",
         kernel=kernel, block_q=block_q, block_k=block_k,
         derived=int(derived), d_qk=d, d_v=d_v, d_rot=d_rot, chains=chains,
-        window=window or 0)
+        window=window or 0, held_steps=held_steps, halves=int(halves))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
@@ -805,13 +885,22 @@ def _flash_fwd_impl(q, k, v, rotated, choice, scale, causal, block_q,
 # 6, PR 27).
 @functools.partial(jax.jit, static_argnames=("plan", "out_dtype"))
 def _fwd_call(q, k, v, rotated=None, choice=None, *, plan, out_dtype):
+    """o, lse: grid (b, h, qi, ti): K and V tiles (a rotated ``k_r``'s, a
+    choice's columns) stream past each query block, whose online softmax
+    lives in VMEM scratch from its first tile to its last. A causal
+    block's steps past the tile that holds its diagonal compute nothing
+    and name that tile, so nothing is fetched for them (``held``); with a
+    window the last axis is a band's tiles. Under GQA a query head reads
+    its shared K/V head through the index map."""
     b, h, s, d = q.shape
     d_v, e = v.shape[-1], _rotated_width(rotated)
     block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
     chosen = choice is not None
     window = plan.window
     _count_trace("fwd" + "_choice" * chosen, block_q, block_k, plan.derived,
-                 d + e, d_v, e, plan.chains, window)
+                 d + e, d_v, e, plan.chains, window,
+                 _held_steps(plan.causal, s, block_q, tile, window),
+                 _takes_the_half(plan.causal, block_q, block_k, window))
     # Grouped-query attention is served ZERO-COPY: query head hi reads
     # K/V head hi // group through the block index map — no repeat
     # materialization, and the shared K/V tile stays VMEM-resident
@@ -821,16 +910,25 @@ def _fwd_call(q, k, v, rotated=None, choice=None, *, plan, out_dtype):
     # scratch accumulators carry the online softmax across tiles
     # with a window the last axis is as long as a block's band and not as
     # the sequence, and a step names its tile of the band: the tiles
-    # wholly before the window are neither walked nor fetched, and the
-    # steps past the diagonal name the diagonal's tile again
+    # wholly before the window are neither walked nor fetched
     grid = (b, h, s // block_q, s // tile if window is None
             else _band_steps("fwd", s, block_q, tile, window))
 
     def held(qi, ti):
-        if window is None:
-            return ti
-        first, last = _band("fwd", qi, block_q, tile, window)
-        return jnp.minimum(first + ti, last)
+        """The tile of the streamed operands (K, V, a rotated ``k_r``, a
+        choice's columns) that grid step ``ti`` of query block ``qi``
+        names: the step's own up to the tile that holds the block's
+        diagonal and that tile from there on, which the pipeline holds
+        already (the kernel's own ``ti`` stays the grid's, and its
+        ``pl.when`` skips those steps), as a windowed call's steps past
+        its band's last tile always did. A call of one tile has no such
+        step and names what it named."""
+        if window is not None:
+            first, last = _band("fwd", qi, block_q, tile, window)
+            return jnp.minimum(first + ti, last)
+        if plan.causal and s // tile > 1:
+            return jnp.minimum(ti, ((qi + 1) * block_q - 1) // tile)
+        return ti
 
     # q and k are ``d`` wide, v and o ``d_v``
     by_query = lambda width: pl.BlockSpec(
@@ -842,12 +940,13 @@ def _fwd_call(q, k, v, rotated=None, choice=None, *, plan, out_dtype):
     if rotated is not None:
         # q_r as q; the one k_r a position, whatever the head
         in_specs += [by_query(e), pl.BlockSpec(
-            (1, tile, e), lambda bi, hi, qi, ti: (bi, ti, 0))]
+            (1, tile, e), lambda bi, hi, qi, ti: (bi, held(qi, ti), 0))]
     if chosen:
         # the block's rows of the mask against the tile's keys, whatever
         # the head
         in_specs += [pl.BlockSpec(
-            (1, block_q, tile), lambda bi, hi, qi, ti: (bi, qi, ti))]
+            (1, block_q, tile),
+            lambda bi, hi, qi, ti: (bi, qi, held(qi, ti)))]
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=plan.scale,
                           causal=plan.causal, block_k=block_k,

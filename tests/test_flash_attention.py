@@ -517,11 +517,18 @@ def _trace_count(**labels):
     labels.setdefault("chains", fa._chains(
         labels["kernel"].removesuffix("_choice"), int(labels["block_q"]),
         int(labels["block_k"]), 4, True))
+    # what the forward's schedule leaves undone (PR 62): no step held in a
+    # call of one tile, and the near edge's sub-block by its half where the
+    # query block is shorter than a key block of whole lane tiles
+    labels.setdefault("held_steps", "0")
+    labels.setdefault("halves", int(
+        labels["kernel"].startswith("fwd") and fa._takes_the_half(
+            True, int(labels["block_q"]), int(labels["block_k"]))))
     m = metrics.registry().get("hvt_flash_kernel_traces_total")
     return m.labels(**labels).value if m else 0.0
 
 
-def test_trace_counter_carries_the_tile_and_who_chose_it():
+def test_trace_counter_carries_the_tile_and_who_chose_it(monkeypatch):
     from horovod_tpu.ops import flash_attention as fa
 
     q, k, v = _qkv(b=1, s=256, h=1)
@@ -573,6 +580,29 @@ def test_trace_counter_carries_the_tile_and_who_chose_it():
     before = halves()
     trace(block_q=128, block_k=64)
     assert halves() == [before[0] + 1, before[1] + 1, before[2]]
+    # what the forward's schedule leaves undone (PR 62): a query block of
+    # 64 under a key block of 256 takes the diagonal's sub-block by its
+    # half, and with the streamed tile at 256 of 1,024 positions 24 of the
+    # 64 grid steps of a head's walk name the diagonal's tile again; the
+    # backward, whose own rules ``chains`` tells, counts neither
+    q, k, v = _qkv(b=1, s=1024, h=1)
+    tile = dict(block_q="64", block_k="256", derived="0", d_qk=q.shape[-1],
+                d_v=v.shape[-1])
+    undone = lambda: [
+        _trace_count(kernel="fwd", held_steps=n, halves=half, **tile)
+        for n, half in ((24, 1), (0, 1), (24, 0))] + [
+        _trace_count(kernel="bwd", chains=1, **tile)]
+    before = undone()
+    monkeypatch.setattr(fa, "_SEQ_TILE", 256)
+    jax.clear_caches()
+    trace(block_q=64, block_k=256)
+    jax.clear_caches()
+    assert undone() == [before[0] + 1, before[1], before[2], before[3] + 1]
+    assert fa._held_steps(True, 8192, 512, 4096) == 8
+    assert fa._held_steps(True, 16384, 512, 4096) == 48
+    assert fa._held_steps(True, 4096, 512, 4096) == 0
+    assert fa._held_steps(False, 8192, 512, 4096) == 0
+    assert fa._held_steps(True, 16384, 512, 1024, window=2048) == 0
 
 
 def test_trace_counter_adds_nothing_to_the_program(monkeypatch):
@@ -970,47 +1000,72 @@ def _chain_case(s, h, h_kv, d, d_v, e, dtype, seed):
 
 
 @pytest.mark.parametrize(
-    "s, h, h_kv, d, d_v, e, causal, dtype, out_dtype, chains", [
-        pytest.param(1024, 1, 1, 64, 64, 0, True, jnp.float32, None, 2,
+    "s, h, h_kv, d, d_v, e, causal, dtype, out_dtype, chains, blocks", [
+        pytest.param(1024, 1, 1, 64, 64, 0, True, jnp.float32, None, 2, None,
                      id="s1024-d64-causal-one-sub-block-a-grid-step"),
-        pytest.param(2048, 1, 1, 64, 64, 0, True, jnp.float32, None, 2,
+        pytest.param(2048, 1, 1, 64, 64, 0, True, jnp.float32, None, 2, None,
                      id="s2048-d64-causal"),
-        pytest.param(1024, 1, 1, 128, 128, 0, False, jnp.float32, None, 2,
+        pytest.param(1024, 1, 1, 128, 128, 0, False, jnp.float32, None, 2, None,
                      id="s1024-d128-not-causal"),
-        pytest.param(2048, 1, 1, 128, 128, 0, True, jnp.float32, None, 2,
+        pytest.param(2048, 1, 1, 128, 128, 0, True, jnp.float32, None, 2, None,
                      id="s2048-d128-causal"),
-        pytest.param(1024, 4, 1, 64, 64, 0, True, jnp.float32, None, 2,
+        pytest.param(1024, 4, 1, 64, 64, 0, True, jnp.float32, None, 2, None,
                      id="grouped-4-on-1"),
-        pytest.param(1024, 2, 2, 128, 128, 64, True, jnp.float32, None, 2,
+        pytest.param(1024, 2, 2, 128, 128, 64, True, jnp.float32, None, 2, None,
                      id="rotated-128+64-on-128"),
         pytest.param(1024, 2, 2, 64, 64, 0, False, jnp.bfloat16,
-                     jnp.float32, 2, id="bf16-out-float32-not-causal"),
-        pytest.param(200, 2, 2, 64, 64, 0, True, jnp.float32, None, 1,
+                     jnp.float32, 2, None, id="bf16-out-float32-not-causal"),
+        pytest.param(200, 2, 2, 64, 64, 0, True, jnp.float32, None, 1, None,
                      id="s200-no-multiple-of-128-one-chain"),
-        pytest.param(96, 2, 2, 32, 32, 0, True, jnp.float32, None, 2,
+        pytest.param(96, 2, 2, 32, 32, 0, True, jnp.float32, None, 2, None,
                      id="s96-one-block-of-96-in-halves-of-48"),
+        # a query block shorter than a key block of whole lane tiles: the
+        # sub-block that holds a block's diagonal goes by its first half
+        # where the block ends inside it (PR 62): every even block of six
+        # at a half, the first two of every four at a quarter
+        pytest.param(768, 1, 1, 32, 32, 0, True, jnp.float32, None, 2,
+                     (128, 256), id="s768-128x256-even-blocks-by-the-half"),
+        pytest.param(1024, 2, 1, 32, 32, 0, True, jnp.float32, None, 2,
+                     (64, 256), id="s1024-64x256-grouped-2-on-1"),
+        pytest.param(512, 2, 2, 32, 16, 8, True, jnp.float32, None, 2,
+                     (128, 256), id="s512-128x256-rotated-32+8-on-16"),
+        pytest.param(512, 2, 2, 32, 32, 0, True, jnp.bfloat16, jnp.float32,
+                     2, (128, 256), id="s512-128x256-bf16"),
+        pytest.param(512, 1, 1, 32, 32, 0, False, jnp.float32, None, 2,
+                     (128, 256), id="s512-128x256-not-causal-no-half"),
     ])
 def test_forward_in_chains_matches_the_formula_and_the_one_chain_pass(
-        s, h, h_kv, d, d_v, e, causal, dtype, out_dtype, chains,
+        s, h, h_kv, d, d_v, e, causal, dtype, out_dtype, chains, blocks,
         monkeypatch):
-    """The forward at the tile the rule derives, with as many chains of
-    query rows a pass as the rule gives that tile: o, lse and every
-    gradient (cotangents for both outputs) against the float32 formula,
-    and equal to the last bit to what the same call gives with the rule
-    held to one chain: a row of the score tile depends on no other row
-    (to float32 rounding where the interpreter's products are of blocks
-    the CPU's dot treats differently)."""
+    """The forward at the tile the rule derives (or at ``blocks``), with as
+    many chains of query rows a pass as the rule gives that tile: o, lse
+    and every gradient (cotangents for both outputs) against the float32
+    formula, and equal to the last bit to what the same call gives with
+    the rule held to one chain: a row of the score tile depends on no
+    other row (to float32 rounding where the interpreter's products are
+    of blocks the CPU's dot treats differently). And against the same call
+    with every sub-block taken whole (``_takes_the_half`` held to no): the
+    half that is left out holds no key a row of the block sees, so its p
+    is 0 and it added 0; the traced forward kernel holds one branch more
+    where it takes the half."""
     from horovod_tpu.ops import flash_attention as fa
 
     operands, w_o, w_lse = _chain_case(s, h, h_kv, d, d_v, e, dtype, seed=s)
     scale = (d + e) ** -0.5
     q_bhsd = jax.ShapeDtypeStruct((1, h, s, d), dtype)
-    assert fa._plan("fwd", q_bhsd, scale, causal, None, None, d_v,
-                    e).chains == chains
+    tile = dict(zip(("block_q", "block_k"), blocks or (None, None)))
+    plan = fa._plan("fwd", q_bhsd, scale, causal, *tile.values(), d_v, e)
+    assert plan.chains == chains
 
     def attend(q, k, v, q_r=None, k_r=None):
         return fa.flash_attention_with_lse(
-            q, k, v, q_r=q_r, k_r=k_r, causal=causal, out_dtype=out_dtype)
+            q, k, v, q_r=q_r, k_r=k_r, causal=causal, out_dtype=out_dtype,
+            **tile)
+
+    def branches():
+        """``cond``s in the traced forward kernel."""
+        return str(jax.make_jaxpr(lambda *t: attend(*t)[0])(
+            *operands)).count(" cond[")
 
     def formula(q, k, v, q_r=None, k_r=None):
         if q_r is not None:
@@ -1029,11 +1084,18 @@ def test_forward_in_chains_matches_the_formula_and_the_one_chain_pass(
             has_aux=True))(*operands)
 
     with jax.default_matmul_precision("highest"):
-        got, want = run(attend), run(formula)
         jax.clear_caches()
+        got, want, n_halved = run(attend), run(formula), branches()
+        jax.clear_caches()
+        monkeypatch.setattr(fa, "_takes_the_half", lambda *a: False)
+        whole, n_whole = run(attend), branches()
+        jax.clear_caches()
+        monkeypatch.undo()
         monkeypatch.setattr(fa, "_chains", lambda *a: 1)
         one_chain = run(attend)
         jax.clear_caches()
+    assert n_halved - n_whole == int(
+        causal and plan.block_q < plan.block_k and plan.block_k % 256 == 0)
     (_, (o, lse)), grads = got
     assert o.dtype == (out_dtype or dtype) and lse.dtype == jnp.float32
     assert [g.shape for g in grads] == [t.shape for t in operands]
@@ -1045,11 +1107,16 @@ def test_forward_in_chains_matches_the_formula_and_the_one_chain_pass(
                                    atol=tol * np.abs(w).max())
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(one_chain),
                     strict=True):
-        if s % 128 == 0:
+        if plan.block_q % 128 == 0:
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         else:   # the CPU's dot sums a product of 48 rows in another order
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-5, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(whole),
+                    strict=True):
+        a, b = (np.asarray(t, np.float32) for t in (a, b))
+        np.testing.assert_allclose(a, b, rtol=1e-5,
+                                   atol=1e-6 * float(np.abs(b).max()))
 
 
 # ---- the backward's loop: nothing carried, and the sub-block a K block
@@ -1153,6 +1220,13 @@ def _pallas_eqns(jaxpr):
             yield from _pallas_eqns(sub)
 
 
+def _block_index(mapping, *step):
+    """The block a ``pallas_call``'s operand takes at a grid step."""
+    closed = mapping.index_map_jaxpr
+    return tuple(int(i) for i in jax.core.eval_jaxpr(
+        closed.jaxpr, closed.consts, *(jnp.int32(i) for i in step)))
+
+
 @pytest.mark.parametrize("causal, with_choice", [
     (True, False), (True, True), (False, False)])
 def test_backward_fetches_no_tile_its_k_block_sees_nothing_of(
@@ -1179,15 +1253,10 @@ def test_backward_fetches_no_tile_its_k_block_sees_nothing_of(
     mappings = bwd.params["grid_mapping"].block_mappings
     n_in = 7 if with_choice else 6
 
-    def block(mapping, *step):
-        closed = mapping.index_map_jaxpr
-        return tuple(int(i) for i in jax.core.eval_jaxpr(
-            closed.jaxpr, closed.consts, *(jnp.int32(i) for i in step)))
-
     for ki in range(s // block_k):
         for ti in range(s // 256):
             first = max(ti, ki * block_k // 256) if causal else ti
-            got = [block(m, 0, 0, ki, ti) for m in mappings]
+            got = [_block_index(m, 0, 0, ki, ti) for m in mappings]
             assert got[:2] == [(0, 0, ki, 0)] * 2            # k, v
             assert got[2:6] == [(0, 0, first, 0)] * 4   # q, dO, lse, delta
             if with_choice:
@@ -1196,6 +1265,75 @@ def test_backward_fetches_no_tile_its_k_block_sees_nothing_of(
             last = ki == s // block_k - 1
             assert got[n_in:] == [(0, 0, ti if last else 0, 0),
                                   (0, 0, ki, 0), (0, 0, ki, 0)]
+
+
+@pytest.mark.parametrize("causal, seq_tile, extra", [
+    pytest.param(True, 256, None, id="plain"),
+    pytest.param(True, 256, "rotated", id="rotated-pair"),
+    pytest.param(True, 256, "choice", id="choice"),
+    pytest.param(True, 4096, "choice", id="one-tile"),
+    pytest.param(False, 256, None, id="not-causal"),
+])
+def test_forward_fetches_no_tile_past_its_blocks_diagonal(
+        causal, seq_tile, extra, monkeypatch):
+    """The block indices the forward call's streamed operands (K, V, a
+    rotated ``k_r`` and a choice's columns) take over the grid, read from
+    the traced ``pallas_call``: a causal query block's grid steps past the
+    tile that holds its diagonal name that tile, so the pipeline fetches
+    nothing for the steps that compute nothing; q and the outputs go by
+    the block. A call of one tile and one that is not causal name what
+    they named, through index maps without a minimum in them. And ``o``,
+    ``lse`` and every gradient are the one-tile call's to the last bit."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    s, block_q, block_k, e = 1024, 128, 256, 8
+    operands, w_o, w_lse = _chain_case(
+        s, 2, 2, 32, 32, e if extra == "rotated" else 0, jnp.float32, seed=7)
+    chosen = ({"choice": jnp.asarray(np.tril(np.ones((1, s, s))), jnp.int8)}
+              if extra == "choice" else {})
+
+    def weighed(*operands):
+        pair = dict(zip(("q_r", "k_r"), operands[3:]))
+        o, lse = fa.flash_attention_with_lse(
+            *operands[:3], causal=causal, block_q=block_q, block_k=block_k,
+            **pair, **chosen)
+        return jnp.sum(o * w_o) + jnp.sum(lse * w_lse), (o, lse)
+
+    run = jax.value_and_grad(weighed, argnums=tuple(range(len(operands))),
+                             has_aux=True)
+    monkeypatch.setattr(fa, "_SEQ_TILE", seq_tile)
+    tile = min(seq_tile, s)
+    jax.clear_caches()
+    fwd, = (eqn for eqn in _pallas_eqns(jax.make_jaxpr(weighed)(
+        *operands).jaxpr) if eqn.params["name"] == "hvt_flash_fwd")
+    mappings = fwd.params["grid_mapping"].block_mappings
+    assert fwd.params["grid_mapping"].grid == (1, 2, s // block_q, s // tile)
+
+    holds = causal and tile < s
+    # k and v, and k_r after q_r or a choice's columns
+    streamed = {1, 2, *({"rotated": [4], "choice": [3]}.get(extra, []))}
+    for i, mapping in enumerate(mappings):
+        assert (" min " in str(mapping.index_map_jaxpr)) == (
+            holds and i in streamed)
+    for qi in range(s // block_q):
+        for ti in range(s // tile):
+            held = min(ti, ((qi + 1) * block_q - 1) // tile) if holds else ti
+            got = [_block_index(m, 0, 1, qi, ti) for m in mappings]
+            assert got[0] == (0, 1, qi, 0)                        # q
+            assert got[1:3] == [(0, 1, held, 0)] * 2              # k, v
+            if extra == "rotated":
+                assert got[3:5] == [(0, 1, qi, 0), (0, held, 0)]  # q_r, k_r
+            if extra == "choice":
+                assert got[3] == (0, qi, held)
+            assert got[-2:] == [(0, 1, qi, 0)] * 2                # o, lse
+    got = run(*operands)
+    monkeypatch.setattr(fa, "_SEQ_TILE", 4096)
+    jax.clear_caches()
+    one_tile = run(*operands)
+    jax.clear_caches()
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(one_tile),
+                    strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---- a choice of keys: a mask a query row, the same for every head
@@ -1234,6 +1372,9 @@ def _dense_chosen(q, k, v, choice, scale=None):
     (256, 4, 2, dict(block_q=128, block_k=64), True, 0.3),
     (512, 2, 1, dict(block_q=256, block_k=128), True, 0.05),
     (256, 2, 2, dict(block_q=128, block_k=64), False, 0.3),
+    # a query block that ends in the first half of a key sub-block: the
+    # forward takes the choice's columns of that half alone (PR 62)
+    (512, 2, 1, dict(block_q=128, block_k=256), True, 0.05),
 ])
 def test_choice_matches_the_einsum_with_the_same_mask(s, h, h_kv, tile,
                                                       causal, keep):

@@ -48,6 +48,19 @@ def _value_and_grads(attend, q, k, v, weight):
     pytest.param(256, 96, 128, 32, 2, 1, id="backward-halves-grouped"),
     pytest.param(384, 64, None, None, 2, 1, id="derived-tile"),
     pytest.param(256, 1, 32, 64, 1, 1, id="its-own-position-alone"),
+    # a key block of whole lane tiles in halves, which a call without a
+    # window takes by the half where a block ends inside the first
+    # (PR 62): a windowed call walks whole sub-blocks (the half measured no
+    # faster under a window). The band's far edge in a sub-block's first
+    # half for the even blocks and in its second for the odd ones; the
+    # other way round; a square tile; a window that ends inside the
+    # block's own sub-block; a block a quarter of the sub-block
+    pytest.param(1024, 257, 128, 256, 2, 1, id="lane-tiles-far-edge-even-odd"),
+    pytest.param(1024, 385, 128, 256, 2, 2, id="lane-tiles-far-edge-odd-even"),
+    pytest.param(768, 200, 128, 256, 4, 2, id="lane-tiles-no-multiple"),
+    pytest.param(768, 120, 256, 256, 2, 2, id="lane-tiles-square"),
+    pytest.param(512, 1, 128, 256, 1, 1, id="lane-tiles-its-own-position"),
+    pytest.param(1024, 300, 64, 256, 2, 1, id="lane-tiles-a-quarter-block"),
 ])
 def test_windowed_kernels_against_a_masked_softmax(s, window, block_q,
                                                    block_k, h, h_kv):
@@ -63,12 +76,17 @@ def test_windowed_kernels_against_a_masked_softmax(s, window, block_q,
         np.testing.assert_allclose(mine, theirs, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("window", [256, 300])
-def test_a_window_no_shorter_than_the_sequence_hides_nothing(window):
+@pytest.mark.parametrize("s, block_q, block_k, window", [
+    (256, 64, 32, 256), (256, 64, 32, 300),
+    # the call without a window takes the diagonal's sub-block by its
+    # half, the windowed one whole: p is 0 in the other half (PR 62)
+    (512, 128, 256, 512), (512, 128, 256, 600)])
+def test_a_window_no_shorter_than_the_sequence_hides_nothing(
+        s, block_q, block_k, window):
     # the same sub-blocks in the same order: the call without a window, to
     # the last bit, though its tiles are four times as long
-    q, k, v, weight = _qkv(256, 4, 2)
-    blocks = dict(block_q=64, block_k=32)
+    q, k, v, weight = _qkv(s, 4, 2)
+    blocks = dict(block_q=block_q, block_k=block_k)
     got = _value_and_grads(lambda q, k, v: fa.flash_attention(
         q, k, v, window=window, **blocks), q, k, v, weight)
     want = _value_and_grads(lambda q, k, v: fa.flash_attention(
@@ -145,7 +163,8 @@ def test_the_trace_counter_carries_the_window():
         return m.labels(kernel=kernel, block_q="64", block_k="64",
                         derived="0", d_qk="16", d_v="16", d_rot="0",
                         chains=str(fa._chains(kernel, 64, 64, 4, True)),
-                        window=str(window)).value if m else 0.0
+                        window=str(window), held_steps="0",
+                        halves="0").value if m else 0.0
 
     q, k, v, weight = _qkv(128, 1, 1)
     jax.clear_caches()
@@ -154,6 +173,29 @@ def test_the_trace_counter_carries_the_window():
         q, k, v, window=48, block_q=64, block_k=64), q, k, v, weight)
     after = [count(kernel, w) for kernel in ("fwd", "bwd") for w in (0, 48)]
     assert after == [before[0], before[1] + 1, before[2], before[3] + 1]
+    # a windowed forward holds no step again (its grid is the band's
+    # already) and walks whole sub-blocks, where the call without a window
+    # takes the diagonal's by its half (PR 62: 128 rows under 256 keys)
+    q, k, v, weight = _qkv(512, 1, 1)
+
+    def wide(kernel, window, halves):
+        m = metrics.registry().get("hvt_flash_kernel_traces_total")
+        return m.labels(kernel=kernel, block_q="128", block_k="256",
+                        derived="0", d_qk="16", d_v="16", d_rot="0",
+                        chains=str(fa._chains(kernel, 128, 256, 4, True)),
+                        window=str(window), held_steps="0",
+                        halves=str(halves)).value if m else 0.0
+
+    sets = (("fwd", 300, 0), ("fwd", 300, 1), ("fwd", 0, 1), ("fwd", 0, 0),
+            ("bwd", 300, 0), ("bwd", 0, 0))
+    before = [wide(*labels) for labels in sets]
+    for window in (300, None):
+        _value_and_grads(lambda q, k, v: fa.flash_attention(
+            q, k, v, window=window, block_q=128, block_k=256),
+            q, k, v, weight)
+    assert [wide(*labels) for labels in sets] == [
+        n + took for n, took in zip(before, (1, 0, 1, 0, 1, 1))]
+    jax.clear_caches()
 
 
 @pytest.mark.parametrize("kwargs, match", [
