@@ -7,9 +7,14 @@ one), beside the plain ``jax.numpy`` path of ``models/gdn.py``, and what the
 triangular inverse costs inside the kernels: the same calls with the
 inverse's float32 products at the default precision (one bf16 pass), with
 no inverse at all (``T = I - N``: a wrong program, timed only) and with
-other numbers of value heads a grid step (``--heads-a-step``). Also whether
-Mosaic honours the float32 precision: one ``[128, 128]`` system inverted in
-a kernel at ``HIGHEST`` and at the default against numpy's float64 inverse,
+other numbers of value heads a grid step (``--heads-a-step``), and a call
+of ``hvt_gdn_inverse`` alone (``inverse_kernel``): as it is, with every
+level a product from blocks of 1 (what it was before PR 63), with the
+substitution on the diagonal blocks of 16 alone and with ``T = I - N`` (two
+wrong programs, timed only: what the levels above 16 and what the system
+and the write cost). Also whether Mosaic honours the float32 precision: one
+``[128, 128]`` system inverted in a kernel at ``HIGHEST`` and at the default
+against numpy's float64 inverse, as it is and with every level a product,
 beside the plain body's ``unit_lower_inverse`` as XLA runs it.
 
 A microbenchmark: the step's own cost is a traced run of the cell
@@ -58,6 +63,41 @@ def _ms(call, args, calls):
     return 1e3 * (time.perf_counter() - t0) / calls
 
 
+def inverse_kernel(call, calls):
+    """Milliseconds a call of a delta rule's inverse kernel alone
+    (``call()`` makes it; both rules take their inverse from
+    ``gated_delta_rule._unit_lower_inverse``): as it is and with that
+    function's parts taken out, each a trace of its own."""
+    import jax
+
+    from horovod_tpu.ops import gated_delta_rule as kernels
+
+    whole, solved = kernels._unit_lower_inverse, kernels._solved
+    rounded = lambda plan: kernels._rounder(plan.state_dtype)
+    parts = {
+        "as_it_is": {},
+        "every_level_a_product": {"_solved": lambda c: 0},
+        # wrong programs, timed only
+        "substitution_alone": {
+            "_unit_lower_inverse": lambda n, row, col, plan:
+                kernels._substituted(n, row, col, rounded(plan))},
+        "without_inverse": {
+            "_unit_lower_inverse": lambda n, row, col, plan:
+                (row == col).astype(n.dtype) - n},
+    }
+    out = {}
+    for name, swapped in parts.items():
+        for attribute, value in swapped.items():
+            setattr(kernels, attribute, value)
+        jax.clear_caches()
+        try:
+            out[f"ms_{name}"] = _ms(call, (), calls)
+        finally:
+            kernels._unit_lower_inverse, kernels._solved = whole, solved
+    jax.clear_caches()
+    return out
+
+
 def time_paths(shape, chunk, calls, heads_a_step):
     import jax
 
@@ -74,6 +114,11 @@ def time_paths(shape, chunk, calls, heads_a_step):
 
     out = {"plain": both(functools.partial(kernels.gated_delta_rule_plain,
                                            chunk=chunk))}
+    plan, (_, k, v, g_col, beta), _ = kernels._prepare(
+        *args, chunk, jax.numpy.float32, highest)
+    out["inverse_kernel"] = inverse_kernel(
+        lambda: kernels._inverse_call(k, g_col, beta, plan=plan,
+                                      dtype=v.dtype), calls)
     as_it_is = kernels._HEADS_A_STEP
     for heads in heads_a_step:      # value heads a grid step
         kernels._HEADS_A_STEP = heads
@@ -121,7 +166,9 @@ def inverse_precision(c=128):
                             / np.linalg.norm(want))
     out = {"xla_unit_lower_inverse": err(jax.jit(kernels.unit_lower_inverse)(
         jnp.asarray(system, jnp.float32)))}
-    for name in ("HIGHEST", "DEFAULT"):
+    solved = kernels._solved
+    for name, levels in (("HIGHEST", ""), ("DEFAULT", ""),
+                         ("HIGHEST", "_every_level_a_product")):
         plan = kernels._Plan(c, 1, 1, c, c, 1, jnp.dtype(jnp.float32),
                              getattr(jax.lax.Precision, name),
                              _pallas.interpret())
@@ -131,10 +178,15 @@ def inverse_precision(c=128):
             t_ref[...] = kernels._unit_lower_inverse(n_ref[...], row, col,
                                                      plan)
 
-        got = pl.pallas_call(
-            body, out_shape=jax.ShapeDtypeStruct((c, c), jnp.float32),
-            interpret=plan.interpret)(jnp.asarray(system, jnp.float32))
-        out[f"kernel_at_{name}"] = err(got)
+        if levels:
+            kernels._solved = lambda c: 0
+        try:
+            got = pl.pallas_call(
+                body, out_shape=jax.ShapeDtypeStruct((c, c), jnp.float32),
+                interpret=plan.interpret)(jnp.asarray(system, jnp.float32))
+        finally:
+            kernels._solved = solved
+        out[f"kernel_at_{name}{levels}"] = err(got)
     return out
 
 

@@ -16,7 +16,12 @@ the same heads, a decay a head, for the price of the channels.
 (``CHUNKxHEADS``: the chunk and the heads a grid step takes), with each
 one's gradients' distance from the plain body's on the checked positions
 and its operations from a trace (``hvt_kda_inverse``, ``hvt_kda_fwd``,
-``hvt_kda_bwd`` a call).
+``hvt_kda_bwd`` a call). ``--inverse`` times a call of ``hvt_kda_inverse``
+alone at the rule's own chunk and heads a step: as it is, with every level
+of the triangular inverse a product (what it was before PR 63), with the
+substitution on the diagonal blocks of 16 alone and with no inverse at all
+(``benchmarks/gdn_kernels.py``'s ``inverse_kernel``, the function the two
+rules share).
 
 A microbenchmark: the step's own cost is a traced run of the cell
 (``python3 -m chipbench.run --workload kimilinear-s8192 --trace 1``).
@@ -133,6 +138,7 @@ def main(argv=None):
     ap.add_argument("--profile", default=None, metavar="VARIANT")
     ap.add_argument("--scalar", action="store_true")
     ap.add_argument("--kernels", default="", metavar="CHUNKxHEADS,...")
+    ap.add_argument("--inverse", action="store_true")
     a = ap.parse_args(argv)
 
     import jax
@@ -192,6 +198,16 @@ def main(argv=None):
         here["by_operation"] = profile and [
             [ms, n, name] for ms, n, name, _ in profile["top"][:8]]
         print(json.dumps({f"kernels_{variant}": here}), flush=True)
+    if a.inverse:
+        from gdn_kernels import inverse_kernel      # beside this script
+
+        plan, (_, k, v, g, beta), _ = rule_op._prepare(
+            *args, rule_op.CHUNK, jnp.float32, jax.lax.Precision.HIGHEST)
+        out["inverse_kernel"] = inverse_kernel(
+            lambda: rule_op._inverse_call(k, g, beta, plan=plan,
+                                          dtype=v.dtype), a.calls)
+        print(json.dumps({"inverse_kernel": out["inverse_kernel"]}),
+              flush=True)
     if a.scalar:
         q, k, v, g, beta = args
         one = (q, k, v, jnp.mean(g, -1), beta)

@@ -67,10 +67,12 @@ one head in VMEM, operands read and written as ``[b, s, H d]`` at a head's
 columns:
 
 - ``hvt_kda_inverse`` forms the sums of ``g`` and the decayed products
-  ``N`` and inverts ``I + N`` by blocks
-  (``gated_delta_rule._unit_lower_inverse``, shared). It depends on no
-  state, so a caller that recomputes its forward pass can keep its result
-  (``KEPT_INVERSE``) and not run it twice.
+  ``N`` and inverts ``I + N``
+  (``gated_delta_rule._unit_lower_inverse``, shared: the diagonal blocks
+  of 16 by substitution on the VPU, the three levels above them by
+  blocks, two float32 products each). It depends on no state, so a caller
+  that recomputes its forward pass can keep its result (``KEPT_INVERSE``)
+  and not run it twice.
 - ``hvt_kda_fwd`` walks the chunks with the state of every head in a VMEM
   scratch, **transposed** (``[d_v, d_k]``: the decay of a key channel then
   scales lanes), and writes ``o`` and the state each chunk was entered
@@ -351,13 +353,14 @@ class _Plan(NamedTuple):
 
 
 def _count_trace(kernel, plan):
-    """Which kernels a job got, by the chunk and head widths."""
+    """Which kernels a job got, by the chunk and head widths, and which
+    inverse: ``solved``, the width of the blocks taken by substitution."""
     _pallas.count_trace(
         "hvt_kda_kernel_traces_total",
         "delta rule kernels with a decay a key channel traced into "
         "compiled programs (counted per trace, not per execution)",
         kernel=kernel, chunk=plan.chunk, key_dim=plan.key_dim,
-        value_dim=plan.value_dim)
+        value_dim=plan.value_dim, solved=scalar_rule._solved(plan.chunk))
 
 
 def _carried(plan):

@@ -16,9 +16,11 @@ one value head is a few hundred KB of VMEM and none of that exists outside
 it:
 
 - ``hvt_gdn_inverse`` forms a chunk's decays and system in registers and
-  inverts it by blocks. It depends on no state, so it carries nothing and a
-  caller that recomputes its forward pass can keep its result
-  (``KEPT_INVERSE``) and not run it twice.
+  inverts it (``_unit_lower_inverse``: the diagonal blocks of 16 by
+  forward substitution on the VPU, exact float32 and no product, then
+  three levels by blocks, two float32 products each). It depends on no
+  state, so it carries nothing and a caller that recomputes its forward
+  pass can keep its result (``KEPT_INVERSE``) and not run it twice.
 - ``hvt_gdn_fwd`` walks the chunks itself with the state ``S [d_k, d_v]``
   of every value head in a VMEM scratch and writes ``o`` and, for the
   backward pass, the state each chunk was *entered with*.
@@ -54,8 +56,9 @@ Here the decay is one number a head and position, so ``exp(G_i - G_j)`` is
 a ``[c, c]`` mask that multiplies ``k k^T`` and ``q k^T`` *after* the
 products; a decay a channel sits inside the sum over channels and is
 folded into the operands through reference rows (the sibling module,
-whose three kernels take the inverse by blocks, ``_unit_lower_inverse``,
-and the rounders of a state dtype steered from outside from here).
+whose three kernels take the inverse, ``_unit_lower_inverse`` (blocks of
+16 by substitution, the levels above them by products), and the rounders
+of a state dtype steered from outside from here).
 Both bodies here keep the contract, the kernels carry ``dS`` in float32
 too, and the cumulative sum of ``g`` inside a chunk and its transpose for
 ``dg`` are XLA operations around the kernels (``[b, s, H_v]`` float32).
@@ -288,13 +291,14 @@ class _Plan(NamedTuple):
 
 
 def _count_trace(kernel, plan):
-    """Which kernels a job got, by the chunk and head widths."""
+    """Which kernels a job got, by the chunk and head widths, and which
+    inverse: ``solved``, the width of the blocks taken by substitution."""
     _pallas.count_trace(
         "hvt_gdn_kernel_traces_total",
         "gated delta rule kernels traced into compiled programs "
         "(counted per trace, not per execution)",
         kernel=kernel, chunk=plan.chunk, key_dim=plan.key_dim,
-        value_dim=plan.value_dim)
+        value_dim=plan.value_dim, solved=_solved(plan.chunk))
 
 
 def _rounder(state_dtype):
@@ -324,12 +328,73 @@ def _positions(c):
     return row, col
 
 
+# The width of the diagonal blocks ``_unit_lower_inverse`` takes by
+# substitution: two float32 sublane tiles a block, so the chunk's eight
+# blocks side by side are two vregs. On a v5e the products that built
+# blocks of 4, 8 and 16 were six of a chunk's eleven, each at a whole
+# ``[128, 128]`` float32 product's fixed cost for a sliver of its work
+# (PERF.md section 6, PR 63).
+_SOLVED = 16
+
+
+def _solved(c):
+    """The width of the diagonal blocks that ``_unit_lower_inverse`` takes
+    by substitution in a chunk of ``c`` positions, from the chunk alone:
+    ``_SOLVED`` where the level above them works on whole blocks, 0 where
+    every level is a product (the tests' small chunks). The label
+    ``solved`` of both rules' trace counters."""
+    return 0 if c % (2 * _SOLVED) else _SOLVED
+
+
+def _substituted(n, row, col, low):
+    """``(I + A)^-1`` for ``A`` the part of the strictly lower ``n [c,
+    c]`` float32 inside its diagonal blocks of ``_SOLVED``: forward
+    substitution in float32 on the VPU, **no product**. With ``a_r``
+    column ``r`` of a block, ``I + A = (I + a_0 e_0^T) ... (I + a_14
+    e_14^T)``, so the inverse is fifteen dependent rank-one updates ``X <-
+    X - a_r (x) X[r, :]`` from ``X = I``, every block at once. The blocks
+    lie side by side along the lanes, ``[_SOLVED, c]`` (block ``b`` at
+    lanes ``16 b`` on: two vregs where the system is sixteen), so a step
+    is one lane of each block spread over its sixteen (a lane gather a
+    vreg, off the chain), row ``r`` spread over the sublanes (a masked
+    sum), a multiply and a subtract. Exact float32 arithmetic, ``low``
+    applied to every step's result. One traced step, unrolled where it is
+    lowered (its index is a constant there), so the traced program does
+    not go by the steps."""
+    c = n.shape[0]
+    s = _SOLVED
+    # iotas of their own: Mosaic does not take a slice of one
+    at = jax.lax.broadcasted_iota(jnp.int32, (s, c), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (s, c), 1)
+    first = lane & -s                       # its block's first lane
+    inside = (row & -s) == (col & -s)
+    blocks = jnp.where(inside, n, 0.0)
+    side_by_side = functools.reduce(
+        jnp.add, [blocks[i:i + s] for i in range(0, c, s)])
+
+    def step(r, x):
+        column = jnp.take_along_axis(side_by_side, first + r, axis=1)
+        x_r = jnp.sum(jnp.where(at == r, x, 0.0), axis=0, keepdims=True)
+        return low(x - column * x_r)
+
+    solved = jax.lax.fori_loop(0, s - 1, step,
+                               (lane - first == at).astype(_F32),
+                               unroll=True)
+    return jnp.where(inside, jnp.concatenate([solved] * (c // s), axis=0),
+                     0.0)
+
+
 def _unit_lower_inverse(n, row, col, plan):
-    """``(I + N)^-1`` for a strictly lower ``n [c, c]`` float32, by blocks
-    as ``unit_lower_inverse`` has it: ``T <- T - T O T`` with ``O`` the
-    part of ``N`` in the lower left quarter of each ``2 m`` block, from
-    ``m = 1``; float32 products at ``plan.precision``. ``T O T`` is zero
-    outside the rows of the blocks' second halves, so where those are
+    """``(I + N)^-1`` for a strictly lower ``n [c, c]`` float32. The
+    diagonal blocks of ``_SOLVED`` by substitution on the VPU
+    (``_substituted``), then by blocks as ``unit_lower_inverse`` has it:
+    ``T <- T - T O T`` with ``O`` the part of ``N`` in the lower left
+    quarter of each ``2 m`` block, from ``m = _SOLVED``, float32 products
+    at ``plan.precision``: three levels and six products at the kernels'
+    chunk of 128, where doubling from ``m = 1`` made eleven. A chunk that
+    twice ``_SOLVED`` does not divide takes every level by products from
+    ``m = 1`` (``_solved``: from the chunk at trace time). ``T O T`` is
+    zero outside the rows of the blocks' second halves, so where those are
     whole sublane tiles (``m`` a multiple of 8) only they go through the
     products (6% of the forward on a v5e, not the third their rows are:
     PERF.md section 6, PR 34)."""
@@ -337,10 +402,14 @@ def _unit_lower_inverse(n, row, col, plan):
     low = _rounder(plan.state_dtype)
     dot32 = lambda a, b: jax.lax.dot_general(
         a, b, NN, precision=plan.precision, preferred_element_type=_F32)
-    # m = 1: the blocks' inverses are the identity, so T O T is O itself
-    inverse = low((row == col).astype(_F32) - jnp.where(
-        ((row ^ col) == 1) & ((row & 1) == 1), n, 0.0))
-    shift = 1
+    solved = _solved(c)
+    if solved:
+        inverse = _substituted(n, row, col, low)
+        shift = solved.bit_length() - 1
+    else:
+        # m = 1: the blocks' inverses are the identity, so T O T is O itself
+        inverse, shift = low((row == col).astype(_F32) - jnp.where(
+            ((row ^ col) == 1) & ((row & 1) == 1), n, 0.0)), 1
     while (m := 1 << shift) < c:
         # rows in the second half of a block of 2 m, columns in its first
         quarter = ((((row ^ col) >> shift) == 1)

@@ -192,6 +192,135 @@ def test_inverse_through_half_the_rows_is_the_inverse(size):
                "against the plain path's", 1e-5)
 
 
+def _cell_systems():
+    """Strictly lower ``[128, 128]`` systems as the kernels form them
+    (``beta_i (k_i . k_j) exp(G_i - G_j)``, unit keys) and two that are
+    zero on one side of the blocks taken by substitution."""
+    c = 128
+    rng = np.random.RandomState(63)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+    # qwen3next-s8192's ranges (benchmarks/gdn_kernels.py): beta a sigmoid
+    # of a normal, g = -exp(normal) softplus(normal + 1) / 16
+    keys = unit(rng.normal(size=(c, 128)))
+    beta = 1 / (1 + np.exp(-rng.normal(size=(c, 1))))
+    cum = np.cumsum(-np.exp(rng.normal()) * np.log1p(np.exp(
+        rng.normal(size=c) + 1.0)) / 16)
+    cells = beta * (keys @ keys.T) * np.exp(
+        np.minimum(cum[:, None] - cum[None, :], 0.0))
+    # every k_i . k_j near one, beta near one and no decay: the worst
+    # conditioned system the rule can meet
+    keys = unit(rng.normal(size=(c, 16)) + 3.0)
+    correlated = rng.uniform(0.95, 1.0, (c, 1)) * (keys @ keys.T)
+    dense = 0.3 * rng.normal(size=(c, c))
+    block = np.arange(c) // rule_op._SOLVED
+    same = block[:, None] == block[None, :]
+    return {
+        "cells-beta-and-decays": cells,
+        "correlated-keys-beta-near-1": correlated,
+        "one-block-of-16-alone": np.where(same & (block[:, None] == 3),
+                                          dense, 0.0),
+        "nothing-inside-the-blocks-of-16": np.where(same, 0.0, dense),
+    }
+
+
+EPS32, EPS16 = 2.0 ** -23, 2.0 ** -8
+
+
+@pytest.mark.parametrize("name, state_dtype", [
+    ("cells-beta-and-decays", jnp.float32),
+    ("correlated-keys-beta-near-1", jnp.float32),
+    ("one-block-of-16-alone", jnp.float32),
+    ("nothing-inside-the-blocks-of-16", jnp.float32),
+    ("cells-beta-and-decays", jnp.bfloat16),
+    ("correlated-keys-beta-near-1", jnp.bfloat16)],
+    ids=lambda v: v if isinstance(v, str) else jnp.dtype(v).name)
+def test_blocks_of_16_by_substitution_are_the_inverse(name, state_dtype):
+    """``_unit_lower_inverse`` alone at the kernels' chunk, where the
+    diagonal blocks of 16 are taken by substitution and three levels by
+    products: against numpy's float64 inverse, against the plain body's
+    ``unit_lower_inverse`` and as a left inverse, ``max |T (I + N) - I|
+    <= 32 eps max |T|`` (float32: observed 0.2 to 4 eps max |T|). In
+    float32 within 2e-6 of both (observed 3e-7 at most, what doubling from
+    blocks of 1 gave); with the state's dtype steered to bfloat16 every
+    step of the substitution is rounded (``_rounder``), the result is a
+    bfloat16's and within one bfloat16 ``eps`` (observed a tenth)."""
+    c = 128
+    low = rule_op._rounder(state_dtype)
+    system = low(jnp.asarray(np.tril(_cell_systems()[name], -1),
+                             jnp.float32))
+    plan = rule_op._Plan(c, 1, 1, c, c, 1, jnp.dtype(state_dtype),
+                         gdn.INVERSE_PRECISION, True)
+    assert rule_op._solved(c) == 16
+    got = rule_op._unit_lower_inverse(system, *rule_op._positions(c), plan)
+    assert got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(low(got)))
+    whole = np.eye(c) + np.asarray(system, np.float64)
+    want = np.linalg.inv(whole)
+    plain = rule_op.unit_lower_inverse(system.astype(state_dtype),
+                                       gdn.INVERSE_PRECISION)
+    exact = state_dtype == jnp.float32
+    _close(got, want, "against float64", 2e-6 if exact else 4 * EPS16)
+    _close(got, plain, "against the plain body's",
+           2e-6 if exact else EPS16)
+    left = np.abs(np.asarray(got, np.float64) @ whole - np.eye(c)).max()
+    assert left <= 32 * (EPS32 if exact else EPS16) * np.abs(want).max(), left
+    # what is outside the lower triangle is exactly the identity's
+    np.testing.assert_array_equal(np.triu(np.asarray(got)), np.eye(c))
+
+
+def test_the_inverse_at_the_cells_chunk_makes_six_products():
+    """``_unit_lower_inverse`` traced alone at a chunk of 128: the three
+    levels above the blocks of 16 are six ``dot_general``, each on the 64
+    rows of its blocks' second halves, at the mixer's precision, and the
+    fifteen steps below them are one traced step that holds none; a chunk
+    that 32 does not divide keeps every level a product. And both rules'
+    trace counters say so at the cells' plans: ``solved="16"`` beside a
+    chunk of 128 and heads of 128 x 128."""
+    from horovod_tpu import metrics
+    from horovod_tpu.ops import channel_delta_rule as channel_op
+
+    def products(c):
+        plan = rule_op._Plan(c, 1, 1, c, c, 1, jnp.dtype(jnp.float32),
+                             gdn.INVERSE_PRECISION, True)
+        jaxpr = jax.make_jaxpr(lambda n: rule_op._unit_lower_inverse(
+            n, *rule_op._positions(c), plan))(jnp.zeros((c, c), jnp.float32))
+        steps = [eqn for eqn in jaxpr.jaxpr.eqns
+                 if eqn.primitive.name == "scan"]
+        return ([eqn for eqn in _equations(jaxpr.jaxpr)
+                 if eqn.primitive.name == "dot_general"], steps)
+
+    made, steps = products(128)
+    assert len(made) == 6
+    for eqn in made:
+        assert eqn.invars[0].aval.shape == (64, 128), eqn
+        assert eqn.invars[1].aval.shape == (128, 128), eqn
+        assert eqn.params["precision"] == (gdn.INVERSE_PRECISION,) * 2, eqn
+    (step,) = steps
+    assert step.params["length"] == 15 and step.params["unroll"] == 15
+    assert not [eqn for eqn in _equations(step.params["jaxpr"].jaxpr)
+                if eqn.primitive.name == "dot_general"]
+    made, steps = products(16)
+    assert len(made) == 2 * 3 and not steps     # m = 2, 4, 8
+
+    count = lambda metric, **labels: metrics.registry().get(
+        metric).labels(**{k: str(v) for k, v in labels.items()}).value
+    like = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32)
+    width = dict(kernel="inverse", chunk=128, key_dim=128, value_dim=128,
+                 solved=16)
+    plan = rule_op._Plan(128, 1, 1, 128, 128, 1, jnp.dtype(jnp.float32),
+                         gdn.INVERSE_PRECISION, True)
+    jax.eval_shape(lambda *a: rule_op._inverse_call(
+        *a, plan=plan, dtype=jnp.bfloat16),
+        like(1, 128, 128), like(1, 128, 1), like(1, 128, 1))
+    assert count("hvt_gdn_kernel_traces_total", **width) >= 1
+    plan = channel_op._Plan(128, 1, 128, 128, 1, jnp.dtype(jnp.float32),
+                            gdn.INVERSE_PRECISION, True)
+    jax.eval_shape(lambda *a: channel_op._inverse_call(
+        *a, plan=plan, dtype=jnp.bfloat16),
+        like(1, 128, 128), like(1, 128, 128), like(1, 128, 1))
+    assert count("hvt_kda_kernel_traces_total", **width) >= 1
+
+
 def _mixer_distance(dtype, monkeypatch, *, through_kernels):
     """``family.mixer_distance`` of a mixer of two key heads of 32 at 256
     positions (so that the rule's own chunk is the kernels' 128)."""
@@ -255,7 +384,7 @@ def _kernel_counts():
 
     m = metrics.registry().get("hvt_gdn_kernel_traces_total")
     return {kernel: m.labels(kernel=kernel, chunk="20", key_dim="8",
-                             value_dim="8").value if m else 0.0
+                             value_dim="8", solved="0").value if m else 0.0
             for kernel in ("inverse", "fwd", "bwd")}
 
 

@@ -7,6 +7,8 @@ is float32 inside the kernels and that every exponent there is of a
 non-positive number; lower precisions steered from outside; and which
 program gets the kernels, under which names."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,21 +35,27 @@ def _calls(fn, *args):
             if eqn.primitive.name == "pallas_call"]
 
 
-@pytest.mark.parametrize("seq, strong", [
-    (12, True), (48, True), (40, False)],
-    ids=["one-chunk-padded-A-16", "three-chunks-A-16", "three-chunks-padded"])
-def test_kernels_match_the_plain_path_and_the_recurrence(seq, strong):
+@pytest.mark.parametrize("seq, strong, chunk", [
+    (12, True, CHUNK), (48, True, CHUNK), (40, False, CHUNK),
+    (40, False, 32)],
+    ids=["one-chunk-padded-A-16", "three-chunks-A-16", "three-chunks-padded",
+         "two-chunks-of-32-blocks-of-16-by-substitution"])
+def test_kernels_match_the_plain_path_and_the_recurrence(seq, strong, chunk):
     """Forward and backward kernels against ``jax.grad`` of
     ``channel_delta_rule_plain`` and of the position-by-position
     recurrence: one and three chunks a sequence, lengths the chunk divides
     and does not (padded with positions whose g and beta are 0), a mild
-    decay and the strongest the initialisation allows, two heads a grid step, d_k != d_v."""
+    decay and the strongest the initialisation allows, two heads a grid step, d_k != d_v;
+    and at a chunk of 32, where the shared inverse takes the diagonal
+    blocks of 16 by substitution as it does at the cell's 128."""
+    assert scalar_op._solved(chunk) == (16 if chunk == 32 else 0)
     args = _operands(seq, strong, batch=1, heads=HEADS)
     cot = jax.random.normal(jax.random.key(7), args[2].shape)
     with jax.default_matmul_precision("highest"):
-        got = _with_gradients(_kernels, cot)(*args)
+        got = _with_gradients(functools.partial(_kernels, chunk=chunk),
+                              cot)(*args)
         plain = _with_gradients(lambda *a: rule_op.channel_delta_rule_plain(
-            *a, chunk=CHUNK), cot)(*args)
+            *a, chunk=chunk), cot)(*args)
         slow = _with_gradients(_recurrence, cot)(*args)
     assert got[0].shape == args[2].shape and got[0].dtype == args[2].dtype
     for name, x, same, far in zip(NAMES, got, plain, slow):
@@ -269,8 +277,9 @@ def _kernel_counts(chunk, key_dim, value_dim):
 
     m = metrics.registry().get("hvt_kda_kernel_traces_total")
     return {kernel: m.labels(kernel=kernel, chunk=str(chunk),
-                             key_dim=str(key_dim),
-                             value_dim=str(value_dim)).value if m else 0.0
+                             key_dim=str(key_dim), value_dim=str(value_dim),
+                             solved=str(scalar_op._solved(chunk))
+                             ).value if m else 0.0
             for kernel in ("inverse", "fwd", "bwd")}
 
 
