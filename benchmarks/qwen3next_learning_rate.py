@@ -93,7 +93,9 @@ def main():
                     "seed": seed, "learning_rate": rate, "step": i,
                     "rows_on_held_experts": [
                         int(n) for n in rows(params, extra, batch)],
-                    "round": int(batch.size)}), flush=True)
+                    # (a batch may be a tree: its largest leaf is the rows)
+                    "round": max(int(leaf.size) for leaf in
+                                 jax.tree.leaves(batch))}), flush=True)
             if i < args.steps:
                 params, extra, state, loss = step(params, extra, state, batch)
                 if i % args.every == 0 or i == args.steps - 1:

@@ -237,6 +237,25 @@ class GPTConfig:
     # The embedding's rows times this before the first layer (a model under
     # muP multiplies them by sqrt(d_model)), in the activations' dtype.
     embed_scale: float = 1.0
+    # Block-diffusion training (Arriola et al., arXiv:2503.09573; SDAR): the
+    # length of a block in positions, 0 = an autoregressive model and every
+    # program what it was. With it set the model takes ``[b, 2 L]`` tokens,
+    # **a clean copy of every sequence and then a noised one**
+    # (``models.diffusion.noise_blocks`` makes them), both copies of position
+    # ``i`` turned by the rotary at ``i``, and in every attention layer a
+    # clean row sees the clean keys of its own block and of those before it
+    # (the whole of its own block, both directions), a noised row the clean
+    # keys of the blocks before its own and the noised keys of its own
+    # block, and nothing else: no clean key of its own block or a later one,
+    # which would hand it its answer. The final norm and the head are over
+    # the ``L`` noised rows alone (``return_hidden`` gives ``[b, L, d]``).
+    # The attention is ``*`` with whatever else it is set to; its products
+    # over positions are ``_attend_blocks``: on the flash path two causal
+    # walks of ``L`` (``ops/flash_attention.py``'s ``blocks``), a quarter
+    # of ``(2 L)^2``. Refused by name: beside ``ring_mesh``, and in a
+    # pattern with a mixer whose record does not say ``two_copies`` (a
+    # windowed, a recurrent or a chosen-keys one).
+    diffusion_block: int = 0
 
 
 def _repeat_kv(k, v, group):
@@ -290,17 +309,18 @@ def held_heads(n_heads, n_kv, held):
     return count, max(1, count // group)
 
 
-def _count_trace(heads, kv_heads, head_dim, core, window, rotary):
+def _count_trace(heads, kv_heads, head_dim, core, window, rotary, blocks):
     """One count a traced layer; ``window`` 0 in a layer that sees every
     causal key; ``rotary`` the law its q and k turn by (``none``,
-    ``plain``, ``yarn``)."""
+    ``plain``, ``yarn``); ``blocks`` the diffusion block's length, 0 in an
+    autoregressive layer."""
     _pallas.count_trace(
         "hvt_attn_layers_traced_total",
         "attention layers traced into compiled programs, by the path "
         "their products over positions take: ring, flash or einsum "
         "(counted per trace, not per execution)",
         heads=heads, kv_heads=kv_heads, head_dim=head_dim, core=core,
-        window=window, rotary=rotary)
+        window=window, rotary=rotary, blocks=blocks)
 
 
 def _attend(cfg, q, k, v, positions, core, window=0):
@@ -362,6 +382,83 @@ def _attend(cfg, q, k, v, positions, core, window=0):
     return jnp.einsum("...hqk,...khd->...qhd", probs, v)
 
 
+def _attend_blocks(cfg, q, k, v, core):
+    """The products over positions of a block-diffusion layer: ``q``, ``k``
+    and ``v`` hold ``2 L`` rows, the clean copy and then the noised one,
+    and blocks are ``cfg.diffusion_block`` positions of a copy. A clean row
+    ``i`` sees the clean keys ``j`` with ``blk(j) <= blk(i)``; a noised row
+    the clean keys with ``blk(j) < blk(i)`` and the noised keys with
+    ``blk(j) == blk(i)``.
+
+    ``einsum``: that rule as a dense ``[2 L, 2 L]`` mask from (half,
+    position), one softmax over whole rows (short sequences and the CPU).
+    ``flash``: (a) the clean rows on the clean keys and (b) the noised rows
+    on the clean keys through the kernels' ``blocks`` in its inclusive and
+    its strict form, each a causal walk of one copy's positions; (c) the
+    noised rows on their own noised block, ``L / B`` products of ``B x B`` in
+    plain ``jax.numpy`` with a log-sum-exp of their own; (b) and (c) merged
+    by their log-sum-exps in float32 (a row of the first block saw nothing
+    in (b): ``lse = -inf``, weight 0). The ring schedule is refused."""
+    size = cfg.diffusion_block
+    *_, rows, n_heads, head_dim = q.shape
+    n_kv, half = k.shape[-2], rows // 2
+    scale = 1.0 / np.sqrt(head_dim)
+    if core == "ring":
+        raise ValueError(
+            f"a diffusion block ({size}) on the ring path is not built "
+            f"(parallel/sequence.py's schedule knows one causal mask): unset "
+            f"ring_mesh, or diffusion_block")
+    if core == "einsum":
+        k, v = _repeat_kv(k, v, n_heads // n_kv)
+        scores = jnp.einsum("...qhd,...khd->...hqk", q, k,
+                            preferred_element_type=jnp.float32) * scale
+        at = jnp.arange(rows)
+        noised, block = at >= half, (at % half) // size
+        clean_key = ~noised[None, :]
+        seen = jnp.where(
+            noised[:, None],
+            (clean_key & (block[None, :] < block[:, None]))
+            | (~clean_key & (block[None, :] == block[:, None])),
+            clean_key & (block[None, :] <= block[:, None]))
+        probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+        return jnp.einsum("...hqk,...khd->...qhd", probs.astype(cfg.dtype), v)
+    from horovod_tpu.ops.flash_attention import (flash_attention,
+                                                 flash_attention_with_lse)
+
+    halves = lambda t: (t[..., :half, :, :], t[..., half:, :, :])
+    (q_c, q_n), (k_c, k_n), (v_c, v_n) = halves(q), halves(k), halves(v)
+    clean = flash_attention(q_c, k_c, v_c, causal=True, blocks=size,
+                            scale=scale)
+    before, lse_before = flash_attention_with_lse(
+        q_n, k_c, v_c, causal=True, blocks=size, strict=True, scale=scale,
+        out_dtype=jnp.float32)
+    with jax.named_scope("attn_blocks_own"):
+        # a block's rows on its own block's keys, a key-value head at a
+        # time: [.., n, B, h_kv, group, d] on [.., n, B, h_kv, d]
+        lead = q_n.shape[:-3]
+        in_blocks = lambda t, *heads: t.reshape(
+            *lead, half // size, size, *heads, head_dim)
+        q_b = in_blocks(q_n, n_kv, n_heads // n_kv)
+        k_b, v_b = in_blocks(k_n, n_kv), in_blocks(v_n, n_kv)
+        scores = jnp.einsum("...nqkgd,...nskd->...nqkgs", q_b, k_b,
+                            preferred_element_type=jnp.float32) * scale
+        lse_own = jax.nn.logsumexp(scores, axis=-1)
+        own = jnp.einsum(
+            "...nqkgs,...nskd->...nqkgd",
+            jnp.exp(scores - lse_own[..., None]).astype(v.dtype), v_b,
+            preferred_element_type=jnp.float32)
+        own = own.reshape(*lead, half, n_heads, head_dim)
+        lse_own = lse_own.reshape(*lead, half, n_heads)
+    with jax.named_scope("attn_blocks_merge"):
+        # the result does not depend on ``most``: no gradient through it
+        most = jax.lax.stop_gradient(jnp.maximum(lse_before, lse_own))
+        w_before = jnp.exp(lse_before - most)[..., None]
+        w_own = jnp.exp(lse_own - most)[..., None]
+        noised = ((w_before * before + w_own * own)
+                  / (w_before + w_own)).astype(cfg.dtype)
+    return jnp.concatenate([clean, noised], axis=-3)
+
+
 class Attention(nn.Module):
     """The attention both of its kinds build (``KINDS``): over every
     causal key and turned as ``cfg.rotary`` says, by the law
@@ -397,18 +494,26 @@ class Attention(nn.Module):
             # (loaded here: importing this file loads no pallas)
             from horovod_tpu.ops.flash_attention import resolve_flash
 
-            core = ("flash" if resolve_flash(cfg.use_flash, x.shape[-2])
-                    else "einsum")
+            # (a diffusion layer's kernels walk a copy's positions)
+            core = ("flash" if resolve_flash(
+                cfg.use_flash, x.shape[-2] // (2 if cfg.diffusion_block
+                                               else 1)) else "einsum")
+        if cfg.diffusion_block and self.window:
+            raise ValueError(
+                f"a diffusion block ({cfg.diffusion_block}) in a layer that "
+                f"sees a window ({self.window}) is not built: the blocks "
+                f"are the causal limit's own")
         # (loaded here, as the flash kernels are)
         from horovod_tpu.ops.rotary import law_name, rotary
 
         law = self.scaling or cfg.rotary_base
         _count_trace(n_heads, n_kv, head_dim, core, self.window,
-                     law_name(law) if self.rotary else "none")
+                     law_name(law) if self.rotary else "none",
+                     cfg.diffusion_block)
         # a model with both kinds of layer sows a layer's own input and
         # output where its caller collects ``intermediates``, as the other
         # mixers do: a check of one layer against a reference
-        sows = bool(cfg.attn_window)
+        sows = bool(cfg.attn_window or cfg.diffusion_block)
         if sows:
             self.sow("intermediates", "attn_input", x)
         with jax.named_scope("attn_proj"):
@@ -439,6 +544,11 @@ class Attention(nn.Module):
                 # of ``attn_core`` has both kinds and one of this the one
                 with jax.named_scope("attn_window"):
                     out = _attend(cfg, q, k, v, positions, core, self.window)
+            elif cfg.diffusion_block:
+                # as a windowed layer's: the kernels' two walks, a block's
+                # own products and the merge under one name
+                with jax.named_scope("attn_blocks"):
+                    out = _attend_blocks(cfg, q, k, v, core)
             else:
                 out = _attend(cfg, q, k, v, positions, core)
         if cfg.attn_gate:
@@ -558,6 +668,11 @@ class Kind:
     # ``kept()``: the names (``checkpoint_name``) of what ``remat`` keeps
     # of the layer, each explained where it is defined
     kept: Callable = tuple
+    # whether a layer of the kind serves a block-diffusion model
+    # (``diffusion_block``), whose rows are a clean and a noised copy of
+    # every sequence: its rows do not see each other, or it knows the two
+    # copies apart
+    two_copies: bool = False
 
 
 KINDS = {kind.letter: kind for kind in (
@@ -565,7 +680,7 @@ KINDS = {kind.letter: kind for kind in (
          lambda cfg: _layer(Attention(cfg, rotary=cfg.rotary,
                                       scaling=cfg.rotary_scaling,
                                       name="attn"), positional=True),
-         _attention_leaf_spec),
+         _attention_leaf_spec, two_copies=True),
     Kind("W", "attn", "attention inside a window", _windowed_attention,
          _attention_leaf_spec),
     Kind("M", "ssm", "Mamba-2",
@@ -615,12 +730,39 @@ KINDS = {kind.letter: kind for kind in (
              "horovod_tpu.models.moe").expert_leaf_spec(
                  names[-1], leaf, ep_axis, tp_axis),
          lambda: (_module("horovod_tpu.models.moe").HELD_SUM,
-                  _module("horovod_tpu.models.moe").HELD_CHOICE)),
+                  _module("horovod_tpu.models.moe").HELD_CHOICE),
+         two_copies=True),
     Kind("-", "mlp", "MLP", lambda cfg: _layer(MLP(cfg, name="mlp")),
          lambda names, leaf, tp_axis, *_: {
              "up": P(None, tp_axis), "gate": P(None, tp_axis),
-             "down": P(tp_axis, None)}.get(names[0], P())),
+             "down": P(tp_axis, None)}.get(names[0], P()), two_copies=True),
 )}
+
+
+def _diffusion_half(cfg: GPTConfig, rows: int) -> int:
+    """The positions of one copy of a block-diffusion model's ``rows``
+    (clean and then noised), and by name what is not built beside a
+    diffusion block."""
+    size = cfg.diffusion_block
+    if cfg.ring_mesh is not None:
+        raise ValueError(
+            f"a diffusion block ({size}) beside ring_mesh is not built: its "
+            f"mask is not the ring schedule's")
+    others = sorted(kind for kind in set(cfg.layer_pattern or "")
+                    if kind in KINDS and not KINDS[kind].two_copies)
+    if others:
+        serve = ", ".join(f"{kind.letter!r} ({kind.words})"
+                          for kind in KINDS.values() if kind.two_copies)
+        raise ValueError(
+            f"a diffusion block ({size}) in a pattern with "
+            + ", ".join(f"{kind!r} ({KINDS[kind].words})" for kind in others)
+            + ": every row of both copies goes through every mixer, and "
+            f"only {serve} know the two copies apart or need not")
+    if rows % 2 or (rows // 2) % size:
+        raise ValueError(
+            f"a diffusion model takes [b, 2 L] tokens, a clean copy and "
+            f"then a noised one, L whole blocks of {size}; got {rows} rows")
+    return rows // 2
 
 
 class Block(nn.Module):
@@ -684,8 +826,13 @@ class GPT(nn.Module):
         (``"dsa_index"``, ``models/dsa.py``), which a training script adds
         to its own."""
         cfg = self.cfg
-        positions = jnp.broadcast_to(
-            jnp.arange(tokens.shape[-1]), tokens.shape)
+        positions = jnp.arange(tokens.shape[-1])
+        if cfg.diffusion_block:
+            half = _diffusion_half(cfg, tokens.shape[-1])
+            # the clean copy and then the noised one: both copies of a
+            # position are turned at that position
+            positions = jnp.tile(positions[:half], 2)
+        positions = jnp.broadcast_to(positions, tokens.shape)
         emb = self.param("embedding", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), jnp.float32)
         with jax.named_scope("embed"):
@@ -718,6 +865,9 @@ class GPT(nn.Module):
             if layer_aux is not None:
                 aux = {**aux, **{name: aux.get(name, 0.0) + value
                                  for name, value in layer_aux.items()}}
+        if cfg.diffusion_block:
+            # the loss reads the noised copy alone
+            x = x[..., half:, :]
         x = _norm(cfg, "ln_f")(x)
         head = emb if cfg.tie_embeddings else self.param(
             "lm_head", nn.initializers.normal(0.02),

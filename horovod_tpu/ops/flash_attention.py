@@ -74,11 +74,19 @@ holding ``q0 - window + 1`` to the diagonal's, the backward's K block
 window - 2``, the streamed tile is one sub-block and the grid's last axis
 a band's tiles (``_band``, ``_band_steps``), so the tiles wholly before
 the window are neither computed nor fetched, as those past the diagonal
-are not. What is not built is refused by name
+are not; ``blocks``, a static length beside ``causal`` (grouped queries
+allowed), with ``strict`` or not: the causal limit by blocks of that many
+positions (block diffusion: a row sees its own block whole and the blocks
+before it, or, strict, the blocks before it alone, the first block's rows
+nothing), which has to divide every piece the kernels walk by, so that the
+tiles with a visible pair are the causal call's and the schedule is left as
+it is. What is not built is refused by name
 (``flash_attention_with_lse``): one of the pair alone, a pair whose shapes
 do not pair, **grouped queries with a rotated pair**, **a choice beside a
 rotated pair**, a choice that is not int8 ``[b, s, s]``, **a window
-without ``causal``, beside a choice or beside a rotated pair**, and
+without ``causal``, beside a choice or beside a rotated pair**, **blocks
+without ``causal``, beside a window, a choice or a rotated pair, or of a
+length that divides no sub-block**, and
 sequence blocks that are not multiples of 8 where the kernel is compiled.
 
 No reference-framework counterpart (Horovod ships gradients, not kernels);
@@ -224,12 +232,21 @@ def _scaled(x, scale):
     return x, scale
 
 
-def _visible(q0, k0, shape, window=None):
+def _visible(q0, k0, shape, window=None, blocks=None):
     """Causal mask of a [queries, keys] score sub-block whose first query
     position is ``q0`` and first key position ``k0``; with a ``window``,
-    of the causal keys the last ``window`` alone: ``t - window < s <= t``."""
+    of the causal keys the last ``window`` alone: ``t - window < s <= t``;
+    with ``blocks = (size, strict)`` the causal limit by blocks of ``size``
+    positions: the keys of the row's own block and of those before it, or,
+    ``strict``, of those before it alone."""
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    if blocks is not None:
+        size, strict = blocks
+        # the first position of the row's block (a power of two is a mask)
+        first = (q_pos & -size if size & (size - 1) == 0
+                 else q_pos - jax.lax.rem(q_pos, size))
+        return k_pos < (first if strict else first + size)
     if window is None:
         return k_pos <= q_pos
     return (k_pos <= q_pos) & (k_pos > q_pos - window)
@@ -309,7 +326,7 @@ def _held_steps(causal, s, block_q, tile, window=None):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
-                choice=False, window=None):
+                choice=False, window=None, blocks=None):
     # a rotated pair, where the caller passed one, comes after the three
     # operands every call has: q_r's block and the shared k_r's tile; a
     # choice, where the caller passed one, after those: the block's rows
@@ -359,11 +376,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
                         0, mine, _sub_block(j, width)]), sc, -jnp.inf)
                 elif causal:
                     # past a window's far edge a row may see no key of a
-                    # sub-block, as under a choice: ``-inf`` there
+                    # sub-block, as under a choice, and a row of the first
+                    # block in the strict form none at all: ``-inf`` there
                     sc = jnp.where(
                         _visible(qi * block_q + c * rows,
-                                 ti * tile + j * width, sc.shape, window),
-                        sc, _NEG_INF if window is None else -jnp.inf)
+                                 ti * tile + j * width, sc.shape, window,
+                                 blocks),
+                        sc, _NEG_INF if window is None and blocks is None
+                        else -jnp.inf)
                 return sc
 
             def softmax_and_values(c, sc):
@@ -428,7 +448,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, chains,
 
 
 def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *refs,
-                scale, causal, block_q, chains, choice=False, window=None):
+                scale, causal, block_q, chains, choice=False, window=None,
+                blocks=None):
     # with a rotated pair each group of refs (in, out, scratch) has two
     # more at its end: k_r's block and q_r's tile, dq_r and a head's dk_r,
     # their accumulators; a choice (never beside a pair) is one more
@@ -515,8 +536,9 @@ def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *refs,
             elif causal:
                 sc = jnp.where(
                     _visible(ti * tile + j * rows, ki * block_k, sc.shape,
-                             window),
-                    sc, _NEG_INF if window is None else -jnp.inf)
+                             window, blocks),
+                    sc, _NEG_INF if window is None and blocks is None
+                    else -jnp.inf)
             p = jnp.exp(sc - lse)
             dv_acc_ref[...] += dot(p, do, TN)
             dp = dot(do, v, NT)
@@ -742,10 +764,37 @@ def _score_tile(kernel, s, d, itemsize, causal, block_q, block_k, d_v=None,
     return bq, bk, derived
 
 
+def _smallest_piece(s, d, itemsize, d_v, block_q, block_k):
+    """The shortest run of positions either kernel of a causal call walks
+    by: the forward's query block and its key sub-block (or the half it is
+    taken by, ``_takes_the_half``), the backward's K block and its query
+    sub-block (or the piece of it, ``_chains``); their greatest common
+    divisor, which a block length has to divide."""
+    fq, fk, _ = _score_tile("fwd", s, d, itemsize, True, block_q, block_k,
+                            d_v)
+    bq, bk, _ = _score_tile("bwd", s, d, itemsize, True, block_q, block_k,
+                            d_v)
+    return math.gcd(
+        fq, fk // 2 if _takes_the_half(True, fq, fk) else fk, bk,
+        bq // _chains("bwd", bq, bk, itemsize, True))
+
+
+def _mask_word(blocks):
+    """What a traced kernel's name in the counter gains under the causal
+    limit by blocks: ``_blocks<size>``, and ``_strict`` in that form; a
+    call without one gains nothing."""
+    if blocks is None:
+        return ""
+    return f"_blocks{blocks[0]}" + "_strict" * blocks[1]
+
+
 def _count_trace(kernel, block_q, block_k, derived, d, d_v, d_rot, chains,
                  window, held_steps=0, halves=False):
-    """Which score tile each traced kernel got, whether the rule or the
-    caller chose it, the two widths it was built for (q and k's whole
+    """Which kernel and mask (``fwd``, ``bwd``; ``_choice`` with a caller's
+    choice of keys, ``_blocks<size>`` and ``_strict`` under the causal limit
+    by blocks, ``_mask_word``), which score tile each traced kernel got,
+    whether the rule or the caller chose it, the two widths it was built
+    for (q and k's whole
     width, v and o's), how many of q and k's columns came as a rotated
     pair of their own (0: q and k came whole) and into how many pieces by
     query rows a pass of its loop takes its sub-block (``_chains``: the
@@ -766,9 +815,9 @@ def _count_trace(kernel, block_q, block_k, derived, d, d_v, d_rot, chains,
         window=window or 0, held_steps=held_steps, halves=int(halves))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, rotated, choice, scale, causal, block_q, block_k,
-           out_dtype, window=None):
+           out_dtype, window=None, blocks=None):
     """Differentiable (o, lse). The lse output carries its own gradient:
     d lse/dS = P, so a dlse cotangent folds into the backward kernel as
     delta := rowsum(do∘o) − dlse — the kernel is unchanged.
@@ -777,7 +826,7 @@ def _flash(q, k, v, rotated, choice, scale, causal, block_q, block_k,
     ``(q_r [b, h, s, e], k_r [b, s, e])`` (``_rotated_width``);
     ``choice`` is None or the mask ``[b, s, s]`` int8 (no gradient)."""
     o, lse = _flash_fwd_impl(q, k, v, rotated, choice, scale, causal,
-                             block_q, block_k, out_dtype, window)
+                             block_q, block_k, out_dtype, window, blocks)
     return o, lse
 
 
@@ -851,10 +900,12 @@ class _Plan(NamedTuple):
     chains: int         # query-row pieces of a sub-block (``_chains``)
     interpret: bool
     window: int | None = None   # keys a query sees, itself among them
+    # the causal limit by blocks of positions: ``(size, strict)``
+    blocks: tuple | None = None
 
 
 def _plan(kernel, q, scale, causal, block_q, block_k, d_v=None, d_rot=0,
-          choice=False, window=None):
+          choice=False, window=None, blocks=None):
     """Made outside the jitted calls below, so that what the process
     holds besides the operands (the backend) is part of their cache's
     key and never read under a cached trace. ``d_rot``: the width of a
@@ -867,15 +918,15 @@ def _plan(kernel, q, scale, causal, block_q, block_k, d_v=None, d_rot=0,
     return _Plan(scale, causal, block_q, block_k, derived,
                  _seq_tile(s, block_q, block_k, window),
                  _chains(kernel, block_q, block_k, q.dtype.itemsize, causal),
-                 _pallas.interpret(), window)
+                 _pallas.interpret(), window, blocks)
 
 
 def _flash_fwd_impl(q, k, v, rotated, choice, scale, causal, block_q,
-                    block_k, out_dtype, window=None):
+                    block_k, out_dtype, window=None, blocks=None):
     return _fwd_call(q, k, v, rotated, choice, out_dtype=out_dtype,
                      plan=_plan("fwd", q, scale, causal, block_q, block_k,
                                 v.shape[-1], _rotated_width(rotated),
-                                choice is not None, window))
+                                choice is not None, window, blocks))
 
 
 # Each of the two calls is a ``jax.jit`` of its own: a model's layers
@@ -897,7 +948,8 @@ def _fwd_call(q, k, v, rotated=None, choice=None, *, plan, out_dtype):
     block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
     chosen = choice is not None
     window = plan.window
-    _count_trace("fwd" + "_choice" * chosen, block_q, block_k, plan.derived,
+    _count_trace("fwd" + "_choice" * chosen + _mask_word(plan.blocks),
+                 block_q, block_k, plan.derived,
                  d + e, d_v, e, plan.chains, window,
                  _held_steps(plan.causal, s, block_q, tile, window),
                  _takes_the_half(plan.causal, block_q, block_k, window))
@@ -950,7 +1002,8 @@ def _fwd_call(q, k, v, rotated=None, choice=None, *, plan, out_dtype):
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=plan.scale,
                           causal=plan.causal, block_k=block_k,
-                          chains=plan.chains, choice=chosen, window=window),
+                          chains=plan.chains, choice=chosen, window=window,
+                          blocks=plan.blocks),
         grid=grid,
         in_specs=in_specs,
         out_specs=[by_query(d_v), by_query(1)],
@@ -969,13 +1022,14 @@ def _fwd_call(q, k, v, rotated=None, choice=None, *, plan, out_dtype):
 
 
 def _flash_fwd(q, k, v, rotated, choice, scale, causal, block_q, block_k,
-               out_dtype, window=None):
+               out_dtype, window=None, blocks=None):
     o, lse = _flash_fwd_impl(q, k, v, rotated, choice, scale, causal,
-                             block_q, block_k, out_dtype, window)
+                             block_q, block_k, out_dtype, window, blocks)
     return (o, lse), (q, k, v, rotated, choice, o, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, out_dtype, window, res, cot):
+def _flash_bwd(scale, causal, block_q, block_k, out_dtype, window, blocks,
+               res, cot):
     do, dlse = cot
     q, k, v, rotated, choice, o, lse = res
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -985,7 +1039,7 @@ def _flash_bwd(scale, causal, block_q, block_k, out_dtype, window, res, cot):
     return _bwd_call(q, k, v, do, lse, delta, rotated, choice,
                      plan=_plan("bwd", q, scale, causal, block_q, block_k,
                                 v.shape[-1], _rotated_width(rotated),
-                                choice is not None, window))
+                                choice is not None, window, blocks))
 
 
 @functools.partial(jax.jit, static_argnames="plan")
@@ -1009,7 +1063,8 @@ def _bwd_call(q, k, v, do, lse, delta, rotated=None, choice=None, *, plan):
     block_q, block_k, tile = plan.block_q, plan.block_k, plan.tile
     chosen = choice is not None
     window = plan.window
-    _count_trace("bwd" + "_choice" * chosen, block_q, block_k, plan.derived,
+    _count_trace("bwd" + "_choice" * chosen + _mask_word(plan.blocks),
+                 block_q, block_k, plan.derived,
                  d + e, d_v, e, plan.chains, window)
     group = h // k.shape[1]
     n_k = s // block_k
@@ -1079,7 +1134,8 @@ def _bwd_call(q, k, v, do, lse, delta, rotated=None, choice=None, *, plan):
     dq, dk, dv, *d_rotated = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=plan.scale,
                           causal=plan.causal, block_q=block_q,
-                          chains=plan.chains, choice=chosen, window=window),
+                          chains=plan.chains, choice=chosen, window=window,
+                          blocks=plan.blocks),
         grid=(b, h, n_k, s // tile if window is None
               else _band_steps("bwd", s, block_k, tile, window)),
         in_specs=in_specs,
@@ -1109,7 +1165,8 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, q_r=None, k_r=None, choice=None, causal=True,
-                    window=None, scale=None, block_q=None, block_k=None):
+                    window=None, blocks=None, strict=False, scale=None,
+                    block_q=None, block_k=None):
     """Fused multi-head attention.
 
     Args:
@@ -1137,6 +1194,13 @@ def flash_attention(q, k, v, *, q_r=None, k_r=None, choice=None, causal=True,
         A static count; needs ``causal``. Neither kernel visits a tile
         that lies wholly outside the band
         (``flash_attention_with_lse``).
+      blocks, strict: None, or the length of the blocks the causal limit
+        goes by (block diffusion): row ``t`` sees the keys of its own block
+        ``t // blocks`` and of those before it, the whole of its own block
+        in both directions; ``strict``: of those before it alone, so the
+        first block's rows see nothing and come back as zero rows with
+        ``lse = -inf``. A static length; needs ``causal``; the kernels walk
+        the causal tiles and no other (``flash_attention_with_lse``).
       scale: softmax scale, default ``head_dim ** -0.5``.
       block_q / block_k: the score tile; ``None`` (the default) derives
         it from the shape, one tile a kernel (``_derive_tile``); an
@@ -1147,14 +1211,16 @@ def flash_attention(q, k, v, *, q_r=None, k_r=None, choice=None, causal=True,
     """
     o, _ = flash_attention_with_lse(q, k, v, q_r=q_r, k_r=k_r, choice=choice,
                                     causal=causal, window=window,
+                                    blocks=blocks, strict=strict,
                                     scale=scale, block_q=block_q,
                                     block_k=block_k)
     return o
 
 
 def flash_attention_with_lse(q, k, v, *, q_r=None, k_r=None, choice=None,
-                             causal=True, window=None, scale=None,
-                             block_q=None, block_k=None, out_dtype=None):
+                             causal=True, window=None, blocks=None,
+                             strict=False, scale=None, block_q=None,
+                             block_k=None, out_dtype=None):
     """Fused attention returning ``(o, lse)``; both are differentiable.
     ``q`` and ``k`` share one width and ``v`` and ``o`` another, which may
     be the same (``flash_attention``).
@@ -1187,6 +1253,28 @@ def flash_attention_with_lse(q, k, v, *, q_r=None, k_r=None, choice=None,
     than the sequence hides nothing, and the results are those of the call
     without one to the last bit. Refused: a window without ``causal``,
     beside a choice, beside a rotated pair.
+
+    With ``blocks`` (a static length, beside ``causal``) the causal limit
+    goes by blocks of that many positions: row ``t`` sees the keys ``s``
+    with ``s // blocks <= t // blocks``, the whole of its own block in both
+    directions, or, ``strict``, ``s // blocks < t // blocks``: the blocks
+    before its own alone. The two forms are what a block-diffusion
+    objective asks of one sequence's clean rows and of its noised rows on
+    the clean keys (``models/transformer.py``). **The schedule is
+    ``causal``'s**: ``blocks`` has to divide every piece either kernel
+    walks by (the query and key blocks of both, the halves a pass is taken
+    by), so every piece's edge is a block's edge, no row sees a key past
+    its query block's last row, and the tiles with a visible pair are the
+    causal ones exactly: the held steps, the halves and the backward's
+    skipped pieces are what they are without it, and neither kernel
+    computes or fetches a tile no row of which sees a key. In the strict
+    form a row of the first block sees nothing: its output is a zero row,
+    its ``lse`` is ``-inf`` and it has no gradient (what is masked is
+    ``-inf`` and not the causal mask's finite fill, which would give such a
+    row the mean of the keys it does not see). Refused: ``blocks`` without
+    ``causal``, beside a window, a choice or a rotated pair, a length that
+    does not divide the pieces, and the strict form at a length that is a
+    whole piece (its diagonal tiles would hold no visible pair).
 
     ``lse[b, s, h]`` is the log-sum-exp of the (scaled, masked) scores for
     each query — exactly what blockwise/ring composition needs to combine
@@ -1257,6 +1345,34 @@ def flash_attention_with_lse(q, k, v, *, q_r=None, k_r=None, choice=None,
     bq, bk, _ = _score_tile("fwd", s, d + e, q.dtype.itemsize, causal,
                              block_q, block_k, v.shape[-1],
                              choice is not None)
+    if blocks is None:
+        if strict:
+            raise ValueError("strict is a form of blocks: pass the blocks' "
+                             "length, or no strict")
+    else:
+        if isinstance(blocks, bool) or not isinstance(blocks, int) or (
+                blocks < 1):
+            raise ValueError(
+                f"blocks is a static length in positions, at least 1; got "
+                f"{blocks!r}")
+        for beside, what in ((not causal, "without causal"),
+                             (window is not None, "beside a window"),
+                             (choice is not None, "beside a choice of keys"),
+                             (e, "beside a rotated pair")):
+            if beside:
+                raise ValueError(
+                    f"blocks {what} is not built: the blocks are the causal "
+                    f"limit's own, and the schedule the causal one")
+        piece = _smallest_piece(s, d, q.dtype.itemsize, v.shape[-1], block_q,
+                                block_k)
+        if piece % blocks or (strict and piece == blocks):
+            raise ValueError(
+                f"blocks of {blocks} positions divide no sub-block: the "
+                f"kernels walk {s} positions by pieces of {piece}, each of "
+                f"which has to be whole blocks"
+                + (", and more than one in the strict form" if strict
+                   else ""))
+        blocks = (blocks, bool(strict))
     if not _pallas.interpret() and (bq % 8 or bk % 8):
         # Mosaic refuses the kernel ("cannot statically prove that index
         # in dimension 2 is a multiple of 8"); the interpreter has no
@@ -1271,7 +1387,11 @@ def flash_attention_with_lse(q, k, v, *, q_r=None, k_r=None, choice=None,
     o, lse = _flash(to_bhsd(q), to_bhsd(k), to_bhsd(v),
                     None if q_r is None else (to_bhsd(q_r), k_r), choice,
                     float(scale), bool(causal), block_q, block_k,
-                    jnp.dtype(out_dtype or q.dtype), window)
+                    jnp.dtype(out_dtype or q.dtype), window, blocks)
+    if strict:
+        # a row that saw nothing left the kernel with its statistics as
+        # they began (``m = _NEG_INF``, ``l = 0``): no key, ``-inf``
+        lse = jnp.where(lse > _NEG_INF, lse, -jnp.inf)
     # lse: [B, H, S, 1] → [B, S, H]
     return jnp.transpose(o, (0, 2, 1, 3)), jnp.transpose(lse[..., 0],
                                                          (0, 2, 1))

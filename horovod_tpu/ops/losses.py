@@ -48,7 +48,8 @@ def _count_trace(chunk, vocab, form, n_chunks):
             "hvt_loss_chunks_traced_total",
             "chunks of the chunked cross-entropy traced into compiled "
             "programs, by form: the value alone, or the value with both "
-            "gradients made beside it (counted per trace, not per "
+            "gradients made beside it, each `_weighted` where the caller "
+            "gave a weight a position (counted per trace, not per "
             "execution)",
             ("chunk", "vocab", "form"),
         ).labels(chunk=str(chunk), vocab=str(vocab), form=form
@@ -57,9 +58,11 @@ def _count_trace(chunk, vocab, form, n_chunks):
         pass  # telemetry must never break a trace
 
 
-def _walk(hidden, emb, targets, chunk, with_grads):
+def _walk(hidden, emb, targets, weights, chunk, with_grads):
     """One pass over the chunks: the mean loss and, ``with_grads``, its
-    gradients for ``hidden`` and ``emb`` (else ``None, None``)."""
+    gradients for ``hidden`` and ``emb`` (else ``None, None``);
+    ``weights`` None, or a position's weight in the sum, which is divided
+    by the number of positions whatever the weights add up to."""
     b, s, d = hidden.shape
     vocab = emb.shape[0]
     pad = (-s) % chunk
@@ -69,11 +72,16 @@ def _walk(hidden, emb, targets, chunk, with_grads):
     # 1 for real tokens, 0 for padding — padded positions contribute 0
     # to the sum (and to both gradients) regardless of their (garbage)
     # logits
-    mask = (jnp.arange(s + pad) < s).astype(jnp.float32)
-    mask = jnp.broadcast_to(mask, (b, s + pad))
+    if weights is None:
+        mask = (jnp.arange(s + pad) < s).astype(jnp.float32)
+        mask = jnp.broadcast_to(mask, (b, s + pad))
+    else:
+        # the padding's weight is 0, as its mask was
+        mask = jnp.pad(weights.astype(jnp.float32), ((0, 0), (0, pad)))
     n_chunks = (s + pad) // chunk
-    _count_trace(chunk, vocab, "value_and_grads" if with_grads else "value",
-                 n_chunks)
+    _count_trace(chunk, vocab,
+                 ("value_and_grads" if with_grads else "value")
+                 + "_weighted" * (weights is not None), n_chunks)
 
     # [n_chunks, B, chunk, ...] scan layout
     hs = jnp.moveaxis(hidden.reshape(b, n_chunks, chunk, d), 1, 0)
@@ -122,26 +130,31 @@ def _walk(hidden, emb, targets, chunk, with_grads):
     return loss, dhidden, demb.astype(emb.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _chunked_loss(hidden, emb, targets, chunk):
-    return _walk(hidden, emb, targets, chunk, with_grads=False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _chunked_loss(hidden, emb, targets, weights, chunk):
+    return _walk(hidden, emb, targets, weights, chunk, with_grads=False)[0]
 
 
-def _chunked_loss_fwd(hidden, emb, targets, chunk):
-    loss, dhidden, demb = _walk(hidden, emb, targets, chunk, with_grads=True)
-    return loss, (dhidden, demb)
+def _chunked_loss_fwd(hidden, emb, targets, weights, chunk):
+    loss, dhidden, demb = _walk(hidden, emb, targets, weights, chunk,
+                                with_grads=True)
+    # (the weights are data: no gradient is made for them, and a zero of
+    # their shape is what the rule hands back)
+    return loss, (dhidden, demb, weights)
 
 
 def _chunked_loss_bwd(chunk, grads, g):
-    dhidden, demb = grads
+    dhidden, demb, weights = grads
     return ((g * dhidden).astype(dhidden.dtype),
-            (g * demb).astype(demb.dtype), None)
+            (g * demb).astype(demb.dtype), None,
+            None if weights is None else jnp.zeros_like(weights))
 
 
 _chunked_loss.defvjp(_chunked_loss_fwd, _chunked_loss_bwd)
 
 
-def softmax_cross_entropy_fused(hidden, emb, targets, *, chunk=128):
+def softmax_cross_entropy_fused(hidden, emb, targets, *, chunk=128,
+                                weights=None):
     """Mean token cross-entropy of ``hidden @ emb.T`` against ``targets``.
 
     Args:
@@ -153,6 +166,13 @@ def softmax_cross_entropy_fused(hidden, emb, targets, *, chunk=128):
         [batch, chunk, vocab]. Sequences that are not a chunk multiple
         are zero-padded and masked — the chunk size (and therefore the
         memory bound and MXU tile shape) is honored for ANY seq.
+      weights: None, or [batch, seq] floats: position ``i``'s
+        cross-entropy times ``weights[i]`` in the sum, **which is divided
+        by the number of positions, batch x seq, and not by the weights'
+        sum** (a block-diffusion loss: ``m_i / t`` over the masked
+        positions, 0 elsewhere, ``models.diffusion.noise_blocks``). Data:
+        no gradient flows to them. ``None`` traces what a call without the
+        argument traced.
 
     Returns the scalar mean loss over all tokens. Differentiable once, in
     reverse mode, w.r.t. ``hidden`` and ``emb``; gradients match the
@@ -166,4 +186,9 @@ def softmax_cross_entropy_fused(hidden, emb, targets, *, chunk=128):
     """
     if chunk < 1:
         raise ValueError(f"chunk must be >= 1, got {chunk}")
-    return _chunked_loss(hidden, emb, targets, min(chunk, hidden.shape[1]))
+    if weights is not None and weights.shape != targets.shape:
+        raise ValueError(
+            f"weights {weights.shape} are one a position, as targets "
+            f"{targets.shape}")
+    return _chunked_loss(hidden, emb, targets, weights,
+                         min(chunk, hidden.shape[1]))

@@ -859,6 +859,42 @@ def test_flash_kernels_with_a_window_compile_for_v5e(window, tiles,
     assert "hvt_flash_fwd" in text and "hvt_flash_bwd" in text
 
 
+@pytest.mark.parametrize("strict", [False, True],
+                         ids=["clean-rows", "noised-rows-strict"])
+def test_flash_kernels_with_blocks_compile_for_v5e(strict, compiled_kernel,
+                                                   v5e_devices):
+    """``sdar-s8192``'s two calls a layer: a copy's 8,192 positions, 32
+    query heads on 4 key-value heads of 128, the causal limit by blocks of
+    4, inclusive (the clean rows) and strict with the log-sum-exp and a
+    float32 output (the noised rows on the clean keys): forward and
+    backward, one Pallas call each, on the causal call's grids (two tiles
+    of 4,096: the schedule is the causal one)."""
+    like, z = _dsa_like(v5e_devices[0]), {**DSA_SHAPE, "s": 8192}
+    q = like(jnp.bfloat16, z["b"], z["s"], z["h"], z["d"])
+    kv = like(jnp.bfloat16, z["b"], z["s"], z["h_kv"], z["d"])
+
+    def step(**mask):
+        def loss(q, k, v):
+            o, lse = fa.flash_attention_with_lse(
+                q, k, v, **mask,
+                **({"out_dtype": jnp.float32} if strict else {}))
+            return ((o.astype(jnp.float32) ** 2).mean()
+                    + jnp.where(jnp.isfinite(lse), lse, 0.0).mean() * strict)
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+
+    from test_flash_window import _grids
+
+    assert _grids(step(), q, kv, kv) == [(1, 32, 16, 2), (1, 32, 16, 2)]
+    assert _grids(step(blocks=4, strict=strict), q, kv, kv) == [
+        (1, 32, 16, 2), (1, 32, 16, 2)]
+    text = step(blocks=4, strict=strict).lower(q, kv, kv).compile().as_text()
+    calls = re.findall(r" custom-call\((.*?)\), custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert len(calls) == 2
+    assert "hvt_flash_fwd" in text and "hvt_flash_bwd" in text
+
+
 @pytest.mark.parametrize("rows", [512, 16384])
 def test_choice_of_2048_keys_compiles_for_v5e(rows, compiled_kernel,
                                               v5e_devices):

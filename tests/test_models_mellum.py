@@ -421,7 +421,7 @@ def test_the_step_names_attn_rope_in_both_kinds_and_the_counter_the_law():
         m = metrics.registry().get("hvt_attn_layers_traced_total")
         return m.labels(heads="4", kv_heads="2", head_dim="16",
                         core="einsum", window=str(window),
-                        rotary=rotary).value if m else 0.0
+                        rotary=rotary, blocks="0").value if m else 0.0
 
     labels = [(_WINDOW, "plain"), (0, "yarn"), (0, "none"), (0, "plain")]
     before = [counted(*label) for label in labels]

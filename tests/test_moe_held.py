@@ -442,7 +442,34 @@ def test_pieces_are_the_one_pass_movement(assigned, monkeypatch):
                        for leaf in jax.tree.leaves(got))
 
 
-@pytest.mark.parametrize("crowd", [None, 2], ids=["even", "one-share-full"])
+def _eight_shares_of_16_of_128():
+    """``sdar-30b-a3b``'s cut at small widths: a softmax router 128 wide, 8
+    a token, renormalised over the chosen, SwiGLU experts, no shared expert;
+    the eight shares of 16 add up to the layer that holds all 128 (the
+    output: the gradient of the input is read on the latent layer's four
+    shares)."""
+    layer = lambda held: MoEMlp(128, 6, 8, dtype=jnp.float32, held=held,
+                                renormalise=True)
+    h = jax.random.normal(jax.random.key(0), (48, 16))
+    params = jax.jit(layer(None).init)(jax.random.key(1), h)["params"]
+    assert moe.held_rows(48, 8, (16, 16), 128) == (4, 2 * 48)
+    every = lambda h: layer(None).apply({"params": params}, h)[0]
+
+    def total(h):
+        parts = 0.0
+        for first in range(0, 128, 16):
+            mine = {**params, **{name: params[name][first:first + 16]
+                                 for name in ("gate", "up", "down")}}
+            parts = parts + layer((first, 16)).apply({"params": mine}, h)[0]
+        return parts
+
+    _close(jax.jit(total)(h), jax.jit(every)(h),
+           "sum of the eight shares against every expert held")
+
+
+@pytest.mark.parametrize("crowd", [None, 2, "eight-shares-of-16-of-128"],
+                         ids=["even", "one-share-full",
+                              "softmax-eight-shares-of-16-of-128"])
 def test_the_shares_of_the_experts_add_up(crowd):
     """Four chips, two experts each, of a layer of eight: the held
     experts' parts, each through the latent up-projection (linear, no
@@ -451,7 +478,11 @@ def test_the_shares_of_the_experts_add_up(crowd):
     expert (and the uncut reference's), in output and in the gradient of
     the input. ``one-share-full``: every token chooses both experts of the
     first share, which then works through two full rounds while the
-    others share what is left."""
+    others share what is left. And, another layer (``sdar-30b-a3b``'s):
+    eight shares of 16 of 128 under a softmax router renormalised over 8 a
+    token (``_eight_shares_of_16_of_128``)."""
+    if isinstance(crowd, str):
+        return _eight_shares_of_16_of_128()
     whole, params, buffers, h = _latent_layer(None)
     if crowd:
         _, params, buffers, h = _latent_layer((0, 8), crowd=crowd)
