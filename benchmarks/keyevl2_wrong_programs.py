@@ -112,6 +112,16 @@ def main():
             jnp.arange(b)[:, None, None], jnp.arange(s)[None, :, None],
             index].set(1)
 
+    def with_lse(mask_of):
+        """``ops.choose``'s pair of a wrong mask: the mask, and the scores'
+        log-sum-exp over it for the indexer's loss."""
+        def choose(scores, k):
+            mask = mask_of(scores, k)
+            return mask, jax.nn.logsumexp(
+                jnp.where(mask != 0, scores, -jnp.inf), axis=-1,
+                keepdims=True)
+        return choose
+
     @contextlib.contextmanager
     def both(*swaps):
         with contextlib.ExitStack() as stack:
@@ -123,11 +133,11 @@ def main():
     for label, wrong in (
             ("topk 1024", both((ops, "choose", lambda scores, k: right[
                 "choose"](scores, topk // 2)))),
-            ("no choice at all", both((ops, "choose", lambda scores, k: (
-                ~jnp.isneginf(scores)).astype(jnp.int8)))),
+            ("no choice at all", both((ops, "choose", with_lse(
+                lambda scores, k: (~jnp.isneginf(scores)).astype(jnp.int8))))),
             ("a choice without the causal limit", both(
                 (ops, "index_scores", every_pair),
-                (ops, "choose", best_of_the_whole_row))),
+                (ops, "choose", with_lse(best_of_the_whole_row)))),
             ("no relu in the index scores", both((
                 ops, "index_scores",
                 lambda q_i, k_i, w: causal_scores(q_i, k_i, w, relu=False)))),

@@ -23,8 +23,10 @@ parent's, ``_parent/``) it reads that checkout's ``horovod_tpu`` and
 ``chiprun_out/step_text_<checkout>.json``.
 
 ``--describe v5e:2x2`` lowers (and compiles) for a chip that is described
-and not attached, with the backend's name steered to ``tpu`` from here so
-that every rule takes its TPU branch: a rehearsal, not the chip's word.
+and not attached (with ``--compile`` also ``step_bytes``, the cell's
+``hbm_step`` to the byte: ``keyevl2-s16384``'s 11.5908 GiB at PR 64),
+with the backend's name steered to ``tpu`` from here so that every rule
+takes its TPU branch: a rehearsal, not the chip's word.
 
 A builder's script: it decides nothing.
 """
@@ -141,6 +143,11 @@ def main(argv=None):
             here["compiled"], _ = digest(compiled.as_text())
             analysis = compiled.memory_analysis()
             here["temp_bytes"] = analysis.temp_size_in_bytes
+            # what ``chipbench.run`` reports as ``hbm_step``
+            here["step_bytes"] = (
+                analysis.argument_size_in_bytes
+                + analysis.output_size_in_bytes + analysis.temp_size_in_bytes
+                - analysis.alias_size_in_bytes)
             here["compile_s"] = round(time.perf_counter() - t0, 1)
         print(json.dumps({"cell": name, **here}), flush=True)
     tree = os.path.basename(os.getcwd())

@@ -28,12 +28,14 @@ them:
 3. ``dsa_select``: ``S_t``, every ``u <= t`` while ``t < topk`` and from
    there on the ``topk`` causal keys of the largest ``I(t, u)``, equal
    scores to the lower ``u``; handed on as a mask ``[b, s, s]`` int8 made
-   once a layer and read by every head (``choose``). Under ``remat`` the
-   mask (``KEPT_CHOICE``: the choice has no gradient, so it is not made
-   twice) and step 5's four gradients (``KEPT_INDEX_GRADS``) are kept for
-   the backward pass and nothing else of steps 2, 3 and 5, **which
-   therefore run once a layer and step**: the recomputed layer holds no
-   indexer, no index scores, no choice and no loss.
+   once a layer and read by every head (``choose``), and beside it ``I``'s
+   log-sum-exp over ``S_t`` ``[b, s, 1]`` for step 5, made in the same
+   pass over the scores. Under ``remat`` the mask (``KEPT_CHOICE``: the
+   choice has no gradient, so it is not made twice) and step 5's four
+   gradients (``KEPT_INDEX_GRADS``) are kept for the backward pass and
+   nothing else of steps 2, 3 and 5, **which therefore run once a layer
+   and step**: the recomputed layer holds no indexer, no index scores, no
+   choice and no loss.
 4. ``dsa_core``: ``score_h(t, u) = q_h(t) . k_{h // g}(u) head_dim^-1/2``
    **for ``u`` in ``S_t`` only**, softmax in float32 over ``S_t``, ``o_h =
    sum p v``. Where ``resolve_flash`` says so, ``ops/flash_attention.py``'s
@@ -42,7 +44,8 @@ them:
 5. ``dsa_target``: the indexer's loss ``L_I = mean_t sum_{u in S_t} pbar
    (log pbar - log r)``, ``pbar`` the main attention's probabilities
    averaged over the heads (detached), ``r`` the softmax over ``S_t`` of
-   ``I`` (``index_loss``). ``L_I`` reaches the indexer's four leaves and
+   ``I`` (``index_loss``, from the indexer's parts and step 3's
+   log-sum-exp). ``L_I`` reaches the indexer's four leaves and
    nothing else, on a detached input, so its whole gradient is known here:
    the loss is made **with its gradients by the three parts** (one kernel
    call, or ``jax.value_and_grad`` of the plain body), step 2's pullback
@@ -219,14 +222,28 @@ class SparseAttention(nn.Module):
             scores = (ops.index_scores if kernels
                       else ops.index_scores_plain)(*parts)
         with jax.named_scope("dsa_select"):
-            choice = checkpoint_name(
-                (ops.choose if kernels else ops.choose_plain)(
-                    scores, self.topk), KEPT_CHOICE)
+            choice, lse_i = (ops.choose if kernels else ops.choose_plain)(
+                scores, self.topk)
+            choice = checkpoint_name(choice, KEPT_CHOICE)
+            if kernels:
+                # Placement, not arithmetic. With nothing of XLA's own
+                # between the choice and the loss, the compiled step of
+                # the published size takes 12.538 GiB where the parent's
+                # took 11.591 (compiled for a described v5e: the same
+                # kernels in the same order, other fusions around the
+                # experts and more of their buffers alive at once); with a
+                # ``logsumexp`` of XLA's in that place, as the parent had
+                # over the whole ``[s, s]``, it takes 11.523, over 128
+                # columns as well as over all (8 MiB read a layer where
+                # the parent's two passes read 2.5 GiB). What else was
+                # compiled, and that why is open: PERF.md, PR 65.
+                lse_i = lse_i + 0.0 * jax.nn.logsumexp(
+                    scores[..., :ops.LANES], axis=-1, keepdims=True)
         with jax.named_scope("dsa_core"):
             out, lse = chosen_attention(q, k, v, choice, scale, kernels)
         with jax.named_scope("dsa_target"):
             if kernels:
-                kl, by_parts = ops.index_loss(q, k, lse, *parts, scores,
+                kl, by_parts = ops.index_loss(q, k, lse, *parts, lse_i,
                                               choice, scale)
             else:
                 kl, by_parts = jax.value_and_grad(

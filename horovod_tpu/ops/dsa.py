@@ -13,18 +13,27 @@ is filled without a product.
 ``choose``: of every row of those scores the mask ``[b, s, s]`` int8 of
 ``S_t``: the ``topk`` largest of the causal keys, **equal scores to the
 lower position** (``jax.lax.top_k``'s order), every causal key where there
-are no more than ``topk``. ``jax.lax.top_k`` sorts every row, and the
-router's walk of ``ops/kth_largest.py`` takes ``k`` passes; at 2,048 of
-16,384 neither will do. The kernel finds the ``topk``-th largest **by
-bisection on the scores' bit patterns**: a float32's bits, the negative
-ones' order turned, compare as integers as the numbers do, so 32 passes of
-"how many keys of the row are at or above this pattern", each a comparison
-and a count over a block of rows that never leaves VMEM, build the
-threshold bit by bit, whatever ``topk`` is. Exact: comparisons and counts
-alone, no arithmetic on a score. The keys above the threshold are chosen,
-and of those equal to it the first that are still needed, by a running
-count along the row. The causal limit is inside: a key above the diagonal
-takes the least pattern and is never handed on.
+are no more than ``topk``; **and beside it ``lse_i [b, s, 1]``, the
+scores' log-sum-exp over ``S_t``**, which the indexer's loss needs and
+which is there for the taking while the scores are held. ``jax.lax.top_k``
+sorts every row, and the router's walk of ``ops/kth_largest.py`` takes
+``k`` passes; at 2,048 of 16,384 neither will do. The kernel finds the
+``topk``-th largest **by bisection on the scores' bit patterns**: a
+float32's bits, the negative ones' order turned, compare as integers as
+the numbers do, so 32 passes of "how many keys of the row are at or above
+this pattern", each a comparison and a count **over the causal columns of
+a block of rows** that never leaves VMEM, build the threshold bit by bit,
+whatever ``topk`` is. A block of rows ``[t0, t0 + rows)`` has causal keys
+in the columns below ``t0 + rows`` alone: every pass is a loop over those
+columns in whole chunks, so a sequence's passes walk half of what lies
+in VMEM (``hvt_dsa_kernel_traces_total``'s ``columns``), and the mask past
+them is written as zeros. Exact: comparisons and counts alone, no
+arithmetic on a score. The keys above the threshold are chosen, and of
+those equal to it the first that are still needed, by a running count
+along the row; the same walk adds ``exp(score - maximum)`` of the chosen
+in float32, the maximum being the row's largest causal score, which is
+always chosen. The causal limit is inside: a key above the diagonal takes
+the least pattern and is never handed on.
 
 ``index_loss``: the KL term that trains the indexer, ``mean_t sum_{u in
 S_t} pbar (log pbar - log r)`` with ``pbar`` the main attention's
@@ -61,21 +70,25 @@ from horovod_tpu.ops._pallas import NN, NT, TN, dot
 
 LANES = 128
 _INT_MIN = -2 ** 31
-# Score tiles of the index-score kernel and of the loss's, and rows of
-# scores a step of the choice holds ([rows, s] float32 in, the patterns
-# beside them, int8 out).
+# Score tiles of the index-score kernel and of the loss's, rows of scores a
+# step of the choice holds ([rows, s] float32 in, the patterns beside them,
+# int8 out), and the columns of them one turn of a pass's loop takes (a
+# turn's 64 vregs beside 8 of counts and 8 of the trial level: nothing
+# spills, and the compare, convert and add fill the vector unit's slots).
 INDEX_TILE = (256, 1024)
 LOSS_TILE = (256, 512)
-CHOICE_ROWS = 128
+CHOICE_ROWS = 64
+CHOICE_CHUNK = 1024
 _XLA_VMEM = 3 * 2 ** 20
 
 
-def _count_trace(kernel, **labels):
+def _count_trace(kernel, columns=0, **labels):
+    """``columns``: the choice's alone, ``_walked``."""
     _pallas.count_trace(
         "hvt_dsa_kernel_traces_total",
         "sparse-attention indexer kernels (index scores, choice, indexer "
         "loss) traced into compiled programs (counted per trace, not per "
-        "execution)", kernel=kernel, **labels)
+        "execution)", kernel=kernel, **labels, columns=columns)
 
 
 def _causal(q0, k0, shape):
@@ -151,83 +164,152 @@ def index_scores(q_i, k_i, w):
 
 def choose_plain(scores, topk: int):
     """``scores [b, s, s]`` (what is above the diagonal is not read) ->
-    ``[b, s, s]`` int8, 1 at the keys of ``S_t``. The ``topk``-th largest
-    is ``jax.lax.top_k``'s last value; the keys equal to it are taken from
-    the lowest position up until ``topk`` are chosen."""
+    ``(choice [b, s, s] int8, lse_i [b, s, 1] float32)``: 1 at the keys of
+    ``S_t``, and the scores' log-sum-exp over them. The ``topk``-th largest
+    is ``jax.lax.top_k``'s last value (its first the log-sum-exp's
+    maximum); the keys equal to it are taken from the lowest position up
+    until ``topk`` are chosen."""
     s = scores.shape[-1]
     causal = _causal(0, 0, (s, s))
     scores = jnp.where(causal, scores.astype(jnp.float32), -jnp.inf)
     k = min(topk, s)
-    kth = jax.lax.top_k(scores, k)[0][..., -1:]
+    best = jax.lax.top_k(scores, k)[0]
+    most, kth = best[..., :1], best[..., -1:]
     above, level = scores > kth, scores == kth
     needed = k - jnp.sum(above, -1, keepdims=True)
-    chosen = above | (level & (jnp.cumsum(level, -1) <= needed))
-    return (chosen & causal).astype(jnp.int8)
+    chosen = (above | (level & (jnp.cumsum(level, -1) <= needed))) & causal
+    # the row's largest score is always chosen: the log-sum-exp's maximum
+    lse_i = most + jnp.log(jnp.sum(
+        jnp.where(chosen, jnp.exp(scores - most), 0.0), -1, keepdims=True))
+    return chosen.astype(jnp.int8), lse_i
 
 
-def _choice_kernel(x_ref, o_ref, keys_ref, *, topk, seq):
+def _walked(seq, rows, chunk):
+    """Column tiles of 128 that one of the choice's passes walks in a
+    sequence, summed over its row blocks: block ``i`` goes by the columns
+    below its diagonal in whole chunks, ``[0, hi)``. Walking every column
+    is ``seq // rows * seq // LANES``."""
+    return sum(-(-(t0 + rows) // chunk) * chunk // LANES
+               for t0 in range(0, seq, rows))
+
+
+def _choice_kernel(x_ref, o_ref, lse_ref, keys_ref, *, topk, seq, chunk):
     rows = x_ref.shape[0]
-    t = (pl.program_id(0) * rows
-         + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)) % seq
-    u = jax.lax.broadcasted_iota(jnp.int32, (rows, seq), 1)
-    bits = pltpu.bitcast(x_ref[...], jnp.int32)
-    bits = jnp.where(bits == _INT_MIN, 0, bits)     # -0.0 is 0.0
-    # a float32's bits ordered as the numbers are: the negative ones'
-    # order turned; a key above the diagonal below them all
-    keys_ref[...] = jnp.where(
-        u <= t, jnp.where(bits < 0, bits ^ 0x7fffffff, bits), _INT_MIN)
+    # the block's rows are positions [t0, t0 + rows) of one sequence, so
+    # its causal keys lie in the columns [0, hi), hi = chunks * chunk.
+    # Nothing past them is read.
+    t0 = (pl.program_id(0) * rows) % seq
+    t = t0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    chunks = (t0 + rows + chunk - 1) // chunk
 
-    def reaching(level):
-        return jnp.sum((keys_ref[...] >= level).astype(jnp.int32), axis=1,
-                       keepdims=True)
+    def over(body, init):
+        """One pass over the columns [0, hi), a chunk at a time: ``body``
+        of the chunk's first column and of what the pass carries."""
+        return jax.lax.fori_loop(0, chunks, lambda c, carry: body(
+            pl.multiple_of(c * chunk, chunk), carry), init)
+
+    def by_lane(x, op):
+        # a chunk's lane tiles onto one: whole vregs, nothing crosses lanes
+        return functools.reduce(
+            op, [x[:, at:at + LANES] for at in range(0, chunk, LANES)])
+
+    def pattern(u0, most):
+        at = pl.ds(u0, chunk)
+        x = x_ref[:, at]
+        seen = u0 + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) <= t
+        bits = pltpu.bitcast(x, jnp.int32)
+        bits = jnp.where(bits == _INT_MIN, 0, bits)     # -0.0 is 0.0
+        # a float32's bits ordered as the numbers are: the negative ones'
+        # order turned; a key above the diagonal below them all
+        keys_ref[:, at] = jnp.where(
+            seen, jnp.where(bits < 0, bits ^ 0x7fffffff, bits), _INT_MIN)
+        # the row's largest causal score is always chosen: the maximum of
+        # the log-sum-exp over the chosen keys
+        return jnp.maximum(most, by_lane(jnp.where(seen, x, -jnp.inf),
+                                         jnp.maximum))
+
+    most = jnp.max(over(pattern, jnp.full((rows, LANES), -jnp.inf,
+                                          jnp.float32)),
+                   axis=1, keepdims=True)
+    most = jnp.where(jnp.abs(most) == jnp.inf, 0.0, most)
+
+    def count(reaches):
+        """How many keys of each row ``reaches`` holds of: added lane by
+        lane, summed across the lanes once a pass (float32 holds a count
+        of columns exactly)."""
+        each = over(lambda u0, acc: acc + by_lane(
+            reaches(keys_ref[:, pl.ds(u0, chunk)]).astype(jnp.int32),
+            jnp.add), jnp.zeros((rows, LANES), jnp.int32))
+        return jnp.sum(each.astype(jnp.float32), axis=1, keepdims=True)
 
     def bit(n, kth):
         # the next bit from the top: kept where topk keys still reach it
         trial = kth ^ jnp.left_shift(jnp.int32(1), 31 - n)
-        return jnp.where(reaching(trial) >= topk, trial, kth)
+        return jnp.where(count(lambda keys: keys >= trial) >= topk, trial,
+                         kth)
 
     kth = jax.lax.fori_loop(0, 32, bit,
                             jnp.full((rows, 1), _INT_MIN, jnp.int32))
-    needed = (topk - jnp.sum((keys_ref[...] > kth).astype(jnp.int32), axis=1,
-                             keepdims=True)).astype(jnp.float32)
+    needed = topk - count(lambda keys: keys > kth)
     upto = (jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
             <= jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
             ).astype(jnp.bfloat16)
 
-    def lanes(c, before):
+    def walk(u0, carry):
         # of the keys equal to the topk-th, the first still needed: a
         # count along the row, 128 lanes at a time (0 and 1 summed on the
-        # MXU in float32: exact)
-        at = pl.ds(pl.multiple_of(c * LANES, LANES), LANES)
-        keys = keys_ref[:, at]
-        level = keys == kth
-        count = before + dot(level.astype(jnp.bfloat16), upto, NN)
-        u = c * LANES + jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
-        chosen = ((keys > kth) | (level & (count <= needed))) & (u <= t)
-        o_ref[:, at] = jnp.where(chosen, 1, 0).astype(jnp.int8)
-        return count[:, LANES - 1:]
+        # MXU in float32: exact; a chunk's products and their last
+        # columns wait for nothing, only the sums go from tile to tile);
+        # and exp(score - maximum) of the chosen, added lane by lane
+        before, total = carry
+        for u in range(0, chunk, LANES):
+            at = pl.ds(u0 + u, LANES)
+            keys = keys_ref[:, at]
+            level = keys == kth
+            within = dot(level.astype(jnp.bfloat16), upto, NN)
+            seen = u0 + u + jax.lax.broadcasted_iota(
+                jnp.int32, keys.shape, 1) <= t
+            chosen = ((keys > kth)
+                      | (level & (before + within <= needed))) & seen
+            o_ref[:, at] = jnp.where(chosen, 1, 0).astype(jnp.int8)
+            before = before + within[:, LANES - 1:]
+            total = total + jnp.where(
+                chosen, jnp.exp(x_ref[:, at] - most), 0.0)
+        return before, total
 
-    jax.lax.fori_loop(0, seq // LANES, lanes,
-                      jnp.zeros((rows, 1), jnp.float32))
+    _, total = over(walk, (jnp.zeros((rows, 1), jnp.float32),
+                           jnp.zeros((rows, LANES), jnp.float32)))
+    lse_ref[...] = most + jnp.log(jnp.sum(total, axis=1, keepdims=True))
+
+    def above(c, _):
+        o_ref[:, pl.ds(pl.multiple_of(c * chunk, chunk), chunk)] = jnp.zeros(
+            (rows, chunk), jnp.int8)
+
+    jax.lax.fori_loop(chunks, seq // chunk, above, None)
 
 
 @functools.partial(jax.jit, static_argnames=("topk", "interpret"))
 def _choice_call(scores, *, topk, interpret):
     """``scores [n, s]``: rows of queries against ``s`` keys, row ``i`` the
-    query at position ``i % s`` (whole sequences one after the other)."""
+    query at position ``i % s`` (whole sequences one after the other).
+    The mask ``[n, s]`` int8 and the log-sum-exp over it ``[n, 1]``."""
     n, s = scores.shape
-    rows = _pallas.largest(n, CHOICE_ROWS, 32)
-    _count_trace("choice", heads=0, width=0, seq=s, topk=topk)
-    # the scores and the mask double-buffered, the patterns, and the
-    # block-wide values the first pass makes of them (the compiler asked
-    # for 36.6 MiB at 128 rows of 16,384, the buffers being 28)
-    limit = rows * s * (2 * 4 + 4 + 2 * 1 + 8) + _XLA_VMEM
+    rows = _pallas.largest(s, CHOICE_ROWS, 32)
+    chunk = _pallas.largest(s, CHOICE_CHUNK, LANES)
+    _count_trace("choice", heads=0, width=0, seq=s, topk=topk,
+                 columns=_walked(s, rows, chunk))
+    # the scores and the mask double-buffered, the patterns, and a few
+    # chunks' values of a pass
+    limit = rows * s * (2 * 4 + 4 + 2 * 1) + 16 * rows * chunk * 4 + _XLA_VMEM
     return pl.pallas_call(
-        functools.partial(_choice_kernel, topk=min(topk, s), seq=s),
+        functools.partial(_choice_kernel, topk=min(topk, s), seq=s,
+                          chunk=chunk),
         grid=(n // rows,),
         in_specs=[pl.BlockSpec((rows, s), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((rows, s), lambda i: (i, 0)),
-        out_shape=_pallas.out((n, s), jnp.int8, scores),
+        out_specs=[pl.BlockSpec((rows, s), lambda i: (i, 0)),
+                   pl.BlockSpec((rows, 1), lambda i: (i, 0))],
+        out_shape=[_pallas.out((n, s), jnp.int8, scores),
+                   _pallas.out((n, 1), jnp.float32, scores)],
         scratch_shapes=[pltpu.VMEM((rows, s), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
@@ -239,9 +321,10 @@ def choose(scores, topk: int):
     """``choose_plain`` through the kernel, for float32 scores of a
     sequence in whole 128-lane tiles. No gradient."""
     b, s, _ = scores.shape
-    return _choice_call(
+    choice, lse_i = _choice_call(
         scores.astype(jnp.float32).reshape(b * s, s), topk=int(topk),
-        interpret=_pallas.interpret()).reshape(b, s, s)
+        interpret=_pallas.interpret())
+    return choice.reshape(b, s, s), lse_i.reshape(b, s, 1)
 
 
 # ------------------------------------------------------- the indexer's loss
@@ -371,18 +454,17 @@ def _loss_call(q, k, lse, q_i, k_i, w, lse_i, choice, *, scale, interpret):
         interpret=interpret, name="hvt_dsa_loss")(*operands)
 
 
-def index_loss(q, k, lse, q_i, k_i, w, scores, choice, scale):
+def index_loss(q, k, lse, q_i, k_i, w, lse_i, choice, scale):
     """``(value, (dq_i, dk_i, dw))``: ``index_loss_plain`` through the
     kernel, and the value's gradients by ``q_i``, ``k_i`` and ``w``, in
     their types, which the same call makes (``jax.value_and_grad`` of the
     plain body, ``argnums=(3, 4, 5)``). Every operand is detached, so the
     pair has no derivative of its own: ``with_gradient`` ties gradients
-    to a value. ``scores`` are ``index_scores`` of the same parts (they
-    give the indexer's log-sum-exp over ``S_t``)."""
-    q, k, lse, q_i, k_i, w, scores = jax.lax.stop_gradient(
-        (q, k, lse, q_i, k_i, w, scores))
-    lse_i = jax.nn.logsumexp(jnp.where(choice != 0, scores, -jnp.inf),
-                             axis=-1, keepdims=True)
+    to a value. ``lse_i [b, s, 1]`` is the indexer's log-sum-exp over
+    ``S_t``, which the choice of the same parts' ``index_scores`` hands on
+    beside ``choice`` (``choose``)."""
+    q, k, lse, q_i, k_i, w, lse_i = jax.lax.stop_gradient(
+        (q, k, lse, q_i, k_i, w, lse_i))
     kl, dq, dw, dk = _loss_call(
         _to_bhsd(q), _to_bhsd(k), lse.astype(jnp.float32), _to_bhsd(q_i),
         k_i, w.astype(jnp.float32), lse_i, choice, scale=float(scale),

@@ -927,15 +927,15 @@ def test_index_scores_and_indexer_loss_compile_for_v5e(compiled_kernel,
     assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) == 1
     assert "hvt_dsa_index" in text
 
-    def loss(q, k, lse, q_i, k_i, w, scores, choice):
-        return dsa.index_loss(q, k, lse, q_i, k_i, w, scores, choice,
+    def loss(q, k, lse, q_i, k_i, w, lse_i, choice):
+        return dsa.index_loss(q, k, lse, q_i, k_i, w, lse_i, choice,
                               z["d"] ** -0.5)
 
     operands = (
         like(jnp.bfloat16, z["b"], z["s"], z["h"], z["d"]),
         like(jnp.bfloat16, z["b"], z["s"], z["h_kv"], z["d"]),
         like(jnp.float32, z["b"], z["s"], z["h"]), q_i, k_i, w,
-        like(jnp.float32, z["b"], z["s"], z["s"]),
+        like(jnp.float32, z["b"], z["s"], 1),
         like(jnp.int8, z["b"], z["s"], z["s"]))
     compiled = jax.jit(loss).lower(*operands).compile()
     text = compiled.as_text()

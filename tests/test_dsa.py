@@ -50,36 +50,95 @@ def test_index_scores_kernel_is_the_plain_body(parts):
     assert float(want[1, t, u]) == pytest.approx(by_hand, abs=1e-5)
 
 
-@pytest.mark.parametrize("topk", [40, 256, 300, 1])
-@pytest.mark.parametrize("ties", ["planted", "none", "all_equal"])
-def test_choice_is_top_k_with_ties_to_the_lower_position(parts, topk, ties):
-    """Rows with planted ties (scores rounded to halves, zeros of both
-    signs among them), a causal limit, ``topk`` below the row's length, at
-    it and above it: the plain body and the kernel are ``top_k``'s set,
-    every row holds ``min(t + 1, topk)`` keys and none above ``t``."""
-    scores = dsa.index_scores_plain(parts["q_i"], parts["k_i"], parts["w"])
-    if ties == "planted":
+@pytest.fixture
+def chunks_of_256():
+    """The choice's passes in chunks of 256 columns, so that 768 positions
+    are three of them and a row block's ``hi`` is rarely a whole one."""
+    saved, dsa.CHOICE_CHUNK = dsa.CHOICE_CHUNK, 256
+    dsa._choice_call.clear_cache()
+    yield 256
+    dsa.CHOICE_CHUNK = saved
+    dsa._choice_call.clear_cache()
+
+
+def _scores(parts, kind, seq):
+    """Index scores ``[B, seq, seq]``, ``-inf`` above the diagonal."""
+    if seq == S:
+        scores = dsa.index_scores_plain(parts["q_i"], parts["k_i"],
+                                        parts["w"])
+    else:
+        scores = jnp.where(
+            np.tril(np.ones((seq, seq), bool)),
+            jax.random.normal(jax.random.key(seq), (B, seq, seq)), -jnp.inf)
+    if kind == "planted":       # halves, zeros of both signs among them
         scores = jnp.round(scores * 2) / 2
         assert bool(jnp.any((scores == 0) & jnp.signbit(scores)))
-    elif ties == "all_equal":
+    elif kind == "all_equal":
         scores = jnp.zeros_like(scores)
+    elif kind.startswith("ties_over_a_chunk_boundary"):
+        # 38 distinct scores above four equal ones that lie on both sides
+        # of column 256, everything else below: of the four, the first
+        # two (columns 254, 255) or three (and 256) are still needed
+        row = np.full(seq, -1.0, np.float32)
+        row[:38] = 10.0 + np.arange(38)
+        row[254:258] = 5.0
+        scores = jnp.where(jnp.isneginf(scores), scores, jnp.asarray(row))
+    return scores
+
+
+@pytest.mark.parametrize("kind, seq, topk", [
+    *((kind, S, topk) for kind in ("planted", "none", "all_equal")
+      for topk in (40, 256, 300, 1)),
+    # three chunks of columns, twelve row blocks a sequence, two sequences
+    ("planted", 768, 300), ("none", 768, 300), ("all_equal", 768, 300),
+    ("none", 768, 1), ("none", 768, 768),
+    ("ties_over_a_chunk_boundary_2", 768, 40),
+    ("ties_over_a_chunk_boundary_3", 768, 41),
+    # the module's own chunk, two of them
+    ("planted", 2 * dsa.CHOICE_CHUNK, 300)])
+def test_choice_is_top_k_with_ties_to_the_lower_position(
+        parts, kind, seq, topk, request):
+    """Rows with planted ties (scores rounded to halves, zeros of both
+    signs among them), a causal limit, ``topk`` below the row's length, at
+    it and above it, one chunk of columns and several with equal scores on
+    both sides of a chunk's edge: the plain body and the kernel are
+    ``top_k``'s set, every row holds ``min(t + 1, topk)`` keys and none
+    above ``t``, and the second result is the scores' log-sum-exp over the
+    chosen keys, of one key the score itself."""
+    if seq == 768:
+        request.getfixturevalue("chunks_of_256")
+    scores = _scores(parts, kind, seq)
     want = _top_k_mask(jnp.where(scores == 0, 0.0, scores), topk)
-    plain = np.asarray(dsa.choose_plain(scores, topk))
-    kernel = np.asarray(dsa.choose(scores, topk))
+    plain, plain_lse = dsa.choose_plain(scores, topk)
+    kernel, kernel_lse = dsa.choose(scores, topk)
+    plain, kernel = np.asarray(plain), np.asarray(kernel)
     assert plain.dtype == kernel.dtype == np.int8
     assert np.array_equal(plain != 0, want)
     assert np.array_equal(kernel, plain)
-    counts = np.minimum(np.arange(S) + 1, topk)
-    assert np.array_equal(kernel.sum(-1), np.broadcast_to(counts, (B, S)))
-    assert not kernel[:, ~np.tril(np.ones((S, S), bool))].any()
+    counts = np.minimum(np.arange(seq) + 1, topk)
+    assert np.array_equal(kernel.sum(-1), np.broadcast_to(counts, (B, seq)))
+    assert not kernel[:, ~np.tril(np.ones((seq, seq), bool))].any()
+    if kind == "ties_over_a_chunk_boundary_2":
+        assert list(kernel[1, -1, 254:258]) == [1, 1, 0, 0]
+    if kind == "ties_over_a_chunk_boundary_3":
+        assert list(kernel[1, -1, 254:258]) == [1, 1, 1, 0]
+    lse = jax.nn.logsumexp(jnp.where(plain != 0, scores, -jnp.inf), axis=-1,
+                           keepdims=True)
+    for got in (plain_lse, kernel_lse):
+        assert got.shape == (B, seq, 1) and got.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(got), np.asarray(lse),
+                                   rtol=1e-6, atol=1e-6)
+        assert np.array_equal(np.asarray(got[:, 0, 0]),
+                              np.asarray(scores[:, 0, 0]))
 
 
-def test_choice_reads_nothing_above_the_diagonal(parts):
+@pytest.mark.parametrize("planted", [1e9, np.inf, np.nan])
+def test_choice_reads_nothing_above_the_diagonal(parts, planted):
     scores = dsa.index_scores_plain(parts["q_i"], parts["k_i"], parts["w"])
-    noisy = jnp.where(jnp.isneginf(scores), 1e9, scores)
+    noisy = jnp.where(jnp.isneginf(scores), planted, scores)
     for choose in (dsa.choose_plain, dsa.choose):
-        assert np.array_equal(np.asarray(choose(noisy, 40)),
-                              np.asarray(choose(scores, 40)))
+        for got, want in zip(choose(noisy, 40), choose(scores, 40)):
+            assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_index_loss_kernel_is_the_plain_body_with_its_gradients(parts):
@@ -89,14 +148,17 @@ def test_index_loss_kernel_is_the_plain_body_with_its_gradients(parts):
     cotangent."""
     scale = D ** -0.5
     scores = dsa.index_scores_plain(parts["q_i"], parts["k_i"], parts["w"])
-    choice = dsa.choose_plain(scores, 40)
+    choice, _ = dsa.choose_plain(scores, 40)
     _, lse = chosen_attention(parts["q"], parts["k"], parts["v"], choice,
                               scale, False)
     plain = lambda q, k, q_i, k_i, w: dsa.index_loss_plain(
         q, k, lse, q_i, k_i, w, choice, scale)
+    # what the mixer hands over: the choice kernel's two results
+    chosen, lse_i = dsa.choose(scores, 40)
+    assert np.array_equal(np.asarray(chosen), np.asarray(choice))
 
     def kernel(q, k, q_i, k_i, w):
-        value, grads = dsa.index_loss(q, k, lse, q_i, k_i, w, scores, choice,
+        value, grads = dsa.index_loss(q, k, lse, q_i, k_i, w, lse_i, chosen,
                                       scale)
         return 3.0 * dsa.with_gradient(value, (q_i, k_i, w), grads)
 
@@ -121,7 +183,7 @@ def test_index_loss_is_the_kl_by_hand(parts):
     keys, r the softmax of the index scores over the same keys."""
     scale = D ** -0.5
     scores = dsa.index_scores_plain(parts["q_i"], parts["k_i"], parts["w"])
-    choice = dsa.choose_plain(scores, 40)
+    choice, _ = dsa.choose_plain(scores, 40)
     _, lse = chosen_attention(parts["q"], parts["k"], parts["v"], choice,
                               scale, False)
     each = []
@@ -146,14 +208,31 @@ def test_index_loss_is_the_kl_by_hand(parts):
 def test_each_kernel_counts_its_trace_with_its_shape(parts):
     family = metrics.counter(
         "hvt_dsa_kernel_traces_total", "", ("kernel", "heads", "width", "seq",
-                                            "topk"))
+                                            "topk", "columns"))
     at = lambda **labels: family.labels(
         **{k: str(v) for k, v in labels.items()}).value
-    before = at(kernel="choice", heads=0, width=0, seq=128, topk=9)
+    # the column tiles a pass walks in a sequence: both row blocks of 64
+    # go by their one chunk of 128
+    choice = dict(kernel="choice", heads=0, width=0, seq=128, topk=9,
+                  columns=2)
+    before = at(**choice)
     dsa.choose(jnp.zeros((1, 128, 128)), 9)
-    assert at(kernel="choice", heads=0, width=0, seq=128, topk=9) \
-        == before + 1
-    before = at(kernel="index", heads=J, width=E, seq=128, topk=0)
+    assert at(**choice) == before + 1
+    index = dict(kernel="index", heads=J, width=E, seq=128, topk=0, columns=0)
+    before = at(**index)
     dsa.index_scores(parts["q_i"][:1, :128], parts["k_i"][:1, :128],
                      parts["w"][:1, :128])
-    assert at(kernel="index", heads=J, width=E, seq=128, topk=0) == before + 1
+    assert at(**index) == before + 1
+
+
+def test_the_passes_walk_the_columns_below_a_row_blocks_diagonal():
+    """``columns`` at the published length: 17,408 tiles of 128 where
+    every column of every block is 32,768, and 8,256 of 16,384 (the
+    causal share itself, 50.4%) were a chunk a tile and a block 128."""
+    rows = dsa._pallas.largest(16384, dsa.CHOICE_ROWS, 32)
+    chunk = dsa._pallas.largest(16384, dsa.CHOICE_CHUNK, dsa.LANES)
+    assert (rows, chunk) == (64, 1024)
+    assert dsa._walked(16384, rows, chunk) == 17408
+    assert 16384 // rows * 16384 // dsa.LANES == 32768
+    assert dsa._walked(16384, 128, 128) == 8256
+    assert dsa._walked(256, 64, 256) == 4 * 2
