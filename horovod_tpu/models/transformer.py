@@ -214,12 +214,15 @@ class GPTConfig:
     # head_dim, heads_held, qk_norm, head_norm, attn_gate, rotary_base,
     # rotary_fraction, use_flash, ring_mesh). "*" sees every causal key and
     # turns q and k as ``rotary`` and ``rotary_scaling`` say; "W" sees its
-    # window, always turns them plainly, and reads neither of the two nor
-    # anything else of its own. A
+    # window, turns them plainly (or not at all: ``attn_window_rotary``),
+    # and reads neither of the two nor anything else of its own. A
     # model of local layers with positions and global ones without is
     # ``rotary=False`` beside a window here. A window on the ring path is
     # refused by name.
     attn_window: int = 0
+    # False leaves the windowed layers' q and k unrotated as well (a model
+    # with no positional term in any layer: ``rotary=False`` beside this).
+    attn_window_rotary: bool = True
     # The law the "*" layers turn q and k by where it is not the plain one
     # at ``rotary_base``: an ``ops.rotary.Yarn`` (its own base in it), or
     # what ``ops.rotary.law`` makes of a published ``rope_parameters``
@@ -256,6 +259,40 @@ class GPTConfig:
     # pattern with a mixer whose record does not say ``two_copies`` (a
     # windowed, a recurrent or a chosen-keys one).
     diffusion_block: int = 0
+    # The Mamba-1 mixers' sizes (models/mamba.py, pattern letter "A"): an
+    # inner width of mamba_expand x d_model channels, a state of
+    # mamba_state a channel, mamba_conv taps, and the rank of the step
+    # size's two-matrix projection (0: Mamba's own ceil(d_model / 16)).
+    # Such a layer hands its scan output, before its gate, to the gated
+    # memory units after it ("U": no field of their own, their width is the
+    # memory's).
+    mamba_expand: int = 2
+    mamba_state: int = 16
+    mamba_conv: int = 4
+    mamba_rank: int = 0
+    # Differential attention (Ye et al., arXiv:2410.05258) in every
+    # attention layer of the three kinds that ``Attention`` builds ("*",
+    # "W" and "X", which projects a query and an output alone and reads the
+    # keys and values of the nearest "*" layer before it): the heads pair
+    # up by neighbours, query heads ``2 j`` and ``2 j + 1`` on key heads
+    # ``2 g`` and ``2 g + 1`` (``g = j // group``) make two softmax maps
+    # over one value of twice the head width, ``[v_2g | v_2g+1]``, and the
+    # pair's output is ``RMSNorm((P1 - lambda P2) V) (1 - lambda_init)``
+    # with ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init`` of
+    # four learned vectors a layer and ``lambda_init = 0.8 - 0.6 exp(-0.3
+    # l)``, ``l`` the layer's number: ``first_layer`` plus the mixers before
+    # it in this model (a feed-forward layer of a pattern is part of the
+    # decoder layer its mixer began). The difference, the norm and the
+    # scale are float32.
+    attn_differential: bool = False
+    # The published number of this model's first decoder layer, where the
+    # model is a stage of a deeper one and a layer reads its own number.
+    first_layer: int = 0
+    # A bias on the attention's four projections (q, k, v, o).
+    attn_bias: bool = False
+    # LayerNorm (the mean taken off, a weight and a bias) in place of
+    # RMSNorm for the layers' norms and the final one.
+    layer_norm: bool = False
 
 
 def _repeat_kv(k, v, group):
@@ -287,7 +324,28 @@ class RMSNorm(nn.Module):
         return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
 
 
-def _norm(cfg: "GPTConfig", name: str) -> RMSNorm:
+class LayerNorm(nn.Module):
+    """``(x - mean x) rsqrt(var x + eps) scale + bias`` over the last axis,
+    float32 inside."""
+
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones_init(),
+                           (x.shape[-1],), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros_init(),
+                          (x.shape[-1],), jnp.float32)
+        x32 = x.astype(jnp.float32)
+        centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+        var = jnp.mean(centred * centred, axis=-1, keepdims=True)
+        return (centred * jax.lax.rsqrt(var + self.eps) * scale
+                + bias).astype(x.dtype)
+
+
+def _norm(cfg: "GPTConfig", name: str) -> nn.Module:
+    if cfg.layer_norm:
+        return LayerNorm(cfg.norm_eps, name=name)
     return RMSNorm(cfg.norm_eps, cfg.norm_unit_offset, name=name)
 
 
@@ -309,24 +367,28 @@ def held_heads(n_heads, n_kv, held):
     return count, max(1, count // group)
 
 
-def _count_trace(heads, kv_heads, head_dim, core, window, rotary, blocks):
+def _count_trace(heads, kv_heads, head_dim, core, window, rotary, blocks,
+                 differential, shared):
     """One count a traced layer; ``window`` 0 in a layer that sees every
     causal key; ``rotary`` the law its q and k turn by (``none``,
     ``plain``, ``yarn``); ``blocks`` the diffusion block's length, 0 in an
-    autoregressive layer."""
+    autoregressive layer; ``differential`` 1 where the heads pair up;
+    ``shared`` 1 in a layer that reads another's keys and values."""
     _pallas.count_trace(
         "hvt_attn_layers_traced_total",
         "attention layers traced into compiled programs, by the path "
         "their products over positions take: ring, flash or einsum "
         "(counted per trace, not per execution)",
         heads=heads, kv_heads=kv_heads, head_dim=head_dim, core=core,
-        window=window, rotary=rotary, blocks=blocks)
+        window=window, rotary=rotary, blocks=blocks,
+        differential=int(differential), shared=int(shared))
 
 
-def _attend(cfg, q, k, v, positions, core, window=0):
+def _attend(cfg, q, k, v, positions, core, window=0, out_dtype=None):
     """The products over positions by the path ``core`` names: the
     ring schedule, the flash kernels, or two einsums and a softmax; of the
-    causal keys the last ``window`` alone where there is one."""
+    causal keys the last ``window`` alone where there is one; the result in
+    ``out_dtype`` where one is named (not on the ring path)."""
     *_, n_heads, head_dim = q.shape
     n_kv = k.shape[-2]
     if core == "ring":
@@ -360,9 +422,14 @@ def _attend(cfg, q, k, v, positions, core, window=0):
                               scale=1.0 / np.sqrt(head_dim),
                               use_flash=cfg.use_flash)
     if core == "flash":
-        from horovod_tpu.ops.flash_attention import flash_attention
+        from horovod_tpu.ops.flash_attention import (
+            flash_attention, flash_attention_with_lse)
 
         # the kernel serves GQA zero-copy (K/V head index aliasing)
+        if out_dtype is not None:
+            return flash_attention_with_lse(
+                q, k, v, causal=True, scale=1.0 / np.sqrt(head_dim),
+                window=window or None, out_dtype=out_dtype)[0]
         return flash_attention(q, k, v, causal=True,
                                scale=1.0 / np.sqrt(head_dim),
                                **({"window": window} if window else {}))
@@ -379,7 +446,23 @@ def _attend(cfg, q, k, v, positions, core, window=0):
     causal = causal[..., None, :, :]
     scores = jnp.where(causal, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+    if out_dtype is not None:
+        return jnp.einsum("...hqk,...khd->...qhd", probs, v,
+                          preferred_element_type=out_dtype)
     return jnp.einsum("...hqk,...khd->...qhd", probs, v)
+
+
+def differential_lambda_init(depth: int) -> float:
+    """``lambda_init`` of differential attention in layer ``depth``."""
+    return 0.8 - 0.6 * float(np.exp(-0.3 * depth))
+
+
+def _by_halves(t, width):
+    """The heads of ``t [.., heads, width]`` with the first of every pair
+    of neighbours before every second: ``[0, 2, 4, .. | 1, 3, 5, ..]``."""
+    pairs = t.shape[-2] // 2
+    return jnp.swapaxes(t.reshape(*t.shape[:-2], pairs, 2, width), -3,
+                        -2).reshape(*t.shape[:-2], 2 * pairs, width)
 
 
 def _attend_blocks(cfg, q, k, v, core):
@@ -460,10 +543,13 @@ def _attend_blocks(cfg, q, k, v, core):
 
 
 class Attention(nn.Module):
-    """The attention both of its kinds build (``KINDS``): over every
-    causal key and turned as ``cfg.rotary`` says, by the law
-    ``cfg.rotary_scaling`` names where it names one, or turned plainly and
-    inside the configuration's window."""
+    """The attention its kinds build (``KINDS``): over every causal key
+    and turned as ``cfg.rotary`` says, by the law ``cfg.rotary_scaling``
+    names where it names one; turned plainly and inside the configuration's
+    window; or ``shared``, over the keys and values another layer made
+    (``read``), with a query and an output projection of its own and no
+    other. ``hands_on``: the layer returns ``(out, (k, v))``, its keys and
+    values as its own products over positions took them."""
 
     cfg: GPTConfig
     rotary: bool        # whether q and k are turned
@@ -471,13 +557,20 @@ class Attention(nn.Module):
     # the law they turn by where it is not the plain one at
     # ``cfg.rotary_base`` (an ``ops.rotary.Yarn``)
     scaling: Optional[tuple] = None
+    depth: int = 0      # the layer's number (differential attention's)
+    shared: bool = False
+    hands_on: bool = False
+    # whether the layer sows its own input and output where its caller
+    # collects ``intermediates``, as the other mixers do (a check of one
+    # layer against a reference): a model with several kinds of layer
+    sows: bool = False
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, read=None):
         cfg = self.cfg
         head_dim = cfg.head_dim or cfg.d_model // cfg.n_heads
         dense = lambda feats, name: nn.DenseGeneral(
-            feats, axis=-1, use_bias=False, dtype=cfg.dtype,
+            feats, axis=-1, use_bias=cfg.attn_bias, dtype=cfg.dtype,
             param_dtype=jnp.float32, name=name)
         n_kv = cfg.n_kv_heads or cfg.n_heads
         if cfg.n_heads % n_kv:
@@ -503,17 +596,28 @@ class Attention(nn.Module):
                 f"a diffusion block ({cfg.diffusion_block}) in a layer that "
                 f"sees a window ({self.window}) is not built: the blocks "
                 f"are the causal limit's own")
+        if cfg.attn_differential and (
+                n_heads % 2 or n_kv % 2 or cfg.heads_held or core == "ring"
+                or cfg.diffusion_block):
+            raise ValueError(
+                f"differential attention pairs {n_heads} query heads on "
+                f"{n_kv} key-value heads by neighbours (both even), whole "
+                f"(no heads_held), off the ring path and outside a "
+                f"diffusion model: nothing else is built")
+        if self.shared and (cfg.qk_norm or cfg.head_norm
+                            or cfg.diffusion_block):
+            raise ValueError(
+                "a layer that reads another's keys and values beside "
+                "qk_norm, head_norm or a diffusion block is not built")
         # (loaded here, as the flash kernels are)
         from horovod_tpu.ops.rotary import law_name, rotary
 
         law = self.scaling or cfg.rotary_base
         _count_trace(n_heads, n_kv, head_dim, core, self.window,
                      law_name(law) if self.rotary else "none",
-                     cfg.diffusion_block)
-        # a model with both kinds of layer sows a layer's own input and
-        # output where its caller collects ``intermediates``, as the other
-        # mixers do: a check of one layer against a reference
-        sows = bool(cfg.attn_window or cfg.diffusion_block)
+                     cfg.diffusion_block, cfg.attn_differential, self.shared)
+        sows = bool(self.sows or self.shared or cfg.diffusion_block
+                    or cfg.attn_differential)
         if sows:
             self.sow("intermediates", "attn_input", x)
         with jax.named_scope("attn_proj"):
@@ -521,8 +625,11 @@ class Attention(nn.Module):
                       "q")(x)
             if cfg.attn_gate:
                 q, gate = jnp.split(q, 2, axis=-1)
-            k = dense((n_kv, head_dim), "k")(x)
-            v = dense((n_kv, head_dim), "v")(x)
+            if self.shared:
+                k, v = read
+            else:
+                k = dense((n_kv, head_dim), "k")(x)
+                v = dense((n_kv, head_dim), "v")(x)
         if cfg.qk_norm:
             full_width = lambda t, name: RMSNorm(cfg.norm_eps, name=name)(
                 t.reshape(*t.shape[:-2], -1)).reshape(t.shape)
@@ -536,32 +643,66 @@ class Attention(nn.Module):
                       else int(cfg.rotary_fraction * head_dim))
             with jax.named_scope("attn_rope"):
                 # a norm over the whole width leaves q and k [b, s, h d]
-                q, k = rotary((q, k), positions, law, turned,
-                              flat=cfg.qk_norm)
+                if self.shared:     # the keys came turned
+                    q = rotary(q, positions, law, turned)
+                else:
+                    q, k = rotary((q, k), positions, law, turned,
+                                  flat=cfg.qk_norm)
+        handed = (k, v)
+        if cfg.attn_differential:
+            # the first softmax map of every pair and then the second, as
+            # heads of one call: a head of either half reads the key head
+            # of its own half, and both the pair's value of twice the width
+            q, k = _by_halves(q, head_dim), _by_halves(k, head_dim)
+            v = jnp.tile(v.reshape(*v.shape[:-2], n_kv // 2, 2 * head_dim),
+                         (2, 1))
+        # (the two maps' difference is taken of float32 results)
+        attend = lambda *window: _attend(
+            cfg, q, k, v, positions, core, *window,
+            **({"out_dtype": jnp.float32} if cfg.attn_differential else {}))
         with jax.named_scope("attn_core"):
             if self.window:
                 # a scope of its own inside the core's, so that a reader
                 # of ``attn_core`` has both kinds and one of this the one
                 with jax.named_scope("attn_window"):
-                    out = _attend(cfg, q, k, v, positions, core, self.window)
+                    out = attend(self.window)
             elif cfg.diffusion_block:
                 # as a windowed layer's: the kernels' two walks, a block's
                 # own products and the merge under one name
                 with jax.named_scope("attn_blocks"):
                     out = _attend_blocks(cfg, q, k, v, core)
             else:
-                out = _attend(cfg, q, k, v, positions, core)
+                out = attend()
+            if cfg.attn_differential:
+                vector = lambda name: self.param(
+                    name, nn.initializers.normal(0.1), (head_dim,),
+                    jnp.float32)
+                lambdas = [vector(f"lambda_{part}")
+                           for part in ("q1", "k1", "q2", "k2")]
+                weight = self.param("subln", nn.initializers.ones_init(),
+                                    (2 * head_dim,), jnp.float32)
+                with jax.named_scope("attn_diff"):
+                    start = differential_lambda_init(self.depth)
+                    lam = (jnp.exp(jnp.sum(lambdas[0] * lambdas[1]))
+                           - jnp.exp(jnp.sum(lambdas[2] * lambdas[3])) + start)
+                    first, second = jnp.split(out.astype(jnp.float32), 2,
+                                              axis=-2)
+                    out = first - lam * second
+                    out = out * jax.lax.rsqrt(jnp.mean(
+                        out * out, axis=-1, keepdims=True) + cfg.norm_eps)
+                    out = (out * weight * (1.0 - start)).astype(cfg.dtype)
+                    out = out.reshape(*out.shape[:-2], n_heads, head_dim)
         if cfg.attn_gate:
             with jax.named_scope("attn_gate"):
                 out = (out.astype(jnp.float32) * jax.nn.sigmoid(
                     gate.astype(jnp.float32))).astype(cfg.dtype)
         with jax.named_scope("attn_out_proj"):
             out = nn.DenseGeneral(cfg.d_model, axis=(-2, -1),
-                                  use_bias=False, dtype=cfg.dtype,
+                                  use_bias=cfg.attn_bias, dtype=cfg.dtype,
                                   param_dtype=jnp.float32, name="o")(out)
         if sows:
             self.sow("intermediates", "attn_output", out)
-        return out
+        return (out, handed) if self.hands_on else out
 
 
 class MLP(nn.Module):
@@ -597,12 +738,13 @@ def _expert_layer(cfg: GPTConfig):
         shared_gate=cfg.moe_shared_gate, name="moe")
 
 
-def _windowed_attention(cfg: GPTConfig):
+def _windowed_attention(cfg: GPTConfig, depth=0):
     if cfg.attn_window < 1:
         raise ValueError(
             f"layer_pattern holds 'W' and attn_window is {cfg.attn_window}: "
             f"a windowed layer sees at least its own position")
-    return _layer(Attention(cfg, rotary=True, window=cfg.attn_window,
+    return _layer(Attention(cfg, rotary=cfg.attn_window_rotary,
+                            window=cfg.attn_window, depth=depth, sows=True,
                             name="attn"), positional=True)
 
 
@@ -624,9 +766,23 @@ def _sparse_attention(cfg: GPTConfig):
 def _layer(module, positional=False):
     """``module``, which has no auxiliary losses, called the one way a
     block calls every kind, ``(h, positions) -> (out, aux or None)``;
-    ``positional`` where it takes the positions too."""
-    return lambda h, positions: (
-        module(h, positions) if positional else module(h), None)
+    ``positional`` where it takes the positions too. A kind that reads an
+    earlier layer (``Kind.reads``) is handed that as a third argument."""
+    return lambda h, positions, *read: (
+        module(h, positions, *read) if positional else module(h, *read),
+        None)
+
+
+def _mamba(cfg: GPTConfig, hands_on=False):
+    mixer = _module("horovod_tpu.models.mamba").Mamba1Mixer(
+        cfg.mamba_expand, cfg.mamba_state, cfg.mamba_conv, cfg.mamba_rank,
+        dtype=cfg.dtype, name="mamba")
+
+    def call(h, positions):
+        out, memory = mixer(h)
+        return ((out, memory) if hands_on else out), None
+
+    return call
 
 
 def _mixers_rule(module, rule):
@@ -637,13 +793,17 @@ def _mixers_rule(module, rule):
 
 def _attention_leaf_spec(names, leaf, tp_axis, tp_size, ep_axis):
     """The attention's leaves, as ``param_partition_spec`` says."""
+    bias = names[-1] == "bias"
     if names[0] in ("q", "k", "v"):
-        heads = leaf.shape[1] if hasattr(leaf, "shape") else None
+        heads = (leaf.shape[0 if bias else 1] if hasattr(leaf, "shape")
+                 else None)
         if tp_size and heads is not None and heads % tp_size:
             return P()                     # replicated GQA K/V
-        return P(None, tp_axis, None)      # (d_model, heads, head_dim)
-    # ``o``: (heads, head_dim, d_model)
-    return P(tp_axis, None, None) if names[0] == "o" else P()
+        # (d_model, heads, head_dim), a bias (heads, head_dim)
+        return P(tp_axis, None) if bias else P(None, tp_axis, None)
+    # ``o``: (heads, head_dim, d_model); its bias, the differential
+    # attention's four vectors and its norm's weight replicate
+    return P(tp_axis, None, None) if names[0] == "o" and not bias else P()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -673,16 +833,46 @@ class Kind:
     # every sequence: its rows do not see each other, or it knows the two
     # copies apart
     two_copies: bool = False
+    # what a layer of the kind hands to later layers beside the stream, in
+    # words (None: nothing), and what it reads of the nearest earlier layer
+    # that makes it. ``build`` takes ``hands_on=True`` where a later layer
+    # reads this one, and the layer's ``out`` is then ``(out, made)``; a
+    # reader's layer is called ``(h, positions, read)``. Which layer feeds
+    # which is the pattern's to say (``_feeds``) and no field's.
+    makes: Optional[str] = None
+    reads: Optional[str] = None
+    # whether ``build`` takes the layer's number, ``depth=``
+    numbered: bool = False
+    # whether a layer of the kind closes the decoder layer its mixer began
+    # (and so has no number of its own)
+    feed_forward: bool = False
 
 
+_KEYS_VALUES, _SCAN_OUTPUT = "keys and values", "scan output before its gate"
 KINDS = {kind.letter: kind for kind in (
     Kind("*", "attn", "attention",
-         lambda cfg: _layer(Attention(cfg, rotary=cfg.rotary,
-                                      scaling=cfg.rotary_scaling,
-                                      name="attn"), positional=True),
-         _attention_leaf_spec, two_copies=True),
+         lambda cfg, depth=0, hands_on=False: _layer(Attention(
+             cfg, rotary=cfg.rotary, scaling=cfg.rotary_scaling, depth=depth,
+             hands_on=hands_on, sows=bool(cfg.attn_window), name="attn"),
+             positional=True),
+         _attention_leaf_spec, two_copies=True, makes=_KEYS_VALUES,
+         numbered=True),
     Kind("W", "attn", "attention inside a window", _windowed_attention,
-         _attention_leaf_spec),
+         _attention_leaf_spec, numbered=True),
+    Kind("X", "cross", "attention over an earlier layer's keys and values",
+         lambda cfg, depth=0: _layer(Attention(
+             cfg, rotary=cfg.rotary, scaling=cfg.rotary_scaling, depth=depth,
+             shared=True, name="cross"), positional=True),
+         _attention_leaf_spec, reads=_KEYS_VALUES, numbered=True),
+    Kind("A", "mamba", "Mamba-1", _mamba,
+         _mixers_rule("horovod_tpu.models.mamba", "mamba_leaf_spec"),
+         makes=_SCAN_OUTPUT),
+    Kind("U", "gmu", "gated memory unit",
+         lambda cfg: _layer(
+             _module("horovod_tpu.models.mamba").GatedMemoryUnit(
+                 dtype=cfg.dtype, name="gmu")),
+         _mixers_rule("horovod_tpu.models.mamba", "gmu_leaf_spec"),
+         reads=_SCAN_OUTPUT),
     Kind("M", "ssm", "Mamba-2",
          lambda cfg: _layer(_module("horovod_tpu.models.ssm").Mamba2Mixer(
              cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state,
@@ -731,12 +921,38 @@ KINDS = {kind.letter: kind for kind in (
                  names[-1], leaf, ep_axis, tp_axis),
          lambda: (_module("horovod_tpu.models.moe").HELD_SUM,
                   _module("horovod_tpu.models.moe").HELD_CHOICE),
-         two_copies=True),
+         two_copies=True, feed_forward=True),
     Kind("-", "mlp", "MLP", lambda cfg: _layer(MLP(cfg, name="mlp")),
          lambda names, leaf, tp_axis, *_: {
              "up": P(None, tp_axis), "gate": P(None, tp_axis),
-             "down": P(tp_axis, None)}.get(names[0], P()), two_copies=True),
+             "down": P(tp_axis, None)}.get(names[0], P()), two_copies=True,
+         feed_forward=True),
 )}
+
+
+def _feeds(pattern) -> dict:
+    """``{reader: maker}`` by the layers' places in ``pattern``: a layer
+    whose kind reads something (``Kind.reads``) reads it of the nearest
+    earlier layer whose kind makes it. A reader with nothing before it to
+    read is refused by name. (A letter no record has is ``MixerBlock``'s
+    to name.)"""
+    feeds, last = {}, {}
+    for i, letter in enumerate(pattern or ""):
+        kind = KINDS.get(letter)
+        if kind is not None and kind.reads:
+            if kind.reads not in last:
+                makers = ", ".join(
+                    f"{k.letter!r} ({k.words})" for k in KINDS.values()
+                    if k.makes == kind.reads)
+                raise ValueError(
+                    f"layer_pattern {pattern!r}: layer {i} is {letter!r} "
+                    f"({kind.words}), which reads the {kind.reads} of the "
+                    f"nearest earlier layer that makes them, and none of "
+                    f"{makers} comes before it")
+            feeds[i] = last[kind.reads]
+        if kind is not None and kind.makes:
+            last[kind.makes] = i
+    return feeds
 
 
 def _diffusion_half(cfg: GPTConfig, rows: int) -> int:
@@ -771,11 +987,13 @@ class Block(nn.Module):
     (``models/moe.py``), None for a dense block."""
 
     cfg: GPTConfig
+    depth: int = 0      # the layer's number (differential attention's)
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        out, _ = KINDS["*"].build(cfg)(_norm(cfg, "ln1")(x), positions)
+        out, _ = KINDS["*"].build(cfg, depth=self.depth)(
+            _norm(cfg, "ln1")(x), positions)
         x = x + out
         out, aux = KINDS["E" if cfg.n_experts else "-"].build(cfg)(
             _norm(cfg, "ln2")(x), positions)
@@ -786,25 +1004,36 @@ class MixerBlock(nn.Module):
     """One layer of a patterned model: ``x + mixer(RMSNorm(x))`` with the
     one mixer ``kind`` names (a letter of ``KINDS``), or under
     ``post_norm`` ``x + RMSNorm(mixer(RMSNorm(x)))``. Returns ``(x, aux)``
-    as ``Block`` does."""
+    as ``Block`` does; called with what its kind reads of an earlier layer
+    as a third argument (``Kind.reads``), and under ``hands_on`` it returns
+    ``(x, aux, made)``, what its kind makes for a later one
+    (``Kind.makes``)."""
 
     cfg: GPTConfig
     kind: str
+    depth: int = 0      # the decoder layer's number
+    hands_on: bool = False
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, *read):
         cfg = self.cfg
         if self.kind not in KINDS:
             raise ValueError(
                 f"layer_pattern holds {self.kind!r}: a layer is one of "
                 + ", ".join(f"{kind.letter!r} ({kind.words})"
                             for kind in KINDS.values()))
-        out, aux = KINDS[self.kind].build(cfg)(
-            _norm(cfg, "norm")(x), positions)
+        kind = KINDS[self.kind]
+        out, aux = kind.build(
+            cfg, **({"depth": self.depth} if kind.numbered else {}),
+            **({"hands_on": True} if self.hands_on else {}))(
+                _norm(cfg, "norm")(x), positions, *read)
+        made = ()
+        if self.hands_on:
+            out, *made = out
         if cfg.post_norm:
             with jax.named_scope("post_norm"):
                 out = _norm(cfg, "post_norm")(out)
-        return x + out, aux
+        return (x + out, aux, *made)
 
 
 class GPT(nn.Module):
@@ -857,11 +1086,25 @@ class GPT(nn.Module):
                 policy=jax.checkpoint_policies.save_only_these_names(*(
                     name for kind in KINDS.values()
                     for name in kind.kept())))
-        aux = {}
+        # what a layer reads of an earlier one beside the stream: under
+        # ``remat`` it is one block's output and another's input, kept once
+        feeds = _feeds(cfg.layer_pattern)
+        aux, made, depth = {}, {}, cfg.first_layer
         for i in range(cfg.n_layers):
             kind = () if cfg.layer_pattern is None else (
                 cfg.layer_pattern[i],)
-            x, layer_aux = block(cfg, *kind, name=f"block_{i}")(x, positions)
+            # a feed-forward layer of a pattern has the number of the
+            # decoder layer it closes
+            closes = any(letter in KINDS and KINDS[letter].feed_forward
+                         for letter in kind)
+            x, layer_aux, *handed = block(
+                cfg, *kind, depth=depth - closes,
+                **({"hands_on": True} if i in feeds.values() else {}),
+                name=f"block_{i}")(
+                    x, positions, *((made[feeds[i]],) if i in feeds else ()))
+            if handed:
+                made[i], = handed
+            depth += not closes
             if layer_aux is not None:
                 aux = {**aux, **{name: aux.get(name, 0.0) + value
                                  for name, value in layer_aux.items()}}

@@ -149,3 +149,24 @@ def qwen_loss(model, params, tokens, sow=False):
     loss = optax.softmax_cross_entropy_with_integer_labels(
         logits[:, :-1], tokens[:, 1:]).mean()
     return (loss, sown["intermediates"]) if sow else loss
+
+
+# ---- a tree of seeded random leaves from shapes alone
+
+def random_tree(shapes, seed, spread=0.1):
+    """Leaves of ``shapes`` (a tree of ``ShapeDtypeStruct``, from
+    ``jax.eval_shape`` of an ``init``: nothing initialised, nothing
+    compiled; numpy draws them) at ``spread`` around 0, and around 1 where
+    a leaf is a norm's weight or a skip term (``scale``, ``subln``,
+    ``D_skip``): every bias away from 0 and every weight from 1, for a
+    comparison with a reference on the same tree."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    centre = lambda path: float(any(
+        name in jax.tree_util.keystr(path)
+        for name in ("scale", "subln", "D_skip")))
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: jnp.asarray(
+            centre(path) + spread * rng.standard_normal(leaf.shape),
+            leaf.dtype), shapes)

@@ -112,7 +112,8 @@ def _counted(heads, kv_heads, head_dim, core, window=0, rotary="plain"):
     m = metrics.registry().get("hvt_attn_layers_traced_total")
     return m.labels(heads=str(heads), kv_heads=str(kv_heads),
                     head_dim=str(head_dim), core=core, window=str(window),
-                    rotary=rotary, blocks="0").value if m else 0.0
+                    rotary=rotary, blocks="0", differential="0",
+                    shared="0").value if m else 0.0
 
 
 @pytest.mark.parametrize("use_flash, seq, on_tpu, core", [

@@ -527,7 +527,8 @@ def test_the_layer_counter_carries_the_block_length(case):
         m = metrics.registry().get("hvt_attn_layers_traced_total")
         return m.labels(heads="4", kv_heads="2", head_dim="16", core="einsum",
                         window="0", rotary="plain",
-                        blocks=str(blocks)).value if m else 0.0
+                        blocks=str(blocks), differential="0",
+                        shared="0").value if m else 0.0
 
     params, batch = case
     before = [count(0), count(SIZE)]

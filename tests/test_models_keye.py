@@ -308,7 +308,7 @@ def test_mixer_refuses_what_it_does_not_build():
             jax.eval_shape(GPT(dataclasses.replace(CFG, **bad)).init,
                            jax.random.key(0), tokens)
     with pytest.raises(ValueError, match="attention over chosen keys"):
-        jax.eval_shape(GPT(dataclasses.replace(CFG, layer_pattern="SEXE")).init,
+        jax.eval_shape(GPT(dataclasses.replace(CFG, layer_pattern="SEQE")).init,
                        jax.random.key(0), tokens)
 
 

@@ -32,7 +32,11 @@ SOURCE = (pathlib.Path(__file__).resolve().parent.parent / "horovod_tpu"
 # ``n_experts`` is the experts' and has no other value here: it is what
 # ``Block`` asks to choose between "E" and "-".
 OWN = {
-    "attn": {"attn_window": (0, 4)},
+    "attn": {"attn_window": (0, 4), "attn_window_rotary": (True, False)},
+    "cross": {},
+    "mamba": {"mamba_expand": (2, 3), "mamba_state": (16, 4),
+              "mamba_conv": (4, 3), "mamba_rank": (0, 2)},
+    "gmu": {},
     "ssm": {"ssm_heads": (0, 4), "ssm_head_dim": (64, 8),
             "ssm_groups": (1, 2), "ssm_state": (128, 16), "ssm_conv": (4, 3),
             "ssm_heads_held": (None, (0, 2))},
@@ -63,7 +67,7 @@ _PREFIX = {"attn": ("attn_window",), "mlp": (),
 _BLOCK_ASKS = [("Block", "n_experts")]
 # a scope that only a layer of the letter puts into a program, where it is
 # not ``/<subtree>_...``
-_SCOPE = {"W": "/attn_window/", "-": "/dense_mlp/"}
+_SCOPE = {"W": "/attn_window/", "-": "/dense_mlp/", "X": "/cross/"}
 
 
 def _family(field):
@@ -281,7 +285,7 @@ def test_other_models_steps_are_as_they_were(other):
     mine = lowered(cfg)
     names = re.findall(r'loc\("([^"]*)"', mine.as_text(debug_info=True))
     assert names
-    for scope in ["/post_norm/"] + [
+    for scope in ["/post_norm/", "/attn_diff/"] + [
             _SCOPE.get(letter, f"/{KINDS[letter].subtree}_")
             for letter in foreign]:
         assert not [n for n in names if scope in n], scope
@@ -299,7 +303,9 @@ def test_pattern_error_names_the_letter(letter):
              "M": "Mamba-2", "G": "Gated DeltaNet",
              "K": "Kimi Delta Attention", "C": "gated short convolution",
              "L": "latent attention", "S": "attention over chosen keys",
-             "E": "experts", "-": "MLP"}[letter]
+             "E": "experts", "-": "MLP", "A": "Mamba-1",
+             "U": "gated memory unit",
+             "X": "attention over an earlier layer's keys and values"}[letter]
     with pytest.raises(ValueError, match=(
             "layer_pattern holds 'Q': a layer is one of .*"
             + re.escape(f"{letter!r} ({words})"))):

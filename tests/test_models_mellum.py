@@ -136,8 +136,10 @@ def test_each_kind_of_mixer_turns_by_its_own_law(block, kind):
 
 
 def test_the_windowed_layers_read_nothing_of_the_new_field():
-    """``rotary_scaling`` is read once in ``models/transformer.py``, inside
-    the record of ``*``; a model of windowed layers alone lowers to the
+    """``rotary_scaling`` is read in ``models/transformer.py`` inside the
+    record of ``*`` and, since PR 66, of ``X`` (whose queries turn by the
+    law the keys it reads were turned by) and nowhere else; a model of
+    windowed layers alone lowers to the
     same step whatever the field says, and a model of full layers does
     not."""
     source = (pathlib.Path(__file__).resolve().parent.parent / "horovod_tpu"
@@ -147,9 +149,10 @@ def test_the_windowed_layers_read_nothing_of_the_new_field():
         node, ast.Attribute) and node.attr == "rotary_scaling"]
     records = [call for call in ast.walk(tree) if isinstance(call, ast.Call)
                and getattr(call.func, "id", None) == "Kind"
-               and call.args[0].value == "*"]
-    assert len(reads) == 1 and len(records) == 1
-    assert reads[0] in list(ast.walk(records[0]))
+               and call.args[0].value in "*X"]
+    assert len(reads) == 2 and len(records) == 2
+    assert all(read in list(ast.walk(record))
+               for read, record in zip(reads, records))
 
     def step(pattern, **changes):
         cfg = _config(pattern, **changes)
@@ -421,7 +424,8 @@ def test_the_step_names_attn_rope_in_both_kinds_and_the_counter_the_law():
         m = metrics.registry().get("hvt_attn_layers_traced_total")
         return m.labels(heads="4", kv_heads="2", head_dim="16",
                         core="einsum", window=str(window),
-                        rotary=rotary, blocks="0").value if m else 0.0
+                        rotary=rotary, blocks="0", differential="0",
+                        shared="0").value if m else 0.0
 
     labels = [(_WINDOW, "plain"), (0, "yarn"), (0, "none"), (0, "plain")]
     before = [counted(*label) for label in labels]

@@ -287,7 +287,8 @@ def test_the_step_names_its_scopes_and_the_counter_the_window():
         lambda m: m.labels(heads="4", kv_heads="2", head_dim="8",
                            core="einsum", window=str(window),
                            rotary="plain" if window else "none",
-                           blocks="0").value
+                           blocks="0", differential="0",
+                           shared="0").value
         if m else 0.0)(metrics.registry().get("hvt_attn_layers_traced_total"))
     params, buffers, tokens = _state()
     model = GPT(_config(remat=True))
