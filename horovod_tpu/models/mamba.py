@@ -133,7 +133,7 @@ class Mamba1Mixer(nn.Module):
         w_out = self.param("out_proj", dense, (inner, d))
         chunk = scan_op.chunk_for(seq, self.chunk)
         _count_trace(inner, n, chunk, "kernels" if scan_op.serves(
-            inner, n, chunk) else "plain")
+            seq, inner, n, chunk, self.dtype, DECAY_DTYPE) else "plain")
 
         self.sow("intermediates", "mamba_input", x)
         lead = x.shape[:-2]

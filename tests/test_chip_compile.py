@@ -8,6 +8,7 @@ The compiles run in the pytest process, one at a time: two processes that
 describe the topology at once collide on libtpu's lock file.
 """
 
+import functools
 import os
 import re
 
@@ -1073,3 +1074,121 @@ def test_sparse_attention_turns_q_and_k_by_the_kernel_in_whole_blocks(
                    "hvt_dsa_choice"):
         assert any(f"/{kernel}/" in n for n in names), kernel
 
+
+@pytest.mark.parametrize("seq, dtype", [
+    pytest.param(16384, jnp.bfloat16, id="scan-phi4flash-s16384"),
+    pytest.param(16384, jnp.float32, id="scan-phi4flash-s16384-float32"),
+    pytest.param(2048, jnp.bfloat16, id="scan-phi4flash-probe"),
+    pytest.param(2048, jnp.float32, id="scan-phi4flash-probe-float32"),
+])
+def test_selective_scan_kernels_compile_for_v5e(seq, dtype, compiled_kernel,
+                                                v5e_devices):
+    """The two kernels of ``ops/selective_scan.py`` at ``phi4flash-s16384``'s
+    own sizes (one sequence of 16,384 positions, 5,120 channels, a state of
+    16) and at its probe's 2,048 positions, in bf16 and with float32
+    operands (the probe runs both), forward and backward, with the blocks
+    they derive: both compile inside the 24 MiB of VMEM they ask for
+    (Mosaic refuses a kernel whose blocks and scratch do not fit its
+    limit), and what the program holds beside its operands and results is
+    the kept states and the sums, never ``[s, D, N]``."""
+    from horovod_tpu.ops import selective_scan as scan_op
+
+    channels, n = 5120, 16
+    one = SingleDeviceSharding(v5e_devices[0])
+    like = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one)
+    operands = (like(1, seq, channels, dtype=dtype), like(1, seq, channels),
+                like(channels, n), like(1, seq, n, dtype=dtype),
+                like(1, seq, n, dtype=dtype))
+    assert scan_op.VMEM_LIMIT <= 24 * 2 ** 20
+    plan = scan_op._plan(channels, scan_op.CHUNK, None, None)
+    assert (plan.tile, plan.fwd, plan.bwd) == (128, 512, 512)
+
+    def loss(*a):
+        return jnp.mean(scan_op.selective_scan_kernels(*a).astype(
+            jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        *operands).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert all(f"hvt_mamba_scan_{kernel}" in text for kernel in ("fwd", "bwd"))
+    # the kept states (s / 128 of [N, D] float32), this loss's own [s, D]
+    # and little else: under an eighth of one [s, D, N] float32
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 4 * seq * channels * n // 8
+
+
+def test_mamba1_layer_lowers_with_the_scan_kernels_under_its_scope(
+        compiled_kernel, v5e_devices, monkeypatch):
+    """``phi4flash-s16384``'s ``Mamba1Mixer`` at its published widths
+    (2,560 wide, 5,120 channels, a state of 16, bf16) at the probe's 2,048
+    positions, as a TPU backend traces it (the backend is the CPU here, so
+    the test steers the rule): the layer's gradient holds one
+    ``hvt_mamba_scan_fwd`` and one ``hvt_mamba_scan_bwd``, both under
+    ``mamba_scan`` (what ``chipbench/layer_metrics`` matches the scope
+    by), beside the convolution's two under ``mamba_conv``; and the counter
+    says ``body="kernels"``."""
+    from horovod_tpu import metrics
+    from horovod_tpu.models import mamba
+
+    monkeypatch.setattr(_pallas, "on_tpu", lambda: True)
+    layer = mamba.Mamba1Mixer(expand=2, state=16, conv=4, dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((1, 2048, 2560), jnp.bfloat16)
+    variables, (x,) = _shapes_on(v5e_devices[0], layer.init, x)
+
+    def counted():
+        m = metrics.registry().get("hvt_mamba_layers_traced_total")
+        return m.labels(channels="5120", state="16", chunk="128",
+                        body="kernels").value if m else 0.0
+
+    def loss(params, x):
+        out, memory = layer.apply({"params": params}, x)
+        return jnp.mean(out.astype(jnp.float32) ** 2) + jnp.mean(
+            memory.astype(jnp.float32))
+
+    before = counted()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        variables["params"], x).compile().as_text()
+    assert counted() == before + 1
+    names = re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*?op_name="([^"]*)"', text)
+    for kernel in ("hvt_mamba_scan_fwd", "hvt_mamba_scan_bwd"):
+        mine = [n for n in names if f"/{kernel}/" in n]
+        assert len(mine) == 1 and "/mamba_scan/" in mine[0], (kernel, names)
+    assert sum("/mamba_conv/" in n for n in names) == 2, names
+
+
+def test_selective_scan_kernels_bodies_stay_inside_the_starts_budget(
+        compiled_kernel):
+    """``_fwd_call`` and ``_bwd_call`` traced at ``phi4flash-s16384``'s own
+    shape on the branch a TPU compiles (``interpret=False``: a CPU traces
+    it, though it cannot compile it): the two together hold at most 1,000
+    equations, loop and kernel bodies included
+    (``benchmarks/selective_scan.py --count`` prints the same count). An
+    equation of a kernel body costs the cell 0.9 ms of ``trace_s`` and 0.2
+    ms of ``lower_s`` on every start, cached executable or not (ledger, PR
+    67: bodies of 2,982 equations raised ``setup_s`` by 4.10 s of a bound
+    of 3.16 and the PR was refused for it at +17% ``tok_s_chip``), so a
+    later edit that unrolls a loop in Python fails here and not at the
+    driver."""
+    from benchmarks.selective_scan import equations
+    from horovod_tpu.ops import selective_scan as scan_op
+
+    seq, channels, n = 16384, 5120, 16
+    like = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(dims, dtype)
+    operands = (like(1, seq, channels, dtype=jnp.bfloat16),
+                like(1, seq, channels), like(channels, n),
+                like(1, seq, n, dtype=jnp.bfloat16),
+                like(1, seq, n, dtype=jnp.bfloat16))
+    plan = scan_op._plan(channels, scan_op.CHUNK, None, None)
+    assert not plan.interpret
+    fwd = jax.make_jaxpr(functools.partial(scan_op._fwd_call, plan=plan))(
+        *operands)
+    y, entered = fwd.out_avals
+    bwd = jax.make_jaxpr(functools.partial(scan_op._bwd_call, plan=plan))(
+        *operands, entered, y)
+    counts = equations(fwd.jaxpr), equations(bwd.jaxpr)
+    # a body is there to count: the recurrence's one exponential a position
+    assert "exp2" in str(fwd) and "exp2" in str(bwd)
+    assert sum(counts) <= 1000, counts
